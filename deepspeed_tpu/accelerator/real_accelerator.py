@@ -3,8 +3,10 @@
 
 Selection order:
   1. explicit ``set_accelerator()``
-  2. ``DSTPU_ACCELERATOR`` env var ("tpu" | "cpu")
-  3. auto-detect: TPU if the default jax backend exposes TPU-ish devices,
+  2. ``DSTPU_ACCELERATOR`` env var ("tpu" | "cpu"); "tpu" with no TPU
+     present raises :class:`AcceleratorUnavailableError` — an explicit
+     request is never answered with the CPU
+  3. auto-detect: TPU if the default jax backend exposes TPU devices,
      else CPU.
 """
 
@@ -18,17 +20,23 @@ from .abstract_accelerator import DeepSpeedAccelerator
 _accelerator: Optional[DeepSpeedAccelerator] = None
 
 
+class AcceleratorUnavailableError(RuntimeError):
+    """The accelerator that was asked for by name is not present."""
+
+
 def _detect() -> DeepSpeedAccelerator:
     from .tpu_accelerator import CPU_Accelerator, TPU_Accelerator
 
     env = os.environ.get("DSTPU_ACCELERATOR")
     if env == "cpu":
         return CPU_Accelerator()
-    if env == "tpu":
-        return TPU_Accelerator()
     tpu = TPU_Accelerator()
     if tpu.is_available():
         return tpu
+    if env == "tpu":
+        raise AcceleratorUnavailableError(
+            "DSTPU_ACCELERATOR=tpu but jax.devices() holds no TPU "
+            "(unset the variable to auto-detect, or set it to 'cpu')")
     return CPU_Accelerator()
 
 

@@ -33,14 +33,52 @@ class ModelSpec(Protocol):
 ATTN_IMPLS = ("dense", "flash", "ring", "ring_flash", "ulysses")
 
 
+def _partitioned_flash_attention(q, k, v, causal: bool):
+    """The Pallas flash kernel on [B, T, H, Dh] operands, partitioned by
+    hand where the initialised topology spans more than one device: the TPU
+    compiler refuses to partition a Mosaic kernel itself ("wrap the call in
+    a shard_map"), and interpret mode never showed it because it lowers to
+    plain HLO. Batch over BATCH_AXES, heads over the model axis, sequence
+    and head-dim whole — each shard is an independent attention problem, so
+    the custom-vjp backward partitions the same way. A dimension its axes do
+    not divide stays whole (replicated); inside somebody else's shard_map
+    (pipeline stages, the 1-bit step) the operands are already local."""
+    from deepspeed_tpu.ops.flash_attention import (_interpret_default,
+                                                   flash_attention)
+    from deepspeed_tpu.parallel.topology import BATCH_AXES, MODEL_AXIS
+    from deepspeed_tpu.utils import groups
+
+    mesh = groups.get_mesh() if groups.is_initialized() else None
+    if (mesh is None or mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return flash_attention(q, k, v, causal)
+    from jax.sharding import PartitionSpec as P
+
+    from deepspeed_tpu.utils.jax_compat import has_vma_typing, shard_map
+
+    def axes_dividing(dim, axes):
+        n = 1
+        for a in axes:
+            n *= mesh.shape[a]
+        return axes if n > 1 and dim % n == 0 else None
+
+    spec = P(axes_dividing(q.shape[0], BATCH_AXES), None,
+             axes_dividing(q.shape[2], (MODEL_AXIS,)), None)
+    # strict vma checking for compiled TPU runs only: the interpreter cannot
+    # type kernel-internal literals against varying refs (the same idiom as
+    # ops/ring_attention.ulysses_attention)
+    strict = not _interpret_default() and has_vma_typing()
+    return shard_map(lambda ql, kl, vl: flash_attention(ql, kl, vl, causal),
+                     mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+                     check_vma=strict)(q, k, v)
+
+
 def sp_attention(attn_impl: str, q, k, v, *, causal: bool = True):
     """Dispatch to the non-dense attention ops: Pallas flash kernel, or the
     sequence-parallel ring / Ulysses forms (models stay topology-agnostic —
     the mesh comes from the globally-initialized topology)."""
     if attn_impl == "flash":
-        from deepspeed_tpu.ops.flash_attention import flash_attention
-
-        return flash_attention(q, k, v, causal)
+        return _partitioned_flash_attention(q, k, v, causal)
     from deepspeed_tpu.ops.ring_attention import (
         ring_attention, ring_flash_attention, ulysses_attention)
     from deepspeed_tpu.utils import groups

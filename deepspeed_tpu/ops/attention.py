@@ -94,8 +94,15 @@ def alloc_kv_cache(num_layers: int, batch: int, num_kv_heads: int,
     pair = (kv_pack_factor(head_dim)
             if (packed and batch >= 2 and max_len % 128 == 0) else 1)
     assert max_len % max(pair, 1) == 0, (max_len, pair)
-    return jnp.zeros((num_layers, batch, num_kv_heads, max_len // pair,
-                      head_dim * pair), dtype)
+    # The barrier makes the zeros a real buffer. Without it the TPU compiler
+    # (libtpu 0.0.34) turns an in-program cache that the layer scan updates
+    # into an uninitialised AllocateBuffer, also where the prompt fills only
+    # its head: generate()'s batch-1 prefill then attends over whatever the
+    # tail of the buffer held, and 0 * NaN made every logit NaN on the v5e
+    # (tests/unit/ops/test_tpu_compile.py pins the compiled text).
+    return jax.lax.optimization_barrier(
+        jnp.zeros((num_layers, batch, num_kv_heads, max_len // pair,
+                   head_dim * pair), dtype))
 
 
 def cache_seq_len(k_full, head_dim: int) -> int:

@@ -59,10 +59,7 @@ _VMEM_LIMIT = 40 * 1024 * 1024
 
 
 def _compiler_params(vmem_bytes: int = _VMEM_LIMIT):
-    try:
-        return pltpu.CompilerParams(vmem_limit_bytes=vmem_bytes)
-    except Exception:  # older naming (flash_attention._grid_params idiom)
-        return pltpu.TPUCompilerParams(vmem_limit_bytes=vmem_bytes)
+    return pltpu.CompilerParams(vmem_limit_bytes=vmem_bytes)
 
 
 def supports(hq: int, hkv: int, s_max: int, dh: int) -> bool:

@@ -206,20 +206,12 @@ def _pick_block(t: int, pref: int) -> int:
     return max(blk, 1)
 
 
-@functools.lru_cache(maxsize=1)
 def vma_typing_supported() -> bool:
-    """True when this JAX carries shard_map varying-axis (vma) typing
-    (aval ``.vma`` + ``ShapeDtypeStruct(vma=...)``). On versions predating
-    it, ``_sds``'s getattr silently finds no vma, so strict-checked
-    shard_map would reject pallas_call outputs opaquely — callers
-    (ops/ring_attention.py) use this to fall back to check_vma=False."""
-    try:
-        jax.ShapeDtypeStruct((1,), jnp.float32, vma=frozenset())
-        return hasattr(jax.typeof(jnp.zeros(())), "vma")
-    except Exception:
-        # any probe failure (TypeError on old ShapeDtypeStruct, AttributeError
-        # when jax.typeof is absent, ...) degrades to check_vma=False
-        return False
+    """The installed JAX carries shard_map varying-axis (vma) typing
+    (aval ``.vma`` + ``ShapeDtypeStruct(vma=...)``), which ``_sds`` below
+    declares on pallas_call outputs; callers (ops/ring_attention.py) keep
+    asking so that strict checking stays a decision made in one place."""
+    return True
 
 
 def _sds(*operands_then_args):
@@ -238,10 +230,7 @@ def _sds(*operands_then_args):
 
 
 def _grid_params(seq_semantics=("parallel", "parallel", "arbitrary")):
-    try:
-        return pltpu.CompilerParams(dimension_semantics=seq_semantics)
-    except Exception:  # older naming
-        return pltpu.TPUCompilerParams(dimension_semantics=seq_semantics)
+    return pltpu.CompilerParams(dimension_semantics=seq_semantics)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))

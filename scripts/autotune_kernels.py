@@ -19,8 +19,7 @@ The HAND-PICKED plan is always candidate 0 and the chosen plan is the
 measured argmin, so a committed entry beats-or-ties the constants by
 construction in its own windows (``us`` vs ``hand_us`` record both).
 Timing methodology is bench.py's: per-candidate MEDIAN over several
-best-of windows with block_until_ready fences — on a time-shared chip
-one long window measures co-tenant load as much as the kernel.
+best-of windows with block_until_ready fences.
 
 Usage:
     python scripts/autotune_kernels.py --preset cpu-smoke   # sandbox

@@ -13,9 +13,7 @@ trajectory was flying blind (ISSUE 11 satellite). This script flattens
 every numeric leaf of each round's ``parsed`` payload into a dotted
 metric path (``serving.bf16.decode_ms_per_token``), lines the rounds up
 into per-metric series, and flags the newest value against the previous
-round with a NOISE THRESHOLD (default 10% relative — the bench chip is
-time-shared and identical configs swing between minutes; see bench.py's
-best-of-windows commentary):
+round with a NOISE THRESHOLD (default 10% relative):
 
   * ``regression``  — moved past the threshold in the BAD direction
   * ``improvement`` — moved past the threshold in the GOOD direction

@@ -93,10 +93,11 @@ def test_registry_exposes_flash_attention():
 
 @pytest.mark.parametrize("tp,stage", [(2, 1), (1, 3)])
 def test_flash_composes_with_tp_and_zero(tp, stage):
-    """The Pallas kernel must partition under GSPMD: flash attention inside
-    the fused train step on a tp>1 (model-axis) and a ZeRO-3 (data-axis)
-    mesh — the bench's default attention path since the 512-block grid
-    rewrite."""
+    """Flash attention inside the fused train step on a tp>1 (model-axis)
+    and a ZeRO-3 (data-axis) mesh — the bench's default attention path.
+    Here the kernel is interpreted, which GSPMD could partition by itself;
+    the compiled kernel cannot, so sp_attention runs it under shard_map
+    (tests/unit/ops/test_tpu_compile.py compiles that for a 2x2 v5e)."""
     import deepspeed_tpu
     from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
     from deepspeed_tpu.parallel.topology import build_topology

@@ -1,0 +1,191 @@
+"""The main path's Pallas kernels compile for a TPU v5e at real widths.
+
+No chip is needed: the TPU compiler installed here compiles for a chip that
+is *described* (``jax.experimental.topologies``), and refuses what the chip's
+compiler would refuse — misaligned slices, too much VMEM, a kernel GSPMD
+cannot partition. Interpret mode, which every other kernel test uses, sees
+none of that. Nothing runs, so these say nothing about results or speed.
+
+This is the ONLY file that describes a topology, and it does so inside a
+fixture: only the process that is given this file may load the TPU library
+(see /opt/skills/guides/on-chip-measurement, section 2). The kernel cases
+assert ``tpu_custom_call`` in the compiled text, i.e. the kernel is really
+there; the last case reads the text for an uninitialised buffer.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(sharding, shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_text(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the compiled text"
+    return text
+
+
+def _flash_loss(q, k, v):
+    from deepspeed_tpu.ops.flash_attention import flash_attention
+
+    out = flash_attention(q, k, v, True, None, 512, 512, False)
+    return jnp.sum(out.astype(jnp.float32))
+
+
+# (B, T, H, Dh): GPT-2 125M at chip_smoke's micro-batch; LLaMA-7B widths
+FLASH_SHAPES = [(8, 1024, 12, 64), (2, 2048, 32, 128)]
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
+def test_flash_forward(one_chip, shape):
+    q = _sds(one_chip, shape)
+    _compiled_text(_flash_loss, q, q, q)
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
+def test_flash_backward(one_chip, shape):
+    q = _sds(one_chip, shape)
+    _compiled_text(jax.grad(_flash_loss, argnums=(0, 1, 2)), q, q, q)
+
+
+# (L, B, Hq, Hkv, S, Dh): 125M serving at 8 slots (token-pair packed cache);
+# LLaMA-7B widths, batch 1
+DECODE_SHAPES = [(12, 8, 12, 12, 1024, 64), (32, 1, 32, 32, 2048, 128)]
+
+
+def _decode_operands(sh, l, b, hq, hkv, s, dh, *, block_size=None):
+    from deepspeed_tpu.ops.attention import kv_pack_factor
+
+    pair = kv_pack_factor(dh)
+    if block_size is None:            # slot-paged: [L, B, Hkv, S/pair, Dh*pair]
+        cache = _sds(sh, (l, b, hkv, s // pair, dh * pair))
+    else:                             # block pool, +1 garbage block
+        n = b * (s // block_size) + 1
+        cache = _sds(sh, (l, n, hkv, block_size // pair, dh * pair))
+    return (_sds(sh, (b, 1, hq, dh)), cache, cache,
+            _sds(sh, (b, 1, hkv, dh)), _sds(sh, (b, 1, hkv, dh)),
+            _sds(sh, (), jnp.int32), _sds(sh, (b,), jnp.int32))
+
+
+@pytest.mark.parametrize("dims", DECODE_SHAPES, ids=str)
+def test_fused_decode_step(one_chip, dims):
+    from deepspeed_tpu.ops.decode_step import fused_decode_step
+
+    fn = functools.partial(fused_decode_step, interpret=False)
+    _compiled_text(fn, *_decode_operands(one_chip, *dims))
+
+
+@pytest.mark.parametrize("dims", DECODE_SHAPES, ids=str)
+def test_fused_block_decode_step(one_chip, dims):
+    from deepspeed_tpu.ops.decode_step import fused_block_decode_step
+
+    l, b, hq, hkv, s, dh = dims
+    block_size = 128
+    table = _sds(one_chip, (b, s // block_size), jnp.int32)
+    fn = functools.partial(fused_block_decode_step, interpret=False)
+    _compiled_text(fn, *_decode_operands(one_chip, *dims,
+                                         block_size=block_size), table)
+
+
+def test_int8_matmul_dma(one_chip):
+    from deepspeed_tpu.ops.int8_matmul import int8_matmul_dma
+
+    d, e = 768, 3072                  # 125M's MLP up-projection, 8 decode rows
+    fn = functools.partial(int8_matmul_dma, interpret=False)
+    _compiled_text(fn, _sds(one_chip, (8, d)), _sds(one_chip, (d, e), jnp.int8),
+                   _sds(one_chip, (1, e), jnp.float32))
+
+
+def test_flash_decode_gqa(one_chip):
+    from deepspeed_tpu.ops.flash_decode import flash_decode
+
+    b, hq, hkv, s, dh = 8, 32, 4, 2048, 128     # wide GQA: rep 8
+    cache = _sds(one_chip, (b, hkv, s, dh))
+    fn = functools.partial(flash_decode, interpret=False)
+    _compiled_text(fn, _sds(one_chip, (b, 1, hq, dh)), cache, cache,
+                   _sds(one_chip, (), jnp.int32))
+
+
+@pytest.fixture
+def mesh_2x2(topo, monkeypatch):
+    """data=2 x model=2 over the four described chips, installed as the
+    initialised topology. ``sp_attention`` picks interpret mode and strict
+    vma checking from ``jax.default_backend()``, which still says cpu here,
+    so the test steers it (the guide's rule: steer in the test, not through
+    an option of the program)."""
+    from deepspeed_tpu.parallel.topology import build_topology
+    from deepspeed_tpu.utils import groups
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    topology = build_topology(tp=2, devices=list(topo.devices))
+    groups.initialize(topology)
+    yield topology.mesh
+    groups.reset()
+
+
+def _mesh_flash_loss(q, k, v):
+    from deepspeed_tpu.models.base import sp_attention
+
+    return jnp.sum(sp_attention("flash", q, k, v).astype(jnp.float32))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
+def test_flash_partitions_under_mesh(mesh_2x2, grad):
+    """Bare, the kernel fails here with "Mosaic kernels cannot be
+    automatically partitioned"; ``sp_attention`` wraps it in shard_map."""
+    from deepspeed_tpu.parallel.topology import BATCH_AXES, MODEL_AXIS
+
+    sharding = NamedSharding(mesh_2x2, P(BATCH_AXES, None, MODEL_AXIS, None))
+    q = _sds(sharding, (8, 1024, 12, 64))
+    fn = jax.grad(_mesh_flash_loss, argnums=(0, 1, 2)) if grad \
+        else _mesh_flash_loss
+    _compiled_text(fn, q, q, q)
+
+
+def test_in_program_kv_cache_is_zero_filled(one_chip):
+    """generate()'s prefill allocates its KV cache inside the program and
+    fills only the prompt's rows. On the v5e the compiler had replaced the
+    zeros by an uninitialised ``AllocateBuffer``; the masked tail then held
+    NaN bit patterns and every logit came out NaN (PR 23, found on the
+    chip). 125M widths, two layers, batch 1, 24 of 128 rows written."""
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
+
+    model = GPT2Model(GPT2Config(num_layers=2))
+
+    def prefill(params, ids):
+        cache = model.init_cache(1, 128, dtype=BF16)
+        logits, cache = model.forward_with_cache(params, ids, cache)
+        return logits[:, -1], cache["k"], cache["v"]
+
+    params = jax.tree_util.tree_map(
+        lambda s: _sds(one_chip, s.shape),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    text = jax.jit(prefill).lower(
+        params, _sds(one_chip, (1, 24), jnp.int32)).compile().as_text()
+    uninitialised = [ln for ln in text.splitlines()
+                     if "AllocateBuffer" in ln and "bf16[2,1,12,128,64]" in ln]
+    assert not uninitialised, uninitialised[0][:200]

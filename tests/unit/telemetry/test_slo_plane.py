@@ -593,13 +593,11 @@ def test_report_degrade_paths(tmp_path):
 
 
 # --------------------------------------------- bench trajectory satellite
-def test_bench_trajectory_markdown(capsys):
-    mod = _load_script("bench_trajectory")
-    root = os.path.join(os.path.dirname(__file__), "..", "..", "..")
-    import glob
+def test_bench_trajectory_markdown(capsys, tmp_path):
+    from tests.unit.telemetry.round_files import write_round_files
 
-    paths = sorted(glob.glob(os.path.join(root, "BENCH_r*.json")))
-    assert paths, "checked-in round files are gone"
+    mod = _load_script("bench_trajectory")
+    paths = write_round_files(tmp_path)
     rounds = mod.load_rounds(paths)
     t = mod.trend(rounds)
     md = mod.render_markdown(t, rounds)

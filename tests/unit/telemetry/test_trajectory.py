@@ -1,14 +1,15 @@
 """scripts/bench_trajectory.py tests (ISSUE 11 satellite) — run against
-the CHECKED-IN per-round bench files (BENCH_r01..r05.json), which is
-exactly the data the script exists to read, plus synthetic series for
+five per-round bench files written into tmp_path (round_files.py: the
+shape of the data the script exists to read), plus synthetic series for
 the flagging logic."""
 
 import importlib.util
-import glob
 import json
 import os
 
 import pytest
+
+from tests.unit.telemetry.round_files import write_round_files
 
 pytestmark = [pytest.mark.tracing, pytest.mark.observability,
               pytest.mark.quick]
@@ -26,10 +27,9 @@ def _mod():
     return m
 
 
-def _round_files():
-    files = sorted(glob.glob(os.path.join(ROOT, "BENCH_r*.json")))
-    assert len(files) >= 5, "checked-in round files went missing"
-    return files
+@pytest.fixture
+def round_files(tmp_path):
+    return write_round_files(tmp_path)
 
 
 def test_flatten_numeric_leaves_only():
@@ -39,15 +39,15 @@ def test_flatten_numeric_leaves_only():
     assert flat == {"a.b": 1.0, "e": 2.5, "f.g.h": 3.0}
 
 
-def test_checked_in_rounds_collate():
+def test_checked_in_rounds_collate(round_files):
     m = _mod()
-    rounds = m.load_rounds(_round_files())
+    rounds = m.load_rounds(round_files)
     labels = [lbl for lbl, _ in rounds]
     assert labels == ["r01", "r02", "r03", "r04", "r05"]
     t = m.trend(rounds)
     # the headline metric has a full 5-point series
     assert list(t["value"]["series"]) == labels
-    assert t["value"]["series"]["r05"] == pytest.approx(93717.0)
+    assert t["value"]["series"]["r05"] == pytest.approx(1250.0)
     # the 774M MFU line appeared in r05 only
     assert t["train_774m.mfu_vs_attainable"]["flag"] == "new"
     # serving bf16 decode series spans r02..r05 and r05 improved
@@ -74,7 +74,7 @@ def test_direction_heuristic_and_threshold():
     assert t["tput"]["flag"] == "stable"
 
 
-def test_gone_and_full_append(tmp_path):
+def test_gone_and_full_append(tmp_path, round_files):
     m = _mod()
     rounds = [("r01", {"a": 1.0, "b": 2.0}), ("r02", {"a": 1.0})]
     t = m.trend(rounds)
@@ -82,23 +82,23 @@ def test_gone_and_full_append(tmp_path):
     # --full appends a fresh bench JSON as the newest point
     full = tmp_path / "full.json"
     full.write_text(json.dumps({"value": 100.0, "nested": {"x": 1}}))
-    loaded = m.load_rounds(_round_files(), full=str(full))
+    loaded = m.load_rounds(round_files, full=str(full))
     assert loaded[-1][0] == "full"
     assert loaded[-1][1]["value"] == 100.0
 
 
-def test_cli_json_output(capsys):
+def test_cli_json_output(capsys, round_files):
     m = _mod()
-    rc = m.main(["--json"] + _round_files())
+    rc = m.main(["--json"] + round_files)
     assert rc == 0
     out = json.loads(capsys.readouterr().out)
     assert out["rounds"] == ["r01", "r02", "r03", "r04", "r05"]
     assert "value" in out["metrics"]
 
 
-def test_cli_table_output(capsys):
+def test_cli_table_output(capsys, round_files):
     m = _mod()
-    rc = m.main(_round_files() + ["--flagged"])
+    rc = m.main(round_files + ["--flagged"])
     assert rc == 0
     out = capsys.readouterr().out
     assert "bench trajectory" in out and "5 rounds" in out
