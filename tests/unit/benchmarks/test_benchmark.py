@@ -1,0 +1,536 @@
+"""The benchmark's own tests: arithmetic, traffic, the trace reducer, the
+by-name resolution of every cell, and two tiny in-process rehearsals.
+
+One module on purpose (tests/conftest.py runs every module in a child
+process). It starts no subprocess, describes no TPU topology
+(tests/unit/ops/test_tpu_compile.py stays the only file that does) and
+imports nothing at module level that loads libtpu.
+"""
+import importlib
+import json
+import math
+import os
+import re
+import time
+
+import numpy as np
+import pytest
+
+from benchmarks import flops, harness, stats, trace_reduce, traffic_gen
+
+BENCH = harness.benchmark_json()
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+CELLS = [w["name"] for w in BENCH["workloads"]]
+LARGE = {"layers": 36, "hidden": 1280, "heads": 20, "head_dim": 64,
+         "mlp": 5120, "vocab": 50257, "positions": 1024}
+XL = {"layers": 48, "hidden": 1600, "heads": 25, "head_dim": 64,
+      "mlp": 6400, "vocab": 50257, "positions": 1024}
+
+
+# ------------------------------------------------------------- arithmetic
+@pytest.mark.parametrize("values,q,want", [
+    ([1.0, 2.0, 3.0, 4.0, 5.0], 50, 3.0),
+    ([1.0, 2.0, 3.0, 4.0], 50, 2.5),
+    ([10.0, 20.0], 95, 19.5),
+    ([7.0], 95, 7.0),
+    (list(range(101)), 95, 95.0),
+    ([5.0, 1.0, 3.0], 0, 1.0),
+    ([5.0, 1.0, 3.0], 100, 5.0),
+])
+def test_percentile_by_hand(values, q, want):
+    assert stats.percentile(values, q) == pytest.approx(want)
+    assert stats.percentile(values, q) == pytest.approx(
+        float(np.percentile(values, q)))
+
+
+@pytest.mark.parametrize("bad", [([], 50), ([1.0], 101), ([1.0], -1)])
+def test_percentile_refuses(bad):
+    with pytest.raises(ValueError):
+        stats.percentile(*bad)
+
+
+def test_window_arithmetic_on_hand_made_timestamps():
+    # three requests: token times in seconds on the engine's clock
+    times = [[0.10, 0.20, 0.35], [0.90, 1.00, 1.05, 1.30], [0.50]]
+    assert stats.inter_token_gaps(times) == pytest.approx(
+        [0.10, 0.15, 0.10, 0.05, 0.25])
+    # tokens committed inside [0, 1.0): 3 + 1 + 1; the stamp at 1.00 is out
+    assert stats.count_in_window(times, 0.0, 1.0) == 5
+    assert stats.count_in_window(times, 0.0, 2.0) == 8
+
+
+def test_an_unfinished_request_stays_in_the_tail():
+    # 20 requests due a second apart; the system returned a first token 0.1 s
+    # after arrival for all but the last, and the loop gave up at 30 s
+    due = {rid: float(rid) for rid in range(20)}
+    first = {rid: rid + 0.1 for rid in range(19)}
+    ttft = stats.times_to_first_token(due, first, gave_up=30.0)
+    assert len(ttft) == 20 and max(ttft) == pytest.approx(11.0)
+    assert stats.percentile(ttft, 95.0) > 0.1 + 0.5  # the tail sees it
+    assert stats.times_to_first_token(due, {**first, 19: 19.1},
+                                      gave_up=30.0) == pytest.approx([0.1] * 20)
+
+
+# ---------------------------------------------------------------- traffic
+CHAT = {"rate": 20.0, "shape_seed": 7, "max_total": 1024,
+        "prompt": {"dist": "lognormal", "median": 192, "sigma": 0.7,
+                   "min": 16, "max": 768},
+        "output": {"dist": "lognormal", "median": 96, "sigma": 0.6,
+                   "min": 8, "max": 256}}
+SESSIONS = {"rate": 10.0, "shape_seed": 8, "max_total": 1024,
+            "shared_prefix": {"count": 4, "len": 384},
+            "prompt": {"dist": "uniform", "min": 32, "max": 256},
+            "output": {"dist": "uniform", "min": 16, "max": 128}}
+
+
+def _gen(params, seed, seconds=10.0):
+    return traffic_gen.open_loop_requests(params, seed=seed, seconds=seconds,
+                                          vocab_size=50257)
+
+
+@pytest.mark.parametrize("params", [CHAT, SESSIONS], ids=["chat", "sessions"])
+def test_traffic_same_seed_same_requests_other_seed_same_schedule(params):
+    big = 2**31 + 12345            # the driver's seeds pass 32 signed bits
+    a, b, c = _gen(params, big), _gen(params, big), _gen(params, 5)
+    assert a == b
+    assert [r.prompt for r in a] != [r.prompt for r in c]
+    # every seed gets the same schedule (arrivals and lengths come from the
+    # mix's shape_seed) and other tokens; another shape_seed moves it
+    assert len(a) == len(c) == round(params["rate"] * 10.0)
+    schedule = lambda rs: [(r.arrival_time, len(r.prompt), r.max_new_tokens)
+                           for r in rs]
+    assert schedule(a) == schedule(c)
+    assert schedule(a) != schedule(_gen(dict(params, shape_seed=9), big))
+
+
+@pytest.mark.parametrize("params", [CHAT, SESSIONS], ids=["chat", "sessions"])
+def test_traffic_honours_clips_and_window(params):
+    reqs = _gen(params, 3, seconds=30.0)
+    shared = params.get("shared_prefix", {"len": 0})["len"]
+    for r in reqs:
+        body = len(r.prompt) - shared
+        assert params["prompt"]["min"] <= body <= params["prompt"]["max"]
+        assert 1 <= r.max_new_tokens <= params["output"]["max"]
+        assert len(r.prompt) + r.max_new_tokens <= params["max_total"]
+        assert 0.0 < r.arrival_time < 30.0
+        assert all(0 <= t < 50257 for t in r.prompt[:8])
+    times = [r.arrival_time for r in reqs]
+    assert times == sorted(times)
+    if shared:
+        heads = {tuple(r.prompt[:shared]) for r in reqs}
+        assert len(heads) == params["shared_prefix"]["count"]
+
+
+def test_traffic_bursts_land_together_and_lengths_follow_the_spec():
+    reqs = _gen(dict(CHAT, burst_size=16), 1, seconds=8.0)
+    assert len({r.arrival_time for r in reqs}) == math.ceil(len(reqs) / 16)
+    rng = np.random.RandomState(0)
+    x = traffic_gen.draw_lengths(rng, CHAT["prompt"], 4000)
+    assert 170 < np.median(x) < 215 and x.min() >= 16 and x.max() <= 768
+    assert set(traffic_gen.draw_lengths(
+        rng, {"dist": "choice", "values": [3, 9]}, 50)) == {3, 9}
+    assert set(traffic_gen.draw_lengths(
+        rng, {"dist": "fixed", "value": 384}, 5)) == {384}
+    with pytest.raises(ValueError):
+        traffic_gen.draw_lengths(rng, {"dist": "zipf"}, 1)
+    rows = traffic_gen.arith_rows(rng, 512, (2, 3, 16))
+    assert rows["input_ids"].shape == rows["labels"].shape == (2, 3, 16)
+    assert (rows["input_ids"][..., 1:] == rows["labels"][..., :-1]).all()
+    assert 0 <= traffic_gen.fold_seed(2**31 + 9) < 2**31
+
+
+# ------------------------------------------------------------------ flops
+@pytest.mark.parametrize("shapes,params,per_token", [
+    # 36 x (4 x 1280^2 + 2 x 1280 x 5120) = 707,788,800; head 64,328,960;
+    # biases and norms 36 x 16,640 = 599,040; wpe 1,310,720; ln_f 2,560
+    (LARGE, 774_030_080,
+     6 * 774_030_080 + 12 * 36 * 1280 * 1024),
+    # 48 x (4 x 1600^2 + 2 x 1600 x 6400) = 1,474,560,000; head 80,411,200;
+    # 48 x 20,800 = 998,400; wpe 1,638,400; ln_f 3,200
+    (XL, 1_557_611_200,
+     6 * 1_557_611_200 + 12 * 48 * 1600 * 1024),
+], ids=["gpt2-large", "gpt2-xl"])
+def test_flops_against_hand_worked_numbers(shapes, params, per_token):
+    assert flops.total_params(shapes) == params
+    assert flops.train_flops_per_token(shapes, 1024) == per_token
+    # flash attention, 2 rows of 1024 through every layer, causal: one
+    # matmul is 2 x rows x heads x T x T x Dh / 2; six of them fwd + bwd
+    one = 2 * 2 * shapes["heads"] * 1024 * 1024 * 64 / 2
+    f, b = flops.flash_train_work(shapes, rows=2, seq_len=1024)
+    assert f == shapes["layers"] * 6 * one
+    assert b == shapes["layers"] * 12 * (2 * 1024 * shapes["heads"] * 64 * 2)
+    # decode: two slots at 100 and 300 cached tokens read 400 rows of K and V
+    f, b = flops.decode_attn_work(shapes, context_lens=[100, 300])
+    assert b == shapes["layers"] * 400 * shapes["heads"] * 64 * 2 * 2
+    assert f == shapes["layers"] * 400 * shapes["heads"] * 4 * 64
+
+
+def test_roofline_and_peaks_table():
+    peaks = harness.load_json("peaks.json")
+    v5e = peaks["devices"]["TPU v5 lite"]
+    assert (v5e["bf16_tflops"], v5e["hbm_gbps"]) == (197.0, 819.0)
+    assert peaks["source"]
+    assert flops.roofline_seconds(197e12, 1.0, v5e) == (1.0, "compute")
+    t, bound = flops.roofline_seconds(1.0, 819e9 * 2, v5e)
+    assert bound == "memory" and t == pytest.approx(2.0)
+    # at 1024 tokens causal flash sits near the ridge: compute bound forward
+    f, b = flops.flash_train_work(LARGE, rows=2, seq_len=1024)
+    assert flops.roofline_seconds(f, b, v5e)[1] == "compute"
+
+
+# ---------------------------------------------------------- trace reducer
+def _synthetic():
+    """Two devices over a 10 s window (seconds on the profile's clock).
+    Device 0: a ``while`` parent 1..6 around fusion 1..3, all-gather 3..4
+    (alone: exposed) and fusion 4..6; then an all-reduce 7..9 with a fusion
+    8..9 nested in it, so only 7..8 of it is its own and exposed."""
+    d0 = [("while.1", 1.0, 6.0), ("fusion.1", 1.0, 3.0),
+          ("all-gather.2", 3.0, 4.0), ("fusion.2", 4.0, 6.0),
+          ("all-reduce.3", 7.0, 9.0), ("fusion.3", 8.0, 9.0)]
+    d1 = [("fusion.1", 0.0, 5.0)]
+    host = [("bench/window", 0.0, 10.0), ("dstpu/serving_admit", 6.1, 6.9),
+            ("dstpu/serving_decode", 9.0, 9.4)]
+    return trace_reduce.Trace({0: d0, 1: d1}, host, (0.0, 10.0))
+
+
+def test_interval_arithmetic():
+    assert trace_reduce.union([(3, 4), (1, 2), (1.5, 3.5), (6, 6)]) == [(1, 4)]
+    assert trace_reduce.clip([(0, 2), (5, 9), (11, 12)], (1, 8)) == [
+        (1, 2), (5, 8)]
+    assert trace_reduce.subtract([(0, 10)], [(1, 2), (4, 6)]) == [
+        (0, 1), (2, 4), (6, 10)]
+    assert trace_reduce.subtract([(0, 3), (5, 8)], [(2, 6)]) == [
+        (0, 2), (6, 8)]
+    assert trace_reduce.subtract([(0, 1)], []) == [(0, 1)]
+
+
+def test_reducer_on_a_synthetic_event_list():
+    tr = _synthetic()
+    # busy: device 0 is 1..6 and 7..9 = 7 s, device 1 is 5 s; mean 6 s
+    assert trace_reduce.busy_seconds(tr) == pytest.approx(6.0)
+    assert trace_reduce.idle_share(tr) == pytest.approx(0.4)
+    # self times: the while keeps nothing of its own
+    by = trace_reduce.time_by_name(tr)
+    assert by["while.1"] == pytest.approx(0.0)
+    assert by["fusion.1"] == pytest.approx((2.0 + 5.0) / 2)
+    assert by["all-gather.2"] == pytest.approx(0.5)
+    assert trace_reduce.matched_seconds(tr, "all-") == pytest.approx(
+        (1.0 + 1.0) / 2)
+    # exposed: all-gather 3..4 and all-reduce 7..8 on device 0, none on 1
+    assert trace_reduce.exposed_collective_seconds(tr) == pytest.approx(1.0)
+    # gaps of device 0, longest first, named by the host annotation open then
+    gaps = trace_reduce.idle_gaps(tr)
+    assert sorted((n, round(s, 6)) for n, s in gaps) == [
+        ("dstpu/serving_admit", 1.0), ("dstpu/serving_decode", 1.0),
+        ("none", 1.0)]
+    bd = trace_reduce.breakdown(tr)
+    assert len(bd["device_ops"]) <= 10 and len(bd["idle_gaps"]) <= 5
+    assert bd["device_ops"][0][0] == "fusion.1"
+    # a window that cuts events counts only what is inside
+    cut = trace_reduce.Trace(tr.device_ops, tr.host, (2.0, 5.0))
+    assert trace_reduce.busy_seconds(cut) == pytest.approx(3.0)
+
+
+def test_readers_on_the_synthetic_trace():
+    tr = _synthetic()
+    obs = {"trace": tr, "peak": {"bf16_tflops": 197.0, "hbm_gbps": 819.0},
+           "shapes": LARGE, "counters": {"compiles_in_window": 0,
+                                         "slot_iterations_active": 48,
+                                         "decode_steps": 3, "num_slots": 32},
+           "train": {"rows_per_device_step": 16, "seq_len": 1024,
+                     "traced_steps": 1},
+           "requests": [{"arrival": 0.0, "admitted": 0.010,
+                         "first_token": 0.030, "prompt_len": 10,
+                         "token_times": [0.03, 0.04]},
+                        {"arrival": 1.0, "admitted": 1.030,
+                         "first_token": 1.040, "prompt_len": 20,
+                         "token_times": [1.04]}],
+           "spans": [{"name": "decode_step", "start": 0.0, "end": 0.004},
+                     {"name": "decode_step", "start": 1.0, "end": 1.006},
+                     {"name": "queue_wait", "start": 0.0, "end": 0.5}]}
+
+    def read(metric):
+        spec = harness.load_json("layer_metrics", metric + ".json")
+        return harness.module("readers", spec["reader"]).read(
+            spec["params"], obs)
+
+    assert read("entry.compiles_in_window.serve") == 0
+    assert read("sched.batch_fill") == pytest.approx(50.0)
+    assert read("sched.queue_wait_p95_ms") == pytest.approx(29.0)
+    assert read("step.prefill_ms") == pytest.approx(15.0)
+    assert read("step.decode_ms") == pytest.approx(5.0)
+    assert read("device.idle_share.train") == pytest.approx(40.0)
+    assert read("partition.exposed_collective_share") == pytest.approx(10.0)
+    # nothing in this trace is a flash kernel: the reader returns nothing
+    assert read("kernel.flash_roofline") is None
+    # and with nothing to read, every reader returns nothing
+    for metric in os.listdir(os.path.join(harness.BENCH_DIR, "layer_metrics")):
+        spec = harness.load_json("layer_metrics", metric)
+        assert harness.module("readers", spec["reader"]).read(
+            spec["params"], {}) is None, metric
+
+
+def test_roofline_reader_counts_the_work_of_the_traced_window():
+    f, b = flops.flash_train_work(LARGE, rows=16, seq_len=1024)
+    least = f / 197e12
+    tr = trace_reduce.Trace(
+        {0: [("_flash_fwd_kernel", 0.0, 2 * least)]},
+        [("bench/window", 0.0, 1.0)], (0.0, max(1.0, 2 * least)))
+    obs = {"trace": tr, "peak": {"bf16_tflops": 197.0, "hbm_gbps": 819.0},
+           "shapes": LARGE, "train": {"rows_per_device_step": 16,
+                                      "seq_len": 1024, "traced_steps": 1}}
+    reader = harness.module("readers", "trace_kernel_roofline")
+    share = reader.read({"pattern": "flash", "work": "flash_train"}, obs)
+    assert share == pytest.approx(50.0)
+
+
+def test_reducer_on_a_real_profile(tmp_path):
+    """A real ``.xplane.pb`` from this backend: host annotations are found
+    and the window is the annotation the harness opens. (The trimmed TPU
+    trace of PERF.md's first runs is over 200 KB and is not committed.)"""
+    import jax
+    import jax.numpy as jnp
+
+    with harness.profile_if(True) as prof:
+        prof.start()
+        prof.open_window()
+        with jax.profiler.TraceAnnotation("dstpu/train_step"):
+            jnp.ones((64, 64)).sum().block_until_ready()
+        time.sleep(0.01)
+        prof.close_window()
+        tr = prof.reduce()
+    assert not os.path.exists(prof.dir)      # raw files are deleted
+    assert tr.window_s >= 0.01
+    assert any(n == "dstpu/train_step" for n, _, _ in tr.host)
+    assert tr.device_ops == {} and trace_reduce.busy_seconds(tr) == 0.0
+
+
+# ------------------------------------------------ names, files, resolution
+def test_every_name_and_unit_holds_only_the_allowed_characters():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    names = [m["name"] for g in ("end_to_end", "per_layer") for m in BENCH[g]]
+    assert len(names) == len(set(names))
+    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
+        assert m["better"] in ("lower", "higher")
+        assert m["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
+    for m in BENCH["end_to_end"]:
+        assert set(m) <= {"name", "unit", "better", "bound", "source",
+                          "workloads"}
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.1
+    for w in BENCH["workloads"]:
+        assert set(w) == {"name", "config", "traffic", "chips", "why"}
+        assert NAME.match(w["name"]) and NAME.match(w["traffic"])
+        assert w["chips"] in (1, 4) and 0 < len(w["why"]) <= 200
+    for c in BENCH["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert NAME.match(c["name"]) and len(c["why"]) <= 200
+        assert c["file"].startswith(tuple(p + "/" for p in BENCH["paths"]))
+    assert any(m["name"] == "setup_s" for m in BENCH["end_to_end"])
+    assert 1 <= BENCH["run_seconds"] <= 51
+    four = sum(w["chips"] == 4 for w in BENCH["workloads"])
+    assert four <= max(1, len(BENCH["workloads"]) // 4)
+    assert len(json.dumps(BENCH)) < 64 * 1024
+
+
+@pytest.mark.parametrize("cell_name", CELLS)
+def test_every_cell_resolves_its_files_by_name(cell_name):
+    cell = harness.load_cell(cell_name, BENCH)
+    cfg, traffic = cell["config_file"], cell["traffic_file"]
+    entry = next(c for c in BENCH["configs"] if c["name"] == cell["config"])
+    assert cfg["source"] == entry["source"]
+    assert sorted(cfg["reduced"]) == sorted(entry["reduced"])
+    assert hasattr(harness.module("kinds", traffic["kind"]), "run")
+    family = harness.module("families", cfg["family"])
+    assert hasattr(harness.module("reference", cfg["family"]),
+                   "forward_logits")
+    shapes = family.shapes(cfg)
+    assert shapes["head_dim"] * shapes["heads"] == shapes["hidden"]
+    e2e = {m["name"] for m in harness.metrics_of(cell_name, "end_to_end",
+                                                 BENCH)}
+    assert "setup_s" in e2e and len(e2e) >= 2
+    layer = harness.metrics_of(cell_name, "per_layer", BENCH)
+    assert layer
+    for m in layer:
+        spec = harness.load_json("layer_metrics", m["name"] + ".json")
+        assert callable(harness.module("readers", spec["reader"]).read)
+        for key in ("layer", "unit", "moves", "better", "source"):
+            assert spec[key] == m[key], (m["name"], key)
+        # the metric it should move is reported wherever it is
+        assert m["moves"] in e2e, (m["name"], cell_name)
+    if traffic["kind"] == "train_job":
+        job = traffic["job"]
+        assert job["mesh"]["dp"] * job["mesh"]["tp"] == cell["chips"]
+        assert (job["micro_batch"] * job["gas"] * job["mesh"]["dp"]
+                == job["rows_per_step"])
+    else:
+        a, s = traffic["arrivals"], traffic["server"]
+        assert a["max_total"] <= s["max_len"] <= cfg["n_positions"]
+        longest = a["prompt"]["max"] + a.get("shared_prefix", {}).get("len", 0)
+        assert longest <= max(s["buckets"])
+
+
+@pytest.mark.parametrize("name,want", [
+    ("gpt2-large", dict(n_layer=36, n_embd=1280, n_head=20)),
+    ("gpt2-xl", dict(n_layer=48, n_embd=1600, n_head=25)),
+])
+def test_configuration_files_hold_the_published_widths(name, want):
+    cfg = harness.load_json("configs", name + ".json")
+    for k, v in dict(want, vocab_size=50257, n_positions=1024,
+                     n_inner=None, layer_norm_epsilon=1e-5).items():
+        assert cfg[k] == v, (name, k)
+    assert "huggingface.co/openai-community/" + name in cfg["source"]
+    widths = re.compile(r"(_dim|_rank)$|^(n_embd|n_inner|n_head)$|hidden")
+    assert not [k for k in cfg["reduced"] if widths.search(k)]
+
+
+def test_a_new_cell_needs_only_new_files_and_entries(tmp_path, monkeypatch):
+    """A later PR adds a configuration, a traffic mix, a kind, a per-layer
+    metric with its reader and a cell, and edits no file that is there."""
+    bench_dir = tmp_path / "benchmarks"
+    for sub in ("configs", "traffic", "layer_metrics"):
+        (bench_dir / sub).mkdir(parents=True)
+    (bench_dir / "configs" / "toy.json").write_text(json.dumps(
+        {"family": "gpt2", "source": "paper", "reduced": []}))
+    (bench_dir / "traffic" / "toy-mix.json").write_text(json.dumps(
+        {"kind": "toy_kind"}))
+    (bench_dir / "layer_metrics" / "toy.metric.json").write_text(json.dumps(
+        {"reader": "toy_reader", "params": {"k": 3}}))
+    bench = {"configs": [{"name": "toy", "file": "benchmarks/configs/toy.json"}],
+             "workloads": [{"name": "toy.toy-mix", "config": "toy",
+                            "traffic": "toy-mix", "chips": 1, "why": "x"}],
+             "end_to_end": [{"name": "setup_s"}],
+             "per_layer": [{"name": "toy.metric", "unit": "count",
+                            "workloads": ["toy.toy-mix"]},
+                           {"name": "other", "workloads": ["elsewhere"]}]}
+    monkeypatch.setattr(harness, "ROOT", str(tmp_path))
+    monkeypatch.setattr(harness, "BENCH_DIR", str(bench_dir))
+    import sys
+    import types
+    kind = types.ModuleType("benchmarks.kinds.toy_kind")
+    kind.run = lambda cell, **kw: {"seen": cell["traffic_file"]["kind"]}
+    reader = types.ModuleType("benchmarks.readers.toy_reader")
+    reader.read = lambda params, obs: params["k"] * obs["n"]
+    monkeypatch.setitem(sys.modules, kind.__name__, kind)
+    monkeypatch.setitem(sys.modules, reader.__name__, reader)
+    cell = harness.load_cell("toy.toy-mix", bench)
+    runner = harness.module("kinds", cell["traffic_file"]["kind"])
+    assert runner.run(cell)["seen"] == "toy_kind"
+    from benchmarks import run as bench_run
+    got = bench_run.per_layer_metrics("toy.toy-mix", bench, {"n": 2})
+    assert got == {"toy.metric": {"value": 6, "unit": "count"}}
+    with pytest.raises(KeyError):
+        harness.load_cell("no.such-cell", bench)
+
+
+# -------------------------------------------------- reference and guard
+def test_reference_agrees_with_the_program_at_tiny_size():
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
+
+    reference = importlib.import_module("benchmarks.reference.gpt2")
+    cfg = GPT2Config.tiny()
+    model = GPT2Model(cfg, compute_dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(0))
+    # the initialiser zeroes every bias: give them values, or a reference
+    # that dropped one would pass
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+    params = jax.tree_util.tree_unflatten(tree, [
+        x + 0.05 * jax.random.normal(k, x.shape) for x, k in zip(leaves, keys)])
+    ids = np.random.RandomState(0).randint(0, cfg.vocab_size, (2, 48))
+    with jax.default_matmul_precision("highest"):
+        want = model.logits(params, model.forward_hidden(params, ids))
+        batch = {"input_ids": ids[:, :-1], "labels": ids[:, 1:]}
+        want_loss, _ = model.apply(params, batch)
+    got = reference.forward_logits(params, jnp.asarray(ids),
+                                   n_head=cfg.num_heads, eps=cfg.eps)
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+    got_loss = reference.loss(params, jnp.asarray(batch["input_ids"]),
+                              jnp.asarray(batch["labels"]),
+                              n_head=cfg.num_heads, eps=cfg.eps)
+    assert float(got_loss) == pytest.approx(float(want_loss), abs=1e-4)
+    # the family builds the same model from the file's keys
+    family = importlib.import_module("benchmarks.families.gpt2")
+    built = family.build_model(
+        family.tiny(harness.load_json("configs", "gpt2-large.json")), {})
+    assert built.config == cfg
+
+
+def test_a_run_without_a_tpu_raises_and_prints_no_result(capsys, monkeypatch):
+    from benchmarks import run as bench_run
+
+    # main() would point this process's compile cache at benchmarks/.cache,
+    # and later test modules run in the same worker
+    monkeypatch.setattr(harness, "setup_compile_cache", lambda: "unused")
+    with pytest.raises(harness.NoAcceleratorError, match="no TPU"):
+        harness.device_guard(1)
+    for cell in CELLS:
+        with pytest.raises(harness.NoAcceleratorError):
+            bench_run.main(["--workload", cell, "--seed", "1",
+                            "--seconds", "1", "--trace", "0"])
+    assert "correct" not in capsys.readouterr().out
+    with pytest.raises(KeyError):
+        bench_run.main(["--workload", "no.such-cell", "--seed", "1",
+                        "--seconds", "1", "--trace", "0"])
+
+
+# ------------------------------------------------------------ rehearsals
+def _one_of_kind(kind):
+    for name in CELLS:
+        cell = harness.load_cell(name, BENCH)
+        if cell["traffic_file"]["kind"] == kind:
+            return cell
+    pytest.skip(f"no cell of kind {kind} in BENCHMARK.json")
+
+
+@pytest.mark.parametrize("kind", ["train_job", "serve_open_loop"])
+def test_rehearsal_in_process_at_tiny_size(kind):
+    """The kind's runner end to end at GPT2Config.tiny for under a second of
+    window, traced, through ``rehearse=True`` (tests only: it skips the
+    device guard, and what it returns is no result)."""
+    from benchmarks import run as bench_run
+
+    cell = _one_of_kind(kind)
+    out = harness.module("kinds", kind).run(
+        cell, seed=2**31 + 7, seconds=0.6, trace=True,
+        clock0=time.perf_counter(), rehearse=True)
+    assert out["device"]["platform"] == "cpu"      # never a chip result
+    assert out["correct"] and out["failed"] == 0 and out["attempted"] > 0
+    e2e = {m["name"] for m in harness.metrics_of(cell["name"], "end_to_end",
+                                                 BENCH)}
+    assert e2e <= set(out["end_to_end"])
+    assert all(v > 0 for v in out["end_to_end"].values())
+    assert out["observations"]["counters"]["compiles_in_window"] == 0
+    line = bench_run.result_line(cell, BENCH, out, trace=True)
+    assert set(line) >= {"correct", "attempted", "failed", "metrics", "device"}
+    assert line["device"]["window_s"] > 0
+    # no device plane on this backend: the trace readers leave their
+    # metrics out of the line and the program's own counters remain
+    assert not [m for m in line["metrics"] if m.startswith(("kernel.",
+                                                            "device."))]
+    assert any(m.startswith("entry.") for m in line["metrics"])
+    line0 = bench_run.result_line(cell, BENCH, out, trace=False)
+    assert set(line0["metrics"]) == e2e
+    json.dumps(line), json.dumps(line0)
+
+
+def test_a_serve_run_that_leaves_requests_unfinished_is_not_correct(
+        monkeypatch):
+    """The loop gives up before the window ends, so some requests never
+    finish: they are failed, they stay in the tail, and the run is not
+    ``correct`` whatever the logits of the finished ones say."""
+    kind = harness.module("kinds", "serve_open_loop")
+    monkeypatch.setattr(kind, "DRAIN_LIMIT_S", -0.3)
+    out = kind.run(_one_of_kind("serve_open_loop"), seed=3, seconds=0.6,
+                   trace=False, clock0=time.perf_counter(), rehearse=True)
+    assert 0 < out["failed"] < out["attempted"]
+    assert out["notes"]["worst_logit_gap"] <= kind.GREEDY_LOGIT_TOL
+    assert not out["correct"]
