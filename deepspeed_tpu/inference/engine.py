@@ -620,7 +620,9 @@ class InferenceEngine:
             def prefill(params, k_slots, v_slots, lengths, ids, slot,
                         length, temp, rng):
                 cache = model.init_cache(1, bucket_len, dtype=self.dtype)
-                logits, cache = model.forward_with_cache(params, ids, cache)
+                with jax.named_scope("dstpu_prefill"):
+                    logits, cache = model.forward_with_cache(params, ids,
+                                                             cache)
                 k_slots, v_slots = write_slot_prefix(
                     k_slots, v_slots, cache["k"], cache["v"], slot)
                 lengths = jax.lax.dynamic_update_index_in_dim(
@@ -658,8 +660,9 @@ class InferenceEngine:
             def decode(params, k_slots, v_slots, lengths, tokens, active,
                        temp, rng):
                 cache = {"k": k_slots, "v": v_slots, "index": lengths}
-                logits, cache = model.forward_with_cache(
-                    params, tokens[:, None], cache)
+                with jax.named_scope("dstpu_decode"):
+                    logits, cache = model.forward_with_cache(
+                        params, tokens[:, None], cache)
                 nxt = jnp.where(active, pick(logits[:, -1], temp, rng),
                                 pad_token_id)
                 lengths = jnp.where(active, lengths + 1, lengths)

@@ -866,6 +866,7 @@ def fused_block_decode_step(q: jax.Array, k_pool, v_pool,
     ]
     out = pl.pallas_call(
         kernel,
+        name="dstpu_block_decode_step",
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
@@ -943,6 +944,7 @@ def fused_decode_step(q: jax.Array, k_full: jax.Array, v_full: jax.Array,
         scale=sc, per_slot=per_slot, mha=mha)
     attn, k_out, v_out = pl.pallas_call(
         kernel,
+        name="dstpu_decode_step",
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),   # layer
             pl.BlockSpec(memory_space=pltpu.SMEM),   # idx

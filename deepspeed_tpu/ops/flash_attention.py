@@ -266,6 +266,7 @@ def flash_fwd_parts(qf, kf, vf, *, causal, scale=None,
     shp = functools.partial(_sds, qf, kf, vf)
     return pl.pallas_call(
         kernel,
+        name="dstpu_flash_fwd",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((None, bq, dh), lambda bh_, qi, kj: (bh_, qi, 0)),
@@ -332,6 +333,7 @@ def flash_bwd_parts(qf, kf, vf, dof, lse, delta, *, causal, scale=None,
                                   block_q=bq, block_k=bk, nk=nk)
     dq = pl.pallas_call(
         dq_kernel,
+        name="dstpu_flash_bwd_dq",
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((None, bq, dh), lambda b_, qi, kj: (b_, qi, 0)),
@@ -352,6 +354,7 @@ def flash_bwd_parts(qf, kf, vf, dof, lse, delta, *, causal, scale=None,
                                    block_q=bq, block_k=bk, nq=nq)
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="dstpu_flash_bwd_dkv",
         grid=(bh, nk, nq),
         in_specs=[
             pl.BlockSpec((None, bq, dh), lambda b_, kj, qi: (b_, qi, 0)),

@@ -184,6 +184,7 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         )
         out = pl.pallas_call(
             kernel,
+            name="dstpu_flash_decode_mha",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((b, hq, dh), q.dtype),
             interpret=interpret,
@@ -221,6 +222,7 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     )
     out = pl.pallas_call(
         kernel,
+        name="dstpu_flash_decode_gqa",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b * hkv, rep, dh), q.dtype),
         interpret=interpret,

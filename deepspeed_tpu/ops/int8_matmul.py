@@ -236,6 +236,7 @@ def int8_matmul_dma(x: jax.Array, q: jax.Array, s: jax.Array,
                                out_dtype=x.dtype, stacked=stacked)
     return pl.pallas_call(
         kernel,
+        name="dstpu_int8_matmul_dma",
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),   # layer
             pl.BlockSpec(memory_space=pltpu.VMEM),   # x
@@ -283,6 +284,7 @@ def int8_matmul(x: jax.Array, q: jax.Array, s: jax.Array,
             dimension_semantics=("parallel", "arbitrary"))
     return pl.pallas_call(
         kernel,
+        name="dstpu_int8_matmul",
         grid=(ne, nd),
         in_specs=[
             pl.BlockSpec((b, bd), lambda ei, di: (0, di)),

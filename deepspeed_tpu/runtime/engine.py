@@ -537,7 +537,11 @@ class DeepSpeedEngine:
                                               train=True, **kwargs)
             return loss * scale, metrics
 
-        (scaled_loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(params)
+        # scopes name the fused step's parts in a device profile; they
+        # change op metadata only, never the program
+        with jax.named_scope("dstpu_fwd_bwd"):
+            (scaled_loss, metrics), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params)
         # grads accumulate in grad_accum_dtype (reference data_types.
         # grad_accum_dtype): bf16 halves the accumulation buffer
         acc_dt = jnp.bfloat16 if self.config.grad_accum_dtype == "bf16" \
@@ -597,7 +601,9 @@ class DeepSpeedEngine:
             _, grads, metrics = self._micro_loss_and_grads(
                 state.params, mb, scale, sub, pld_theta, constrain=constrain,
                 ltd_keep=ltd_keep)
-            grads_acc = jax.tree_util.tree_map(jnp.add, grads_acc, grads)
+            with jax.named_scope("dstpu_accumulate"):
+                grads_acc = jax.tree_util.tree_map(jnp.add, grads_acc,
+                                                   grads)
             return (grads_acc, loss_acc + metrics["loss"]), None
 
         acc_dt = jnp.bfloat16 if self.config.grad_accum_dtype == "bf16" \
@@ -687,11 +693,13 @@ class DeepSpeedEngine:
             grads, loss_sum = self._scan_micro_grads(state, batch, rng,
                                                      pld_theta,
                                                      ltd_keep=ltd_keep)
-            # back to f32 for unscale/clip/optimizer regardless of the
-            # accumulation dtype
-            grads = jax.tree_util.tree_map(
-                lambda g: g.astype(jnp.float32) / gas, grads)
-            new_state, overflow, norm = self._apply_grads(state, grads, lr)
+            with jax.named_scope("dstpu_optimizer"):
+                # back to f32 for unscale/clip/optimizer regardless of the
+                # accumulation dtype
+                grads = jax.tree_util.tree_map(
+                    lambda g: g.astype(jnp.float32) / gas, grads)
+                new_state, overflow, norm = self._apply_grads(state, grads,
+                                                              lr)
             metrics = {"loss": loss_sum / gas, "overflow": overflow, "grad_norm": norm,
                        "loss_scale": state.scaler.cur_scale}
             return new_state, metrics
@@ -856,10 +864,13 @@ class DeepSpeedEngine:
         self.tput_timer.start()
         self.timers(TRAIN_BATCH_TIMER).start()
         t_start = time.perf_counter()
-        lr = jnp.asarray(self.get_lr()[0], jnp.float32)
-        rng = jax.random.fold_in(self._dropout_rng, self.global_steps)
-        batch = self._apply_curriculum(batch)
-        batch = jax.device_put(batch, self._gas_batch_shardings(batch))
+        # host annotations name the device's idle gaps around the step on
+        # the profiler's clock; no fence, no clock read
+        with jax.profiler.TraceAnnotation("dstpu/train_batch_put"):
+            lr = jnp.asarray(self.get_lr()[0], jnp.float32)
+            rng = jax.random.fold_in(self._dropout_rng, self.global_steps)
+            batch = self._apply_curriculum(batch)
+            batch = jax.device_put(batch, self._gas_batch_shardings(batch))
         ltd_keep = None
         if self._use_random_ltd:
             seq_len = int(batch["input_ids"].shape[-1]) \
@@ -887,20 +898,21 @@ class DeepSpeedEngine:
             else:
                 self.state, metrics = self._compiled_train_step(
                     self.state, batch, lr, rng, None, ltd_keep)
-        self._global_grad_norm = metrics["grad_norm"]
-        self.micro_steps += self.gas
-        self.global_steps += 1
-        if self.lr_scheduler is not None:
-            self.lr_scheduler.step()
-        self._after_step(metrics)
-        self.timers(TRAIN_BATCH_TIMER).stop(record=True)
-        self.tput_timer.stop(global_step=True)
-        if self.telemetry is not None:
-            self._record_step_telemetry(
-                metrics, batch, time.perf_counter() - t_start,
-                ltd_keep=ltd_keep)
-        if self.sentinel is not None:
-            self._resilience_step(metrics, batch)
+        with jax.profiler.TraceAnnotation("dstpu/train_after_step"):
+            self._global_grad_norm = metrics["grad_norm"]
+            self.micro_steps += self.gas
+            self.global_steps += 1
+            if self.lr_scheduler is not None:
+                self.lr_scheduler.step()
+            self._after_step(metrics)
+            self.timers(TRAIN_BATCH_TIMER).stop(record=True)
+            self.tput_timer.stop(global_step=True)
+            if self.telemetry is not None:
+                self._record_step_telemetry(
+                    metrics, batch, time.perf_counter() - t_start,
+                    ltd_keep=ltd_keep)
+            if self.sentinel is not None:
+                self._resilience_step(metrics, batch)
         if self._sync_each_step:
             # dstpu-lint: fence=opt-in per-step fence (config sync_each_step)
             jax.block_until_ready(self.state.params)

@@ -164,6 +164,49 @@ class _ReqTrace:
         self.submitted_t = submitted_t
 
 
+class _Phase:
+    """``with _Phase(engine, annotation, span, now):`` — one phase of a
+    serving iteration on both clocks. The ``jax.profiler.TraceAnnotation``
+    (a no-op without a profiler session) suspends the phase open around
+    it and reopens that one after, so no two ``dstpu/serving_*``
+    annotations of the thread overlap: a device-trace reader names an
+    idle gap by the annotation that covers most of it, and a nested phase
+    would never win. ``span`` is recorded on exit only while an
+    ``iteration`` span is open, which takes a tracer: the bare engine
+    pays the annotation and this one ``is None`` test."""
+
+    __slots__ = ("engine", "annotation", "span", "now", "outer", "live")
+
+    def __init__(self, engine: "ServingEngine", annotation: str,
+                 span: Optional[str], now: float):
+        self.engine = engine
+        self.annotation = annotation
+        self.span = span
+        self.now = now
+
+    def _open(self) -> None:
+        self.live = jax.profiler.TraceAnnotation(self.annotation)
+        self.live.__enter__()
+
+    def __enter__(self) -> "_Phase":
+        self.outer = self.engine._open_phase
+        if self.outer is not None:
+            self.outer.live.__exit__(None, None, None)
+        self._open()
+        self.engine._open_phase = self
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.live.__exit__(*exc)
+        self.engine._open_phase = self.outer
+        if self.outer is not None:
+            self.outer._open()
+        if (self.engine._iter_span is not None and self.span is not None
+                and exc[0] is None):
+            self.engine._phase_end(self.span, self.now)
+        return False
+
+
 class ServingEngine:
     """Drives an :class:`InferenceEngine`'s slot programs with an
     iteration-level scheduler.
@@ -271,7 +314,11 @@ class ServingEngine:
         preemption swap-out/swapped/swap-in, shed/cancel — under a root
         span the engine owns (or the fabric router's, when the request
         arrives with trace context), and per-program wall time is
-        accumulated for :meth:`attribution_table`'s roofline. Arming
+        accumulated for :meth:`attribution_table`'s roofline. On the
+        engine-scope trace every step() that found work records one
+        ``iteration`` span tiled by ``iter_schedule``, ``iter_upload``,
+        ``iter_launch``, ``iter_fetch`` and ``iter_commit``, the same
+        phases the ``dstpu/serving_*`` profiler annotations name. Arming
         adds no device work: greedy output stays bit-identical and the
         armed-vs-bare overhead is pinned <= 2% by bench.py
         ``tracing_overhead``.
@@ -529,6 +576,13 @@ class ServingEngine:
         self.tracer = tracer
         self._rtraces: Dict[int, _ReqTrace] = {}
         self._engine_trace: Optional[str] = None  # iteration-span trace
+        # the open `iteration` span (armed, and only while a step() that
+        # found work runs), the instant its last recorded phase ended —
+        # the next phase starts there, so the phases tile it — and the
+        # phase whose TraceAnnotation is open (see _Phase)
+        self._iter_span = None
+        self._phase_t = 0.0
+        self._open_phase: Optional[_Phase] = None
         self._last_step_now = 0.0     # cancel() has no `now` argument
         # context-carrying records awaiting their submit-time stamp
         # (resolved by the next step(); see _ReqTrace.submitted_t)
@@ -1287,7 +1341,10 @@ class ServingEngine:
             if armed:
                 t_span0 = self._now(now)
                 t_wall0 = time.perf_counter()
-            with jax.profiler.TraceAnnotation("dstpu/serving_prefill"):
+            # open from before the program call until, for the last chunk,
+            # its fenced token fetch returns: an idle device in between is
+            # the prefill's, not the admission's
+            with _Phase(self, "dstpu/serving_prefill", None, now):
                 if self.prefix is not None:
                     pname = f"prefill_{bucket}"
                     out = self._prefill_fn(bucket)(
@@ -1310,38 +1367,41 @@ class ServingEngine:
                         np.int32(slot), np.int32(st.prefill_pos),
                         np.int32(chunk), self._temp, self._next_rng())
                 self.cache.update(*out[:3])
-            if armed:
-                # host-stamped at the instants the loop already holds:
-                # no fence added (under async dispatch this brackets the
-                # dispatch; the LAST chunk's token fetch below is the
-                # same fence the untraced engine always paid)
-                self._prog_note(pname, time.perf_counter() - t_wall0)
-                rt = self._rtraces.get(req.rid)
-                if rt is not None:
-                    self.tracer.record(
-                        "prefill_chunk", t_span0, self._now(now),
-                        trace_id=rt.trace_id, parent_id=rt.root,
-                        program=pname, bucket=bucket, tokens=chunk,
-                        slot=slot)
-            st.prefill_pos += chunk
-            # the budget is charged in BUCKET-PADDED tokens — the
-            # compute actually dispatched — so one iteration's prefill
-            # work genuinely stays near the cap (true-token charging
-            # would let padding push real work past it); chunks are
-            # never clamped below their natural size, since a padded
-            # bucket costs the same forward whether half full or full
-            spent += bucket
-            self.prefill_tokens_computed += chunk
-            self.prefill_chunks += 1
-            st.result.prefill_chunks += 1
-            if self.telemetry is not None:
-                self.telemetry.counter("serving/prefill_chunks").inc()
-            if self.tenants is not None:
-                # billed at the same increment as the engine counter, so
-                # per-tenant computed tokens sum EXACTLY to it
-                self.tenants.note_prefill(st.tenant, chunk)
+                if armed:
+                    # host-stamped at the instants the loop already holds:
+                    # no fence added. An intermediate chunk has no fence,
+                    # so under async dispatch its span brackets the
+                    # dispatch only (fenced=False); the LAST chunk's span
+                    # closes below, after the token fetch the untraced
+                    # engine always paid
+                    self._prog_note(pname, time.perf_counter() - t_wall0)
+                    rt = self._rtraces.get(req.rid)
+                    if rt is not None and not last:
+                        self.tracer.record(
+                            "prefill_chunk", t_span0, self._now(now),
+                            trace_id=rt.trace_id, parent_id=rt.root,
+                            program=pname, bucket=bucket, tokens=chunk,
+                            slot=slot, fenced=False)
+                st.prefill_pos += chunk
+                # the budget is charged in BUCKET-PADDED tokens — the
+                # compute actually dispatched — so one iteration's prefill
+                # work genuinely stays near the cap (true-token charging
+                # would let padding push real work past it); chunks are
+                # never clamped below their natural size, since a padded
+                # bucket costs the same forward whether half full or full
+                spent += bucket
+                self.prefill_tokens_computed += chunk
+                self.prefill_chunks += 1
+                st.result.prefill_chunks += 1
+                if self.telemetry is not None:
+                    self.telemetry.counter("serving/prefill_chunks").inc()
+                if self.tenants is not None:
+                    # billed at the same increment as the engine counter,
+                    # so per-tenant computed tokens sum EXACTLY to it
+                    self.tenants.note_prefill(st.tenant, chunk)
+                if last:
+                    tok = int(jax.device_get(out[3]))  # dstpu-lint: fence=token emission: the chunk's final pick must reach the host stream
             if last:
-                tok = int(jax.device_get(out[3]))  # dstpu-lint: fence=token emission: the chunk's final pick must reach the host stream
                 self.prefill_calls += 1
                 self.tokens_generated += 1
                 st.last_token = tok
@@ -1360,10 +1420,16 @@ class ServingEngine:
                     self.tenants.note_tokens(st.tenant, 1)
                     self.tenants.note_ttft(st.tenant, ttft)
                 if armed:
-                    # decode-phase residency starts at the first-token
-                    # commit; closed at finish/preemption/cancel
                     rt = self._rtraces.get(req.rid)
                     if rt is not None:
+                        # the fenced chunk ends at the first-token commit,
+                        # where decode-phase residency starts (closed at
+                        # finish/preemption/cancel)
+                        self.tracer.record(
+                            "prefill_chunk", t_span0, t_emit,
+                            trace_id=rt.trace_id, parent_id=rt.root,
+                            program=pname, bucket=bucket, tokens=chunk,
+                            slot=slot, fenced=True)
                         rt.decode_span = self.tracer.begin(
                             "decode_segment", trace_id=rt.trace_id,
                             parent_id=rt.root, t=t_emit, slot=slot)
@@ -1600,8 +1666,20 @@ class ServingEngine:
                 rt.submitted_t = now
             self._pending_submit_stamps.clear()
         finished: List[RequestResult] = []
-        with jax.profiler.TraceAnnotation("dstpu/serving_admit"):
+        armed = self.tracer is not None
+        if armed:
+            self._iter_span = None     # a step that raised left its own
+            t_iter0 = self._now(now)
+        with _Phase(self, "dstpu/serving_admit", None, now):
             self._schedule(now, finished)
+        if armed and (finished or any(s is not None for s in self._slots)):
+            # this step admitted, prefilled or will decode: one
+            # `iteration` span in the engine-scope trace, tiled by its
+            # phases (an idle poll of the queue records nothing)
+            self._iter_span = self.tracer.begin(
+                "iteration", trace_id=self._iter_trace(), t=t_iter0)
+            self._phase_t = t_iter0
+            self._phase_end("iter_schedule", now)
         active_slots = [i for i, s in enumerate(self._slots)
                         if s is not None and not s.prefilling]
         if self.telemetry is not None:
@@ -1619,11 +1697,17 @@ class ServingEngine:
             # no decode ran: a later gap against _last_decode_t would
             # fold queue-idle time into the TPOT-SLO EMA
             self._last_decode_t = None
-            return finished
-        self._note_decode_gap()
-        if self.spec is not None:
-            return self._spec_step(now, active_slots, finished)
-        return self._plain_step(now, active_slots, finished)
+        else:
+            self._note_decode_gap()
+            if self.spec is not None:
+                self._spec_step(now, active_slots, finished)
+            else:
+                self._plain_step(now, active_slots, finished)
+        if self._iter_span is not None:
+            # ends where its last phase ended
+            self.tracer.end(self._iter_span, t=self._phase_t)
+            self._iter_span = None
+        return finished
 
     def _account_kv_occupancy(self, now: float) -> None:
         """Integrate per-tenant KV occupancy over the interval since
@@ -1655,12 +1739,23 @@ class ServingEngine:
                                            self._kv_bytes_per_block)
 
     def _iter_trace(self) -> str:
-        """Lazy engine-scope trace for iteration-level spans (decode
-        steps, speculative draft/verify) — structural context that is
-        not any single request's lifecycle."""
+        """Lazy engine-scope trace for iteration-level spans (the
+        iteration and its phases, decode steps, speculative
+        draft/verify) — structural context that is not any single
+        request's lifecycle."""
         if self._engine_trace is None:
             self._engine_trace = self.tracer.new_trace()
         return self._engine_trace
+
+    def _phase_end(self, name: str, now: float) -> None:
+        """Armed, inside an open iteration: record phase ``name`` from
+        where the previous phase ended to a fresh read of the engine
+        clock, and start the next phase there."""
+        t = self._now(now)
+        self.tracer.record(name, self._phase_t, t,
+                           trace_id=self._iter_span.trace_id,
+                           parent_id=self._iter_span.span_id)
+        self._phase_t = t
 
     def _note_decode_gap(self) -> None:
         """EMA of wall time between consecutive decode invocations —
@@ -1679,21 +1774,24 @@ class ServingEngine:
         """One plain decode iteration: one token for every active slot.
         Also the speculative path's fallback when drafting proposes
         nothing anywhere (a 1-wide step beats an empty k-wide verify)."""
-        toks = np.full((self.num_slots,), self.pad_token_id, np.int32)
-        for i in active_slots:
-            toks[i] = self._slots[i].last_token
-        active = np.zeros((self.num_slots,), bool)
-        active[active_slots] = True
-        armed = self.tracer is not None
-        if armed:
-            t_dec0 = self._now(now)
-        t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation("dstpu/serving_decode"):
-            out = self._decode(self.engine.params, *self.cache.carry(),
-                               *self._table_args(),
-                               jnp.asarray(toks), jnp.asarray(active),
-                               self._temp, self._next_rng())
+        with _Phase(self, "dstpu/serving_upload", "iter_upload", now):
+            toks = np.full((self.num_slots,), self.pad_token_id, np.int32)
+            for i in active_slots:
+                toks[i] = self._slots[i].last_token
+            active = np.zeros((self.num_slots,), bool)
+            active[active_slots] = True
+            armed = self.tracer is not None
+            if armed:
+                t_dec0 = self._now(now)
+            t0 = time.perf_counter()
+            args = (self.engine.params, *self.cache.carry(),
+                    *self._table_args(),
+                    jnp.asarray(toks), jnp.asarray(active),
+                    self._temp, self._next_rng())
+        with _Phase(self, "dstpu/serving_launch", "iter_launch", now):
+            out = self._decode(*args)
             self.cache.update(*out[:3])
+        with _Phase(self, "dstpu/serving_fetch", "iter_fetch", now):
             nxt = np.asarray(jax.device_get(out[3]))  # dstpu-lint: fence=token emission: decode's picks feed host continuations + streams
         dt = time.perf_counter() - t0
         self.decode_wall += dt
@@ -1701,31 +1799,33 @@ class ServingEngine:
             # the token fetch above IS a fence, so this wall is honest
             # device-inclusive time — the attribution 'achieved' clock
             self._prog_note("decode", dt)
-            self.tracer.record("decode_step", t_dec0, self._now(now),
+            # iter_fetch closed at a read of the clock after the fence
+            self.tracer.record("decode_step", t_dec0, self._phase_t,
                                trace_id=self._iter_trace(),
                                program="decode",
                                n_slots=len(active_slots))
-        self.decode_steps += 1
-        self._active_slot_iterations += len(active_slots)
-        if self.telemetry is not None:
-            self.telemetry.counter("serving/decode_steps").inc()
-            self.telemetry.counter("serving/slot_iterations_active").inc(
-                len(active_slots))
-        t_emit = self._now(now)
-        for i in active_slots:
-            st = self._slots[i]
-            tok = int(nxt[i])
-            st.result.tokens.append(tok)
-            st.result.token_times.append(t_emit)
-            st.result.decode_calls += 1
-            st.last_token = tok
-            self.tokens_generated += 1
-            if self.tenants is not None:
-                self.tenants.note_tokens(st.tenant, 1)
-            self._stream(st, [tok])
-            done = self._maybe_finish(i, now)
-            if done is not None:
-                finished.append(done)
+        with _Phase(self, "dstpu/serving_commit", "iter_commit", now):
+            self.decode_steps += 1
+            self._active_slot_iterations += len(active_slots)
+            if self.telemetry is not None:
+                self.telemetry.counter("serving/decode_steps").inc()
+                self.telemetry.counter("serving/slot_iterations_active").inc(
+                    len(active_slots))
+            t_emit = self._now(now)
+            for i in active_slots:
+                st = self._slots[i]
+                tok = int(nxt[i])
+                st.result.tokens.append(tok)
+                st.result.token_times.append(t_emit)
+                st.result.decode_calls += 1
+                st.last_token = tok
+                self.tokens_generated += 1
+                if self.tenants is not None:
+                    self.tenants.note_tokens(st.tenant, 1)
+                self._stream(st, [tok])
+                done = self._maybe_finish(i, now)
+                if done is not None:
+                    finished.append(done)
         return finished
 
     def _spec_step(self, now: float, active_slots: List[int],
@@ -1774,9 +1874,14 @@ class ServingEngine:
         self._draft_wall += dt
         self.decode_wall += dt
         if armed:
-            self.tracer.record("spec_draft", t_sp0, self._now(now),
-                               trace_id=self._iter_trace(), k_bucket=kb,
-                               n_slots=len(active_slots))
+            # the speculative phases do not tile the iteration (host work
+            # between them is in no span); each moves the phase clock to
+            # its end, so that the next tiled phase starts there
+            self._phase_t = self._now(now)
+            self.tracer.record("spec_draft", t_sp0, self._phase_t,
+                               trace_id=self._iter_trace(),
+                               parent_id=self._iter_span.span_id,
+                               k_bucket=kb, n_slots=len(active_slots))
         longest = int(lens.max())
         if longest == 0:
             # nothing proposed anywhere (e.g. prompt-lookup on novel
@@ -1810,53 +1915,56 @@ class ServingEngine:
         self.decode_wall += dt
         if armed:
             self._prog_note(f"verify_{kb}", dt)
-            self.tracer.record("spec_verify", t_vf0, self._now(now),
+            self._phase_t = self._now(now)
+            self.tracer.record("spec_verify", t_vf0, self._phase_t,
                                trace_id=self._iter_trace(),
+                               parent_id=self._iter_span.span_id,
                                program=f"verify_{kb}",
                                n_slots=len(active_slots))
-        self.decode_steps += 1
-        self._active_slot_iterations += len(active_slots)
-        reg = self.telemetry
-        if reg is not None:
-            reg.counter("serving/decode_steps").inc()
-            reg.counter("serving/spec_verify_steps").inc()
-            reg.counter("serving/slot_iterations_active").inc(
-                len(active_slots))
-        t_emit = self._now(now)
-        for i in active_slots:
-            st = self._slots[i]
-            n = int(n_emit[i])
-            emitted = [int(t) for t in out_tokens[i, :n]]
-            n_drafted, n_accepted = int(lens[i]), n - 1
-            if (self.eos_token_id is not None
-                    and self.eos_token_id in emitted):
-                # EOS inside the accepted block: baseline decode stops
-                # at its first EOS, so every token behind it is dropped
-                # (the slot retires; its dead cache tail is overwritten
-                # by the next prefill into the slot)
-                emitted = emitted[:emitted.index(self.eos_token_id) + 1]
-            st.result.tokens.extend(emitted)
-            st.result.token_times.extend([t_emit] * len(emitted))
-            st.result.decode_calls += 1
-            st.last_token = emitted[-1]
-            self.tokens_generated += len(emitted)
-            if self.tenants is not None:
-                self.tenants.note_tokens(st.tenant, len(emitted))
-            # stream only the ACCEPTED (post-truncation) block — a
-            # rejected draft token is never observable
-            self._stream(st, emitted)
-            self.spec_drafted_tokens += n_drafted
-            self.spec_accepted_tokens += n_accepted
-            if self._adaptive is not None:
-                self._adaptive.update(i, n_accepted, n_drafted)
+        with _Phase(self, "dstpu/serving_commit", "iter_commit", now):
+            self.decode_steps += 1
+            self._active_slot_iterations += len(active_slots)
+            reg = self.telemetry
             if reg is not None:
-                reg.counter("serving/spec_drafted_tokens").inc(n_drafted)
-                reg.counter("serving/spec_accepted_tokens").inc(n_accepted)
-                reg.histogram("serving/accepted_tokens_per_step",
-                              buckets=_TOKENS_PER_STEP_BUCKETS).observe(n)
-            done = self._maybe_finish(i, now)
-            if done is not None:
-                finished.append(done)
+                reg.counter("serving/decode_steps").inc()
+                reg.counter("serving/spec_verify_steps").inc()
+                reg.counter("serving/slot_iterations_active").inc(
+                    len(active_slots))
+            t_emit = self._now(now)
+            for i in active_slots:
+                st = self._slots[i]
+                n = int(n_emit[i])
+                emitted = [int(t) for t in out_tokens[i, :n]]
+                n_drafted, n_accepted = int(lens[i]), n - 1
+                if (self.eos_token_id is not None
+                        and self.eos_token_id in emitted):
+                    # EOS inside the accepted block: baseline decode stops
+                    # at its first EOS, so every token behind it is dropped
+                    # (the slot retires; its dead cache tail is overwritten
+                    # by the next prefill into the slot)
+                    emitted = emitted[:emitted.index(self.eos_token_id) + 1]
+                st.result.tokens.extend(emitted)
+                st.result.token_times.extend([t_emit] * len(emitted))
+                st.result.decode_calls += 1
+                st.last_token = emitted[-1]
+                self.tokens_generated += len(emitted)
+                if self.tenants is not None:
+                    self.tenants.note_tokens(st.tenant, len(emitted))
+                # stream only the ACCEPTED (post-truncation) block — a
+                # rejected draft token is never observable
+                self._stream(st, emitted)
+                self.spec_drafted_tokens += n_drafted
+                self.spec_accepted_tokens += n_accepted
+                if self._adaptive is not None:
+                    self._adaptive.update(i, n_accepted, n_drafted)
+                if reg is not None:
+                    reg.counter("serving/spec_drafted_tokens").inc(n_drafted)
+                    reg.counter("serving/spec_accepted_tokens").inc(n_accepted)
+                    reg.histogram("serving/accepted_tokens_per_step",
+                                  buckets=_TOKENS_PER_STEP_BUCKETS).observe(n)
+                done = self._maybe_finish(i, now)
+                if done is not None:
+                    finished.append(done)
         return finished
 
     # ----------------------------------------------------------------- run

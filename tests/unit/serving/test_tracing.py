@@ -26,6 +26,8 @@ Pinned here:
 import importlib.util
 import json
 import os
+import time
+import types
 
 import numpy as np
 import pytest
@@ -162,6 +164,198 @@ def test_trace_context_on_request_is_honored():
     # the engine did NOT close the caller-owned root
     assert root.end is None
     tracer.end(root, t=clock.now)
+
+
+# --------------------------------------------- iteration phase spans
+PHASES = ("iter_schedule", "iter_upload", "iter_launch", "iter_fetch",
+          "iter_commit")
+
+
+def test_phases_tile_the_iteration_span():
+    """Every step() that found work records one ``iteration`` span on
+    the engine-scope trace; its phases have it as parent, follow each
+    other without a gap from its start to its end, and the four decode
+    phases close on iteration less schedule exactly."""
+    tracer = SpanTracer()
+    srv = _serving(FakeClock(auto_dt=0.001), tracer=tracer)
+    results = srv.run(_trace(8, seed=6))
+    iters = [s for s in tracer.spans if s.name == "iteration"]
+    assert len(iters) >= srv.decode_steps > 0
+    req_traces = {s["trace"] for s in trace_summaries(tracer.spans)}
+    assert {s.trace_id for s in iters}.isdisjoint(req_traces)
+    decoded = 0
+    for it in iters:
+        kids = [s for s in tracer.spans if s.parent_id == it.span_id]
+        phases = sorted((s for s in kids if s.name in PHASES),
+                        key=lambda s: s.start)
+        assert {s.name for s in kids} <= set(PHASES) | {"decode_step"}
+        names = [s.name for s in phases]
+        assert names in (list(PHASES), ["iter_schedule"]), names
+        assert phases[0].start == it.start and phases[-1].end == it.end
+        for a, b in zip(phases, phases[1:]):
+            assert a.end == b.start
+        assert all(s.trace_id == it.trace_id for s in kids)
+        if len(phases) > 1:
+            decoded += 1
+            rest = sum(s.duration for s in phases[1:])
+            assert rest == pytest.approx(it.duration - phases[0].duration)
+    assert decoded == srv.decode_steps
+    # decode_step keeps its place: from before the program's arguments
+    # are bound to after the fetch, so it ends with iter_fetch
+    fetch_ends = {s.end for s in tracer.spans if s.name == "iter_fetch"}
+    steps = [s for s in tracer.spans if s.name == "decode_step"]
+    assert len(steps) == srv.decode_steps
+    assert all(s.end in fetch_ends for s in steps)
+    assert len(results) == 8
+
+
+@pytest.mark.parametrize("chunked", [False, True],
+                         ids=["monolithic", "chunked"])
+def test_prefill_chunk_of_the_last_chunk_is_fenced(chunked):
+    """The last chunk's span closes at the first-token commit, after the
+    token fetch (``fenced=True``); an intermediate chunk has no fence of
+    its own and says so."""
+    cfg, _ = _inference_engine()
+    tracer = SpanTracer()
+    kw = dict(prefill_token_budget=16) if chunked \
+        else {}
+    srv = _serving(FakeClock(auto_dt=0.001), tracer=tracer, **kw)
+    rng = np.random.RandomState(7)
+    reqs = [Request(rid=i, prompt=rng.randint(
+                0, cfg.vocab_size, size=n).tolist(), max_new_tokens=4)
+            for i, n in enumerate((40, 9, 33))]
+    results = {r.rid: r for r in srv.run(reqs)}
+    sums = {s["attrs"]["rid"]: s for s in trace_summaries(tracer.spans)}
+    for rid, res in results.items():
+        chunks = sorted((s for s in tracer.spans_for(sums[rid]["trace"])
+                         if s.name == "prefill_chunk"),
+                        key=lambda s: s.start)
+        assert len(chunks) == res.prefill_chunks
+        assert [c.attrs["fenced"] for c in chunks] == \
+            [False] * (len(chunks) - 1) + [True]
+        assert chunks[-1].end >= res.first_token_time
+        assert all(c.end <= res.first_token_time for c in chunks[:-1])
+    assert any(r.prefill_chunks > 1 for r in results.values()) == chunked
+
+
+class _RecordingAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs every enter
+    and exit in order."""
+
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name))
+        return False
+
+
+@pytest.mark.parametrize("mode", ["plain", "chunked", "speculative",
+                                  "preemption"])
+def test_no_two_serving_annotations_overlap(mode, monkeypatch):
+    """A device-trace reader names an idle gap by the annotation that
+    covers most of it, so at most one ``dstpu/serving_*`` annotation is
+    open at any time: a prefill suspends the admit phase around it."""
+    import jax
+
+    cfg, _ = _inference_engine()
+    kw = {"plain": {},
+          "chunked": dict(prefill_token_budget=16),
+          "speculative": dict(speculative="ngram", num_slots=2,
+                              max_len=128, buckets=(64,)),
+          "preemption": dict(preemption="swap", num_slots=1,
+                             buckets=(16, 32))}[mode]
+    srv = _serving(FakeClock(auto_dt=0.001), **kw)
+    srv.warmup()
+    log = _RecordingAnnotation.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        _RecordingAnnotation)
+    rng = np.random.RandomState(8)
+    if mode == "speculative":
+        pattern = rng.randint(0, cfg.vocab_size, size=5).tolist()
+        reqs = [Request(rid=i, prompt=pattern * 6, max_new_tokens=10)
+                for i in range(2)]
+    elif mode == "preemption":
+        reqs = [Request(rid=0, prompt=rng.randint(
+                    0, cfg.vocab_size, size=21).tolist(),
+                    max_new_tokens=24, priority=1, arrival_time=0.0),
+                Request(rid=1, prompt=rng.randint(
+                    0, cfg.vocab_size, size=9).tolist(),
+                    max_new_tokens=6, priority=0, arrival_time=0.02)]
+    else:
+        reqs = _trace(8, seed=9)
+    assert len(srv.run(reqs, warmup=False)) == len(reqs)
+    open_now = None
+    seen = set()
+    for what, name in log:
+        assert name.startswith("dstpu/serving_")
+        if what == "enter":
+            assert open_now is None, (open_now, name)
+            open_now = name
+            seen.add(name)
+        else:
+            assert open_now == name
+            open_now = None
+    assert open_now is None
+    assert "dstpu/serving_decode" not in seen
+    want = {"dstpu/serving_admit", "dstpu/serving_prefill",
+            "dstpu/serving_commit"}
+    want |= {"dstpu/serving_draft", "dstpu/serving_verify"} \
+        if mode == "speculative" else \
+        {"dstpu/serving_upload", "dstpu/serving_launch",
+         "dstpu/serving_fetch"}
+    assert want <= seen, sorted(seen)
+
+
+def test_bare_engine_reads_no_clock_the_parent_did_not(monkeypatch):
+    """With ``tracer=None`` the iteration reads the engine's clock once
+    for each first token, once for each decode step (the commit stamp)
+    and once for each finished request, as before the phases existed,
+    and ``perf_counter`` three times a decode step (the decode-gap EMA
+    and the decode wall); armed output is bit-identical."""
+    from deepspeed_tpu.serving import engine as engine_mod
+
+    reqs = _trace(8, seed=10)
+    calls = {"clock": 0, "perf": 0}
+
+    class CountingClock(FakeClock):
+        def time(self):
+            calls["clock"] += 1
+            return super().time()
+
+    def perf_counter():
+        calls["perf"] += 1
+        return time.perf_counter()
+
+    bare = _serving(CountingClock(auto_dt=0.001))
+    bare.warmup()
+    monkeypatch.setattr(engine_mod, "time", types.SimpleNamespace(
+        perf_counter=perf_counter))
+    for r in reqs:
+        bare.submit(r)
+    bare._run_t0 = 0.0            # as run() does: stamps read the clock
+    calls["clock"] = calls["perf"] = 0
+    results, now = [], 0.0
+    while bare.pending:
+        now += 0.01               # past every arrival soon; no clock read
+        results.extend(bare.step(now))
+    assert len(results) == len(reqs)
+    assert calls["clock"] == (bare.prefill_calls + bare.decode_steps
+                              + len(results))
+    assert calls["perf"] == 3 * bare.decode_steps
+    assert bare._iter_span is None and bare._open_phase is None
+    monkeypatch.undo()
+    tracer = SpanTracer()
+    armed = _serving(FakeClock(auto_dt=0.001), tracer=tracer)
+    got = {r.rid: r.tokens for r in armed.run(reqs)}
+    assert got == {r.rid: r.tokens for r in results}
+    assert all(v == 1 for v in armed.program_cache_sizes().values())
 
 
 # -------------------------------------------------- preemption + swap
