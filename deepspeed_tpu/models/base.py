@@ -15,6 +15,7 @@ for TP/EP while ZeRO picks up the rest. Flax linen modules are adapted via
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Dict, Optional, Protocol, Tuple, runtime_checkable
 
 import jax
@@ -233,6 +234,84 @@ def layer_view(blocks, i):
         return jax.lax.dynamic_index_in_dim(node, i, 0, keepdims=False)
 
     return walk(blocks)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _gather_for_use(w, whole, shard):
+    return jax.lax.with_sharding_constraint(w, whole)
+
+
+def _gather_fwd(w, whole, shard):
+    return jax.lax.with_sharding_constraint(w, whole), None
+
+
+def _gather_bwd(whole, shard, _, g):
+    # straight to the gradient's shard: a reduce-scatter, where the
+    # transpose of a bare constraint leaves an all-reduce and a slice
+    return (jax.lax.with_sharding_constraint(g, shard),)
+
+
+_gather_for_use.defvjp(_gather_fwd, _gather_bwd)
+
+
+def gathered(tree, *path, stacked: bool = False):
+    """ZeRO-3's parameter gather, stated where the parameters are used.
+
+    ``tree`` is the part of the params found under ``path`` (keys into the
+    params tree; a dict may hold only some of the keys found there); with
+    ``stacked`` it is one layer's slice of layer-stacked leaves, so the
+    specs lose their leading entry. Under a training engine whose plan
+    shards compute params over the ZeRO axis, every such leaf is constrained
+    to its gathered spec (TP/EP axes stay) and its cotangent to the
+    gradient's spec. The identity (the same jaxpr) when no plan is stating
+    gathers — no engine, stage below 3, a ZeRO axis of size 1 — and for
+    leaves the plan keeps replicated (runtime/zero/partition.py).
+
+    Call it INSIDE the rematerialised block of a layer scan: the backward
+    pass then gathers again, and the scan saves no whole weight. Call it
+    from a function made for this trace (a closure of ``forward_hidden``),
+    not from a bound method handed to ``jax.checkpoint`` or ``lax.scan``:
+    those keep a traced function by its identity, and would replay the
+    gathers (or their absence) of whichever engine traced first."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.runtime.zero.partition import active_param_use
+
+    use = active_param_use()
+    if use is None:
+        return tree
+
+    def walk(node, specs):
+        if isinstance(node, dict):
+            return {k: walk(v, tuple(s[k] for s in specs))
+                    for k, v in node.items()}
+        if isinstance(node, (list, tuple)):
+            return type(node)(walk(v, tuple(s[i] for s in specs))
+                              for i, v in enumerate(node))
+        c, g, r = specs
+        if c == g:
+            return node
+        if stacked:
+            g, r = P(*g[1:]), P(*r[1:])
+            # gather the slice, not the [1, ...] window of the stack the
+            # scan cut it from: gathered through the unit dimension the
+            # MLP weights come out in a (2,128) tiling and are copied
+            # before and after (4% of a GPT-2 XL step, PERF.md, PR 28)
+            node = jax.lax.optimization_barrier(node)
+        return _gather_for_use(node, NamedSharding(use.mesh, g),
+                               NamedSharding(use.mesh, r))
+
+    specs = (use.compute, use.gathered, use.grad)
+    for key in path:
+        specs = tuple(s[key] for s in specs)
+    return walk(tree, specs)
+
+
+def gathered_top(params):
+    """:func:`gathered` for what lies outside the layer stack (embeddings,
+    final norm, head), at the place that uses some of it: what is not used
+    there is dead code to the compiler."""
+    return gathered({k: v for k, v in params.items() if k != "blocks"})
 
 
 def cross_entropy_loss(logits, labels, ignore_index: int = -100):

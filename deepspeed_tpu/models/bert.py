@@ -21,7 +21,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import cross_entropy_loss, layer_norm, qdot
+from deepspeed_tpu.models.base import cross_entropy_loss, gathered, gathered_top, layer_norm, qdot
 from deepspeed_tpu.ops.attention import multihead_attention
 
 _ACTS = {
@@ -191,20 +191,26 @@ class BertModel:
                        token_type_ids=None, *, rngs=None, train=False):
         c = self.config
         b, t = input_ids.shape
-        x = params["wte"].astype(self.compute_dtype)[input_ids]
-        x = x + params["wpe"].astype(self.compute_dtype)[:t][None]
+        emb = gathered_top(params)
+        x = emb["wte"].astype(self.compute_dtype)[input_ids]
+        x = x + emb["wpe"].astype(self.compute_dtype)[:t][None]
         if c.type_vocab_size > 0:
             if token_type_ids is None:
                 token_type_ids = jnp.zeros_like(input_ids)
-            x = x + params["wtt"].astype(self.compute_dtype)[token_type_ids]
-        x = layer_norm(x, params["emb_ln_scale"], params["emb_ln_bias"], c.eps)
+            x = x + emb["wtt"].astype(self.compute_dtype)[token_type_ids]
+        x = layer_norm(x, emb["emb_ln_scale"], emb["emb_ln_bias"], c.eps)
 
         mask_bias = None
         if attention_mask is not None:
             # [B, 1, 1, T] boolean: key positions that may be attended
             mask_bias = attention_mask[:, None, None, :].astype(bool)
 
-        block_fn = self._block
+        def block_fn(x, blk, mask_bias):
+            # ZeRO-3 gathers inside what remat wraps; a closure of this
+            # call, because jax keeps a traced block by its function
+            return self._block(x, gathered(blk, "blocks", stacked=True),
+                               mask_bias)
+
         if self.remat:
             block_fn = jax.checkpoint(block_fn)
 
@@ -244,7 +250,7 @@ class BertModel:
         hidden = self.forward_hidden(
             params, batch["input_ids"], batch.get("attention_mask"),
             batch.get("token_type_ids"), rngs=rngs, train=train)
-        logits = self.logits(params, hidden)
+        logits = self.logits(gathered_top(params), hidden)
         labels = batch["labels"]
         if self.head == "mlm":
             loss, n = cross_entropy_loss(logits, labels)

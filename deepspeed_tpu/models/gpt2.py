@@ -20,7 +20,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import ATTN_IMPLS, cache_positions, cross_entropy_loss, embed_tokens, gelu, layer_norm, layer_view, qdot, sp_attention, tied_logits
+from deepspeed_tpu.models.base import ATTN_IMPLS, cache_positions, cross_entropy_loss, embed_tokens, gathered, gathered_top, gelu, layer_norm, layer_view, qdot, sp_attention, tied_logits
 from deepspeed_tpu.ops.attention import alloc_kv_cache, cached_attention, multihead_attention
 
 
@@ -206,10 +206,18 @@ class GPT2Model:
                        pld_theta=None, ltd_keep=None):
         c = self.config
         b, t = input_ids.shape
-        x = embed_tokens(params["wte"], input_ids, self.compute_dtype)
-        x = x + params["wpe"].astype(self.compute_dtype)[:t][None]
+        top = gathered_top(params)     # ZeRO-3: the embeddings, whole
+        x = embed_tokens(top["wte"], input_ids, self.compute_dtype)
+        x = x + top["wpe"].astype(self.compute_dtype)[:t][None]
 
-        block_fn = self._block
+        def block_fn(x, blk, rng, train):
+            # ZeRO-3 gathers the layer's weights inside what remat wraps,
+            # so the backward pass gathers them again. A closure of this
+            # call, not the bound method: jax keeps a traced block by its
+            # function, and what ``gathered`` states is the engine's
+            blk = gathered(blk, "blocks", stacked=True)
+            return self._block(x, blk, rng, train)
+
         if self.remat:
             from deepspeed_tpu.runtime.activation_checkpointing import checkpoint_policy
 
@@ -248,8 +256,7 @@ class GPT2Model:
             (x, rng0), _ = jax.lax.scan(ltd_body, (x, rng0), mid)
             rng0, sub = jax.random.split(rng0)
             x = block_fn(x, last, sub, train)
-            return layer_norm(x, params["ln_f_scale"], params["ln_f_bias"],
-                              c.eps)
+            return layer_norm(x, top["ln_f_scale"], top["ln_f_bias"], c.eps)
 
         use_pld = pld_theta is not None and train
         layer_idx = jnp.arange(c.num_layers)
@@ -280,7 +287,7 @@ class GPT2Model:
         rng = rngs.get("dropout") if isinstance(rngs, dict) else rngs
         (x, _), _ = jax.lax.scan(scan_body, (x, rng),
                                  (params["blocks"], layer_idx))
-        return layer_norm(x, params["ln_f_scale"], params["ln_f_bias"], c.eps)
+        return layer_norm(x, top["ln_f_scale"], top["ln_f_bias"], c.eps)
 
     def logits(self, params, hidden):
         if self.config.tie_embeddings:
@@ -293,15 +300,16 @@ class GPT2Model:
                                      train=train, pld_theta=pld_theta,
                                      ltd_keep=ltd_keep)
         c = self.config
+        head = gathered_top(params)    # gathered again, for the loss head
         if c.loss_chunk:
             from deepspeed_tpu.runtime.zero.tiling import (
                 chunked_cross_entropy)
 
-            loss, n = chunked_cross_entropy(hidden, params["wte"],
+            loss, n = chunked_cross_entropy(hidden, head["wte"],
                                             batch["labels"],
                                             chunk=c.loss_chunk)
         else:
-            loss, n = cross_entropy_loss(self.logits(params, hidden),
+            loss, n = cross_entropy_loss(self.logits(head, hidden),
                                          batch["labels"])
         return loss, {"loss": loss, "ntokens": n}
 

@@ -17,7 +17,8 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.models.base import (cache_positions, cross_entropy_loss,
-                                       gelu, layer_norm)
+                                       gathered, gathered_top, gelu,
+                                       layer_norm)
 from deepspeed_tpu.moe.layer import MoE
 from deepspeed_tpu.ops.attention import alloc_kv_cache, cached_attention, multihead_attention
 
@@ -169,11 +170,15 @@ class GPTMoEModel:
     def _forward_blocks(self, params, x, *, rng=None, train: bool = False):
         total_aux = jnp.zeros((), jnp.float32)
         for i, blk in enumerate(params["blocks"]):
+            # ZeRO-3 gathers a block's weights at the block (expert
+            # weights keep their 'expert' axis)
+            blk = gathered(blk, "blocks", i)
             x, _, _ = self._attn(x, blk)
             x, l_aux = self._ffn(x, blk, i, train=train, rng=rng)
             total_aux = total_aux + l_aux
         c = self.config
-        return layer_norm(x, params["ln_f_scale"], params["ln_f_bias"],
+        top = gathered_top(params)
+        return layer_norm(x, top["ln_f_scale"], top["ln_f_bias"],
                           c.eps), total_aux
 
     def forward_hidden(self, params, input_ids, *, rngs=None,
@@ -190,9 +195,9 @@ class GPTMoEModel:
     def apply(self, params, batch, *, rngs=None, train: bool = False):
         c = self.config
         rng = rngs.get("dropout") if isinstance(rngs, dict) else rngs
-        x = self._embed(params, batch["input_ids"])
+        x = self._embed(gathered_top(params), batch["input_ids"])
         hidden, total_aux = self._forward_blocks(params, x, rng=rng, train=train)
-        logits = self.logits(params, hidden)
+        logits = self.logits(gathered_top(params), hidden)
         ce, n = cross_entropy_loss(logits, batch["labels"])
         loss = ce + c.aux_loss_weight * total_aux / max(len(self.moe_layers), 1)
         return loss, {"loss": loss, "ce_loss": ce, "aux_loss": total_aux, "ntokens": n}

@@ -14,7 +14,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import ATTN_IMPLS, cross_entropy_loss, layer_view, qdot, rms_norm, sp_attention  # noqa: E501
+from deepspeed_tpu.models.base import ATTN_IMPLS, cross_entropy_loss, gathered, gathered_top, layer_view, qdot, rms_norm, sp_attention  # noqa: E501
 from deepspeed_tpu.ops.attention import alloc_kv_cache, cached_attention, multihead_attention
 from deepspeed_tpu.ops.rotary import apply_rotary_pos_emb, rope_frequencies
 
@@ -165,10 +165,16 @@ class LlamaModel:
     def forward_hidden(self, params, input_ids, *, rngs=None, train: bool = False):
         c = self.config
         b, t = input_ids.shape
-        x = params["embed"].astype(self.compute_dtype)[input_ids]
+        top = gathered_top(params)     # ZeRO-3: the embedding, whole
+        x = top["embed"].astype(self.compute_dtype)[input_ids]
         cos, sin = rope_frequencies(c.head_dim, c.max_seq_len, c.rope_theta)
 
-        block_fn = self._block
+        def block_fn(x, blk, cos, sin, train):
+            # ZeRO-3 gathers inside what remat wraps; a closure of this
+            # call, because jax keeps a traced block by its function
+            blk = gathered(blk, "blocks", stacked=True)
+            return self._block(x, blk, cos, sin, train)
+
         if self.remat:
             from deepspeed_tpu.runtime.activation_checkpointing import checkpoint_policy
 
@@ -179,14 +185,14 @@ class LlamaModel:
             return block_fn(x, layer_params, cos, sin, train), None
 
         x, _ = jax.lax.scan(scan_body, x, params["blocks"])
-        return rms_norm(x, params["final_norm"], c.eps)
+        return rms_norm(x, top["final_norm"], c.eps)
 
     def logits(self, params, hidden):
         return jnp.einsum("btd,dv->btv", hidden, params["lm_head"].astype(hidden.dtype))
 
     def apply(self, params, batch, *, rngs=None, train: bool = False):
         hidden = self.forward_hidden(params, batch["input_ids"], rngs=rngs, train=train)
-        logits = self.logits(params, hidden)
+        logits = self.logits(gathered_top(params), hidden)
         loss, n = cross_entropy_loss(logits, batch["labels"])
         return loss, {"loss": loss, "ntokens": n}
 

@@ -29,7 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.models.base import (cache_positions, cross_entropy_loss,
-                                       gelu, layer_norm, layer_view, qdot)
+                                       gathered, gathered_top, gelu,
+                                       layer_norm, layer_view, qdot)
 from deepspeed_tpu.ops.attention import (alloc_kv_cache, cache_seq_len,
                                          cached_attention,
                                          multihead_attention,
@@ -364,9 +365,13 @@ class DecoderModel:
 
     def forward_hidden(self, params, input_ids, *, rngs=None, train=False):
         c = self.config
-        x = self._embed(params, input_ids, jnp.zeros((), jnp.int32))
+        # ZeRO-3 gathers what is used, where it is used: the embedding's
+        # leaves here, a layer's weights inside the (rematerialised) block
+        top = gathered_top(params)
+        x = self._embed(top, input_ids, jnp.zeros((), jnp.int32))
 
         def block_fn(x, blk, flag):
+            blk = gathered(blk, "blocks", stacked=True)
             return self._block_impl(x, blk, None, local_flag=flag)[0]
 
         if self.remat:
@@ -389,8 +394,7 @@ class DecoderModel:
 
             x, _ = jax.lax.scan(scan_body, x, params["blocks"])
         if c.final_ln:
-            x = layer_norm(x, params["ln_f_scale"], params["ln_f_bias"],
-                           c.eps)
+            x = layer_norm(x, top["ln_f_scale"], top["ln_f_bias"], c.eps)
         return x
 
     def logits(self, params, hidden):
@@ -409,7 +413,7 @@ class DecoderModel:
     def apply(self, params, batch, *, rngs=None, train=False):
         hidden = self.forward_hidden(params, batch["input_ids"], rngs=rngs,
                                      train=train)
-        logits = self.logits(params, hidden)
+        logits = self.logits(gathered_top(params), hidden)
         loss, n = cross_entropy_loss(logits, batch["labels"])
         return loss, {"loss": loss, "ntokens": n}
 

@@ -47,7 +47,7 @@ from deepspeed_tpu.runtime.precision import (
     global_grad_norm,
     has_inf_or_nan,
 )
-from deepspeed_tpu.runtime.zero.partition import PartitionPlan
+from deepspeed_tpu.runtime.zero.partition import PartitionPlan, stating_param_use
 from deepspeed_tpu.utils import groups as groups_mod
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (
@@ -419,6 +419,9 @@ class DeepSpeedEngine:
         self.master_specs = self.plan.master_specs(params_shape, self.logical_axes)
         self.compute_specs = self.plan.compute_specs(params_shape, self.logical_axes)
         self.grad_specs = self.plan.grad_specs(params_shape, self.logical_axes)
+        # stage 3's gathers, stated by the model where it uses a parameter
+        # (models/base.gathered); None when nothing is sharded for compute
+        self._param_use = self.plan.param_use(params_shape, self.logical_axes)
         mem_kind = "pinned_host" if (self.offload_optimizer and
                                      self.accelerator.name() == "tpu") else None
         self.master_shardings = self.plan.shardings(self.master_specs)
@@ -532,9 +535,10 @@ class DeepSpeedEngine:
                 jax.tree_util.tree_map(
                     lambda x: x.astype(self.compute_dtype)
                     if x.dtype == jnp.float32 else x, master_params)
-            loss, metrics = self.module.apply(cparams, batch,
-                                              rngs={"dropout": rng},
-                                              train=True, **kwargs)
+            with stating_param_use(self._param_use if constrain else None):
+                loss, metrics = self.module.apply(cparams, batch,
+                                                  rngs={"dropout": rng},
+                                                  train=True, **kwargs)
             return loss * scale, metrics
 
         # scopes name the fused step's parts in a device profile; they
@@ -1666,7 +1670,8 @@ class DeepSpeedEngine:
         if self._compiled_eval is None:
             def ev(params, batch):
                 cparams = self._cast_for_compute(params)
-                loss, metrics = self.module.apply(cparams, batch, rngs=None, train=False)
+                with stating_param_use(self._param_use):
+                    loss, metrics = self.module.apply(cparams, batch, rngs=None, train=False)
                 return loss
             self._compiled_eval = jax.jit(ev)
         batch = jax.device_put(batch, self._batch_shardings(batch))

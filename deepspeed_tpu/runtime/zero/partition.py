@@ -20,12 +20,24 @@ Mapping (see SURVEY.md §2.2):
   stage 2  as stage 1, but the grad pytree carries a sharded constraint so
            the backward pass lowers to ``reduce_scatter`` instead of
            all-reduce (average_tensor, stage_1_and_2.py:894).
-  stage 3  compute (bf16) params are *also* sharded: every use triggers an
-           XLA-scheduled all-gather which is freed after use — the compiler
-           plays the PartitionedParameterCoordinator's prefetch/release role
-           with overlap for free. Small params stay replicated below
-           ``param_persistence_threshold`` (mirroring persistent params,
-           partition_parameters.py).
+  stage 3  compute (bf16) params are *also* sharded, and the gather is
+           stated where a parameter is used: the model brings a layer's
+           slice to its *gathered* spec (``gathered_spec``: the TP/EP
+           entries alone) inside the rematerialised block of its layer
+           scan (``models/base.gathered``), so the compiler all-gathers one
+           layer's weights, frees them after the block, gathers again in
+           the backward pass, and reduce-scatters the cotangent to
+           ``grad_spec`` — the PartitionedParameterCoordinator's
+           fetch/release, scheduled by XLA. The sharded spec alone does
+           NOT do this: ZeRO puts 'data' on a feature dimension of a weight
+           and the batch is sharded over 'data' as well, so an unstated
+           use reads to the SPMD partitioner as tensor parallelism over
+           'data', and it moves the *activations* of every matmul
+           (all-to-all) instead of the weights — "overlap for free" was
+           56.8% of a GPT-2 XL step spent in exposed collectives on four
+           chips (PERF.md, PR 25 and PR 28). Small params stay replicated
+           below ``param_persistence_threshold`` (mirroring persistent
+           params, partition_parameters.py) and need no gather.
 
 Tensor parallelism composes orthogonally: logical-axis rules assign 'model'
 to hidden dimensions first; ZeRO then shards the largest remaining dimension
@@ -35,6 +47,7 @@ over 'data'. Offload (ZeRO-Offload/Infinity host residency) is handled in
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import re
 from typing import Any, Dict, Optional, Tuple
@@ -67,6 +80,37 @@ DEFAULT_LOGICAL_RULES: Dict[str, Optional[str]] = {
     "seq": None,
     "norm": None,
 }
+
+
+@dataclasses.dataclass(frozen=True)
+class ParamUse:
+    """Spec trees (keyed like the params) for the gather at the point of
+    use: a leaf whose ``compute`` spec differs from its ``gathered`` spec
+    is brought to the latter, and its cotangent to ``grad``."""
+
+    mesh: Any
+    compute: Any
+    gathered: Any
+    grad: Any
+
+
+_ACTIVE_USE: list = []
+
+
+@contextlib.contextmanager
+def stating_param_use(use: Optional[ParamUse]):
+    """While a model's ``apply`` is traced under this, ``models/base.
+    gathered`` states the gathers of ``use``; with None it stays the
+    identity (as it is outside any engine)."""
+    _ACTIVE_USE.append(use)
+    try:
+        yield
+    finally:
+        _ACTIVE_USE.pop()
+
+
+def active_param_use() -> Optional[ParamUse]:
+    return _ACTIVE_USE[-1] if _ACTIVE_USE else None
 
 
 @dataclasses.dataclass
@@ -139,6 +183,12 @@ class PartitionPlan:
             entries = self._add_zero_axis(entries, shape)
         return P(*entries)
 
+    def gathered_spec(self, shape: Tuple[int, ...],
+                      logical_axes: Optional[Tuple[str, ...]] = None) -> P:
+        """Sharding of a compute param where it is used: ``compute_spec``
+        with the ZeRO axis taken out (TP/EP entries stay)."""
+        return P(*self._tp_spec(shape, logical_axes))
+
     def grad_spec(self, shape: Tuple[int, ...],
                   logical_axes: Optional[Tuple[str, ...]] = None) -> P:
         """Sharding constraint on gradients: sharded from stage 2 up so the
@@ -166,6 +216,20 @@ class PartitionPlan:
 
     def grad_specs(self, params, logical_axes_tree=None):
         return self._tree_specs(params, logical_axes_tree, self.grad_spec)
+
+    def param_use(self, params, logical_axes_tree=None) -> Optional["ParamUse"]:
+        """What ``models/base.gathered`` needs to state stage 3's gathers,
+        or None when no compute param is sharded over the ZeRO axis (stage
+        below 3, a ZeRO axis of size 1, every leaf under the persistence
+        threshold): then there is nothing to gather."""
+        compute = self.compute_specs(params, logical_axes_tree)
+        gathered = self._tree_specs(params, logical_axes_tree, self.gathered_spec)
+        is_spec = lambda x: isinstance(x, P)
+        if jax.tree_util.tree_leaves(compute, is_leaf=is_spec) == \
+                jax.tree_util.tree_leaves(gathered, is_leaf=is_spec):
+            return None
+        return ParamUse(self.topology.mesh, compute, gathered,
+                        self.grad_specs(params, logical_axes_tree))
 
     def shardings(self, specs, memory_kind: Optional[str] = None):
         mesh = self.topology.mesh

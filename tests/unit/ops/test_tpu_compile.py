@@ -14,6 +14,7 @@ there; the last case reads the text for an uninitialised buffer.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -189,3 +190,77 @@ def test_in_program_kv_cache_is_zero_filled(one_chip):
     uninitialised = [ln for ln in text.splitlines()
                      if "AllocateBuffer" in ln and "bf16[2,1,12,128,64]" in ln]
     assert not uninitialised, uninitialised[0][:200]
+
+
+@pytest.fixture
+def mesh_data4(topo):
+    """data=4 over the four described chips, as the initialised topology."""
+    from deepspeed_tpu.parallel.topology import build_topology
+    from deepspeed_tpu.utils import groups
+
+    topology = build_topology(devices=list(topo.devices))
+    groups.initialize(topology)
+    yield topology
+    groups.reset()
+
+
+def test_zero3_layer_scan_gathers_weights_per_layer(mesh_data4):
+    """GPT-2 XL widths under ZeRO-3 on data=4, ``dots_no_batch`` remat, one
+    row of 512 a chip, as ``_micro_loss_and_grads`` builds it. Unstated, the
+    partitioner kept the weight shards in place and moved the activations of
+    every matmul (all-to-all, 56.8% of the step on the chip, PR 25); stated
+    inside the rematerialised block (``models/base.gathered``) it gathers a
+    layer's weights, and the scan saves none of them whole."""
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
+    from deepspeed_tpu.runtime.zero.partition import (PartitionPlan,
+                                                      stating_param_use)
+
+    d, mesh = 1600, mesh_data4.mesh
+    plan = PartitionPlan(topology=mesh_data4, zero_stage=3)
+
+    def compiled(layers):
+        model = GPT2Model(GPT2Config(num_layers=layers, hidden_size=d,
+                                     num_heads=25, loss_chunk=512),
+                          remat=True, remat_policy="dots_no_batch")
+        shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+        axes = model.logical_axes()
+        use = plan.param_use(shapes, axes)
+        grad_sh = plan.shardings(plan.grad_specs(shapes, axes))
+
+        def loss_and_grads(params, batch):
+            def loss(p):
+                with stating_param_use(use):
+                    return model.apply(p, batch, train=True)[0]
+
+            value, grads = jax.value_and_grad(loss)(params)
+            return value, jax.tree_util.tree_map(
+                jax.lax.with_sharding_constraint, grads, grad_sh)
+
+        params = jax.tree_util.tree_map(
+            lambda s, sh: _sds(sh, s.shape), shapes,
+            plan.shardings(plan.compute_specs(shapes, axes)))
+        ids = _sds(NamedSharding(mesh, plan.batch_spec(2)), (4, 512),
+                   jnp.int32)
+        return jax.jit(loss_and_grads).lower(
+            params, {"input_ids": ids, "labels": ids}).compile()
+
+    shallow, deep = compiled(2), compiled(6)
+    text = deep.as_text()
+    lines = text.splitlines()
+    # one all-to-all stays, outside the scan and cheaper than what it
+    # replaces: the embedding lookup's transpose sends each chip the
+    # quarter of the features it scatter-adds into its shard of wte's
+    # gradient (3 MB here, against a reduce-scatter of the 160 MB table)
+    moved = [ln for ln in lines if re.search(r" all-to-all(-start)?\(", ln)]
+    assert not [ln for ln in moved if "/while/" in ln], moved[0][:300]
+    assert all("scatter-add" in ln for ln in moved), moved
+    for whole in ("[1600,4800]", "[1600,6400]", "[6400,1600]", "[1600,1600]"):
+        assert any("all-gather" in ln and whole in ln.split(" all-gather")[0]
+                   for ln in lines), f"no all-gather of {whole}"
+    # a residual of gathered weights would be a stack of them, whole
+    for stack in ("[6,1600,6400]", "[6,6400,1600]", "[6,1600,4800]"):
+        assert stack not in text, f"the scan saves {stack}"
+    layer_bytes = 12 * d * d * 2          # one layer's weights, gathered
+    grown = (deep.memory_analysis().temp_size_in_bytes
+             - shallow.memory_analysis().temp_size_in_bytes) / 4
+    assert grown < 0.75 * layer_bytes, (grown, layer_bytes)
