@@ -6,12 +6,21 @@ from typing import Mapping
 
 
 def shapes(cfg: Mapping) -> dict:
-    """The sizes ``benchmarks/flops.py`` computes from."""
-    d = cfg["n_embd"]
-    return {"layers": cfg["n_layer"], "hidden": d, "heads": cfg["n_head"],
-            "head_dim": d // cfg["n_head"],
-            "mlp": cfg.get("n_inner") or 4 * d, "vocab": cfg["vocab_size"],
-            "positions": cfg["n_positions"]}
+    """The one place that translates the published keys: the sizes the kinds,
+    ``benchmarks/flops.py`` and the ``work`` modules compute from. ``params``
+    is every parameter of the published model (tied embedding, learned
+    positions, biases and norms); a dense model's are all active."""
+    d, layers = cfg["n_embd"], cfg["n_layer"]
+    m = cfg.get("n_inner") or 4 * d
+    vocab, positions = cfg["vocab_size"], cfg["n_positions"]
+    per_layer = 4 * d * d + 2 * d * m + (3 * d + d + m + d) + 4 * d
+    params = vocab * d + positions * d + layers * per_layer + 2 * d
+    if not cfg.get("tie_word_embeddings", True):
+        params += vocab * d
+    return {"layers": layers, "hidden": d, "heads": cfg["n_head"],
+            "kv_heads": cfg["n_head"], "head_dim": d // cfg["n_head"],
+            "mlp": m, "vocab": vocab, "positions": positions,
+            "params": params, "active_params": params}
 
 
 def tiny(cfg: Mapping) -> dict:

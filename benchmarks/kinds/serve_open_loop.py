@@ -14,6 +14,15 @@ drains.
 Every time is the engine's: ``RequestResult.arrival_time`` (when the request
 was due), ``admitted_time``, ``first_token_time``, ``token_times`` (stamped
 at token commit, after the fenced fetch).
+
+``correct``: a served token's reference logit may sit at most the mix's
+``check.logit_tol`` below the reference's best for that position (greedy
+decoding through the cache picks the argmax of the served logits), and by at
+most ``check.mean_gap_tol`` on average over the replayed positions: one far
+token and many near ones are different faults. The mix's ``check.why`` says
+where its numbers came from. Of a configuration this file
+reads ``family`` alone: sizes come from the family's ``shapes()``, and the
+reference takes its own hyper-parameters from the configuration's dict.
 """
 from __future__ import annotations
 
@@ -25,15 +34,8 @@ import numpy as np
 from benchmarks import harness, stats, traffic_gen
 
 DRAIN_LIMIT_S = 10.0
-# A served token's reference logit may sit this far below the reference's
-# best for that position. Greedy decoding in bf16 through the cache picks
-# the argmax of bf16 logits; random weights tie often, and at logits of 2 to
-# 4 one bf16 step is 2**-6. chip_smoke.py set 0.0625 (four steps) at 125M;
-# 36 layers of bf16 rounding at 1280 wide showed up to 0.07 on the chip
-# (PERF.md, PR 25), so six steps. An int8 weight path or a bf16 softmax
-# moves logits by 0.1 and more.
-GREEDY_LOGIT_TOL = 0.09375
-REPLAYED_REQUESTS = 4
+REPLAYED_REQUESTS = 16   # about 1,650 positions: the mean gap over 4 requests
+#                          spread twice as widely from seed to seed (PERF.md)
 
 
 def _rehearsal(server: Mapping, arrivals: Mapping):
@@ -55,9 +57,12 @@ def run(cell: Mapping, *, seed: int, seconds: float, trace: bool,
     family = harness.module("families", cfg["family"])
     reference = harness.module("reference", cfg["family"])
     server, arrivals = traffic["server"], traffic["arrivals"]
+    logit_tol = traffic["check"]["logit_tol"]
+    mean_gap_tol = traffic["check"]["mean_gap_tol"]
     if rehearse:
         cfg = family.tiny(cfg)
         server, arrivals = _rehearsal(server, arrivals)
+    shapes = family.shapes(cfg)
     guard = harness.device_guard(cell["chips"], rehearse=rehearse)
     if cell["chips"] != 1:
         raise ValueError("serve_open_loop drives one chip")
@@ -85,7 +90,7 @@ def run(cell: Mapping, *, seed: int, seconds: float, trace: bool,
     t_warm = time.perf_counter()
 
     planned = traffic_gen.open_loop_requests(
-        arrivals, seed=seed, seconds=seconds, vocab_size=cfg["vocab_size"])
+        arrivals, seed=seed, seconds=seconds, vocab_size=shapes["vocab"])
     for p in planned:
         srv.submit(Request(rid=p.rid, prompt=p.prompt,
                            max_new_tokens=p.max_new_tokens,
@@ -153,10 +158,9 @@ def run(cell: Mapping, *, seed: int, seconds: float, trace: bool,
     # ---- correct: replay served tokens, teacher-forced, through the plain
     # reference's full forward on the engine's weights (after the window)
     t_chk = time.perf_counter()
-    positions = cfg["n_positions"] if not rehearse else server["max_len"]
-    ref_fn = jax.jit(lambda p, x: reference.forward_logits(
-        p, x, n_head=cfg["n_head"], eps=cfg["layer_norm_epsilon"]))
-    worst, exact, checked = 0.0, 0, 0
+    positions = shapes["positions"] if not rehearse else server["max_len"]
+    ref_fn = jax.jit(lambda p, x: reference.forward_logits(p, x, cfg))
+    worst, gap_sum, exact, checked, replayed = 0.0, 0.0, 0, 0, []
     plan = {p.rid: p for p in planned}
     for r in done[:REPLAYED_REQUESTS]:
         prompt = plan[r.rid].prompt
@@ -168,8 +172,11 @@ def run(cell: Mapping, *, seed: int, seconds: float, trace: bool,
             rows, jnp.asarray(r.tokens)[:, None], -1)[:, 0]
         worst = max(worst, float(gap.max())) if bool(
             jnp.all(jnp.isfinite(rows))) else float("inf")
+        gap_sum += float(gap.sum())
         exact += int((gap == 0).sum())
         checked += len(r.tokens)
+        replayed.append([len(r.tokens), float(gap.max()), float(gap.sum())])
+    mean_gap = gap_sum / checked if checked else float("inf")
     check_s = time.perf_counter() - t_chk
 
     e2e = {"setup_s": setup_s, "serve_tokens_per_s": committed / seconds,
@@ -177,10 +184,6 @@ def run(cell: Mapping, *, seed: int, seconds: float, trace: bool,
     if gaps:
         e2e["itl_p95_ms"] = stats.percentile(gaps, 95.0) * 1e3
     counters = registry.snapshot()["counters"]
-
-    def counter(name):
-        return counters.get(name, 0)
-
     requests = [{"rid": r.rid, "prompt_len": r.prompt_len,
                  "arrival": r.arrival_time, "admitted": r.admitted_time,
                  "first_token": r.first_token_time,
@@ -188,8 +191,8 @@ def run(cell: Mapping, *, seed: int, seconds: float, trace: bool,
     spans = [] if tracer is None else [
         {"name": s.name, "start": s.start, "end": s.end} for s in tracer.spans]
     return {
-        "correct": (failed == 0 and checked > 0
-                    and worst <= GREEDY_LOGIT_TOL),
+        "correct": (failed == 0 and checked > 0 and worst <= logit_tol
+                    and mean_gap <= mean_gap_tol),
         "attempted": len(planned), "failed": failed,
         "end_to_end": e2e,
         "device": dict(guard["device"], memory_peak_bytes=harness.
@@ -199,11 +202,19 @@ def run(cell: Mapping, *, seed: int, seconds: float, trace: bool,
             "offered_rate_per_s": arrivals["rate"],
             "output_tokens_planned": sum(p.max_new_tokens for p in planned),
             "tokens_in_window": committed, "drain_s": drained_s,
-            "ttft_ms": {"p50": stats.median(ttft) * 1e3 if ttft else None,
+            # beside the judged 95th percentile, two steadier candidates
+            # from the same list, as evidence for a later choice (PERF.md)
+            "ttft_ms": {"p50": stats.median(ttft) * 1e3,
+                        "mean_p90_p99":
+                            stats.mean_between(ttft, 90.0, 99.0) * 1e3,
+                        "share_within_100ms":
+                            stats.share_within(ttft, 0.100),
                         "n": len(ttft)},
             "itl_ms": {"p50": stats.median(gaps) * 1e3 if gaps else None,
                        "n": len(gaps)},
-            "worst_logit_gap": worst, "logit_tol": GREEDY_LOGIT_TOL,
+            "worst_logit_gap": worst, "logit_tol": logit_tol,
+            "mean_logit_gap": mean_gap, "mean_gap_tol": mean_gap_tol,
+            "replayed": replayed,   # per request: tokens, largest gap, sum
             "exact_argmax": [exact, checked], "reference_check_s": check_s,
             "programs": programs_after,
             "setup_parts_s": {"import_and_guard": t_import - clock0,
@@ -212,15 +223,18 @@ def run(cell: Mapping, *, seed: int, seconds: float, trace: bool,
                                   setup_s - (t_warm - clock0)},
         },
         "observations": {
+            # every counter the program kept, under its registry name, and
+            # the short names of the first metric files beside them
             "counters": {
+                **counters,
                 "compiles_in_window": compiles,
-                "decode_steps": counter("serving/decode_steps"),
+                "decode_steps": counters.get("serving/decode_steps", 0),
                 "slot_iterations_active":
-                    counter("serving/slot_iterations_active"),
+                    counters.get("serving/slot_iterations_active", 0),
                 "num_slots": server["num_slots"],
             },
             "requests": requests, "spans": spans, "trace": reduced,
             "trace_span": trace_span, "peak": guard["peak"],
-            "shapes": family.shapes(cfg),
+            "shapes": shapes,
         },
     }

@@ -10,6 +10,13 @@ The window: steps are begun while less than ``--seconds`` have passed since
 the first; the window closes when the last of them is fenced. Every step ends
 inside it, so the rate is all tokens over all the time, with no whole step
 rounded away at the edge.
+
+``correct``: the largest |engine logit - reference logit| over the checked
+rows stays inside the mix's ``check.logit_tol`` (``check.why`` says where the
+number came from), the reference is finite and no step's loss is not. Of a
+configuration this file reads ``family`` alone: sizes come from the family's
+``shapes()``, and the reference takes its own hyper-parameters from the
+configuration's dict.
 """
 from __future__ import annotations
 
@@ -21,14 +28,6 @@ import numpy as np
 
 from benchmarks import flops, harness, traffic_gen
 
-# Largest |engine logit - reference logit| over the checked rows. The engine
-# runs bf16 activations and the flash kernel on fp32 master weights; the
-# reference is float32 at "highest" matmul precision on the same weights.
-# Logits of a freshly initialised GPT-2 lie within about +-4, where one bf16
-# step is 2**-6 = 0.016; 36 to 48 layers of bf16 rounding gave 0.02 to 0.05
-# on the chip (PERF.md, PR 25). An int8 matmul or a bf16 softmax moves logits
-# by 0.1 and more at these widths.
-LOGIT_ATOL = 0.08
 WARMUP_STEPS = 3   # compile, the engine's second trace of the step, one steady
 
 
@@ -48,9 +47,11 @@ def run(cell: Mapping, *, seed: int, seconds: float, trace: bool,
     family = harness.module("families", cfg["family"])
     reference = harness.module("reference", cfg["family"])
     job, options = traffic["job"], traffic["model_options"]
+    logit_tol = traffic["check"]["logit_tol"]
     if rehearse:
         cfg = family.tiny(cfg)
         job, options = _rehearsal(job, options)
+    shapes = family.shapes(cfg)
     guard = harness.device_guard(cell["chips"], rehearse=rehearse)
     t_import = time.perf_counter()
 
@@ -80,7 +81,7 @@ def run(cell: Mapping, *, seed: int, seconds: float, trace: bool,
         model=model, config=DeepSpeedConfig(conf, world_size=n_dev),
         topology=build_topology(devices=guard["devices"][:n_dev], dp=dp,
                                 tp=tp))
-    vocab = cfg["vocab_size"]
+    vocab = shapes["vocab"]
     data_rng = np.random.RandomState(traffic_gen.fold_seed(seed, 4))
 
     def make_batch():
@@ -98,8 +99,7 @@ def run(cell: Mapping, *, seed: int, seconds: float, trace: bool,
     params = engine.state.params
     eng_logits = jax.jit(lambda p, x: family.engine_logits(model, p, x))(
         params, jnp.asarray(ids)).astype(jnp.float32)
-    ref_fn = jax.jit(lambda p, x: reference.forward_logits(
-        p, x, n_head=cfg["n_head"], eps=cfg["layer_norm_epsilon"]))
+    ref_fn = jax.jit(lambda p, x: reference.forward_logits(p, x, cfg))
     logit_err = 0.0
     for r in range(ids.shape[0]):  # a row at a time: the reference is float32
         ref = ref_fn(params, jnp.asarray(ids[r:r + 1]))
@@ -144,13 +144,16 @@ def run(cell: Mapping, *, seed: int, seconds: float, trace: bool,
             reduced = prof.reduce()
 
     rate = tokens / window_s
-    shapes = family.shapes(cfg)
     flops_tok = flops.train_flops_per_token(shapes, seq)
     mfu = rate * flops_tok / (n_dev * guard["peak"]["bf16_tflops"] * 1e12)
     peak_bytes = harness.memory_peak_bytes(guard["devices"][:n_dev])
+    # whatever the engine counted (its telemetry is on unless the mix's
+    # engine_config turns it off; this file turns nothing on or off)
+    registry = getattr(engine, "telemetry", None)
+    counters = {} if registry is None else registry.snapshot()["counters"]
     engine.destroy()
     return {
-        "correct": bool(finite_ref and logit_err <= LOGIT_ATOL
+        "correct": bool(finite_ref and logit_err <= logit_tol
                         and failed == 0),
         "attempted": steps, "failed": failed,
         "end_to_end": {"train_tokens_per_s": rate, "setup_s": setup_s},
@@ -159,7 +162,7 @@ def run(cell: Mapping, *, seed: int, seconds: float, trace: bool,
             "steps": steps, "window_s": window_s,
             "mean_step_s": window_s / steps,
             "loss_first_last": [loss_values[0], loss_values[-1]],
-            "logit_max_abs_err": logit_err, "logit_atol": LOGIT_ATOL,
+            "logit_max_abs_err": logit_err, "logit_tol": logit_tol,
             "flops_per_token": flops_tok, "model_flops_utilisation": mfu,
             "setup_parts_s": {"import_and_guard": t_import - clock0,
                               "build_and_warmup": t_warm - t_import,
@@ -167,7 +170,7 @@ def run(cell: Mapping, *, seed: int, seconds: float, trace: bool,
                               "first_batch": t0 - t_check},
         },
         "observations": {
-            "counters": {"compiles_in_window": compiles},
+            "counters": {**counters, "compiles_in_window": compiles},
             "trace": reduced, "peak": guard["peak"], "shapes": shapes,
             "train": {"rows_per_device_step": micro * gas, "seq_len": seq,
                       "traced_steps": job["trace_steps"]},

@@ -1,5 +1,8 @@
 """Share of the traced window spent in collective operations while nothing
-else ran on that device, in percent. No params."""
+else ran on that device, in percent: events whose result name is a
+collective's (``trace_reduce.COLLECTIVE``), the wait for an asynchronous one
+included; an operation that only reads a collective's result is compute.
+No params."""
 from benchmarks import trace_reduce
 
 

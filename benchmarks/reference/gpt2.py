@@ -24,8 +24,10 @@ def _gelu_new(x):
         jnp.sqrt(2.0 / jnp.pi) * (x + 0.044715 * x ** 3)))
 
 
-def forward_logits(params, input_ids, *, n_head: int, eps: float):
-    """``[B, T]`` token ids to ``[B, T, V]`` float32 logits."""
+def forward_logits(params, input_ids, cfg):
+    """``[B, T]`` token ids to ``[B, T, V]`` float32 logits. ``cfg`` is the
+    configuration file's dict under its published keys."""
+    n_head, eps = cfg["n_head"], cfg["layer_norm_epsilon"]
     with jax.default_matmul_precision("highest"):
         f32 = lambda a: jnp.asarray(a, jnp.float32)  # noqa: E731
         b, t = input_ids.shape
@@ -57,9 +59,9 @@ def forward_logits(params, input_ids, *, n_head: int, eps: float):
         return x @ head
 
 
-def loss(params, input_ids, labels, *, n_head: int, eps: float):
+def loss(params, input_ids, labels, cfg):
     """Mean next-token cross entropy of ``labels`` under the logits."""
-    logits = forward_logits(params, input_ids, n_head=n_head, eps=eps)
+    logits = forward_logits(params, input_ids, cfg)
     logp = jax.nn.log_softmax(logits, axis=-1)
     picked = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
     return -picked.mean()

@@ -24,6 +24,21 @@ def median(values: Sequence[float]) -> float:
     return percentile(values, 50.0)
 
 
+def mean_between(values: Sequence[float], q_lo: float, q_hi: float) -> float:
+    """Mean of the values from the ``q_lo``-th to the ``q_hi``-th percentile,
+    both ends included: a tail read from many samples, not from one rank."""
+    lo, hi = percentile(values, q_lo), percentile(values, q_hi)
+    inside = [v for v in values if lo <= v <= hi]
+    return sum(inside) / len(inside)
+
+
+def share_within(values: Sequence[float], limit: float) -> float:
+    """Share of the values at or under ``limit``, 0..1."""
+    if not values:
+        raise ValueError("share of no values")
+    return sum(1 for v in values if v <= limit) / len(values)
+
+
 def times_to_first_token(due: Mapping[int, float],
                          first_token: Mapping[int, float],
                          gave_up: float) -> List[float]:

@@ -24,8 +24,13 @@ Interval = Tuple[float, float]
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 WINDOW_ANNOTATION = "bench/window"
+# A device event is named by its whole HLO instruction, which also names its
+# operands: only the result name, at the start, says what ran. The last name
+# is the TPU's wait for a collective it started earlier and split into a
+# matmul (``%async-collective-done.4 = ... fusion(...)``).
 COLLECTIVE = re.compile(
-    r"all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute")
+    r"^%[\w.\-]*(all-gather|all-reduce|reduce-scatter|all-to-all"
+    r"|collective-permute|async-collective-done)")
 
 
 @dataclasses.dataclass
@@ -178,7 +183,8 @@ def matched_seconds(trace: Trace, pattern: str) -> float:
 
 
 def exposed_collective_seconds(trace: Trace) -> float:
-    """Seconds inside collective operations during which no other operation
+    """Seconds inside collective operations (``COLLECTIVE``: by result name,
+    waits for asynchronous ones included) during which no other operation
     ran on that device, averaged over the devices. Parents that only wrap
     other events (control flow) are neither collective nor compute."""
     acc = 0.0
