@@ -120,3 +120,158 @@ def test_flash_composes_with_tp_and_zero(tp, stage):
              "labels": ids[:, :, 1:].astype(np.int32)}
         losses.append(float(jax.device_get(engine.train_batch_from_stacked(b))))
     assert losses[-1] < losses[0], losses
+
+
+# ----------------------------------------------- the tile program's choices
+def _walk_counts():
+    from deepspeed_tpu.telemetry.registry import get_registry
+
+    reg = get_registry()
+    return (reg.counter("flash/traced_short_seq").value,
+            reg.counter("flash/traced_grid_walk").value)
+
+
+def _flash_tiles(q, k, v, do, causal, block_q, block_k, walk_budget=None):
+    """Forward and backward through the internal tile-layout calls, the way
+    ``flash_attention`` makes them, with the walk side's VMEM budget forced
+    where a case wants the chunked walk at a tiny size. [B, T, H, Dh] in and
+    out: (out, dq, dk, dv), and the rows a tile that the shapes chose."""
+    from deepspeed_tpu.ops import flash_attention as fa
+
+    b, t, h, dh = q.shape
+    rows = fa._tile_rows(b * h, dh, t)
+    qp, kp, vp, dop = (fa._pack(fa._reshape_bh(x), rows)
+                       for x in (q, k, v, do))
+    kw = dict(rows=rows, causal=causal, scale=dh ** -0.5, block_q=block_q,
+              block_k=block_k, interpret=True)
+    if walk_budget is not None:
+        kw["walk_budget"] = walk_budget
+    outp, lse = fa._fwd_tiles(qp, kp, vp, **kw)
+    delta = fa._delta_tiles(dop, outp, rows, lse.shape[-1])
+    grads = fa._bwd_tiles(qp, kp, vp, dop, lse, delta, **kw)
+    return [fa._unshape_bh(fa._unpack(x, rows), b, h)
+            for x in (outp,) + tuple(grads)], rows
+
+
+def _dense_with_grads(q, k, v, do, causal):
+    out, vjp = jax.vjp(
+        lambda q, k, v: multihead_attention(q, k, v, causal=causal), q, k, v)
+    return [out, *vjp(do)]
+
+
+def _assert_matches_dense(got, want):
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=2e-5, atol=2e-5, err_msg="out")
+    for name, a, b in zip(("dq", "dk", "dv"), got[1:], want[1:]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-4, err_msg=name)
+
+
+# name, (b, tq, tk, h, dh), blocks, walk budget in bytes, rows a tile, walk
+TILE_CASES = [
+    # gpt2-xl's 50 rows in small: 2 x 5 heads pair across the batch boundary
+    ("paired_across_batch", (2, 64, 64, 5, 16), (32, 32), None, 2, "short"),
+    ("odd_rows_fall_back", (1, 64, 64, 3, 16), (32, 32), None, 1, "short"),
+    ("head_size_128", (1, 64, 64, 2, 128), (32, 32), None, 1, "short"),
+    ("blocks_differ", (2, 128, 128, 2, 16), (32, 64), None, 2, "short"),
+    ("wide_query_block", (2, 128, 128, 2, 16), (64, 32), None, 2, "short"),
+    # K and V (2 x 128 x 32 floats = 32 KB) above a budget of 8 KB: chunks
+    ("above_budget", (2, 128, 128, 2, 16), (32, 32), 8192, 2, "grid"),
+    ("above_budget_one_row", (1, 128, 128, 3, 16), (32, 32), 4096, 1, "grid"),
+]
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize("name,dims,blocks,budget,rows,walk", TILE_CASES,
+                         ids=[c[0] for c in TILE_CASES])
+def test_tile_program_matches_dense(name, dims, blocks, budget, rows, walk,
+                                    causal):
+    b, tq, tk, h, dh = dims
+    q, _, _ = qkv(b=b, t=tq, h=h, dh=dh, seed=5)
+    k, v, _ = qkv(b=b, t=tk, h=h, dh=dh, seed=6)
+    do, _, _ = qkv(b=b, t=tq, h=h, dh=dh, seed=7)
+    short0, grid0 = _walk_counts()
+    got, got_rows = _flash_tiles(q, k, v, do, causal, *blocks,
+                                 walk_budget=budget)
+    short1, grid1 = _walk_counts()
+    assert got_rows == rows
+    # three kernels a call, each says which walk it was traced with
+    assert (short1 - short0, grid1 - grid0) == \
+        ((3, 0) if walk == "short" else (0, 3))
+    _assert_matches_dense(got, _dense_with_grads(q, k, v, do, causal))
+
+
+@pytest.mark.parametrize("budget", [None, 8192], ids=["short", "grid"])
+def test_ring_hop_keys_longer_than_queries(budget):
+    """A ring hop: not causal, 64 queries against 128 keys."""
+    q, _, do = qkv(b=2, t=64, h=3, dh=16, seed=8)
+    k, v, _ = qkv(b=2, t=128, h=3, dh=16, seed=9)
+    got, rows = _flash_tiles(q, k, v, do, False, 32, 32, walk_budget=budget)
+    assert rows == 2
+    _assert_matches_dense(got, _dense_with_grads(q, k, v, do, False))
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_all_walks_agree(causal, monkeypatch):
+    """The same inputs and blocks down the resident walk unrolled by hand,
+    down the resident walk as a fori_loop (a row of more blocks than are
+    unrolled) and down the chunked one: only the loop, and who carries the
+    softmax state, differ."""
+    from deepspeed_tpu.ops import flash_attention as fa
+
+    q, k, v = qkv(b=2, t=128, h=5, dh=16, seed=10)
+    do, _, _ = qkv(b=2, t=128, h=5, dh=16, seed=11)
+    unrolled, _ = _flash_tiles(q, k, v, do, causal, 32, 32)
+    chunked, _ = _flash_tiles(q, k, v, do, causal, 32, 32, walk_budget=4096)
+    monkeypatch.setattr(fa, "_MAX_UNROLL", 4)    # the row has 16 blocks
+    monkeypatch.setattr(fa, "_MAX_TILE", 64)     # and two tiles of queries
+    looped, _ = _flash_tiles(q, k, v, do, causal, 32, 32)
+    _assert_matches_dense(looped, _dense_with_grads(q, k, v, do, causal))
+    for other in (looped, chunked):
+        for name, a, b in zip(("out", "dq", "dk", "dv"), unrolled, other):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-6, atol=1e-6, err_msg=name)
+
+
+@pytest.mark.parametrize("b,h", [(2, 5), (1, 3)], ids=["paired", "one_row"])
+def test_public_call_pairs_rows_over_batch_times_heads(b, h):
+    """flash_attention itself on gpt2-xl's row pattern, default blocks."""
+    q, k, v = qkv(b=b, t=64, h=h, dh=16, seed=12)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v) ** 2)
+
+    flash = loss(lambda q, k, v: flash_attention(q, k, v, True, None, None,
+                                                 None, True))
+    dense = loss(lambda q, k, v: multihead_attention(q, k, v, causal=True))
+    short0, grid0 = _walk_counts()
+    g1 = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
+    short1, grid1 = _walk_counts()
+    assert short1 > short0 and grid1 == grid0
+    g2 = jax.grad(dense, argnums=(0, 1, 2))(q, k, v)
+    for name, a, b_ in zip("qkv", g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=2e-4,
+                                   atol=2e-4, err_msg=f"d{name}")
+
+
+def test_parts_keep_their_flat_contract():
+    """ring attention's building blocks: flat [BH, T, Dh] operands, lse
+    [BH, T, 1] float32 and global."""
+    from deepspeed_tpu.ops.flash_attention import (flash_bwd_parts,
+                                                   flash_fwd_parts)
+
+    rng = np.random.RandomState(13)
+    qf, kf, vf, dof = (jnp.asarray(rng.randn(10, 64, 16), jnp.float32) * 0.5
+                       for _ in range(4))
+    out, lse = flash_fwd_parts(qf, kf, vf, causal=True, interpret=True)
+    assert out.shape == (10, 64, 16) and lse.shape == (10, 64, 1)
+    assert lse.dtype == jnp.float32
+    s = jnp.einsum("bqd,bkd->bqk", qf, kf) * 16 ** -0.5
+    s = jnp.where(jnp.tril(jnp.ones((64, 64), bool)), s, -jnp.inf)
+    np.testing.assert_allclose(np.asarray(lse[..., 0]),
+                               np.asarray(jax.nn.logsumexp(s, axis=-1)),
+                               rtol=2e-5, atol=2e-5)
+    delta = jnp.sum(dof * out, axis=-1, keepdims=True)
+    dq, dk, dv = flash_bwd_parts(qf, kf, vf, dof, lse, delta, causal=True,
+                                 interpret=True)
+    assert dq.shape == dk.shape == dv.shape == (10, 64, 16)

@@ -53,12 +53,18 @@ def _compiled_text(fn, *args):
 def _flash_loss(q, k, v):
     from deepspeed_tpu.ops.flash_attention import flash_attention
 
-    out = flash_attention(q, k, v, True, None, 512, 512, False)
+    # blocks and walk as the shapes choose them: what the cells run
+    out = flash_attention(q, k, v, True, None, None, None, False)
     return jnp.sum(out.astype(jnp.float32))
 
 
 # (B, T, H, Dh): GPT-2 125M at chip_smoke's micro-batch; LLaMA-7B widths
-FLASH_SHAPES = [(8, 1024, 12, 64), (2, 2048, 32, 128)]
+# (one row a tile); the train cells' micro-batches, gpt2-large's 40 rows and
+# gpt2-xl's 50 (25 heads: rows pair across the batch boundary); the longest
+# sequence whose K and V stay resident in VMEM, 8 MiB of it double-buffered;
+# and one that walks them in chunks
+FLASH_SHAPES = [(8, 1024, 12, 64), (2, 2048, 32, 128), (2, 1024, 20, 64),
+                (2, 1024, 25, 64), (1, 8192, 8, 64), (1, 16384, 4, 64)]
 
 
 @pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
