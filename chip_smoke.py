@@ -226,10 +226,10 @@ class Smoke:
         from deepspeed_tpu.utils import groups
 
         cfg = self.model_cfg()
-        # block_size 128, as bench.py serves 125M on the chip: at Dh=64 the
-        # pool is token-pair packed only when a block is 128-aligned
-        # (ops/attention.alloc_kv_cache), and only a packed pool routes to
-        # fused_block_decode_step; the default 16 takes gather + einsum.
+        # block_size 128: at Dh=64 the pool is token-pair packed only when
+        # a block is 128-aligned (ops/attention.alloc_kv_cache), and only a
+        # packed pool routes to fused_block_decode_step; the default 16
+        # takes gather + einsum.
         block_size = 128
         if self.rehearse:
             slots, max_len, buckets = 4, 256, (16, 64)

@@ -1127,15 +1127,14 @@ class InferenceEngine:
                           *, do_sample: bool = False, top_k: int = 0,
                           top_p: float = 1.0):
         """The (prefill, decode) jitted programs generate() uses for this
-        shape — built on demand. For benches that time the programs
-        directly (PROFILE_DECODE.md methodology) without reconstructing
-        the private cache keys. Greedy/eos-free only (decode is the scan
+        shape — built on demand, for a caller that times or lowers the
+        programs directly without reconstructing the private cache keys. Greedy/eos-free only (decode is the scan
         program; the eos path's while-loop program is not exposed).
 
         NOTE: the decode program DONATES its cache argument
         (donate_argnums=(2,)) — a second dec() call on the same cache
         hits a deleted-buffer error; run the prefill program again per
-        decode invocation, as bench.py does."""
+        decode invocation."""
         self._build_generate(batch, prompt_len, max_new,
                              do_sample=do_sample, top_k=top_k,
                              top_p=float(top_p), eos_token_id=None,

@@ -172,9 +172,9 @@ def qdot(eq, x, w):
 
 def embed_tokens(wte, input_ids, dtype):
     """Token-embedding gather whose table may be weight-only-int8
-    ``{"__q__", "__scale__"}`` with PER-VOCAB-ROW scales (ISSUE 12
-    satellite — the tied embedding was the deliberately-unquantized 77
-    MB of the 125M int8 stream, PROFILE_DECODE.md). The row gather
+    ``{"__q__", "__scale__"}`` with PER-VOCAB-ROW scales (the tied
+    embedding was the deliberately-unquantized 77 MB of the 125M int8
+    weight stream). The row gather
     stays int8 (1 byte/element of HBM traffic) and each row's single
     scale multiplies after the gather — an EXACT dequantization per
     row, so embedding lookups carry no extra error beyond the row's
@@ -321,11 +321,12 @@ def gathered(tree, *path, stacked: bool = False):
     return walk(tree, specs)
 
 
-def gathered_top(params):
-    """:func:`gathered` for what lies outside the layer stack (embeddings,
-    final norm, head), at the place that uses some of it: what is not used
-    there is dead code to the compiler."""
-    return gathered({k: v for k, v in params.items() if k != "blocks"})
+def gathered_top(params, *stacks: str):
+    """:func:`gathered` for what lies outside the model's layer ``stacks``
+    (embeddings, final norm, head; models/stack.py gathers a stack a layer at
+    a time), at the place that uses some of it: what is not used there is
+    dead code to the compiler."""
+    return gathered({k: v for k, v in params.items() if k not in stacks})
 
 
 def cross_entropy_loss(logits, labels, ignore_index: int = -100):

@@ -21,7 +21,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import cross_entropy_loss, gathered, gathered_top, layer_norm, qdot
+from deepspeed_tpu.models.base import cross_entropy_loss, gathered_top, layer_norm, qdot
+from deepspeed_tpu.models.stack import walk, wrapped_block
 from deepspeed_tpu.ops.attention import multihead_attention
 
 _ACTS = {
@@ -191,7 +192,7 @@ class BertModel:
                        token_type_ids=None, *, rngs=None, train=False):
         c = self.config
         b, t = input_ids.shape
-        emb = gathered_top(params)
+        emb = gathered_top(params, "blocks")
         x = emb["wte"].astype(self.compute_dtype)[input_ids]
         x = x + emb["wpe"].astype(self.compute_dtype)[:t][None]
         if c.type_vocab_size > 0:
@@ -205,20 +206,9 @@ class BertModel:
             # [B, 1, 1, T] boolean: key positions that may be attended
             mask_bias = attention_mask[:, None, None, :].astype(bool)
 
-        def block_fn(x, blk, mask_bias):
-            # ZeRO-3 gathers inside what remat wraps; a closure of this
-            # call, because jax keeps a traced block by its function
-            return self._block(x, gathered(blk, "blocks", stacked=True),
-                               mask_bias)
-
-        if self.remat:
-            block_fn = jax.checkpoint(block_fn)
-
-        def scan_body(x, blk):
-            return block_fn(x, blk, mask_bias), None
-
-        x, _ = jax.lax.scan(scan_body, x, params["blocks"])
-        return x
+        # "nothing": save nothing, whatever policy the engine configured
+        block_fn = wrapped_block(self._block, "blocks", self.remat, "nothing")
+        return walk(block_fn, x, params["blocks"], mask_bias)
 
     def pooled(self, params, hidden):
         """act(dense(CLS)) — tanh (reference BertPooler) or relu
@@ -250,7 +240,7 @@ class BertModel:
         hidden = self.forward_hidden(
             params, batch["input_ids"], batch.get("attention_mask"),
             batch.get("token_type_ids"), rngs=rngs, train=train)
-        logits = self.logits(gathered_top(params), hidden)
+        logits = self.logits(gathered_top(params, "blocks"), hidden)
         labels = batch["labels"]
         if self.head == "mlm":
             loss, n = cross_entropy_loss(logits, labels)

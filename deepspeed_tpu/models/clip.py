@@ -20,7 +20,8 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import gathered, gathered_top, layer_norm
+from deepspeed_tpu.models.base import gathered_top, layer_norm
+from deepspeed_tpu.models.stack import walk, wrapped_block
 from deepspeed_tpu.ops.attention import multihead_attention
 
 _ACTS = {
@@ -130,14 +131,10 @@ class CLIPTextModel:
     def forward_hidden(self, params, input_ids, *, rngs=None, train=False):
         c = self.config
         t = input_ids.shape[1]
-        top = gathered_top(params)
+        top = gathered_top(params, "blocks")
         x = top["wte"].astype(self.compute_dtype)[input_ids]
         x = x + top["wpe"].astype(self.compute_dtype)[:t][None]
-
-        def scan_body(x, blk):
-            return self._block(x, gathered(blk, "blocks", stacked=True)), None
-
-        x, _ = jax.lax.scan(scan_body, x, params["blocks"])
+        x = walk(wrapped_block(self._block, "blocks"), x, params["blocks"])
         return layer_norm(x, top["ln_f_scale"], top["ln_f_bias"], c.eps)
 
     def pooled(self, params, hidden, input_ids):

@@ -3,7 +3,7 @@
 Design notes (vs. the reference's per-module torch GPT-2 used in its tests
 and the fused ``csrc/transformer`` training kernel, SURVEY §2.4):
   * all transformer blocks are *stacked* on a leading 'layer' dimension and
-    executed with ``lax.scan`` — one compiled block, L iterations; this is
+    executed as a scan (models/stack.py) — one compiled block, L iterations;
     the XLA-idiomatic form that keeps compile time flat in depth and lets
     ZeRO-3 shard the layer dimension.
   * activations/matmuls run in the engine's compute dtype (bf16); softmax,
@@ -20,8 +20,9 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import ATTN_IMPLS, cache_positions, cross_entropy_loss, embed_tokens, gathered, gathered_top, gelu, layer_norm, layer_view, qdot, sp_attention, tied_logits
-from deepspeed_tpu.ops.attention import alloc_kv_cache, cached_attention, multihead_attention
+from deepspeed_tpu.models.base import ATTN_IMPLS, cache_positions, cross_entropy_loss, embed_tokens, gathered_top, gelu, layer_norm, qdot, sp_attention, tied_logits
+from deepspeed_tpu.models.stack import cached_walk, kv_cache, next_cache, wrapped_block
+from deepspeed_tpu.ops.attention import cached_attention, multihead_attention
 
 
 @dataclasses.dataclass
@@ -82,13 +83,9 @@ class GPT2Model:
 
     def __init__(self, config: GPT2Config, compute_dtype=jnp.bfloat16,
                  remat: bool = False, remat_policy: Optional[str] = None,
-                 attn_impl: str = "dense", decode_unroll: int = 1):
+                 attn_impl: str = "dense"):
         self.config = config
         self.compute_dtype = compute_dtype
-        # layer-scan unroll factor for single-token decode steps: unrolling
-        # lets XLA overlap consecutive layers' weight DMAs with compute
-        # (per-layer matmuls are tiny at decode, so HBM latency dominates)
-        self.decode_unroll = decode_unroll
         self.remat = remat
         self.remat_policy = remat_policy
         assert attn_impl in ATTN_IMPLS, attn_impl
@@ -153,13 +150,14 @@ class GPT2Model:
         return axes
 
     # ------------------------------------------------------------------ layers
-    def _block_impl(self, x, blk, rng, train: bool, cache):
-        """One transformer block; with ``cache=(k_full, v_full, layer, idx)``
-        the attention runs against the KV cache (one shared implementation so
-        training and serving can never diverge numerically). ``k_full`` /
-        ``v_full`` are the FULL stacked head-major [L, B, H, S, Dh] caches:
-        only the new token's slice is written (in place, as a loop-carry
-        dynamic update) — never the whole cache (see
+    def _block(self, x, blk, kv=None, layer=None, idx=None, bt=None, *,
+               rng=None, train: bool = False):
+        """One transformer block -> ``(x, kv)``; with ``kv=(k_full, v_full)``
+        the attention runs against the KV cache at ``layer`` and ``idx`` (one
+        shared implementation so training and serving can never diverge
+        numerically). ``k_full`` / ``v_full`` are the FULL stacked head-major
+        [L, B, H, S, Dh] caches: only the new token's slice is written (in
+        place, as a loop-carry dynamic update) — never the whole cache (see
         ops/attention.decode_attention)."""
         c = self.config
         b, t, d = x.shape
@@ -173,7 +171,7 @@ class GPT2Model:
         q = q.reshape(b, t, h, dh)
         k_ = k_.reshape(b, t, h, dh)
         v_ = v_.reshape(b, t, h, dh)
-        if cache is None:
+        if kv is None:
             if self.attn_impl != "dense":
                 attn = sp_attention(self.attn_impl, q, k_, v_)
             else:
@@ -183,12 +181,10 @@ class GPT2Model:
                 attn = multihead_attention(q, k_, v_, causal=True,
                                            dropout_rate=c.dropout if train else 0.0,
                                            dropout_rng=drop_rng)
-            kc = vc = None
         else:
-            kc, vc, layer, idx, *rest = cache
-            attn, kc, vc = cached_attention(
-                q, kc, vc, k_, v_, layer, idx,
-                block_table=rest[0] if rest else None)
+            attn, kc, vc = cached_attention(q, *kv, k_, v_, layer, idx,
+                                            block_table=bt)
+            kv = (kc, vc)
         attn = attn.reshape(b, t, d)
         x = x + qdot("btd,de->bte", attn, blk["attn_out_w"]) + \
             blk["attn_out_b"].astype(x.dtype)
@@ -197,32 +193,18 @@ class GPT2Model:
                     blk["mlp_fc_b"].astype(y.dtype))
         x = x + qdot("btm,md->btd", hmid, blk["mlp_out_w"]) + \
             blk["mlp_out_b"].astype(x.dtype)
-        return x, kc, vc
-
-    def _block(self, x, blk, rng, train: bool):
-        return self._block_impl(x, blk, rng, train, None)[0]
+        return x, kv
 
     def forward_hidden(self, params, input_ids, *, rngs=None, train: bool = False,
                        pld_theta=None, ltd_keep=None):
         c = self.config
         b, t = input_ids.shape
-        top = gathered_top(params)     # ZeRO-3: the embeddings, whole
+        top = gathered_top(params, "blocks")   # ZeRO-3: the embeddings, whole
         x = embed_tokens(top["wte"], input_ids, self.compute_dtype)
         x = x + top["wpe"].astype(self.compute_dtype)[:t][None]
-
-        def block_fn(x, blk, rng, train):
-            # ZeRO-3 gathers the layer's weights inside what remat wraps,
-            # so the backward pass gathers them again. A closure of this
-            # call, not the bound method: jax keeps a traced block by its
-            # function, and what ``gathered`` states is the engine's
-            blk = gathered(blk, "blocks", stacked=True)
-            return self._block(x, blk, rng, train)
-
-        if self.remat:
-            from deepspeed_tpu.runtime.activation_checkpointing import checkpoint_policy
-
-            block_fn = jax.checkpoint(block_fn, policy=checkpoint_policy(self.remat_policy),
-                                      static_argnums=(3,))
+        block_fn = wrapped_block(
+            lambda x, blk, rng: self._block(x, blk, rng=rng, train=train)[0],
+            "blocks", self.remat, self.remat_policy)
 
         rng0 = rngs.get("dropout") if isinstance(rngs, dict) else rngs
         if (ltd_keep is not None and train and ltd_keep < t
@@ -244,18 +226,18 @@ class GPT2Model:
             last = jax.tree_util.tree_map(lambda p: p[-1], params["blocks"])
             mid = jax.tree_util.tree_map(lambda p: p[1:-1], params["blocks"])
             rng0, sub = jax.random.split(rng0)
-            x = block_fn(x, first, sub, train)
+            x = block_fn(x, first, sub)
 
             def ltd_body(carry, blk):
                 x, rng = carry
                 rng, r_idx, r_blk = jax.random.split(rng, 3)
                 idx = sample_token_indices(r_idx, b, t, ltd_keep)
-                kept = block_fn(gather_tokens(x, idx), blk, r_blk, train)
+                kept = block_fn(gather_tokens(x, idx), blk, r_blk)
                 return (scatter_tokens(x, kept, idx), rng), None
 
             (x, rng0), _ = jax.lax.scan(ltd_body, (x, rng0), mid)
             rng0, sub = jax.random.split(rng0)
-            x = block_fn(x, last, sub, train)
+            x = block_fn(x, last, sub)
             return layer_norm(x, top["ln_f_scale"], top["ln_f_bias"], c.eps)
 
         use_pld = pld_theta is not None and train
@@ -268,7 +250,7 @@ class GPT2Model:
                 rng, sub = jax.random.split(rng)
             else:
                 sub = None
-            x_new = block_fn(x, layer_params, sub, train)
+            x_new = block_fn(x, layer_params, sub)
             if use_pld:
                 # stochastic depth (progressive layer drop): keep prob anneals
                 # linearly in depth from 1 to theta; expectation-preserving
@@ -284,8 +266,7 @@ class GPT2Model:
                 x = x_new
             return (x, rng), None
 
-        rng = rngs.get("dropout") if isinstance(rngs, dict) else rngs
-        (x, _), _ = jax.lax.scan(scan_body, (x, rng),
+        (x, _), _ = jax.lax.scan(scan_body, (x, rng0),
                                  (params["blocks"], layer_idx))
         return layer_norm(x, top["ln_f_scale"], top["ln_f_bias"], c.eps)
 
@@ -300,7 +281,7 @@ class GPT2Model:
                                      train=train, pld_theta=pld_theta,
                                      ltd_keep=ltd_keep)
         c = self.config
-        head = gathered_top(params)    # gathered again, for the loss head
+        head = gathered_top(params, "blocks")  # gathered again, for the head
         if c.loss_chunk:
             from deepspeed_tpu.runtime.zero.tiling import (
                 chunked_cross_entropy)
@@ -316,19 +297,11 @@ class GPT2Model:
     # --------------------------------------------------------- inference path
     def init_cache(self, batch_size: int, max_len: int, dtype=None):
         """Static-shape KV cache (the inference_context.h workspace analog —
-        reference csrc/transformer/inference/includes/inference_context.h).
-        Head-major, token-pair packed for Dh < 128 — see
-        ops/attention.kv_pack_factor / decode_attention."""
+        reference csrc/transformer/inference/includes/inference_context.h):
+        models/stack.kv_cache."""
         c = self.config
-        dtype = dtype or self.compute_dtype
-        return {"k": alloc_kv_cache(c.num_layers, batch_size, c.num_heads,
-                                    max_len, c.head_dim, dtype),
-                "v": alloc_kv_cache(c.num_layers, batch_size, c.num_heads,
-                                    max_len, c.head_dim, dtype),
-                "index": jnp.zeros((), jnp.int32)}
-
-    def _block_cached(self, x, blk, kc, vc, layer, idx, bt):
-        return self._block_impl(x, blk, None, False, (kc, vc, layer, idx, bt))
+        return kv_cache(c.num_layers, batch_size, c.num_heads, max_len,
+                        c.head_dim, dtype or self.compute_dtype)
 
     def forward_with_cache(self, params, input_ids, cache):
         """Prefill (T>1) or decode (T=1) step against the KV cache.
@@ -340,38 +313,21 @@ class GPT2Model:
         switches the cache arrays to the block-paged pool addressing of
         ops/attention.write_kv_blocks (prefix-sharing serving, ISSUE 6).
 
-        The stacked caches ride the layer-scan CARRY (per-layer slice writes
-        XLA keeps in place), not xs/ys — the ys form copied the entire cache
-        every step, which dominated decode latency (round-2 weak #2)."""
+        The stacked caches ride the layer scan's carry
+        (models/stack.cached_walk)."""
         c = self.config
         b, t = input_ids.shape
         idx = cache["index"]
-        bt = cache.get("block_table")
         x = embed_tokens(params["wte"], input_ids, self.compute_dtype)
         pos = cache_positions(idx, t)
         pe = params["wpe"].astype(self.compute_dtype)[pos]
         x = x + (pe if pos.ndim == 2 else pe[None])
-
-        def scan_body(carry, _):
-            x, kc, vc, layer = carry
-            # counter-indexed blocks: layer_view keeps int8 weight dicts
-            # whole so qdot's kernel DMA-slices the layer in-kernel (a
-            # host-side int8 operand slice copies the weight every step)
-            blk = layer_view(params["blocks"], layer)
-            x, kc, vc = self._block_cached(x, blk, kc, vc, layer, idx, bt)
-            return (x, kc, vc, layer + 1), None
-
-        (x, k_new, v_new, _), _ = jax.lax.scan(
-            scan_body,
-            (x, cache["k"], cache["v"], jnp.zeros((), jnp.int32)),
-            None, length=c.num_layers,
-            unroll=self.decode_unroll if t == 1 else 1)
+        x, (k_new, v_new) = cached_walk(
+            self._block, x, params["blocks"], (cache["k"], cache["v"]), idx,
+            cache.get("block_table"), count=c.num_layers)
         hidden = layer_norm(x, params["ln_f_scale"], params["ln_f_bias"], c.eps)
-        logits = self.logits(params, hidden)
-        out = {"k": k_new, "v": v_new, "index": idx + t}
-        if bt is not None:
-            out["block_table"] = bt
-        return logits, out
+        return self.logits(params, hidden), next_cache(cache, t, k=k_new,
+                                                       v=v_new)
 
     # ------------------------------------------------------------------- cost
     def flops_per_token(self) -> float:

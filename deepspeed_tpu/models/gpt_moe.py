@@ -1,11 +1,11 @@
-"""GPT with MoE FFN layers — the BASELINE ladder's "GPT-MoE" config
-(reference analog: Megatron-DeepSpeed MoE models driven through
-``deepspeed.moe.layer.MoE``; test fixture analog SimpleMoEModel,
-reference tests/unit/simple_model.py:70).
+"""GPT with MoE FFN layers (reference analog: Megatron-DeepSpeed MoE models
+driven through ``deepspeed.moe.layer.MoE``; test fixture analog
+SimpleMoEModel, reference tests/unit/simple_model.py:70).
 
 Interleaves dense and MoE transformer blocks (every other layer MoE, the
-standard GShard/DeepSpeed-MoE pattern). Blocks are unrolled (not scanned)
-because MoE and dense layers alternate structurally.
+standard GShard/DeepSpeed-MoE pattern). Blocks are a list looped over in
+Python (not a scanned stack) because MoE and dense layers alternate
+structurally; the cache tree and its way out are models/stack's.
 """
 
 from __future__ import annotations
@@ -19,8 +19,9 @@ import jax.numpy as jnp
 from deepspeed_tpu.models.base import (cache_positions, cross_entropy_loss,
                                        gathered, gathered_top, gelu,
                                        layer_norm)
+from deepspeed_tpu.models.stack import kv_cache, next_cache
 from deepspeed_tpu.moe.layer import MoE
-from deepspeed_tpu.ops.attention import alloc_kv_cache, cached_attention, multihead_attention
+from deepspeed_tpu.ops.attention import cached_attention, multihead_attention
 
 
 @dataclasses.dataclass
@@ -177,7 +178,7 @@ class GPTMoEModel:
             x, l_aux = self._ffn(x, blk, i, train=train, rng=rng)
             total_aux = total_aux + l_aux
         c = self.config
-        top = gathered_top(params)
+        top = gathered_top(params, "blocks")
         return layer_norm(x, top["ln_f_scale"], top["ln_f_bias"],
                           c.eps), total_aux
 
@@ -195,25 +196,20 @@ class GPTMoEModel:
     def apply(self, params, batch, *, rngs=None, train: bool = False):
         c = self.config
         rng = rngs.get("dropout") if isinstance(rngs, dict) else rngs
-        x = self._embed(gathered_top(params), batch["input_ids"])
+        x = self._embed(gathered_top(params, "blocks"), batch["input_ids"])
         hidden, total_aux = self._forward_blocks(params, x, rng=rng, train=train)
-        logits = self.logits(gathered_top(params), hidden)
+        logits = self.logits(gathered_top(params, "blocks"), hidden)
         ce, n = cross_entropy_loss(logits, batch["labels"])
         loss = ce + c.aux_loss_weight * total_aux / max(len(self.moe_layers), 1)
         return loss, {"loss": loss, "ce_loss": ce, "aux_loss": total_aux, "ntokens": n}
 
     # --------------------------------------------------------- inference path
     def init_cache(self, batch_size: int, max_len: int, dtype=None):
-        """Static-shape stacked KV cache, head-major, token-pair packed for
-        Dh < 128 (same layout as the dense families;
-        ops/attention.kv_pack_factor)."""
+        """Static-shape stacked KV cache, the dense families' layout
+        (models/stack.kv_cache)."""
         c = self.config
-        dtype = dtype or self.compute_dtype
-        return {"k": alloc_kv_cache(c.num_layers, batch_size, c.num_heads,
-                                    max_len, c.head_dim, dtype),
-                "v": alloc_kv_cache(c.num_layers, batch_size, c.num_heads,
-                                    max_len, c.head_dim, dtype),
-                "index": jnp.zeros((), jnp.int32)}
+        return kv_cache(c.num_layers, batch_size, c.num_heads, max_len,
+                        c.head_dim, dtype or self.compute_dtype)
 
     def forward_with_cache(self, params, input_ids, cache):
         """Prefill (T>1) or decode (T=1) step against the KV cache →
@@ -231,7 +227,5 @@ class GPTMoEModel:
             x, kc, vc = self._attn(x, blk, cache=(kc, vc, i, idx, bt))
             x, _ = self._ffn(x, blk, i, train=False, rng=None)
         hidden = layer_norm(x, params["ln_f_scale"], params["ln_f_bias"], c.eps)
-        out = {"k": kc, "v": vc, "index": idx + input_ids.shape[1]}
-        if bt is not None:
-            out["block_table"] = bt
-        return self.logits(params, hidden), out
+        return self.logits(params, hidden), next_cache(
+            cache, input_ids.shape[1], k=kc, v=vc)

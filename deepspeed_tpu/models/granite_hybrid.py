@@ -11,20 +11,21 @@ key-value rows that grow with the request on the attention layers, and a
 fixed-size recurrent state (``ssm``, ``conv``) on the Mamba layers.
 
 The stack follows ``layer_types``: the Mamba layers are one stacked tree, the
-attention layers another, and the forward scans over runs of equal layers, so
-40 layers compile as a handful of loops.
+attention layers another, and the forward walks runs of equal layers
+(models/stack.py), so 40 layers compile as a handful of loops.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import cross_entropy_loss, layer_view, qdot, rms_norm, tied_logits
-from deepspeed_tpu.ops.attention import alloc_kv_cache, cached_attention, multihead_attention
+from deepspeed_tpu.models.base import cross_entropy_loss, gathered_top, qdot, rms_norm, tied_logits
+from deepspeed_tpu.models.stack import cached_walk, kv_cache, next_cache, walk, wrapped_block
+from deepspeed_tpu.ops.attention import cached_attention, multihead_attention
 from deepspeed_tpu.ops.ssm import causal_conv, ssd_prefill, ssm_update
 
 MAMBA, ATTENTION = "mamba", "attention"
@@ -140,12 +141,15 @@ class GraniteHybridModel:
     state_dtype = jnp.float32
 
     def __init__(self, config: GraniteHybridConfig,
-                 compute_dtype=jnp.bfloat16, param_dtype=jnp.float32):
+                 compute_dtype=jnp.bfloat16, param_dtype=jnp.float32,
+                 remat: bool = False, remat_policy: Optional[str] = None):
         self.config = config
         self.compute_dtype = compute_dtype
         # what init() draws the matrices in: float32 master weights for
         # training, the checkpoint's bfloat16 where only serving follows
         self.param_dtype = param_dtype
+        self.remat = remat
+        self.remat_policy = remat_policy
 
     # ----------------------------------------------------------------- init
     def init(self, rng):
@@ -235,12 +239,13 @@ class GraniteHybridModel:
         return x + c.residual_multiplier * qdot("btm,md->btd", gate * up,
                                                 blk["w_down"])
 
-    def _mamba_layer(self, x, blk, state):
-        """``state``: ``None`` (no cache: zeros in, nothing out) or
-        ``(ssm_full [Lm,B,H,P,N], conv_full [Lm,B,K-1,C], layer, idx,
-        valid_len)``. One token (``T == 1``) with a cache runs the recurrence
-        in place on the stacked state; a block runs the chunked form from the
-        layer's state and writes the state at the true length back."""
+    def _mamba_layer(self, x, blk, state=None, layer=None, idx=None, valid=None):
+        """-> ``(x, state)``. ``state``: ``None`` (no cache: zeros in, nothing
+        out) or ``(ssm_full [Lm,B,H,P,N], conv_full [Lm,B,K-1,C])`` at
+        ``layer``, ``idx`` and the rows' ``valid`` lengths. One token (``T ==
+        1``) with a cache runs the recurrence in place on the stacked state; a
+        block runs the chunked form from the layer's state and writes the
+        state at the true length back."""
         c = self.config
         b, t, _ = x.shape
         h, p, n, g = (c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state,
@@ -252,11 +257,11 @@ class GraniteHybridModel:
         dt = jax.nn.softplus(dt.astype(jnp.float32)
                              + blk["dt_bias"].astype(jnp.float32))
         a = -jnp.exp(blk["A_log"].astype(jnp.float32))
-        s0 = valid = None
+        s0 = None
         if state is None:
             conv0 = jnp.zeros((b, c.mamba_d_conv - 1, c.conv_dim), x.dtype)
         else:
-            ssm_full, conv_full, layer, idx, valid = state
+            ssm_full, conv_full = state
             conv0 = jax.lax.dynamic_index_in_dim(conv_full, layer, 0, False)
             if t > 1:
                 # a row at position 0 has no history, whatever its slot held
@@ -291,12 +296,12 @@ class GraniteHybridModel:
         y = rms_norm(y, blk["gate_norm"], c.eps).astype(x.dtype)
         x = x + c.residual_multiplier * qdot("bte,ed->btd", y, blk["out_proj"])
         x = self._mlp(x, blk)
-        return (x, None, None) if state is None else (x, ssm_full, conv_full)
+        return x, (None if state is None else (ssm_full, conv_full))
 
-    def _attn_layer(self, x, blk, cache):
+    def _attn_layer(self, x, blk, cache=None, layer=None, idx=None):
         """No rotary and no position term; softmax of
         ``q k^T * attention_multiplier``. ``cache``: ``None`` or
-        ``(k_full, v_full, layer, idx)``."""
+        ``(k_full, v_full)`` at ``layer`` and ``idx``. -> ``(x, cache)``."""
         c = self.config
         b, t, _ = x.shape
         hq, hkv, dh = c.num_heads, c.num_kv_heads, c.head_dim
@@ -309,14 +314,13 @@ class GraniteHybridModel:
             attn = multihead_attention(
                 q, jnp.repeat(k_, rep, axis=2), jnp.repeat(v_, rep, axis=2),
                 causal=True, scale=c.attention_multiplier)
-            kc = vc = None
         else:
-            kc, vc, layer, idx = cache
-            attn, kc, vc = cached_attention(q, kc, vc, k_, v_, layer, idx,
+            attn, kc, vc = cached_attention(q, *cache, k_, v_, layer, idx,
                                             scale=c.attention_multiplier)
+            cache = (kc, vc)
         x = x + c.residual_multiplier * qdot(
             "bte,ed->btd", attn.reshape(b, t, hq * dh), blk["wo"])
-        return self._mlp(x, blk), kc, vc
+        return self._mlp(x, blk), cache
 
     # -------------------------------------------------------------- forward
     def _embed(self, params, input_ids):
@@ -327,16 +331,15 @@ class GraniteHybridModel:
     def forward_hidden(self, params, input_ids, *, rngs=None,
                        train: bool = False):
         c = self.config
-        x = self._embed(params, input_ids)
+        top = gathered_top(params, MAMBA, ATTENTION)
+        x = self._embed(top, input_ids)
         for kind, first, count in c.runs():
-            def body(x, i, kind=kind):
-                blk = layer_view(params[kind], i)
-                if kind == MAMBA:
-                    return self._mamba_layer(x, blk, None)[0], None
-                return self._attn_layer(x, blk, None)[0], None
-
-            x, _ = jax.lax.scan(body, x, first + jnp.arange(count))
-        return rms_norm(x, params["final_norm"], c.eps)
+            block = self._mamba_layer if kind == MAMBA else self._attn_layer
+            block_fn = wrapped_block(
+                lambda x, blk, block=block: block(x, blk)[0], kind,
+                self.remat, self.remat_policy)
+            x = walk(block_fn, x, params[kind], run=(first, count))
+        return rms_norm(x, top["final_norm"], c.eps)
 
     def logits(self, params, hidden):
         out = tied_logits(hidden, params["embed"])
@@ -345,7 +348,8 @@ class GraniteHybridModel:
     def apply(self, params, batch, *, rngs=None, train: bool = False):
         hidden = self.forward_hidden(params, batch["input_ids"], rngs=rngs,
                                      train=train)
-        loss, n = cross_entropy_loss(self.logits(params, hidden),
+        head = gathered_top(params, MAMBA, ATTENTION)
+        loss, n = cross_entropy_loss(self.logits(head, hidden),
                                      batch["labels"])
         return loss, {"loss": loss, "ntokens": n}
 
@@ -356,9 +360,7 @@ class GraniteHybridModel:
         ``[Lm, B, K-1, C]`` over the Mamba layers, and the index."""
         c = self.config
         dtype = dtype or self.compute_dtype
-        lm, la = c.count(MAMBA), c.count(ATTENTION)
-        kv = [alloc_kv_cache(la, batch_size, c.num_kv_heads, max_len,
-                             c.head_dim, dtype) for _ in range(2)]
+        lm = c.count(MAMBA)
         # no barrier as alloc_kv_cache has: a block at position 0 starts from
         # zeros whatever the buffer held (_mamba_layer), every layer's row is
         # written before it is read again, and called eagerly at 64 slots a
@@ -367,8 +369,8 @@ class GraniteHybridModel:
                          c.mamba_d_state), self.state_dtype)
         conv = jnp.zeros((lm, batch_size, c.mamba_d_conv - 1, c.conv_dim),
                          dtype)
-        return {"k": kv[0], "v": kv[1], "ssm": ssm, "conv": conv,
-                "index": jnp.zeros((), jnp.int32)}
+        return dict(kv_cache(c.count(ATTENTION), batch_size, c.num_kv_heads,
+                             max_len, c.head_dim, dtype), ssm=ssm, conv=conv)
 
     def forward_with_cache(self, params, input_ids, cache):
         """Prefill (T > 1) or decode (T == 1) against the cache tree.
@@ -386,28 +388,18 @@ class GraniteHybridModel:
         if valid is not None:
             valid = jnp.broadcast_to(jnp.asarray(valid, jnp.int32), (b,))
         x = self._embed(params, input_ids)
-        kc, vc, ssm, conv = cache["k"], cache["v"], cache["ssm"], cache["conv"]
+        kv, recurrent = (cache["k"], cache["v"]), (cache["ssm"], cache["conv"])
         for kind, first, count in c.runs():
-            layers = first + jnp.arange(count)
             if kind == MAMBA:
-                def body(carry, i):
-                    x, ssm, conv = carry
-                    return self._mamba_layer(
-                        x, layer_view(params[MAMBA], i),
-                        (ssm, conv, i, idx, valid)), None
-
-                (x, ssm, conv), _ = jax.lax.scan(body, (x, ssm, conv), layers)
+                x, recurrent = cached_walk(
+                    self._mamba_layer, x, params[MAMBA], recurrent, idx,
+                    valid, first=first, count=count)
             else:
-                def body(carry, i):
-                    x, kc, vc = carry
-                    return self._attn_layer(
-                        x, layer_view(params[ATTENTION], i),
-                        (kc, vc, i, idx)), None
-
-                (x, kc, vc), _ = jax.lax.scan(body, (x, kc, vc), layers)
+                x, kv = cached_walk(self._attn_layer, x, params[ATTENTION],
+                                    kv, idx, first=first, count=count)
         hidden = rms_norm(x, params["final_norm"], c.eps)
-        return self.logits(params, hidden), {
-            "k": kc, "v": vc, "ssm": ssm, "conv": conv, "index": idx + t}
+        return self.logits(params, hidden), next_cache(
+            cache, t, k=kv[0], v=kv[1], ssm=recurrent[0], conv=recurrent[1])
 
     def num_params(self) -> int:
         c = self.config

@@ -3,7 +3,7 @@
 The reference serves LLaMA through the AutoTP path (no dedicated container in
 the v0.9.2 snapshot — SURVEY §2.5); here it is a first-class model: RMSNorm,
 RoPE, SwiGLU, grouped-query attention, scan-stacked blocks, logical axes for
-TP/EP, optional remat. Flagship config for the BASELINE ladder is llama_7b.
+TP/EP, optional remat.
 """
 
 from __future__ import annotations
@@ -14,8 +14,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import ATTN_IMPLS, cross_entropy_loss, gathered, gathered_top, layer_view, qdot, rms_norm, sp_attention  # noqa: E501
-from deepspeed_tpu.ops.attention import alloc_kv_cache, cached_attention, multihead_attention
+from deepspeed_tpu.models.base import ATTN_IMPLS, cross_entropy_loss, gathered_top, qdot, rms_norm, sp_attention
+from deepspeed_tpu.models.stack import cached_walk, kv_cache, next_cache, walk, wrapped_block
+from deepspeed_tpu.ops.attention import cached_attention, multihead_attention
 from deepspeed_tpu.ops.rotary import apply_rotary_pos_emb, rope_frequencies
 
 
@@ -67,15 +68,13 @@ class LlamaModel:
 
     def __init__(self, config: LlamaConfig, compute_dtype=jnp.bfloat16,
                  remat: bool = False, remat_policy: Optional[str] = None,
-                 attn_impl: str = "dense", decode_unroll: int = 1):
+                 attn_impl: str = "dense"):
         self.config = config
         self.compute_dtype = compute_dtype
         self.remat = remat
         self.remat_policy = remat_policy
         assert attn_impl in ATTN_IMPLS, attn_impl
         self.attn_impl = attn_impl
-        # see GPT2Model: layer-scan unroll for single-token decode steps
-        self.decode_unroll = decode_unroll
 
     def init(self, rng):
         c = self.config
@@ -119,16 +118,16 @@ class LlamaModel:
             "lm_head": ("hidden", "vocab"),
         }
 
-    def _block_impl(self, x, blk, cos, sin, train: bool, cache):
-        """One LLaMA block; with ``cache=(k_full, v_full, layer, idx)``
-        attention runs against the GQA KV cache (shared implementation for
-        train + serving). Only the new token's slice of the full stacked
+    def _block(self, x, blk, kv, layer, idx, bt, cos, sin):
+        """One LLaMA block -> ``(x, kv)``; with ``kv=(k_full, v_full)``
+        attention runs against the GQA KV cache at ``layer`` and ``idx``
+        (shared implementation for train + serving; training passes None and
+        position 0). Only the new token's slice of the full stacked
         head-major [L, B, Hkv, S, Dh] cache is written — see
         ops/attention.decode_attention."""
         c = self.config
         b, t, d = x.shape
         hq, hkv, dh = c.num_heads, c.num_kv_heads, c.head_dim
-        idx = cache[3] if cache is not None else 0
         y = rms_norm(x, blk["attn_norm"], c.eps)
         # qdot streams int8 weights straight into the matmul (scale folded
         # into the output) — no dequantized bf16 tiles in HBM
@@ -137,7 +136,7 @@ class LlamaModel:
         v_ = qdot("btd,de->bte", y, blk["wv"]).reshape(b, t, hkv, dh)
         q = apply_rotary_pos_emb(q, cos, sin, position_offset=idx)
         k_ = apply_rotary_pos_emb(k_, cos, sin, position_offset=idx)
-        if cache is None:
+        if kv is None:
             if hkv != hq:  # GQA: repeat kv heads
                 rep = hq // hkv
                 k_ = jnp.repeat(k_, rep, axis=2)
@@ -146,45 +145,27 @@ class LlamaModel:
                 attn = sp_attention(self.attn_impl, q, k_, v_)
             else:
                 attn = multihead_attention(q, k_, v_, causal=True)
-            kc = vc = None
         else:
-            kc, vc, layer, idx, *rest = cache
-            attn, kc, vc = cached_attention(
-                q, kc, vc, k_, v_, layer, idx,
-                block_table=rest[0] if rest else None)
+            attn, kc, vc = cached_attention(q, *kv, k_, v_, layer, idx,
+                                            block_table=bt)
+            kv = (kc, vc)
         x = x + qdot("bte,ed->btd", attn.reshape(b, t, hq * dh), blk["wo"])
         y = rms_norm(x, blk["mlp_norm"], c.eps)
         gate = jax.nn.silu(qdot("btd,dm->btm", y, blk["w_gate"]))
         up = qdot("btd,dm->btm", y, blk["w_up"])
         x = x + qdot("btm,md->btd", gate * up, blk["w_down"])
-        return x, kc, vc
-
-    def _block(self, x, blk, cos, sin, train: bool):
-        return self._block_impl(x, blk, cos, sin, train, None)[0]
+        return x, kv
 
     def forward_hidden(self, params, input_ids, *, rngs=None, train: bool = False):
         c = self.config
-        b, t = input_ids.shape
-        top = gathered_top(params)     # ZeRO-3: the embedding, whole
+        top = gathered_top(params, "blocks")   # ZeRO-3: the embedding, whole
         x = top["embed"].astype(self.compute_dtype)[input_ids]
         cos, sin = rope_frequencies(c.head_dim, c.max_seq_len, c.rope_theta)
-
-        def block_fn(x, blk, cos, sin, train):
-            # ZeRO-3 gathers inside what remat wraps; a closure of this
-            # call, because jax keeps a traced block by its function
-            blk = gathered(blk, "blocks", stacked=True)
-            return self._block(x, blk, cos, sin, train)
-
-        if self.remat:
-            from deepspeed_tpu.runtime.activation_checkpointing import checkpoint_policy
-
-            block_fn = jax.checkpoint(block_fn, policy=checkpoint_policy(self.remat_policy),
-                                      static_argnums=(4,))
-
-        def scan_body(x, layer_params):
-            return block_fn(x, layer_params, cos, sin, train), None
-
-        x, _ = jax.lax.scan(scan_body, x, params["blocks"])
+        block_fn = wrapped_block(     # no cache: every row at position 0
+            lambda x, blk, cos, sin: self._block(x, blk, None, None, 0, None,
+                                                 cos, sin)[0],
+            "blocks", self.remat, self.remat_policy)
+        x = walk(block_fn, x, params["blocks"], cos, sin)
         return rms_norm(x, top["final_norm"], c.eps)
 
     def logits(self, params, hidden):
@@ -192,32 +173,22 @@ class LlamaModel:
 
     def apply(self, params, batch, *, rngs=None, train: bool = False):
         hidden = self.forward_hidden(params, batch["input_ids"], rngs=rngs, train=train)
-        logits = self.logits(gathered_top(params), hidden)
+        logits = self.logits(gathered_top(params, "blocks"), hidden)
         loss, n = cross_entropy_loss(logits, batch["labels"])
         return loss, {"loss": loss, "ntokens": n}
 
     # --------------------------------------------------------- inference path
     def init_cache(self, batch_size: int, max_len: int, dtype=None):
-        """Static-shape GQA KV cache — stores num_kv_heads only (the grouped
-        query repeat happens inside decode_attention). Head-major,
-        token-pair packed for Dh < 128 — see ops/attention.kv_pack_factor."""
+        """Static-shape GQA KV cache (models/stack.kv_cache): stores
+        num_kv_heads only, the grouped query repeat happens inside
+        decode_attention."""
         c = self.config
-        dtype = dtype or self.compute_dtype
-        return {"k": alloc_kv_cache(c.num_layers, batch_size,
-                                    c.num_kv_heads, max_len, c.head_dim,
-                                    dtype),
-                "v": alloc_kv_cache(c.num_layers, batch_size,
-                                    c.num_kv_heads, max_len, c.head_dim,
-                                    dtype),
-                "index": jnp.zeros((), jnp.int32)}
-
-    def _block_cached(self, x, blk, kc, vc, layer, idx, cos, sin, bt):
-        return self._block_impl(x, blk, cos, sin, False,
-                                (kc, vc, layer, idx, bt))
+        return kv_cache(c.num_layers, batch_size, c.num_kv_heads, max_len,
+                        c.head_dim, dtype or self.compute_dtype)
 
     def forward_with_cache(self, params, input_ids, cache):
         """Prefill (T>1) or decode (T=1) against the KV cache. Stacked caches
-        ride the scan carry with per-layer slice writes (see GPT2Model).
+        ride the scan carry (models/stack.cached_walk).
         ``cache["index"]`` may be a scalar or a per-slot [B] vector
         (continuous batching): RoPE then rotates each row at its own
         position (ops/rotary vector offset) and cached_attention masks
@@ -225,32 +196,14 @@ class LlamaModel:
         c = self.config
         b, t = input_ids.shape
         idx = cache["index"]
-        bt = cache.get("block_table")
         x = params["embed"].astype(self.compute_dtype)[input_ids]
         cos, sin = rope_frequencies(c.head_dim, c.max_seq_len, c.rope_theta)
-
-        def scan_body(carry, _):
-            x, kc, vc, layer = carry
-            # blocks are indexed by the carried counter (not scan xs):
-            # layer_view keeps int8 weight dicts WHOLE so qdot's kernel
-            # DMA-slices the layer in-kernel instead of paying a full
-            # per-step operand copy (models/base.layer_view)
-            blk = layer_view(params["blocks"], layer)
-            x, kc, vc = self._block_cached(x, blk, kc, vc, layer, idx,
-                                           cos, sin, bt)
-            return (x, kc, vc, layer + 1), None
-
-        (x, k_new, v_new, _), _ = jax.lax.scan(
-            scan_body,
-            (x, cache["k"], cache["v"], jnp.zeros((), jnp.int32)),
-            None, length=c.num_layers,
-            unroll=self.decode_unroll if t == 1 else 1)
+        x, (k_new, v_new) = cached_walk(
+            self._block, x, params["blocks"], (cache["k"], cache["v"]), idx,
+            cache.get("block_table"), cos, sin, count=c.num_layers)
         hidden = rms_norm(x, params["final_norm"], c.eps)
-        logits = self.logits(params, hidden)
-        out = {"k": k_new, "v": v_new, "index": idx + t}
-        if bt is not None:
-            out["block_table"] = bt
-        return logits, out
+        return self.logits(params, hidden), next_cache(cache, t, k=k_new,
+                                                       v=v_new)
 
     def flops_per_token(self) -> float:
         c = self.config

@@ -1,0 +1,115 @@
+"""How a model's layers are stacked and walked: the one place.
+
+A model file keeps its configuration, its parameter tree, its embedding, its
+head and its *block functions*. What is not the model's lives here: the
+training walk (:func:`wrapped_block`, :func:`walk`), the cached walk of
+prefill and decode (:func:`cached_walk`), and the key-value cache tree with
+the cache a step returns (:func:`kv_cache`, :func:`next_cache`). A stack is
+a dict of leaves with a leading ``layer`` dimension under one key of the
+params tree (``"blocks"``; the hybrid model has two).
+"""
+
+from __future__ import annotations
+
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.models.base import gathered, layer_view
+from deepspeed_tpu.ops.attention import alloc_kv_cache
+from deepspeed_tpu.runtime.activation_checkpointing import checkpoint_policy
+
+
+def wrapped_block(block, stack: str, remat: bool = False,
+                  remat_policy: Optional[str] = None):
+    """``block(x, blk, *args) -> x`` as the training walk runs it on one
+    layer's slice ``blk`` of the stack found under ``params[stack]``.
+
+    ZeRO-3 gathers the layer's weights inside what remat wraps, so the
+    backward pass gathers them again and the scan saves no whole weight.
+    Call this inside the model's ``forward_hidden``, once a trace: jax keeps
+    a traced block (``jax.checkpoint``, ``lax.scan``) by its function, and a
+    function that outlived its trace would replay the gathers, or their
+    absence, of whichever engine traced first (``base.gathered``)."""
+
+    def fn(x, blk, *args):
+        return block(x, gathered(blk, stack, stacked=True), *args)
+
+    if remat:
+        fn = jax.checkpoint(fn, policy=checkpoint_policy(remat_policy))
+    return fn
+
+
+def walk(block, x, stack, *args, xs=(), run=None):
+    """``x`` through the layers of ``stack`` (its stacked leaves):
+    ``block(x, blk, *xs_of_the_layer, *args)`` for each, ``block`` from
+    :func:`wrapped_block`. The stack is the scan's input, so the layers'
+    gradients come back stacked.
+
+    ``run=(first, count)`` walks that sub-range of the stack and indexes it
+    by layer number instead: a slice of the stack as the scan's input would
+    be a copy of it (the hybrid model's runs of equal layers)."""
+    if run is None:
+        def body(x, layer_in):
+            blk, per_layer = layer_in
+            return block(x, blk, *per_layer, *args), None
+
+        return jax.lax.scan(body, x, (stack, xs))[0]
+    first, count = run
+
+    def body(x, layer):
+        return block(x, layer_view(stack, layer), *args), None
+
+    return jax.lax.scan(body, x, first + jnp.arange(count))[0]
+
+
+def cached_walk(block, x, stack, state, idx, *args, count: int,
+                first: int = 0):
+    """Prefill (``T > 1``) or decode (``T == 1``) through ``count`` layers of
+    ``stack`` from layer ``first``: ``block(x, blk, state, layer, idx, *args)
+    -> (x, state)``. ``state`` is a tuple of FULL stacked per-layer leaves
+    (``[L, B, ...]`` key-value rows, recurrent state); a block reads and
+    writes only its layer's slice.
+
+    The state rides the scan's CARRY (per-layer slice writes XLA keeps in
+    place), not its inputs and outputs: that form copied the entire cache
+    every step and dominated decode latency. The layers are indexed by the
+    carried counter, not fed as the scan's input: ``layer_view`` keeps int8
+    weight dicts whole so ``qdot``'s kernel DMA-slices the layer in-kernel
+    (a host-side slice of an int8 operand copies the weight every step)."""
+
+    def body(carry, _):
+        x, *state, layer = carry
+        x, state = block(x, layer_view(stack, layer), tuple(state), layer,
+                         idx, *args)
+        return (x, *state, layer + 1), None
+
+    (x, *state, _), _ = jax.lax.scan(
+        body, (x, *state, jnp.full((), first, jnp.int32)), None, length=count)
+    return x, tuple(state)
+
+
+def kv_cache(layers: int, batch: int, kv_heads: int, max_len: int,
+             head_dim: int, dtype, packed: bool = True):
+    """The static-shape key-value cache tree ``{"k", "v", "index"}``:
+    stacked head-major ``[L, B, Hkv, S, Dh]``, token-pair packed for
+    ``Dh < 128`` unless the model's decode always takes the einsum path
+    (``packed=False``; ops/attention.alloc_kv_cache), and a scalar index.
+    A model with other per-layer state adds its leaves to this tree."""
+    return {"k": alloc_kv_cache(layers, batch, kv_heads, max_len, head_dim,
+                                dtype, packed=packed),
+            "v": alloc_kv_cache(layers, batch, kv_heads, max_len, head_dim,
+                                dtype, packed=packed),
+            "index": jnp.zeros((), jnp.int32)}
+
+
+def next_cache(cache, t: int, **state):
+    """The cache a step over ``t`` positions returns: the carried leaves,
+    the index moved on (a scalar, or a per-slot ``[B]`` vector under
+    continuous batching), and the block table of a block-paged pool passed
+    through when there is one."""
+    out = dict(state, index=cache["index"] + t)
+    if cache.get("block_table") is not None:
+        out["block_table"] = cache["block_table"]
+    return out

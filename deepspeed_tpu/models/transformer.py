@@ -29,10 +29,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.models.base import (cache_positions, cross_entropy_loss,
-                                       gathered, gathered_top, gelu,
-                                       layer_norm, layer_view, qdot)
-from deepspeed_tpu.ops.attention import (alloc_kv_cache, cache_seq_len,
-                                         cached_attention,
+                                       gathered_top, gelu, layer_norm, qdot)
+from deepspeed_tpu.models.stack import (cached_walk, kv_cache, next_cache,
+                                        walk, wrapped_block)
+from deepspeed_tpu.ops.attention import (cache_seq_len, cached_attention,
                                          multihead_attention,
                                          pool_block_size)
 from deepspeed_tpu.ops.rotary import apply_rotary_pos_emb, rope_frequencies
@@ -139,14 +139,11 @@ class DecoderModel:
     supports_weight_quant = True   # weight matmuls go through base.qdot
 
     def __init__(self, config: DecoderConfig, compute_dtype=jnp.bfloat16,
-                 remat: bool = False, remat_policy: Optional[str] = None,
-                 decode_unroll: int = 1):
+                 remat: bool = False, remat_policy: Optional[str] = None):
         self.config = config
         self.compute_dtype = compute_dtype
         self.remat = remat
         self.remat_policy = remat_policy
-        # see GPT2Model: layer-scan unroll for single-token decode steps
-        self.decode_unroll = decode_unroll
         c = config
         assert c.activation in ("gelu", "gelu_exact", "relu"), c.activation
         assert c.pos_emb in ("learned", "none"), c.pos_emb
@@ -275,20 +272,20 @@ class DecoderModel:
             k_ = jnp.concatenate([rk, pk], axis=-1)
         return q, k_, v_
 
-    def _block_impl(self, x, blk, cache, local_flag=None):
-        # cache = (k_full, v_full, layer, idx): full stacked head-major
-        # [L,B,H,S,Dh] caches, updated with per-token slice writes only
-        # (see ops/attention.decode_attention docstring). Weight matmuls go
-        # through qdot: int8 weights stream into the matmul, scale on the
-        # output.
+    def _block(self, x, blk, kv=None, layer=None, idx=0, bt=None,
+               local_flag=None):
+        # -> (x, kv). kv = (k_full, v_full): full stacked head-major
+        # [L,B,H,S,Dh] caches, updated at ``layer`` and ``idx`` with
+        # per-token slice writes only (see ops/attention.decode_attention
+        # docstring); None in training. Weight matmuls go through qdot: int8
+        # weights stream into the matmul, scale on the output.
         c = self.config
         b, t, d = x.shape
-        idx = cache[3] if cache is not None else 0
 
         y1 = x if c.post_ln else layer_norm(x, blk["ln1_scale"],
                                             blk["ln1_bias"], c.eps)
         q, k_, v_ = self._qkv(y1, blk, idx)
-        if cache is None:
+        if kv is None:
             mask = None
             if local_flag is not None:
                 # sliding-window causal: key allowed iff q_pos-k_pos < window
@@ -299,10 +296,8 @@ class DecoderModel:
             attn = multihead_attention(q, k_, v_, causal=True, mask=mask,
                                        bias=self._attn_bias(t, t),
                                        scale=c.qk_scale)
-            kc = vc = None
         else:
-            kc, vc, layer, _, *rest = cache
-            bt = rest[0] if rest else None
+            kc, vc = kv
             if bt is not None:
                 # block-paged pool (ISSUE 6): the attended view is the
                 # gathered block chain [B, MB * bs, ...], not the pool's
@@ -320,6 +315,7 @@ class DecoderModel:
             attn, kc, vc = cached_attention(q, kc, vc, k_, v_, layer, idx,
                                             bias=dec_bias, scale=c.qk_scale,
                                             window=window, block_table=bt)
+            kv = (kc, vc)
         attn = attn.reshape(b, t, d)
         attn_out = qdot("btd,de->bte", attn, blk["attn_out_w"]) + \
             blk["attn_out_b"].astype(x.dtype)
@@ -344,7 +340,7 @@ class DecoderModel:
                 blk["mlp_out_b"].astype(x.dtype)
             if c.post_ln:
                 x = layer_norm(x, blk["ln2_scale"], blk["ln2_bias"], c.eps)
-        return x, kc, vc
+        return x, kv
 
     # ---------------------------------------------------------------- forward
     def _embed(self, params, input_ids, idx):
@@ -367,32 +363,14 @@ class DecoderModel:
         c = self.config
         # ZeRO-3 gathers what is used, where it is used: the embedding's
         # leaves here, a layer's weights inside the (rematerialised) block
-        top = gathered_top(params)
+        top = gathered_top(params, "blocks")
         x = self._embed(top, input_ids, jnp.zeros((), jnp.int32))
-
-        def block_fn(x, blk, flag):
-            blk = gathered(blk, "blocks", stacked=True)
-            return self._block_impl(x, blk, None, local_flag=flag)[0]
-
-        if self.remat:
-            from deepspeed_tpu.runtime.activation_checkpointing import (
-                checkpoint_policy)
-
-            block_fn = jax.checkpoint(block_fn,
-                                      policy=checkpoint_policy(self.remat_policy))
-
-        if self._local_flags is not None:
-            def scan_body(x, layer_in):
-                blk, flag = layer_in
-                return block_fn(x, blk, flag), None
-
-            x, _ = jax.lax.scan(scan_body, x,
-                                (params["blocks"], self._local_flags))
-        else:
-            def scan_body(x, blk):
-                return block_fn(x, blk, None), None
-
-            x, _ = jax.lax.scan(scan_body, x, params["blocks"])
+        block_fn = wrapped_block(
+            lambda x, blk, flag=None: self._block(x, blk, local_flag=flag)[0],
+            "blocks", self.remat, self.remat_policy)
+        flags = self._local_flags
+        x = walk(block_fn, x, params["blocks"],
+                 xs=() if flags is None else (flags,))
         if c.final_ln:
             x = layer_norm(x, top["ln_f_scale"], top["ln_f_bias"], c.eps)
         return x
@@ -413,7 +391,7 @@ class DecoderModel:
     def apply(self, params, batch, *, rngs=None, train=False):
         hidden = self.forward_hidden(params, batch["input_ids"], rngs=rngs,
                                      train=train)
-        logits = self.logits(gathered_top(params), hidden)
+        logits = self.logits(gathered_top(params, "blocks"), hidden)
         loss, n = cross_entropy_loss(logits, batch["labels"])
         return loss, {"loss": loss, "ntokens": n}
 
@@ -424,51 +402,28 @@ class DecoderModel:
         # local windows), which keep the plain [L, B, H, S, Dh] form so
         # every step isn't paying an unpack view (ops/attention.kv_pack_factor)
         c = self.config
-        dtype = dtype or self.compute_dtype
-        packed = not (c.alibi or c.attn_layer_pattern)
-        return {"k": alloc_kv_cache(c.num_layers, batch_size, c.num_heads,
-                                    max_len, c.head_dim, dtype,
-                                    packed=packed),
-                "v": alloc_kv_cache(c.num_layers, batch_size, c.num_heads,
-                                    max_len, c.head_dim, dtype,
-                                    packed=packed),
-                "index": jnp.zeros((), jnp.int32)}
+        return kv_cache(c.num_layers, batch_size, c.num_heads, max_len,
+                        c.head_dim, dtype or self.compute_dtype,
+                        packed=not (c.alibi or c.attn_layer_pattern))
 
     def forward_with_cache(self, params, input_ids, cache):
         c = self.config
         idx = cache["index"]
-        bt = cache.get("block_table")
         x = self._embed(params, input_ids, idx)
         flags = self._local_flags
-        if flags is None:
-            flags = jnp.zeros((c.num_layers,), bool)
-            use_flags = False
-        else:
-            use_flags = True
 
-        def scan_body(carry, flag):
-            x, kc, vc, layer = carry
-            # counter-indexed blocks: layer_view keeps int8 weight dicts
-            # whole so qdot's kernel DMA-slices the layer in-kernel (a
-            # host-side int8 operand slice copies the weight every step)
-            blk = layer_view(params["blocks"], layer)
-            x, kc, vc = self._block_impl(
-                x, blk, (kc, vc, layer, idx, bt),
-                local_flag=flag if use_flags else None)
-            return (x, kc, vc, layer + 1), None
+        def block(x, blk, kv, layer, idx, bt):
+            return self._block(x, blk, kv, layer, idx, bt,
+                               None if flags is None else flags[layer])
 
-        t = input_ids.shape[1]
-        (x, k_new, v_new, _), _ = jax.lax.scan(
-            scan_body,
-            (x, cache["k"], cache["v"], jnp.zeros((), jnp.int32)),
-            flags, unroll=self.decode_unroll if t == 1 else 1)
+        x, (k_new, v_new) = cached_walk(
+            block, x, params["blocks"], (cache["k"], cache["v"]), idx,
+            cache.get("block_table"), count=c.num_layers)
         if c.final_ln:
             x = layer_norm(x, params["ln_f_scale"], params["ln_f_bias"],
                            c.eps)
-        out = {"k": k_new, "v": v_new, "index": idx + input_ids.shape[1]}
-        if bt is not None:
-            out["block_table"] = bt
-        return self.logits(params, x), out
+        return self.logits(params, x), next_cache(
+            cache, input_ids.shape[1], k=k_new, v=v_new)
 
     def flops_per_token(self) -> float:
         c = self.config

@@ -23,10 +23,10 @@ import jax.numpy as jnp
 # when the cache stream is fat enough: measured LOSS at 125M B=1 Dh=64
 # (~1.0 MB K/layer: einsum 0.46 vs kernel 0.60 ms/tok) and WIN at 6.7B
 # B=1 Dh=128 (~5.2 MB K/layer: 19.15 -> 18.25 ms/tok). 2 MB splits the
-# two measured points; scripts/measure_decode.py --b1-dh128 measures the
-# LLaMA geometry directly on hardware, and the env override lets that
-# measurement force either path without a code change (ADVICE round 5:
-# the fixed per-layer DMA overhead was never measured at B=1/Dh>=128).
+# two measured points (neither taken on the v5e: ROADMAP D5), and the env
+# override lets a measurement force either path without a code change
+# (ADVICE round 5: the fixed per-layer DMA overhead was never measured at
+# B=1/Dh>=128).
 _B1_FUSED_MIN_BYTES = int(os.environ.get(
     "DEEPSPEED_TPU_B1_FUSED_MIN_BYTES", 2 * 1024 * 1024))
 

@@ -293,8 +293,8 @@ class DeepSpeedEngine:
         self.monitor = MonitorMaster(config.monitor_config)
 
         # ---- telemetry (ISSUE 3): in-process metrics registry + optional
-        # JSONL sink. Per-step cost is a few dict ops (2% budget pinned by
-        # bench.py observability_overhead); device-truth metrics (device
+        # JSONL sink. Per-step cost is a few dict ops (the budget is 2%);
+        # device-truth metrics (device
         # step time, MFU, grad-norm, fp16 skips, memory) are sampled at a
         # periodic block_until_ready fence so async dispatch survives.
         tcfg = config.telemetry_config

@@ -238,8 +238,7 @@ class ServingEngine:
         global metrics registry (queue-wait/TTFT/TPOT latency histograms,
         slot-occupancy and batch-fill gauges, recompile counter,
         finished-requests/sec — ISSUE 3); pass a MetricsRegistry to use a
-        private one, or False/None to run bare (the bench.py
-        ``observability_overhead`` baseline).
+        private one, or False/None to run bare.
     speculative: speculative decoding (ISSUE 4): None/"off" (default),
         a mode string ("ngram" | "draft"), a dict of
         :class:`~deepspeed_tpu.serving.speculative.SpeculativeConfig`
@@ -328,9 +327,8 @@ class ServingEngine:
         ``iteration`` span tiled by ``iter_schedule``, ``iter_upload``,
         ``iter_launch``, ``iter_fetch`` and ``iter_commit``, the same
         phases the ``dstpu/serving_*`` profiler annotations name. Arming
-        adds no device work: greedy output stays bit-identical and the
-        armed-vs-bare overhead is pinned <= 2% by bench.py
-        ``tracing_overhead``.
+        adds no device work: greedy output stays bit-identical (pinned by
+        tests/unit/serving/test_tracing.py); the armed-vs-bare budget is 2%.
     slo: an :class:`~deepspeed_tpu.telemetry.slo.SLOEngine` (ISSUE 13),
         or None (default). When armed, the engine calls
         ``slo.maybe_evaluate(now)`` once per serving iteration ON THE
@@ -772,8 +770,7 @@ class ServingEngine:
             progs["block_copy"] = self._copy_fn
         if isinstance(self._drafter, DraftModelDrafter):
             # the draft model's programs ride program_cache_sizes and
-            # must ride the roofline table too (coverage is pinned by
-            # bench.py's all_programs_covered)
+            # must ride the roofline table too
             for kb, fn in self._drafter._programs.items():
                 progs[f"draft_{kb}"] = fn
         return progs

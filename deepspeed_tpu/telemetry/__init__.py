@@ -19,8 +19,8 @@ comms logger, redesigned for JAX's async-dispatch execution model:
 Instrumentation points: ``runtime/engine.py`` (per-step wall/device time,
 tokens/sec, MFU, grad-norm, fp16 skip counters, device memory) and
 ``serving/engine.py`` (queue-wait/TTFT/TPOT histograms, slot occupancy,
-recompile counter, finished-requests/sec). Overhead is budgeted at 2% and
-measured by ``bench.py``'s ``observability_overhead`` section.
+recompile counter, finished-requests/sec). Overhead is budgeted at 2%; no
+benchmark cell measures it yet.
 """
 
 from deepspeed_tpu.telemetry.attribution import (abstract_args,

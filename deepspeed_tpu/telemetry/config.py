@@ -2,8 +2,8 @@
 
 Keys:
   enabled        — master switch for engine/serving instrumentation
-                   (default true; the registry ops it gates cost ~1us/step,
-                   see bench.py observability_overhead).
+                   (default true; the registry ops it gates are a few
+                   dict updates a step).
   jsonl_path     — when non-empty, a JsonlSink is attached to the global
                    registry and periodic snapshots + events stream there
                    (render with scripts/telemetry_report.py).

@@ -9,9 +9,8 @@ Design constraints, in order:
 
 1. **Overhead.** A hot-loop update is a dict lookup + an int add (counters),
    a float store (gauges) or a ``bisect`` + int add (histograms) — no
-   locks on the update path, no allocation, no syscalls. bench.py's
-   ``observability_overhead`` section holds instrumented train and decode
-   steps to a 2% budget against bare runs.
+   locks on the update path, no allocation, no syscalls. The budget for
+   instrumented train and decode steps is 2% against bare runs.
 2. **Fixed memory.** Histograms are fixed-bucket (default: log-spaced
    latency buckets, ~1.25x ratio) so a week-long serving run costs the
    same bytes as a unit test. Percentiles (p50/p95/p99) are estimated by
@@ -69,8 +68,8 @@ def _default_latency_buckets_ms() -> List[float]:
     """Log-spaced (ratio 1.25) upper bounds from 10us to ~2min, in ms.
     The ratio bounds histogram-percentile quantization error to ~25%
     worst-case (a few % typical after interpolation) — tight enough that
-    telemetry p50/p95 agree with direct measurement (bench.py
-    ``observability_overhead.histogram_agreement``)."""
+    telemetry p50/p95 agree with direct measurement
+    (tests/unit/telemetry/test_registry.py)."""
     out, v = [], 0.01
     while v < 120_000.0:
         out.append(round(v, 6))

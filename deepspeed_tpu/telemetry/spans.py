@@ -17,8 +17,7 @@ Design constraints, in order:
    never even reads a clock. The serving/fabric integrations pass the
    engine-clock instants they were already holding, so an armed run
    issues the same device work as a bare one (greedy output
-   bit-identical, pinned by tests; armed-vs-bare overhead <= 2%,
-   pinned by bench.py ``tracing_overhead``).
+   bit-identical, pinned by tests; the armed-vs-bare budget is 2%).
 2. **Virtual-clock compatible.** All times are plain floats in the
    CALLER's clock base (``time.monotonic`` offsets in production, a
    :class:`~deepspeed_tpu.testing.fault_injection.FakeClock` in the

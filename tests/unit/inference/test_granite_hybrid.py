@@ -134,6 +134,29 @@ def test_gradients_match_the_reference_loss(built):
         assert float(jnp.abs(g_ref).max()) > 0, path   # every leaf is used
 
 
+@pytest.mark.parametrize("policy", [None, "dots_no_batch"])
+def test_remat_changes_no_loss_and_no_gradient(built, policy):
+    """The walk the hybrid shares with the other decoders wraps each layer
+    in ``jax.checkpoint`` by policy: the same numbers, recomputed."""
+    model, params, ids, _ = built
+    batch = {"input_ids": ids, "labels": jnp.roll(ids, -1, axis=1)}
+
+    def loss_and_grads(remat):
+        m = GraniteHybridModel(model.config, compute_dtype=jnp.float32,
+                               remat=remat, remat_policy=policy)
+        return jax.jit(jax.value_and_grad(lambda p: m.apply(p, batch)[0]))
+
+    plain, wrapped = loss_and_grads(False), loss_and_grads(True)
+    assert "remat" not in str(jax.make_jaxpr(plain)(params))
+    assert "remat" in str(jax.make_jaxpr(wrapped)(params))
+    (loss, grads), (loss_r, grads_r) = plain(params), wrapped(params)
+    np.testing.assert_allclose(float(loss_r), float(loss), rtol=1e-6)
+    for g, g_r in zip(jax.tree_util.tree_leaves(grads),
+                      jax.tree_util.tree_leaves(grads_r)):
+        np.testing.assert_allclose(np.asarray(g_r), np.asarray(g),
+                                   rtol=1e-5, atol=1e-9)
+
+
 @pytest.mark.parametrize("change,needle", [
     (dict(num_local_experts=4), "experts"),
     (dict(position_embedding_type="rope"), "position"),

@@ -1,6 +1,7 @@
 """ZeRO-3 states its parameter gather where a parameter is used
 (``models/base.gathered``): a layer's slice inside the rematerialised block
-of the layer scan, the embedding and the head at their use. On the CPU's
+of the layer scan (``models/stack.wrapped_block``, the one walk every
+decoder shares), the embedding and the head at their use. On the CPU's
 virtual devices: what the compiled train step moves between devices, and
 that the mathematics is stage 0's."""
 
@@ -46,7 +47,26 @@ def _transformer():
                         remat_policy="dots_no_batch")
 
 
-MODELS = {"gpt2": _gpt2, "llama": _llama, "transformer": _transformer}
+def _gpt_moe():
+    from deepspeed_tpu.models.gpt_moe import GPTMoEConfig, GPTMoEModel
+
+    cfg = GPTMoEConfig.tiny(vocab_size=VOCAB, max_seq_len=SEQ)
+    return GPTMoEModel(cfg, compute_dtype=jnp.float32)
+
+
+def _granite():
+    from deepspeed_tpu.models.granite_hybrid import (GraniteHybridConfig,
+                                                     GraniteHybridModel)
+
+    cfg = GraniteHybridConfig.tiny(    # two stacks, walked as four runs
+        vocab_size=VOCAB, max_seq_len=SEQ,
+        layer_types=("mamba", "mamba", "attention", "mamba", "attention"))
+    return GraniteHybridModel(cfg, compute_dtype=jnp.float32, remat=True,
+                              remat_policy="dots_no_batch")
+
+
+MODELS = {"gpt2": _gpt2, "llama": _llama, "transformer": _transformer,
+          "gpt_moe": _gpt_moe, "granite": _granite}
 
 
 def _engine(model, stage, dp, tp=1):
@@ -107,12 +127,21 @@ def test_stage3_gathers_weights_and_moves_no_activation(name):
     text = _step_text(engine, batch)
     assert not _collectives(text, "all-to-all"), \
         "the partitioner reshards activations around a weight shard"
-    # a layer's whole weight, gathered inside the layer scan: the stacked
-    # leaf's shape without (or with a unit) layer dimension
-    blocks = jax.tree_util.tree_leaves(engine._params_shape["blocks"])
-    whole = {tuple(b.shape[1:]) for b in blocks if b.ndim == 3}
     gathered = {tuple(int(d) for d in s[s.index("[") + 1:-1].split(",")
                       if d) for s in _collectives(text, "all-gather")}
+    stacks = ("mamba", "attention") if name == "granite" else ("blocks",)
+    # a stack's matrices; gpt_moe keeps a list of layers, whose matrices
+    # count as slices of a stack of two
+    lift = (2,) if name == "gpt_moe" else ()
+    stacked = [lift + leaf.shape for stack in stacks
+               for leaf in jax.tree_util.tree_leaves(
+                   engine._params_shape[stack]) if len(lift + leaf.shape) == 3]
+    assert stacked
+    # never a whole stack: the gather is stated on a layer's slice
+    assert not {s for s in stacked if s[0] > 1} & gathered, gathered
+    # a layer's whole weight, gathered inside the layer scan: the stacked
+    # leaf's shape without (or with a unit) layer dimension
+    whole = {s[1:] for s in stacked}
     gathered |= {g[1:] for g in gathered if g[:1] == (1,)}
     assert whole <= gathered, (whole, gathered)
     _assert_same_training(_train(engine, batch), reference)
@@ -137,6 +166,27 @@ def test_stage3_with_tensor_parallel_keeps_model_axis():
                for s in shapes), shapes
     assert not any(s.endswith(f"[{HIDDEN},{3 * HIDDEN}]") for s in shapes)
     _assert_same_training(_train(engine, batch), reference)
+
+
+def test_what_lies_outside_the_stacks_is_named_by_the_model():
+    """``gathered_top`` knows no stack's name: a model whose stacks are not
+    called ``blocks`` names them, and they are left alone."""
+    from deepspeed_tpu.models.base import gathered_top
+
+    model = _granite()
+    engine = _engine(model, 3, 4)
+    params = engine._params_shape
+    with stating_param_use(engine._param_use):
+        jaxpr = jax.make_jaxpr(
+            lambda p: gathered_top(p, "mamba", "attention"))(params)
+        everything = jax.make_jaxpr(gathered_top)(params)
+    assert set(gathered_top(params, "mamba", "attention")) == \
+        {"embed", "final_norm"}
+    # a gather for the embedding and one for the final norm (the threshold
+    # is 0), and one more for every leaf of the stacks when they go unnamed
+    assert str(jaxpr).count("sharding_constraint") == 2
+    assert str(everything).count("sharding_constraint") > 2 + \
+        len(jax.tree_util.tree_leaves(params["attention"]))
 
 
 @pytest.mark.parametrize("stage,dp", [(0, 4), (1, 4), (2, 4), (3, 1)])
