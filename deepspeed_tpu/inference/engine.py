@@ -667,22 +667,30 @@ class InferenceEngine:
         serving runtime: ONE token for every slot against the slot-paged
         KV cache with a per-slot valid-length vector
         (models/base.cache_positions + ops/attention per-slot masking).
-        Inactive slots (``active`` false) keep their length and emit
-        ``pad_token_id``. Their masked garbage key-value write lands at a
-        row behind their length and is overwritten by the next prefill or
-        chunk into that slot. That is true of key-value rows only: a
-        recurrent state has no rows to hide a write behind, and a slot
+        Inactive slots (``active`` false) keep their length, which goes
+        stale once the slot is freed, and emit ``pad_token_id``. A slot
         mid-way through a chunked prefill is inactive here while its state
-        is live, so ``active`` reaches the model as ``cache["valid_len"]``
-        (1 or 0 real positions) and an inactive slot's state is neither
-        read nor written. Fixed slot count + fixed cache shape = exactly
-        one compiled program for the entire decode side of the serving
-        loop, regardless of arrival pattern.
+        is live, so ``active`` reaches the model twice over and an inactive
+        slot's state is neither read nor written: as ``cache["valid_len"]``
+        (1 or 0 real positions) for recurrent state, which has no rows to
+        hide a write behind, and as ``cache["slot_walk"]`` for key-value
+        rows, the order the fused decode step walks the slots in (active
+        ones by descending length, ops/decode_step.slot_walk), made here
+        once a step for every layer. The fused step fetches an active
+        slot's own prefix and nothing of an inactive one, whatever its
+        stale length; on the einsum path (a CPU, a bias, a window) an
+        inactive slot's masked write still lands at the row behind its
+        length, dead until the next prefill or chunk overwrites it. Fixed
+        slot count + fixed cache shape = exactly one compiled program for
+        the entire decode side of the serving loop, regardless of arrival
+        pattern.
 
         Signature: ``(params, *state, lengths[B], tokens[B], active[B]
         bool, temp, rng) -> (*state, lengths, next_tokens[B])`` with the
         state's leaves as in :meth:`slot_prefill_program` (cache operands
         donated on TPU)."""
+        from deepspeed_tpu.ops.decode_step import slot_walk
+
         key = ("slot_dec", num_slots, max_len, do_sample, top_k,
                float(top_p), pad_token_id)
         if key not in self._compiled:
@@ -693,7 +701,8 @@ class InferenceEngine:
             def decode(params, *ops):
                 *leaves, lengths, tokens, active, temp, rng = ops
                 cache = dict(zip(names, leaves), index=lengths,
-                             valid_len=active.astype(jnp.int32))
+                             valid_len=active.astype(jnp.int32),
+                             slot_walk=slot_walk(lengths, active))
                 with jax.named_scope("dstpu_decode"):
                     logits, cache = model.forward_with_cache(
                         params, tokens[:, None], cache)

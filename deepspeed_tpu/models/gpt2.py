@@ -150,8 +150,8 @@ class GPT2Model:
         return axes
 
     # ------------------------------------------------------------------ layers
-    def _block(self, x, blk, kv=None, layer=None, idx=None, bt=None, *,
-               rng=None, train: bool = False):
+    def _block(self, x, blk, kv=None, layer=None, idx=None, bt=None,
+               active=None, *, rng=None, train: bool = False):
         """One transformer block -> ``(x, kv)``; with ``kv=(k_full, v_full)``
         the attention runs against the KV cache at ``layer`` and ``idx`` (one
         shared implementation so training and serving can never diverge
@@ -183,7 +183,7 @@ class GPT2Model:
                                            dropout_rng=drop_rng)
         else:
             attn, kc, vc = cached_attention(q, *kv, k_, v_, layer, idx,
-                                            block_table=bt)
+                                            block_table=bt, active=active)
             kv = (kc, vc)
         attn = attn.reshape(b, t, d)
         x = x + qdot("btd,de->bte", attn, blk["attn_out_w"]) + \
@@ -312,6 +312,9 @@ class GPT2Model:
         ``cache["block_table"]`` (optional, int32 [B, max_blocks])
         switches the cache arrays to the block-paged pool addressing of
         ops/attention.write_kv_blocks (prefix-sharing serving, ISSUE 6).
+        ``cache["slot_walk"]`` (optional; the slot decode program's) goes to
+        ops/attention.cached_attention as ``active``: the fused decode step
+        skips the rows of a slot that is not decoding.
 
         The stacked caches ride the layer scan's carry
         (models/stack.cached_walk)."""
@@ -324,7 +327,8 @@ class GPT2Model:
         x = x + (pe if pos.ndim == 2 else pe[None])
         x, (k_new, v_new) = cached_walk(
             self._block, x, params["blocks"], (cache["k"], cache["v"]), idx,
-            cache.get("block_table"), count=c.num_layers)
+            cache.get("block_table"), cache.get("slot_walk"),
+            count=c.num_layers)
         hidden = layer_norm(x, params["ln_f_scale"], params["ln_f_bias"], c.eps)
         return self.logits(params, hidden), next_cache(cache, t, k=k_new,
                                                        v=v_new)

@@ -125,9 +125,10 @@ class GPTMoEModel:
                 "blocks": blocks, "ln_f_scale": ("hidden",), "ln_f_bias": ("hidden",)}
 
     def _attn(self, x, blk, cache=None):
-        """Attention sub-block; ``cache=(k_full, v_full, layer, idx)`` runs
-        against the stacked head-major [L, B, H, S, Dh] KV cache (same
-        write/read ops as the dense families — ops/attention.py)."""
+        """Attention sub-block; ``cache=(k_full, v_full, layer, idx,
+        block_table, slot_walk)`` runs against the stacked head-major
+        [L, B, H, S, Dh] KV cache (same write/read ops as the dense
+        families — ops/attention.py)."""
         c = self.config
         b, t, d = x.shape
         y = layer_norm(x, blk["ln1_scale"], blk["ln1_bias"], c.eps)
@@ -139,10 +140,9 @@ class GPTMoEModel:
             attn = multihead_attention(q, k_, v_, causal=True)
             kc = vc = None
         else:
-            kc, vc, layer, idx, *rest = cache
+            kc, vc, layer, idx, bt, active = cache
             attn, kc, vc = cached_attention(
-                q, kc, vc, k_, v_, layer, idx,
-                block_table=rest[0] if rest else None)
+                q, kc, vc, k_, v_, layer, idx, block_table=bt, active=active)
         x = x + attn.reshape(b, t, d) @ blk["out_w"].astype(x.dtype) + \
             blk["out_b"].astype(x.dtype)
         return x, kc, vc
@@ -224,7 +224,8 @@ class GPTMoEModel:
         x = self._embed(params, input_ids, start_pos=idx)
         kc, vc = cache["k"], cache["v"]
         for i, blk in enumerate(params["blocks"]):
-            x, kc, vc = self._attn(x, blk, cache=(kc, vc, i, idx, bt))
+            x, kc, vc = self._attn(
+                x, blk, cache=(kc, vc, i, idx, bt, cache.get("slot_walk")))
             x, _ = self._ffn(x, blk, i, train=False, rng=None)
         hidden = layer_norm(x, params["ln_f_scale"], params["ln_f_bias"], c.eps)
         return self.logits(params, hidden), next_cache(

@@ -298,10 +298,12 @@ class GraniteHybridModel:
         x = self._mlp(x, blk)
         return x, (None if state is None else (ssm_full, conv_full))
 
-    def _attn_layer(self, x, blk, cache=None, layer=None, idx=None):
+    def _attn_layer(self, x, blk, cache=None, layer=None, idx=None,
+                    active=None):
         """No rotary and no position term; softmax of
         ``q k^T * attention_multiplier``. ``cache``: ``None`` or
-        ``(k_full, v_full)`` at ``layer`` and ``idx``. -> ``(x, cache)``."""
+        ``(k_full, v_full)`` at ``layer`` and ``idx``; ``active``: the
+        decode program's ``cache["slot_walk"]``. -> ``(x, cache)``."""
         c = self.config
         b, t, _ = x.shape
         hq, hkv, dh = c.num_heads, c.num_kv_heads, c.head_dim
@@ -316,7 +318,8 @@ class GraniteHybridModel:
                 causal=True, scale=c.attention_multiplier)
         else:
             attn, kc, vc = cached_attention(q, *cache, k_, v_, layer, idx,
-                                            scale=c.attention_multiplier)
+                                            scale=c.attention_multiplier,
+                                            active=active)
             cache = (kc, vc)
         x = x + c.residual_multiplier * qdot(
             "bte,ed->btd", attn.reshape(b, t, hq * dh), blk["wo"])
@@ -380,7 +383,10 @@ class GraniteHybridModel:
         a prompt, or 0 for a slot that is not decoding. Key-value rows need
         no such thing (padding is causally invisible and masked by the
         lengths); recurrent state would fold the padding in, so it stops at
-        ``valid_len``: a row with 0 valid positions keeps its state."""
+        ``valid_len``: a row with 0 valid positions keeps its state. The
+        decode program's ``cache["slot_walk"]`` says the same to the fused
+        decode step of the attention layers (ops/attention.cached_attention,
+        ``active``): it skips the rows of a slot that is not decoding."""
         c = self.config
         b, t = input_ids.shape
         idx = cache["index"]
@@ -396,7 +402,8 @@ class GraniteHybridModel:
                     valid, first=first, count=count)
             else:
                 x, kv = cached_walk(self._attn_layer, x, params[ATTENTION],
-                                    kv, idx, first=first, count=count)
+                                    kv, idx, cache.get("slot_walk"),
+                                    first=first, count=count)
         hidden = rms_norm(x, params["final_norm"], c.eps)
         return self.logits(params, hidden), next_cache(
             cache, t, k=kv[0], v=kv[1], ssm=recurrent[0], conv=recurrent[1])

@@ -118,7 +118,7 @@ class LlamaModel:
             "lm_head": ("hidden", "vocab"),
         }
 
-    def _block(self, x, blk, kv, layer, idx, bt, cos, sin):
+    def _block(self, x, blk, kv, layer, idx, bt, cos, sin, active=None):
         """One LLaMA block -> ``(x, kv)``; with ``kv=(k_full, v_full)``
         attention runs against the GQA KV cache at ``layer`` and ``idx``
         (shared implementation for train + serving; training passes None and
@@ -147,7 +147,7 @@ class LlamaModel:
                 attn = multihead_attention(q, k_, v_, causal=True)
         else:
             attn, kc, vc = cached_attention(q, *kv, k_, v_, layer, idx,
-                                            block_table=bt)
+                                            block_table=bt, active=active)
             kv = (kc, vc)
         x = x + qdot("bte,ed->btd", attn.reshape(b, t, hq * dh), blk["wo"])
         y = rms_norm(x, blk["mlp_norm"], c.eps)
@@ -200,7 +200,8 @@ class LlamaModel:
         cos, sin = rope_frequencies(c.head_dim, c.max_seq_len, c.rope_theta)
         x, (k_new, v_new) = cached_walk(
             self._block, x, params["blocks"], (cache["k"], cache["v"]), idx,
-            cache.get("block_table"), cos, sin, count=c.num_layers)
+            cache.get("block_table"), cos, sin, cache.get("slot_walk"),
+            count=c.num_layers)
         hidden = rms_norm(x, params["final_norm"], c.eps)
         return self.logits(params, hidden), next_cache(cache, t, k=k_new,
                                                        v=v_new)

@@ -273,7 +273,7 @@ class DecoderModel:
         return q, k_, v_
 
     def _block(self, x, blk, kv=None, layer=None, idx=0, bt=None,
-               local_flag=None):
+               local_flag=None, active=None):
         # -> (x, kv). kv = (k_full, v_full): full stacked head-major
         # [L,B,H,S,Dh] caches, updated at ``layer`` and ``idx`` with
         # per-token slice writes only (see ops/attention.decode_attention
@@ -314,7 +314,8 @@ class DecoderModel:
                 window = jnp.where(local_flag, c.local_attn_window, s_max + 1)
             attn, kc, vc = cached_attention(q, kc, vc, k_, v_, layer, idx,
                                             bias=dec_bias, scale=c.qk_scale,
-                                            window=window, block_table=bt)
+                                            window=window, block_table=bt,
+                                            active=active)
             kv = (kc, vc)
         attn = attn.reshape(b, t, d)
         attn_out = qdot("btd,de->bte", attn, blk["attn_out_w"]) + \
@@ -412,13 +413,15 @@ class DecoderModel:
         x = self._embed(params, input_ids, idx)
         flags = self._local_flags
 
-        def block(x, blk, kv, layer, idx, bt):
+        def block(x, blk, kv, layer, idx, bt, active):
             return self._block(x, blk, kv, layer, idx, bt,
-                               None if flags is None else flags[layer])
+                               None if flags is None else flags[layer],
+                               active)
 
         x, (k_new, v_new) = cached_walk(
             block, x, params["blocks"], (cache["k"], cache["v"]), idx,
-            cache.get("block_table"), count=c.num_layers)
+            cache.get("block_table"), cache.get("slot_walk"),
+            count=c.num_layers)
         if c.final_ln:
             x = layer_norm(x, params["ln_f_scale"], params["ln_f_bias"],
                            c.eps)

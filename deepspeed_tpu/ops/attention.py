@@ -111,7 +111,8 @@ def cache_seq_len(k_full, head_dim: int) -> int:
 
 
 def cached_attention(q, k_full, v_full, k_new, v_new, layer, idx, *,
-                     scale=None, bias=None, window=None, block_table=None):
+                     scale=None, bias=None, window=None, block_table=None,
+                     active=None):
     """One cached-attention layer step: write the new block's K/V into the
     full stacked [L, B, Hkv, S, Dh] caches (possibly token-pair packed,
     see :func:`kv_pack_factor`), attend, return ``(attn, k_full, v_full)``.
@@ -119,7 +120,12 @@ def cached_attention(q, k_full, v_full, k_new, v_new, layer, idx, *,
     ``idx`` is the first free cache position: a scalar for the uniform
     batch-decode path, or a PER-SLOT ``[B]`` vector for the continuous-
     batching serving runtime (serving/engine.py) — each batch row then
-    writes at and attends over ITS OWN valid prefix.
+    writes at and attends over ITS OWN valid prefix. ``active`` goes with
+    the per-slot vector: which slots decode this step, as a ``[B]`` mask or
+    as the ``SlotWalk`` the decode program made of it once for all layers
+    (ops/decode_step.slot_walk). The fused step neither reads nor writes an
+    inactive slot's rows and returns zeros for it; the einsum path ignores
+    it (there an inactive slot's masked write lands behind its length).
 
     Single-token decode on TPU routes to the fused Pallas step
     (ops/decode_step.py): the kernel owns BOTH the cache write and the
@@ -175,7 +181,8 @@ def cached_attention(q, k_full, v_full, k_new, v_new, layer, idx, *,
         if supports(q.shape[2], k_full.shape[2],
                     k_full.shape[3] * pair, dh):
             return fused_decode_step(q, k_full, v_full, k_new, v_new,
-                                     layer, idx, scale=scale)
+                                     layer, idx, scale=scale,
+                                     active=active)
     if pair > 1:  # unpack for the einsum path (free on CPU; prefill-only
         # on TPU, where the repack copy is once per generate, not per step)
         l, b, hkv, sp, dhp = k_full.shape
@@ -208,8 +215,9 @@ def write_kv_cache(k_full, v_full, k_new, v_new, layer, idx):
     past the accepted prefix stay dead behind the per-slot length vector
     (rollback-by-masking, no copies). ``mode="drop"`` makes any position
     past the allocation a silent no-op instead of undefined behavior
-    (inactive slots carry stale lengths; their masked garbage writes must
-    never land out of bounds)."""
+    (this path does not know which slots are active: an inactive slot
+    carries a stale length, and its masked garbage write, which the fused
+    decode step skips, must never land out of bounds)."""
     if jnp.ndim(idx) == 1:
         b, t = k_new.shape[0], k_new.shape[1]
         rows = jnp.broadcast_to(jnp.arange(b)[:, None], (b, t))

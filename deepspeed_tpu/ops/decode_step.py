@@ -18,9 +18,22 @@ Why manual DMA instead of a gridded ``pallas_call``: the gridded decode
 kernels measured ~2 us of per-grid-cell overhead, which at 125M shapes
 (40 cells/layer) cost 5x more than the cache streaming itself. Here the
 whole layer-step is ONE invocation: a dynamic ``fori_loop`` walks the
-VALID prefix of the cache in token chunks (one strided DMA covers all
-batch rows), double-buffered so the VPU/MXU math overlaps the next
-chunk's fetch, with the online-softmax state in VMEM scratch.
+VALID prefix of the cache in token chunks, double-buffered so the VPU/MXU
+math overlaps the next chunk's fetch, with the online-softmax state in
+VMEM scratch. Two walks, chosen by the shape of ``idx``:
+
+* a scalar ``idx`` (generate(): every row live and equally long) takes the
+  uniform walk, ``_kernel``: one strided DMA a chunk covers all rows of a
+  batch group;
+* a per-slot ``[B]`` vector (continuous batching) takes ``_slot_kernel``,
+  which fetches LIVE rows only. The decode program hands it the slots in
+  walk order (``slot_walk``: the active ones by descending length); each
+  row of a group of like length has its own DMA a chunk, started only
+  while that row has cache rows left, and a slot that is not decoding is
+  neither read nor written, whatever length it still carries. Until PR 33
+  a group of eight NEIGHBOURING slots walked to its longest length, stale
+  lengths of freed slots included, and three quarters of what the kernel
+  fetched at the chip's peak was dead (PERF.md, PR 33).
 
 Head-dim handling: Mosaic requires DMA slices of the minor dim to be
 128-aligned, so for Dh < 128 the cache is VIEWED as token-pairs
@@ -29,7 +42,8 @@ buffer; ``pair = 128 // Dh``). Packed sub-tokens are never interleaved
 back: each of the ``pair`` lane slices keeps its own position mask and
 feeds the shared online-softmax state. The new token's write is a
 read-modify-write of the 8-aligned pair-row window (HBM tiling forbids
-single-row writes), a ~100 KB round-trip per layer step.
+single-row writes), a ~100 KB round-trip per layer step (per ACTIVE slot
+in the per-slot walk).
 
 MHA (rep == 1) scores/PV run as VPU broadcast-multiply + reduce;
 GQA (rep > 1) runs batched MXU ``dot_general`` ([rep, Dh] x [Dh, CS]
@@ -40,7 +54,7 @@ ops/flash_attention.py).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +70,10 @@ _NEG = float("-inf")
 # stall per layer instead of B/bg).
 _CHUNK_BUDGET = 3_300_000
 _VMEM_LIMIT = 40 * 1024 * 1024
+# per-slot walk: all four chunk buffers of a group's rows together, and
+# the tokens a row's last fetch rounds up to
+_SLOT_BUFFERS = 12 * 1024 * 1024
+_SLOT_CHUNK = 128
 
 
 def _compiler_params(vmem_bytes: int = _VMEM_LIMIT):
@@ -164,14 +182,84 @@ def _resolve_block_plan(b: int, hkv: int, bs: int, dh: int, itemsize: int,
     return vmem, mha
 
 
+def _attend_chunk(qv, kc, load_v, valid, m_ref, l_ref, acc_ref, *,
+                  hq: int, hkv: int, dh: int, pair: int, scale: float,
+                  mha: str):
+    """One chunk of the online softmax, shared by the two slot-paged
+    kernels. ``qv [bg, Hq, 1, Dh]`` (the unit dim comes pre-shaped from the
+    wrapper: Mosaic cannot reshape bf16 vectors to add one before the minor
+    dim); ``kc [bg, Hkv, CSP, Dh*pair]`` the loaded K chunk; ``load_v()``
+    gives the V chunk and is called after the scores, so a kernel waits
+    for V there; ``valid(h, shape)`` is the position mask of packed lane
+    slice ``h`` (each slice keeps its own position stream). State in
+    ``m_ref / l_ref [bg, Hq]``, ``acc_ref [bg, Hq, Dh]``.
+
+    bf16: products run in bf16 with f32 accumulation — the same precision
+    contract as the einsum path's MXU (bf16 multiply, f32 accumulate); a
+    full f32 materialization of both chunks measured ~2x the VPU time."""
+    bg, _, csp, _ = kc.shape
+    rep = hq // hkv
+    ss = []
+    for h in range(pair):
+        k = kc[..., h * dh:(h + 1) * dh]    # [bg, Hkv, CSP, Dh]
+        if rep == 1 and mha == "vpu":
+            s = jnp.sum(qv * k, -1,
+                        dtype=jnp.float32)         # VPU [bg, H, CSP]
+        else:
+            # MXU [rep, Dh] x [Dh, CS] slabs per kv head (rep==1
+            # degenerates to [1, Dh] matvecs — the ISSUE 12
+            # default; the autotuned plan can select "vpu" back)
+            qg = qv.reshape(bg * hkv, rep, dh)     # 1 batch dim
+            kg = k.reshape(bg * hkv, csp, dh)      # (Mosaic limit)
+            s = jax.lax.dot_general(               # MXU
+                qg, kg, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            s = s.reshape(bg, hq, csp)
+        s = s * scale
+        ss.append(jnp.where(valid(h, s.shape), s, _NEG))
+
+    m_prev = m_ref[...]                            # [bg, Hq]
+    m_new = m_prev
+    for s in ss:
+        m_new = jnp.maximum(m_new, s.max(-1))
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_ref[...] * corr
+    acc = acc_ref[...] * corr[:, :, None]
+    ps = [jnp.exp(s - m_new[:, :, None]) for s in ss]
+    for p in ps:
+        l_new = l_new + p.sum(-1)
+
+    vc = load_v()
+    for h, p in enumerate(ps):
+        v = vc[..., h * dh:(h + 1) * dh]
+        if rep == 1 and mha == "vpu":
+            pb = p[:, :, :, None].astype(v.dtype)  # None-insert in
+            # f32 (bf16 unit-dim reshape is unsupported), cast after
+            pv = jnp.sum(pb * v, 2,
+                         dtype=jnp.float32)        # VPU [bg, H, Dh]
+        else:
+            pg = p.reshape(bg * hkv, rep, csp).astype(v.dtype)
+            vg = v.reshape(bg * hkv, csp, dh)
+            pv = jax.lax.dot_general(              # MXU
+                pg, vg, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+            pv = pv.reshape(bg, hq, dh)
+        acc = acc + pv
+    l_ref[...] = l_new
+    acc_ref[...] = acc
+    m_ref[...] = m_new
+
+
 def _kernel(layer_ref, idx_ref, q_ref, kn_ref, vn_ref, _kin_ref, _vin_ref,
             attn_ref, k_ref, v_ref,
             kbuf, vbuf, kwin, vwin, m_ref, l_ref, acc_ref, wsem, rsem,
             *, b: int, bg: int, cs: int, hq: int, hkv: int, dh: int,
-            pair: int, scale: float, per_slot: bool, mha: str = "mxu"):
+            pair: int, scale: float, mha: str = "mxu"):
+    """The uniform walk (scalar ``idx``: generate(), every row live and
+    equally long): one strided DMA a chunk covers all rows of a batch
+    group. Per-slot lengths take :func:`_slot_kernel`."""
     layer = layer_ref[0]
     idx = idx_ref[0]
-    rep = hq // hkv
     csp = cs // pair          # pair-rows per chunk
     dhp = dh * pair           # packed minor dim (>= 128)
 
@@ -183,104 +271,39 @@ def _kernel(layer_ref, idx_ref, q_ref, kn_ref, vn_ref, _kin_ref, _vin_ref,
     # new token into the loaded chunk IN-REGISTER (see `body`), so no
     # read waits on the write-back (a serialized RMW measured +0.13
     # ms/tok at B=1 — pure DMA latency, 12 layers x 4 chained waits).
-    #
-    # per_slot (continuous batching): idx_ref is a [B] vector of per-slot
-    # valid lengths — each row's window is its own DMA (rows' write
-    # positions are unrelated), and the splice/position masks below go
-    # per-row. The chunk walk streams each batch group to the GROUP MAX
-    # length (shorter slots' tails are masked, not skipped: one strided
-    # DMA still covers all rows of the group).
-    if per_slot:
-        w0s = [(idx_ref[i] // pair // 8) * 8 for i in range(b)]
+    w0 = (idx // pair // 8) * 8
+    fk = pltpu.make_async_copy(
+        k_ref.at[layer, :, :, pl.ds(w0, 8), :], kwin, wsem.at[0, 0])
+    fv = pltpu.make_async_copy(
+        v_ref.at[layer, :, :, pl.ds(w0, 8), :], vwin, wsem.at[1, 0])
+    fk.start()
+    fv.start()
 
-        def kdma(i):
-            return pltpu.make_async_copy(
-                k_ref.at[layer, pl.ds(i, 1), :, pl.ds(w0s[i], 8), :],
-                kwin.at[pl.ds(i, 1)], wsem.at[0, i])
-
-        def vdma(i):
-            return pltpu.make_async_copy(
-                v_ref.at[layer, pl.ds(i, 1), :, pl.ds(w0s[i], 8), :],
-                vwin.at[pl.ds(i, 1)], wsem.at[1, i])
-
-        for i in range(b):
-            kdma(i).start()
-            vdma(i).start()
-
-        def finish_write():
-            for i in range(b):
-                kdma(i).wait()
-                vdma(i).wait()
-            bi = jax.lax.broadcasted_iota(jnp.int32, (b, hkv, 8, dhp), 0)
-            ri = jax.lax.broadcasted_iota(jnp.int32, (b, hkv, 8, dhp), 2)
-            li = jax.lax.broadcasted_iota(jnp.int32, (b, hkv, 8, dhp), 3)
-            sel = bi < 0  # all-false
-            for i in range(b):
-                idx_i = idx_ref[i]
-                sel_i = (bi == i) & (ri == jax.lax.rem(idx_i // pair, 8))
-                if pair > 1:
-                    sel_i &= (li // dh == idx_i - (idx_i // pair) * pair)
-                sel |= sel_i
-            kwin[...] = jnp.where(sel, kn_ref[...], kwin[...])
-            vwin[...] = jnp.where(sel, vn_ref[...], vwin[...])
-            for i in range(b):
-                pltpu.make_async_copy(
-                    kwin.at[pl.ds(i, 1)],
-                    k_ref.at[layer, pl.ds(i, 1), :, pl.ds(w0s[i], 8), :],
-                    wsem.at[0, i]).start()
-                pltpu.make_async_copy(
-                    vwin.at[pl.ds(i, 1)],
-                    v_ref.at[layer, pl.ds(i, 1), :, pl.ds(w0s[i], 8), :],
-                    wsem.at[1, i]).start()
-    else:
-        w0 = (idx // pair // 8) * 8
-        fk = pltpu.make_async_copy(
-            k_ref.at[layer, :, :, pl.ds(w0, 8), :], kwin, wsem.at[0, 0])
-        fv = pltpu.make_async_copy(
-            v_ref.at[layer, :, :, pl.ds(w0, 8), :], vwin, wsem.at[1, 0])
-        fk.start()
-        fv.start()
-
-        def finish_write():
-            """Insert the token into the fetched window and write it back —
-            called after the first chunk DMAs are in flight."""
-            fk.wait()
-            fv.wait()
-            row = idx // pair - w0
-            half = idx - (idx // pair) * pair
-            sel = (jax.lax.broadcasted_iota(
-                jnp.int32, (b, hkv, 8, dhp), 2) == row)
-            if pair > 1:
-                sel &= (jax.lax.broadcasted_iota(
-                    jnp.int32, (b, hkv, 8, dhp), 3) // dh == half)
-            kwin[...] = jnp.where(sel, kn_ref[...], kwin[...])
-            vwin[...] = jnp.where(sel, vn_ref[...], vwin[...])
-            pltpu.make_async_copy(
-                kwin, k_ref.at[layer, :, :, pl.ds(w0, 8), :],
-                wsem.at[0, 0]).start()
-            pltpu.make_async_copy(
-                vwin, v_ref.at[layer, :, :, pl.ds(w0, 8), :],
-                wsem.at[1, 0]).start()
+    def finish_write():
+        """Insert the token into the fetched window and write it back —
+        called after the first chunk DMAs are in flight."""
+        fk.wait()
+        fv.wait()
+        row = idx // pair - w0
+        half = idx - (idx // pair) * pair
+        sel = (jax.lax.broadcasted_iota(
+            jnp.int32, (b, hkv, 8, dhp), 2) == row)
+        if pair > 1:
+            sel &= (jax.lax.broadcasted_iota(
+                jnp.int32, (b, hkv, 8, dhp), 3) // dh == half)
+        kwin[...] = jnp.where(sel, kn_ref[...], kwin[...])
+        vwin[...] = jnp.where(sel, vn_ref[...], vwin[...])
+        pltpu.make_async_copy(
+            kwin, k_ref.at[layer, :, :, pl.ds(w0, 8), :],
+            wsem.at[0, 0]).start()
+        pltpu.make_async_copy(
+            vwin, v_ref.at[layer, :, :, pl.ds(w0, 8), :],
+            wsem.at[1, 0]).start()
 
     nchunks = idx // cs + 1  # valid-prefix walk: dead chunks never fetched
 
     for g in range(b // bg):  # static unroll over batch groups
         b0 = g * bg
-        if per_slot:
-            gmax = idx_ref[b0]
-            for i in range(1, bg):
-                gmax = jnp.maximum(gmax, idx_ref[b0 + i])
-            nchunks = gmax // cs + 1
-
-        def group_idx_vec(shape):
-            """int32 [shape] with entry (i, ...) == idx_ref[b0 + i] —
-            per-row lengths broadcast into a vector register (built by
-            bg unrolled selects: SMEM scalars can't gather)."""
-            bi = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-            out = jnp.zeros(shape, jnp.int32)
-            for i in range(bg):
-                out = jnp.where(bi == i, idx_ref[b0 + i], out)
-            return out
 
         def chunk_dma(slot, c, src, buf, t):
             return pltpu.make_async_copy(
@@ -296,8 +319,6 @@ def _kernel(layer_ref, idx_ref, q_ref, kn_ref, vn_ref, _kin_ref, _vin_ref,
         if g == 0:
             finish_write()  # overlaps with chunk 0's flight
         qv = q_ref[pl.ds(b0, bg)]                    # [bg, Hq, 1, Dh] bf16
-        # (the unit dim comes pre-shaped from the wrapper: Mosaic cannot
-        # reshape bf16 vectors to add one before the minor dim)
 
         def body(c, _, splice=False):
             slot = jax.lax.rem(c, 2)
@@ -308,21 +329,11 @@ def _kernel(layer_ref, idx_ref, q_ref, kn_ref, vn_ref, _kin_ref, _vin_ref,
                 chunk_dma(nxt, c + 1, k_ref, kbuf, 0).start()
                 chunk_dma(nxt, c + 1, v_ref, vbuf, 1).start()
 
-            # splice mask (shared by K now and V below): each row's new
-            # token lands at its own position (per_slot: any chunk of the
-            # group walk; uniform: only the final chunk — the prefix walk
-            # never pays the vector work)
+            # splice mask (shared by K now and V below): the new token
+            # lands in the final chunk only — the prefix walk never pays
+            # the vector work
             spl = None
-            if per_slot:
-                idxm = group_idx_vec((bg, hkv, csp, dhp))
-                rowg = c * csp + jax.lax.broadcasted_iota(
-                    jnp.int32, (bg, hkv, csp, dhp), 2)
-                spl = rowg == idxm // pair
-                if pair > 1:
-                    spl &= (jax.lax.broadcasted_iota(
-                        jnp.int32, (bg, hkv, csp, dhp), 3) // dh
-                            == idxm - (idxm // pair) * pair)
-            elif splice:
+            if splice:
                 # in-register splice of the new token (its async cache
                 # write may still be in flight; every other row is
                 # unchanged, so a read/write race can only return
@@ -341,97 +352,213 @@ def _kernel(layer_ref, idx_ref, q_ref, kn_ref, vn_ref, _kin_ref, _vin_ref,
             # VPU/MXU math it could hide under)
             chunk_dma(slot, c, k_ref, kbuf, 0).wait()
             kc = kbuf[slot]                         # [bg, Hkv, CSP, Dh*pair]
-            # bf16: products run in bf16 with f32 accumulation — the same
-            # precision contract as the einsum path's MXU (bf16 multiply,
-            # f32 accumulate); a full f32 materialization of both chunks
-            # measured ~2x the VPU time
             if spl is not None:
                 kc = jnp.where(spl, kn_ref[pl.ds(b0, bg)], kc)
-            # scores for each packed lane slice (its own position stream)
-            ss = []
-            for h in range(pair):
-                k = kc[..., h * dh:(h + 1) * dh]    # [bg, Hkv, CSP, Dh]
-                if rep == 1 and mha == "vpu":
-                    s = jnp.sum(qv * k, -1,
-                                dtype=jnp.float32)         # VPU [bg, H, CSP]
-                else:
-                    # MXU [rep, Dh] x [Dh, CS] slabs per kv head (rep==1
-                    # degenerates to [1, Dh] matvecs — the ISSUE 12
-                    # default; the autotuned plan can select "vpu" back)
-                    qg = qv.reshape(bg * hkv, rep, dh)     # 1 batch dim
-                    kg = k.reshape(bg * hkv, csp, dh)      # (Mosaic limit)
-                    s = jax.lax.dot_general(               # MXU
-                        qg, kg, (((2,), (2,)), ((0,), (0,))),
-                        preferred_element_type=jnp.float32)
-                    s = s.reshape(bg, hq, csp)
-                s = s * scale
+
+            def valid(h, shape):
                 pos = c * cs + pair * jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 2) + h
-                bound = group_idx_vec(s.shape) if per_slot else idx
-                ss.append(jnp.where(pos <= bound, s, _NEG))
+                    jnp.int32, shape, 2) + h
+                return pos <= idx
 
-            m_prev = m_ref[...]                            # [bg, Hq]
-            m_new = m_prev
-            for s in ss:
-                m_new = jnp.maximum(m_new, s.max(-1))
-            corr = jnp.exp(m_prev - m_new)
-            l_new = l_ref[...] * corr
-            acc = acc_ref[...] * corr[:, :, None]
-            ps = [jnp.exp(s - m_new[:, :, None]) for s in ss]
-            for p in ps:
-                l_new = l_new + p.sum(-1)
+            def load_v():
+                chunk_dma(slot, c, v_ref, vbuf, 1).wait()
+                vc = vbuf[slot]
+                if spl is not None:
+                    vc = jnp.where(spl, vn_ref[pl.ds(b0, bg)], vc)
+                return vc
 
-            chunk_dma(slot, c, v_ref, vbuf, 1).wait()
-            vc = vbuf[slot]
-            if spl is not None:
-                vc = jnp.where(spl, vn_ref[pl.ds(b0, bg)], vc)
-            for h, p in enumerate(ps):
-                v = vc[..., h * dh:(h + 1) * dh]
-                if rep == 1 and mha == "vpu":
-                    pb = p[:, :, :, None].astype(v.dtype)  # None-insert in
-                    # f32 (bf16 unit-dim reshape is unsupported), cast after
-                    pv = jnp.sum(pb * v, 2,
-                                 dtype=jnp.float32)        # VPU [bg, H, Dh]
-                else:
-                    pg = p.reshape(bg * hkv, rep, csp).astype(v.dtype)
-                    vg = v.reshape(bg * hkv, csp, dh)
-                    pv = jax.lax.dot_general(              # MXU
-                        pg, vg, (((2,), (1,)), ((0,), (0,))),
-                        preferred_element_type=jnp.float32)
-                    pv = pv.reshape(bg, hq, dh)
-                acc = acc + pv
-            l_ref[...] = l_new
-            acc_ref[...] = acc
-            m_ref[...] = m_new
+            _attend_chunk(qv, kc, load_v, valid, m_ref, l_ref, acc_ref,
+                          hq=hq, hkv=hkv, dh=dh, pair=pair, scale=scale,
+                          mha=mha)
             return 0
 
-        if per_slot:
-            # every chunk splices (the per-row masks gate it), so the walk
-            # is one uniform loop to the group-max chunk count
-            jax.lax.fori_loop(0, nchunks, body, 0)
-        else:
-            jax.lax.fori_loop(0, nchunks - 1, body, 0)
-            body(nchunks - 1, 0, splice=True)
+        jax.lax.fori_loop(0, nchunks - 1, body, 0)
+        body(nchunks - 1, 0, splice=True)
         l_safe = jnp.maximum(l_ref[...], 1e-20)
         attn_ref[pl.ds(b0, bg)] = (acc_ref[...] / l_safe[:, :, None]) \
             .astype(attn_ref.dtype)
 
     # drain the async write-back before the kernel exits
-    if per_slot:
-        for i in range(b):
-            pltpu.make_async_copy(
-                kwin.at[pl.ds(i, 1)],
-                k_ref.at[layer, pl.ds(i, 1), :, pl.ds(w0s[i], 8), :],
-                wsem.at[0, i]).wait()
-            pltpu.make_async_copy(
-                vwin.at[pl.ds(i, 1)],
-                v_ref.at[layer, pl.ds(i, 1), :, pl.ds(w0s[i], 8), :],
-                wsem.at[1, i]).wait()
-    else:
-        pltpu.make_async_copy(
-            kwin, k_ref.at[layer, :, :, pl.ds(w0, 8), :], wsem.at[0, 0]).wait()
-        pltpu.make_async_copy(
-            vwin, v_ref.at[layer, :, :, pl.ds(w0, 8), :], wsem.at[1, 0]).wait()
+    pltpu.make_async_copy(
+        kwin, k_ref.at[layer, :, :, pl.ds(w0, 8), :], wsem.at[0, 0]).wait()
+    pltpu.make_async_copy(
+        vwin, v_ref.at[layer, :, :, pl.ds(w0, 8), :], wsem.at[1, 0]).wait()
+
+
+def _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, q_ref, kn_ref, vn_ref,
+                 _kin_ref, _vin_ref, attn_ref, k_ref, v_ref,
+                 kbuf, vbuf, kwin, vwin, qrow, knrow, vnrow,
+                 m_ref, l_ref, acc_ref, wsem, rsem,
+                 *, b: int, bg: int, cs: int, hq: int, hkv: int, dh: int,
+                 pair: int, scale: float, mha: str = "mxu"):
+    """The per-slot walk (continuous batching): ``idx_ref [B]`` holds each
+    slot's own length, ``order_ref [B]`` the slots with the active ones
+    first by descending length (:func:`slot_walk`), ``n_ref [1]`` how many
+    are active. Only what an active slot needs is fetched or written:
+
+    * Everything goes by SORTED POSITION ``p``: row ``j`` of group ``g`` is
+      slot ``order[g * bg + j]``. Sorted slots are not neighbours in HBM,
+      so each row has its own DMA per chunk into row ``j`` of the group's
+      buffer, started only while the row has cache rows left
+      (``c < ceil(len / cs)``) and waited on under the same condition. A
+      group walks to its FIRST row's chunk count (the longest); groups
+      beyond ``ceil(n_active / bg)`` do not run. The chunk of group ``g+1``
+      is prefetched under the last chunk of group ``g``: one DMA warm-up
+      stall a layer, not one a group.
+    * A buffer row whose DMA was skipped holds whatever an earlier chunk
+      left there, and ``0 * NaN`` in the PV product is NaN, so ``vbuf`` is
+      zeroed on entry: from then on it only ever holds cache rows of
+      active slots (the K side is masked by select, which drops a NaN).
+    * The new token is not spliced into a chunk: the online softmax starts
+      from it (a one-position chunk made of ``k_new`` / ``v_new``), and the
+      cache walk covers the positions strictly before it. So no chunk pays
+      the splice's two full-chunk selects, and a row of length 0 fetches
+      nothing.
+    * An inactive slot's 8-row write window is neither read nor written
+      (a slot midway through a chunked prefill is inactive here while its
+      rows are live), and its output row is zero."""
+    layer = layer_ref[0]
+    n_act = n_ref[0]
+    csp = cs // pair          # pair-rows per chunk
+    dhp = dh * pair           # packed minor dim (>= 128)
+
+    def slot_at(p):
+        # (clamped: a position past the last slot is never active)
+        return order_ref[jnp.minimum(p, b - 1)]
+
+    def nch_at(p):
+        """Chunks of cache rows sorted position ``p`` fetches."""
+        return jnp.where(p < n_act, (idx_ref[slot_at(p)] + cs - 1) // cs, 0)
+
+    # ---- the active slots' new K/V into the cache: the same 8-row window
+    # read-modify-write as the uniform kernel's, one window a slot (write
+    # positions are unrelated), fully async under the walk
+    def win_copy(p, t, back: bool):
+        s = slot_at(p)
+        w0 = (idx_ref[s] // pair // 8) * 8
+        hbm = (k_ref, v_ref)[t].at[layer, pl.ds(s, 1), :, pl.ds(w0, 8), :]
+        win = (kwin, vwin)[t].at[pl.ds(p, 1)]
+        return pltpu.make_async_copy(win, hbm, wsem.at[t, p]) if back \
+            else pltpu.make_async_copy(hbm, win, wsem.at[t, p])
+
+    def each_active(fn):
+        def step(p, _):
+            fn(p)
+            return 0
+        jax.lax.fori_loop(0, n_act, step, 0)
+
+    def fetch_window(p):
+        win_copy(p, 0, False).start()
+        win_copy(p, 1, False).start()
+
+    def insert_token(p):
+        win_copy(p, 0, False).wait()
+        win_copy(p, 1, False).wait()
+        s = slot_at(p)
+        i = idx_ref[s]
+        sel = (jax.lax.broadcasted_iota(jnp.int32, (1, hkv, 8, dhp), 2)
+               == jax.lax.rem(i // pair, 8))
+        if pair > 1:
+            sel &= (jax.lax.broadcasted_iota(
+                jnp.int32, (1, hkv, 8, dhp), 3) // dh
+                    == jax.lax.rem(i, pair))
+        kwin[pl.ds(p, 1)] = jnp.where(sel, kn_ref[pl.ds(s, 1)],
+                                      kwin[pl.ds(p, 1)])
+        vwin[pl.ds(p, 1)] = jnp.where(sel, vn_ref[pl.ds(s, 1)],
+                                      vwin[pl.ds(p, 1)])
+        win_copy(p, 0, True).start()
+        win_copy(p, 1, True).start()
+
+    def drain_window(p):
+        win_copy(p, 0, True).wait()
+        win_copy(p, 1, True).wait()
+
+    # ---- the walk
+    def chunk_copy(p, j, c, slot, t):
+        return pltpu.make_async_copy(
+            (k_ref, v_ref)[t].at[layer, pl.ds(slot_at(p), 1), :,
+                                 pl.ds(c * csp, csp), :],
+            (kbuf, vbuf)[t].at[slot, pl.ds(j, 1)], rsem.at[slot, t, j])
+
+    def each_row(g, c, fn):
+        """``fn(p, j)`` for the rows of group ``g`` that hold chunk ``c``."""
+        for j in range(bg):
+            p = g * bg + j
+
+            @pl.when(c < nch_at(p))
+            def _():
+                fn(p, j)
+
+    def start_chunk(g, c, slot):
+        def go(p, j):
+            chunk_copy(p, j, c, slot, 0).start()
+            chunk_copy(p, j, c, slot, 1).start()
+        each_row(g, c, go)
+
+    attn_ref[...] = jnp.zeros_like(attn_ref)
+    vbuf[...] = jnp.zeros_like(vbuf)
+    each_active(fetch_window)
+    start_chunk(0, 0, 0)
+    each_active(insert_token)    # overlaps with chunk 0's flight
+
+    def group(g, t):
+        """Group ``g`` from linear step ``t`` (its parity names the buffer
+        the step computes on) -> the next group's first step."""
+        nch_g = nch_at(g * bg)
+        lens = jnp.zeros((bg, hq, csp), jnp.int32)
+        rows = jax.lax.broadcasted_iota(jnp.int32, lens.shape, 0)
+        for j in range(bg):      # SMEM scalars cannot gather: bg selects
+            p = g * bg + j
+            s = slot_at(p)
+            lens = jnp.where(rows == j,
+                             jnp.where(p < n_act, idx_ref[s], 0), lens)
+            qrow[pl.ds(j, 1)] = q_ref[pl.ds(s, 1)]
+            knrow[pl.ds(j, 1)] = kn_ref[pl.ds(s, 1)]
+            vnrow[pl.ds(j, 1)] = vn_ref[pl.ds(s, 1)]
+        qv = qrow[...]                               # [bg, Hq, 1, Dh] bf16
+        attend = functools.partial(
+            _attend_chunk, qv, m_ref=m_ref, l_ref=l_ref, acc_ref=acc_ref,
+            hq=hq, hkv=hkv, dh=dh, pair=pair, scale=scale, mha=mha)
+
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        # the new token first: one live position of an 8-row chunk
+        attend(jnp.broadcast_to(knrow[...], (bg, hkv, 8, dhp)),
+               lambda: jnp.broadcast_to(vnrow[...], (bg, hkv, 8, dhp)),
+               lambda h, shape: jax.lax.broadcasted_iota(
+                   jnp.int32, shape, 2) < (1 if h == 0 else 0))
+
+        def body(c, t):
+            slot = jax.lax.rem(t, 2)
+            more = c + 1 < nch_g
+            start_chunk(jnp.where(more, g, g + 1),
+                        jnp.where(more, c + 1, 0), 1 - slot)
+            # K first: the scores run while V is still in flight
+            each_row(g, c, lambda p, j: chunk_copy(p, j, c, slot, 0).wait())
+
+            def load_v():
+                each_row(g, c,
+                         lambda p, j: chunk_copy(p, j, c, slot, 1).wait())
+                return vbuf[slot]
+
+            attend(kbuf[slot], load_v,
+                   lambda h, shape: c * cs + pair * jax.lax.broadcasted_iota(
+                       jnp.int32, shape, 2) + h < lens)
+            return t + 1
+
+        t = jax.lax.fori_loop(0, nch_g, body, t)
+        out = (acc_ref[...] / l_ref[...][:, :, None]).astype(attn_ref.dtype)
+        for j in range(bg):      # back in slot order
+            p = g * bg + j
+
+            @pl.when(p < n_act)
+            def _():
+                attn_ref[pl.ds(slot_at(p), 1)] = out[j:j + 1]
+        return t
+
+    jax.lax.fori_loop(0, (n_act + bg - 1) // bg, group, 0)
+    each_active(drain_window)    # before the kernel exits
 
 
 def supports_block(hq: int, hkv: int, block_size: int, dh: int) -> bool:
@@ -884,9 +1011,61 @@ def fused_block_decode_step(q: jax.Array, k_pool, v_pool,
     return attn[:, None], k_out, v_out
 
 
+class SlotWalk(NamedTuple):
+    """What the per-slot kernel walks by, made once a decode step
+    (:func:`slot_walk`) and shared by every layer's call."""
+    order: jax.Array       # [B] int32: active slots by descending length,
+    #                        then the inactive ones
+    n_active: jax.Array    # [1] int32
+
+
+def slot_walk(lengths, active=None) -> SlotWalk:
+    """The walk order of a decode step over per-slot ``lengths [B]``:
+    ``active [B]`` (bool, or 0 / 1; ``None``: every slot) slots first, the
+    longest first, so a group of neighbours in the order is of like length;
+    ties and the inactive tail keep slot order. A freed slot's stale length
+    does not matter: it sorts behind every active slot."""
+    lengths = jnp.asarray(lengths, jnp.int32).reshape(-1)
+    act = jnp.ones(lengths.shape, bool) if active is None \
+        else jnp.asarray(active).reshape(-1) != 0
+    order = jnp.argsort(jnp.where(act, -lengths, 1), stable=True)
+    return SlotWalk(order.astype(jnp.int32),
+                    jnp.sum(act).astype(jnp.int32).reshape(1))
+
+
+def _slot_plan(b: int, hkv: int, s_max: int, dh: int, itemsize: int):
+    """(bg, cs) of the per-slot walk, by measurement on the v5e (PERF.md,
+    PR 33). A row has its own DMA a chunk, so ``bg`` is free of the uniform
+    plan's one-DMA-covers-the-group sizing: it is how many rows of like
+    length share one loop step and one masked compute pass. At gpt2-large's
+    geometry 36 layers took 2.38 / 2.48 / 2.89 / 3.87 ms at ``bg`` 2 / 4 /
+    8 / 16 with 18 of 32 slots active and 4.15 / 4.01 / 4.28 ms at 2 / 4 /
+    8 with all 32: wider groups compute more masked rows, narrower ones
+    pay more loop steps. ``cs`` is what a row's tail rounds up to
+    (:func:`decode_rows_fetched`): 256 fetched 9% more rows for no gain.
+    The four chunk buffers (2 slots x {K, V}) of a group stay inside
+    ``_SLOT_BUFFERS``."""
+    cs = _SLOT_CHUNK
+    bg = next(g for g in (4, 2, 1) if b % g == 0)
+    while bg > 1 and 4 * bg * hkv * cs * dh * itemsize > _SLOT_BUFFERS:
+        bg //= 2
+    return bg, cs
+
+
+def decode_rows_fetched(active_lengths, cs: int = _SLOT_CHUNK) -> int:
+    """Cache rows the per-slot walk fetches in one layer of a decode step,
+    in plain integers for the host's bookkeeping: ``active_lengths`` are
+    the ACTIVE slots' cache lengths (the rows before the token fed now),
+    each rounded up to the walk's chunk; an inactive slot fetches nothing,
+    whatever its stale length. The live rows among them are
+    ``sum(active_lengths)``."""
+    return sum(-(-int(n) // cs) * cs for n in active_lengths)
+
+
 def fused_decode_step(q: jax.Array, k_full: jax.Array, v_full: jax.Array,
                       k_new: jax.Array, v_new: jax.Array,
-                      layer, idx, *, scale: Optional[float] = None,
+                      layer, idx, *, active=None,
+                      scale: Optional[float] = None,
                       interpret: Optional[bool] = None,
                       plan: Optional[dict] = None):
     """One decode layer-step against the FULL stacked cache.
@@ -897,12 +1076,19 @@ def fused_decode_step(q: jax.Array, k_full: jax.Array, v_full: jax.Array,
     layer:        scalar int32 — layer index
     idx:          scalar int32 first free cache position, or a PER-SLOT
                   [B] int32 vector of valid lengths (continuous batching,
-                  serving/engine.py) — each row then writes at and
-                  attends over its own prefix, and each batch group
-                  streams to the group's max length.
+                  serving/engine.py) — each active row then writes at and
+                  attends over its own prefix, and fetches its own prefix
+                  only (:func:`_slot_kernel`).
+    active:       per-slot ``idx`` only: which slots decode this step — a
+                  ``[B]`` mask (bool, or 0 / 1), or the :class:`SlotWalk`
+                  made of it once a step (:func:`slot_walk`); ``None``:
+                  every slot. An inactive slot's cache is neither read nor
+                  written and its output row is zero.
     plan:         optional measured-plan override (the autotune
                   harness's candidate; ops/autotune.py entries are
-                  consulted otherwise — ``_resolve_plan``).
+                  consulted otherwise — ``_resolve_plan``). The per-slot
+                  walk takes ``bg`` / ``cs`` from :func:`_slot_plan`
+                  unless the override names them.
 
     Returns ``(attn [B, 1, Hq, Dh], k_full, v_full)`` with the caches
     updated in place (the returned caches alias the inputs).
@@ -916,8 +1102,9 @@ def fused_decode_step(q: jax.Array, k_full: jax.Array, v_full: jax.Array,
     assert pair in (1, 128 // dh if dh < 128 else 1), (d_last, dh)
     want_pair = 128 // dh if dh < 128 else 1
     sc = float(scale) if scale is not None else dh ** -0.5
-    bg, cs, vmem, mha = _resolve_plan(
-        b, hkv, s_max, dh, jnp.dtype(k_full.dtype).itemsize, override=plan)
+    itemsize = jnp.dtype(k_full.dtype).itemsize
+    bg, cs, vmem, mha = _resolve_plan(b, hkv, s_max, dh, itemsize,
+                                      override=plan)
 
     qf = q.transpose(0, 2, 1, 3)                   # [B, Hq, 1, Dh]
     kn = k_new.transpose(0, 2, 1, 3)               # [B, Hkv, 1, Dh]
@@ -939,18 +1126,49 @@ def fused_decode_step(q: jax.Array, k_full: jax.Array, v_full: jax.Array,
     assert idx_a.shape[0] in (1, b), (idx_a.shape, b)
     per_slot = idx_a.shape[0] > 1  # [1] degenerates to the uniform path
 
-    kernel = functools.partial(
-        _kernel, b=b, bg=bg, cs=cs, hq=hq, hkv=hkv, dh=dh, pair=pair,
-        scale=sc, per_slot=per_slot, mha=mha)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem_spec = pl.BlockSpec(memory_space=pltpu.VMEM)
+    scalars = [layer_a, idx_a]
+    geometry = dict(b=b, hq=hq, hkv=hkv, dh=dh, pair=pair, scale=sc, mha=mha)
+    if per_slot:
+        walk = active if isinstance(active, SlotWalk) \
+            else slot_walk(idx_a, active)
+        scalars += [walk.order, walk.n_active]
+        if not (plan and {"bg", "cs"} <= plan.keys()):
+            bg, cs = _slot_plan(b, hkv, s_max, dh, itemsize)
+        kernel = functools.partial(_slot_kernel, bg=bg, cs=cs, **geometry)
+    else:
+        kernel = functools.partial(_kernel, bg=bg, cs=cs, **geometry)
+    chunk = (2, bg, hkv, cs // pair, dh * pair)
+    scratch = [
+        pltpu.VMEM(chunk, k_full.dtype),
+        pltpu.VMEM(chunk, v_full.dtype),
+        pltpu.VMEM((b, hkv, 8, dh * pair), k_full.dtype),  # write window
+        pltpu.VMEM((b, hkv, 8, dh * pair), v_full.dtype),
+    ]
+    if per_slot:
+        scratch += [  # a group's rows gathered by sorted position
+            pltpu.VMEM((bg, hq, 1, dh), q.dtype),
+            pltpu.VMEM((bg, hkv, 1, dh * pair), kn.dtype),
+            pltpu.VMEM((bg, hkv, 1, dh * pair), vn.dtype),
+        ]
+    scratch += [
+        pltpu.VMEM((bg, hq), jnp.float32),                 # running max
+        pltpu.VMEM((bg, hq), jnp.float32),                 # running sum
+        pltpu.VMEM((bg, hq, dh), jnp.float32),             # accumulator
+        # write sems: per-row windows in the per-slot path; read sems:
+        # per-row chunks there
+        pltpu.SemaphoreType.DMA((2, b if per_slot else 1)),
+        pltpu.SemaphoreType.DMA((2, 2, bg) if per_slot else (2, 2)),
+    ]
+    n_scalar = len(scalars)
     attn, k_out, v_out = pl.pallas_call(
         kernel,
         name="dstpu_decode_step",
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),   # layer
-            pl.BlockSpec(memory_space=pltpu.SMEM),   # idx
-            pl.BlockSpec(memory_space=pltpu.VMEM),   # q
-            pl.BlockSpec(memory_space=pltpu.VMEM),   # k_new
-            pl.BlockSpec(memory_space=pltpu.VMEM),   # v_new
+        in_specs=[smem] * n_scalar + [
+            vmem_spec,                               # q
+            vmem_spec,                               # k_new
+            vmem_spec,                               # v_new
             pl.BlockSpec(memory_space=pl.ANY),       # k_full (aliased)
             pl.BlockSpec(memory_space=pl.ANY),       # v_full (aliased)
         ],
@@ -964,23 +1182,12 @@ def fused_decode_step(q: jax.Array, k_full: jax.Array, v_full: jax.Array,
             jax.ShapeDtypeStruct(kview.shape, k_full.dtype),
             jax.ShapeDtypeStruct(vview.shape, v_full.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((2, bg, hkv, cs // pair, dh * pair), k_full.dtype),
-            pltpu.VMEM((2, bg, hkv, cs // pair, dh * pair), v_full.dtype),
-            pltpu.VMEM((b, hkv, 8, dh * pair), k_full.dtype),  # write window
-            pltpu.VMEM((b, hkv, 8, dh * pair), v_full.dtype),
-            pltpu.VMEM((bg, hq), jnp.float32),                 # running max
-            pltpu.VMEM((bg, hq), jnp.float32),                 # running sum
-            pltpu.VMEM((bg, hq, dh), jnp.float32),             # accumulator
-            # write sems: per-row windows in the per-slot path
-            pltpu.SemaphoreType.DMA((2, b if per_slot else 1)),
-            pltpu.SemaphoreType.DMA((2, 2)),                   # read sems
-        ],
-        input_output_aliases={5: 1, 6: 2},
+        scratch_shapes=scratch,
+        input_output_aliases={n_scalar + 3: 1, n_scalar + 4: 2},
         compiler_params=_compiler_params(vmem),
         interpret=(jax.default_backend() != "tpu" if interpret is None
                    else interpret),
-    )(layer_a, idx_a, qf, kn, vn, kview, vview)
+    )(*scalars, qf, kn, vn, kview, vview)
     if k_out.shape != k_full.shape:
         k_out = k_out.reshape(k_full.shape)
         v_out = v_out.reshape(v_full.shape)

@@ -44,6 +44,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.models.base import recurrent_state_keys, slot_state_keys
+from deepspeed_tpu.ops.decode_step import decode_rows_fetched
 from deepspeed_tpu.serving.errors import (EmptyPromptError,
                                           EngineConfigError,
                                           EngineInvariantError,
@@ -1848,6 +1849,17 @@ class ServingEngine:
                 self.telemetry.counter("serving/decode_steps").inc()
                 self.telemetry.counter("serving/slot_iterations_active").inc(
                     len(active_slots))
+                if self.prefix is None and self.cache.fused_walk:
+                    # what the fused decode step's walk moves this step, a
+                    # layer, by the host's own bookkeeping: a slot's cache
+                    # holds its prompt and every token but the one fed now
+                    lens = [len(self._slots[i].request.prompt)
+                            + len(self._slots[i].result.tokens) - 1
+                            for i in active_slots]
+                    self.telemetry.counter("serving/decode_rows_live").inc(
+                        sum(lens))
+                    self.telemetry.counter("serving/decode_rows_fetched").inc(
+                        decode_rows_fetched(lens))
             t_emit = self._now(now)
             for i in active_slots:
                 st = self._slots[i]

@@ -11,9 +11,11 @@ Two kinds of state can live in it side by side:
     head-major, token-pair packed for Dh < 128) over the model's attention
     layers. They grow with the request. A per-slot ``lengths`` int32 vector
     replaces the single scalar cache position, so the fused decode kernel
-    (ops/decode_step.py) streams only each slot's valid prefix and the
-    einsum path masks per row. Rows behind a slot's length are dead: bucket
-    padding and an inactive slot's write land there and are overwritten.
+    (ops/decode_step.py) streams only each ACTIVE slot's valid prefix and
+    the einsum path masks per row. Rows behind a slot's length are dead:
+    bucket padding lands there (and, on the einsum path, an inactive slot's
+    write) and is overwritten; a freed slot keeps its stale length until
+    the next prefill resets it.
   * **recurrent state** (every other leaf, ``[L', B_slots, ...]``; a
     state-space model's ``ssm`` and ``conv``): fixed size, no rows, nothing
     to hide a write behind. Prefill writes it at the request's true length,
@@ -45,6 +47,8 @@ from typing import Tuple
 import jax.numpy as jnp
 
 from deepspeed_tpu.models.base import recurrent_state_keys, slot_state_keys
+from deepspeed_tpu.ops.attention import kv_pack_factor
+from deepspeed_tpu.ops.decode_step import supports
 from deepspeed_tpu.serving.errors import EngineConfigError
 
 
@@ -70,6 +74,14 @@ class SlotKVCache:
         # path — see ops/attention.alloc_kv_cache)
         head_dim = model.config.head_dim
         self.pair = self.k.shape[4] // head_dim
+        # whether that allocation is one the fused decode step streams on a
+        # TPU (the shapes' part of ops/attention.cached_attention's route):
+        # then a decode step fetches what ops/decode_step's walk fetches
+        hkv = self.k.shape[2]
+        self.fused_walk = (num_slots >= 2
+                           and self.pair == kv_pack_factor(head_dim)
+                           and supports(hkv, hkv, self.k.shape[3] * self.pair,
+                                        head_dim))
 
     @property
     def k(self):

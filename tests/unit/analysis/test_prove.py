@@ -1098,11 +1098,16 @@ MUTATIONS = [
      "pallas-tile"),
     # drop the V-chunk DMA wait in the fused decode walk
     ("drop-chunk-wait", "deepspeed_tpu/ops/decode_step.py",
-     "            chunk_dma(slot, c, v_ref, vbuf, 1).wait()\n",
-     "", "pallas-dma"),
+     "                chunk_dma(slot, c, v_ref, vbuf, 1).wait()\n",
+     "                pass\n", "pallas-dma"),
     # drop the new-token V-window fetch wait
     ("drop-window-wait", "deepspeed_tpu/ops/decode_step.py",
-     "            fv.wait()\n", "", "pallas-dma"),
+     "        fv.wait()\n", "", "pallas-dma"),
+    # the same in the per-slot walk: a row's V-chunk wait
+    ("drop-slot-chunk-wait", "deepspeed_tpu/ops/decode_step.py",
+     "                each_row(g, c,\n"
+     "                         lambda p, j: chunk_copy(p, j, c, slot, 1).wait())\n",
+     "                pass\n", "pallas-dma"),
 ]
 
 

@@ -83,39 +83,113 @@ def test_fused_decode_step_matches_einsum(b, l, hq, hkv, s, dh, idx):
         np.asarray(v0, np.float32))
 
 
-@pytest.mark.serving
-@pytest.mark.parametrize("b,l,hq,hkv,s,dh,idxs", [
-    (4, 2, 4, 4, 256, 64, [100, 3, 255, 0]),   # MHA packed, mixed lengths
-    (2, 2, 8, 2, 256, 128, [200, 17]),          # GQA rep=4, dh=128
-    (4, 3, 4, 2, 512, 64, [511, 130, 0, 258]),  # lengths span chunk bounds
-])
-def test_fused_decode_step_per_slot_matches_einsum(b, l, hq, hkv, s, dh,
-                                                   idxs):
-    """Per-slot valid-length vector (continuous batching): the fused
-    kernel's per-row write/splice/masking == the einsum reference with
-    the same vector index."""
+def _check_per_slot(b, l, hq, hkv, s, dh, idxs, active, plan,
+                    poison=False, interpret=True):
     rng = np.random.RandomState(0)
     pair = kv_pack_factor(dh)
+    act = np.ones(b, bool) if active is None else np.asarray(active, bool)
     q = jnp.asarray(rng.randn(b, 1, hq, dh), jnp.bfloat16)
-    kf = jnp.asarray(rng.randn(l, b, hkv, s, dh), jnp.bfloat16)
-    vf = jnp.asarray(rng.randn(l, b, hkv, s, dh), jnp.bfloat16)
+    kf, vf = rng.randn(2, l, b, hkv, s, dh).astype(np.float32)
     kn = jnp.asarray(rng.randn(b, 1, hkv, dh), jnp.bfloat16)
     vn = jnp.asarray(rng.randn(b, 1, hkv, dh), jnp.bfloat16)
     layer = jnp.int32(l - 1)
     idx = jnp.asarray(idxs, jnp.int32)
-    a0, k0, v0 = _ref_step(q, kf, vf, kn, vn, layer, idx)
+    a0 = _ref_step(q, jnp.asarray(kf, jnp.bfloat16),
+                   jnp.asarray(vf, jnp.bfloat16), kn, vn, layer, idx)[0]
+    if poison:   # every row the walk has no business fetching
+        for i in range(b):
+            dead = 0 if not act[i] else -(-idxs[i] // plan["cs"]) * plan["cs"]
+            kf[:, i, :, dead:] = vf[:, i, :, dead:] = np.nan
+    kf, vf = jnp.asarray(kf, jnp.bfloat16), jnp.asarray(vf, jnp.bfloat16)
+    _, k0, v0 = _ref_step(q, kf, vf, kn, vn, layer, idx)
     packed = (l, b, hkv, s // pair, dh * pair)
     a1, k1, v1 = fused_decode_step(
         q, kf.reshape(packed), vf.reshape(packed), kn, vn, layer, idx,
-        interpret=True)
-    np.testing.assert_allclose(
-        np.asarray(a1, np.float32), np.asarray(a0, np.float32), atol=0.06)
-    np.testing.assert_array_equal(
-        np.asarray(k1.reshape(kf.shape), np.float32),
-        np.asarray(k0, np.float32))
-    np.testing.assert_array_equal(
-        np.asarray(v1.reshape(vf.shape), np.float32),
-        np.asarray(v0, np.float32))
+        active=None if active is None else jnp.asarray(active),
+        interpret=interpret, plan=plan)
+    a0, a1 = np.asarray(a0, np.float32), np.asarray(a1, np.float32)
+    assert np.isfinite(a1).all()
+    np.testing.assert_allclose(a1[act], a0[act], atol=0.06)
+    np.testing.assert_array_equal(a1[~act], 0.0)
+    for new, ref, old in ((k1, k0, kf), (v1, v0, vf)):
+        new = np.asarray(new.reshape(old.shape), np.float32)
+        # the reference wrote every slot's token; the kernel, the active
+        # slots' alone (NaN compares equal to NaN here)
+        np.testing.assert_array_equal(new[:, act],
+                                      np.asarray(ref, np.float32)[:, act])
+        np.testing.assert_array_equal(new[:, ~act],
+                                      np.asarray(old, np.float32)[:, ~act])
+
+
+# stale lengths of freed slots: longer than every active slot's
+_STALE = [1000, 130, 5, 258, 700, 1023, 0, 128]
+_MHA = (8, 2, 4, 4, 1024, 64)     # b, l, hq, hkv, s, dh (pair = 2)
+_GQA = (8, 2, 8, 2, 1024, 64)     # rep = 4: the MXU path
+
+
+@pytest.mark.serving
+@pytest.mark.parametrize("b,l,hq,hkv,s,dh,idxs,active,plan", [
+    pytest.param(4, 2, 4, 4, 256, 64, [100, 3, 255, 0], None, None,
+                 id="mha-mixed"),
+    pytest.param(2, 2, 8, 2, 256, 128, [200, 17], None, None,
+                 id="gqa-dh128"),
+    pytest.param(4, 3, 4, 2, 512, 64, [511, 130, 0, 258], None, None,
+                 id="chunk-bounds"),
+    # one to eight chunks of 128 in one batch, groups of two by length
+    pytest.param(*_MHA, [1023, 130, 5, 258, 700, 900, 127, 128], None,
+                 {"bg": 2, "cs": 128}, id="mha-1-to-8-chunks"),
+    pytest.param(*_GQA, [1023, 130, 5, 258, 700, 900, 127, 128], None,
+                 {"bg": 4, "cs": 128}, id="gqa-1-to-8-chunks"),
+    # freed slots keep lengths longer than every active slot's
+    pytest.param(*_MHA, _STALE, [0, 1, 1, 1, 0, 0, 1, 1],
+                 {"bg": 2, "cs": 128}, id="mha-stale-longer"),
+    pytest.param(*_GQA, _STALE, [0, 1, 1, 1, 0, 0, 1, 1],
+                 {"bg": 4, "cs": 256}, id="gqa-stale-longer"),
+    # three active of eight in groups of two: groups with no active slot
+    pytest.param(*_MHA, _STALE, [0, 1, 0, 1, 0, 0, 0, 1],
+                 {"bg": 2, "cs": 128}, id="mha-empty-groups"),
+    pytest.param(*_GQA, _STALE, [0, 0, 0, 1, 0, 0, 0, 0],
+                 {"bg": 4, "cs": 128}, id="gqa-one-active"),
+    pytest.param(*_MHA, _STALE, [0, 0, 0, 0, 0, 0, 1, 0],
+                 {"bg": 2, "cs": 128}, id="mha-one-active-empty-cache"),
+    pytest.param(*_MHA, _STALE, [0] * 8, {"bg": 2, "cs": 128},
+                 id="mha-none-active"),
+    pytest.param(*_GQA, _STALE, [0] * 8, None, id="gqa-none-active"),
+])
+def test_fused_decode_step_per_slot_matches_einsum(b, l, hq, hkv, s, dh,
+                                                   idxs, active, plan):
+    """Per-slot valid-length vector (continuous batching): the fused
+    kernel's per-row write and masking == the einsum reference with the
+    same vector index on the active slots; an inactive slot's output is
+    zero and its cache rows are the bits they were."""
+    _check_per_slot(b, l, hq, hkv, s, dh, idxs, active, plan)
+
+
+@pytest.mark.serving
+def test_fused_decode_step_skipped_rows_cannot_poison():
+    """Hazard of the per-row walk: a buffer row whose DMA was skipped holds
+    stale or uninitialised VMEM, and ``0 * NaN`` in the PV product is NaN.
+    The TPU interpreter hands out NaN for uninitialised scratch; the cache
+    is NaN wherever the walk must not fetch (freed slots whole, active
+    slots past their last chunk). A long and a short slot share a group, so
+    the short one's row is skipped on a buffer no DMA ever filled."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    _check_per_slot(
+        *_MHA, _STALE, [0, 0, 1, 0, 0, 1, 0, 0], {"bg": 2, "cs": 128},
+        poison=True,
+        interpret=pltpu.InterpretParams(uninitialized_memory="nan"))
+
+
+def test_slot_walk_orders_active_slots_by_length():
+    from deepspeed_tpu.ops.decode_step import slot_walk
+
+    walk = slot_walk(jnp.asarray(_STALE), jnp.asarray([0, 1, 1, 1, 0, 1, 1, 1]))
+    assert np.asarray(walk.order).tolist() == [5, 3, 1, 7, 2, 6, 0, 4]
+    assert np.asarray(walk.n_active).tolist() == [6]
+    walk = slot_walk(jnp.asarray([3, 9, 9, 1]))            # all active
+    assert np.asarray(walk.order).tolist() == [1, 2, 0, 3]
+    assert np.asarray(walk.n_active).tolist() == [4]
 
 
 def test_cached_attention_packed_fallback_matches_unpacked():

@@ -106,6 +106,27 @@ def test_fused_decode_step(one_chip, dims):
     _compiled_text(fn, *_decode_operands(one_chip, *dims))
 
 
+# the serve cells' slot caches: gpt2-large, 32 slots x 1024 (MHA), and the
+# hybrid's four attention layers, 64 slots x 2048 (4 query heads a KV head)
+SLOT_DECODE_SHAPES = [(36, 32, 20, 20, 1024, 64), (4, 64, 32, 8, 2048, 64)]
+
+
+@pytest.mark.parametrize("dims", SLOT_DECODE_SHAPES, ids=str)
+def test_fused_decode_step_per_slot_active(one_chip, dims):
+    """The per-slot walk with the active mask, as the decode program calls
+    it: the walk order made outside the kernel, per-row chunk DMAs, the
+    dynamic loops over groups and active slots."""
+    from deepspeed_tpu.ops.decode_step import fused_decode_step, slot_walk
+
+    def fn(q, k, v, kn, vn, layer, idx, active):
+        return fused_decode_step(q, k, v, kn, vn, layer, idx,
+                                 active=slot_walk(idx, active),
+                                 interpret=False)
+
+    ops = _decode_operands(one_chip, *dims)
+    _compiled_text(fn, *ops, _sds(one_chip, (dims[1],), jnp.bool_))
+
+
 @pytest.mark.parametrize("dims", DECODE_SHAPES, ids=str)
 def test_fused_block_decode_step(one_chip, dims):
     from deepspeed_tpu.ops.decode_step import fused_block_decode_step
