@@ -688,7 +688,11 @@ class InferenceEngine:
         Signature: ``(params, *state, lengths[B], tokens[B], active[B]
         bool, temp, rng) -> (*state, lengths, next_tokens[B])`` with the
         state's leaves as in :meth:`slot_prefill_program` (cache operands
-        donated on TPU)."""
+        donated on TPU). A model that counts on the device what a step did
+        (``model.step_counters``; an expert layer's touched experts) returns
+        the counts behind the tokens, an int32 vector in that order, so the
+        host fetches both at once and hands the vector to the model's
+        ``record_step_counters(telemetry, counts)``."""
         from deepspeed_tpu.ops.decode_step import slot_walk
 
         key = ("slot_dec", num_slots, max_len, do_sample, top_k,
@@ -709,7 +713,10 @@ class InferenceEngine:
                 nxt = jnp.where(active, pick(logits[:, -1], temp, rng),
                                 pad_token_id)
                 lengths = jnp.where(active, lengths + 1, lengths)
-                return (*(cache[n] for n in names), lengths, nxt)
+                out = (*(cache[n] for n in names), lengths, nxt)
+                if getattr(model, "step_counters", ()):
+                    out += (cache["step_counters"],)
+                return out
 
             self._compiled[key] = jax.jit(
                 decode, donate_argnums=self._carry_donation(len(names)))

@@ -237,17 +237,30 @@ def layer_view(blocks, i):
     layer recorded as ``__layer__`` — qdot's int8 kernel DMA-slices the
     layer in-kernel, because a host-side slice of an int8 custom-call
     operand materializes a full per-step copy of the weight (measured as
-    the '66% of streaming bound' int8 serving ceiling at 6.7B)."""
+    the '66% of streaming bound' int8 serving ceiling at 6.7B). A model
+    keeps any other leaf whole the same way by handing the walk ``{"__whole__":
+    leaf}`` in its place (:func:`whole_leaves`): an expert stack, which a
+    grouped matmul addresses by group and need not slice (moe/grouped.py)."""
 
     def walk(node):
         if isinstance(node, dict):
             if "__q__" in node:
                 return {"__q__": node["__q__"],
                         "__scale__": node["__scale__"], "__layer__": i}
+            if "__whole__" in node:
+                return {"__whole__": node["__whole__"], "__layer__": i}
             return {k: walk(v) for k, v in node.items()}
         return jax.lax.dynamic_index_in_dim(node, i, 0, keepdims=False)
 
     return walk(blocks)
+
+
+def whole_leaves(stack, *names):
+    """``stack`` with the leaves ``names`` marked to stay whole under
+    :func:`layer_view`: their consumer gets ``{"__whole__": [L, ...],
+    "__layer__": i}`` and addresses the layer itself."""
+    return {k: {"__whole__": v} if k in names else v
+            for k, v in stack.items()}
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))

@@ -1,0 +1,138 @@
+"""A routed expert layer that is told which experts it holds.
+
+The DeepSeek-V3 style router that today's large open models use (sigmoid
+scores, the ``k`` largest after a selection bias, weights normalised over the
+chosen, a scaling factor) and a dispatch with no capacity and no drop: the
+(token, expert) pairs routed to the experts held HERE are sorted by expert
+into contiguous groups and each group is multiplied by its expert
+(``jax.lax.ragged_dot``: on a TPU a grouped-matmul kernel of XLA's own that
+visits only the row tiles the groups fill, so an expert no token chose is
+neither multiplied nor read).
+
+``held=(first, count)`` is one chip's share of an expert-parallel layer: the
+router, the choice of ``k`` and the normalisation run over ALL experts, the
+sum runs over the chosen experts in ``[first, first + count)``, and what the
+other chips' experts would add is left out. The exchange between chips is not
+here (ROADMAP R2); summed over the shares ``(0, c), (c, c), ...`` the results
+give the uncut layer (tests/unit/inference/test_exaone_moe.py).
+
+A step's time follows what it touches: a decode step's 5 to 12 tokens leave
+about half of 16 held experts without one, the kernel reads the others only,
+and each costs about 0.19 ms. With weights drawn from a seed the share of
+pairs routed here is the seed's (10.5% to 14.1% over 12 seeds for the
+expected 12.5%), and the decode gap moves with it (PERF.md, PR 35, which also
+tried a decode step that multiplies every held expert: 4 ms a step slower,
+and no steadier; the benchmark's cell serves one checkpoint for that reason).
+
+Shapes are static: a token's ``k`` experts are distinct, so at most
+``min(k, count)`` of its pairs are held, and the sorted buffer has
+``N * min(k, count)`` rows whatever the routing; rows behind the last group
+are masked. ``TopKGate`` / ``top1gating`` / ``top2gating`` (sharded_moe.py)
+stay the capacity-einsum dispatch of ``gpt_moe``.
+"""
+
+from __future__ import annotations
+
+from typing import NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+
+class Routing(NamedTuple):
+    experts: jax.Array     # [N, k] int32: the chosen experts, of all of them
+    weights: jax.Array     # [N, k] float32: normalised, scaled
+
+
+def sigmoid_topk_route(x, w_router, select_bias, k: int, *,
+                       scale: float = 1.0, normalize: bool = True) -> Routing:
+    """``x [N, d]`` against ``w_router [d, E]``: ``sigma = sigmoid(x W)`` in
+    float32 over all ``E``; the ``k`` experts with the largest ``sigma +
+    select_bias`` (the bias picks and does not weigh); ``w = sigma_chosen /
+    (their sum + 1e-20)`` if ``normalize``; times ``scale``."""
+    sigma = jax.nn.sigmoid(jnp.einsum(
+        "nd,de->ne", x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, experts = jax.lax.top_k(sigma + select_bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(sigma, experts, axis=-1)
+    if normalize:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return Routing(experts.astype(jnp.int32), w * scale)
+
+
+class ExpertCounts(NamedTuple):
+    """What a call did, counted on the device (int32 scalars)."""
+    touched: jax.Array           # held experts that got at least one pair
+    streamed: jax.Array          # held experts whose weights the call read
+    assignments_held: jax.Array  # pairs routed to held experts
+    assignments: jax.Array       # all pairs of the valid tokens
+
+
+def held_experts(x, routing: Routing, w_gate, w_up, w_down,
+                 held: Tuple[int, int], *, valid: Optional[jax.Array] = None):
+    """Sum over the chosen experts held here of ``w_e * Expert_e(x)``, each a
+    gated MLP ``(silu(x Wg) * (x Wu)) Wd``.
+
+    ``x [N, d]``; ``w_gate, w_up [count, d, m]``, ``w_down [count, m, d]``:
+    the weights of experts ``first .. first + count - 1``, or each a
+    layer-stacked ``{"__whole__": [L, count, ..], "__layer__": i}``
+    (models/base.layer_view): the stack is then the grouped matmul's
+    operand as it lies, layer ``i``'s experts being groups ``i * count ..``
+    of ``L * count`` with every other group empty: a slice of it would be a
+    copy of the layer's experts (1.2 GB at 16 x 3 x 6144 x 2048) every step.
+    ``valid [N]`` (bool): tokens that are real (bucket padding and slots that
+    do not decode get no pair, touch no expert and come out zero).
+    -> ``(y [N, d], ExpertCounts)``."""
+    first, count = held
+    n, k = routing.experts.shape
+    rows = n * min(k, count)
+    local = routing.experts - first
+    here = (local >= 0) & (local < count)
+    if valid is not None:
+        here &= valid[:, None]
+    # pairs of held experts first, by expert; the rest behind them
+    key = jnp.where(here, local, count).reshape(-1)
+    order = jnp.argsort(key, stable=True)[:rows]
+    token = order // k
+    sizes = jnp.sum(jax.nn.one_hot(key, count, dtype=jnp.int32), axis=0)
+    total = sizes.sum()
+
+    def grouped(lhs, w):
+        groups = sizes
+        if isinstance(w, dict):
+            w, layer = w["__whole__"], w["__layer__"]
+            groups = jax.lax.dynamic_update_slice(
+                jnp.zeros((w.shape[0] * count,), sizes.dtype), sizes,
+                (layer * count,))
+            w = w.reshape((-1,) + w.shape[2:])
+        assert w.shape[0] == groups.shape[0], (w.shape, held)
+        return jax.lax.ragged_dot(lhs, w.astype(lhs.dtype), groups)
+
+    with jax.named_scope("dstpu_moe_experts"):
+        xs = x[token]
+        h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+        ys = grouped(h, w_down)
+    w = routing.weights.reshape(-1)[order]
+    # rows behind the last group hold whatever the kernel left there
+    ys = jnp.where((jnp.arange(rows) < total)[:, None],
+                   ys.astype(jnp.float32) * w[:, None], 0.0)
+    # back to tokens: a pair's row in the sorted buffer, then the k of a
+    # token summed (a gather; a scatter-add of the rows serialises on a TPU)
+    rank = jnp.zeros((n * k,), jnp.int32).at[order].set(
+        jnp.arange(rows, dtype=jnp.int32))
+    picked = jnp.where(here.reshape(-1)[:, None],
+                       ys[jnp.minimum(rank, rows - 1)], 0.0)
+    y = picked.reshape(n, k, -1).sum(1).astype(x.dtype)
+
+    n_valid = n if valid is None else valid.sum()
+    counts = ExpertCounts(
+        touched=(sizes > 0).sum().astype(jnp.int32),
+        # XLA's TPU kernel for ragged_dot makes row tiles for filled groups
+        # only, so an expert without a pair is not read (measured, PERF.md
+        # PR 35: 0.41 ms a matrix a layer in decode, where all 16 experts'
+        # 402 MB would take 0.49 ms at the chip's peak); an implementation
+        # that multiplies every held expert counts ``count`` here
+        streamed=(sizes > 0).sum().astype(jnp.int32),
+        assignments_held=total.astype(jnp.int32),
+        assignments=jnp.asarray(n_valid * k, jnp.int32))
+    return y, counts

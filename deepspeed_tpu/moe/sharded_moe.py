@@ -128,7 +128,10 @@ class TopKGate:
                  capacity_factor: float = 1.0, eval_capacity_factor: float = 1.0,
                  min_capacity: int = 4, noisy_gate_policy: Optional[str] = None,
                  drop_tokens: bool = True):
-        assert k in (1, 2), "only top-1 and top-2 gating supported (as reference)"
+        assert k in (1, 2), (
+            "TopKGate dispatches by a capacity einsum for top-1 and top-2 "
+            "only; for k > 2 (sigmoid top-k, no capacity, no drop) use "
+            "moe/grouped.py: sigmoid_topk_route and held_experts")
         self.model_dim = model_dim
         self.num_experts = num_experts
         self.k = k

@@ -30,6 +30,11 @@ import jax.numpy as jnp
 _B1_FUSED_MIN_BYTES = int(os.environ.get(
     "DEEPSPEED_TPU_B1_FUSED_MIN_BYTES", 2 * 1024 * 1024))
 
+# float32 scores one einsum of a prompt block may hold; a block with more is
+# walked in query blocks (decode_attention). No cell's prefill up to PR 33
+# comes near (32 heads x 1024 x 1024 are 134 MB).
+_SCORE_BLOCK_BYTES = 256 * 1024 * 1024
+
 
 def multihead_attention(
     q: jax.Array,  # [B, T, H, Dh]
@@ -198,6 +203,111 @@ def cached_attention(q, k_full, v_full, k_new, v_new, layer, idx, *,
     attn = decode_attention(q, kl, vl, idx, scale=scale, bias=bias,
                             window=window)
     return attn, k_full, v_full
+
+
+def ring_positions(idx, rows: int):
+    """The position each row of a ring of ``rows`` rows holds for a request
+    whose next position is ``idx`` (``[B]``): the latest position before
+    ``idx`` that is congruent to the row; negative where the row was never
+    written. -> ``[B, rows]``."""
+    last = idx[:, None] - 1
+    return last - (last - jnp.arange(rows)[None, :]) % rows
+
+
+def window_cached_attention(q, k_ring, v_ring, k_new, v_new, layer, idx, *,
+                            scale=None, valid=None, active=None):
+    """One sliding-window layer step against a RING cache ``[L, B, Hkv, W,
+    Dh]`` whose ``W`` rows are the window: position ``p`` lives at row ``p %
+    W``, so a slot's cache does not grow with the request. A query at
+    position ``i`` attends positions ``j <= i`` with ``i - j < W``; keys carry
+    their own position (rotary applied before caching), so the order of the
+    rows does not matter. Returns ``(attn, k_ring, v_ring)``.
+
+    ``idx``: first position of the block, a scalar or a per-slot ``[B]``
+    vector; ``valid`` (scalar or ``[B]``): how many of the block's ``T``
+    positions are real (bucket padding behind a prompt; 0 for a slot that
+    does not decode): the ring takes the last ``W`` REAL positions.
+
+    One token on a TPU goes through the fused decode step with ``ring=True``
+    (ops/decode_step.py: a slot fetches its ``min(idx, W)`` live rows and
+    leaves out the row the new token takes, which in a full ring holds the
+    position that has just left the window). Everything else is an einsum
+    over the ring's rows and the block's own keys: a prompt block in query
+    blocks of ``W``, each against the ``W`` keys before it and its own (a
+    band, never ``T x T`` scores), the ring being the block before the first."""
+    b, t, hq, dh = q.shape
+    hkv, w = k_ring.shape[2], k_ring.shape[3]
+    assert k_ring.shape[4] == dh, "a ring cache is not token-pair packed"
+    idx_v = jnp.broadcast_to(jnp.asarray(idx, jnp.int32), (b,))
+    if t == 1 and b >= 2 and jax.default_backend() == "tpu":
+        from deepspeed_tpu.ops.decode_step import fused_decode_step, supports
+
+        if supports(hq, hkv, w, dh) and dh % 128 == 0:
+            if active is None and valid is not None:
+                active = jnp.broadcast_to(valid, (b,)) > 0
+            return fused_decode_step(q, k_ring, v_ring, k_new, v_new, layer,
+                                     idx_v, scale=scale, active=active,
+                                     ring=True)
+    scale = scale if scale is not None else dh ** -0.5
+    rep = hq // hkv
+    kl = jax.lax.dynamic_index_in_dim(k_ring, layer, 0, keepdims=False)
+    vl = jax.lax.dynamic_index_in_dim(v_ring, layer, 0, keepdims=False)
+    kn = k_new.transpose(0, 2, 1, 3).astype(kl.dtype)       # [B, Hkv, T, Dh]
+    vn = v_new.transpose(0, 2, 1, 3).astype(vl.dtype)
+    k_all = jnp.concatenate([kl, kn], axis=2)               # [B, Hkv, W+T, Dh]
+    v_all = jnp.concatenate([vl, vn], axis=2)
+    q_pos = idx_v[:, None] + jnp.arange(t)[None, :]         # [B, T]
+    pos = jnp.concatenate([ring_positions(idx_v, w), q_pos], axis=1)
+
+    def masked_softmax(logits, kp, qp, lead):
+        """Softmax over the keys at positions ``kp`` a query at ``qp`` may
+        see; ``lead`` places the mask among the logits' head dimensions."""
+        ok = (kp >= 0) & (kp <= qp) & (qp - kp < w)
+        return jax.nn.softmax(jnp.where(ok[lead], logits,
+                                        jnp.finfo(jnp.float32).min), axis=-1)
+
+    qg = q.reshape(b, t, hkv, rep, dh)
+    if t > w and t % w == 0:
+        n = t // w
+
+        def band(a, lead):   # blocks i and i + 1 of W + T rows, side by side
+            blocks = a.reshape(lead + (n + 1, w) + a.shape[len(lead) + 1:])
+            ax = len(lead)
+            return jnp.concatenate(
+                [jax.lax.slice_in_dim(blocks, 0, n, axis=ax),
+                 jax.lax.slice_in_dim(blocks, 1, n + 1, axis=ax)], axis=ax + 1)
+
+        kb, vb = band(k_all, (b, hkv)), band(v_all, (b, hkv))  # [B,Hkv,n,2W,Dh]
+        pb = band(pos, (b,))                                   # [B, n, 2W]
+        qb = qg.reshape(b, n, w, hkv, rep, dh)
+        logits = jnp.einsum("bnqkrd,bknsd->bknrqs", qb, kb
+                            ).astype(jnp.float32) * scale
+        probs = masked_softmax(
+            logits, pb[:, :, None, :],                      # [B, n, 1, 2W]
+            q_pos.reshape(b, n, w)[:, :, :, None],          # [B, n, W, 1]
+            (slice(None), None, slice(None), None)).astype(vb.dtype)
+        attn = jnp.einsum("bknrqs,bknsd->bnqkrd", probs, vb
+                          ).reshape(b, t, hq, dh)
+    else:
+        logits = jnp.einsum("btkrd,bksd->bkrts", qg, k_all
+                            ).astype(jnp.float32) * scale
+        probs = masked_softmax(                             # [B, T, W+T]
+            logits, pos[:, None, :], q_pos[:, :, None],
+            (slice(None), None, None)).astype(v_all.dtype)
+        attn = jnp.einsum("bkrts,bksd->btkrd", probs, v_all
+                          ).reshape(b, t, hq, dh)
+    # the ring after the block: row r takes the last real position congruent
+    # to r, if the block holds one
+    n_real = jnp.full((b,), t, jnp.int32) if valid is None \
+        else jnp.broadcast_to(jnp.asarray(valid, jnp.int32), (b,))
+    after = ring_positions(idx_v + n_real, w)               # [B, W]
+    take = (after >= idx_v[:, None])[:, None, :, None]
+    j = jnp.clip(after - idx_v[:, None], 0, t - 1)[:, None, :, None]
+    kl = jnp.where(take, jnp.take_along_axis(kn, j, axis=2), kl)
+    vl = jnp.where(take, jnp.take_along_axis(vn, j, axis=2), vl)
+    return (attn,
+            jax.lax.dynamic_update_index_in_dim(k_ring, kl, layer, 0),
+            jax.lax.dynamic_update_index_in_dim(v_ring, vl, layer, 0))
 
 
 def write_kv_cache(k_full, v_full, k_new, v_new, layer, idx):
@@ -572,6 +682,30 @@ def decode_attention(
         from deepspeed_tpu.ops.flash_decode import flash_decode
 
         return flash_decode(q, k_cache, v_cache, cache_index, scale=scale)
+    s_max = k_cache.shape[2]
+    qb = t
+    while qb % 2 == 0 and b * hq * qb * s_max * 4 > _SCORE_BLOCK_BYTES:
+        qb //= 2
+    if qb < t:
+        # a long prompt block (64 heads x 4096 x 4096 float32 scores are
+        # 4.3 GB): the queries in blocks of ``qb``, one block's scores live
+        def block(i):
+            qi = jax.lax.dynamic_slice_in_dim(q, i * qb, qb, 1)
+            return _dense_decode_attention(
+                qi, k_cache, v_cache, cache_index + i * qb, scale=scale,
+                bias=bias, window=window)
+
+        out = jax.lax.map(block, jnp.arange(t // qb))   # [n, B, qb, Hq, Dh]
+        return out.transpose(1, 0, 2, 3, 4).reshape(b, t, hq, dh)
+    return _dense_decode_attention(q, k_cache, v_cache, cache_index,
+                                   scale=scale, bias=bias, window=window)
+
+
+def _dense_decode_attention(q, k_cache, v_cache, cache_index, *, scale, bias,
+                            window):
+    """:func:`decode_attention`'s einsum: every score of the block at once."""
+    b, t, hq, dh = q.shape
+    per_slot = jnp.ndim(cache_index) == 1
     hkv = k_cache.shape[1]
     s_max = k_cache.shape[2]
     scale = scale if scale is not None else dh ** -0.5

@@ -390,7 +390,7 @@ def _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, q_ref, kn_ref, vn_ref,
                  kbuf, vbuf, kwin, vwin, qrow, knrow, vnrow,
                  m_ref, l_ref, acc_ref, wsem, rsem,
                  *, b: int, bg: int, cs: int, hq: int, hkv: int, dh: int,
-                 pair: int, scale: float, mha: str = "mxu"):
+                 pair: int, scale: float, mha: str = "mxu", wpos_ref=None):
     """The per-slot walk (continuous batching): ``idx_ref [B]`` holds each
     slot's own length, ``order_ref [B]`` the slots with the active ones
     first by descending length (:func:`slot_walk`), ``n_ref [1]`` how many
@@ -416,7 +416,14 @@ def _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, q_ref, kn_ref, vn_ref,
       nothing.
     * An inactive slot's 8-row write window is neither read nor written
       (a slot midway through a chunked prefill is inactive here while its
-      rows are live), and its output row is zero."""
+      rows are live), and its output row is zero.
+    * ``wpos_ref [B]`` (:func:`_ring_slot_kernel`; a sliding-window layer's
+      cache is a ring of ``window`` rows): the token is written at row
+      ``wpos`` and not at the length, and that row is left out of the walk:
+      in a full ring it holds the one position that has just left the
+      window. Without it the token lands at the length, which the walk's
+      ``< length`` mask already leaves out."""
+    write_at = idx_ref if wpos_ref is None else wpos_ref
     layer = layer_ref[0]
     n_act = n_ref[0]
     csp = cs // pair          # pair-rows per chunk
@@ -435,7 +442,7 @@ def _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, q_ref, kn_ref, vn_ref,
     # positions are unrelated), fully async under the walk
     def win_copy(p, t, back: bool):
         s = slot_at(p)
-        w0 = (idx_ref[s] // pair // 8) * 8
+        w0 = (write_at[s] // pair // 8) * 8
         hbm = (k_ref, v_ref)[t].at[layer, pl.ds(s, 1), :, pl.ds(w0, 8), :]
         win = (kwin, vwin)[t].at[pl.ds(p, 1)]
         return pltpu.make_async_copy(win, hbm, wsem.at[t, p]) if back \
@@ -455,7 +462,7 @@ def _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, q_ref, kn_ref, vn_ref,
         win_copy(p, 0, False).wait()
         win_copy(p, 1, False).wait()
         s = slot_at(p)
-        i = idx_ref[s]
+        i = write_at[s]
         sel = (jax.lax.broadcasted_iota(jnp.int32, (1, hkv, 8, dhp), 2)
                == jax.lax.rem(i // pair, 8))
         if pair > 1:
@@ -506,12 +513,15 @@ def _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, q_ref, kn_ref, vn_ref,
         the step computes on) -> the next group's first step."""
         nch_g = nch_at(g * bg)
         lens = jnp.zeros((bg, hq, csp), jnp.int32)
+        wrow = lens
         rows = jax.lax.broadcasted_iota(jnp.int32, lens.shape, 0)
         for j in range(bg):      # SMEM scalars cannot gather: bg selects
             p = g * bg + j
             s = slot_at(p)
             lens = jnp.where(rows == j,
                              jnp.where(p < n_act, idx_ref[s], 0), lens)
+            if wpos_ref is not None:
+                wrow = jnp.where(rows == j, wpos_ref[s], wrow)
             qrow[pl.ds(j, 1)] = q_ref[pl.ds(s, 1)]
             knrow[pl.ds(j, 1)] = kn_ref[pl.ds(s, 1)]
             vnrow[pl.ds(j, 1)] = vn_ref[pl.ds(s, 1)]
@@ -542,9 +552,14 @@ def _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, q_ref, kn_ref, vn_ref,
                          lambda p, j: chunk_copy(p, j, c, slot, 1).wait())
                 return vbuf[slot]
 
-            attend(kbuf[slot], load_v,
-                   lambda h, shape: c * cs + pair * jax.lax.broadcasted_iota(
-                       jnp.int32, shape, 2) + h < lens)
+            def valid(h, shape):
+                tok = c * cs + pair * jax.lax.broadcasted_iota(
+                    jnp.int32, shape, 2) + h
+                if wpos_ref is None:
+                    return tok < lens
+                return (tok < lens) & (tok != wrow)
+
+            attend(kbuf[slot], load_v, valid)
             return t + 1
 
         t = jax.lax.fori_loop(0, nch_g, body, t)
@@ -559,6 +574,14 @@ def _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, q_ref, kn_ref, vn_ref,
 
     jax.lax.fori_loop(0, (n_act + bg - 1) // bg, group, 0)
     each_active(drain_window)    # before the kernel exits
+
+
+def _ring_slot_kernel(layer_ref, idx_ref, order_ref, n_ref, wpos_ref, *refs,
+                      **geometry):
+    """:func:`_slot_kernel` on a ring: ``idx_ref`` the live rows of each
+    slot's ring, ``wpos_ref`` the row the new token takes."""
+    _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, *refs,
+                 wpos_ref=wpos_ref, **geometry)
 
 
 def supports_block(hq: int, hkv: int, block_size: int, dh: int) -> bool:
@@ -1067,7 +1090,7 @@ def fused_decode_step(q: jax.Array, k_full: jax.Array, v_full: jax.Array,
                       layer, idx, *, active=None,
                       scale: Optional[float] = None,
                       interpret: Optional[bool] = None,
-                      plan: Optional[dict] = None):
+                      plan: Optional[dict] = None, ring: bool = False):
     """One decode layer-step against the FULL stacked cache.
 
     q:            [B, 1, Hq, Dh]  — the new token's queries
@@ -1084,6 +1107,13 @@ def fused_decode_step(q: jax.Array, k_full: jax.Array, v_full: jax.Array,
                   made of it once a step (:func:`slot_walk`); ``None``:
                   every slot. An inactive slot's cache is neither read nor
                   written and its output row is zero.
+    ring:         per-slot ``idx`` only: the cache's ``S`` rows are a ring
+                  over positions (a sliding-window layer whose window is
+                  ``S``: position ``p`` lives at row ``p % S``). A slot
+                  at position ``idx`` writes at ``idx % S``, walks its
+                  ``min(idx, S)`` live rows and leaves out the row it
+                  writes, so it attends the last ``S`` positions with the
+                  new one and nothing older.
     plan:         optional measured-plan override (the autotune
                   harness's candidate; ops/autotune.py entries are
                   consulted otherwise — ``_resolve_plan``). The per-slot
@@ -1130,13 +1160,21 @@ def fused_decode_step(q: jax.Array, k_full: jax.Array, v_full: jax.Array,
     vmem_spec = pl.BlockSpec(memory_space=pltpu.VMEM)
     scalars = [layer_a, idx_a]
     geometry = dict(b=b, hq=hq, hkv=hkv, dh=dh, pair=pair, scale=sc, mha=mha)
+    assert per_slot or not ring, "a ring cache is walked per slot"
     if per_slot:
         walk = active if isinstance(active, SlotWalk) \
             else slot_walk(idx_a, active)
-        scalars += [walk.order, walk.n_active]
         if not (plan and {"bg", "cs"} <= plan.keys()):
             bg, cs = _slot_plan(b, hkv, s_max, dh, itemsize)
-        kernel = functools.partial(_slot_kernel, bg=bg, cs=cs, **geometry)
+        if ring:
+            scalars = [layer_a, jnp.minimum(idx_a, s_max), walk.order,
+                       walk.n_active, idx_a % s_max]
+            kernel = functools.partial(_ring_slot_kernel, bg=bg, cs=cs,
+                                       **geometry)
+        else:
+            scalars += [walk.order, walk.n_active]
+            kernel = functools.partial(_slot_kernel, bg=bg, cs=cs,
+                                       **geometry)
     else:
         kernel = functools.partial(_kernel, bg=bg, cs=cs, **geometry)
     chunk = (2, bg, hkv, cs // pair, dh * pair)

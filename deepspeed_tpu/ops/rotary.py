@@ -49,3 +49,21 @@ def apply_rotary_pos_emb(x: jax.Array, cos: jax.Array, sin: jax.Array,
     o2 = x2 * c + x1 * s
     out = jnp.stack([o1, o2], axis=-1).reshape(b, t, h, dh)
     return out.astype(x.dtype)
+
+
+def apply_rotary_half(x: jax.Array, positions: jax.Array, theta: float
+                      ) -> jax.Array:
+    """RoPE in the rotate-half convention (HF LLaMA's ``rotate_half``: the
+    halves ``x[..., :Dh/2]`` and ``x[..., Dh/2:]`` are the pairs) over the
+    whole head dimension, angles computed and not tabulated: ``x [B, T, H,
+    Dh]`` at ``positions`` (``[T]``, or ``[B, T]`` under continuous
+    batching), in float32."""
+    dh = x.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh))
+    ang = positions.astype(jnp.float32)[..., None] * inv      # [.., T, Dh/2]
+    ang = jnp.concatenate([ang, ang], axis=-1)
+    ang = ang[None, :, None] if ang.ndim == 2 else ang[:, :, None]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = jnp.split(x32, 2, axis=-1)
+    turned = jnp.concatenate([-x2, x1], axis=-1)
+    return (x32 * jnp.cos(ang) + turned * jnp.sin(ang)).astype(x.dtype)

@@ -388,8 +388,9 @@ class ServingEngine:
                 if value:
                     raise EngineConfigError(
                         f"{option}={value!r} addresses the cache by token "
-                        f"rows, and {type(model).__name__} keeps recurrent "
-                        f"state {list(recurrent)} that has none")
+                        f"rows, and {type(model).__name__} keeps state "
+                        f"{list(recurrent)} that has none (recurrent state, "
+                        f"or a sliding window's ring)")
         self.kv_dtype = normalize_kv_dtype(kv_dtype)
         if self.kv_dtype is not None and not prefix_cache:
             raise EngineConfigError(
@@ -1830,7 +1831,9 @@ class ServingEngine:
         with _Phase(self, "dstpu/serving_launch", "iter_launch", now):
             out = self._adopt(self._decode(*args))
         with _Phase(self, "dstpu/serving_fetch", "iter_fetch", now):
-            nxt = np.asarray(jax.device_get(out[0]))  # dstpu-lint: fence=token emission: decode's picks feed host continuations + streams
+            # a model's device-side step counters ride behind the tokens
+            nxt, *counted = jax.device_get(out)  # dstpu-lint: fence=token emission: decode's picks feed host continuations + streams
+            nxt = np.asarray(nxt)
         dt = time.perf_counter() - t0
         self.decode_wall += dt
         if armed:
@@ -1860,6 +1863,20 @@ class ServingEngine:
                         sum(lens))
                     self.telemetry.counter("serving/decode_rows_fetched").inc(
                         decode_rows_fetched(lens))
+                    if self.cache.fused_window_walk:
+                        # the same of a sliding layer's ring: the last
+                        # ``window`` positions at most, every sliding layer
+                        ring = [min(n, self.cache.window) for n in lens]
+                        layers = self.cache.window_layers
+                        self.telemetry.counter(
+                            "serving/decode_rows_live_window").inc(
+                                layers * sum(ring))
+                        self.telemetry.counter(
+                            "serving/decode_rows_fetched_window").inc(
+                                layers * decode_rows_fetched(ring))
+                if counted:     # the model names what its step counted
+                    self.engine.module.record_step_counters(self.telemetry,
+                                                            counted[0])
             t_emit = self._now(now)
             for i in active_slots:
                 st = self._slots[i]

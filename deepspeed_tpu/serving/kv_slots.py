@@ -24,6 +24,14 @@ Two kinds of state can live in it side by side:
     it: ``recurrent_keys`` names these leaves and the engine refuses those
     options while there are any.
 
+  * **window rows** (leaves the model names in ``window_state_keys``; a
+    sliding-window layer's ``k_win``, ``v_win`` ``[L'', B_slots, Hkv, W,
+    Dh]``): a ring of the last ``W`` positions, position ``p`` at row ``p %
+    W``, so its size does not grow with ``max_len``. Prefill writes the last
+    ``W`` real positions, decode overwrites the row that leaves the window.
+    Like recurrent state it is not addressed by token rows, so it counts
+    among ``recurrent_keys`` and the same options are refused.
+
 A finished request's slot is reused by the next admission with ZERO cache
 reshaping — the prefill program overwrites the slot's prefix rows
 (ops/attention.write_slot_prefix) and its whole recurrent row, and resets
@@ -82,6 +90,16 @@ class SlotKVCache:
                            and self.pair == kv_pack_factor(head_dim)
                            and supports(hkv, hkv, self.k.shape[3] * self.pair,
                                         head_dim))
+        # ring leaves (a sliding-window layer's last ``window`` positions):
+        # their layers, the window, and whether the fused step walks them
+        # too (ops/attention.window_cached_attention's route)
+        ring = next((self.state[n] for n in
+                     getattr(model, "window_state_keys", ())), None)
+        self.window_layers, self.window = \
+            (0, 0) if ring is None else (ring.shape[0], ring.shape[3])
+        self.fused_window_walk = (
+            ring is not None and num_slots >= 2 and head_dim % 128 == 0
+            and supports(hkv, hkv, self.window, head_dim))
 
     @property
     def k(self):

@@ -127,6 +127,76 @@ def test_fused_decode_step_per_slot_active(one_chip, dims):
     _compiled_text(fn, *ops, _sds(one_chip, (dims[1],), jnp.bool_))
 
 
+# K-EXAONE's share (head size 128, 8 query heads a KV head, 32 slots): the
+# global layer's rows at 4096 and the four sliding layers' rings of 128
+WIDE_GQA_SHAPES = [(1, 32, 64, 8, 4096, 128, False),
+                   (4, 32, 64, 8, 128, 128, True)]
+
+
+@pytest.mark.parametrize("dims", WIDE_GQA_SHAPES, ids=str)
+def test_fused_decode_step_head_128_rep_8_and_ring(one_chip, dims):
+    """The per-slot walk at head size 128 and ``rep`` 8, and on a ring
+    (``ring=True``: the write row apart from the length, the written row
+    left out of the walk)."""
+    from deepspeed_tpu.ops.decode_step import fused_decode_step, slot_walk
+
+    *dims, ring = dims
+
+    def fn(q, k, v, kn, vn, layer, idx, active):
+        return fused_decode_step(q, k, v, kn, vn, layer, idx,
+                                 active=slot_walk(idx, active), ring=ring,
+                                 interpret=False)
+
+    ops = _decode_operands(one_chip, *dims)
+    _compiled_text(fn, *ops, _sds(one_chip, (dims[1],), jnp.bool_))
+
+
+@pytest.mark.parametrize("tokens", [32, 4096], ids=["decode", "prefill"])
+def test_held_experts_grouped_matmul(one_chip, tokens):
+    """The expert layer's grouped matmuls (``jax.lax.ragged_dot``, XLA's own
+    kernel on a TPU) at K-EXAONE's widths, 16 of 128 experts held, against
+    the whole layer-stacked weights: no copy of a layer's experts (1.2 GB)
+    is made to slice them."""
+    from deepspeed_tpu.moe.grouped import held_experts, sigmoid_topk_route
+
+    d, m, e, held, layers = 6144, 2048, 128, 16, 4
+
+    def fn(x, router, bias, wg, wu, wd, layer):
+        routing = sigmoid_topk_route(x, router, bias, 8, scale=2.5)
+        whole = [{"__whole__": w, "__layer__": layer} for w in (wg, wu, wd)]
+        return held_experts(x, routing, *whole, (0, held))
+
+    text = _compiled_text(
+        fn, _sds(one_chip, (tokens, d)), _sds(one_chip, (d, e)),
+        _sds(one_chip, (e,)), _sds(one_chip, (layers, held, d, m)),
+        _sds(one_chip, (layers, held, d, m)),
+        _sds(one_chip, (layers, held, m, d)), _sds(one_chip, (), jnp.int32))
+    assert len(re.findall(r"%ragged-dot-(?!metadata)[\w.\-]* = ", text)) == 3
+    # the stacks reach the kernels as they lie: nothing of a layer's size
+    assert not re.search(r"= bf16\[16,(6144,2048|2048,6144)\]", text)
+
+
+def test_window_prefill_scores_are_a_band(one_chip):
+    """A 4096-token prompt block on a sliding layer: query blocks of the
+    window against two blocks of keys, not ``[64, 4096, 4096]`` scores."""
+    from deepspeed_tpu.ops.attention import window_cached_attention
+
+    b, t, hq, hkv, w, dh = 1, 4096, 64, 8, 128, 128
+    ring = _sds(one_chip, (4, b, hkv, w, dh))
+    new = _sds(one_chip, (b, t, hkv, dh))
+
+    def fn(q, kr, vr, kn, vn, layer, length):
+        return window_cached_attention(q, kr, vr, kn, vn, layer, 0,
+                                       valid=length)
+
+    compiled = jax.jit(fn).lower(
+        _sds(one_chip, (b, t, hq, dh)), ring, ring, new, new,
+        _sds(one_chip, (), jnp.int32), _sds(one_chip, (), jnp.int32)
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+    assert "4096,4096" not in compiled.as_text()
+
+
 @pytest.mark.parametrize("dims", DECODE_SHAPES, ids=str)
 def test_fused_block_decode_step(one_chip, dims):
     from deepspeed_tpu.ops.decode_step import fused_block_decode_step
