@@ -600,6 +600,17 @@ class InferenceEngine:
         return tuple(range(1, n_state + 2)) \
             if jax.default_backend() == "tpu" else ()
 
+    @staticmethod
+    def _with_first_token(carry: tuple, token, slot, behind) -> tuple:
+        """A slot prefill program's outputs: the carry, the picked token,
+        and where the serving loop passed the decode step's previous tokens
+        (``behind``), those with the token written at ``slot``."""
+        out = (*carry, token)
+        if behind:
+            out += (jax.lax.dynamic_update_index_in_dim(
+                behind[0], token, slot, 0),)
+        return out
+
     def slot_prefill_program(self, bucket_len: int, num_slots: int,
                              max_len: int, *, do_sample: bool = False,
                              top_k: int = 0, top_p: float = 1.0):
@@ -625,8 +636,12 @@ class InferenceEngine:
 
         Signature of the returned program:
         ``(params, *state, lengths, ids[1, bucket], slot, length, temp,
-        rng) -> (*state, lengths, first_token)`` (cache operands donated on
-        TPU)."""
+        rng[, previous[B]]) -> (*state, lengths, first_token[, previous])``
+        (cache operands donated on TPU). The serving loop passes
+        ``previous``, the decode step's next tokens on the device (see
+        :meth:`slot_decode_program`), and gets it back with the first token
+        written at ``slot``: the next decode step can then be launched
+        before the host has fetched that token."""
         from deepspeed_tpu.ops.attention import write_slot_prefix
 
         key = ("slot_pf", bucket_len, num_slots, max_len, do_sample,
@@ -637,7 +652,9 @@ class InferenceEngine:
             names = slot_state_keys(model)
 
             def prefill(params, *ops):
-                *leaves, lengths, ids, slot, length, temp, rng = ops
+                leaves = ops[:len(names)]
+                lengths, ids, slot, length, temp, rng, *behind = \
+                    ops[len(names):]
                 state = dict(zip(names, leaves))
                 cache = model.init_cache(1, bucket_len, dtype=self.dtype)
                 cache["valid_len"] = length
@@ -653,8 +670,9 @@ class InferenceEngine:
                     lengths, length, slot, 0)
                 last = jax.lax.dynamic_index_in_dim(
                     logits, length - 1, 1, keepdims=False)       # [1, V]
-                return (*(state[n] for n in names), lengths,
-                        pick(last, temp, rng)[0])
+                return self._with_first_token(
+                    (*(state[n] for n in names), lengths),
+                    pick(last, temp, rng)[0], slot, behind)
 
             self._compiled[key] = jax.jit(
                 prefill, donate_argnums=self._carry_donation(len(names)))
@@ -686,9 +704,16 @@ class InferenceEngine:
         pattern.
 
         Signature: ``(params, *state, lengths[B], tokens[B], active[B]
-        bool, temp, rng) -> (*state, lengths, next_tokens[B])`` with the
-        state's leaves as in :meth:`slot_prefill_program` (cache operands
-        donated on TPU). A model that counts on the device what a step did
+        bool, temp, rng[, previous[B], from_host[B] bool]) -> (*state,
+        lengths, next_tokens[B])`` with the state's leaves as in
+        :meth:`slot_prefill_program` (cache operands donated on TPU). The
+        serving loop passes the two trailing operands: ``previous`` is the
+        ``next_tokens`` of the step launched before this one, still on the
+        device, and a slot's input is ``tokens`` where ``from_host`` says
+        the host has it (a prefill just finished, a resume) and
+        ``previous`` elsewhere, so a step can be launched before the last
+        one's tokens have come back. ``previous`` is read only: never
+        donated. A model that counts on the device what a step did
         (``model.step_counters``; an expert layer's touched experts) returns
         the counts behind the tokens, an int32 vector in that order, so the
         host fetches both at once and hands the vector to the model's
@@ -703,7 +728,11 @@ class InferenceEngine:
             names = slot_state_keys(model)
 
             def decode(params, *ops):
-                *leaves, lengths, tokens, active, temp, rng = ops
+                leaves = ops[:len(names)]
+                lengths, tokens, active, temp, rng, *behind = ops[len(names):]
+                if behind:
+                    previous, from_host = behind
+                    tokens = jnp.where(from_host, tokens, previous)
                 cache = dict(zip(names, leaves), index=lengths,
                              valid_len=active.astype(jnp.int32),
                              slot_walk=slot_walk(lengths, active))
@@ -884,8 +913,10 @@ class InferenceEngine:
         sentinel tables — their writes land in the pool's garbage row.
 
         Signature: ``(params, k_pool, v_pool, lengths[B], tables[B, MB],
-        tokens[B], active[B] bool, temp, rng) -> (k_pool, v_pool,
-        lengths, next_tokens[B])`` (pool operands donated on TPU)."""
+        tokens[B], active[B] bool, temp, rng[, previous[B], from_host[B]
+        bool]) -> (k_pool, v_pool, lengths, next_tokens[B])`` (pool
+        operands donated on TPU); the trailing pair as in
+        :meth:`slot_decode_program`."""
         key = ("blk_dec", num_slots, max_blocks, do_sample, top_k,
                float(top_p), pad_token_id, kv_dtype)
         if key not in self._compiled:
@@ -893,7 +924,10 @@ class InferenceEngine:
             pick = self._make_pick(do_sample, top_k, float(top_p))
 
             def decode(params, k_pool, v_pool, lengths, tables, tokens,
-                       active, temp, rng):
+                       active, temp, rng, *behind):
+                if behind:
+                    previous, from_host = behind
+                    tokens = jnp.where(from_host, tokens, previous)
                 cache = {"k": k_pool, "v": v_pool, "index": lengths,
                          "block_table": tables}
                 logits, cache = model.forward_with_cache(
@@ -1021,9 +1055,9 @@ class InferenceEngine:
         chunk's true length is written back.
 
         Signature: ``(params, *state, lengths, ids[1, bucket], slot, start,
-        length, temp, rng) -> (*state, lengths, token)`` with the state's
-        leaves as in :meth:`slot_prefill_program` (cache operands donated
-        on TPU)."""
+        length, temp, rng[, previous[B]]) -> (*state, lengths, token[,
+        previous])`` with the state's leaves and the trailing operand as in
+        :meth:`slot_prefill_program` (cache operands donated on TPU)."""
         key = ("slot_chunk_pf", bucket_len, num_slots, max_len, do_sample,
                top_k, float(top_p))
         if key not in self._compiled:
@@ -1032,7 +1066,9 @@ class InferenceEngine:
             names = slot_state_keys(model)
 
             def chunk(params, *ops):
-                *leaves, lengths, ids, slot, start, length, temp, rng = ops
+                leaves = ops[:len(names)]
+                lengths, ids, slot, start, length, temp, rng, *behind = \
+                    ops[len(names):]
                 state = dict(zip(names, leaves))
                 idx = jnp.reshape(jnp.asarray(start, jnp.int32), (1,))
                 cache = {n: extract_slot_row(state[n], slot) for n in names}
@@ -1045,8 +1081,9 @@ class InferenceEngine:
                     lengths, start + length, slot, 0)
                 last = jax.lax.dynamic_index_in_dim(
                     logits, length - 1, 1, keepdims=False)       # [1, V]
-                return (*(state[n] for n in names), lengths,
-                        pick(last, temp, rng)[0])
+                return self._with_first_token(
+                    (*(state[n] for n in names), lengths),
+                    pick(last, temp, rng)[0], slot, behind)
 
             self._compiled[key] = jax.jit(
                 chunk, donate_argnums=self._carry_donation(len(names)))

@@ -110,10 +110,13 @@ class _SlotState:
     sequence — chunk continuations run priority-then-admission order,
     so earlier same-class prompts finish prefilling first. ``tenant``
     is the request's SANITIZED accounting tenant (ISSUE 13), resolved
-    once at admission."""
+    once at admission. ``in_flight`` counts the slot's tokens that a
+    launched program (the last prefill chunk, a decode step) has picked
+    and the host has not fetched yet: the host's bookkeeping of lengths
+    and of ends by length runs that far ahead of ``result.tokens``."""
 
     __slots__ = ("request", "result", "last_token", "prefill_pos",
-                 "prefill_total", "order", "tenant")
+                 "prefill_total", "order", "tenant", "in_flight")
 
     def __init__(self, request: Request, result: RequestResult,
                  last_token: int, prefill_pos: int, prefill_total: int,
@@ -125,10 +128,46 @@ class _SlotState:
         self.prefill_total = prefill_total
         self.order = order
         self.tenant = tenant
+        self.in_flight = 0
 
     @property
     def prefilling(self) -> bool:
         return self.prefill_pos < self.prefill_total
+
+
+class _Flight:
+    """A launched decode step whose tokens have not been fetched:
+    ``answer`` is what the program returned behind the cache's carry (the
+    next tokens, then the model's step counters), still on the device;
+    ``states`` pairs every slot active in the
+    step with the ``_SlotState`` it held at launch, so that commit gives a
+    token only to a slot that still holds the same object; ``overlapped``
+    says the step before it was still unfetched when this one launched."""
+
+    __slots__ = ("answer", "states", "overlapped")
+
+    def __init__(self, answer, states, overlapped: bool):
+        self.answer = answer
+        self.states = states
+        self.overlapped = overlapped
+
+
+class _FirstToken:
+    """A prompt's last prefill chunk whose pick has not been fetched: the
+    token on the device, and what the commit needs to stamp the chunk's
+    span from its program call."""
+
+    __slots__ = ("slot", "state", "token", "t_span0", "program", "bucket",
+                 "chunk")
+
+    def __init__(self, slot, state, token, t_span0, program, bucket, chunk):
+        self.slot = slot
+        self.state = state
+        self.token = token
+        self.t_span0 = t_span0
+        self.program = program
+        self.bucket = bucket
+        self.chunk = chunk
 
 
 class _Preempted:
@@ -538,8 +577,24 @@ class ServingEngine:
                 self._drafter = NgramDrafter(self.spec)
             if self.spec.adaptive:
                 self._adaptive = AdaptiveK(self.spec, num_slots)
+        # ---- results fetched one launch behind (ISSUE 36): decode step
+        # N+1 is launched before step N's tokens are fetched, its inputs
+        # taken on the device from step N's output. The look-ahead is 1
+        # where the loop can see that the next step needs no host token and
+        # 0 where it cannot: drafts are made from host tokens, and the
+        # block-paged mode's radix and tables move with every finish
+        self._ahead = self.spec is None and not prefix_cache
+        self._flight: Optional[_Flight] = None
+        # last chunks launched this schedule phase, first tokens unfetched
+        self._firsts: List[_FirstToken] = []
+        # every slot's newest pick, on the device: the last decode step's
+        # next tokens, with the first token of each prefill launched since
+        self._previous = self._canon(
+            jnp.full((num_slots,), pad_token_id, jnp.int32))
         # metrics
         self.decode_steps = 0
+        self.decode_steps_overlapped = 0
+        self.slot_steps_wasted = 0
         self.prefill_calls = 0
         # prompt tokens actually run through a prefill program (suffix
         # tokens in prefix-cache mode — the bench's "prefill tokens
@@ -572,6 +627,10 @@ class ServingEngine:
             self.telemetry = get_registry()
         else:
             self.telemetry = telemetry or None
+        if self.telemetry is not None:
+            # read as a share of serving/decode_steps: there from the start
+            self.telemetry.counter("serving/decode_steps_overlapped")
+            self.telemetry.counter("serving/slot_steps_wasted")
         # ---- SLO control plane + per-tenant accounting (ISSUE 13)
         self.slo = slo
         if tenants is None:
@@ -637,6 +696,15 @@ class ServingEngine:
         back to the cache; what is left is the program's answer."""
         self.cache.update(*out[:self._n_carry])
         return out[self._n_carry:]
+
+    def _adopt_first(self, out):
+        """:meth:`_adopt` for a prefill program: a slot-paged one also
+        hands back the decode step's previous tokens with its pick written
+        at the slot. Returns the pick, on the device."""
+        token, *previous = self._adopt(out)
+        if previous:
+            self._previous, = previous
+        return token
 
     def _prefill_fn(self, bucket: int):
         if bucket not in self._prefill:
@@ -813,8 +881,9 @@ class ServingEngine:
         """Compile every serving program (each bucket's prefill + the
         decode step + with speculation each k-bucket's verify and draft
         programs) on dummy data, then reset the slot lengths. Two
-        passes, so both carry signatures — canonical (post-reset) and
-        program-output — are cached for every program; after this, a
+        passes, so both signatures — canonical (post-reset) and
+        program-output — of the carry and of the decode step's previous
+        tokens are cached for every program; after this, a
         trace of ANY shape mix (including adaptive-k transitions) runs
         zero compiles."""
         if self._warm:
@@ -836,8 +905,9 @@ class ServingEngine:
                     out = self._prefill_fn(b)(*self._cap(
                         f"prefill_{b}",
                         eng.params, *self.cache.carry(), ids, np.int32(0),
-                        np.int32(1), self._temp, self._zero_key))
-                self._adopt(out)
+                        np.int32(1), self._temp, self._zero_key,
+                        self._previous))
+                self._adopt_first(out)
                 if (self._chunk_max is not None and not paged
                         and b <= self._chunk_max):
                     # slot-paged chunk programs: chunks never exceed
@@ -846,8 +916,8 @@ class ServingEngine:
                         f"chunk_prefill_{b}",
                         eng.params, *self.cache.carry(), ids, np.int32(0),
                         np.int32(0), np.int32(1), self._temp,
-                        self._zero_key))
-                    self._adopt(out)
+                        self._zero_key, self._previous))
+                    self._adopt_first(out)
             if self.preemption is not None:
                 # swap round trip through slot/garbage rows, with the
                 # host upload in the loop so BOTH runtime operand
@@ -876,12 +946,15 @@ class ServingEngine:
                 self.cache.update(*out)
             toks = np.zeros((self.num_slots,), np.int32)
             active = np.zeros((self.num_slots,), bool)
+            # the previous step's tokens too come with both signatures:
+            # uploaded on the first pass, a program's output on the second
             out = self._decode(*self._cap(
                 "decode", eng.params, *self.cache.carry(),
                 *self._table_args(),
                 jnp.asarray(toks), jnp.asarray(active),
-                self._temp, self._zero_key))
-            self._adopt(out)
+                self._temp, self._zero_key, self._previous,
+                jnp.asarray(active)))
+            self._previous = self._adopt(out)[0]
             if paged:
                 # COW copy program: garbage row onto itself is a no-op
                 k, v = self._copy_fn(*self._cap(
@@ -1188,7 +1261,15 @@ class ServingEngine:
         copies, and prefills only the unmatched suffix — bucketed by
         SUFFIX length (and chunked under a prefill budget), so a long
         shared system prompt with a short unique tail prefills in the
-        smallest bucket."""
+        smallest bucket.
+
+        A prompt's first token is not fetched after its own launch (ISSUE
+        36) but after the iteration's last, the decode step's
+        (:meth:`_land_firsts`): a burst's prefills and the step behind them
+        run on the device back to back, and each first token is still
+        stamped when its own fetch returns. A request that ends at its first
+        token (``max_new_tokens`` 1, EOS) frees its slot at that commit, for
+        the next iteration's admission."""
         budget = self._iteration_prefill_budget(now)
         # (1) in-flight chunked prefills first: an admitted prompt
         # finishes prefilling before new admissions eat the budget
@@ -1210,12 +1291,12 @@ class ServingEngine:
             pairs = self.scheduler.admit(now, fits=self._admit_fits,
                                          limit=1)
             if not pairs:
-                if not self._try_preempt(now):
+                if not self._try_preempt(now, finished):
                     break
                 continue
             (req, slot), = pairs
             if req.rid in self._preempted:
-                self._resume(slot, req, now)
+                self._resume(slot, req, now, finished)
                 continue
             spent += self._admit_one(
                 slot, req, now, None if budget is None else budget - spent,
@@ -1356,7 +1437,10 @@ class ServingEngine:
         The first generated token is picked only by the LAST chunk —
         intermediate chunk picks are never device_get (discarded, still
         async) — and TTFT is stamped at that commit (ISSUE 8
-        latency-accounting fix)."""
+        latency-accounting fix), which :meth:`_land_firsts` makes: behind
+        the iteration's decode launch, or here where the loop never
+        launches ahead. A slot-paged program also writes the pick into
+        ``_previous`` at the slot, where that decode step finds it."""
         st = self._slots[slot]
         req = st.request
         eng = self.engine
@@ -1374,9 +1458,9 @@ class ServingEngine:
             if armed:
                 t_span0 = self._now(now)
                 t_wall0 = time.perf_counter()
-            # open from before the program call until, for the last chunk,
-            # its fenced token fetch returns: an idle device in between is
-            # the prefill's, not the admission's
+            # an idle device from the program call on is the prefill's, not
+            # the admission's; the last chunk's fetch opens the same
+            # annotation again
             with _Phase(self, "dstpu/serving_prefill", None, now):
                 if self.prefix is not None:
                     pname = f"prefill_{bucket}"
@@ -1392,21 +1476,22 @@ class ServingEngine:
                     out = self._prefill_fn(bucket)(
                         eng.params, *self.cache.carry(), jnp.asarray(ids),
                         np.int32(slot), np.int32(chunk), self._temp,
-                        self._next_rng())
+                        self._next_rng(), self._previous)
                 else:
                     pname = f"chunk_prefill_{bucket}"
                     out = self._chunk_fn(bucket)(
                         eng.params, *self.cache.carry(), jnp.asarray(ids),
                         np.int32(slot), np.int32(st.prefill_pos),
-                        np.int32(chunk), self._temp, self._next_rng())
-                out = self._adopt(out)
+                        np.int32(chunk), self._temp, self._next_rng(),
+                        self._previous)
+                token = self._adopt_first(out)
                 if armed:
                     # host-stamped at the instants the loop already holds:
                     # no fence added. An intermediate chunk has no fence,
                     # so under async dispatch its span brackets the
                     # dispatch only (fenced=False); the LAST chunk's span
-                    # closes below, after the token fetch the untraced
-                    # engine always paid
+                    # closes at its commit, after the token fetch the
+                    # untraced engine always paid
                     self._prog_note(pname, time.perf_counter() - t_wall0)
                     rt = self._rtraces.get(req.rid)
                     if rt is not None and not last:
@@ -1440,46 +1525,78 @@ class ServingEngine:
                     # so per-tenant computed tokens sum EXACTLY to it
                     self.tenants.note_prefill(st.tenant, chunk)
                 if last:
-                    tok = int(jax.device_get(out[0]))  # dstpu-lint: fence=token emission: the chunk's final pick must reach the host stream
-            if last:
-                self.prefill_calls += 1
-                self.tokens_generated += 1
-                st.last_token = tok
-                st.result.tokens.append(tok)
-                t_emit = self._now(now)
-                st.result.first_token_time = t_emit
-                st.result.token_times.append(t_emit)
-                self._stream(st, [tok])
-                ttft = max(t_emit - req.arrival_time, 0.0) * 1e3
-                if self.telemetry is not None:
-                    self.telemetry.histogram("serving/ttft_ms").observe(ttft)
-                    self.telemetry.histogram(
-                        f"serving/ttft_ms/p{metric_label(req.priority)}"
-                    ).observe(ttft)
-                if self.tenants is not None:
-                    self.tenants.note_tokens(st.tenant, 1)
-                    self.tenants.note_ttft(st.tenant, ttft)
-                if armed:
-                    rt = self._rtraces.get(req.rid)
-                    if rt is not None:
-                        # the fenced chunk ends at the first-token commit,
-                        # where decode-phase residency starts (closed at
-                        # finish/preemption/cancel)
-                        self.tracer.record(
-                            "prefill_chunk", t_span0, t_emit,
-                            trace_id=rt.trace_id, parent_id=rt.root,
-                            program=pname, bucket=bucket, tokens=chunk,
-                            slot=slot, fenced=True)
-                        rt.decode_span = self.tracer.begin(
-                            "decode_segment", trace_id=rt.trace_id,
-                            parent_id=rt.root, t=t_emit, slot=slot)
-                done = self._maybe_finish(slot, now)
-                if done is not None:
-                    finished.append(done)
+                    st.in_flight += 1
+                    self._firsts.append(_FirstToken(
+                        slot, st, token, t_span0 if armed else 0.0, pname,
+                        bucket, chunk))
+            if last and not self._ahead:
+                self._land_firsts(now, finished)
         return spent
 
+    def _land_firsts(self, now: float,
+                     finished: List[RequestResult]) -> None:
+        """Fetch and commit, in launch order, the first tokens of the
+        prefills launched since the last call: each token is stamped (TTFT,
+        the ``fenced`` chunk span's end) at a fresh read of the clock when
+        its own fetch returns."""
+        firsts, self._firsts = self._firsts, []
+        for first in firsts:
+            slot, st = first.slot, first.state
+            req = st.request
+            with _Phase(self, "dstpu/serving_prefill", None, now):
+                tok = int(jax.device_get(first.token))  # dstpu-lint: fence=token emission: the chunk's final pick must reach the host stream
+            st.in_flight -= 1
+            self.prefill_calls += 1
+            self.tokens_generated += 1
+            st.last_token = tok
+            st.result.tokens.append(tok)
+            t_emit = self._now(now)
+            st.result.first_token_time = t_emit
+            st.result.token_times.append(t_emit)
+            self._stream(st, [tok])
+            ttft = max(t_emit - req.arrival_time, 0.0) * 1e3
+            if self.telemetry is not None:
+                self.telemetry.histogram("serving/ttft_ms").observe(ttft)
+                self.telemetry.histogram(
+                    f"serving/ttft_ms/p{metric_label(req.priority)}"
+                ).observe(ttft)
+            if self.tenants is not None:
+                self.tenants.note_tokens(st.tenant, 1)
+                self.tenants.note_ttft(st.tenant, ttft)
+            if self.tracer is not None:
+                rt = self._rtraces.get(req.rid)
+                if rt is not None:
+                    # the fenced chunk ends at the first-token commit,
+                    # where decode-phase residency starts (closed at
+                    # finish/preemption/cancel)
+                    self.tracer.record(
+                        "prefill_chunk", first.t_span0, t_emit,
+                        trace_id=rt.trace_id, parent_id=rt.root,
+                        program=first.program, bucket=first.bucket,
+                        tokens=first.chunk, slot=slot, fenced=True)
+                    rt.decode_span = self.tracer.begin(
+                        "decode_segment", trace_id=rt.trace_id,
+                        parent_id=rt.root, t=t_emit, slot=slot)
+            done = self._maybe_finish(slot, now)
+            if done is not None:
+                finished.append(done)
+
+    def _land_all(self, now: float, finished: List[RequestResult]) -> bool:
+        """Commit whatever is launched and unfetched, first tokens and the
+        decode step in flight, before host work that needs every slot's
+        state whole (a swap-out parks ``last_token``). Says whether there
+        was anything: a commit can finish requests and free their slots."""
+        if not self._firsts and self._flight is None:
+            return False
+        self._land_firsts(now, finished)
+        flight = self._flight
+        if flight is not None:
+            self._commit(flight, self._fetch(flight, now), now, finished)
+        return True
+
     # -------------------------------------------------------- preemption
-    def _try_preempt(self, now: float) -> bool:
+    def _try_preempt(self, now: float,
+                     finished: List[RequestResult]) -> bool:
         """Make room for the best waiting request by swapping out one
         strictly-lower-priority running slot (ISSUE 8). Called only
         after admission came up empty, i.e. the candidate is blocked on
@@ -1493,7 +1610,9 @@ class ServingEngine:
         ping-pong this guard exists to prevent). Victim choice: the
         worst class, and within it the most recently admitted (least
         sunk work). Returns True if a slot was freed (the caller
-        retries admission)."""
+        retries admission). Nothing is in flight while a victim is chosen
+        and swapped out: what was is committed first, and since that alone
+        can free a slot the caller retries after it too."""
         if self.preemption is None:
             return False
         cand = self.scheduler.peek(now)
@@ -1506,6 +1625,8 @@ class ServingEngine:
                    and eff(s.request, now) > cand_eff]
         if not victims:
             return False
+        if self._land_all(now, finished):
+            return True
         victim = max(victims, key=lambda i: (self._slots[i].request.priority,
                                              self._slots[i].order))
         try:
@@ -1597,14 +1718,17 @@ class ServingEngine:
             reg.counter("serving/swapped_blocks_out").inc(
                 n_used if self.prefix is not None else 1)
 
-    def _resume(self, slot: int, req: Request, now: float) -> None:
+    def _resume(self, slot: int, req: Request, now: float,
+                finished: List[RequestResult]) -> None:
         """Swap a preempted request back into ``slot``: upload its host
         KV, restore its length, and reattach its slot state. Block-paged
         mode first re-matches the prompt against the radix index —
         still-cached full prefix blocks are re-pinned and skipped by the
         upload (and a trie that learned a LONGER prefix while the
         request was parked fast-forwards a mid-prefill resume past it).
-        Decode continues exactly where it left off."""
+        Decode continues exactly where it left off, from the host's token,
+        with nothing in flight."""
+        self._land_all(now, finished)
         rec = self._preempted.pop(req.rid)
         st = rec.state
         armed = self.tracer is not None
@@ -1685,7 +1809,15 @@ class ServingEngine:
         (chunk continuations, admissions, preemptions — ISSUE 8), then
         decode one step for every DECODE-PHASE slot (slots still
         prefilling their prompt sit the decode out). Returns requests
-        finished this iteration."""
+        finished this iteration.
+
+        Results are fetched one launch behind (ISSUE 36): the iteration
+        launches its decode step and then fetches and commits the step the
+        iteration before launched, so tokens, ``on_token`` calls and
+        finished requests arrive one call later than the step that made
+        them, and an iteration with a step in flight and nothing to launch
+        still fetches and commits. With ``speculative`` or ``prefix_cache``
+        set the step is fetched in the iteration that launched it."""
         if not self._warm:
             self.warmup()
         if now is None:
@@ -1712,7 +1844,8 @@ class ServingEngine:
             t_iter0 = self._now(now)
         with _Phase(self, "dstpu/serving_admit", None, now):
             self._schedule(now, finished)
-        if armed and (finished or any(s is not None for s in self._slots)):
+        if armed and (finished or self._flight is not None
+                      or any(s is not None for s in self._slots)):
             # this step admitted, prefilled or will decode: one
             # `iteration` span in the engine-scope trace, tiled by its
             # phases (an idle poll of the queue records nothing)
@@ -1720,8 +1853,12 @@ class ServingEngine:
                 "iteration", trace_id=self._iter_trace(), t=t_iter0)
             self._phase_t = t_iter0
             self._phase_end("iter_schedule", now)
+        # a slot whose last token the step in flight is picking sits out:
+        # an end by length is known at launch, one by EOS only at commit
         active_slots = [i for i, s in enumerate(self._slots)
-                        if s is not None and not s.prefilling]
+                        if s is not None and not s.prefilling
+                        and len(s.result.tokens) + s.in_flight
+                        < s.request.max_new_tokens]
         if self.telemetry is not None:
             # iteration-level gauges: slot occupancy after admission
             # (prefilling slots included) and the decode batch's fill
@@ -1733,16 +1870,19 @@ class ServingEngine:
             if active_slots:
                 self.telemetry.gauge("serving/batch_fill_ratio").set(
                     len(active_slots) / self.num_slots)
-        if not active_slots:
+        if not active_slots and self._flight is None:
             # no decode ran: a later gap against _last_decode_t would
             # fold queue-idle time into the TPOT-SLO EMA
             self._last_decode_t = None
-        else:
+            self._land_firsts(now, finished)    # prompts of one token
+        elif self.spec is not None:
             self._note_decode_gap()
-            if self.spec is not None:
-                self._spec_step(now, active_slots, finished)
-            else:
-                self._plain_step(now, active_slots, finished)
+            self._spec_step(now, active_slots, finished)
+        else:
+            # an iteration that only fetches is no decode invocation
+            t0 = self._note_decode_gap() if active_slots \
+                else time.perf_counter()
+            self._plain_step(now, active_slots, finished, t0)
         if self._iter_span is not None:
             # ends where its last phase ended
             self.tracer.end(self._iter_span, t=self._phase_t)
@@ -1797,7 +1937,7 @@ class ServingEngine:
                            parent_id=self._iter_span.span_id)
         self._phase_t = t
 
-    def _note_decode_gap(self) -> None:
+    def _note_decode_gap(self) -> float:
         """EMA of wall time between consecutive decode invocations —
         the signal the ``tpot_slo_ms`` admission guard watches. Host
         wall, not the injected clock: the guard protects real decode
@@ -1808,57 +1948,118 @@ class ServingEngine:
             self._decode_gap_ema = gap if self._decode_gap_ema is None \
                 else 0.7 * self._decode_gap_ema + 0.3 * gap
         self._last_decode_t = t
+        return t
 
     def _plain_step(self, now: float, active_slots: List[int],
-                    finished: List[RequestResult]) -> List[RequestResult]:
-        """One plain decode iteration: one token for every active slot.
-        Also the speculative path's fallback when drafting proposes
-        nothing anywhere (a 1-wide step beats an empty k-wide verify)."""
-        with _Phase(self, "dstpu/serving_upload", "iter_upload", now):
-            toks = np.full((self.num_slots,), self.pad_token_id, np.int32)
-            for i in active_slots:
-                toks[i] = self._slots[i].last_token
-            active = np.zeros((self.num_slots,), bool)
-            active[active_slots] = True
-            armed = self.tracer is not None
+                    finished: List[RequestResult],
+                    t0: float) -> List[RequestResult]:
+        """One plain decode iteration: launch one token for every active
+        slot, then fetch and commit the step launched an iteration earlier
+        (this one's own where the loop does not launch ahead). A slot whose
+        last pick is still unfetched (the step in flight covers it, or its
+        prefill ran in this iteration) takes its input on the device, from
+        ``_previous``; the host uploads the token of the others (a
+        resume, a step committed early). Also the speculative path's fallback
+        when drafting proposes nothing anywhere (a 1-wide step beats an
+        empty k-wide verify). ``t0`` is the host's wall as the step
+        begins."""
+        landing = self._flight
+        armed = self.tracer is not None
+        # decode_step opens with the iteration's first decode phase, which
+        # starts where the phase before it ended
+        t_dec0 = self._phase_t
+        if active_slots:
+            with _Phase(self, "dstpu/serving_upload", "iter_upload", now):
+                toks = np.full((self.num_slots,), self.pad_token_id, np.int32)
+                from_host = np.zeros((self.num_slots,), bool)
+                states = [(i, self._slots[i]) for i in active_slots]
+                for i, st in states:
+                    if not st.in_flight:
+                        toks[i] = st.last_token
+                        from_host[i] = True
+                active = np.zeros((self.num_slots,), bool)
+                active[active_slots] = True
+                args = (self.engine.params, *self.cache.carry(),
+                        *self._table_args(),
+                        jnp.asarray(toks), jnp.asarray(active),
+                        self._temp, self._next_rng(), self._previous,
+                        jnp.asarray(from_host))
+            with _Phase(self, "dstpu/serving_launch", "iter_launch", now):
+                answer = self._adopt(self._decode(*args))
+                # no copy_to_host_async() here: the way back runs under the
+                # step queued behind in any case (the fetch comes after the
+                # next launch), and a copy asked of a result not yet
+                # computed brought stalls of 1.6 to 7.1 s in device_get in 6
+                # of 16 runs of the hybrid serve cell, none in 10 without
+                # (PERF.md, PR 36)
+                self._previous = answer[0]
+                for _, st in states:
+                    st.in_flight += 1
+                self._flight = _Flight(answer, states, landing is not None)
+        # the prompts prefilled in this iteration's schedule phase: their
+        # first tokens come back behind the last launch, in launch order
+        self._land_firsts(now, finished)
+        if landing is None and not self._ahead:
+            landing = self._flight
+        if landing is not None:
+            fetched = self._fetch(landing, now)
+            dt = time.perf_counter() - t0
+            self.decode_wall += dt
             if armed:
-                t_dec0 = self._now(now)
-            t0 = time.perf_counter()
-            args = (self.engine.params, *self.cache.carry(),
-                    *self._table_args(),
-                    jnp.asarray(toks), jnp.asarray(active),
-                    self._temp, self._next_rng())
-        with _Phase(self, "dstpu/serving_launch", "iter_launch", now):
-            out = self._adopt(self._decode(*args))
+                # upload opened to fetch closed: the fetch IS a fence, and
+                # in a run of decode iterations each waits for the step
+                # launched one earlier, so this wall is what a step costs —
+                # the attribution 'achieved' clock
+                self._prog_note("decode", dt)
+                # iter_fetch closed at a read of the clock after the fence
+                self.tracer.record("decode_step", t_dec0, self._phase_t,
+                                   trace_id=self._iter_trace(),
+                                   program="decode",
+                                   n_slots=len(landing.states))
+            self._commit(landing, fetched, now, finished)
+        return finished
+
+    def _fetch(self, flight: _Flight, now: float) -> list:
+        """Wait for a launched decode step's answer on the host."""
+        if flight is self._flight:
+            self._flight = None
         with _Phase(self, "dstpu/serving_fetch", "iter_fetch", now):
-            # a model's device-side step counters ride behind the tokens
-            nxt, *counted = jax.device_get(out)  # dstpu-lint: fence=token emission: decode's picks feed host continuations + streams
-            nxt = np.asarray(nxt)
-        dt = time.perf_counter() - t0
-        self.decode_wall += dt
-        if armed:
-            # the token fetch above IS a fence, so this wall is honest
-            # device-inclusive time — the attribution 'achieved' clock
-            self._prog_note("decode", dt)
-            # iter_fetch closed at a read of the clock after the fence
-            self.tracer.record("decode_step", t_dec0, self._phase_t,
-                               trace_id=self._iter_trace(),
-                               program="decode",
-                               n_slots=len(active_slots))
+            return jax.device_get(flight.answer)  # dstpu-lint: fence=token emission: decode's picks feed host continuations + streams
+
+    def _commit(self, flight: _Flight, fetched: list, now: float,
+                finished: List[RequestResult]) -> None:
+        """Commit a fetched decode step: a token goes to a slot only if it
+        still holds the state it held at launch. A request that ended at an
+        EOS the host had not seen when the next step was launched (or was
+        cancelled under it) ran one slot-step for nothing; its token is
+        dropped here."""
+        # a model's device-side step counters ride behind the tokens
+        nxt, *counted = fetched
+        nxt = np.asarray(nxt)
         with _Phase(self, "dstpu/serving_commit", "iter_commit", now):
+            states = flight.states
+            live = [(i, st) for i, st in states if self._slots[i] is st]
+            wasted = len(states) - len(live)
             self.decode_steps += 1
-            self._active_slot_iterations += len(active_slots)
+            self.decode_steps_overlapped += flight.overlapped
+            self.slot_steps_wasted += wasted
+            self._active_slot_iterations += len(states)
             if self.telemetry is not None:
                 self.telemetry.counter("serving/decode_steps").inc()
+                if flight.overlapped:
+                    self.telemetry.counter(
+                        "serving/decode_steps_overlapped").inc()
+                if wasted:
+                    self.telemetry.counter("serving/slot_steps_wasted").inc(
+                        wasted)
                 self.telemetry.counter("serving/slot_iterations_active").inc(
-                    len(active_slots))
+                    len(states))
                 if self.prefix is None and self.cache.fused_walk:
-                    # what the fused decode step's walk moves this step, a
-                    # layer, by the host's own bookkeeping: a slot's cache
-                    # holds its prompt and every token but the one fed now
-                    lens = [len(self._slots[i].request.prompt)
-                            + len(self._slots[i].result.tokens) - 1
-                            for i in active_slots]
+                    # what the fused decode step's walk moved in this step,
+                    # a layer, by the host's own bookkeeping: a slot's cache
+                    # held its prompt and every token but the one fed then
+                    lens = [len(st.request.prompt)
+                            + len(st.result.tokens) - 1 for _, st in states]
                     self.telemetry.counter("serving/decode_rows_live").inc(
                         sum(lens))
                     self.telemetry.counter("serving/decode_rows_fetched").inc(
@@ -1878,8 +2079,9 @@ class ServingEngine:
                     self.engine.module.record_step_counters(self.telemetry,
                                                             counted[0])
             t_emit = self._now(now)
-            for i in active_slots:
-                st = self._slots[i]
+            for i, st in states:
+                st.in_flight -= 1
+            for i, st in live:
                 tok = int(nxt[i])
                 st.result.tokens.append(tok)
                 st.result.token_times.append(t_emit)
@@ -1892,7 +2094,6 @@ class ServingEngine:
                 done = self._maybe_finish(i, now)
                 if done is not None:
                     finished.append(done)
-        return finished
 
     def _spec_step(self, now: float, active_slots: List[int],
                    finished: List[RequestResult]) -> List[RequestResult]:
@@ -1953,7 +2154,8 @@ class ServingEngine:
             # nothing proposed anywhere (e.g. prompt-lookup on novel
             # text): the plain decode step emits the identical token at
             # 1-token width
-            return self._plain_step(now, active_slots, finished)
+            return self._plain_step(now, active_slots, finished,
+                                    time.perf_counter())
         # shrink the verify width to the drafts we actually have (a
         # partial match needs a narrower program than the full want)
         kb = pick_k_bucket(longest, spec.k_buckets)

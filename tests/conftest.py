@@ -363,7 +363,10 @@ _LATE_MODULES = _OBSERVABILITY_MODULES + (
     # in directory order they ran beside unit/model_parallelism and
     # starved test_tp.py::test_llama_trains' rendezvous (below)
     "unit/inference/test_exaone_moe",
-    "unit/benchmarks/test_exaone_moe",)
+    "unit/benchmarks/test_exaone_moe",
+    # PR 36: three tiny families' serving programs in one module (about
+    # 100 s of compiles), kept away from that rendezvous too
+    "unit/serving/test_overlapped_decode",)
 
 # Dead-last group, AFTER even the torch modules: pure-AST, device-free
 # suites (the dstpu-lint/prove analysis tests never launch a collective,

@@ -95,12 +95,13 @@ def test_decode_row_counters_over_a_serving_run():
         srv.step(srv._time() - t0)
         # the host's arithmetic is the device's: a slot's cache holds its
         # prompt and every emitted token but the last, which the next step
-        # feeds; that is the length the counters take before a step
+        # feeds; that is the length the counters take before a step. The
+        # device runs one launched step ahead of the committed tokens
         dev = np.asarray(srv.cache.lengths)
         for i, st in enumerate(srv._slots):
             if st is not None and not st.prefilling:
                 assert dev[i] == len(st.request.prompt) \
-                    + len(st.result.tokens) - 1
+                    + len(st.result.tokens) + st.in_flight - 1
                 seen += 1
     assert seen > 3
     live = reg.counter("serving/decode_rows_live").value

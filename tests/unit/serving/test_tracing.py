@@ -174,8 +174,10 @@ PHASES = ("iter_schedule", "iter_upload", "iter_launch", "iter_fetch",
 def test_phases_tile_the_iteration_span():
     """Every step() that found work records one ``iteration`` span on
     the engine-scope trace; its phases have it as parent, follow each
-    other without a gap from its start to its end, and the four decode
-    phases close on iteration less schedule exactly."""
+    other without a gap from its start to its end, and the decode phases
+    close on iteration less schedule exactly. Results are fetched one
+    launch behind (ISSUE 36): the first iteration of a run of steps
+    launches and fetches nothing, the last fetches and launches nothing."""
     tracer = SpanTracer()
     srv = _serving(FakeClock(auto_dt=0.001), tracer=tracer)
     results = srv.run(_trace(8, seed=6))
@@ -190,18 +192,22 @@ def test_phases_tile_the_iteration_span():
                         key=lambda s: s.start)
         assert {s.name for s in kids} <= set(PHASES) | {"decode_step"}
         names = [s.name for s in phases]
-        assert names in (list(PHASES), ["iter_schedule"]), names
+        launch_only, fetch_only = list(PHASES[:3]), \
+            [PHASES[0]] + list(PHASES[3:])
+        assert names in (list(PHASES), ["iter_schedule"], launch_only,
+                         fetch_only), names
         assert phases[0].start == it.start and phases[-1].end == it.end
         for a, b in zip(phases, phases[1:]):
             assert a.end == b.start
         assert all(s.trace_id == it.trace_id for s in kids)
         if len(phases) > 1:
-            decoded += 1
+            decoded += "iter_fetch" in names
             rest = sum(s.duration for s in phases[1:])
             assert rest == pytest.approx(it.duration - phases[0].duration)
     assert decoded == srv.decode_steps
     # decode_step keeps its place: from before the program's arguments
-    # are bound to after the fetch, so it ends with iter_fetch
+    # are bound to after the fetch (of the step launched an iteration
+    # earlier), so it ends with iter_fetch
     fetch_ends = {s.end for s in tracer.spans if s.name == "iter_fetch"}
     steps = [s for s in tracer.spans if s.name == "decode_step"]
     assert len(steps) == srv.decode_steps
@@ -317,8 +323,10 @@ def test_bare_engine_reads_no_clock_the_parent_did_not(monkeypatch):
     """With ``tracer=None`` the iteration reads the engine's clock once
     for each first token, once for each decode step (the commit stamp)
     and once for each finished request, as before the phases existed,
-    and ``perf_counter`` three times a decode step (the decode-gap EMA
-    and the decode wall); armed output is bit-identical."""
+    and ``perf_counter`` at most three times a decode step (once as a step
+    is launched, for the decode-gap EMA and the decode wall's start, once
+    as one is fetched; once more where an iteration only fetches); armed
+    output is bit-identical."""
     from deepspeed_tpu.serving import engine as engine_mod
 
     reqs = _trace(8, seed=10)
@@ -348,7 +356,7 @@ def test_bare_engine_reads_no_clock_the_parent_did_not(monkeypatch):
     assert len(results) == len(reqs)
     assert calls["clock"] == (bare.prefill_calls + bare.decode_steps
                               + len(results))
-    assert calls["perf"] == 3 * bare.decode_steps
+    assert 2 * bare.decode_steps <= calls["perf"] <= 3 * bare.decode_steps
     assert bare._iter_span is None and bare._open_phase is None
     monkeypatch.undo()
     tracer = SpanTracer()
