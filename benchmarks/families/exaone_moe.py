@@ -26,11 +26,10 @@ def shapes(cfg: Mapping) -> dict:
     ``experts_held``, ``experts_per_token``, ``expert_mlp``, ``window``,
     ``sliding_layers`` and ``global_layers`` are for ``work/moe_experts.py``.
 
-    ``hidden`` is what the harness computes with under that name, the width
-    of attention, ``heads x head_dim`` = 8192 (``flops.train_flops_per_token``
-    counts attention's FLOPs from it, and ``test_benchmark.py`` holds every
-    family to ``head_dim * heads == hidden``); the residual stream's width,
-    which the published ``hidden_size`` names, is ``width`` = 6144."""
+    ``hidden`` is the residual stream's width, the published ``hidden_size``
+    = 6144; attention is ``heads x head_dim`` = 8192 wide on it, which
+    ``flops.py`` computes from those two. ``width`` says the same as
+    ``hidden`` and stays for ``work/moe_experts.py``, which reads it."""
     d, dh = cfg["hidden_size"], cfg["head_dim"]
     heads, kv_heads = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     m, em, vocab = (cfg["intermediate_size"], cfg["moe_intermediate_size"],
@@ -49,8 +48,7 @@ def shapes(cfg: Mapping) -> dict:
               + n_sparse * (outside + held * expert))
     active = (2 * vocab * d + d + n_dense * dense
               + n_sparse * (outside + k * held / experts * expert))
-    return {"layers": len(kinds), "hidden": heads * dh, "width": d,
-            "heads": heads,
+    return {"layers": len(kinds), "hidden": d, "width": d, "heads": heads,
             "kv_heads": kv_heads, "head_dim": dh, "mlp": m, "vocab": vocab,
             "positions": cfg["max_position_embeddings"],
             "params": params, "active_params": int(active),

@@ -2,6 +2,11 @@
 by-name resolution of every cell, the plain references against the program,
 and tiny in-process rehearsals of both kinds for both families.
 
+What is read of ``BENCHMARK.json`` is read twice: as accepted, and with one
+cell appended as the next PR that brings a configuration will leave it
+(appended.py; the ``bench`` fixture and ``CELL_CASES``). No test here may pin
+the end of a list, a list's length or the absence of a neighbour.
+
 One module on purpose (tests/conftest.py runs every module in a child
 process). It starts no subprocess, describes no TPU topology
 (tests/unit/ops/test_tpu_compile.py stays the only file that does) and
@@ -18,8 +23,9 @@ import numpy as np
 import pytest
 
 from benchmarks import flops, harness, stats, trace_reduce, traffic_gen
+from tests.unit.benchmarks import appended
 
-BENCH = harness.benchmark_json()
+BENCH = appended.ACCEPTED
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 CELLS = [w["name"] for w in BENCH["workloads"]]
@@ -210,6 +216,74 @@ def test_flops_count_key_value_heads_and_active_parameters():
         6 * (7_241_732_096 // 4) + 12 * 32 * 4096 * 4096)
 
 
+def test_attention_is_counted_from_heads_and_head_size_not_from_hidden():
+    """The contract of ``shapes()``: ``hidden`` is the residual stream's
+    width and ``flops.py`` computes nothing from it; a family whose value
+    heads or cache rows are of another size states ``v_head_dim`` and
+    ``cache_row_dim``. By hand, for shapes no configuration here has: 16
+    heads of 192 for queries and keys, values of 128, one cache row of 576
+    a token shared by all heads, on a stream of 1024."""
+    s = dict(LARGE, hidden=1024, heads=16, kv_heads=1, head_dim=192)
+    for hidden in (1024, 16 * 192, 7):
+        assert flops.train_flops_per_token(dict(s, hidden=hidden), 512) == \
+            6 * s["active_params"] + 12 * 36 * 16 * 192 * 512
+    f, b = flops.decode_attn_work(dict(s, hidden=7), context_lens=[100, 300])
+    assert (f, b) == (36 * 400 * 16 * 4 * 192, 36 * 400 * 2 * 192 * 2)
+    wide = dict(s, v_head_dim=128, cache_row_dim=576)
+    assert flops.train_flops_per_token(wide, 512) == \
+        6 * s["active_params"] + 6 * 36 * 16 * (192 + 128) * 512
+    f, b = flops.decode_attn_work(wide, context_lens=[100, 300])
+    assert (f, b) == (36 * 400 * 16 * 2 * (192 + 128), 36 * 400 * 576 * 2)
+
+
+# A fixed window, and what every function of operations and bytes returned
+# for it on the commit before ``hidden`` stopped meaning attention's width
+# (8bcba6a, ``python3`` on the files as they were): the four configurations'
+# numbers do not move with the contract.
+FIXED_OBS = {
+    "trace_span": [10.0, 11.0],
+    "counters": {"serving/decode_steps": 300,
+                 "serving/moe_experts_touched": 2250,
+                 "serving/moe_assignments_held": 3600,
+                 "serving/moe_assignments": 28800},
+    "spans": [{"name": "decode_step", "start": 9.99, "end": 10.01},
+              {"name": "decode_step", "start": 10.2, "end": 10.21},
+              {"name": "decode_step", "start": 10.9, "end": 11.1}],
+    "requests": [{"prompt_len": 1000, "admitted": 10.5,
+                  "token_times": [10.1, 10.2, 10.6, 11.2]},
+                 {"prompt_len": 400, "admitted": 9.0,
+                  "token_times": [9.5, 9.9, 10.9]}],
+    "train": {"rows_per_device_step": 16, "seq_len": 1024,
+              "traced_steps": 2}}
+ON_THE_PARENT = {
+    "gpt2-large": {"train_flops_per_token": 5210411520.0,
+                   "decode_attn": (443289600.0, 443289600.0),
+                   "flash_train": (9277129359360.0, 36238786560)},
+    "gpt2-xl": {"train_flops_per_token": 10289385600.0,
+                "decode_attn": (738816000.0, 738816000.0),
+                "flash_train": (15461882265600.0, 60397977600)},
+    "granite-4.0-h-micro": {"train_flops_per_token": 20155009536.0,
+                            "decode_attn": (788070400.0, 197017600.0),
+                            "flash_train": (16492674416640.0, 40265318400),
+                            "ssm_update": (283115520.0, 452984832.0)},
+    "k-exaone-236b-a23b": {"train_flops_per_token": 9185942016.0,
+                           "decode_attn": (394035200.0, 49254400.0),
+                           "flash_train": (8246337208320.0, 18119393280),
+                           "moe_experts": (303801827328.0, 5964300288.0)}}
+
+
+@pytest.mark.parametrize("config,what", [
+    (c, w) for c, row in ON_THE_PARENT.items() for w in row])
+def test_work_and_flops_return_what_they_returned_on_the_parent(config, what):
+    cfg = harness.load_json("configs", config + ".json")
+    shapes = harness.module("families", cfg["family"]).shapes(cfg)
+    if what == "train_flops_per_token":
+        got = flops.train_flops_per_token(shapes, 1024)
+    else:
+        got = harness.module("work", what).work(dict(FIXED_OBS, shapes=shapes))
+    assert got == ON_THE_PARENT[config][what]
+
+
 def test_roofline_and_peaks_table():
     peaks = harness.load_json("peaks.json")
     v5e = peaks["devices"]["TPU v5 lite"]
@@ -380,41 +454,98 @@ def test_reducer_on_a_real_profile(tmp_path):
 
 
 # ------------------------------------------------ names, files, resolution
-def test_every_name_and_unit_holds_only_the_allowed_characters():
-    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+def test_every_name_and_unit_holds_only_the_allowed_characters(bench):
+    assert set(bench) == {"command", "paths", "run_seconds", "configs",
                           "workloads", "end_to_end", "per_layer"}
-    names = [m["name"] for g in ("end_to_end", "per_layer") for m in BENCH[g]]
+    names = [m["name"] for g in ("end_to_end", "per_layer") for m in bench[g]]
     assert len(names) == len(set(names))
-    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+    for g in ("configs", "workloads"):
+        assert len({e["name"] for e in bench[g]}) == len(bench[g])
+    for m in bench["end_to_end"] + bench["per_layer"]:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
         assert m["better"] in ("lower", "higher")
         assert m["source"] in ("device_trace", "program_span",
                                "program_counter", "host_clock")
-    for m in BENCH["end_to_end"]:
+    for m in bench["end_to_end"]:
         assert set(m) <= {"name", "unit", "better", "bound", "source",
                           "workloads"}
         assert m["source"] in ("host_clock", "device_trace")
         assert 0.01 <= m["bound"] <= 0.1
-    for w in BENCH["workloads"]:
+    for w in bench["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         assert NAME.match(w["name"]) and NAME.match(w["traffic"])
         assert w["chips"] in (1, 4) and 0 < len(w["why"]) <= 200
-    for c in BENCH["configs"]:
+    for c in bench["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert NAME.match(c["name"]) and len(c["why"]) <= 200
-        assert c["file"].startswith(tuple(p + "/" for p in BENCH["paths"]))
-    assert any(m["name"] == "setup_s" for m in BENCH["end_to_end"])
-    assert 1 <= BENCH["run_seconds"] <= 51
-    four = sum(w["chips"] == 4 for w in BENCH["workloads"])
-    assert four <= max(1, len(BENCH["workloads"]) // 4)
-    assert len(json.dumps(BENCH)) < 64 * 1024
+        assert c["file"].startswith(tuple(p + "/" for p in bench["paths"]))
+    assert any(m["name"] == "setup_s" for m in bench["end_to_end"])
+    assert 1 <= bench["run_seconds"] <= 51
+    assert 1 <= len(bench["workloads"]) <= 24 >= len(bench["configs"]) >= 1
+    four = sum(w["chips"] == 4 for w in bench["workloads"])
+    assert four <= max(1, len(bench["workloads"]) // 4)
+    assert len(json.dumps(bench)) < 64 * 1024
 
 
-@pytest.mark.parametrize("cell_name", CELLS)
-def test_every_cell_resolves_its_files_by_name(cell_name):
-    cell = harness.load_cell(cell_name, BENCH)
+def test_the_appended_benchmark_appends_and_edits_nothing():
+    """The fixture's second benchmark is the accepted one with entries
+    appended: every list of the first is a prefix of the second's, and the
+    twin reports what its donor reports."""
+    one, two = BENCH, appended.BENCHES["one-appended"]
+    twin = appended.APPENDED_CELL
+    assert twin not in CELLS
+    for g, name in (("configs", appended.APPENDED_CONFIG),
+                    ("workloads", twin)):
+        assert two[g][:len(one[g])] == one[g]
+        assert [e["name"] for e in two[g][len(one[g]):]] == [name]
+    for g in ("end_to_end", "per_layer"):
+        assert [m["name"] for m in two[g]] == [m["name"] for m in one[g]]
+        for a, b in zip(one[g], two[g]):
+            was = a.get("workloads", [])
+            assert b == dict(a, workloads=was + [twin] * (
+                appended.DONOR in was)) or (b == a and "workloads" not in a)
+        assert [m["name"] for m in harness.metrics_of(twin, g, two)] == \
+            [m["name"] for m in harness.metrics_of(appended.DONOR, g, two)]
+    for key in ("command", "paths", "run_seconds"):
+        assert two[key] == one[key]
+    assert harness.load_cell(twin, two)["config_file"] == \
+        harness.load_cell(appended.DONOR, one)["config_file"]
+
+
+def _longest(spec):
+    """The longest length a spec of ``traffic_gen.draw_lengths`` can draw, by
+    its ``dist``: a ``choice`` or ``fixed`` spec needs no ``max`` beside its
+    values, and is clipped by one it has."""
+    if spec["dist"] == "choice":
+        top = max(spec["values"])
+    elif spec["dist"] == "fixed":
+        top = spec["value"]
+    else:                       # lognormal is clipped at max, uniform ends there
+        top = spec["max"]
+    return min(top, spec.get("max", top))
+
+
+@pytest.mark.parametrize("spec,want", [
+    ({"dist": "lognormal", "median": 192, "sigma": 0.7, "min": 16,
+      "max": 768}, 768),
+    ({"dist": "uniform", "min": 32, "max": 256}, 256),
+    ({"dist": "choice", "values": [96, 3584, 640]}, 3584),
+    ({"dist": "choice", "values": [96, 3584, 640], "max": 1024}, 1024),
+    ({"dist": "fixed", "value": 384}, 384),
+], ids=["lognormal", "uniform", "choice", "choice-clipped", "fixed"])
+def test_the_longest_length_of_a_spec_follows_its_dist(spec, want):
+    assert _longest(spec) == want
+    drawn = traffic_gen.draw_lengths(np.random.RandomState(0), spec, 2000)
+    assert drawn.max() <= want
+    if spec["dist"] != "lognormal":     # its clip is reached by few draws
+        assert drawn.max() == want
+
+
+@pytest.mark.parametrize("bench,cell_name", appended.CELL_CASES)
+def test_every_cell_resolves_its_files_by_name(bench, cell_name):
+    cell = harness.load_cell(cell_name, bench)
     cfg, traffic = cell["config_file"], cell["traffic_file"]
-    entry = next(c for c in BENCH["configs"] if c["name"] == cell["config"])
+    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     assert cfg["source"] == entry["source"]
     assert sorted(cfg["reduced"]) == sorted(entry["reduced"])
     assert hasattr(harness.module("kinds", traffic["kind"]), "run")
@@ -422,16 +553,17 @@ def test_every_cell_resolves_its_files_by_name(cell_name):
     reference = harness.module("reference", cfg["family"])
     assert callable(reference.forward_logits) and callable(reference.loss)
     shapes = family.shapes(cfg)
-    assert set(shapes) >= set(LARGE)        # what a family owes the harness
-    assert shapes["head_dim"] * shapes["heads"] == shapes["hidden"]
+    # what a family owes the harness. ``hidden`` is the stream's width;
+    # attention's is heads x head_dim and need not equal it
+    assert set(shapes) >= set(LARGE)
     assert shapes["heads"] % shapes["kv_heads"] == 0
     assert 0 < shapes["active_params"] <= shapes["params"]
     check = traffic["check"]
     assert 0.0 <= check["logit_tol"] and len(check["why"]) > 80
     e2e = {m["name"] for m in harness.metrics_of(cell_name, "end_to_end",
-                                                 BENCH)}
+                                                 bench)}
     assert "setup_s" in e2e and len(e2e) >= 2
-    layer = harness.metrics_of(cell_name, "per_layer", BENCH)
+    layer = harness.metrics_of(cell_name, "per_layer", bench)
     assert layer
     for m in layer:
         spec = harness.load_json("layer_metrics", m["name"] + ".json")
@@ -449,7 +581,8 @@ def test_every_cell_resolves_its_files_by_name(cell_name):
         assert 0.0 < check["mean_gap_tol"] < check["logit_tol"]
         a, s = traffic["arrivals"], traffic["server"]
         assert a["max_total"] <= s["max_len"] <= shapes["positions"]
-        longest = a["prompt"]["max"] + a.get("shared_prefix", {}).get("len", 0)
+        longest = (_longest(a["prompt"])
+                   + a.get("shared_prefix", {}).get("len", 0))
         assert longest <= max(s["buckets"])
 
 
@@ -654,15 +787,17 @@ def test_llama_reference_agrees_with_the_program_at_tiny_size(kv_heads):
     assert float(got_loss) == pytest.approx(float(want_loss), abs=1e-4)
 
 
-def test_a_run_without_a_tpu_raises_and_prints_no_result(capsys, monkeypatch):
+def test_a_run_without_a_tpu_raises_and_prints_no_result(capsys, monkeypatch,
+                                                          bench):
     from benchmarks import run as bench_run
 
     # main() would point this process's compile cache at benchmarks/.cache,
     # and later test modules run in the same worker
     monkeypatch.setattr(harness, "setup_compile_cache", lambda: "unused")
+    monkeypatch.setattr(harness, "benchmark_json", lambda: bench)
     with pytest.raises(harness.NoAcceleratorError, match="no TPU"):
         harness.device_guard(1)
-    for cell in CELLS:
+    for cell in [w["name"] for w in bench["workloads"]]:
         with pytest.raises(harness.NoAcceleratorError):
             bench_run.main(["--workload", cell, "--seed", "1",
                             "--seconds", "1", "--trace", "0"])
@@ -734,10 +869,11 @@ def test_rehearsal_in_process_at_tiny_size(kind, family, monkeypatch):
     assert set(line) >= {"correct", "attempted", "failed", "metrics", "device"}
     assert line["device"]["window_s"] > 0
     # no device plane on this backend: the trace readers leave their
-    # metrics out of the line and the program's own counters remain
-    assert not [m for m in line["metrics"] if m.startswith(("kernel.",
-                                                            "device."))]
-    assert any(m.startswith("entry.") for m in line["metrics"])
+    # metrics out of the line and the program's own counters remain; a
+    # device metric is told by its source, whatever its name
+    sources = {m["name"]: m["source"] for m in BENCH["per_layer"]}
+    assert not [m for m in line["metrics"] if sources[m] == "device_trace"]
+    assert [m for m in line["metrics"] if sources[m] == "program_counter"]
     line0 = bench_run.result_line(cell, BENCH, out, trace=False)
     assert set(line0["metrics"]) == e2e
     json.dumps(line), json.dumps(line0)
@@ -817,6 +953,13 @@ def test_the_control_moves_logits_more_than_the_stated_precision(family_name,
         assert np.abs(np.asarray(w - q)).max() <= scale.max() * 0.5001
         column = np.asarray(q).reshape(-1, *q.shape[-2:])[0, :, 0]
         assert len(np.unique(column)) <= 255
+    # rounded where it lies, a leaf at a time (what ``main`` does, so that one
+    # chip holds a configuration that fills half of it): the tree that the
+    # whole tree rounded in one compiled call gives, as ``main`` did before
+    for q, r in zip(jax.tree_util.tree_leaves(jax.jit(control.int8_weights)(
+            params)), jax.tree_util.tree_leaves(control.int8_weights_in_place(
+                jax.tree_util.tree_map(jnp.copy, params)))):
+        assert (q == r).all()
     ids = jnp.asarray(np.random.RandomState(1).randint(
         0, cfg["vocab_size"], (1, 96)))
     bf16 = jax.tree_util.tree_map(

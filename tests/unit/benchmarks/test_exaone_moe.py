@@ -2,7 +2,10 @@
 against the published keys and its stated cut, its sizes against the hand
 count, the work of its expert matmuls against a hand-worked window, its metric
 files through their readers, and a tiny in-process rehearsal of its cell
-(``rehearse=True``: no device guard, never a result).
+(``rehearse=True``: no device guard, never a result). What it reads of
+``BENCHMARK.json`` it reads through the ``bench`` fixture, as accepted and with
+a cell appended behind this family's (appended.py), and it speaks of its own
+cell only: that the cell is listed, never that it is last or alone.
 
 One module (tests/conftest.py runs every module in a child process); it starts
 no subprocess and describes no TPU topology.
@@ -45,7 +48,7 @@ CUT = {"num_hidden_layers": 5, "num_experts": 16, "vocab_size": 19200,
        "mlp_layer_types": ["dense"] + ["sparse"] * 4}
 
 
-def test_the_configuration_file_holds_the_published_keys():
+def test_the_configuration_file_holds_the_published_keys(bench):
     for key, value in PUBLISHED.items():
         assert key in CFG, key
         if key not in CUT:
@@ -66,7 +69,7 @@ def test_the_configuration_file_holds_the_published_keys():
     for needle in ("48 -> 5", "128 -> 16", "153600 -> 19200", "1 -> 0",
                    "262144 -> 4096", "No width is cut"):
         assert needle in CFG["reduced_why"], needle
-    entry = next(c for c in BENCH["configs"]
+    entry = next(c for c in bench["configs"]
                  if c["name"] == "k-exaone-236b-a23b")
     assert entry["source"] == CFG["source"] and \
         entry["reduced"] == CFG["reduced"] and len(entry["why"]) <= 200
@@ -125,10 +128,13 @@ def test_shapes_against_the_hand_count():
             s["expert_mlp"], s["window"], s["sliding_layers"],
             s["global_layers"], s["sparse_layers"]) == \
         (128, 16, 8, 2048, 128, 4, 1, 4)
-    # "hidden" is the harness's name for attention's width, 64 x 128
+    # "hidden" is the residual stream's width; attention is 64 x 128 = 8192
+    # wide on it, which the family states in heads and head_dim alone
     assert (s["layers"], s["width"], s["hidden"], s["heads"], s["kv_heads"],
             s["head_dim"], s["mlp"], s["vocab"], s["positions"]) == \
-        (5, 6144, 8192, 64, 8, 128, 18432, 19200, 4096)
+        (5, 6144, 6144, 64, 8, 128, 18432, 19200, 4096)
+    assert s["heads"] * s["head_dim"] == 8192 != s["hidden"]
+    assert "v_head_dim" not in s and "cache_row_dim" not in s
     model = FAMILY.build_model(CFG, {})
     assert model.num_params() == s["params"]
     assert model.config.held == (0, 16) and model.config.num_experts == 128
@@ -137,11 +143,10 @@ def test_shapes_against_the_hand_count():
     assert row * (4096 + 4 * 128) * 32 == 603_979_776
 
 
-def test_the_cell_is_one_chip_and_lists_what_it_reports():
-    cell = next(w for w in BENCH["workloads"] if w["name"] == CELL)
+def test_the_cell_is_one_chip_and_lists_what_it_reports(bench):
+    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert cell["chips"] == 1 and len(cell["why"]) <= 200
-    assert BENCH["workloads"][-1]["name"] == CELL      # appended
-    mix = harness.load_cell(CELL, BENCH)["traffic_file"]
+    mix = harness.load_cell(CELL, bench)["traffic_file"]
     assert mix["kind"] == "serve_open_loop"
     assert mix["server"] == {"dtype": "bf16", "num_slots": 32,
                              "max_len": 4096,
@@ -151,10 +156,10 @@ def test_the_cell_is_one_chip_and_lists_what_it_reports():
     assert arr["prompt"]["values"] == [96, 160, 224, 320, 448, 640, 896,
                                        1280, 1920, 3584]
     assert arr["max_total"] == 4096 and arr.get("burst_size", 1) == 1
-    e2e = {m["name"] for m in harness.metrics_of(CELL, "end_to_end", BENCH)}
+    e2e = {m["name"] for m in harness.metrics_of(CELL, "end_to_end", bench)}
     assert e2e == {"serve_tokens_per_s", "ttft_p95_ms", "itl_p95_ms",
                    "setup_s"}
-    layer = {m["name"] for m in harness.metrics_of(CELL, "per_layer", BENCH)}
+    layer = {m["name"] for m in harness.metrics_of(CELL, "per_layer", bench)}
     assert {"kernel.moe_experts_roofline", "kernel.moe_experts_share",
             "moe.expert_live_share", "cache.window_live_share",
             "kernel.decode_attn_share", "kernel.decode_attn_live_share",
@@ -164,21 +169,22 @@ def test_the_cell_is_one_chip_and_lists_what_it_reports():
     assert "kernel.decode_attn_roofline" not in layer
     for name in ("kernel.moe_experts_roofline", "kernel.moe_experts_share",
                  "moe.expert_live_share", "cache.window_live_share"):
-        m = next(m for m in BENCH["per_layer"] if m["name"] == name)
+        m = next(m for m in bench["per_layer"] if m["name"] == name)
         spec = harness.load_json("layer_metrics", name + ".json")
-        assert m["workloads"] == [CELL] and m["moves"] == spec["moves"]
+        # listed; which other family's cell shares the metric is theirs
+        assert CELL in m["workloads"] and m["moves"] == spec["moves"]
         assert (m["unit"], m["better"], m["source"], m["layer"]) == \
             (spec["unit"], spec["better"], spec["source"], spec["layer"])
 
 
-def test_the_schedule_replays_long_prompts():
+def test_the_schedule_replays_long_prompts(bench):
     """At least two of the first 16 requests, the ones the check replays,
     carry a prompt past 1,280 tokens (ten windows), whatever the seed: a
     sliding layer that attends past its window, or a global layer that
     rotates, then fails ``correct``."""
     from benchmarks import traffic_gen
 
-    arr = harness.load_cell(CELL, BENCH)["traffic_file"]["arrivals"]
+    arr = harness.load_cell(CELL, bench)["traffic_file"]["arrivals"]
     for seed in (1, 2**31 + 5):
         planned = traffic_gen.open_loop_requests(arr, seed=seed, seconds=51,
                                                  vocab_size=19200)
@@ -264,13 +270,19 @@ def test_the_new_metric_files_through_their_readers():
     assert read("kernel.moe_experts_share", bare) is None
 
 
-def test_rehearsal_in_process_at_tiny_size():
+@pytest.fixture(scope="module")
+def rehearsed():
     """The serving kind's runner end to end at the family's tiny sizes,
-    traced, under the new cell's own mix."""
+    traced, under the cell's own mix: the cell and what the run returned."""
     cell = harness.load_cell(CELL, BENCH)
     out = harness.module("kinds", "serve_open_loop").run(
         cell, seed=2**31 + 11, seconds=0.6, trace=True,
         clock0=time.perf_counter(), rehearse=True)
+    return cell, out
+
+
+def test_rehearsal_in_process_at_tiny_size(rehearsed):
+    _, out = rehearsed
     assert out["device"]["platform"] == "cpu"
     assert out["correct"] and out["failed"] == 0 and out["attempted"] > 0
     counters = out["observations"]["counters"]
@@ -282,14 +294,21 @@ def test_rehearsal_in_process_at_tiny_size():
         3 * 2 * counters["serving/decode_steps"]
     assert 0 < counters["serving/moe_assignments_held"] < \
         counters["serving/moe_assignments"]
-    line = bench_run.result_line(cell, BENCH, out, trace=True)
+
+
+def test_the_rehearsal_prints_the_cells_metrics(rehearsed, bench):
+    """The result lines of that run, whatever else ``BENCHMARK.json`` lists
+    behind this cell."""
+    cell, out = rehearsed
+    line = bench_run.result_line(cell, bench, out, trace=True)
     assert 0 < line["metrics"]["moe.expert_live_share"]["value"] <= 100
     # no device plane on this backend, and a window of 8 is no ring the
     # fused step walks: the trace readers and the window's ratio leave
     # theirs out
-    assert not [m for m in line["metrics"] if m.startswith("kernel.")]
+    sources = {m["name"]: m["source"] for m in bench["per_layer"]}
+    assert not [m for m in line["metrics"] if sources[m] == "device_trace"]
     assert "cache.window_live_share" not in line["metrics"]
-    line0 = bench_run.result_line(cell, BENCH, out, trace=False)
+    line0 = bench_run.result_line(cell, bench, out, trace=False)
     assert set(line0["metrics"]) == {"serve_tokens_per_s", "ttft_p95_ms",
                                      "itl_p95_ms", "setup_s"}
     json.dumps(line), json.dumps(line0)

@@ -2,7 +2,9 @@
 file against the published keys, its sizes against the hand count, the work of
 its kernel against a hand-worked window, its metric files through their
 readers, and tiny in-process rehearsals of both kinds (``rehearse=True``: no
-device guard, never a result).
+device guard, never a result). What it reads of ``BENCHMARK.json`` it reads
+through the ``bench`` fixture, as accepted and with a cell appended
+(appended.py), and it speaks of its own cell only.
 
 One module (tests/conftest.py runs every module in a child process); it starts
 no subprocess and describes no TPU topology.
@@ -36,7 +38,7 @@ PUBLISHED = {
     "vocab_size": 100352}
 
 
-def test_the_configuration_file_holds_the_published_keys():
+def test_the_configuration_file_holds_the_published_keys(bench):
     for key, value in PUBLISHED.items():
         assert CFG[key] == value, key
     kinds = CFG["layer_types"]
@@ -47,7 +49,7 @@ def test_the_configuration_file_holds_the_published_keys():
     assert CFG["reduced"] == ["max_position_embeddings"]
     assert CFG["max_position_embeddings"] == 2048
     assert "131072" in CFG["reduced_why"] and "nope" in CFG["reduced_why"]
-    entry = next(c for c in BENCH["configs"]
+    entry = next(c for c in bench["configs"]
                  if c["name"] == "granite-4.0-h-micro")
     assert entry["source"] == CFG["source"] and \
         entry["reduced"] == CFG["reduced"] and len(entry["why"]) <= 200
@@ -75,13 +77,13 @@ def test_shapes_against_the_hand_count():
         == 76_437_504
 
 
-def test_the_cell_is_one_chip_and_lists_what_it_reports():
-    cell = next(w for w in BENCH["workloads"] if w["name"] == CELL)
+def test_the_cell_is_one_chip_and_lists_what_it_reports(bench):
+    cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert cell["chips"] == 1 and cell["traffic"] == "serve-chat-bursty"
-    e2e = {m["name"] for m in harness.metrics_of(CELL, "end_to_end", BENCH)}
+    e2e = {m["name"] for m in harness.metrics_of(CELL, "end_to_end", bench)}
     assert e2e == {"serve_tokens_per_s", "ttft_p95_ms", "itl_p95_ms",
                    "setup_s"}
-    layer = {m["name"] for m in harness.metrics_of(CELL, "per_layer", BENCH)}
+    layer = {m["name"] for m in harness.metrics_of(CELL, "per_layer", bench)}
     assert {"kernel.ssm_update_roofline", "kernel.ssm_update_share",
             "step.prefill_pad_share", "device.idle_share.serve",
             "sched.batch_fill", "step.decode_ms"} <= layer
@@ -153,21 +155,32 @@ def test_the_new_metric_files_through_their_readers():
         roof["params"], bare) is None
 
 
+@pytest.fixture(scope="module")
+def rehearsed():
+    """kind -> (cell, what the kind's runner returned) at the family's tiny
+    sizes, traced, run once a kind: the serving kind under the cell's own
+    mix, the training kind under the mix of the first training cell."""
+    done = {}
+
+    def run(kind):
+        if kind not in done:
+            if kind == "serve_open_loop":
+                cell = harness.load_cell(CELL, BENCH)
+            else:
+                name = next(w["name"] for w in BENCH["workloads"]
+                            if harness.load_cell(w["name"], BENCH)
+                            ["traffic_file"]["kind"] == kind)
+                cell = dict(harness.load_cell(name, BENCH), config_file=CFG)
+            done[kind] = cell, harness.module("kinds", kind).run(
+                cell, seed=2**31 + 11, seconds=0.6, trace=True,
+                clock0=time.perf_counter(), rehearse=True)
+        return done[kind]
+    return run
+
+
 @pytest.mark.parametrize("kind", ["serve_open_loop", "train_job"])
-def test_rehearsal_in_process_at_tiny_size(kind):
-    """The kind's runner end to end at the family's tiny sizes, traced: the
-    serving kind under the new cell's own mix, the training kind under the
-    mix of the first training cell."""
-    if kind == "serve_open_loop":
-        cell = harness.load_cell(CELL, BENCH)
-    else:
-        name = next(w["name"] for w in BENCH["workloads"]
-                    if harness.load_cell(w["name"], BENCH)
-                    ["traffic_file"]["kind"] == kind)
-        cell = dict(harness.load_cell(name, BENCH), config_file=CFG)
-    out = harness.module("kinds", kind).run(
-        cell, seed=2**31 + 11, seconds=0.6, trace=True,
-        clock0=time.perf_counter(), rehearse=True)
+def test_rehearsal_in_process_at_tiny_size(kind, rehearsed):
+    _, out = rehearsed(kind)
     assert out["device"]["platform"] == "cpu"
     assert out["correct"] and out["failed"] == 0 and out["attempted"] > 0
     assert out["observations"]["counters"]["compiles_in_window"] == 0
@@ -176,13 +189,20 @@ def test_rehearsal_in_process_at_tiny_size(kind):
         counters = out["observations"]["counters"]
         assert counters["serving/prefill_rows_run"] > \
             counters["serving/prefill_rows_padding"] > 0
-        line = bench_run.result_line(cell, BENCH, out, trace=True)
-        assert "step.prefill_pad_share" in line["metrics"]
-        # no device plane on this backend: the trace readers leave theirs out
-        assert not [m for m in line["metrics"] if m.startswith("kernel.")]
-        line0 = bench_run.result_line(cell, BENCH, out, trace=False)
-        assert set(line0["metrics"]) == {"serve_tokens_per_s", "ttft_p95_ms",
-                                         "itl_p95_ms", "setup_s"}
-        json.dumps(line), json.dumps(line0)
     else:
         assert out["notes"]["logit_max_abs_err"] < 1e-2
+
+
+def test_the_rehearsal_prints_the_cells_metrics(rehearsed, bench):
+    """The result lines of the serving run, whatever else ``BENCHMARK.json``
+    lists beside this cell."""
+    cell, out = rehearsed("serve_open_loop")
+    line = bench_run.result_line(cell, bench, out, trace=True)
+    assert "step.prefill_pad_share" in line["metrics"]
+    # no device plane on this backend: the trace readers leave theirs out
+    sources = {m["name"]: m["source"] for m in bench["per_layer"]}
+    assert not [m for m in line["metrics"] if sources[m] == "device_trace"]
+    line0 = bench_run.result_line(cell, bench, out, trace=False)
+    assert set(line0["metrics"]) == {"serve_tokens_per_s", "ttft_p95_ms",
+                                     "itl_p95_ms", "setup_s"}
+    json.dumps(line), json.dumps(line0)

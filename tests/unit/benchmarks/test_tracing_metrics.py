@@ -87,12 +87,8 @@ def test_span_self_time_counts_overlapping_and_straddling_children_once():
 # ----------------------------------------------------------- trace readers
 def _serving_trace():
     """One device, a 10 s window. Three decode iterations: the program
-    runs 1.0..1.8, 3.0..3.8 and 5.0..5.8 as a fusion and a kernel. The
-    launch annotation opens 0.3 s before the first program starts, while
-    the device is still busy for the second (a prefill runs over it), and
-    0.1 s before the third. The fetch annotation returns 0.2, 0.4 and 0.1 s
-    after the program's last operation ended. A fourth fetch lies outside
-    the window and a fifth saw no operation end inside it."""
+    runs 1.0..1.8, 3.0..3.8 and 5.0..5.8 as a fusion, the decode kernel and
+    a fusion that reads the kernel's result; a prefill runs 2.5..3.0."""
     kernel = ("%dstpu_decode_step.7 = (bf16[32,20,64]) custom-call(bf16[] "
               "%p), custom_call_target=\"tpu_custom_call\"")
     user = ("%fusion.9 = bf16[32,1,1280] fusion(bf16[32,20,64] "
@@ -102,37 +98,18 @@ def _serving_trace():
         ops += [("%fusion.1 = bf16[32,1,3840] fusion()", t, t + 0.2),
                 (kernel, t + 0.2, t + 0.7), (user, t + 0.7, t + 0.8)]
     ops.append(("%fusion.5 = bf16[1,256,1280] fusion()", 2.5, 3.0))
-    host = [("bench/window", 0.0, 10.0),
-            ("dstpu/serving_launch", 0.7, 0.8),
-            ("dstpu/serving_fetch", 0.8, 2.0),
-            ("dstpu/serving_launch", 2.8, 2.9),
-            ("dstpu/serving_fetch", 2.9, 4.2),
-            ("dstpu/serving_launch", 4.9, 4.95),
-            ("dstpu/serving_fetch", 4.95, 5.9),
-            ("dstpu/serving_fetch", 9.5, 10.5),
-            ("dstpu/serving_fetch", 7.0, 7.5),
-            ("dstpu/serving_admit", 6.0, 6.5)]
-    return trace_reduce.Trace({0: ops}, host, (0.0, 10.0))
+    return trace_reduce.Trace({0: ops}, [("bench/window", 0.0, 10.0)],
+                              (0.0, 10.0))
 
 
 def test_trace_readers_on_a_hand_made_trace():
     obs = {"trace": _serving_trace()}
-    # launches wait 0.3, 0 (device busy) and 0.1 s: the median is 0.1 s
-    assert _read("device.launch_latency_ms", obs) == pytest.approx(100.0)
-    # returns take 0.2, 0.4 and 0.1 s: the median is 0.2 s
-    assert _read("device.return_latency_ms", obs) == pytest.approx(200.0)
     # the kernel ran 3 x 0.5 s of 2.9 s busy; the fusion that names the
     # kernel as its operand is not the kernel
     assert _read("kernel.decode_attn_share", obs) == pytest.approx(
         100.0 * 1.5 / 2.9)
     assert _read("kernel.flash_share", obs) is None
-    reader = harness.module("readers", "trace_host_to_device")
-    with pytest.raises(ValueError):
-        reader.read({"annotation": "dstpu/serving_fetch", "edge": "middle"},
-                    obs)
-    # an annotation the program does not have (the parent commit): nothing
-    assert reader.read({"annotation": "dstpu/serving_nothing",
-                        "edge": "start"}, obs) is None
+    assert _read("device.idle_share.serve", obs) == pytest.approx(71.0)
 
 
 def test_flash_share_finds_the_kernels_under_any_wrapper():
@@ -151,8 +128,7 @@ def test_new_readers_return_nothing_without_observations():
     for metric in ("sched.schedule_host_ms", "sched.iter_schedule_p95_ms",
                    "step.prefill_chunk_ms", "step.upload_host_ms",
                    "step.launch_host_ms", "step.fetch_wait_ms",
-                   "step.commit_host_ms", "device.launch_latency_ms",
-                   "device.return_latency_ms", "kernel.decode_attn_share",
+                   "step.commit_host_ms", "kernel.decode_attn_share",
                    "kernel.flash_share"):
         assert _read(metric, {}) is None, metric
         assert _read(metric, {"spans": [], "trace": None}) is None, metric
@@ -162,8 +138,9 @@ def test_new_readers_return_nothing_without_observations():
 
 # ------------------------------------------------- what the readers lean on
 def _pallas_calls():
-    """Every ``pallas_call(...)`` of ``deepspeed_tpu/ops``: (file, n-th
-    call in it, the call's node). Parsed, never imported."""
+    """Every ``pallas_call(...)`` of ``deepspeed_tpu/ops``, called through a
+    module (``pl.pallas_call``) or by its bare name: (file, n-th call in it,
+    the call's node). Parsed, never imported."""
     found = []
     for fname in sorted(os.listdir(OPS_DIR)):
         if not fname.endswith(".py"):
@@ -172,8 +149,8 @@ def _pallas_calls():
             tree = ast.parse(f.read())
         calls = sorted((n for n in ast.walk(tree)
                         if isinstance(n, ast.Call)
-                        and isinstance(n.func, ast.Attribute)
-                        and n.func.attr == "pallas_call"),
+                        and getattr(n.func, "attr", getattr(n.func, "id", None))
+                        == "pallas_call"),
                        key=lambda n: n.lineno)
         found += [(fname, i, n) for i, n in enumerate(calls)]
     return found
@@ -194,16 +171,22 @@ def test_every_pallas_call_has_a_stable_name(fname, i, call):
 
 
 def test_pallas_names_are_distinct_and_cover_the_kernels_the_cells_run():
+    """A new Pallas call needs a ``name=`` of its own and nothing else here:
+    the count is a lower bound (nine through ``pl.`` and one by the bare
+    name when this was written), and each share metric's pattern is held to
+    its kernels among ALL the names, whatever is added."""
     names = [k.value.value for _, _, c in PALLAS_CALLS for k in c.keywords
              if k.arg == "name"]
-    assert len(names) == len(set(names)) == 9
+    assert len(names) == len(set(names)) == len(PALLAS_CALLS) >= 10
     assert {"dstpu_flash_fwd", "dstpu_flash_bwd_dq", "dstpu_flash_bwd_dkv",
             "dstpu_decode_step", "dstpu_block_decode_step"} <= set(names)
+    assert "dstpu_ssm_update" in names      # the call by its bare name
     # what each share metric's pattern finds among the names
     for metric, want in [
             ("kernel.flash_share", {"dstpu_flash_fwd", "dstpu_flash_bwd_dq",
                                     "dstpu_flash_bwd_dkv"}),
-            ("kernel.decode_attn_share", {"dstpu_decode_step"})]:
+            ("kernel.decode_attn_share", {"dstpu_decode_step"}),
+            ("kernel.ssm_update_share", {"dstpu_ssm_update"})]:
         rx = re.compile(harness.load_json(
             "layer_metrics", metric + ".json")["params"]["pattern"])
         assert {n for n in names if rx.search(f"%{n}.3 = x")} == want
@@ -271,25 +254,66 @@ def test_scopes_of_the_fused_train_step_change_no_operation(monkeypatch):
     assert "dstpu_fwd_bwd" in scoped and "dstpu_accumulate" in scoped
 
 
-def test_serving_programs_carry_their_scopes():
-    import jax
-    import jax.numpy as jnp
+def _served_configs():
+    """One configuration a family among the cells a ``ServingEngine``
+    serves, by ``BENCHMARK.json``: a family that a later cell brings is
+    lowered here too."""
+    bench, seen = harness.benchmark_json(), {}
+    for w in bench["workloads"]:
+        cell = harness.load_cell(w["name"], bench)
+        if cell["traffic_file"]["kind"] == "serve_open_loop":
+            seen.setdefault(cell["config_file"]["family"], w["config"])
+    return sorted(seen.items())
 
+
+SERVED = _served_configs()
+
+
+class _Recorded:
+    """A jitted program that notes each call: itself and its operands, as
+    shapes (a donated operand is gone after the call)."""
+
+    def __init__(self, program, calls):
+        self.program, self.calls = program, calls
+
+    def __call__(self, *args):
+        import jax
+
+        self.calls.append((self.program, jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(np.shape(x), np.result_type(x)),
+            args)))
+        return self.program(*args)
+
+    def __getattr__(self, name):        # ``_cache_size`` and the like
+        return getattr(self.program, name)
+
+
+@pytest.mark.parametrize("family_name,config", SERVED,
+                         ids=[f for f, _ in SERVED])
+def test_serving_programs_carry_their_scopes(family_name, config, monkeypatch):
+    """The decode and prefill programs lowered with the operands
+    ``ServingEngine`` itself passes them at warm-up, whatever those are (the
+    model's state tree leaf by leaf today): the scopes the trace readers
+    lean on are in the lowered text. The test fixes no signature."""
     import deepspeed_tpu
-    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
+    from deepspeed_tpu.serving import ServingEngine
     from deepspeed_tpu.utils import groups
 
+    family = harness.module("families", family_name)
+    cfg = family.tiny(harness.load_json("configs", config + ".json"))
     groups.reset()
-    eng = deepspeed_tpu.init_inference(GPT2Model(GPT2Config.tiny()),
-                                       dtype="fp32", max_out_tokens=32)
-    cache = eng.module.init_cache(2, 32, dtype=jnp.float32)
-    lengths = jnp.zeros((2,), jnp.int32)
-    key = jax.random.PRNGKey(0)
-    decode = eng.slot_decode_program(2, 32).lower(
-        eng.params, cache["k"], cache["v"], lengths,
-        jnp.zeros((2,), jnp.int32), jnp.ones((2,), bool), 1.0, key)
-    assert "dstpu_decode" in decode.as_text(debug_info=True)
-    prefill = eng.slot_prefill_program(16, 2, 32).lower(
-        eng.params, cache["k"], cache["v"], lengths,
-        jnp.zeros((1, 16), jnp.int32), np.int32(0), np.int32(5), 1.0, key)
-    assert "dstpu_prefill" in prefill.as_text(debug_info=True)
+    eng = deepspeed_tpu.init_inference(family.build_model(cfg, {}),
+                                       dtype="fp32", max_out_tokens=64)
+    calls = {"slot_decode_program": [], "slot_prefill_program": []}
+    for method, noted in calls.items():
+        monkeypatch.setattr(
+            eng, method, lambda *a, _real=getattr(eng, method), _noted=noted,
+            **kw: _Recorded(_real(*a, **kw), _noted))
+    ServingEngine(eng, num_slots=4, max_len=64, buckets=(16, 32),
+                  tenants=False).warmup()
+    for method, scope in [("slot_decode_program", "dstpu_decode"),
+                          ("slot_prefill_program", "dstpu_prefill")]:
+        assert calls[method], method
+        program, operands = calls[method][0]
+        text = program.lower(*operands).as_text(debug_info=True)
+        assert scope in text, (family_name, method)
