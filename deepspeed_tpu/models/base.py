@@ -170,6 +170,36 @@ def qdot(eq, x, w):
     return jnp.einsum(eq, x, w.astype(x.dtype))
 
 
+def project_heads(x, w, heads: int, head_dim: int):
+    """``x [B, T, D]`` through the projection ``w [D, heads * head_dim]``
+    (a ``qdot`` weight) -> ``[B, T, heads, head_dim]``, for a block that
+    works per head on the result (a norm over ``head_dim``, a rotation, an
+    attention kernel's operands).
+
+    The result is fenced before it is split into heads. Unfenced, the TPU
+    compiler fuses the matmul with the per-head consumer, that fusion asks
+    for the weight with its contracted dimension minor, and a layer's slice
+    of the stacked weight (:func:`layer_view`) can no longer be read where
+    it lies: it is written out and transposed every step, HBM to HBM
+    (K-EXAONE's ``wq``: 100 MB twice a decode step, a quarter of the step
+    with its siblings; PERF.md, PR 41). The fence costs one pass over the
+    activations, 0.5 MB at decode. Held by
+    ``tests/unit/ops/test_tpu_compile.py::test_decode_step_copies_no_weight``."""
+    b, t, _ = x.shape
+    out = jax.lax.optimization_barrier(qdot("btd,de->bte", x, w))
+    return out.reshape(b, t, heads, head_dim)
+
+
+def merge_heads(x, w):
+    """:func:`project_heads`' inverse for an output projection: ``x [B, T,
+    heads, head_dim]`` through ``w [heads * head_dim, D]`` -> ``[B, T, D]``,
+    fenced between the merge of the heads and the matmul for the same
+    reason."""
+    b, t, heads, head_dim = x.shape
+    flat = jax.lax.optimization_barrier(x.reshape(b, t, heads * head_dim))
+    return qdot("bte,ed->btd", flat, w)
+
+
 def embed_tokens(wte, input_ids, dtype):
     """Token-embedding gather whose table may be weight-only-int8
     ``{"__q__", "__scale__"}`` with PER-VOCAB-ROW scales (the tied
@@ -232,12 +262,19 @@ def recurrent_state_keys(keys) -> tuple:
 def layer_view(blocks, i):
     """Per-layer view of a layer-stacked block tree for a scan body that
     indexes with its own counter: normal ``[L, ...]`` leaves are
-    dynamic-indexed (XLA fuses the slice into the consuming einsum), but
-    weight-quantized ``{"__q__", "__scale__"}`` dicts stay WHOLE with the
-    layer recorded as ``__layer__`` — qdot's int8 kernel DMA-slices the
-    layer in-kernel, because a host-side slice of an int8 custom-call
-    operand materializes a full per-step copy of the weight (measured as
-    the '66% of streaming bound' int8 serving ceiling at 6.7B). A model
+    dynamic-indexed. XLA fuses the slice into the consuming einsum *unless
+    that einsum has fused with a per-head consumer* (a reshape to heads and
+    a norm or rotation over the head dimension): the fusion then wants the
+    weight transposed and the slice is written out, a copy of the layer's
+    weight every step. A block with such a consumer projects through
+    :func:`project_heads` / :func:`merge_heads`, which keep the two apart
+    (``tests/unit/ops/test_tpu_compile.py::test_decode_step_copies_no_weight``
+    reads the compiled step for such copies). Weight-quantized ``{"__q__",
+    "__scale__"}`` dicts stay WHOLE with the layer recorded as ``__layer__``
+    — qdot's int8 kernel DMA-slices the layer in-kernel, because a
+    host-side slice of an int8 custom-call operand materializes a full
+    per-step copy of the weight (measured as the '66% of streaming bound'
+    int8 serving ceiling at 6.7B). A model
     keeps any other leaf whole the same way by handing the walk ``{"__whole__":
     leaf}`` in its place (:func:`whole_leaves`): an expert stack, which a
     grouped matmul addresses by group and need not slice (moe/grouped.py)."""

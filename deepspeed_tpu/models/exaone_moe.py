@@ -28,7 +28,8 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import cache_positions, cross_entropy_loss, gathered_top, qdot, rms_norm, whole_leaves
+from deepspeed_tpu.models.base import (cache_positions, cross_entropy_loss, gathered_top, merge_heads,
+                                       project_heads, qdot, rms_norm, whole_leaves)
 from deepspeed_tpu.models.stack import cached_walk, kv_cache, next_cache, walk, wrapped_block
 from deepspeed_tpu.moe.grouped import held_experts, sigmoid_topk_route
 from deepspeed_tpu.ops.attention import cached_attention, multihead_attention, window_cached_attention
@@ -280,11 +281,10 @@ class ExaoneMoeModel:
         b, t, _ = x.shape
         hq, hkv, dh = c.num_heads, c.num_kv_heads, c.head_dim
         y = rms_norm(x, blk["attn_norm"], c.eps)
-        q = qdot("btd,de->bte", y, blk["wq"]).reshape(b, t, hq, dh)
-        k_ = qdot("btd,de->bte", y, blk["wk"]).reshape(b, t, hkv, dh)
-        v_ = qdot("btd,de->bte", y, blk["wv"]).reshape(b, t, hkv, dh)
-        q = rms_norm(q, blk["q_norm"], c.eps)
-        k_ = rms_norm(k_, blk["k_norm"], c.eps)
+        q = rms_norm(project_heads(y, blk["wq"], hq, dh), blk["q_norm"], c.eps)
+        k_ = rms_norm(project_heads(y, blk["wk"], hkv, dh), blk["k_norm"],
+                      c.eps)
+        v_ = project_heads(y, blk["wv"], hkv, dh)
         if attn == SLIDING:
             pos = cache_positions(0 if idx is None else idx, t)
             q = apply_rotary_half(q, pos, c.rope_theta)
@@ -309,7 +309,7 @@ class ExaoneMoeModel:
             else:
                 out, kc, vc = cached_attention(q, kc, vc, k_, v_, at, idx,
                                                active=walk_)
-        x = x + qdot("bte,ed->btd", out.reshape(b, t, hq * dh), blk["wo"])
+        x = x + merge_heads(out, blk["wo"])
         z = rms_norm(x, blk["mlp_norm"], c.eps)
         y, n = self._ffn(z, blk, ffn, tokens)
         return x + y, (None if state is None else (kc, vc, counts + n))

@@ -23,7 +23,8 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import cross_entropy_loss, gathered_top, qdot, rms_norm, tied_logits
+from deepspeed_tpu.models.base import (cross_entropy_loss, gathered_top, merge_heads, project_heads, qdot, rms_norm,
+                                       tied_logits)
 from deepspeed_tpu.models.stack import cached_walk, kv_cache, next_cache, walk, wrapped_block
 from deepspeed_tpu.ops.attention import cached_attention, multihead_attention
 from deepspeed_tpu.ops.ssm import causal_conv, ssd_prefill, ssm_update
@@ -308,9 +309,9 @@ class GraniteHybridModel:
         b, t, _ = x.shape
         hq, hkv, dh = c.num_heads, c.num_kv_heads, c.head_dim
         y = rms_norm(x, blk["norm"], c.eps)
-        q = qdot("btd,de->bte", y, blk["wq"]).reshape(b, t, hq, dh)
-        k_ = qdot("btd,de->bte", y, blk["wk"]).reshape(b, t, hkv, dh)
-        v_ = qdot("btd,de->bte", y, blk["wv"]).reshape(b, t, hkv, dh)
+        q = project_heads(y, blk["wq"], hq, dh)
+        k_ = project_heads(y, blk["wk"], hkv, dh)
+        v_ = project_heads(y, blk["wv"], hkv, dh)
         if cache is None:
             rep = hq // hkv
             attn = multihead_attention(
@@ -321,8 +322,7 @@ class GraniteHybridModel:
                                             scale=c.attention_multiplier,
                                             active=active)
             cache = (kc, vc)
-        x = x + c.residual_multiplier * qdot(
-            "bte,ed->btd", attn.reshape(b, t, hq * dh), blk["wo"])
+        x = x + c.residual_multiplier * merge_heads(attn, blk["wo"])
         return self._mlp(x, blk), cache
 
     # -------------------------------------------------------------- forward

@@ -366,7 +366,11 @@ _LATE_MODULES = _OBSERVABILITY_MODULES + (
     "unit/benchmarks/test_exaone_moe",
     # PR 36: three tiny families' serving programs in one module (about
     # 100 s of compiles), kept away from that rendezvous too
-    "unit/serving/test_overlapped_decode",)
+    "unit/serving/test_overlapped_decode",
+    # PR 41: whole decode steps compiled for the described v5e at the
+    # cells' widths (8 to 16 s each, on every core): in directory order
+    # unit/ops follows unit/model_parallelism
+    "unit/ops/test_tpu_compile",)
 
 # Dead-last group, AFTER even the torch modules: pure-AST, device-free
 # suites (the dstpu-lint/prove analysis tests never launch a collective,

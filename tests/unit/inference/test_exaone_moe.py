@@ -132,9 +132,23 @@ def test_a_padded_prompt_leaves_the_rings_of_the_unpadded_one(built, length,
     assert int(bare["step_counters"][3]) == 3 * 4 * length  # layers x k x T
 
 
+# what the server served for the five requests of the test below before the
+# attention projections were fenced from the per-head work behind them
+# (models/base.project_heads, merge_heads: an ordering, no arithmetic); the
+# reference's best logit leads its second by 8.7e-3 at least along them
+SERVED_BEFORE_THE_FENCE = [
+    [318, 299, 107, 10, 32, 372],
+    [242, 357, 401, 372, 454, 377, 172, 187, 475, 190, 118, 272],
+    [497, 170, 273, 455, 344, 478, 189, 161, 106],
+    [140, 50, 198, 489, 116, 190, 118, 272, 50, 198, 489, 116, 190, 118, 272,
+     50, 198, 489, 116, 190],
+    [219, 348, 49, 57, 357]]
+
+
 def test_the_serving_engine_serves_it_over_two_sizes_of_state(built):
     """init_inference + ServingEngine: bucketed slot prefill, per-slot
-    decode, slots reused; every served token is the reference's argmax."""
+    decode, slots reused; every served token is the reference's argmax, and
+    the one served before the fence."""
     import deepspeed_tpu
     from deepspeed_tpu.serving import Request, ServingEngine
     from deepspeed_tpu.telemetry.registry import MetricsRegistry
@@ -157,6 +171,8 @@ def test_the_serving_engine_serves_it_over_two_sizes_of_state(built):
                                         (27, 5)])]
     results = srv.run(reqs)
     assert len(results) == 5
+    assert [list(r.tokens) for r in sorted(results, key=lambda r: r.rid)] \
+        == SERVED_BEFORE_THE_FENCE
     with jax.default_matmul_precision("highest"):
         for r in results:
             prompt = reqs[r.rid].prompt
@@ -178,6 +194,46 @@ def test_the_serving_engine_serves_it_over_two_sizes_of_state(built):
     assert 0 < c["serving/moe_assignments_held"] < \
         c["serving/moe_assignments"] / 3
     groups.reset()
+
+
+# (query heads, key-value heads, head size): multi-head, rep 4, rep 8
+HEAD_SHAPES = [(4, 4, 32), (8, 2, 32), (8, 1, 16)]
+
+
+@pytest.mark.parametrize("hq,hkv,dh", HEAD_SHAPES,
+                         ids=["multi-head", "rep-4", "rep-8"])
+def test_project_and_merge_heads_are_the_einsum_and_the_reshape(hq, hkv, dh):
+    """The helpers alone, values and gradients: the fence passes both (the
+    training walk runs the same block)."""
+    from deepspeed_tpu.models.base import merge_heads, project_heads
+
+    b, t, d = 2, 5, 48
+    keys = jax.random.split(jax.random.PRNGKey(hq + hkv), 4)
+    x = jax.random.normal(keys[0], (b, t, d))
+    wq, wk, wo = (jax.random.normal(k, shape) * 0.1 for k, shape in zip(
+        keys[1:], [(d, hq * dh), (d, hkv * dh), (hq * dh, d)]))
+
+    def helped(x, wq, wk, wo):
+        q = project_heads(x, wq, hq, dh)
+        k = jnp.repeat(project_heads(x, wk, hkv, dh), hq // hkv, axis=2)
+        return merge_heads(q * jnp.tanh(k), wo)
+
+    def plain(x, wq, wk, wo):
+        q = jnp.einsum("btd,de->bte", x, wq).reshape(b, t, hq, dh)
+        k = jnp.repeat(jnp.einsum("btd,de->bte", x, wk).reshape(
+            b, t, hkv, dh), hq // hkv, axis=2)
+        return jnp.einsum("bte,ed->btd",
+                          (q * jnp.tanh(k)).reshape(b, t, hq * dh), wo)
+
+    assert project_heads(x, wq, hq, dh).shape == (b, t, hq, dh)
+    np.testing.assert_array_equal(jax.jit(helped)(x, wq, wk, wo),
+                                  jax.jit(plain)(x, wq, wk, wo))
+    loss = lambda f: lambda *a: jnp.sum(jnp.sin(f(*a)))
+    got = jax.jit(jax.grad(loss(helped), argnums=(0, 1, 2, 3)))(x, wq, wk, wo)
+    want = jax.jit(jax.grad(loss(plain), argnums=(0, 1, 2, 3)))(x, wq, wk, wo)
+    for g, w in zip(got, want):
+        assert float(jnp.abs(w).max()) > 1e-3
+        np.testing.assert_allclose(g, w, **TOL)
 
 
 @pytest.mark.parametrize("option", [dict(prefix_cache=True),

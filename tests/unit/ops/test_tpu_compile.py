@@ -10,7 +10,8 @@ This is the ONLY file that describes a topology, and it does so inside a
 fixture: only the process that is given this file may load the TPU library
 (see /opt/skills/guides/on-chip-measurement, section 2). The kernel cases
 assert ``tpu_custom_call`` in the compiled text, i.e. the kernel is really
-there; the last case reads the text for an uninitialised buffer.
+there; one case reads the text for an uninitialised buffer, and the serve
+cells' whole decode steps are read for copies of a layer's weights.
 """
 
 import functools
@@ -229,16 +230,22 @@ def test_flash_decode_gqa(one_chip):
 
 
 @pytest.fixture
-def mesh_2x2(topo, monkeypatch):
+def fused_routes(monkeypatch):
+    """The program picks its fused routes (``sp_attention``'s interpret mode
+    and strict vma checking, the models' fused decode step) from
+    ``jax.default_backend()``, which still says cpu here, so the test steers
+    it (the guide's rule: steer in the test, not through an option of the
+    program)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+@pytest.fixture
+def mesh_2x2(topo, fused_routes):
     """data=2 x model=2 over the four described chips, installed as the
-    initialised topology. ``sp_attention`` picks interpret mode and strict
-    vma checking from ``jax.default_backend()``, which still says cpu here,
-    so the test steers it (the guide's rule: steer in the test, not through
-    an option of the program)."""
+    initialised topology."""
     from deepspeed_tpu.parallel.topology import build_topology
     from deepspeed_tpu.utils import groups
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     topology = build_topology(tp=2, devices=list(topo.devices))
     groups.initialize(topology)
     yield topology.mesh
@@ -287,6 +294,185 @@ def test_in_program_kv_cache_is_zero_filled(one_chip):
     uninitialised = [ln for ln in text.splitlines()
                      if "AllocateBuffer" in ln and "bf16[2,1,12,128,64]" in ln]
     assert not uninitialised, uninitialised[0][:200]
+
+
+def _outside_fusions(text):
+    """``(computation, result type, opcode, line)`` of every
+    instruction of the compiled text that sits outside fused computations:
+    the entry, the loops' bodies and what else is called as it stands; and
+    the fused computations' bodies by name."""
+    bodies, name = {}, None
+    for line in re.sub(r"/\*.*?\*/", "", text).splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif line == "}":
+            name = None
+        elif name is not None:
+            bodies[name].append(line)
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", text))
+    found = []
+    for comp, lines in bodies.items():
+        if comp in fused:
+            continue
+        for line in lines:
+            m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.*)", line)
+            if not m:
+                continue
+            rest = m.group(1)
+            if rest.startswith("("):        # a tuple: to its closing bracket
+                depth = 0
+                for end, ch in enumerate(rest):
+                    depth += (ch == "(") - (ch == ")")
+                    if depth == 0:
+                        break
+                kind, rest = rest[:end + 1], rest[end + 1:].lstrip()
+            else:
+                kind, rest = rest.split(" ", 1)
+            found.append((comp, kind, rest.split("(", 1)[0], line))
+    return found, bodies
+
+
+def _sizes(dims):
+    """A shape as its dimensions above 1, sorted: what a layer's slice of a
+    stacked leaf keeps through ``[1, d, e]``, a squeeze, a transposing
+    bitcast."""
+    return tuple(sorted(int(d) for d in dims if d and int(d) > 1))
+
+
+def _weight_sized_copies(text, leaves):
+    """Synchronous operations outside fused computations that write a
+    result of a weight leaf's size to HBM and are no matmul: a ``copy``,
+    ``slice``, ``dynamic-slice``, or a ``fusion`` with no ``convolution`` /
+    ``dot`` in it. ``leaves``: ``{(dtype, _sizes of one layer)}``. A result
+    in the compiler's fast memory (``S(1)`` in its layout) passes, and so do
+    the asynchronous prefetches (``copy-start`` / ``-done``, ``slice-start``
+    / ``-done``): they run beside the matmuls. -> the offending lines."""
+    found, bodies = _outside_fusions(text)
+    out = []
+    for comp, kind, opcode, line in found:
+        if opcode not in ("copy", "slice", "dynamic-slice", "fusion"):
+            continue
+        if opcode == "fusion":
+            body = "\n".join(bodies[re.search(r"calls=%([\w.\-]+)",
+                                              line).group(1)])
+            if " convolution(" in body or " dot(" in body:
+                continue
+        for dtype, dims, layout in re.findall(
+                r"(\w+)\[([\d,]*)\](\{[^}]*\})?", kind):
+            if (dtype, _sizes(dims.split(","))) in leaves \
+                    and "S(1)" not in layout:
+                out.append(f"{comp}: {line.strip()[:240]}")
+                break
+    return out
+
+
+def _exaone_cell():
+    from deepspeed_tpu.models.exaone_moe import (DENSE, GLOBAL, SLIDING,
+                                                 SPARSE, ExaoneMoeConfig,
+                                                 ExaoneMoeModel)
+
+    # the cell's five layers: runs of one (dense), two, one (global), one
+    return ExaoneMoeModel(ExaoneMoeConfig(
+        vocab_size=19200, max_seq_len=4096, held=(0, 16),
+        layer_types=(SLIDING, SLIDING, SLIDING, GLOBAL, SLIDING),
+        mlp_layer_types=(DENSE,) + (SPARSE,) * 4)), 32, 4096
+
+
+def _granite_cell():
+    from deepspeed_tpu.models.granite_hybrid import (ATTENTION, MAMBA,
+                                                     GraniteHybridConfig,
+                                                     GraniteHybridModel)
+
+    # of 40 layers a Mamba run of two and two attention layers, each a run
+    # of one as all four of the cell's are
+    return GraniteHybridModel(GraniteHybridConfig(
+        max_seq_len=2048,
+        layer_types=(ATTENTION, MAMBA, MAMBA, ATTENTION))), 64, 2048
+
+
+def _gpt2_large_cell():
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
+
+    # of 36 layers four: one loop, as the cell's
+    return GPT2Model(GPT2Config(num_layers=4, hidden_size=1280,
+                                num_heads=20)), 32, 1024
+
+
+def _weights(model, sharding):
+    """The model's parameters as the serving engine holds them: bf16, as
+    shapes on the described chip; and one layer's sizes of every stacked
+    matrix (projections, MLPs, a layer's experts)."""
+    params = jax.tree_util.tree_map(
+        lambda s: _sds(sharding, s.shape),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    leaves = {("bf16", _sizes(s.shape[1:]))
+              for s in jax.tree_util.tree_leaves(params) if len(s.shape) >= 3}
+    return params, leaves
+
+
+def _assert_copies_no_weight(compiled, leaves):
+    copies = _weight_sized_copies(compiled.as_text(), leaves)
+    assert not copies, "\n".join(copies)
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
+
+
+@pytest.mark.parametrize("cell", [_exaone_cell, _granite_cell,
+                                  _gpt2_large_cell],
+                         ids=["k-exaone", "granite-4.0-h-micro",
+                              "gpt2-large"])
+def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
+    """The serve cells' decode step (``InferenceEngine.slot_decode_program``'s
+    call of the model: one token a slot, per-slot lengths, the slot walk) at
+    the cells' widths and slots, depth cut for compile time with a loop run
+    and a one-layer run each kept: every weight reaches its matmul where it
+    lies. K-EXAONE's block once fused its projections with the per-head norm
+    behind them, and a layer's ``wq``, ``wk``, ``wv`` were sliced out of the
+    stack and transposed, ``wo`` copied, every step: 232 MB of temporaries,
+    ``constant_dynamic-slice_fusion`` on the chip's trace, 0.87 ms of a step
+    of 7.4 (PERF.md, PR 41; ``models/base.project_heads``)."""
+    from deepspeed_tpu.ops.decode_step import slot_walk
+
+    model, slots, max_len = cell()
+    params, leaves = _weights(model, one_chip)
+    state = jax.eval_shape(
+        lambda: model.init_cache(slots, max_len, dtype=BF16))
+    del state["index"]
+    state = jax.tree_util.tree_map(
+        lambda s: _sds(one_chip, s.shape, s.dtype), state)
+
+    def step(params, state, lengths, tokens, active):
+        cache = dict(state, index=lengths, valid_len=active.astype(jnp.int32),
+                     slot_walk=slot_walk(lengths, active))
+        logits, cache = model.forward_with_cache(params, tokens[:, None],
+                                                 cache)
+        return logits[:, -1], {k: cache[k] for k in state}
+
+    per_slot = _sds(one_chip, (slots,), jnp.int32)
+    compiled = jax.jit(step, donate_argnums=1).lower(
+        params, state, per_slot, per_slot,
+        _sds(one_chip, (slots,), jnp.bool_)).compile()
+    _assert_copies_no_weight(compiled, leaves)
+
+
+def test_prefill_copies_no_weight(one_chip, fused_routes):
+    """K-EXAONE's bucket-256 prefill (``slot_prefill_program``'s call of the
+    model: one row, a cache of its own, the true length) walks the same
+    block: no weight is copied there either."""
+    model, _, _ = _exaone_cell()
+    params, leaves = _weights(model, one_chip)
+
+    def prefill(params, ids, length):
+        cache = model.init_cache(1, 256, dtype=BF16)
+        cache["valid_len"] = length
+        logits, cache = model.forward_with_cache(params, ids, cache)
+        return logits, cache
+
+    compiled = jax.jit(prefill).lower(
+        params, _sds(one_chip, (1, 256), jnp.int32),
+        _sds(one_chip, (), jnp.int32)).compile()
+    _assert_copies_no_weight(compiled, leaves)
 
 
 @pytest.fixture
