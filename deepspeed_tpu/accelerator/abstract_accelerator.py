@@ -143,11 +143,11 @@ class DeepSpeedAccelerator(abc.ABC):
         return None
 
     def peak_hbm_gbps(self) -> Optional[float]:
-        """Peak HBM bandwidth per chip in GB/s — the memory roof of the
-        per-program roofline attribution (telemetry/attribution.py).
-        Concrete accelerators consult their device-kind table;
-        ``DSTPU_PEAK_HBM_GBPS`` overrides everywhere. None = unknown,
-        and attainable-vs-achieved is simply not reported."""
+        """Peak HBM bandwidth per chip in GB/s: the memory roof a roofline
+        is judged against (``chip_smoke.py`` reports it beside
+        ``peak_tflops()`` and refuses a chip with no entry). Concrete
+        accelerators consult their device-kind table;
+        ``DSTPU_PEAK_HBM_GBPS`` overrides everywhere. None = unknown."""
         import os
 
         env = os.environ.get("DSTPU_PEAK_HBM_GBPS")

@@ -31,9 +31,9 @@ TPU_PEAK_TFLOPS = {
     "v6e": 918.0,
 }
 
-# HBM bandwidth per CHIP in GB/s, same published specs + lookup rules —
-# the memory roof of the per-program roofline attribution
-# (telemetry/attribution.py); DSTPU_PEAK_HBM_GBPS overrides.
+# HBM bandwidth per CHIP in GB/s, same published specs + lookup rules:
+# the memory roof beside the compute roof above (chip_smoke.py reports
+# both); DSTPU_PEAK_HBM_GBPS overrides.
 TPU_PEAK_HBM_GBPS = {
     "v2": 700.0,
     "v3": 900.0,

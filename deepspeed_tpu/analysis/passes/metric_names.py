@@ -23,7 +23,7 @@ from typing import Dict, List
 from deepspeed_tpu.analysis.core import (Corpus, Finding, LintPass,
                                          register)
 
-PREFIXES = ("train", "serving", "fabric", "resilience", "device",
+PREFIXES = ("train", "serving", "fabric", "resilience", "device", "entry",
             "checkpoint", "elastic", "slo", "telemetry")
 _NAME_RE = re.compile(
     r"^(?:%s)/[A-Za-z0-9_][A-Za-z0-9_/<>*-]*$" % "|".join(PREFIXES))

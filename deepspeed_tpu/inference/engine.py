@@ -31,8 +31,19 @@ from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
 from deepspeed_tpu.models.base import recurrent_state_keys, slot_state_keys
 from deepspeed_tpu.ops.attention import extract_slot_row, insert_slot_row
 from deepspeed_tpu.runtime.zero.partition import PartitionPlan
+from deepspeed_tpu.telemetry.compile_log import SetupPhase, compile_log
 from deepspeed_tpu.utils import groups as groups_mod
 from deepspeed_tpu.utils.logging import log_dist, logger
+
+
+def _named_jit(name: str, fn, **jit_kw):
+    """``jax.jit(fn)`` under ``name``: the name the program has in
+    ``ServingEngine.program_cache_sizes()``, which JAX then gives its
+    trace, lowering and compile events (telemetry/compile_log.py), the
+    compiled module and the profile. ``fn`` is renamed: pass a closure of
+    the builder's own, never a function other code imports."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **jit_kw)
 
 
 def filter_logits(logits, *, top_k: int = 0, top_p: float = 1.0):
@@ -66,6 +77,9 @@ class InferenceEngine:
         if not isinstance(config, DeepSpeedInferenceConfig):
             config = DeepSpeedInferenceConfig(**(config or {}))
         self._config = config
+        # listening before the first program of this engine is traced: a
+        # registry that subscribes later is brought up to these too
+        compile_log()
         self.dtype = config.jax_dtype()
         # int8 = weight-only quantization (reference GroupQuantizer path,
         # module_inject/replace_module.py:140): HBM holds int8 weights +
@@ -118,8 +132,6 @@ class InferenceEngine:
         self.logical_axes = model.logical_axes() if hasattr(model, "logical_axes") else None
 
         # ---- parameters: explicit > checkpoint > fresh init
-        if params is None and config.checkpoint is not None:
-            params = self._load_checkpoint_params(config.checkpoint)
         if self.weight_quant and not getattr(self.module,
                                              "supports_weight_quant", False):
             # an explicit int8 request that cannot be honored must fail
@@ -130,6 +142,12 @@ class InferenceEngine:
                 f"{type(self.module).__name__} does not support dequant "
                 "blocks (models must route weight matmuls through models/base.qdot in "
                 "their block scan and set supports_weight_quant = True)")
+        # set-up's weights phase, closed at a fence on the tree it made. No
+        # registry here: the reading stays on the engine, and a
+        # ServingEngine publishes it into the registry it was given
+        self.setup_weights = SetupPhase("weights")
+        if params is None and config.checkpoint is not None:
+            params = self._load_checkpoint_params(config.checkpoint)
         if (params is None and self.weight_quant
                 and config.tp_size == 1 and config.ep_size == 1):
             # stream-init: each quantizable block leaf is initialized AND
@@ -164,6 +182,7 @@ class InferenceEngine:
                          ranks=[0])
                 self.params = self._maybe_quantize_embedding(self.params)
 
+        self.setup_weights.close(fence=self.params)
         self._compiled: Dict[Tuple, Any] = {}
         self._gen_rng = jax.random.PRNGKey(config.seed)
         log_dist(
@@ -499,7 +518,7 @@ class InferenceEngine:
                 rng, sub = jax.random.split(rng)
                 return pick(logits[:, -1], temp, sub), cache, rng
 
-            self._compiled[pf_key] = jax.jit(prefill)
+            self._compiled[pf_key] = _named_jit("generate_prefill", prefill)
         prefill_fn = self._compiled[pf_key]
 
         if eos_token_id is None:
@@ -520,7 +539,8 @@ class InferenceEngine:
 
                 # donate the cache: the decode loop must not double-buffer
                 # the [L,B,H,S,Dh] KV tensors at 7B scale
-                self._compiled[dec_key] = jax.jit(decode, donate_argnums=(2,))
+                self._compiled[dec_key] = _named_jit(
+                    "generate_decode", decode, donate_argnums=(2,))
             decode_fn = self._compiled[dec_key]
 
             def gen(params, ids, temp, rng):
@@ -571,7 +591,8 @@ class InferenceEngine:
                     cond, body, (0, tok, cache, rng, done, prev_done, buf))
                 return buf.T
 
-            self._compiled[dec_key] = jax.jit(decode_eos, donate_argnums=(2,))
+            self._compiled[dec_key] = _named_jit(
+                "generate_decode_eos", decode_eos, donate_argnums=(2,))
         decode_eos_fn = self._compiled[dec_key]
 
         def gen(params, ids, temp, rng):
@@ -674,8 +695,9 @@ class InferenceEngine:
                     (*(state[n] for n in names), lengths),
                     pick(last, temp, rng)[0], slot, behind)
 
-            self._compiled[key] = jax.jit(
-                prefill, donate_argnums=self._carry_donation(len(names)))
+            self._compiled[key] = _named_jit(
+                f"prefill_{bucket_len}", prefill,
+                donate_argnums=self._carry_donation(len(names)))
         return self._compiled[key]
 
     def slot_decode_program(self, num_slots: int, max_len: int, *,
@@ -747,8 +769,9 @@ class InferenceEngine:
                     out += (cache["step_counters"],)
                 return out
 
-            self._compiled[key] = jax.jit(
-                decode, donate_argnums=self._carry_donation(len(names)))
+            self._compiled[key] = _named_jit(
+                "decode", decode,
+                donate_argnums=self._carry_donation(len(names)))
         return self._compiled[key]
 
     def slot_verify_program(self, num_slots: int, max_len: int, k: int, *,
@@ -800,7 +823,8 @@ class InferenceEngine:
                         n_emit)
 
             donate = (1, 2, 3) if jax.default_backend() == "tpu" else ()
-            self._compiled[key] = jax.jit(verify, donate_argnums=donate)
+            self._compiled[key] = _named_jit(f"verify_{k}", verify,
+                                             donate_argnums=donate)
         return self._compiled[key]
 
     def slot_draft_program(self, window_len: int, num_slots: int, k: int):
@@ -850,7 +874,7 @@ class InferenceEngine:
                     idx = idx + 1
                 return jnp.stack(out, axis=1)
 
-            self._compiled[key] = jax.jit(draft)
+            self._compiled[key] = _named_jit(f"draft_{k}", draft)
         return self._compiled[key]
 
     # --------------------------------------------- block-paged programs
@@ -899,7 +923,8 @@ class InferenceEngine:
                         pick(last, temp, rng)[0])
 
             donate = (1, 2, 3) if jax.default_backend() == "tpu" else ()
-            self._compiled[key] = jax.jit(prefill, donate_argnums=donate)
+            self._compiled[key] = _named_jit(f"prefill_{bucket_len}", prefill,
+                                             donate_argnums=donate)
         return self._compiled[key]
 
     def block_decode_program(self, num_slots: int, max_blocks: int, *,
@@ -938,7 +963,8 @@ class InferenceEngine:
                 return cache["k"], cache["v"], lengths, nxt
 
             donate = (1, 2, 3) if jax.default_backend() == "tpu" else ()
-            self._compiled[key] = jax.jit(decode, donate_argnums=donate)
+            self._compiled[key] = _named_jit("decode", decode,
+                                             donate_argnums=donate)
         return self._compiled[key]
 
     def block_verify_program(self, num_slots: int, max_blocks: int, k: int,
@@ -982,7 +1008,8 @@ class InferenceEngine:
                         n_emit)
 
             donate = (1, 2, 3) if jax.default_backend() == "tpu" else ()
-            self._compiled[key] = jax.jit(verify, donate_argnums=donate)
+            self._compiled[key] = _named_jit(f"verify_{k}", verify,
+                                             donate_argnums=donate)
         return self._compiled[key]
 
     def block_copy_program(self, num_blocks: int, block_size: int, *,
@@ -1013,7 +1040,8 @@ class InferenceEngine:
                 return copy_one(k_pool), copy_one(v_pool)
 
             donate = (0, 1) if jax.default_backend() == "tpu" else ()
-            self._compiled[key] = jax.jit(copy, donate_argnums=donate)
+            self._compiled[key] = _named_jit("block_copy", copy,
+                                             donate_argnums=donate)
         return self._compiled[key]
 
     # ----------------------------------------- SLO-aware serving programs
@@ -1085,8 +1113,9 @@ class InferenceEngine:
                     (*(state[n] for n in names), lengths),
                     pick(last, temp, rng)[0], slot, behind)
 
-            self._compiled[key] = jax.jit(
-                chunk, donate_argnums=self._carry_donation(len(names)))
+            self._compiled[key] = _named_jit(
+                f"chunk_prefill_{bucket_len}", chunk,
+                donate_argnums=self._carry_donation(len(names)))
         return self._compiled[key]
 
     def slot_swap_out_program(self, num_slots: int, max_len: int):
@@ -1101,8 +1130,8 @@ class InferenceEngine:
 
         key = ("slot_swap_out", num_slots, max_len)
         if key not in self._compiled:
-            self._compiled[key] = jax.jit(
-                lambda k, v, slot: extract_slot_kv(k, v, slot))
+            self._compiled[key] = _named_jit(
+                "swap_out", lambda k, v, slot: extract_slot_kv(k, v, slot))
         return self._compiled[key]
 
     def slot_swap_in_program(self, num_slots: int, max_len: int):
@@ -1127,7 +1156,8 @@ class InferenceEngine:
                 return k_slots, v_slots, lengths
 
             donate = (0, 1, 4) if jax.default_backend() == "tpu" else ()
-            self._compiled[key] = jax.jit(swap_in, donate_argnums=donate)
+            self._compiled[key] = _named_jit("swap_in", swap_in,
+                                             donate_argnums=donate)
         return self._compiled[key]
 
     def block_swap_out_program(self, num_blocks: int, max_blocks: int, *,
@@ -1143,8 +1173,8 @@ class InferenceEngine:
 
         key = ("blk_swap_out", num_blocks, max_blocks, kv_dtype)
         if key not in self._compiled:
-            self._compiled[key] = jax.jit(
-                lambda k, v, table: gather_pool_blocks(k, v, table))
+            self._compiled[key] = _named_jit(
+                "swap_out", lambda k, v, table: gather_pool_blocks(k, v, table))
         return self._compiled[key]
 
     def block_swap_in_program(self, num_blocks: int, max_blocks: int, *,
@@ -1172,7 +1202,8 @@ class InferenceEngine:
                 return k_pool, v_pool, lengths
 
             donate = (0, 1, 5) if jax.default_backend() == "tpu" else ()
-            self._compiled[key] = jax.jit(swap_in, donate_argnums=donate)
+            self._compiled[key] = _named_jit("swap_in", swap_in,
+                                             donate_argnums=donate)
         return self._compiled[key]
 
     # ------------------------------------------------------------- utilities

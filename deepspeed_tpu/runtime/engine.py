@@ -27,6 +27,7 @@ from __future__ import annotations
 import dataclasses
 import os
 import time
+import weakref
 from typing import Any, Callable, Dict, Iterator, NamedTuple, Optional, Tuple, Union
 
 import jax
@@ -48,6 +49,8 @@ from deepspeed_tpu.runtime.precision import (
     has_inf_or_nan,
 )
 from deepspeed_tpu.runtime.zero.partition import PartitionPlan, stating_param_use
+from deepspeed_tpu.telemetry.compile_log import (SetupPhase, at_work,
+                                                 compile_log)
 from deepspeed_tpu.utils import groups as groups_mod
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (
@@ -68,6 +71,12 @@ class TrainState(NamedTuple):
 
 
 class DeepSpeedEngine:
+    # set by ``at_work`` for the length of the constructor and of every
+    # step entry point: the compile events that arrive then are this
+    # engine's (telemetry/compile_log.py)
+    _at_work = False
+
+    @at_work
     def __init__(self, model, config: Union[DeepSpeedConfig, dict, str], *,
                  optimizer=None, lr_scheduler=None, training_data=None,
                  collate_fn=None, topology=None, init_rng=None, dont_change_device=False):
@@ -177,7 +186,13 @@ class DeepSpeedEngine:
 
         # ---- state init (zero.Init analog: params born sharded on device)
         self._init_rng = init_rng if init_rng is not None else jax.random.PRNGKey(config.seed)
+        # set-up's weights phase (parameters, master copy, optimizer state),
+        # closed at a fence on the state it made; published where the
+        # telemetry registry is made, further down
+        compile_log()     # listening before the first program is traced
+        setup_weights = SetupPhase("weights")
         self.state = self._init_state()
+        setup_weights.close(fence=self.state)
         self._dropout_rng = jax.random.fold_in(self._init_rng, 0x5eed)
 
         # ---- debug/safe mode (SURVEY §5.2: the functional design makes
@@ -269,6 +284,8 @@ class DeepSpeedEngine:
 
         # ---- compiled steps
         self._compiled_train_step = None
+        # open from the fused step's build until its first result is fenced
+        self._setup_first_step: Optional[SetupPhase] = None
         self._compiled_micro_grad = None
         self._compiled_apply_grads = None
         self._compiled_eval = None
@@ -300,7 +317,7 @@ class DeepSpeedEngine:
         tcfg = config.telemetry_config
         self.telemetry = None
         self._telemetry_flops: Optional[float] = None  # None=unprobed, 0=n/a
-        self._telemetry_bytes: Optional[float] = None  # cost_analysis bytes
+        self._compile_sub = None
         self._fence_t: Optional[float] = None
         self._fence_step = 0
         self._fence_tokens = 0
@@ -334,6 +351,22 @@ class DeepSpeedEngine:
                     span_sink = self.telemetry.sink  # interleave, if any
                 self.tracer = _tele.SpanTracer(sink=span_sink)
                 self._train_trace = self.tracer.new_trace()
+            # what this process has traced, lowered, compiled and loaded so
+            # far, and what it will while this engine is at work: entry/*
+            log = compile_log()
+            engine = weakref.ref(self)
+
+            def follows():
+                # this engine's own: the event arrives inside its
+                # constructor or one of its step entry points
+                live = engine()
+                return live is not None and live._at_work
+
+            self._compile_sub = log.subscribe(self.telemetry,
+                                              follows=follows)
+            weakref.finalize(self, log.unsubscribe, self._compile_sub)
+            setup_weights.publish(self.telemetry, self.tracer,
+                                  trace_id=self._train_trace)
         # ---- flight recorder + SLO seam (ISSUE 13): the recorder tees
         # the telemetry/span streams into bounded rings and dumps one
         # postmortem JSON when the sentinel hits an actionable anomaly;
@@ -806,7 +839,10 @@ class DeepSpeedEngine:
             # ends in an allgather reconstruction identical on every device)
             # — vma typing cannot prove that statically
             check_vma=False)
-        self._compiled_train_step = jax.jit(sharded, donate_argnums=(0,))
+        def train_step(state, batch, lr, rng):   # the fused step's one name
+            return sharded(state, batch, lr, rng)
+
+        self._compiled_train_step = jax.jit(train_step, donate_argnums=(0,))
         return self._compiled_train_step
 
     def _gas_batch_shardings(self, batch):
@@ -857,6 +893,7 @@ class DeepSpeedEngine:
         self._engine_owned_stream = False  # caller owns the data stream
         return self._run_fused_step(batch)
 
+    @at_work
     def _run_fused_step(self, batch):
         h = getattr(self, "_preemption_handler", None)
         if h is not None:
@@ -864,6 +901,8 @@ class DeepSpeedEngine:
         if self._host_opt is not None:
             return self._run_host_step(batch)
         if self._compiled_train_step is None:
+            if self.telemetry is not None:
+                self._setup_first_step = SetupPhase("first_step")
             self._build_train_step(batch)
         self.tput_timer.start()
         self.timers(TRAIN_BATCH_TIMER).start()
@@ -1072,6 +1111,20 @@ class DeepSpeedEngine:
         if interval and (self.global_steps % interval == 0
                          or self.global_steps == 1):
             self._telemetry_fence(metrics, batch, ltd_keep)
+        elif self._setup_first_step is not None:
+            # this step brings no fence of the engine's own (sync_interval
+            # is 0, or a resumed run built its step off the interval, where
+            # the next fence lies whole steps away): the reading ends here,
+            # at the dispatch, as an unfenced prefill_chunk's does
+            self._close_first_step(fenced=False)
+
+    def _close_first_step(self, fenced: bool) -> None:
+        """``entry/setup_first_step_ms``: the fused step's build (trace,
+        lowering, compile or cache load) and its first run, closed at the
+        first telemetry fence behind it."""
+        phase, self._setup_first_step = self._setup_first_step, None
+        phase.close().publish(self.telemetry, self.tracer,
+                              trace_id=self._train_trace, fenced=fenced)
 
     def _reset_telemetry_window(self):
         """Invalidate the fence-to-fence device-rate baseline. Called
@@ -1093,6 +1146,8 @@ class DeepSpeedEngine:
         reg = self.telemetry
         # dstpu-lint: fence=THE periodic telemetry fence (sync_interval): device-truth metrics
         jax.block_until_ready(self.state.params)
+        if self._setup_first_step is not None:
+            self._close_first_step(fenced=True)
         now = time.perf_counter()
         steps = self.global_steps - self._fence_step
         if self._fence_t is not None and steps > 0:
@@ -1191,11 +1246,6 @@ class DeepSpeedEngine:
                 # agree. Replicated compute makes this a slight
                 # overcount — acceptable for an MFU estimate.
                 flops *= jax.device_count()
-                # bytes accessed ride the same probe — the memory axis
-                # of the train step's roofline row (ISSUE 11)
-                self._telemetry_bytes = float(
-                    (ca or {}).get("bytes accessed", 0.0)
-                    or 0.0) * jax.device_count()
             except Exception as e:
                 logger.warning("telemetry: cost_analysis of the train step "
                                "failed (%s: %s); using analytic flops",
@@ -1208,47 +1258,6 @@ class DeepSpeedEngine:
                 flops = 6.0 * n_params * tokens
         self._telemetry_flops = flops
         return flops or None
-
-    def train_step_attribution(self) -> dict:
-        """Roofline row for the fused train step (ISSUE 11): XLA
-        cost-analysis flops/bytes (probed at the telemetry fence; the
-        analytic-flops fallback leaves the memory axis empty) joined
-        with the fence-measured device step time and the accelerator's
-        compute/bandwidth roofs. When a telemetry sink is attached, the
-        row is also streamed as an ``{"kind": "attribution", "scope":
-        "train"}`` record for scripts/telemetry_report.py."""
-        from deepspeed_tpu.telemetry.attribution import (accelerator_peaks,
-                                                         roofline_row)
-
-        flops = self._telemetry_flops
-        if not flops:
-            return {}
-        wall_s = None
-        if self.telemetry is not None:
-            ms = self.telemetry.gauge("train/device_step_time_ms").value
-            if ms:
-                wall_s = ms / 1e3
-        peak_flops, peak_bw = accelerator_peaks()
-        # _telemetry_flops/_telemetry_bytes are CLUSTER totals (the MFU
-        # probe scales cost_analysis by device_count; the analytic
-        # fallback counts global-batch tokens) while the accelerator
-        # roofs are PER CHIP — normalize to per-chip so achieved vs
-        # attainable compares like with like on multi-chip meshes
-        n_dev = max(jax.device_count(), 1)
-        row = roofline_row(flops / n_dev,
-                           (self._telemetry_bytes or 0.0) / n_dev,
-                           wall_s=wall_s, calls=self.global_steps,
-                           peak_flops=peak_flops,
-                           peak_bytes_per_sec=peak_bw)
-        table = {"train_step": row}
-        if self.telemetry is not None and self.telemetry.sink is not None:
-            try:
-                self.telemetry.sink.write({
-                    "kind": "attribution", "scope": "train",
-                    "programs": table})
-            except Exception:
-                pass
-        return table
 
     # ------------------------------------------------- resilience (ISSUE 10)
     def _resilience_step(self, metrics, batch):
@@ -1557,6 +1566,9 @@ class DeepSpeedEngine:
             dist.log_summary()
         if self.telemetry is not None:
             self.telemetry.flush(step=self.global_steps)
+        if self._compile_sub is not None:
+            compile_log().unsubscribe(self._compile_sub)
+            self._compile_sub = None
         if self._spans_sink is not None:
             self._spans_sink.close()
             self._spans_sink = None
@@ -1574,6 +1586,7 @@ class DeepSpeedEngine:
             self._attached_sink = None
 
     # ------------------------------------------ forward/backward/step parity
+    @at_work
     def forward(self, batch):
         """Compute loss for one microbatch; grads are computed in the same
         compiled program and cached for backward() (JAX has no separate
@@ -1594,6 +1607,7 @@ class DeepSpeedEngine:
 
     __call__ = forward
 
+    @at_work
     def backward(self, loss=None, allreduce_gradients: bool = True):
         """Accumulate the cached grads (reference backward:1755 + grad hooks)."""
         assert getattr(self, "_pending", None) is not None, \
@@ -1614,6 +1628,7 @@ class DeepSpeedEngine:
     def is_gradient_accumulation_boundary(self) -> bool:
         return (self.micro_steps + 1) % self.gas == 0
 
+    @at_work
     def step(self):
         """Apply optimizer at gas boundary (reference step:1951)."""
         self.timers(STEP_GLOBAL_TIMER).start()
@@ -1666,6 +1681,7 @@ class DeepSpeedEngine:
         self.timers(STEP_GLOBAL_TIMER).stop()
 
     # -------------------------------------------------------------- eval path
+    @at_work
     def eval_batch(self, batch):
         if self._compiled_eval is None:
             def ev(params, batch):

@@ -34,6 +34,7 @@ from deepspeed_tpu.parallel.pipeline import spmd_pipeline
 from deepspeed_tpu.parallel.topology import PIPE_AXIS
 from deepspeed_tpu.runtime.engine import DeepSpeedEngine, TrainState
 from deepspeed_tpu.runtime.pipe.module import LayerSpec, PipelineModule, TiedLayerSpec
+from deepspeed_tpu.telemetry.compile_log import at_work
 from deepspeed_tpu.utils.logging import log_dist
 
 
@@ -425,6 +426,7 @@ class PipelineEngine(DeepSpeedEngine):
         return metrics["loss"]
 
     # --------------------------------------------------------------- user API
+    @at_work
     def eval_batch(self, batch, compute_loss: bool = True):
         """reference eval_batch:362 — forward-only pipeline pass. In
         host_1f1b mode this interprets InferenceSchedule tick by tick (the

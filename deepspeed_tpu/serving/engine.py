@@ -36,6 +36,7 @@ token rows — ``prefix_cache``, ``kv_dtype``, ``speculative``,
 from __future__ import annotations
 
 import time
+import weakref
 from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
@@ -63,6 +64,8 @@ from deepspeed_tpu.serving.speculative import (AdaptiveK, DraftModelDrafter,
                                                normalize_speculative,
                                                pick_k_bucket)
 from deepspeed_tpu.serving.swap import HostSwapBuffer
+from deepspeed_tpu.telemetry.compile_log import (SetupPhase, at_work,
+                                                 compile_log)
 from deepspeed_tpu.telemetry.registry import metric_label
 from deepspeed_tpu.utils.logging import log_dist
 
@@ -361,12 +364,15 @@ class ServingEngine:
         prefill chunk, decode segments, speculative draft/verify,
         preemption swap-out/swapped/swap-in, shed/cancel — under a root
         span the engine owns (or the fabric router's, when the request
-        arrives with trace context), and per-program wall time is
-        accumulated for :meth:`attribution_table`'s roofline. On the
-        engine-scope trace every step() that found work records one
-        ``iteration`` span tiled by ``iter_schedule``, ``iter_upload``,
+        arrives with trace context). On the engine-scope trace every
+        step() that found work records one ``iteration`` span tiled by
+        ``iter_schedule``, ``iter_upload``,
         ``iter_launch``, ``iter_fetch`` and ``iter_commit``, the same
-        phases the ``dstpu/serving_*`` profiler annotations name. Arming
+        phases the ``dstpu/serving_*`` profiler annotations name; the
+        phases of set-up (``setup_weights``, ``setup_cache``,
+        ``setup_warmup`` over two ``warmup_pass``) and, after warm-up, a
+        ``compile`` span for every stage of a program compiled inside
+        ``step()`` lie on the same trace. Arming
         adds no device work: greedy output stays bit-identical (pinned by
         tests/unit/serving/test_tracing.py); the armed-vs-bare budget is 2%.
     slo: an :class:`~deepspeed_tpu.telemetry.slo.SLOEngine` (ISSUE 13),
@@ -388,6 +394,11 @@ class ServingEngine:
         pinned by tests).
     """
 
+    # set by ``at_work`` for the length of the constructor, ``warmup()`` and
+    # ``step()``: the compile events that arrive then are this engine's
+    _at_work = False
+
+    @at_work
     def __init__(self, engine, *, num_slots: int = 8, max_len: int = 1024,
                  buckets: Sequence[int] = (128, 512, 2048),
                  eos_token_id: Optional[int] = None, pad_token_id: int = 0,
@@ -436,6 +447,7 @@ class ServingEngine:
                 f"kv_dtype={kv_dtype!r} needs prefix_cache=True: quantized "
                 "KV lives in the block-paged pool (serving/kv_quant.py); "
                 "the slot-paged cache stays in the compute dtype")
+        cache_phase = SetupPhase("cache")
         if prefix_cache:
             self.cache = BlockKVPool(model, num_slots, max_len,
                                      block_size=block_size,
@@ -459,6 +471,7 @@ class ServingEngine:
         self._canon = lambda x: jax.device_put(
             x, NamedSharding(engine.mesh, P()))
         self.cache.update(*map(self._canon, self.cache.carry()))
+        cache_phase.close(fence=self.cache.carry())
         # a serving program's leading outputs are the cache's carry back
         self._n_carry = len(self.cache.carry())
         # clamp oversized buckets to the slot capacity (silently DROPPING
@@ -659,7 +672,7 @@ class ServingEngine:
                 self.telemetry.gauge("serving/state_bytes_per_slot").set(
                     self._kv_bytes_per_block)
         self._acct_last_t: Optional[float] = None
-        # ---- span-graph tracing + roofline attribution (ISSUE 11)
+        # ---- span-graph tracing (ISSUE 11)
         self.tracer = tracer
         self._rtraces: Dict[int, _ReqTrace] = {}
         self._engine_trace: Optional[str] = None  # iteration-span trace
@@ -674,14 +687,11 @@ class ServingEngine:
         # context-carrying records awaiting their submit-time stamp
         # (resolved by the next step(); see _ReqTrace.submitted_t)
         self._pending_submit_stamps: List[_ReqTrace] = []
-        # program name -> abstract operand shapes, captured at warmup
-        # (ShapeDtypeStructs — no live buffers retained); the lazy
-        # cost_analysis probe in attribution_table() lowers with these
-        self._program_shapes: Dict[str, tuple] = {}
-        # program name -> [total host wall s, calls] (armed runs only —
-        # the bare path must stay byte-identical to pre-tracing code)
-        self._prog_wall: Dict[str, list] = {}
-        self._attr_cache: Dict[str, dict] = {}
+        # ---- set-up and compiles, measured from inside (ISSUE 42)
+        self._compile_sub = None
+        # stages told while no iteration span was open (armed only)
+        self._loose_compiles: List[tuple] = []
+        self._subscribe_compile_log(cache_phase)
         # radix prefix index over the block pool (ISSUE 6) — created
         # after telemetry so its hit/miss/COW/eviction counters land in
         # the same registry as the serving histograms
@@ -804,79 +814,109 @@ class ServingEngine:
             out.update(self._drafter.program_cache_sizes())
         return out
 
-    # ------------------------------------------------- attribution (ISSUE 11)
-    def _cap(self, name: str, *args):
-        """Capture a program's operand shapes (once, at warmup) for the
-        lazy roofline cost probe; passes the args through unchanged."""
-        if name not in self._program_shapes:
-            from deepspeed_tpu.telemetry.attribution import abstract_args
+    # ------------------------------------- set-up and compiles (ISSUE 42)
+    def _subscribe_compile_log(self, cache_phase: SetupPhase) -> None:
+        """Bring the registry up to what this process has traced, lowered,
+        compiled and loaded so far (``init_inference`` ran before the
+        registry existed) and keep it there; publish the set-up phases that
+        are over: the engine's weights, once a registry, and the cache.
 
-            self._program_shapes[name] = abstract_args(args)
-        return args
+        JAX stamps compile events with ``time.time()`` and the phases are
+        stamped with ``time.perf_counter()``. The offsets from both to the
+        engine's clock are taken once, here, where that clock is a real one;
+        under a virtual clock such an interval is recorded with no length at
+        the engine's last instant, so a replayed timeline stays its own."""
+        self._from_perf = self._from_wall = None
+        if self.telemetry is None and self.tracer is None:
+            return
+        if self._real_clock:
+            now = self._time()
+            self._from_perf = now - time.perf_counter()
+            self._from_wall = now - time.time()
+        if self.telemetry is not None:
+            # read beside entry/traces: there from the start, at 0
+            self.telemetry.counter("entry/traces_after_warm")
+        for phase in (getattr(self.engine, "setup_weights", None),
+                      cache_phase):
+            if phase is not None:
+                self._publish_phase(phase)
+        engine = weakref.ref(self)
 
-    def _prog_note(self, name: str, dt: float) -> None:
-        """Accumulate host wall for one program call (armed runs)."""
-        w = self._prog_wall.get(name)
-        if w is None:
-            self._prog_wall[name] = [dt, 1]
+        def on_stage(stage, program, start, end):
+            live = engine()
+            if live is not None:
+                live._on_compile_stage(stage, program, start, end)
+
+        def follows():
+            # this engine's own: the event arrives inside its constructor,
+            # its warmup() or its step(), whoever else compiles meanwhile
+            live = engine()
+            return live is not None and live._at_work
+
+        log = compile_log()
+        self._compile_sub = log.subscribe(self.telemetry, on_stage, follows)
+        # an engine dropped without close() must not be told for ever
+        weakref.finalize(self, log.unsubscribe, self._compile_sub)
+
+    def _engine_clock(self, t0: float, t1: float, offset) -> tuple:
+        """An interval of one of the host's clocks on the engine's."""
+        if offset is None:
+            t = self._phase_t if self._iter_span is not None \
+                else self._last_step_now
+            return t, t
+        offset -= self._run_t0 or 0.0
+        return t0 + offset, t1 + offset
+
+    def _publish_phase(self, phase: SetupPhase):
+        """A set-up phase into the registry and, armed, onto the engine's
+        trace; the span, if one was recorded."""
+        span = {} if self.tracer is None else {"trace_id": self._iter_trace()}
+        return phase.publish(
+            self.telemetry, self.tracer,
+            clock=lambda t0, t1: self._engine_clock(t0, t1, self._from_perf),
+            **span)
+
+    def _on_compile_stage(self, stage: str, program: str, start: float,
+                          end: float) -> None:
+        """One stage of one program's compile that arrived while this
+        engine was at work, as the compile log tells it. Before
+        ``warmup()`` has returned that is set-up, and the ``entry/*``
+        counters hold it. After it, it arrived inside ``step()`` and is a
+        recompile in service: the trace counts in
+        ``entry/traces_after_warm`` and, armed, every stage is a ``compile``
+        span under the open ``iteration``. What compiles outside ``step()``
+        (the caller's own ``jnp`` calls, another engine warming up in this
+        process) is not told here."""
+        if not self._warm:
+            return
+        if stage == "trace" and self.telemetry is not None:
+            self.telemetry.counter("entry/traces_after_warm").inc()
+        if self.tracer is None:
+            return
+        if self._iter_span is None:
+            # a prefill compiles in the schedule phase, before step() has
+            # opened the iteration it belongs to: step() records it then
+            self._loose_compiles.append((stage, program, start, end))
         else:
-            w[0] += dt
-            w[1] += 1
+            self._record_compile(stage, program, start, end, self._iter_span)
 
-    def _program_map(self) -> Dict[str, Callable]:
-        """name -> jitted program, names matching program_cache_sizes
-        (the registry the attribution table covers)."""
-        progs: Dict[str, Callable] = {"decode": self._decode}
-        for b, fn in self._prefill.items():
-            progs[f"prefill_{b}"] = fn
-        for b, fn in self._chunk_prefill.items():
-            progs[f"chunk_prefill_{b}"] = fn
-        for kb, fn in self._verify.items():
-            progs[f"verify_{kb}"] = fn
-        if self._swap_out_fn is not None:
-            progs["swap_out"] = self._swap_out_fn
-            progs["swap_in"] = self._swap_in_fn
-        if self._copy_fn is not None:
-            progs["block_copy"] = self._copy_fn
-        if isinstance(self._drafter, DraftModelDrafter):
-            # the draft model's programs ride program_cache_sizes and
-            # must ride the roofline table too
-            for kb, fn in self._drafter._programs.items():
-                progs[f"draft_{kb}"] = fn
-        return progs
+    def _record_compile(self, stage: str, program: str, start: float,
+                        end: float, parent) -> None:
+        t0, t1 = self._engine_clock(start, end, self._from_wall)
+        if parent is not None and t1 < parent.start:
+            parent = None    # between two steps: no iteration's
+        self.tracer.record(
+            "compile", t0, t1, trace_id=self._iter_trace(),
+            parent_id=None if parent is None else parent.span_id,
+            program=program, stage=stage)
 
-    def attribution_table(self) -> Dict[str, dict]:
-        """Per-program roofline attribution (ISSUE 11): XLA
-        cost-analysis flops/bytes for every compiled serving program,
-        joined with host-observed per-call wall (tracer-armed runs)
-        and the accelerator's compute/bandwidth roofs —
-        achieved-vs-attainable per program, and which roof binds it.
-        Cost probes are one extra lower+compile each, memoized; never
-        called from the serving hot path."""
-        from deepspeed_tpu.telemetry.attribution import attribution_table
+    def close(self) -> None:
+        """Stop following the compile log. The engine holds no other
+        process-wide registration; dropping it unsubscribes too."""
+        compile_log().unsubscribe(self._compile_sub)
+        self._compile_sub = None
 
-        progs = {n: (fn, self._program_shapes[n])
-                 for n, fn in self._program_map().items()
-                 if n in self._program_shapes}
-        walls = {n: (w[0], w[1]) for n, w in self._prog_wall.items()}
-        return attribution_table(progs, walls=walls,
-                                 cache=self._attr_cache)
-
-    def record_attribution(self) -> Dict[str, dict]:
-        """Compute :meth:`attribution_table` and stream it to the
-        telemetry JSONL sink as an ``{"kind": "attribution"}`` record
-        (rendered by scripts/telemetry_report.py's ``attribution``
-        section). Returns the table."""
-        table = self.attribution_table()
-        if self.telemetry is not None and self.telemetry.sink is not None:
-            try:
-                self.telemetry.sink.write({
-                    "kind": "attribution", "scope": "serving",
-                    "programs": table})
-            except Exception:
-                pass
-        return table
-
+    @at_work
     def warmup(self) -> None:
         """Compile every serving program (each bucket's prefill + the
         decode step + with speculation each k-bucket's verify and draft
@@ -885,104 +925,119 @@ class ServingEngine:
         program-output — of the carry and of the decode step's previous
         tokens are cached for every program; after this, a
         trace of ANY shape mix (including adaptive-k transitions) runs
-        zero compiles."""
+        zero compiles. Each pass ends at a fence of its own, so
+        ``entry/setup_warmup_ms`` and, for the second pass alone,
+        ``entry/setup_warmup_repeat_ms`` read work done (spans
+        ``setup_warmup`` and, a pass, ``warmup_pass``)."""
         if self._warm:
             return
+        whole = SetupPhase("warmup")
+        passes = []
+        for _ in range(2):
+            t_pass = time.perf_counter()
+            self._warmup_pass()
+            # dstpu-lint: fence=warmup: a pass is read at its end, set-up only
+            jax.block_until_ready((self.cache.carry(), self._previous))
+            passes.append((t_pass, time.perf_counter()))
+        whole.close()
+        if self.telemetry is not None:
+            self.telemetry.counter("entry/setup_warmup_repeat_ms").inc(
+                (passes[1][1] - passes[1][0]) * 1e3)
+        outer = self._publish_phase(whole)
+        if outer is not None:
+            for i, (t0, t1) in enumerate(passes):
+                self.tracer.record(
+                    "warmup_pass",
+                    *self._engine_clock(t0, t1, self._from_perf),
+                    trace_id=outer.trace_id, parent_id=outer.span_id,
+                    **{"pass": i})
+        self._warm = True
+
+    def _warmup_pass(self) -> None:
+        """Every serving program once, on dummy data."""
         eng = self.engine
         paged = self.prefix is not None
-        for _ in range(2):
-            for b in self.buckets:
-                ids = jnp.zeros((1, b), jnp.int32)
-                if paged:
-                    # sentinel table row: the dummy prefill's writes land
-                    # in the pool's garbage block, never a real one
-                    out = self._prefill_fn(b)(*self._cap(
-                        f"prefill_{b}",
-                        eng.params, *self.cache.carry(), ids,
-                        self.cache.table_row(0), np.int32(0), np.int32(0),
-                        np.int32(1), self._temp, self._zero_key))
-                else:
-                    out = self._prefill_fn(b)(*self._cap(
-                        f"prefill_{b}",
-                        eng.params, *self.cache.carry(), ids, np.int32(0),
-                        np.int32(1), self._temp, self._zero_key,
-                        self._previous))
-                self._adopt_first(out)
-                if (self._chunk_max is not None and not paged
-                        and b <= self._chunk_max):
-                    # slot-paged chunk programs: chunks never exceed
-                    # _chunk_max, so only buckets up to it can run one
-                    out = self._chunk_fn(b)(*self._cap(
-                        f"chunk_prefill_{b}",
-                        eng.params, *self.cache.carry(), ids, np.int32(0),
-                        np.int32(0), np.int32(1), self._temp,
-                        self._zero_key, self._previous))
-                    self._adopt_first(out)
-            if self.preemption is not None:
-                # swap round trip through slot/garbage rows, with the
-                # host upload in the loop so BOTH runtime operand
-                # signatures (canonical carry + numpy-uploaded rows) are
-                # cached — a first preemption mid-trace must not compile
-                self._build_swap_programs()
-                if paged:
-                    sent = jnp.asarray(np.full(
-                        (self.cache.max_blocks_per_slot,),
-                        self.cache.sentinel, np.int32))
-                    ko, vo = self._swap_out_fn(*self._cap(
-                        "swap_out", self.cache.k, self.cache.v, sent))
-                    args_in = (_to_device(jax.device_get(ko)),  # dstpu-lint: fence=warmup: pre-cache numpy-upload swap signature
-                               _to_device(jax.device_get(vo)),
-                               sent)
-                else:
-                    ko, vo = self._swap_out_fn(*self._cap(
-                        "swap_out", self.cache.k, self.cache.v,
-                        np.int32(0)))
-                    args_in = (jnp.asarray(np.asarray(jax.device_get(ko))),  # dstpu-lint: fence=warmup: pre-cache numpy-upload swap signature
-                               jnp.asarray(np.asarray(jax.device_get(vo))))
-                out = self._swap_in_fn(*self._cap(
-                    "swap_in", self.cache.k, self.cache.v,
-                    *args_in, self.cache.lengths,
-                    np.int32(0), np.int32(0)))
-                self.cache.update(*out)
-            toks = np.zeros((self.num_slots,), np.int32)
-            active = np.zeros((self.num_slots,), bool)
-            # the previous step's tokens too come with both signatures:
-            # uploaded on the first pass, a program's output on the second
-            out = self._decode(*self._cap(
-                "decode", eng.params, *self.cache.carry(),
-                *self._table_args(),
-                jnp.asarray(toks), jnp.asarray(active),
-                self._temp, self._zero_key, self._previous,
-                jnp.asarray(active)))
-            self._previous = self._adopt(out)[0]
+        for b in self.buckets:
+            ids = jnp.zeros((1, b), jnp.int32)
             if paged:
-                # COW copy program: garbage row onto itself is a no-op
-                k, v = self._copy_fn(*self._cap(
-                    "block_copy", self.cache.k, self.cache.v,
-                    np.int32(self.cache.sentinel),
-                    np.int32(self.cache.sentinel)))
-                self.cache.update_kv(k, v)
-            if self.spec is not None:
-                zeros = jnp.zeros((self.num_slots,), jnp.int32)
-                for kb in self.spec.k_buckets:
-                    blk = jnp.zeros((self.num_slots, kb + 1), jnp.int32)
-                    out = self._verify_fn(kb)(*self._cap(
-                        f"verify_{kb}",
-                        eng.params, *self.cache.carry(),
-                        *self._table_args(), blk, zeros,
-                        jnp.asarray(active), self._temp, self._zero_key))
-                    self._adopt(out)
-                    if isinstance(self._drafter, DraftModelDrafter):
-                        window = jnp.zeros(
-                            (self.num_slots, self._drafter.window),
-                            jnp.int32)
-                        self._drafter._program(kb)(*self._cap(
-                            f"draft_{kb}",
-                            self._drafter.engine.params, window,
-                            jnp.ones((self.num_slots,), jnp.int32)))
-            self.cache.lengths = self._canon(
-                jnp.zeros((self.num_slots,), jnp.int32))
-        self._warm = True
+                # sentinel table row: the dummy prefill's writes land
+                # in the pool's garbage block, never a real one
+                out = self._prefill_fn(b)(
+                    eng.params, *self.cache.carry(), ids,
+                    self.cache.table_row(0), np.int32(0), np.int32(0),
+                    np.int32(1), self._temp, self._zero_key)
+            else:
+                out = self._prefill_fn(b)(
+                    eng.params, *self.cache.carry(), ids, np.int32(0),
+                    np.int32(1), self._temp, self._zero_key,
+                    self._previous)
+            self._adopt_first(out)
+            if (self._chunk_max is not None and not paged
+                    and b <= self._chunk_max):
+                # slot-paged chunk programs: chunks never exceed
+                # _chunk_max, so only buckets up to it can run one
+                out = self._chunk_fn(b)(
+                    eng.params, *self.cache.carry(), ids, np.int32(0),
+                    np.int32(0), np.int32(1), self._temp,
+                    self._zero_key, self._previous)
+                self._adopt_first(out)
+        if self.preemption is not None:
+            # swap round trip through slot/garbage rows, with the
+            # host upload in the loop so BOTH runtime operand
+            # signatures (canonical carry + numpy-uploaded rows) are
+            # cached — a first preemption mid-trace must not compile
+            self._build_swap_programs()
+            if paged:
+                sent = jnp.asarray(np.full(
+                    (self.cache.max_blocks_per_slot,),
+                    self.cache.sentinel, np.int32))
+                ko, vo = self._swap_out_fn(self.cache.k, self.cache.v, sent)
+                args_in = (_to_device(jax.device_get(ko)),  # dstpu-lint: fence=warmup: pre-cache numpy-upload swap signature
+                           _to_device(jax.device_get(vo)),
+                           sent)
+            else:
+                ko, vo = self._swap_out_fn(self.cache.k, self.cache.v,
+                                           np.int32(0))
+                args_in = (jnp.asarray(np.asarray(jax.device_get(ko))),  # dstpu-lint: fence=warmup: pre-cache numpy-upload swap signature
+                           jnp.asarray(np.asarray(jax.device_get(vo))))
+            out = self._swap_in_fn(self.cache.k, self.cache.v,
+                                   *args_in, self.cache.lengths,
+                                   np.int32(0), np.int32(0))
+            self.cache.update(*out)
+        toks = np.zeros((self.num_slots,), np.int32)
+        active = np.zeros((self.num_slots,), bool)
+        # the previous step's tokens too come with both signatures:
+        # uploaded on the first pass, a program's output on the second
+        out = self._decode(
+            eng.params, *self.cache.carry(), *self._table_args(),
+            jnp.asarray(toks), jnp.asarray(active),
+            self._temp, self._zero_key, self._previous,
+            jnp.asarray(active))
+        self._previous = self._adopt(out)[0]
+        if paged:
+            # COW copy program: garbage row onto itself is a no-op
+            k, v = self._copy_fn(self.cache.k, self.cache.v,
+                                 np.int32(self.cache.sentinel),
+                                 np.int32(self.cache.sentinel))
+            self.cache.update_kv(k, v)
+        if self.spec is not None:
+            zeros = jnp.zeros((self.num_slots,), jnp.int32)
+            for kb in self.spec.k_buckets:
+                blk = jnp.zeros((self.num_slots, kb + 1), jnp.int32)
+                out = self._verify_fn(kb)(
+                    eng.params, *self.cache.carry(),
+                    *self._table_args(), blk, zeros,
+                    jnp.asarray(active), self._temp, self._zero_key)
+                self._adopt(out)
+                if isinstance(self._drafter, DraftModelDrafter):
+                    window = jnp.zeros(
+                        (self.num_slots, self._drafter.window),
+                        jnp.int32)
+                    self._drafter._program(kb)(
+                        self._drafter.engine.params, window,
+                        jnp.ones((self.num_slots,), jnp.int32))
+        self.cache.lengths = self._canon(
+            jnp.zeros((self.num_slots,), jnp.int32))
 
     def _table_args(self) -> tuple:
         """Extra traced operand for the block-paged programs: the full
@@ -1383,14 +1438,9 @@ class ServingEngine:
             total = plen + req.max_new_tokens + self._lookahead
             start, copies = self.prefix.admit(slot, req.prompt, total)
             for src, dst in copies:
-                w0 = time.perf_counter() if self.tracer is not None \
-                    else 0.0
                 k, v = self._copy_fn(self.cache.k, self.cache.v,
                                      np.int32(src), np.int32(dst))
                 self.cache.update_kv(k, v)
-                if self.tracer is not None:
-                    self._prog_note("block_copy",
-                                    time.perf_counter() - w0)
         res = RequestResult(rid=req.rid, prompt_len=plen,
                             arrival_time=req.arrival_time,
                             admitted_time=now, priority=req.priority)
@@ -1457,7 +1507,6 @@ class ServingEngine:
             armed = self.tracer is not None
             if armed:
                 t_span0 = self._now(now)
-                t_wall0 = time.perf_counter()
             # an idle device from the program call on is the prefill's, not
             # the admission's; the last chunk's fetch opens the same
             # annotation again
@@ -1492,7 +1541,6 @@ class ServingEngine:
                     # dispatch only (fenced=False); the LAST chunk's span
                     # closes at its commit, after the token fetch the
                     # untraced engine always paid
-                    self._prog_note(pname, time.perf_counter() - t_wall0)
                     rt = self._rtraces.get(req.rid)
                     if rt is not None and not last:
                         self.tracer.record(
@@ -1659,7 +1707,6 @@ class ServingEngine:
         rt = self._rtraces.get(st.request.rid) if armed else None
         if armed:
             t_sw0 = self._now(now)
-            w0 = time.perf_counter()
         length = int(jax.device_get(self.cache.lengths[slot]))  # dstpu-lint: fence=preemption swap-out: computed length bounds the parked blocks
         if self.prefix is not None:
             n_used = self.cache.blocks_for(length)
@@ -1693,22 +1740,20 @@ class ServingEngine:
         st.result.preemptions += 1
         since = self._now(now)
         self._preempted[st.request.rid] = _Preempted(st, length, since)
-        if armed:
-            self._prog_note("swap_out", time.perf_counter() - w0)
-            if rt is not None:
-                # the decode segment ends where the swap began; the
-                # swapped interval opens at the park instant and closes
-                # on resume — preempted time lands in its own phase
-                self.tracer.end(rt.decode_span, t=t_sw0,
-                                reason="preempted")
-                rt.decode_span = None
-                self.tracer.record("swap_out", t_sw0, since,
-                                   trace_id=rt.trace_id,
-                                   parent_id=rt.root, program="swap_out",
-                                   blocks=n_used, slot=slot)
-                rt.swap_span = self.tracer.begin(
-                    "swapped", trace_id=rt.trace_id, parent_id=rt.root,
-                    t=since, blocks=n_used)
+        if rt is not None:
+            # the decode segment ends where the swap began; the
+            # swapped interval opens at the park instant and closes
+            # on resume — preempted time lands in its own phase
+            self.tracer.end(rt.decode_span, t=t_sw0,
+                            reason="preempted")
+            rt.decode_span = None
+            self.tracer.record("swap_out", t_sw0, since,
+                               trace_id=rt.trace_id,
+                               parent_id=rt.root, program="swap_out",
+                               blocks=n_used, slot=slot)
+            rt.swap_span = self.tracer.begin(
+                "swapped", trace_id=rt.trace_id, parent_id=rt.root,
+                t=since, blocks=n_used)
         self.preemptions += 1
         if self.tenants is not None:
             self.tenants.note_preemption(st.tenant)
@@ -1735,7 +1780,6 @@ class ServingEngine:
         rt = self._rtraces.get(req.rid) if armed else None
         if armed:
             t_in0 = self._now(now)
-            w0 = time.perf_counter()
         host_k, host_v = self.swap.pop(req.rid)
         length = rec.length
         if self.prefix is not None:
@@ -1768,20 +1812,18 @@ class ServingEngine:
             swapped_in = 1
         self.cache.update(*out)
         t_res = self._now(now)
-        if armed:
-            self._prog_note("swap_in", time.perf_counter() - w0)
-            if rt is not None:
-                self.tracer.end(rt.swap_span, t=t_in0)
-                rt.swap_span = None
-                self.tracer.record("swap_in", t_in0, t_res,
-                                   trace_id=rt.trace_id,
-                                   parent_id=rt.root, program="swap_in",
-                                   blocks=swapped_in, slot=slot)
-                if st.result.tokens:
-                    rt.decode_span = self.tracer.begin(
-                        "decode_segment", trace_id=rt.trace_id,
-                        parent_id=rt.root, t=t_res, slot=slot,
-                        resumed=True)
+        if rt is not None:
+            self.tracer.end(rt.swap_span, t=t_in0)
+            rt.swap_span = None
+            self.tracer.record("swap_in", t_in0, t_res,
+                               trace_id=rt.trace_id,
+                               parent_id=rt.root, program="swap_in",
+                               blocks=swapped_in, slot=slot)
+            if st.result.tokens:
+                rt.decode_span = self.tracer.begin(
+                    "decode_segment", trace_id=rt.trace_id,
+                    parent_id=rt.root, t=t_res, slot=slot,
+                    resumed=True)
         gap = max(t_res - rec.since, 0.0)
         st.result.preempted_wall += gap
         if st.result.tokens:
@@ -1804,6 +1846,7 @@ class ServingEngine:
             # wait did
             reg.histogram("serving/queue_wait_ms").observe(gap * 1e3)
 
+    @at_work
     def step(self, now: Optional[float] = None) -> List[RequestResult]:
         """One serving iteration: run the budgeted admit/prefill side
         (chunk continuations, admissions, preemptions — ISSUE 8), then
@@ -1852,6 +1895,10 @@ class ServingEngine:
             self._iter_span = self.tracer.begin(
                 "iteration", trace_id=self._iter_trace(), t=t_iter0)
             self._phase_t = t_iter0
+            if self._loose_compiles:
+                for told in self._loose_compiles:
+                    self._record_compile(*told, self._iter_span)
+                self._loose_compiles.clear()
             self._phase_end("iter_schedule", now)
         # a slot whose last token the step in flight is picking sits out:
         # an end by length is known at launch, one by EOS only at commit
@@ -2008,9 +2055,7 @@ class ServingEngine:
             if armed:
                 # upload opened to fetch closed: the fetch IS a fence, and
                 # in a run of decode iterations each waits for the step
-                # launched one earlier, so this wall is what a step costs —
-                # the attribution 'achieved' clock
-                self._prog_note("decode", dt)
+                # launched one earlier, so the span is what a step costs;
                 # iter_fetch closed at a read of the clock after the fence
                 self.tracer.record("decode_step", t_dec0, self._phase_t,
                                    trace_id=self._iter_trace(),
@@ -2182,7 +2227,6 @@ class ServingEngine:
         self._verify_wall += dt
         self.decode_wall += dt
         if armed:
-            self._prog_note(f"verify_{kb}", dt)
             self._phase_t = self._now(now)
             self.tracer.record("spec_verify", t_vf0, self._phase_t,
                                trace_id=self._iter_trace(),

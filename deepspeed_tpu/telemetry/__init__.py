@@ -15,6 +15,9 @@ comms logger, redesigned for JAX's async-dispatch execution model:
     around any block, with the hot loops' named scopes inside.
   * :mod:`mfu`       — PaLM-sense model-flops-utilization against the
     accelerator layer's per-chip peak table.
+  * :mod:`compile_log` — what start-up cost, from inside: JAX's trace,
+    lowering, compile and cache events by program name as ``entry/*``
+    counters, and the phases of set-up (ISSUE 42).
 
 Instrumentation points: ``runtime/engine.py`` (per-step wall/device time,
 tokens/sec, MFU, grad-norm, fp16 skip counters, device memory) and
@@ -23,9 +26,8 @@ recompile counter, finished-requests/sec). Overhead is budgeted at 2%; no
 benchmark cell measures it yet.
 """
 
-from deepspeed_tpu.telemetry.attribution import (abstract_args,
-                                                 attribution_table,
-                                                 program_cost, roofline_row)
+from deepspeed_tpu.telemetry.compile_log import (CompileLog, SetupPhase,
+                                                 compile_log)
 from deepspeed_tpu.telemetry.config import TelemetryConfig, get_telemetry_config
 from deepspeed_tpu.telemetry.flight_recorder import FlightRecorder
 from deepspeed_tpu.telemetry.mfu import mfu, peak_flops_per_sec
@@ -53,6 +55,7 @@ from deepspeed_tpu.telemetry.trace import annotate, trace
 
 __all__ = [
     "BurnRateRule",
+    "CompileLog",
     "DEFAULT_LATENCY_BUCKETS_MS",
     "DEFAULT_SLO_CONFIG",
     "DEFAULT_TENANT",
@@ -68,14 +71,14 @@ __all__ = [
     "SLOAlert",
     "SLOConfigError",
     "SLOEngine",
+    "SetupPhase",
     "Span",
     "SpanTracer",
     "TelemetryConfig",
     "TenantLedger",
-    "abstract_args",
     "aggregate_phase_stats",
     "annotate",
-    "attribution_table",
+    "compile_log",
     "get_registry",
     "get_telemetry_config",
     "metric_label",
@@ -83,11 +86,9 @@ __all__ = [
     "parse_slo_config",
     "peak_flops_per_sec",
     "phase_breakdown",
-    "program_cost",
     "read_jsonl",
     "record_event",
     "reset_registry",
-    "roofline_row",
     "sanitize_metric_name",
     "trace",
     "trace_summaries",

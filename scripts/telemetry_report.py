@@ -18,10 +18,11 @@ Sections:
   events      — occurrence counts per event name
   spans       — span-graph critical paths (ISSUE 11): per-request
                 p50/p95 time + fraction in queue/prefill/decode/
-                swapped/failover, reconstructed from "span" records
-  attribution — per-program roofline (ISSUE 11): flops/bytes/intensity,
-                achieved vs attainable TFLOPs and the binding roof, from
-                "attribution" records
+                swapped/failover, reconstructed from "span" records;
+                ``setup_ms``: the phases of set-up (setup_weights,
+                setup_cache, setup_warmup and its warmup_pass spans,
+                setup_first_step) and the compile spans of a recompile
+                in service, total milliseconds a name (ISSUE 42)
   slo         — SLO scheduling view (ISSUE 8) merged with the SLO
                 control plane (ISSUE 13): error-budget consumption per
                 SLI, burn-rate timeline stats per rule, and the
@@ -80,7 +81,6 @@ def aggregate(records, n_bad_lines=0, postmortem=None):
     scalars = OrderedDict()   # tag -> stats dict
     events = OrderedDict()    # name -> {count, last_fields}
     spans = []                # raw span records, arrival order
-    attributions = OrderedDict()   # scope -> last program table
     slo_evals = []            # SLO-engine burn-rate timeline (ISSUE 13)
     elastic_events = []       # autoscaler + pool-membership events (ISSUE 16)
     for rec in records:
@@ -91,8 +91,6 @@ def aggregate(records, n_bad_lines=0, postmortem=None):
             spans.append(rec)
         elif kind == "slo_eval":
             slo_evals.append(rec)
-        elif kind == "attribution":
-            attributions[rec.get("scope", "?")] = rec.get("programs", {})
         elif kind == "scalar":
             tag = rec.get("tag", "?")
             try:
@@ -136,7 +134,6 @@ def aggregate(records, n_bad_lines=0, postmortem=None):
         "autoscaler": _autoscaler_summary(metrics, elastic_events),
         "resilience": _resilience_summary(metrics),
         "spans": _spans_summary(spans),
-        "attribution": _attribution_summary(attributions),
         "postmortem": _postmortem_summary(postmortem),
         "n_records": len(records),
         "n_bad_lines": n_bad_lines,
@@ -160,10 +157,15 @@ def _spans_summary(spans):
     phases = ("queue", "prefill", "decode", "swapped", "failover")
     by_name = OrderedDict()
     by_trace = OrderedDict()
+    setup_ms = OrderedDict()     # set-up phase or compile -> total ms
     for s in spans:
         name = s.get("name", "?")
         by_name[name] = by_name.get(name, 0) + 1
         by_trace.setdefault(s.get("trace"), []).append(s)
+        if (name.startswith("setup_") or name in ("warmup_pass", "compile")) \
+                and s.get("end") is not None:
+            setup_ms[name] = round(setup_ms.get(name, 0.0) + max(
+                s["end"] - s.get("start", 0.0), 0.0) * 1e3, 3)
     requests = []
     for group in by_trace.values():
         roots = [s for s in group if s.get("name") == "request"
@@ -181,6 +183,8 @@ def _spans_summary(spans):
         requests.append((total, ph))
     out = {"n_spans": len(spans), "span_counts": dict(by_name),
            "n_requests": len(requests)}
+    if setup_ms:
+        out["setup_ms"] = dict(setup_ms)
     if not requests:
         return out
 
@@ -201,15 +205,6 @@ def _spans_summary(spans):
                   "ms_p50": round(pct(ab, 0.5) * 1e3, 3),
                   "ms_p95": round(pct(ab, 0.95) * 1e3, 3)}
     return out
-
-
-def _attribution_summary(attributions):
-    """Per-program roofline tables (ISSUE 11), keyed by scope (serving
-    / train): the last "attribution" record per scope wins — it carries
-    the most wall-time context. Empty dict when the run recorded
-    none."""
-    return {scope: table for scope, table in attributions.items()
-            if table}
 
 
 def _speculation_summary(metrics):
@@ -665,24 +660,6 @@ def render(agg):
            [(k, _fmt(v) if not isinstance(v, dict) else
              " ".join(f"{kk}={_fmt(vv)}" for kk, vv in v.items()))
             for k, v in agg.get("spans", {}).items()], out)
-    for scope, table in agg.get("attribution", {}).items():
-        arows = []
-        for name, row in sorted(table.items()):
-            if not isinstance(row, dict):
-                continue
-            arows.append((name, _fmt(row.get("flops")),
-                          _fmt(row.get("bytes_accessed")),
-                          _fmt(row.get("intensity_flops_per_byte")),
-                          _fmt(row.get("calls")),
-                          _fmt(row.get("mean_wall_ms")),
-                          _fmt(row.get("achieved_tflops")),
-                          _fmt(row.get("attainable_tflops")),
-                          _fmt(row.get("achieved_vs_attainable")),
-                          _fmt(row.get("bound"))))
-        _table(f"attribution ({scope})",
-               ("program", "flops", "bytes", "flops/byte", "calls",
-                "wall_ms", "achieved_tf", "attainable_tf",
-                "ach/att", "bound"), arows, out)
     erows = [(k, e["count"],
               json.dumps(e["last"], default=str)[:60])
              for k, e in agg["events"].items()]

@@ -1,5 +1,5 @@
-"""End-to-end request tracing + roofline attribution (ISSUE 11
-acceptance). All in-process, on CPU, in virtual time.
+"""End-to-end request tracing (ISSUE 11 acceptance) and the compile log
+behind it (ISSUE 42). All in-process, on CPU, in virtual time.
 
 Pinned here:
 
@@ -17,10 +17,10 @@ Pinned here:
     ORIGINAL trace id through the Request trace-context fields — the
     Chrome-trace export is valid JSON, and the report's spans section
     renders the critical paths;
-  * ATTRIBUTION: the per-program roofline table names flops/bytes (and
-    achieved wall, armed) for EVERY compiled serving program in the
-    jit-cache registry, and streams to telemetry JSONL for the
-    report's attribution section.
+  * COMPILE LOG: EVERY compiled serving program in the jit-cache
+    registry, the draft model's too, is in the process's compile log
+    under its ``program_cache_sizes()`` name, and the ``entry/*``
+    counters reach the registry's JSONL snapshot.
 """
 
 import importlib.util
@@ -38,8 +38,9 @@ from deepspeed_tpu.serving import (FabricRouter, InProcessReplica,
                                    ReplicaSupervisor, Request,
                                    ServingEngine, bimodal_trace,
                                    poisson_trace)
-from deepspeed_tpu.telemetry import (JsonlSink, SpanTracer, phase_breakdown,
-                                     read_jsonl, trace_summaries)
+from deepspeed_tpu.telemetry import (JsonlSink, SpanTracer, compile_log,
+                                     phase_breakdown, read_jsonl,
+                                     trace_summaries)
 from deepspeed_tpu.testing import FakeClock, FaultInjector
 from deepspeed_tpu.utils import groups
 
@@ -395,10 +396,10 @@ def test_preemption_swap_spans_and_phase():
     assert victim["fractions"]["swapped"] > 0
     # the un-preempted request never swapped
     assert sums[1]["phases_s"]["swapped"] == 0.0
-    # swap programs show in the attribution registry with wall samples
-    att = srv.attribution_table()
-    assert att["swap_out"]["calls"] >= 1
-    assert att["swap_in"]["calls"] >= 1
+    # the swap spans name the programs as program_cache_sizes() does
+    assert {s.attrs["program"] for s in group
+            if s.name in ("swap_out", "swap_in")} \
+        == {"swap_out", "swap_in"} <= set(srv.program_cache_sizes())
     ph = phase_breakdown(group)
     assert ph["swapped"] == pytest.approx(victim["phases_s"]["swapped"])
 
@@ -424,14 +425,14 @@ def test_speculative_iteration_spans():
     assert all(v.trace_id not in req_traces for v in verifies)
     assert all(v.attrs["program"].startswith("verify_")
                for v in verifies)
-    att = srv.attribution_table()
-    assert any(k.startswith("verify_") for k in att)
+    assert {v.attrs["program"] for v in verifies} \
+        <= set(srv.program_cache_sizes())
 
 
-def test_draft_model_programs_ride_the_attribution_registry():
+def test_draft_model_programs_are_in_the_compile_log():
     """Draft-backend speculation: the draft model's compiled programs
-    appear in program_cache_sizes AND must appear in the roofline table
-    — coverage of 'every compiled program' includes them."""
+    appear in program_cache_sizes AND in the compile log under those
+    names: 'every compiled program' includes them."""
     from deepspeed_tpu.serving.speculative import SpeculativeConfig
 
     cfg, eng = _inference_engine()
@@ -448,20 +449,21 @@ def test_draft_model_programs_ride_the_attribution_registry():
     pattern = np.random.RandomState(5).randint(
         0, cfg.vocab_size, size=5).tolist()
     srv.run([Request(rid=0, prompt=pattern * 6, max_new_tokens=8)])
-    table = srv.attribution_table()
     jit_programs = set(srv.program_cache_sizes())
     assert any(k.startswith("draft_") for k in jit_programs)
-    assert jit_programs <= set(table), \
-        (sorted(jit_programs), sorted(table))
-    assert table["draft_2"]["flops"] > 0
+    log = compile_log()
+    missing = {p for p in jit_programs
+               if not log.programs.get(p, {}).get("trace")}
+    assert not missing, (sorted(missing), sorted(log.programs))
+    assert log.programs["draft_2"]["lower"][0] >= 1
 
 
-# ------------------------------------------------------- attribution
-def test_attribution_covers_every_compiled_program(tmp_path):
-    """The roofline table names every program in the jit-cache registry
-    — prefill buckets, decode, swap, (prefix mode: block_copy) — with
-    XLA cost-analysis flops/bytes, and streams to telemetry JSONL for
-    the report's attribution section."""
+# ------------------------------------------------------- compile log
+def test_compile_log_covers_every_compiled_program(tmp_path):
+    """The compile log names every program in the jit-cache registry
+    (prefill buckets, decode, swap, in prefix mode block_copy) with the
+    stages JAX ran for it, and the registry's ``entry/*`` counters,
+    brought up to the process's totals, reach its JSONL snapshot."""
     from deepspeed_tpu.telemetry import MetricsRegistry
 
     reg = MetricsRegistry()
@@ -473,24 +475,26 @@ def test_attribution_covers_every_compiled_program(tmp_path):
                    preemption="swap", prefix_cache=True, block_size=8,
                    telemetry=reg, tracer=tracer)
     srv.run(_trace(6, seed=4))
-    table = srv.record_attribution()
+    log = compile_log()
     jit_programs = set(srv.program_cache_sizes())
-    assert jit_programs <= set(table), \
-        (sorted(jit_programs), sorted(table))
-    for name, row in table.items():
-        assert row.get("flops", 0) >= 0, name
-        assert "bytes_accessed" in row, name
-    # hot programs carry flops AND host-observed wall (armed run)
-    assert table["decode"]["flops"] > 0
-    assert table["decode"]["calls"] > 0
-    assert table["decode"]["mean_wall_ms"] > 0
-    assert table["prefill_16"]["flops"] > 0
-    assert table["block_copy"]["bytes_accessed"] >= 0
+    assert {"decode", "prefill_16", "prefill_32", "swap_out", "swap_in",
+            "block_copy"} <= jit_programs
+    for name in jit_programs:
+        stages = log.programs.get(name, {})
+        assert stages.get("trace", [0])[0] >= 1, (name, sorted(log.programs))
+        assert stages["lower"][0] >= 1 and stages["lower"][1] > 0, name
+        assert stages["backend_compile"][0] >= 1, name
+    counters = reg.snapshot()["counters"]
+    # the totals at subscription and what the engine's own work added
+    assert len(jit_programs) <= counters["entry/traces"] \
+        <= log.totals["entry/traces"]
+    assert counters["entry/trace_ms"] > 0 and counters["entry/lower_ms"] > 0
+    assert counters["entry/setup_warmup_ms"] > 0
+    srv.close()
     reg.sink.close()
-    recs = read_jsonl(path)
-    [att] = [r for r in recs if r["kind"] == "attribution"]
-    assert att["scope"] == "serving"
-    assert set(att["programs"]) == set(table)
+    snaps = [r for r in read_jsonl(path) if r["kind"] == "snapshot"]
+    assert snaps[-1]["metrics"]["counters"]["entry/traces"] \
+        == counters["entry/traces"]
 
 
 # ------------------------------------------------------- chaos fabric

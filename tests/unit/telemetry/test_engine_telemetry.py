@@ -62,12 +62,23 @@ def test_per_step_metrics_and_fence(tmp_path):
 
 
 def test_telemetry_disabled_is_bare(tmp_path):
-    engine = _engine(telemetry={"enabled": False})
+    # an enabled engine that nobody destroyed still has the global registry
+    # subscribed to the compile log (ISSUE 42); it follows its own work
+    # alone, so what a disabled engine compiles beside it leaves no entry/*
+    log = telemetry.compile_log()
+    forgotten = _engine(telemetry={"sync_interval": 0})
+    _step(forgotten)
+    assert forgotten._compile_sub in log._subs
+    traced = log.totals["entry/traces"]
+    engine = _engine(telemetry={"enabled": False})    # clears the registry
     for i in range(2):
         _step(engine, i)
-    assert engine.telemetry is None
+    assert engine.telemetry is None and engine._compile_sub is None
+    assert log.totals["entry/traces"] > traced        # it did compile
     assert telemetry.get_registry().snapshot()["counters"] == {}
     engine.destroy()                           # no sink, no comms: no-op
+    forgotten.destroy()
+    assert forgotten._compile_sub is None
 
 
 def test_monitor_interval_decouples_from_steps_per_print(tmp_path):

@@ -109,12 +109,20 @@ def test_histogram_percentiles_agree_with_direct(capsys):
 
 
 def test_bare_mode_writes_nothing():
+    # an engine on the global registry that nobody closed: it follows the
+    # compile log for its own work alone (ISSUE 42), so the bare engine's
+    # programs, compiled beside it, leave nothing in that registry
+    _, forgotten = _serving(True, num_slots=3)
+    assert forgotten._compile_sub in telemetry.compile_log()._subs
     telemetry.reset_registry()
-    cfg, srv = _serving(False)
-    assert srv.telemetry is None
+    traced = telemetry.compile_log().totals["entry/traces"]
+    cfg, srv = _serving(False, buckets=(24,))
+    assert srv.telemetry is None and srv._compile_sub is None
     srv.run(_reqs(cfg, [5, 7], [2, 2]))
+    assert telemetry.compile_log().totals["entry/traces"] > traced
     snap = telemetry.get_registry().snapshot()
     assert snap["counters"] == {} and snap["gauges"] == {}
+    forgotten.close()
 
 
 def test_default_telemetry_uses_global_registry():

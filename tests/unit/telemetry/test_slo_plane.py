@@ -542,7 +542,7 @@ def test_report_degrade_paths(tmp_path):
     mod = _load_script("telemetry_report")
     sections = ("counters", "gauges", "histograms", "scalars", "events",
                 "speculation", "prefix_cache", "slo", "tenants", "fabric",
-                "resilience", "spans", "attribution", "postmortem")
+                "resilience", "spans", "postmortem")
 
     def check(path):
         recs, n_bad = mod.load_records(str(path))

@@ -1,13 +1,13 @@
-"""Span-graph tracer + roofline attribution unit tests (ISSUE 11).
+"""Span-graph tracer unit tests (ISSUE 11), set-up spans (ISSUE 42).
 
 Pure-host coverage of the tentpole's building blocks: deterministic
 trace/span ids and parent links, closed-span stamping, JSONL streaming,
 Chrome-trace export validity, per-trace phase breakdown / critical-path
 aggregation, the Prometheus text exposition (satellite, round-tripped),
 the metric-name drift lint (satellite), the telemetry_report ``spans``
-and ``attribution`` sections, and the TRAINING engine's span points
-(step windows, sentinel fence, checkpoint save/load) plus the train
-step's roofline row.
+section with the set-up spans in it, and the TRAINING engine's span
+points (step windows, sentinel fence, checkpoint save/load) plus the
+phases of its set-up.
 """
 
 import importlib.util
@@ -223,20 +223,23 @@ def test_metric_name_lint_detects_drift(tmp_path):
 
 
 # -------------------------------------------------- report spans section
-def test_report_spans_and_attribution_sections(tmp_path):
+def test_report_spans_section_with_setup_spans(tmp_path):
     path = str(tmp_path / "run.jsonl")
     tr = SpanTracer(time_fn=lambda: 0.0, sink=JsonlSink(path))
     _synthetic_request_trace(tr, 0.0, queue=0.6, prefill=0.1, decode=0.3)
     _synthetic_request_trace(tr, 0.0, queue=0.2, prefill=0.2, decode=0.6)
     _synthetic_request_trace(tr, 0.0, queue=0.2, prefill=0.2, decode=0.6)
-    tr.sink.write({"kind": "attribution", "scope": "serving",
-                   "programs": {"decode": {
-                       "flops": 1e9, "bytes_accessed": 1e8,
-                       "intensity_flops_per_byte": 10.0, "calls": 42,
-                       "mean_wall_ms": 1.5, "achieved_tflops": 0.66,
-                       "attainable_tflops": 1.0,
-                       "achieved_vs_attainable": 0.66,
-                       "bound": "memory"}}})
+    # a start-up and one recompile in service, on the engine's trace
+    engine_trace = tr.new_trace()
+    tr.record("setup_weights", 0.0, 1.5, trace_id=engine_trace)
+    tr.record("setup_cache", 1.5, 1.75, trace_id=engine_trace)
+    warm = tr.record("setup_warmup", 2.0, 5.0, trace_id=engine_trace)
+    for i, (t0, t1) in enumerate(((2.0, 4.0), (4.0, 5.0))):
+        tr.record("warmup_pass", t0, t1, trace_id=engine_trace,
+                  parent_id=warm.span_id, **{"pass": i})
+    for stage, t0, t1 in (("trace", 6.0, 6.25), ("lower", 6.25, 6.5)):
+        tr.record("compile", t0, t1, trace_id=engine_trace,
+                  program="prefill_512", stage=stage)
     tr.sink.close()
     mod = _load_script("telemetry_report")
     records, n_bad = mod.load_records(path)
@@ -248,11 +251,14 @@ def test_report_spans_and_attribution_sections(tmp_path):
     assert spans["queue"]["frac_p50"] == pytest.approx(0.2, abs=1e-6)
     assert spans["queue"]["frac_p95"] == pytest.approx(0.6, abs=1e-6)
     assert spans["decode"]["ms_p95"] == pytest.approx(600.0)
-    att = agg["attribution"]["serving"]
-    assert att["decode"]["achieved_vs_attainable"] == 0.66
+    assert "attribution" not in agg
+    assert spans["setup_ms"] == {
+        "setup_weights": 1500.0, "setup_cache": 250.0,
+        "setup_warmup": 3000.0, "warmup_pass": 3000.0, "compile": 500.0}
+    assert spans["span_counts"]["warmup_pass"] == 2
     rendered = mod.render(agg)
-    assert "spans" in rendered and "attribution (serving)" in rendered
-    assert "decode" in rendered and "memory" in rendered
+    assert "spans" in rendered and "setup_ms" in rendered
+    assert "setup_warmup=3,000" in rendered and "decode" in rendered
 
 
 def test_report_without_spans_keeps_sections_empty(tmp_path):
@@ -264,15 +270,26 @@ def test_report_without_spans_keeps_sections_empty(tmp_path):
     mod = _load_script("telemetry_report")
     records, _ = mod.load_records(str(path))
     agg = mod.aggregate(records)
-    assert agg["spans"] == {} and agg["attribution"] == {}
+    assert agg["spans"] == {} and "attribution" not in agg
 
 
 # --------------------------------------------------- training engine spans
-def test_training_engine_spans_and_attribution(tmp_path):
+def test_training_engine_spans_and_setup_phases(tmp_path, monkeypatch):
     """telemetry.spans arms the training tracer: fence step-windows,
     checkpoint save/load spans (zero extra device syncs — they stamp
     at fences the engine already pays), the spans JSONL stream, and
-    the train step's roofline row."""
+    the phases of set-up as counters, spans and annotations beside
+    ``dstpu/train_step``."""
+    import jax
+
+    annotated = []
+    real_annotation = jax.profiler.TraceAnnotation
+
+    def recording(name, *a, **kw):
+        annotated.append(name)
+        return real_annotation(name, *a, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", recording)
     import deepspeed_tpu
     from deepspeed_tpu import telemetry
     from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
@@ -303,9 +320,13 @@ def test_training_engine_spans_and_attribution(tmp_path):
         engine.train_batch_from_stacked(mb())
     engine.save_checkpoint(str(tmp_path / "ck"))
     engine.load_checkpoint(str(tmp_path / "ck"))
-    att = engine.train_step_attribution()
-    assert att["train_step"]["flops"] > 0
-    assert att["train_step"]["calls"] == 5
+    counters = telemetry.get_registry().snapshot()["counters"]
+    assert counters["entry/setup_weights_ms"] > 0
+    assert counters["entry/setup_first_step_ms"] > 0
+    assert {"dstpu/setup_weights", "dstpu/setup_first_step",
+            "dstpu/train_step"} <= set(annotated)
+    assert annotated.count("dstpu/setup_first_step") == 1
+    assert annotated.count("dstpu/train_step") == 5
     engine.destroy()
     recs = read_jsonl(jsonl)
     names = [r["name"] for r in recs if r["kind"] == "span"]
@@ -317,6 +338,11 @@ def test_training_engine_spans_and_attribution(tmp_path):
     assert all(w["trace"] == wins[0]["trace"] for w in wins)
     # fences at steps 1/2/4 -> windows of 1 + 2 steps before the save
     assert sum(w["attrs"]["steps"] for w in wins) >= 3
-    # attribution record reached the same JSONL
-    assert any(r["kind"] == "attribution" and r.get("scope") == "train"
-               for r in recs)
+    # the set-up phases reached the same JSONL, on the train trace, the
+    # first step closed at the step-1 fence the engine already pays
+    setup = {r["name"]: r for r in recs if r["kind"] == "span"
+             and r["name"].startswith("setup_")}
+    assert set(setup) == {"setup_weights", "setup_first_step"}
+    assert setup["setup_first_step"]["attrs"] == {"fenced": True}
+    assert all(r["trace"] == wins[0]["trace"] and r["dur_ms"] > 0
+               for r in setup.values())
