@@ -28,8 +28,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.accelerator import get_accelerator
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
-from deepspeed_tpu.models.base import recurrent_state_keys, slot_state_keys
-from deepspeed_tpu.ops.attention import extract_slot_row, insert_slot_row
+from deepspeed_tpu.models.base import recurrent_state_keys, row_state_keys, slot_state_keys
+from deepspeed_tpu.ops.attention import extract_slot_row, insert_slot_row, write_slot_rows
 from deepspeed_tpu.runtime.zero.partition import PartitionPlan
 from deepspeed_tpu.telemetry.compile_log import SetupPhase, compile_log
 from deepspeed_tpu.utils import groups as groups_mod
@@ -632,6 +632,18 @@ class InferenceEngine:
                 behind[0], token, slot, 0),)
         return out
 
+    @staticmethod
+    def _last_logits(logits, length):
+        """``[B, V]`` at a prompt block's last real position. A model told
+        the block's true length (``cache["valid_len"]``) may compute its head
+        there alone and hand back ``[B, 1, V]``: at 16,384 positions and
+        32,768 vocabulary rows the other logits are a gigabyte that nothing
+        reads (models/sarvam_mla.py)."""
+        if logits.shape[1] == 1:
+            return logits[:, 0]
+        return jax.lax.dynamic_index_in_dim(logits, length - 1, 1,
+                                            keepdims=False)
+
     def slot_prefill_program(self, bucket_len: int, num_slots: int,
                              max_len: int, *, do_sample: bool = False,
                              top_k: int = 0, top_p: float = 1.0):
@@ -639,7 +651,7 @@ class InferenceEngine:
         runtime (serving/engine.py): run ONE request's bucket-padded
         prompt through a fresh bucket-sized cache, copy the prefix KV
         into slot ``slot`` of the persistent slot-paged cache
-        (ops/attention.write_slot_prefix), set the slot's valid length,
+        (ops/attention.write_slot_rows), set the slot's valid length,
         and pick the first generated token from the logits at the TRUE
         last prompt position (pad tokens behind it are causally
         invisible, so bucket padding cannot change the pick). Slot index
@@ -663,14 +675,13 @@ class InferenceEngine:
         :meth:`slot_decode_program`), and gets it back with the first token
         written at ``slot``: the next decode step can then be launched
         before the host has fetched that token."""
-        from deepspeed_tpu.ops.attention import write_slot_prefix
-
         key = ("slot_pf", bucket_len, num_slots, max_len, do_sample,
                top_k, float(top_p))
         if key not in self._compiled:
             model = self.module
             pick = self._make_pick(do_sample, top_k, float(top_p))
             names = slot_state_keys(model)
+            rows = row_state_keys(model)
 
             def prefill(params, *ops):
                 leaves = ops[:len(names)]
@@ -682,15 +693,15 @@ class InferenceEngine:
                 with jax.named_scope("dstpu_prefill"):
                     logits, cache = model.forward_with_cache(params, ids,
                                                              cache)
-                state["k"], state["v"] = write_slot_prefix(
-                    state["k"], state["v"], cache["k"], cache["v"], slot)
-                for name in recurrent_state_keys(names):
+                for name in rows:
+                    state[name] = write_slot_rows(state[name], cache[name],
+                                                  slot)
+                for name in recurrent_state_keys(names, rows):
                     state[name] = insert_slot_row(state[name], cache[name],
                                                   slot)
                 lengths = jax.lax.dynamic_update_index_in_dim(
                     lengths, length, slot, 0)
-                last = jax.lax.dynamic_index_in_dim(
-                    logits, length - 1, 1, keepdims=False)       # [1, V]
+                last = self._last_logits(logits, length)         # [1, V]
                 return self._with_first_token(
                     (*(state[n] for n in names), lengths),
                     pick(last, temp, rng)[0], slot, behind)
@@ -917,8 +928,7 @@ class InferenceEngine:
                 logits, cache = model.forward_with_cache(params, ids, cache)
                 lengths = jax.lax.dynamic_update_index_in_dim(
                     lengths, start + length, slot, 0)
-                last = jax.lax.dynamic_index_in_dim(
-                    logits, length - 1, 1, keepdims=False)       # [1, V]
+                last = self._last_logits(logits, length)         # [1, V]
                 return (cache["k"], cache["v"], lengths,
                         pick(last, temp, rng)[0])
 
@@ -1107,8 +1117,7 @@ class InferenceEngine:
                                                   slot)
                 lengths = jax.lax.dynamic_update_index_in_dim(
                     lengths, start + length, slot, 0)
-                last = jax.lax.dynamic_index_in_dim(
-                    logits, length - 1, 1, keepdims=False)       # [1, V]
+                last = self._last_logits(logits, length)         # [1, V]
                 return self._with_first_token(
                     (*(state[n] for n in names), lengths),
                     pick(last, temp, rng)[0], slot, behind)

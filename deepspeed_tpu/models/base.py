@@ -253,10 +253,25 @@ def slot_state_keys(model) -> tuple:
     return tuple(getattr(model, "slot_state_keys", ("k", "v")))
 
 
-def recurrent_state_keys(keys) -> tuple:
-    """Of a model's ``slot_state_keys``, those that are not key-value rows:
-    fixed-size state that no token row addresses."""
-    return tuple(k for k in keys if k not in ("k", "v"))
+def row_state_keys(model) -> tuple:
+    """Of a model's ``slot_state_keys``, the leaves that hold TOKEN ROWS: they
+    grow with the request, a prefill writes a prefix of them and a per-slot
+    length says how many are live. ``("k", "v")`` where the model declares
+    none (``[L, B, Hkv, S, Dh]`` key and value rows a head); a model whose
+    cached row is something else names its leaves in ``row_state_keys`` (a
+    latent row all heads share, ``[L, B, S, W]``: models/sarvam_mla.py). The
+    token axis of such a leaf is the one that ``init_cache(1, n)`` sizes by
+    ``n``; a prefix is written at its origin."""
+    keys = slot_state_keys(model)
+    return tuple(getattr(model, "row_state_keys",
+                         tuple(k for k in keys if k in ("k", "v"))))
+
+
+def recurrent_state_keys(keys, rows=("k", "v")) -> tuple:
+    """Of a model's ``slot_state_keys``, those that are not token rows
+    (``rows``: its :func:`row_state_keys`): fixed-size state that no token
+    row addresses."""
+    return tuple(k for k in keys if k not in rows)
 
 
 def layer_view(blocks, i):

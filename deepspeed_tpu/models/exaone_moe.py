@@ -29,17 +29,15 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.models.base import (cache_positions, cross_entropy_loss, gathered_top, merge_heads,
-                                       project_heads, qdot, rms_norm, whole_leaves)
+                                       project_heads, rms_norm, whole_leaves)
+from deepspeed_tpu.models.moe_ffn import (DENSE, EXPERT_LEAVES, SPARSE, STEP_COUNTERS, gated_axes,
+                                          gated_init, record_step_counters)
+from deepspeed_tpu.models.moe_ffn import ffn as ffn_layer
 from deepspeed_tpu.models.stack import cached_walk, kv_cache, next_cache, walk, wrapped_block
-from deepspeed_tpu.moe.grouped import held_experts, sigmoid_topk_route
 from deepspeed_tpu.ops.attention import cached_attention, multihead_attention, window_cached_attention
 from deepspeed_tpu.ops.rotary import apply_rotary_half
 
 SLIDING, GLOBAL = "sliding_attention", "full_attention"
-DENSE, SPARSE = "dense", "sparse"
-# what a step counts on the device, in the order of the vector it returns
-STEP_COUNTERS = ("moe_experts_touched", "moe_experts_streamed",
-                 "moe_assignments_held", "moe_assignments")
 
 
 @dataclasses.dataclass
@@ -161,17 +159,7 @@ class ExaoneMoeModel:
         self.remat = remat
         self.remat_policy = remat_policy
 
-    @staticmethod
-    def record_step_counters(telemetry, counts) -> None:
-        """A decode step's ``step_counters`` vector, fetched with the
-        tokens, into the registry: held experts that got a token and held
-        experts whose weights were read, summed over the sparse layers;
-        (token, expert) pairs routed here and all pairs of the step."""
-        touched, streamed, held, pairs = (int(n) for n in counts)
-        telemetry.counter("serving/moe_experts_touched").inc(touched)
-        telemetry.counter("serving/moe_experts_streamed").inc(streamed)
-        telemetry.counter("serving/moe_assignments_held").inc(held)
-        telemetry.counter("serving/moe_assignments").inc(pairs)
+    record_step_counters = staticmethod(record_step_counters)
 
     # ----------------------------------------------------------------- init
     def init(self, rng):
@@ -203,10 +191,8 @@ class ExaoneMoeModel:
                     "mlp_norm": jnp.ones((l, d))}
 
         def gated(keys, lead, width, prefix):
-            return {prefix + "gate": init(keys[0], lead + (d, width), pd),
-                    prefix + "up": init(keys[1], lead + (d, width), pd),
-                    prefix + "down": init(keys[2], lead + (width, d), pd)
-                    * out_scale}
+            return gated_init(init, keys, lead, d, width, prefix, pd,
+                              out_scale)
 
         k = jax.random.split(rng, 8)
         ld, ls = c.count(DENSE), c.count(SPARSE)
@@ -233,42 +219,15 @@ class ExaoneMoeModel:
                      "wo": ("layer", "heads", "hidden"),
                      "mlp_norm": ("layer", "hidden")}
 
-        def gated(prefix, *lead):
-            return {prefix + "gate": ("layer", *lead, "hidden", "mlp"),
-                    prefix + "up": ("layer", *lead, "hidden", "mlp"),
-                    prefix + "down": ("layer", *lead, "mlp", "hidden")}
-
         return {"embed": ("vocab_in", "hidden"),
-                DENSE: {**attention, **gated("w_")},
+                DENSE: {**attention, **gated_axes("w_")},
                 SPARSE: {**attention, "router": ("layer", "hidden", None),
-                         "select_bias": ("layer", None), **gated("shared_"),
-                         **gated("expert_", "expert")},
+                         "select_bias": ("layer", None),
+                         **gated_axes("shared_"),
+                         **gated_axes("expert_", "expert")},
                 "final_norm": ("hidden",), "lm_head": ("hidden", "vocab")}
 
     # --------------------------------------------------------------- layers
-    def _ffn(self, z, blk, ffn: str, valid):
-        """-> ``(FFN(z), counts [4] int32)``; ``valid [B, T]`` bool or None."""
-        c = self.config
-
-        def gated(prefix):
-            gate = jax.nn.silu(qdot("btd,dm->btm", z, blk[prefix + "gate"]))
-            return qdot("btm,md->btd", gate * qdot("btd,dm->btm", z,
-                                                   blk[prefix + "up"]),
-                        blk[prefix + "down"])
-
-        if ffn == DENSE:
-            return gated("w_"), jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
-        b, t, d = z.shape
-        flat = z.reshape(b * t, d)
-        routing = sigmoid_topk_route(
-            flat, blk["router"], blk["select_bias"], c.num_experts_per_tok,
-            scale=c.routed_scaling_factor, normalize=c.norm_topk_prob)
-        routed, counts = held_experts(
-            flat, routing, blk["expert_gate"], blk["expert_up"],
-            blk["expert_down"], c.held,
-            valid=None if valid is None else valid.reshape(b * t))
-        return gated("shared_") + routed.reshape(b, t, d), jnp.stack(counts)
-
     def _block(self, x, blk, state, layer, idx, valid, walk_, *, ffn: str,
                attn: str, shift: int = 0):
         """One layer -> ``(x, state)``. ``state``: ``None`` (no cache), or
@@ -311,15 +270,14 @@ class ExaoneMoeModel:
                                                active=walk_)
         x = x + merge_heads(out, blk["wo"])
         z = rms_norm(x, blk["mlp_norm"], c.eps)
-        y, n = self._ffn(z, blk, ffn, tokens)
+        y, n = ffn_layer(z, blk, ffn, tokens, c)
         return x + y, (None if state is None else (kc, vc, counts + n))
 
     @staticmethod
     def _stack(params, ffn: str):
         """The stacked layers of one FFN kind as the walk takes them: the
         expert stacks whole, for the grouped matmul to address by group."""
-        return whole_leaves(params[ffn], "expert_gate", "expert_up",
-                            "expert_down")
+        return whole_leaves(params[ffn], *EXPERT_LEAVES)
 
     # -------------------------------------------------------------- forward
     def forward_hidden(self, params, input_ids, *, rngs=None,

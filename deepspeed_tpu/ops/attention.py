@@ -348,32 +348,33 @@ def write_kv_cache(k_full, v_full, k_new, v_new, layer, idx):
             jax.lax.dynamic_index_in_dim(v_full, layer, 0, keepdims=False))
 
 
-def write_slot_prefix(k_full, v_full, k_pref, v_pref, slot):
-    """Insert a prefilled single-sequence prefix cache into slot ``slot``
-    of the persistent slot-paged caches (serving/kv_slots.py).
+def write_slot_rows(full, prefix, slot):
+    """Insert ONE leaf of a prefilled single-sequence prefix cache into slot
+    ``slot`` of the persistent slot-paged cache (serving/kv_slots.py),
+    whatever the leaf's kind: ``prefix [L, 1, ...]`` from a batch-1 bucket
+    prefill into ``full [L, B, ...]`` with ONE dynamic_update_slice at batch
+    position ``slot``, at the origin of every axis behind the slot's.
 
-    k_pref/v_pref: [L, 1, Hkv, T_bucket, Dh] UNPACKED prefix caches from a
-    batch-1 bucket prefill (alloc_kv_cache never packs batch 1).
-    k_full/v_full: [L, B, Hkv, S/pair, Dh*pair] possibly packed persistent
-    caches. The bucket rows are viewed in the persistent pack factor (a
-    free bitcast — requires T_bucket % pair == 0) and written with ONE
-    dynamic_update_slice at batch position ``slot``, row 0. Rows past the
-    request's true length hold pad-token garbage; the per-slot length
-    vector masks them until the decode loop overwrites them one by one."""
-    l, one, hkv, t_b, dh = k_pref.shape
-    assert one == 1, "slot insert takes a single-sequence prefix cache"
-    pair = k_full.shape[4] // dh
+    A leaf whose persistent form packs token pairs into its minor dimension
+    (``k``, ``v`` at ``Dh < 128``: ``[L, B, Hkv, S / pair, Dh * pair]``;
+    alloc_kv_cache never packs batch 1, so the prefix comes unpacked) gets
+    the bucket rows viewed in the persistent pack factor (a free bitcast,
+    requires ``T_bucket % pair == 0``); any other (a latent row ``[L, B, S,
+    W]``) is written as it is. Rows past the request's true length hold
+    pad-token garbage; the per-slot length vector masks them until the decode
+    loop overwrites them one by one."""
+    assert prefix.shape[1] == 1 and prefix.ndim == full.ndim, \
+        (prefix.shape, full.shape)
+    pair = full.shape[-1] // prefix.shape[-1]
     if pair > 1:
+        t_b = prefix.shape[-2]
         assert t_b % pair == 0, (t_b, pair)
-        k_pref = k_pref.reshape(l, 1, hkv, t_b // pair, dh * pair)
-        v_pref = v_pref.reshape(l, 1, hkv, t_b // pair, dh * pair)
-    slot = jnp.asarray(slot, jnp.int32)
+        prefix = prefix.reshape(prefix.shape[:-2]
+                                + (t_b // pair, prefix.shape[-1] * pair))
     zero = jnp.zeros((), jnp.int32)
-    k_full = jax.lax.dynamic_update_slice(
-        k_full, k_pref.astype(k_full.dtype), (zero, slot, zero, zero, zero))
-    v_full = jax.lax.dynamic_update_slice(
-        v_full, v_pref.astype(v_full.dtype), (zero, slot, zero, zero, zero))
-    return k_full, v_full
+    starts = (zero, jnp.asarray(slot, jnp.int32)) + (zero,) * (full.ndim - 2)
+    return jax.lax.dynamic_update_slice(full, prefix.astype(full.dtype),
+                                        starts)
 
 
 def extract_slot_row(full, slot):

@@ -7,6 +7,8 @@ the surrounding projections, so the jnp form IS the fast path.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -60,7 +62,49 @@ def apply_rotary_half(x: jax.Array, positions: jax.Array, theta: float
     batching), in float32."""
     dh = x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh))
-    ang = positions.astype(jnp.float32)[..., None] * inv      # [.., T, Dh/2]
+    return apply_rotary_half_freqs(x, positions, inv)
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float,
+                  original_max: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0):
+    """YaRN's ``[dim / 2]`` inverse frequencies (DeepSeek's ``deepseek_yarn``):
+    a pair that turns more than ``beta_fast`` times over the ``original_max``
+    trained positions keeps its frequency ``f_i = theta ** (-2 i / dim)``, one
+    that turns less than ``beta_slow`` times is slowed by ``factor``, and a
+    linear ramp blends the pairs between.
+
+        dim(n) = dim ln(original_max / (2 pi n)) / (2 ln theta)
+        low = floor(dim(beta_fast)), high = ceil(dim(beta_slow)), in [0, dim/2 - 1]
+        r_i = clip((i - low) / (high - low), 0, 1)
+        inv_freq_i = f_i (1 - r_i) + f_i / factor r_i"""
+    half = dim // 2
+
+    def turns_at(n):
+        return dim * math.log(original_max / (2 * math.pi * n)) / \
+            (2 * math.log(theta))
+
+    low = max(math.floor(turns_at(beta_fast)), 0)
+    high = min(math.ceil(turns_at(beta_slow)), half - 1)
+    span = (high - low) or 0.001
+    i = jnp.arange(half, dtype=jnp.float32)
+    f = theta ** (-2.0 * i / dim)
+    r = jnp.clip((i - low) / span, 0.0, 1.0)
+    return f * (1.0 - r) + f / factor * r
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """``0.1 mscale ln(factor) + 1`` (1 where ``factor <= 1``): with
+    ``mscale_all_dim`` it multiplies the softmax scale twice over."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rotary_half_freqs(x: jax.Array, positions: jax.Array,
+                            inv_freq: jax.Array) -> jax.Array:
+    """:func:`apply_rotary_half` at given inverse frequencies ``[Dh / 2]``
+    (:func:`yarn_inv_freq`): ``x [B, T, H, Dh]`` at ``positions`` (``[T]`` or
+    ``[B, T]``), rotate-half, in float32."""
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq   # [.., T, Dh/2]
     ang = jnp.concatenate([ang, ang], axis=-1)
     ang = ang[None, :, None] if ang.ndim == 2 else ang[:, :, None]
     x32 = x.astype(jnp.float32)

@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from deepspeed_tpu.models.base import recurrent_state_keys, slot_state_keys
+from deepspeed_tpu.models.base import recurrent_state_keys, row_state_keys, slot_state_keys
 from deepspeed_tpu.ops.decode_step import decode_rows_fetched
 from deepspeed_tpu.serving.errors import (EmptyPromptError,
                                           EngineConfigError,
@@ -426,21 +426,30 @@ class ServingEngine:
             raise EngineConfigError(
                 f"serving max_len {max_len} exceeds the model's max_seq_len "
                 f"{model_max} (position table size)")
-        recurrent = recurrent_state_keys(slot_state_keys(model))
-        if recurrent:
+        rows = row_state_keys(model)
+        recurrent = recurrent_state_keys(slot_state_keys(model), rows)
+        latent = tuple(k for k in rows if k not in ("k", "v"))
+        if recurrent or latent:
             # state with no token rows: nothing below can share a prefix of
             # it, quantize its pool blocks, roll a rejected draft back out
-            # of it or (yet) park it on the host
+            # of it or (yet) park it on the host. Token rows that are no
+            # head's keys or values: the block pool, the verify step and the
+            # swap programs address ``k`` / ``v`` pairs and do not know them
+            name = type(model).__name__
+            what = (f"token rows, and {name} keeps state {list(recurrent)} "
+                    f"that has none (recurrent state, or a sliding window's "
+                    f"ring)") if recurrent else (
+                f"rows of k / v pairs, and {name} keeps its token rows in "
+                f"{list(latent)} (one latent row a token that all heads "
+                f"share): the block pool, the verify step and the swap "
+                f"programs do not hold such a leaf yet")
             for option, value in (("prefix_cache", prefix_cache),
                                   ("kv_dtype", kv_dtype),
                                   ("speculative", speculative),
                                   ("preemption", preemption)):
                 if value:
                     raise EngineConfigError(
-                        f"{option}={value!r} addresses the cache by token "
-                        f"rows, and {type(model).__name__} keeps state "
-                        f"{list(recurrent)} that has none (recurrent state, "
-                        f"or a sliding window's ring)")
+                        f"{option}={value!r} addresses the cache by {what}")
         self.kv_dtype = normalize_kv_dtype(kv_dtype)
         if self.kv_dtype is not None and not prefix_cache:
             raise EngineConfigError(

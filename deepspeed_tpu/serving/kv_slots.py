@@ -4,7 +4,10 @@ The vLLM PagedAttention idea specialized to XLA's static-shape world: a
 persistent per-slot state TREE whose BATCH dimension is the page table. The
 model declares the tree (``model.init_cache``; the leaves' names and operand
 order are its ``slot_state_keys``, ``("k", "v")`` where it declares none).
-Two kinds of state can live in it side by side:
+Three kinds of state can live in it side by side; a model says which of its
+leaves hold token rows (``row_state_keys``, models/base.py; ``("k", "v")``
+where it declares none) and which are rings (``window_state_keys``), and
+every other leaf is recurrent:
 
   * **key-value rows** ``k``, ``v``: ``[L, B_slots, Hkv, S_max/pair,
     Dh*pair]`` stacked caches (ops/attention.alloc_kv_cache layout —
@@ -16,6 +19,19 @@ Two kinds of state can live in it side by side:
     bucket padding lands there (and, on the einsum path, an inactive slot's
     write) and is overwritten; a freed slot keeps its stale length until
     the next prefill resets it.
+  * **latent rows** (a leaf the model names in ``row_state_keys`` that is
+    neither ``k`` nor ``v``; models/sarvam_mla.py's ``latent`` ``[L, B_slots,
+    S_max, W]``): ONE row a token a layer that all heads share, the
+    compressed key-value latent with the rotated key behind it. Like
+    key-value rows it grows with the request, is addressed by token rows, is
+    written as a prefix by prefill and a row a step by decode, and rows
+    behind a slot's length are dead; unlike them it is no key or value of a
+    head, so what addresses the cache by rows of ``k`` / ``v`` PAIRS (prefix
+    reuse in the block pool, speculative verify, swap, ``kv_dtype``) is
+    refused for it at construction (serving/engine.py; ROADMAP R2 (c)). Its
+    decode walk is the model's own fused step (ops/mla_decode_step.py), and
+    whether this allocation routes to it is asked of the model
+    (``fused_row_walk``).
   * **recurrent state** (every other leaf, ``[L', B_slots, ...]``; a
     state-space model's ``ssm`` and ``conv``): fixed size, no rows, nothing
     to hide a write behind. Prefill writes it at the request's true length,
@@ -34,7 +50,7 @@ Two kinds of state can live in it side by side:
 
 A finished request's slot is reused by the next admission with ZERO cache
 reshaping — the prefill program overwrites the slot's prefix rows
-(ops/attention.write_slot_prefix) and its whole recurrent row, and resets
+(ops/attention.write_slot_rows) and its whole recurrent row, and resets
 its length.
 
 Memory model: the tree is allocated ONCE at serving-engine construction
@@ -54,7 +70,7 @@ from typing import Tuple
 
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import recurrent_state_keys, slot_state_keys
+from deepspeed_tpu.models.base import recurrent_state_keys, row_state_keys, slot_state_keys
 from deepspeed_tpu.ops.attention import kv_pack_factor
 from deepspeed_tpu.ops.decode_step import supports
 from deepspeed_tpu.serving.errors import EngineConfigError
@@ -64,7 +80,7 @@ class SlotKVCache:
     """Owns the persistent per-slot state tree + per-slot lengths.
 
     The leaves are exposed (``state``, ``lengths``; ``k`` and ``v`` by
-    name) so the jitted serving programs can take them as (donated)
+    name where the model has them) so the jitted serving programs can take them as (donated)
     operands; after every program call the engine stores the returned
     arrays back via :meth:`update` — the host never mutates them in place.
     """
@@ -78,28 +94,36 @@ class SlotKVCache:
         self.lengths = jnp.zeros((num_slots,), jnp.int32)
         self.num_slots = num_slots
         self.max_len = max_len
-        # pack factor the persistent allocation chose (routes the decode
-        # path — see ops/attention.alloc_kv_cache)
-        head_dim = model.config.head_dim
-        self.pair = self.k.shape[4] // head_dim
+        self.row_keys = row_state_keys(model)
+        # key-value rows: the pack factor the persistent allocation chose
+        # (routes the decode path, see ops/attention.alloc_kv_cache), and
         # whether that allocation is one the fused decode step streams on a
         # TPU (the shapes' part of ops/attention.cached_attention's route):
-        # then a decode step fetches what ops/decode_step's walk fetches
+        # then a decode step fetches what ops/decode_step's walk fetches. A
+        # model with row leaves of its own has no pack factor and says
+        # itself whether its fused step walks this allocation
+        self.window_layers = self.window = 0
+        self.fused_window_walk = False
+        if "k" not in self.state:
+            self.pair = 1
+            self.fused_walk = bool(model.fused_row_walk(self.state, num_slots))
+            return
+        head_dim = model.config.head_dim
         hkv = self.k.shape[2]
-        self.fused_walk = (num_slots >= 2
-                           and self.pair == kv_pack_factor(head_dim)
-                           and supports(hkv, hkv, self.k.shape[3] * self.pair,
-                                        head_dim))
+        self.pair = self.k.shape[4] // head_dim
+        self.fused_walk = (
+            num_slots >= 2 and self.pair == kv_pack_factor(head_dim)
+            and supports(hkv, hkv, self.k.shape[3] * self.pair, head_dim))
         # ring leaves (a sliding-window layer's last ``window`` positions):
         # their layers, the window, and whether the fused step walks them
         # too (ops/attention.window_cached_attention's route)
         ring = next((self.state[n] for n in
                      getattr(model, "window_state_keys", ())), None)
-        self.window_layers, self.window = \
-            (0, 0) if ring is None else (ring.shape[0], ring.shape[3])
-        self.fused_window_walk = (
-            ring is not None and num_slots >= 2 and head_dim % 128 == 0
-            and supports(hkv, hkv, self.window, head_dim))
+        if ring is not None:
+            self.window_layers, self.window = ring.shape[0], ring.shape[3]
+            self.fused_window_walk = (
+                num_slots >= 2 and head_dim % 128 == 0
+                and supports(hkv, hkv, self.window, head_dim))
 
     @property
     def k(self):
@@ -111,8 +135,8 @@ class SlotKVCache:
 
     @property
     def recurrent_keys(self) -> Tuple[str, ...]:
-        """Leaves that are not key-value rows: state with no token rows."""
-        return recurrent_state_keys(self.keys)
+        """Leaves that are not token rows: state that no row addresses."""
+        return recurrent_state_keys(self.keys, self.row_keys)
 
     # ------------------------------------------------------------- carry
     def carry(self) -> Tuple:

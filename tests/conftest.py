@@ -370,7 +370,11 @@ _LATE_MODULES = _OBSERVABILITY_MODULES + (
     # PR 41: whole decode steps compiled for the described v5e at the
     # cells' widths (8 to 16 s each, on every core): in directory order
     # unit/ops follows unit/model_parallelism
-    "unit/ops/test_tpu_compile",)
+    "unit/ops/test_tpu_compile",
+    # PR 46: the latent-attention family's modules (96 s and 32 s of
+    # compiles), kept away from that rendezvous as the EXAONE-MoE ones are
+    "unit/inference/test_sarvam_mla",
+    "unit/benchmarks/test_sarvam_mla",)
 
 # Dead-last group, AFTER even the torch modules: pure-AST, device-free
 # suites (the dstpu-lint/prove analysis tests never launch a collective,

@@ -275,5 +275,8 @@ def test_the_kernel_has_a_stable_name_of_its_own():
                 assert len(found) == 1 and isinstance(found[0], ast.Constant)
                 names.setdefault(found[0].value, []).append(fname)
     assert names["dstpu_ssm_update"] == ["ssm.py"]
-    assert all(len(files) == 1 for files in names.values()) and len(names) == 10
+    # counted from below: a later kernel adds a name (PR 46:
+    # ``dstpu_mla_decode_step``, the eleventh) and edits nothing here
+    assert all(len(files) == 1 for files in names.values()) and len(names) >= 11
+    assert names["dstpu_mla_decode_step"] == ["mla_decode_step.py"]
     assert all(re.match(r"^dstpu_[a-z0-9_]+$", n) for n in names)

@@ -152,6 +152,28 @@ def test_fused_decode_step_head_128_rep_8_and_ring(one_chip, dims):
     _compiled_text(fn, *ops, _sds(one_chip, (dims[1],), jnp.bool_))
 
 
+def test_fused_mla_decode_step(one_chip):
+    """The absorbed latent-attention step at the Sarvam-105B cell's shapes:
+    five layers, 16 slots x 16,384 rows of 640 lanes (512 + 64, padded), 64
+    heads; the walk order made outside the kernel, as the decode program
+    calls it."""
+    from deepspeed_tpu.ops.decode_step import slot_walk
+    from deepspeed_tpu.ops.mla_decode_step import fused_mla_decode_step
+
+    l, b, s, w, h = 5, 16, 16384, 640, 64
+
+    def fn(q, latent, row, layer, idx, active):
+        return fused_mla_decode_step(
+            q, latent, row, layer, idx, value_width=512, scale=0.1,
+            active=slot_walk(idx, active), interpret=False)
+
+    text = _compiled_text(
+        fn, _sds(one_chip, (b, h, w)), _sds(one_chip, (l, b, s, w)),
+        _sds(one_chip, (b, w)), _sds(one_chip, (), jnp.int32),
+        _sds(one_chip, (b,), jnp.int32), _sds(one_chip, (b,), jnp.bool_))
+    assert "dstpu_mla_decode_step" in text
+
+
 @pytest.mark.parametrize("tokens", [32, 4096], ids=["decode", "prefill"])
 def test_held_experts_grouped_matmul(one_chip, tokens):
     """The expert layer's grouped matmuls (``jax.lax.ragged_dot``, XLA's own
@@ -403,6 +425,16 @@ def _gpt2_large_cell():
                                 num_heads=20)), 32, 1024
 
 
+def _sarvam_cell():
+    from deepspeed_tpu.models.sarvam_mla import (SarvamMlaConfig,
+                                                 SarvamMlaModel)
+
+    # the cell's dense layer and two of its four sparse ones (a loop run)
+    return SarvamMlaModel(SarvamMlaConfig(
+        vocab_size=32768, max_seq_len=16384, num_layers=3,
+        held=(0, 16))), 16, 16384
+
+
 def _weights(model, sharding):
     """The model's parameters as the serving engine holds them: bf16, as
     shapes on the described chip; and one layer's sizes of every stacked
@@ -422,9 +454,9 @@ def _assert_copies_no_weight(compiled, leaves):
 
 
 @pytest.mark.parametrize("cell", [_exaone_cell, _granite_cell,
-                                  _gpt2_large_cell],
+                                  _gpt2_large_cell, _sarvam_cell],
                          ids=["k-exaone", "granite-4.0-h-micro",
-                              "gpt2-large"])
+                              "gpt2-large", "sarvam-105b"])
 def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
     """The serve cells' decode step (``InferenceEngine.slot_decode_program``'s
     call of the model: one token a slot, per-slot lengths, the slot walk) at

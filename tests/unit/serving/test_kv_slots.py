@@ -7,7 +7,7 @@ import pytest
 
 from deepspeed_tpu.models.base import cache_positions
 from deepspeed_tpu.ops.attention import (alloc_kv_cache, decode_attention,
-                                         write_kv_cache, write_slot_prefix)
+                                         write_kv_cache, write_slot_rows)
 from deepspeed_tpu.serving.kv_slots import SlotKVCache
 
 pytestmark = [pytest.mark.serving, pytest.mark.quick]
@@ -115,7 +115,7 @@ def test_per_slot_decode_attention_matches_per_row_scalar():
 
 
 @pytest.mark.parametrize("pair_packed", [False, True])
-def test_write_slot_prefix(pair_packed):
+def test_write_slot_rows(pair_packed):
     """Bucket-prefix insert lands in exactly the target slot's leading
     rows, packed or unpacked, and touches nothing else."""
     rng = np.random.RandomState(2)
@@ -128,7 +128,8 @@ def test_write_slot_prefix(pair_packed):
     vf = kf + 1.0
     kp = jnp.asarray(rng.randn(l, 1, h, bucket, dh), jnp.float32)
     vp = jnp.asarray(rng.randn(l, 1, h, bucket, dh), jnp.float32)
-    k2, v2 = write_slot_prefix(kf, vf, kp, vp, jnp.int32(1))
+    k2, v2 = (write_slot_rows(full, prefix, jnp.int32(1))
+              for full, prefix in ((kf, kp), (vf, vp)))
     ku = np.asarray(k2).reshape(l, slots, h, s, dh)
     vu = np.asarray(v2).reshape(l, slots, h, s, dh)
     np.testing.assert_array_equal(ku[:, 1, :, :bucket], np.asarray(kp)[:, 0])
