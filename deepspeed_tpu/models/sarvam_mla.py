@@ -25,8 +25,10 @@ and the serving layer handles it by that declaration (serving/kv_slots.py).
 DECOMPRESSED form: a block of keys' ``k_nope`` and ``v`` are computed from
 their latents and attended at head sizes ``nope + rope`` / ``v``, key blocks
 walked up to the diagonal with a running softmax, so no score matrix over the
-context exists (:meth:`_prompt_attention`, scope ``dstpu_mla_prefill``; XLA's
-own matmuls in a ``lax`` loop). One token takes the ABSORBED form,
+context exists (:meth:`_prompt_attention`, scope ``dstpu_mla_prefill``): on a
+TPU ops/mla_prefill.py's one call a layer, which keeps a key block's scores in
+VMEM, elsewhere XLA's own matmuls in a ``lax`` loop. One token takes the
+ABSORBED form,
 
     q^_h = q_nope_h W_UK[h]^T;  score_h(j) = s (q^_h . c~(j) + q_rope_h . k_r(j))
     u_h = sum_j p_h(j) c~(j);   o_h = u_h W_UV[h]
@@ -59,6 +61,7 @@ from deepspeed_tpu.models.moe_ffn import (DENSE, EXPERT_LEAVES, SPARSE, STEP_COU
                                           gated_init, record_step_counters)
 from deepspeed_tpu.models.stack import cached_walk, next_cache, walk, wrapped_block
 from deepspeed_tpu.ops.attention import multihead_attention
+from deepspeed_tpu.ops import mla_prefill
 from deepspeed_tpu.ops.mla_decode_step import count_form, fused_mla_decode_step, supports
 from deepspeed_tpu.ops.rotary import apply_rotary_half_freqs, yarn_inv_freq, yarn_mscale
 
@@ -253,22 +256,52 @@ class SarvamMlaModel:
         return (q[..., :n], apply_rotary_half_freqs(q[..., n:], pos, inv),
                 lat, k_r)
 
+    def _wkv_b(self, blk):
+        """The layer's ``Wkv_b [r, H * (nope + v)]``. The walk hands the
+        stack whole (:meth:`_stack`), so that the prompt kernel fetches a
+        head's columns where they lie; every other consumer is a matmul that
+        reads the layer's slice in place."""
+        w = blk["wkv_b"]
+        if isinstance(w, dict):
+            w = jax.lax.dynamic_index_in_dim(w["__whole__"], w["__layer__"],
+                                             0, keepdims=False)
+        return w.astype(self.compute_dtype)
+
     def _up_projection(self, blk):
         """``Wkv_b`` as ``[r, H, nope + v]``: head ``h``'s ``W_UK`` are its
         first ``nope`` columns, ``W_UV`` the ``v`` behind them."""
         c = self.config
-        return blk["wkv_b"].astype(self.compute_dtype).reshape(
+        return self._wkv_b(blk).reshape(
             c.kv_lora_rank, c.num_heads, c.qk_nope_head_dim + c.v_head_dim)
 
-    def _prompt_attention(self, q_nope, q_rope, latent, layer, q_pos, blk):
+    def _prompt_attention(self, q_nope, q_rope, latent, layer, q_pos, blk,
+                          valid=None):
         """The decompressed form over the cache's rows, a block of keys at a
         time up to the diagonal, running softmax: ``q_* [B, T, H, .]`` at
-        positions ``q_pos [B, T]`` against ``latent[layer]``'s rows, which
-        already hold the block's own -> ``[B, T, H, v]``."""
+        the consecutive positions ``q_pos [B, T]``, of which the first
+        ``valid [B]`` are real (``None``: all), against ``latent[layer]``'s
+        rows, which already hold the block's own -> ``[B, T, H, v]``. On a
+        TPU ops/mla_prefill.py's one call where the shapes fit (the rows of a
+        query tile with no real position then come back as zeros: nothing
+        real attends them), elsewhere XLA's own matmuls in a ``lax`` loop."""
         c = self.config
         b, t, h, n = q_nope.shape
         r, rope, vd = c.kv_lora_rank, c.qk_rope_head_dim, c.v_head_dim
         s_max, w = latent.shape[2], latent.shape[3]
+        if jax.default_backend() == "tpu" and mla_prefill.supports(
+                s_max, w, c.key_block, t):
+            mla_prefill.count_traced()
+            wkv_b, w_layer = blk["wkv_b"], None
+            if isinstance(wkv_b, dict) and \
+                    wkv_b["__whole__"].dtype == latent.dtype:
+                wkv_b, w_layer = wkv_b["__whole__"], wkv_b["__layer__"]
+            else:                     # a cast is a copy: of the layer alone
+                wkv_b = self._wkv_b(blk).astype(latent.dtype)
+            with jax.named_scope("dstpu_mla_prefill"):
+                return mla_prefill.mla_prefill(
+                    q_nope, q_rope, latent, wkv_b, layer, q_pos[:, 0], valid,
+                    latent_width=r, scale=c.score_scale,
+                    key_block=c.key_block, w_layer=w_layer)
         bk = c.key_block if s_max % c.key_block == 0 else s_max
         f32 = jnp.float32
         up = self._up_projection(blk)                     # [r, H, n + v]
@@ -374,7 +407,7 @@ class SarvamMlaModel:
         pos = cache_positions(0 if idx is None else idx, t)
         q_nope, q_rope, lat, k_r = self._projections(x, blk, pos)
         if state is None:
-            kv = project_heads(lat, blk["wkv_b"], c.num_heads,
+            kv = project_heads(lat, self._wkv_b(blk), c.num_heads,
                                c.qk_nope_head_dim + c.v_head_dim)
             keys = jnp.concatenate(
                 [kv[..., :c.qk_nope_head_dim],
@@ -398,7 +431,7 @@ class SarvamMlaModel:
                 latent = self._write_rows(latent, row, at, idx)
                 out = self._prompt_attention(
                     q_nope, q_rope, latent, at,
-                    jnp.broadcast_to(pos, (b, t)), blk)
+                    jnp.broadcast_to(pos, (b, t)), blk, valid)
         x = x + merge_heads(out, blk["wo"])
         z = rms_norm(x, blk["mlp_norm"], c.eps)
         tokens = None if valid is None else \
@@ -409,8 +442,11 @@ class SarvamMlaModel:
     @staticmethod
     def _stack(params, kind: str):
         """The stacked layers of one FFN kind as the walk takes them: the
-        expert stacks whole, for the grouped matmul to address by group."""
-        return whole_leaves(params[kind], *EXPERT_LEAVES)
+        expert stacks whole, for the grouped matmul to address by group, and
+        ``wkv_b`` whole, for the prompt kernel to address by layer and head
+        (a layer's slice as a kernel's operand is written out: 16.8 MB a
+        layer a token block)."""
+        return whole_leaves(params[kind], *EXPERT_LEAVES, "wkv_b")
 
     # -------------------------------------------------------------- forward
     def forward_hidden(self, params, input_ids, *, rngs=None,

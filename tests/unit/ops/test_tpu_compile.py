@@ -174,6 +174,29 @@ def test_fused_mla_decode_step(one_chip):
     assert "dstpu_mla_decode_step" in text
 
 
+def test_mla_prefill_kernel(one_chip):
+    """The decompressed prompt attention at the Sarvam-105B cell's shapes: a
+    token block of 2,048 at 64 heads of 128 + 64 / 128 against 16,384 cached
+    rows of 640 lanes in key blocks of 512, ``wkv_b`` as the layer-stacked
+    leaf it is fetched from; the first position, the valid length and the
+    layers traced, as the prefill's scan over token blocks calls it."""
+    from deepspeed_tpu.ops.mla_prefill import mla_prefill
+
+    l, b, s, w, h, t = 5, 1, 16384, 640, 64, 2048
+
+    def fn(q_nope, q_rope, latent, wkv_b, layer, first, valid, w_layer):
+        return mla_prefill(q_nope, q_rope, latent, wkv_b, layer, first, valid,
+                           latent_width=512, scale=0.1, key_block=512,
+                           w_layer=w_layer, interpret=False)
+
+    scalar = _sds(one_chip, (), jnp.int32)
+    text = _compiled_text(
+        fn, _sds(one_chip, (b, t, h, 128)), _sds(one_chip, (b, t, h, 64)),
+        _sds(one_chip, (l, b, s, w)), _sds(one_chip, (4, 512, h * 256)),
+        scalar, scalar, scalar, scalar)
+    assert "dstpu_mla_prefill" in text
+
+
 @pytest.mark.parametrize("tokens", [32, 4096], ids=["decode", "prefill"])
 def test_held_experts_grouped_matmul(one_chip, tokens):
     """The expert layer's grouped matmuls (``jax.lax.ragged_dot``, XLA's own
@@ -547,6 +570,47 @@ def test_prefill_copies_no_weight(one_chip, fused_routes):
         params, _sds(one_chip, (1, 256), jnp.int32),
         _sds(one_chip, (), jnp.int32)).compile()
     _assert_copies_no_weight(compiled, leaves)
+
+
+def test_sarvam_prefill_keeps_scores_on_chip(one_chip, fused_routes):
+    """Sarvam-105B's 4,096-bucket prefill (``slot_prefill_program``'s call of
+    the model: two token blocks of 2,048 through the stack, a cache of its
+    own, the true length): the prompt attention is the one kernel a layer
+    run, no weight is copied (``wkv_b`` reaches the kernel as the stack it
+    lies in: as a layer's slice it was written out, 16.8 MB a layer a token
+    block) and no float32 buffer of a score block's size ``[64, 2048, 512]``
+    is a temporary of the program. The ``lax`` loop wrote three such buffers a
+    key block and read them back: 0.84 s of a busy 2.22 s (PERF.md, PR 47)."""
+    model, _, _ = _sarvam_cell()
+    params, leaves = _weights(model, one_chip)
+    # the expert layer's sorted rows, prompt_block x 8 a token block, are
+    # [16384, 4096] like the dense layer's w_down, and are gathered
+    leaves = leaves - {("bf16", (4096, 16384))}
+
+    def prefill(params, ids, length):
+        cache = model.init_cache(1, 4096, dtype=BF16)
+        cache["valid_len"] = length
+        logits, cache = model.forward_with_cache(params, ids, cache)
+        return logits, cache
+
+    compiled = jax.jit(prefill).lower(
+        params, _sds(one_chip, (1, 4096), jnp.int32),
+        _sds(one_chip, (), jnp.int32)).compile()
+    text = compiled.as_text()
+    copies = _weight_sized_copies(text, leaves)
+    assert not copies, "\n".join(copies)
+    found, _ = _outside_fusions(text)
+    kernels = [line for _, _, opcode, line in found
+               if opcode == "custom-call" and "dstpu_mla_prefill" in line]
+    assert len(kernels) == 2, kernels       # the dense run's and the sparse
+    # a key block's scores, and the loop's float32 accumulator of all heads
+    walked = {(64, 512, 2048), (64, 128, 2048)}
+    big = [line.strip()[:200] for _, kind, _, line in found
+           for dims in re.findall(r"f32\[([\d,]*)\]", kind)
+           if _sizes(dims.split(",")) in walked]
+    assert not big, "\n".join(big)
+    # 643 MB here; the loop's program 694 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 680 * 2 ** 20
 
 
 @pytest.fixture
