@@ -739,3 +739,49 @@ def _dense_decode_attention(q, k_cache, v_cache, cache_index, *, scale, bias,
     probs = jax.nn.softmax(logits, axis=-1).astype(v_cache.dtype)
     out = jnp.einsum("bkrts,bksd->btkrd", probs, v_cache)
     return out.reshape(b, t, hq, dh)
+
+
+def blocked_prompt_attention(q, k_layer, v_layer, q_pos, *, scale=None,
+                             key_block: int = 512):
+    """A prompt block's grouped-query attention against a cache that already
+    holds its rows, a block of ``key_block`` keys at a time up to the
+    diagonal with a running softmax: no score matrix over the context exists
+    (64 heads x 2,048 queries x 16,384 keys in float32 are 8.6 GB; the query
+    blocks of :func:`decode_attention` compute every key of the allocation,
+    the masked half too).
+
+    ``q [B, T, Hq, Dh]`` at the consecutive positions ``q_pos [B, T]``;
+    ``k_layer``, ``v_layer [B, Hkv, S, Dh]`` (unpacked rows, ``S`` a whole
+    number of key blocks) -> ``[B, T, Hq, Dh]`` in ``q``'s dtype. Key blocks
+    past the last query's are not visited; XLA's own matmuls."""
+    b, t, hq, dh = q.shape
+    hkv, s_max = k_layer.shape[1], k_layer.shape[2]
+    rep, bk = hq // hkv, key_block
+    assert s_max % bk == 0, (s_max, bk)
+    scale = scale if scale is not None else dh ** -0.5
+    f32 = jnp.float32
+    qg = q.reshape(b, t, hkv, rep, dh)
+    blocks = jnp.minimum((jnp.max(q_pos) + bk) // bk, s_max // bk)
+
+    def body(kb, carry):
+        m, l, acc = carry
+        keys = jax.lax.dynamic_slice_in_dim(k_layer, kb * bk, bk, 2)
+        vals = jax.lax.dynamic_slice_in_dim(v_layer, kb * bk, bk, 2)
+        s = jnp.einsum("btkrd,bksd->bkrts", qg, keys,
+                       preferred_element_type=f32) * scale
+        live = (kb * bk + jnp.arange(bk))[None, None, :] <= q_pos[:, :, None]
+        s = jnp.where(live[:, None, None], s, -jnp.inf)
+        m_new = jnp.maximum(m, s.max(-1))
+        corr = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new[..., None])
+        pv = jnp.einsum("bkrts,bksd->bkrtd", p.astype(vals.dtype), vals,
+                        preferred_element_type=f32)
+        return m_new, l * corr + p.sum(-1), acc * corr[..., None] + pv
+
+    # every query sees key 0, so the first block leaves a finite maximum
+    init = (jnp.full((b, hkv, rep, t), -jnp.inf, f32),
+            jnp.zeros((b, hkv, rep, t), f32),
+            jnp.zeros((b, hkv, rep, t, dh), f32))
+    _, l, acc = jax.lax.fori_loop(0, blocks, body, init)
+    out = (acc / l[..., None]).astype(q.dtype)           # [B, Hkv, rep, T, Dh]
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, hq, dh)

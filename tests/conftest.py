@@ -374,7 +374,11 @@ _LATE_MODULES = _OBSERVABILITY_MODULES + (
     # PR 46: the latent-attention family's modules (96 s and 32 s of
     # compiles), kept away from that rendezvous as the EXAONE-MoE ones are
     "unit/inference/test_sarvam_mla",
-    "unit/benchmarks/test_sarvam_mla",)
+    "unit/benchmarks/test_sarvam_mla",
+    # PR 48: the delta-rule family's modules (125 s and 35 s of compiles),
+    # kept away from that rendezvous as the two families' above are
+    "unit/inference/test_solar_kda",
+    "unit/benchmarks/test_solar_kda",)
 
 # Dead-last group, AFTER even the torch modules: pure-AST, device-free
 # suites (the dstpu-lint/prove analysis tests never launch a collective,

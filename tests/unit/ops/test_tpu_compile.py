@@ -197,6 +197,38 @@ def test_mla_prefill_kernel(one_chip):
     assert "dstpu_mla_prefill" in text
 
 
+def test_kda_update_kernel(one_chip):
+    """``dstpu_kda_update`` at the Solar-Open2 cell's shapes: 16 slots, three
+    delta-rule layers of 64 heads of 128 x 128 float32 state, four taps; a
+    grid cell 16 heads of an active slot, the state and the tails in place
+    (no temporary of the state's or the tails' size)."""
+    from deepspeed_tpu.ops import kda
+    from deepspeed_tpu.ops.ssm import slot_order
+
+    b, l, h, d, taps = 16, 3, 64, 128, 4
+    f32 = jnp.float32
+
+    def fn(qkv, g_pre, beta, gate, state, tail, conv_w, a_log, dt_bias,
+           o_norm, layer, active):
+        weights = kda.fold_weights({"conv_w": conv_w, "A_log": a_log,
+                                    "dt_bias": dt_bias, "o_norm": o_norm}, h)
+        return kda.kda_step(qkv, g_pre, beta, gate, state, tail, layer,
+                            weights, slot_order(active), active, eps=1e-5,
+                            interpret=False)
+
+    sds = functools.partial(_sds, one_chip)
+    compiled = jax.jit(fn, donate_argnums=(4, 5)).lower(
+        sds((b, 3 * h * d)), sds((b, h * d)), sds((b, h), f32),
+        sds((b, h * d)), sds((l, b, h, d, d), f32),
+        sds((l, b) + kda.tail_shape(taps, h, d)),
+        sds((l, taps, 3 * h * d), f32), sds((l, h), f32),
+        sds((l, h * d), f32), sds((l, d), f32), sds((), jnp.int32),
+        sds((b,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "dstpu_kda_update" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 @pytest.mark.parametrize("tokens", [32, 4096], ids=["decode", "prefill"])
 def test_held_experts_grouped_matmul(one_chip, tokens):
     """The expert layer's grouped matmuls (``jax.lax.ragged_dot``, XLA's own
@@ -458,6 +490,14 @@ def _sarvam_cell():
         held=(0, 16))), 16, 16384
 
 
+def _solar_cell():
+    from deepspeed_tpu.models.solar_kda import SolarKdaConfig, SolarKdaModel
+
+    # the cell's one period: the softmax layer and a run of three
+    return SolarKdaModel(SolarKdaConfig(
+        vocab_size=24576, max_seq_len=16384, held=(0, 40))), 16, 16384
+
+
 def _weights(model, sharding):
     """The model's parameters as the serving engine holds them: bf16, as
     shapes on the described chip; and one layer's sizes of every stacked
@@ -477,9 +517,11 @@ def _assert_copies_no_weight(compiled, leaves):
 
 
 @pytest.mark.parametrize("cell", [_exaone_cell, _granite_cell,
-                                  _gpt2_large_cell, _sarvam_cell],
+                                  _gpt2_large_cell, _sarvam_cell,
+                                  _solar_cell],
                          ids=["k-exaone", "granite-4.0-h-micro",
-                              "gpt2-large", "sarvam-105b"])
+                              "gpt2-large", "sarvam-105b",
+                              "solar-open2-250b"])
 def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
     """The serve cells' decode step (``InferenceEngine.slot_decode_program``'s
     call of the model: one token a slot, per-slot lengths, the slot walk) at
@@ -514,6 +556,9 @@ def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
     _assert_copies_no_weight(compiled, leaves)
     if "conv" in state:
         _assert_mamba_runs_are_folded(compiled.as_text(), state["conv"])
+    if "kda" in state:
+        # one folded call the delta-rule run's layer, the state in place
+        assert compiled.as_text().count("dstpu_kda_update") >= 1
 
 
 def _assert_mamba_runs_are_folded(text, conv):
