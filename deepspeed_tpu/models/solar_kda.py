@@ -338,9 +338,15 @@ class SolarKdaModel:
                                          step["active"])
             o = o[:, None]
         else:
-            kda.count_chunked_block()
-            o, s1 = kda.kda_chunked(q, k_, v_, g, beta, chunk=c.kda_chunk,
-                                    init_state=s0, length=valid)
+            # serving only (the kernel has no VJP): a cache, a TPU, shapes
+            # that fit; training and everything else take the chunked form
+            kernel = s0 is not None and kda.default_route() == "pallas" \
+                and kda.supports_prefill(t, h, dk, dk, c.kda_chunk)
+            count, prompt = (kda.count_prefill_kernel, kda.kda_prefill) \
+                if kernel else (kda.count_chunked_block, kda.kda_chunked)
+            count()
+            o, s1 = prompt(q, k_, v_, g, beta, chunk=c.kda_chunk,
+                           init_state=s0, length=valid)
             if kda_full is not None:
                 kda_full = jax.lax.dynamic_update_index_in_dim(
                     kda_full, s1.astype(kda_full.dtype), layer, 0)

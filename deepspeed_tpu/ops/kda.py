@@ -1,6 +1,6 @@
 """Gated delta-rule linear attention with a per-channel decay (Kimi Delta
 Attention): a layer's one-token decode step, the one-token update alone, and
-the chunked prompt form.
+the chunked prompt form, in XLA's own operations and as a kernel.
 
 The recurrence, per head with state ``S [K, V]`` (keys by values, float32),
 query ``q_t [K]`` and key ``k_t [K]`` (both L2-normalised, the query also times
@@ -37,12 +37,23 @@ Entry points, by serving phase:
     from the DIFFERENCE of the cumulative logs (``k_j / exp(G_j)`` alone
     overflows where a channel decays fast), the in-chunk dependence is a unit
     lower-triangular solve, and a short ``lax.scan`` carries the state from
-    chunk to chunk. XLA's own matmuls; no kernel yet. Positions at or beyond
-    ``length`` get ``g = 0`` and ``beta = 0``: the state stops at the true
-    length (padding is not invisible to a recurrence).
+    chunk to chunk. XLA's own operations: the route of a CPU, of training
+    (it is differentiable) and of shapes the kernel does not fit, and the
+    kernel's reference. Positions at or beyond ``length`` get ``g = 0`` and
+    ``beta = 0``: the state stops at the true length (padding is not
+    invisible to a recurrence).
+  * :func:`kda_prefill` — the same block, for a cached prompt on a TPU, as
+    the one Pallas call ``dstpu_kda_prefill``: a grid cell ``PREFILL_HEADS``
+    heads of a chunk, the chunk axis sequential with the heads' state in
+    VMEM from the block's first chunk to its last; the chunk in sub-chunks of
+    ``SUB``, whose own pairwise decays are the only ones formed element by
+    element and whose part of the solve is a forward substitution, all else
+    matmuls of rows and columns scaled against a sub-chunk's first position;
+    a chunk of padding is neither fetched nor computed. Taken where
+    :func:`supports_prefill` says the shapes fit.
 
 The step is bound by memory (the state is read and written once a token, 0.87
-FLOPs a byte). Serving only: no VJP.
+FLOPs a byte). The two kernels serve only: no VJP.
 """
 
 from __future__ import annotations
@@ -84,20 +95,26 @@ def _count(name: str) -> None:
 
     reg = get_registry()
     counters = {n: reg.counter("kda/traced_" + n) for n in
-                ("folded_step", "split_step", "chunked_block")}
+                ("folded_step", "split_step", "chunked_block",
+                 "prefill_kernel")}
     counters[name].inc()
 
 
 def count_step(folded: bool) -> None:
     """Say in the program's registry which way a one-token layer was traced:
     folded into the kernel, or split into XLA's own operations around
-    :func:`kda_update`. All three counters exist from the first call on."""
+    :func:`kda_update`. All four counters exist from the first call on."""
     _count("folded_step" if folded else "split_step")
 
 
 def count_chunked_block() -> None:
     """A prompt block traced in the chunked form."""
     _count("chunked_block")
+
+
+def count_prefill_kernel() -> None:
+    """A prompt block traced as the Pallas call :func:`kda_prefill`."""
+    _count("prefill_kernel")
 
 
 def l2_normalize(x):
@@ -405,3 +422,279 @@ def kda_chunked(q, k, v, g, beta, *, chunk: int, init_state=None,
     o = jnp.moveaxis(o, 0, 1).transpose(0, 1, 3, 2, 4).reshape(
         b, t + pad, h, dv)
     return o[:, :t], s_last
+
+
+# ------------------------------------------- the chunked prompt form, fused
+# positions of a sub-chunk: between sub-chunks the pairwise decays are matmuls
+# of rows and columns scaled against the row sub-chunk's first position, inside
+# one they are formed element by element
+SUB = 16
+# rows of a sub-chunk that share one traced body of its forward substitution:
+# a body a row (exact slices) is 1,900 equations to trace and lower in every
+# prefill program, a body for all sixteen (masked) twice the vector work
+ROWS = 4
+# heads of a grid cell: a position's rows of 8 heads are one float32 tile, so
+# the work that is the same for every head (the cumulative logs, a sub-chunk's
+# own scores and its forward substitution) runs on whole tiles a position
+PREFILL_HEADS = 8
+
+
+def supports_prefill(tokens: int, heads: int, key_dim: int, value_dim: int,
+                     chunk: int) -> bool:
+    """Whether a prompt block fits :func:`kda_prefill`: a head's keys and
+    values are each one row of lanes, the heads split into whole cells, the
+    block is whole chunks and a chunk whole sub-chunks whose scores fit one
+    row of lanes."""
+    return (key_dim == LANES and value_dim == LANES
+            and heads % PREFILL_HEADS == 0 and chunk % SUB == 0
+            and chunk <= LANES and tokens >= chunk
+            and tokens % chunk == 0)
+
+
+def _prefill_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref,
+                    o_ref, s1_ref, st, q_t, k_t, v_t, g_t, b_t, xv_t, xk_t,
+                    t_t, a_t, *, c: int, hb: int):
+    """``hb`` heads of one chunk of ``c`` positions of one row. The operands'
+    blocks are ``[c, hb x 128]``: a head's ``[c, 128]`` is a slice of whole
+    tiles. The ``_t`` scratches are ``[c x hb, 128]``, a position's ``hb``
+    heads one tile: head ``h``'s rows are every ``hb``-th. ``st`` holds the
+    heads' states TRANSPOSED (``[values, keys]``: the decay then scales
+    lanes) from the row's first chunk to its last."""
+    f32 = jnp.float32
+    row, n = pl.program_id(0), pl.program_id(2)
+    length = len_ref[row]
+    nsub = c // SUB
+
+    def dot(x, y, dims):
+        return jax.lax.dot_general(x, y, (dims, ((), ())), precision=_HIGHEST,
+                                   preferred_element_type=f32)
+
+    def tile(i, count=1):
+        """Positions ``i .. i + count`` of a ``_t`` scratch."""
+        return pl.ds(pl.multiple_of(i * hb, hb), count * hb)
+
+    def of_head(h):
+        return pl.ds(h, c, stride=hb)
+
+    def lanes(h):
+        return pl.ds(pl.multiple_of(h * LANES, LANES), LANES)
+
+    def heads(body):
+        """``body(h)`` for each of the cell's heads, one traced body."""
+        def step(h, carry):
+            body(h)
+            return carry
+
+        jax.lax.fori_loop(0, hb, step, 0)
+
+    @pl.when(n == 0)
+    def _load():
+        @heads
+        def _(h):
+            st[h] = s0_ref[h].astype(f32).T
+        # rows a sub-chunk's group takes behind the one it solves are masked,
+        # not skipped: they hold the chunk's before, and zeros before that
+        for ref in (xv_t, xk_t, t_t):
+            ref[...] = jnp.zeros_like(ref)
+
+    @pl.when(n * c >= length)
+    def _padding():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n * c < length)
+    def _live():
+        # ---- every head at once, a position a [hb, 128] tile
+        @heads
+        def _(h):
+            for ref, to in ((q_ref, q_t), (k_ref, k_t), (v_ref, v_t),
+                            (g_ref, g_t)):
+                to[of_head(h), :] = ref[:, lanes(h)].astype(f32)
+
+        def cum(i, acc):
+            live = n * c + i < length
+            acc = acc + jnp.where(live, g_t[tile(i), :], 0.0)
+            g_t[tile(i), :] = acc
+            b_t[tile(i), :] = jnp.where(live, b_ref[i], 0.0)
+            return acc
+
+        jax.lax.fori_loop(0, c, cum, jnp.zeros((hb, LANES), f32))
+        lane = jax.lax.broadcasted_iota(jnp.int32, (hb, LANES), 1)
+
+        def sub_chunk(a, carry):
+            """A sub-chunk's own part of the solve, by forward substitution:
+            ``(I + L_aa)^-1`` applied to ``diag(beta) [V | K exp(G)]`` (into
+            ``xv_t``, ``xk_t``) and to the identity (``t_t``, at the
+            sub-chunk's lanes), and its block of ``A_qk`` (``a_t``). Rows in
+            groups of ``ROWS``, one traced body a group: a row takes the
+            sub-chunk's rows up to its group's end and masks those at and
+            behind itself."""
+            base = pl.multiple_of(a * SUB, SUB)
+            for count in range(ROWS, SUB + ROWS, ROWS):
+                def taken(ref):
+                    return ref[tile(base, count), :].reshape(count, hb, -1)
+
+                at = base + jax.lax.broadcasted_iota(
+                    jnp.int32, (count, hb, LANES), 0)
+
+                def row(r, carry):      # traced here, inside its group
+                    i = base + count - ROWS + r
+                    gi, ki, qi = (ref[tile(i), :] for ref in (g_t, k_t, q_t))
+                    bi = b_t[tile(i), :][:, :1]
+                    # exp(G_i - G_j) k_j: every exponent <= 0 before i, and
+                    # held there behind it, where the mask drops the term
+                    e = jnp.exp(jnp.minimum(gi - taken(g_t), 0.0)) * taken(k_t)
+                    before = at[..., :1] < i
+                    lij = jnp.where(before, bi * jnp.sum(
+                        e * ki, axis=-1, keepdims=True), 0.0)
+                    aij = jnp.where(at[..., :1] <= i, jnp.sum(
+                        e * qi, axis=-1, keepdims=True), 0.0)
+                    xv_t[tile(i), :] = bi * v_t[tile(i), :] \
+                        - jnp.sum(lij * taken(xv_t), axis=0)
+                    xk_t[tile(i), :] = bi * ki * jnp.exp(gi) \
+                        - jnp.sum(lij * taken(xk_t), axis=0)
+                    t_t[tile(i), :] = (lane == i).astype(f32) \
+                        - jnp.sum(lij * taken(t_t), axis=0)
+                    a_t[tile(i), :] = jnp.sum(
+                        jnp.where(lane == at, aij, 0.0), axis=0)
+                    return carry
+
+                jax.lax.fori_loop(0, ROWS, row, 0, unroll=True)
+            return carry
+
+        jax.lax.fori_loop(0, nsub, sub_chunk, 0)
+
+        # ---- a head at a time, on the MXU
+        r_blk = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0) // SUB
+        c_blk = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1) // SUB
+        below = c_blk < r_blk
+
+        @heads
+        def _(h):
+            q, k = q_ref[:, lanes(h)].astype(f32), k_ref[:, lanes(h)].astype(
+                f32)
+            gc, bt = g_t[of_head(h), :], b_t[of_head(h), :]
+            firsts = [jnp.broadcast_to(gc[a * SUB:a * SUB + 1], (SUB, LANES))
+                      for a in range(nsub)]
+            # rows against their sub-chunk's first position
+            rs = jnp.exp(gc - jnp.concatenate(firsts, axis=0))
+            kr, qr = bt * k * rs, q * rs
+            zeros = jnp.zeros((SUB, c), f32)
+            n_off, a_off = [zeros], [zeros]
+            for a in range(1, nsub):
+                # columns against the same position: <= 0 before it; at and
+                # behind it the block is masked
+                first = jnp.broadcast_to(gc[a * SUB:a * SUB + 1], (c, LANES))
+                kc = k * jnp.exp(jnp.minimum(first - gc, 0.0))
+                rows = slice(a * SUB, (a + 1) * SUB)
+                s = dot(jnp.concatenate([kr[rows], qr[rows]], axis=0), kc,
+                        ((1,), (1,)))                        # [2 SUB, c]
+                n_off.append(s[:SUB])
+                a_off.append(s[SUB:])
+            lower = jnp.where(below, jnp.concatenate(n_off, axis=0), 0.0)
+            a_qk = a_t[of_head(h), :][:, :c] + jnp.where(
+                below, jnp.concatenate(a_off, axis=0), 0.0)
+            # (I + D^-1 N)^-1 D^-1 R, sub-chunk after sub-chunk
+            lower = dot(t_t[of_head(h), :][:, :c], lower, ((1,), (0,)))
+            x = jnp.concatenate([xv_t[of_head(h), :], xk_t[of_head(h), :]],
+                                axis=1)
+            xs = [x[a * SUB:(a + 1) * SUB] for a in range(nsub)]
+            for a in range(1, nsub):
+                # rows of ``lower`` are zero from their own sub-chunk on
+                xs[a] = xs[a] - dot(lower[a * SUB:(a + 1) * SUB],
+                                    jnp.concatenate(xs, axis=0),
+                                    ((1,), (0,)))
+            x = jnp.concatenate(xs, axis=0)
+            u0, w = x[:, :LANES], x[:, LANES:]
+            s_t = st[h]                                      # [V, K]
+            ws = dot(jnp.concatenate([w, q * jnp.exp(gc)], axis=0), s_t,
+                     ((1,), (1,)))                           # [2 c, V]
+            u = u0 - ws[:c]
+            o = ws[c:] + dot(a_qk, u, ((1,), (0,)))
+            o_ref[:, lanes(h)] = o.astype(o_ref.dtype)
+            last = gc[c - 1:c]
+            k_out = k * jnp.exp(last - gc)
+            st[h] = s_t * jnp.exp(last) + dot(u, k_out, ((0,), (0,)))
+
+    @pl.when(n == pl.num_programs(2) - 1)
+    def _store():
+        @heads
+        def _(h):
+            s1_ref[h] = st[h].T.astype(s1_ref.dtype)
+
+
+def kda_prefill(q, k, v, g, beta, *, chunk: int, init_state, length=None,
+                interpret: Optional[bool] = None):
+    """:func:`kda_chunked` for a cached prompt block as the one Pallas call
+    ``dstpu_kda_prefill``: same operands (``init_state [B, H, 128, 128]``
+    given), same results in float32, every intermediate of a chunk in VMEM.
+
+    A grid cell is ``PREFILL_HEADS`` heads of one chunk; the chunk axis is
+    sequential and carries the heads' state in a scratch, read from
+    ``init_state`` at the first chunk and written once behind the last. The
+    operands are fetched as the projections and the convolution leave them
+    (``[B, T, H x 128]``, a position a row: a block is ``[chunk, heads x
+    128]`` and a head's part of it whole tiles; no transposed copy is made
+    outside). In a chunk the cumulative logs, each sub-chunk's own scores (the
+    only ``exp(G_i - G_j)`` formed element by element) and its forward
+    substitution run for the cell's heads at once, on copies in VMEM that
+    hold a position's heads as one tile; then, a head at a time on the MXU:
+    the scores between sub-chunks (rows times ``exp(G_i - G_r)`` against
+    columns times ``exp(G_r - G_j)``, ``r`` the row sub-chunk's first
+    position: both exponents at most 0), the solve across sub-chunks and
+    :func:`kda_chunked`'s chunk step. Positions at or beyond ``length`` get
+    ``g = 0``, ``beta = 0``; a chunk that holds none before ``length`` is not
+    fetched, does no arithmetic, writes zeros and leaves the state as it is.
+    Float32 scores, solve, state and accumulations, matmuls at
+    ``Precision.HIGHEST``. Serving only: no VJP."""
+    b, t, h, dk = k.shape
+    assert supports_prefill(t, h, dk, v.shape[-1], chunk), (
+        k.shape, v.shape, chunk)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    length = jnp.full((b,), t, jnp.int32) if length is None else \
+        jnp.broadcast_to(jnp.asarray(length, jnp.int32), (b,))
+    return _prefill_call(length, q, k, v, g, beta, init_state, chunk=chunk,
+                         interpret=interpret)
+
+
+# jitted (and inlined where it is called), so that the kernel's body, some 700
+# equations, is traced once a process and not once in each of the four prefill
+# programs that hold it: set-up time on every warm start (PERF.md, PR 49)
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"),
+                   inline=True)
+def _prefill_call(length, q, k, v, g, beta, init_state, *, chunk: int,
+                  interpret: bool):
+    f32 = jnp.float32
+    b, t, h, dk = k.shape
+    c, hb = chunk, PREFILL_HEADS
+
+    def live_chunk(i, n, len_ref):
+        # chunks of padding stay on the last live one: nothing is fetched
+        return jnp.minimum(n, jnp.maximum(len_ref[i] - 1, 0) // c)
+
+    rows = pl.BlockSpec((None, c, hb * LANES), lambda i, j, n, len_ref: (
+        i, live_chunk(i, n, len_ref), j))
+    state = pl.BlockSpec((None, hb, dk, LANES),
+                         lambda i, j, n, len_ref: (i, j, 0, 0))
+    tile = pltpu.VMEM((c * hb, LANES), f32)
+    o, s1 = pl.pallas_call(
+        functools.partial(_prefill_kernel, c=c, hb=hb),
+        name="dstpu_kda_prefill",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, h // hb, t // c),
+            in_specs=[rows] * 4 + [
+                pl.BlockSpec((None, c, hb, LANES), lambda i, j, n, len_ref: (
+                    i, live_chunk(i, n, len_ref), j, 0)), state],
+            out_specs=[pl.BlockSpec((None, c, hb * LANES),
+                                    lambda i, j, n, len_ref: (i, n, j)),
+                       state],
+            scratch_shapes=[pltpu.VMEM((hb, LANES, dk), f32)] + [tile] * 9),
+        out_shape=[jax.ShapeDtypeStruct((b, t, h * LANES), f32),
+                   jax.ShapeDtypeStruct((b, h, dk, LANES), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(length, *(x.reshape(b, t, h * LANES) for x in (q, k, v, g)),
+      jnp.broadcast_to(beta.astype(f32)[..., None], (b, t, h, LANES)),
+      init_state.astype(f32))
+    return o.reshape(b, t, h, LANES), s1

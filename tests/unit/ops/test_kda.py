@@ -1,8 +1,13 @@
 """Gated delta-rule operators (ops/kda.py) on the CPU: the chunked prompt form
 against the token-by-token recurrence written out here (also under fast decay
 and with ``beta`` near 2), the one-token ``jnp`` update as one step of that
-recurrence, and a layer's whole decode step folded into the Pallas call, in
-interpret mode, against the carried convolution + the ``jnp`` update."""
+recurrence, a layer's whole decode step folded into the Pallas call, in
+interpret mode, against the carried convolution + the ``jnp`` update, and the
+prompt block as the Pallas call ``dstpu_kda_prefill``, in interpret mode,
+against the chunked form and the recurrence, at the cell's key and value width
+with one group of heads."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -190,22 +195,230 @@ def test_supports_says_from_shapes_what_folds():
     assert kda.tail_shape(4, 64, 128) == (3, 3, 64, 128)
 
 
-def test_traced_counters_name_the_route():
+COUNTERS = ("folded_step", "split_step", "chunked_block", "prefill_kernel")
+
+
+def _counters():
     from deepspeed_tpu.telemetry.registry import get_registry
 
     reg = get_registry()
-    before = {n: reg.counter("kda/traced_" + n).value for n in
-              ("folded_step", "split_step", "chunked_block")}
+    return {n: reg.counter("kda/traced_" + n).value for n in COUNTERS}
+
+
+def test_traced_counters_name_the_route():
+    before = _counters()
     kda.count_step(True)
     kda.count_step(False)
     kda.count_chunked_block()
-    for name, was in before.items():
-        assert reg.counter("kda/traced_" + name).value == was + 1
+    kda.count_prefill_kernel()
+    assert _counters() == {n: was + 1 for n, was in before.items()}
 
 
 def test_the_kernel_has_a_stable_name_of_its_own():
     import inspect
 
     src = inspect.getsource(kda)
-    assert src.count("pl.pallas_call(") == 1
+    assert src.count("pl.pallas_call(") == 2
     assert 'name="dstpu_kda_update"' in src
+    assert 'name="dstpu_kda_prefill"' in src
+
+
+# ------------------------------------------------ the prompt block's kernel
+HB, D, CHUNK = kda.PREFILL_HEADS, kda.LANES, 64
+
+
+@functools.lru_cache(maxsize=None)
+def _prefill():
+    """One compilation a block length for every case below."""
+    return jax.jit(functools.partial(kda.kda_prefill, chunk=CHUNK,
+                                     interpret=True))
+
+
+def _state(seed, b=1):
+    return jnp.asarray(np.random.RandomState(seed).randn(b, HB, D, D),
+                       jnp.float32)
+
+
+def _assert_prefill(args, s0, length, rtol=1e-4, atol=2e-5):
+    """The kernel against the chunked form and the recurrence up to
+    ``length``; zeros behind the last chunk that holds a real position."""
+    t = args[0].shape[1]
+    o, s = _prefill()(*args, init_state=s0, length=jnp.asarray([length]))
+    o, s = np.asarray(o), np.asarray(s)
+    assert np.isfinite(o).all() and np.isfinite(s).all()
+    o_c, s_c = kda.kda_chunked(*args, chunk=CHUNK, init_state=s0,
+                               length=length)
+    o_r, s_r = _sequential(*args, s0=s0, length=length)
+    live = -(-length // CHUNK) * CHUNK
+    # inside a live chunk the padded positions read the state as the chunked
+    # form's do
+    np.testing.assert_allclose(o[:, :live], np.asarray(o_c)[:, :live],
+                               rtol=rtol, atol=atol)
+    np.testing.assert_allclose(o[:, :length], o_r[:, :length], rtol=rtol,
+                               atol=atol)
+    assert not o[:, live:].any()
+    np.testing.assert_allclose(s, np.asarray(s_c), rtol=rtol, atol=atol)
+    np.testing.assert_allclose(s, s_r, rtol=rtol, atol=atol)
+    return o, s
+
+
+@pytest.mark.parametrize("t,length,carried", [
+    (128, 128, False), (128, 128, True), (128, 37, True), (128, 64, True),
+    (128, 1, False), (256, 70, True), (256, 192, False)],
+    ids=["fresh", "carried", "inside-a-sub-chunk", "on-a-chunk-edge",
+         "one-position", "chunks-of-padding", "a-chunk-of-padding"])
+def test_prefill_kernel_matches_the_chunked_form_and_the_recurrence(
+        t, length, carried):
+    args = _inputs(1, t, HB, D, D, seed=21)
+    s0 = _state(22) if carried else jnp.zeros((1, HB, D, D), jnp.float32)
+    _assert_prefill(args, s0, length)
+
+
+def test_prefill_kernel_leaves_a_row_of_padding_alone():
+    """``length`` 0: zeros out, the state bit for bit, nothing non-finite
+    even where the operands of the padding are."""
+    q, k, v, g, beta = _inputs(1, 128, HB, D, D, seed=23)
+    v = v.at[:, 5].set(jnp.inf)
+    s0 = _state(24)
+    o, s = _prefill()(q, k, v, g, beta, init_state=s0,
+                      length=jnp.asarray([0]))
+    assert not np.asarray(o).any()
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(s0))
+
+
+def test_prefill_kernel_is_finite_and_right_under_fast_decay():
+    """The case the chunked form is held to: a channel at ``log 0.05`` a
+    position for a whole chunk (``G`` to -190), so that neither a sub-chunk's
+    own ``exp(G_i - G_j)`` nor the scalings between sub-chunks may be formed
+    as a quotient."""
+    q, k, v, g, beta = _inputs(1, 128, HB, D, D, seed=25)
+    g = g.at[:, :, :, 0].set(np.log(0.05)).at[:, :64, 1, 3].set(np.log(0.05))
+    _assert_prefill((q, k, v, g, beta), _state(26), 128)
+
+
+def test_prefill_kernel_holds_with_beta_near_two():
+    args = _inputs(1, 128, HB, D, D, seed=27, g_range=(0.001, 0.05),
+                   beta_range=(1.9, 1.999))
+    _assert_prefill(args, _state(28), 128, atol=2e-4)
+
+
+def test_prefill_kernel_two_blocks_in_a_row_equal_one_call():
+    """A token block continues from the state the one before it returned, as
+    ``forward_with_cache`` walks a long prompt."""
+    args = _inputs(1, 256, HB, D, D, seed=29)
+    s0 = _state(30)
+    length = 200
+    o_all, s_all = _prefill()(*args, init_state=s0,
+                              length=jnp.asarray([length]))
+    o1, s1 = _prefill()(*(x[:, :128] for x in args), init_state=s0,
+                        length=jnp.asarray([128]))
+    o2, s2 = _prefill()(*(x[:, 128:] for x in args), init_state=s1,
+                        length=jnp.asarray([length - 128]))
+    np.testing.assert_allclose(np.asarray(jnp.concatenate([o1, o2], 1)),
+                               np.asarray(o_all), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(s2), np.asarray(s_all), rtol=1e-5,
+                               atol=1e-6)
+
+
+def test_prefill_kernel_takes_rows_of_their_own_lengths():
+    args = _inputs(2, 128, HB, D, D, seed=31)
+    s0 = _state(32, b=2)
+    lengths = jnp.asarray([128, 50])
+    o, s = _prefill()(*args, init_state=s0, length=lengths)
+    for row, length in enumerate((128, 50)):
+        o_r, s_r = _sequential(*(x[row:row + 1] for x in args),
+                               s0=s0[row:row + 1], length=length)
+        np.testing.assert_allclose(np.asarray(o)[row, :length],
+                                   o_r[0, :length], rtol=1e-4, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(s)[row], s_r[0], rtol=1e-4,
+                                   atol=2e-5)
+
+
+@pytest.mark.parametrize("shape,fits", [
+    ((2048, 64, 128, 128, 64), True),      # the cell's token block
+    ((128, 8, 128, 128, 64), True),        # one group of heads, two chunks
+    ((16, 4, 16, 16, 8), False),           # a tiny model: kda_chunk 8
+    ((2048, 64, 128, 64, 64), False),      # values narrower than a row
+    ((2048, 60, 128, 128, 64), False),     # heads that split no group
+    ((2000, 64, 128, 128, 64), False),     # a block that is no whole chunks
+    ((32, 64, 128, 128, 64), False),       # shorter than a chunk
+    ((2048, 64, 128, 128, 24), False),     # a chunk of no whole sub-chunks
+    ((2048, 64, 128, 128, 256), False)],   # a chunk wider than a row of lanes
+    ids=str)
+def test_supports_prefill_says_from_shapes_what_routes(shape, fits):
+    assert kda.supports_prefill(*shape) is fits
+
+
+@pytest.fixture
+def kernel_route(monkeypatch):
+    """The model picks the kernel where ``jax.default_backend()`` says tpu
+    (steered here, not through an option of the program); the call itself
+    runs in the Pallas interpreter."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(kda, "kda_prefill", functools.partial(
+        kda.kda_prefill, interpret=True))
+
+
+def _mixer(kda_chunk=CHUNK, heads=HB, dim=D):
+    """``SolarKdaModel._kda_mixer`` on one layer's small weights, a prompt
+    block of two chunks in rows of their own lengths."""
+    from deepspeed_tpu.models.solar_kda import SolarKdaConfig, SolarKdaModel
+
+    c = SolarKdaConfig.tiny(kda_chunk=kda_chunk)
+    c.kda_heads, c.kda_head_dim = heads, dim
+    model = SolarKdaModel(c, compute_dtype=jnp.float32)
+    b, t, w, lk = 2, 2 * kda_chunk, heads * dim, 2
+    rng = np.random.RandomState(41)
+    f = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)  # noqa: E731
+    blk = {"conv_w": jnp.asarray(rng.uniform(-0.5, 0.5, (c.kda_conv, 3 * w)),
+                                 jnp.float32),
+           "A_log": jnp.asarray(np.log(rng.uniform(1, 16, (heads,))),
+                                jnp.float32),
+           "dt_bias": f(w) - 3.0, "o_norm": 1.0 + 0.1 * f(dim)}
+    operands = (f(b, t, 3 * w), f(b, t, w), jnp.asarray(
+        rng.uniform(0.1, 1.9, (b, t, heads)), jnp.float32), f(b, t, w))
+    state = f(lk, b, heads, dim, dim)
+    tail = f(lk, b, *kda.tail_shape(c.kda_conv, heads, dim))
+
+    def run(cached=True):
+        before = _counters()
+        out = model._kda_mixer(
+            *operands, blk, state if cached else None,
+            tail if cached else None, 1, jnp.asarray([3, 0]),
+            jnp.asarray([t, kda_chunk + 5]), None)
+        return out, {n: v - before[n] for n, v in _counters().items()}
+
+    return run
+
+
+def test_the_model_takes_the_kernel_for_a_cached_prompt_block_on_a_tpu(
+        kernel_route, monkeypatch):
+    run = _mixer()
+    (o, state, tail), counted = run()
+    assert counted == {"folded_step": 0, "split_step": 0, "chunked_block": 0,
+                       "prefill_kernel": 1}
+    monkeypatch.undo()                    # a CPU: the chunked form
+    (o_c, state_c, tail_c), counted = run()
+    assert counted == {"folded_step": 0, "split_step": 0, "chunked_block": 1,
+                       "prefill_kernel": 0}
+    np.testing.assert_allclose(np.asarray(o[0]), np.asarray(o_c[0]),
+                               rtol=1e-4, atol=1e-4)
+    live = CHUNK + 5
+    np.testing.assert_allclose(np.asarray(o[1, :live]),
+                               np.asarray(o_c[1, :live]), rtol=1e-4,
+                               atol=1e-4)
+    np.testing.assert_allclose(np.asarray(state), np.asarray(state_c),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(tail), np.asarray(tail_c))
+
+
+@pytest.mark.parametrize("why,kw,cached", [
+    ("no cache", {}, False), ("kda_chunk 8", {"kda_chunk": 8}, True),
+    ("a tiny model's heads", {"kda_chunk": 16, "heads": 4, "dim": 16}, True)])
+def test_the_model_keeps_the_chunked_form_where_the_kernel_does_not_fit(
+        kernel_route, why, kw, cached):
+    """Training and ``forward_hidden`` carry no cache and need a VJP; a tiny
+    configuration's shapes fit no tile."""
+    _, counted = _mixer(**kw)(cached)
+    assert counted == {"folded_step": 0, "split_step": 0, "chunked_block": 1,
+                       "prefill_kernel": 0}, why

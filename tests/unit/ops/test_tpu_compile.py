@@ -229,6 +229,25 @@ def test_kda_update_kernel(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
+def test_kda_prefill_kernel(one_chip):
+    """``dstpu_kda_prefill`` at the Solar-Open2 cell's shapes: a token block
+    of 2,048 positions, 64 heads of 128 keys and values, chunks of 64, the
+    values in bf16 as the convolution leaves them."""
+    from deepspeed_tpu.ops import kda
+
+    t, h, d = 2048, 64, 128
+    assert kda.supports_prefill(t, h, d, d, 64)
+    sds = functools.partial(_sds, one_chip)
+    fn = functools.partial(kda.kda_prefill, chunk=64, interpret=False)
+    rows = sds((1, t, h, d), jnp.float32)
+    text = jax.jit(lambda q, k, v, g, beta, state, length: fn(
+        q, k, v, g, beta, init_state=state, length=length)).lower(
+            rows, rows, sds((1, t, h, d)), rows, sds((1, t, h), jnp.float32),
+            sds((1, h, d, d), jnp.float32),
+            sds((1,), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text and "dstpu_kda_prefill" in text
+
+
 @pytest.mark.parametrize("tokens", [32, 4096], ids=["decode", "prefill"])
 def test_held_experts_grouped_matmul(one_chip, tokens):
     """The expert layer's grouped matmuls (``jax.lax.ragged_dot``, XLA's own
@@ -557,8 +576,10 @@ def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
     if "conv" in state:
         _assert_mamba_runs_are_folded(compiled.as_text(), state["conv"])
     if "kda" in state:
-        # one folded call the delta-rule run's layer, the state in place
+        # one folded call the delta-rule run's layer, the state in place; the
+        # prompt block's kernel has no part in a one-token step
         assert compiled.as_text().count("dstpu_kda_update") >= 1
+        assert "dstpu_kda_prefill" not in compiled.as_text()
 
 
 def _assert_mamba_runs_are_folded(text, conv):
@@ -598,6 +619,20 @@ def _assert_mamba_runs_are_folded(text, conv):
     assert len(sorts) == 1, sorts    # the active mask's, once a step
 
 
+def _compile_prefill(model, params, sharding, bucket):
+    """``slot_prefill_program``'s call of the model, compiled: one row of
+    ``bucket`` positions, a cache of its own, the true length."""
+    def prefill(params, ids, length):
+        cache = model.init_cache(1, bucket, dtype=BF16)
+        cache["valid_len"] = length
+        logits, cache = model.forward_with_cache(params, ids, cache)
+        return logits, cache
+
+    return jax.jit(prefill).lower(
+        params, _sds(sharding, (1, bucket), jnp.int32),
+        _sds(sharding, (), jnp.int32)).compile()
+
+
 def test_prefill_copies_no_weight(one_chip, fused_routes):
     """K-EXAONE's bucket-256 prefill (``slot_prefill_program``'s call of the
     model: one row, a cache of its own, the true length) walks the same
@@ -605,15 +640,7 @@ def test_prefill_copies_no_weight(one_chip, fused_routes):
     model, _, _ = _exaone_cell()
     params, leaves = _weights(model, one_chip)
 
-    def prefill(params, ids, length):
-        cache = model.init_cache(1, 256, dtype=BF16)
-        cache["valid_len"] = length
-        logits, cache = model.forward_with_cache(params, ids, cache)
-        return logits, cache
-
-    compiled = jax.jit(prefill).lower(
-        params, _sds(one_chip, (1, 256), jnp.int32),
-        _sds(one_chip, (), jnp.int32)).compile()
+    compiled = _compile_prefill(model, params, one_chip, 256)
     _assert_copies_no_weight(compiled, leaves)
 
 
@@ -632,15 +659,7 @@ def test_sarvam_prefill_keeps_scores_on_chip(one_chip, fused_routes):
     # [16384, 4096] like the dense layer's w_down, and are gathered
     leaves = leaves - {("bf16", (4096, 16384))}
 
-    def prefill(params, ids, length):
-        cache = model.init_cache(1, 4096, dtype=BF16)
-        cache["valid_len"] = length
-        logits, cache = model.forward_with_cache(params, ids, cache)
-        return logits, cache
-
-    compiled = jax.jit(prefill).lower(
-        params, _sds(one_chip, (1, 4096), jnp.int32),
-        _sds(one_chip, (), jnp.int32)).compile()
+    compiled = _compile_prefill(model, params, one_chip, 4096)
     text = compiled.as_text()
     copies = _weight_sized_copies(text, leaves)
     assert not copies, "\n".join(copies)
@@ -656,6 +675,37 @@ def test_sarvam_prefill_keeps_scores_on_chip(one_chip, fused_routes):
     assert not big, "\n".join(big)
     # 643 MB here; the loop's program 694 MB
     assert compiled.memory_analysis().temp_size_in_bytes < 680 * 2 ** 20
+
+
+@pytest.mark.parametrize("bucket,temp_mb", [(2048, 619), (16384, 1009)])
+def test_solar_prefill_keeps_a_chunk_on_chip(one_chip, fused_routes, bucket,
+                                             temp_mb):
+    """Solar-Open2's prefill (``slot_prefill_program``'s call of the model: a
+    cache of its own, the true length; the 16,384 bucket walks eight token
+    blocks of 2,048): the delta-rule run's prompt block is the one kernel
+    ``dstpu_kda_prefill``. Gone with the chunked form: the unit triangular
+    solve's expander (``InvertDiagBlocksLowerTriangular`` and its loops), the
+    chunk's pairwise-decay scores ``[32, 64, 64, 64]`` as a variadic
+    reduction's results, and every chunk operand transposed to ``[.., 64, 64,
+    128]``; and the program's temporaries are below the chunked form's
+    (``temp_mb``: 619 MB and 1,009 MB, PERF.md, PR 48)."""
+    model, _, _ = _solar_cell()
+    params, _ = _weights(model, one_chip)
+
+    compiled = _compile_prefill(model, params, one_chip, bucket)
+    text = compiled.as_text()
+    found, _ = _outside_fusions(text)
+    kernels = [line for _, _, opcode, line in found
+               if opcode == "custom-call" and "dstpu_kda_prefill" in line
+               and "tpu_custom_call" in line]
+    assert len(kernels) == 1, kernels       # the run of three layers: a loop
+    assert "triangular" not in text.lower() and "cholesky" not in text.lower()
+    chunked = [line.strip()[:200] for _, kind, _, line in found
+               for dims in re.findall(r"f32\[([\d,]*)\]", kind)
+               if _sizes(dims.split(","))[-3:] == (64, 64, 128)
+               or _sizes(dims.split(",")) == (32, 64, 64, 64)]
+    assert not chunked, "\n".join(chunked)
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_mb * 2 ** 20
 
 
 @pytest.fixture
