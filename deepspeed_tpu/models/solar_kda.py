@@ -31,9 +31,11 @@ runs the chunked form from the layer's state and writes the state at the true
 length back; a prompt longer than ``prompt_block`` passes the whole stack a
 block of tokens at a time inside the one program call, the state and tails
 carried from block to block, and the softmax layer attends rows ``[0, end of
-block)`` in key blocks with a running softmax
-(ops/attention.blocked_prompt_attention). A prefill that is told the prompt's
-true length (``valid_len``) computes its head there alone: ``[B, 1, V]``.
+block)`` in key blocks with a running softmax (scope ``dstpu_gqa_prefill``:
+on a TPU ops/gqa_prefill.py's one call, which keeps a key block's scores in
+VMEM, elsewhere ops/attention.blocked_prompt_attention). A prefill that is
+told the prompt's true length (``valid_len``) computes its head there alone:
+``[B, 1, V]``.
 """
 
 from __future__ import annotations
@@ -49,7 +51,7 @@ from deepspeed_tpu.models.base import (cache_positions, cross_entropy_loss, gath
 from deepspeed_tpu.models.moe_ffn import (EXPERT_LEAVES, SPARSE, STEP_COUNTERS, ffn, gated_axes, gated_init,
                                           record_step_counters)
 from deepspeed_tpu.models.stack import cached_walk, kv_cache, next_cache, walk, wrapped_block
-from deepspeed_tpu.ops import kda
+from deepspeed_tpu.ops import gqa_prefill, kda
 from deepspeed_tpu.ops.attention import (blocked_prompt_attention, cached_attention, multihead_attention,
                                          write_kv_cache)
 from deepspeed_tpu.ops.ssm import causal_conv, slot_order
@@ -399,10 +401,20 @@ class SolarKdaModel:
             if t > 1 and kc.shape[4] == dh and s_max > c.key_block \
                     and s_max % c.key_block == 0:
                 kc, vc, kl, vl = write_kv_cache(kc, vc, k_, v_, layer, idx)
-                attn = blocked_prompt_attention(
-                    q, kl, vl, jnp.broadcast_to(cache_positions(idx, t),
-                                                (b, t)),
-                    key_block=c.key_block)
+                # serving only (the kernel has no VJP): a TPU and shapes that
+                # fit take the one call over the leaves where they lie, whose
+                # dead query tiles come back as zeros; the rest the loop
+                kernel = jax.default_backend() == "tpu" and \
+                    gqa_prefill.supports(s_max, kc.shape[4], dh, c.key_block,
+                                         t, hq, hkv)
+                gqa_prefill.count_traced(kernel)
+                with jax.named_scope("dstpu_gqa_prefill"):
+                    attn = gqa_prefill.gqa_prefill(
+                        q, kc, vc, layer, idx, valid, key_block=c.key_block
+                    ) if kernel else blocked_prompt_attention(
+                        q, kl, vl, jnp.broadcast_to(cache_positions(idx, t),
+                                                    (b, t)),
+                        key_block=c.key_block)
             else:
                 attn, kc, vc = cached_attention(q, kc, vc, k_, v_, layer, idx,
                                                 active=walk_)

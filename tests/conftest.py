@@ -280,6 +280,13 @@ def pytest_runtest_protocol(item, nextitem):
             or not any(item.nodeid.endswith(n) for n in _XDIST_ISOLATED)):
         return None
     _run_module_child(item.session, [item], attempts=_XDIST_ATTEMPTS)
+    # what pytest's own protocol does behind every item: the item before
+    # this one left the collectors it shares with it set up (test_tp.py's
+    # module where it ran there too), and nothing here tears them down. The
+    # worker's next item, if xdist hands it one of another module, then
+    # fails its set-up with "previous item was not torn down properly" (one
+    # whole run of PR 50's tree, by the scheduling alone)
+    item.session._setupstate.teardown_exact(nextitem)
     return True
 
 

@@ -360,3 +360,58 @@ def test_a_chunked_prefill_continues_state_tails_and_rows(built):
                                       jnp.asarray(r.tokens)]
             assert float(gap.max()) < 1e-4, r.rid
     groups.reset()
+
+
+def test_a_cached_prefill_by_the_prompt_kernel_is_the_loops(monkeypatch):
+    """The softmax layer's prompt block as ``dstpu_gqa_prefill`` (the route a
+    TPU takes, steered here; the call in the Pallas interpreter) against the
+    ``lax`` loop, through the whole stack: a prompt of 41 in a bucket of 64
+    walks two token blocks of 32, the second one's last query tile all
+    padding, which the kernel returns as zeros and nothing real reads. The
+    last real position's logits agree inside the cell's ``mean_gap_tol``, and
+    so do the rows and the state the prefill leaves."""
+    import dataclasses
+    import functools
+
+    from deepspeed_tpu.ops import gqa_prefill
+    from deepspeed_tpu.telemetry.registry import get_registry
+
+    # head size 128: the kernel takes whole rows of lanes only
+    c = dataclasses.replace(
+        SolarKdaConfig.tiny(prompt_block=32, key_block=16), head_dim=128)
+    model = SolarKdaModel(c, compute_dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(4))
+    ids = jnp.asarray(np.random.RandomState(6).randint(0, 512, (1, 64)),
+                      jnp.int32)
+    length = 41
+
+    def prefill():
+        cache = model.init_cache(1, 64, dtype=jnp.float32)
+        cache["valid_len"] = jnp.asarray(length)
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(model.forward_with_cache)(params, ids, cache)
+
+    def counted():
+        n = get_registry().snapshot()["counters"]
+        return (n.get("gqa/traced_prefill_kernel", 0),
+                n.get("gqa/traced_blocked_block", 0))
+
+    before = counted()
+    want, cache_w = prefill()
+    assert counted() == (before[0], before[1] + 1)      # a CPU: 0 and n
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(gqa_prefill, "_QUERY_TILE", 16)
+    monkeypatch.setattr(gqa_prefill, "gqa_prefill", functools.partial(
+        gqa_prefill.gqa_prefill, interpret=True))
+    got, cache_g = prefill()
+    assert counted() == (before[0] + 1, before[1] + 1)  # the scan's one body
+    assert want.shape == got.shape == (1, 1, 512)
+    tol = harness.load_json("traffic", "serve-agent-contexts.json")["check"]
+    gap = np.abs(np.asarray(got) - np.asarray(want))
+    assert float(np.abs(np.asarray(want)).max()) > 0.1
+    assert gap.mean() < tol["mean_gap_tol"] and gap.max() < 1e-4
+    for name in ("k", "v"):      # the real positions' rows, and the state
+        np.testing.assert_allclose(cache_g[name][..., :length, :],
+                                   cache_w[name][..., :length, :], **TOL)
+    for name in ("kda", "kda_conv"):
+        np.testing.assert_allclose(cache_g[name], cache_w[name], **TOL)

@@ -248,6 +248,26 @@ def test_kda_prefill_kernel(one_chip):
     assert "tpu_custom_call" in text and "dstpu_kda_prefill" in text
 
 
+@pytest.mark.parametrize("rows", [2048, 16384], ids=str)
+def test_gqa_prefill_kernel(one_chip, rows):
+    """``dstpu_gqa_prefill`` at the Solar-Open2 cell's shapes: a token block
+    of 2,048 at 64 query over 8 key-value heads of 128 against the smallest
+    and the largest bucket's rows in key blocks of 512, the cache leaves
+    whole; the layer, the first position and the valid length traced, as the
+    prefill's scan over token blocks calls it."""
+    from deepspeed_tpu.ops import gqa_prefill
+
+    t, hq, hkv, d = 2048, 64, 8, 128
+    assert gqa_prefill.supports(rows, d, d, 512, t, hq, hkv)
+    fn = functools.partial(gqa_prefill.gqa_prefill, key_block=512,
+                           interpret=False)
+    scalar = _sds(one_chip, (), jnp.int32)
+    leaf = _sds(one_chip, (1, 1, hkv, rows, d))
+    text = _compiled_text(fn, _sds(one_chip, (1, t, hq, d)), leaf, leaf,
+                          scalar, scalar, scalar)
+    assert "dstpu_gqa_prefill" in text
+
+
 @pytest.mark.parametrize("tokens", [32, 4096], ids=["decode", "prefill"])
 def test_held_experts_grouped_matmul(one_chip, tokens):
     """The expert layer's grouped matmuls (``jax.lax.ragged_dot``, XLA's own
@@ -677,7 +697,7 @@ def test_sarvam_prefill_keeps_scores_on_chip(one_chip, fused_routes):
     assert compiled.memory_analysis().temp_size_in_bytes < 680 * 2 ** 20
 
 
-@pytest.mark.parametrize("bucket,temp_mb", [(2048, 619), (16384, 1009)])
+@pytest.mark.parametrize("bucket,temp_mb", [(2048, 538), (16384, 806)])
 def test_solar_prefill_keeps_a_chunk_on_chip(one_chip, fused_routes, bucket,
                                              temp_mb):
     """Solar-Open2's prefill (``slot_prefill_program``'s call of the model: a
@@ -687,18 +707,33 @@ def test_solar_prefill_keeps_a_chunk_on_chip(one_chip, fused_routes, bucket,
     solve's expander (``InvertDiagBlocksLowerTriangular`` and its loops), the
     chunk's pairwise-decay scores ``[32, 64, 64, 64]`` as a variadic
     reduction's results, and every chunk operand transposed to ``[.., 64, 64,
-    128]``; and the program's temporaries are below the chunked form's
-    (``temp_mb``: 619 MB and 1,009 MB, PERF.md, PR 48)."""
+    128]``. The softmax layer's prompt block is the one kernel
+    ``dstpu_gqa_prefill`` over the cache leaves where they lie: no float32
+    score buffer ``[.., 2048, 512]`` of the ``lax`` loop is left, and in the
+    16,384 bucket nothing outside the kernel copies or slices a layer's key
+    or value rows (in the 2,048 bucket the block's own new rows, turned for
+    the write, are as many). The program's temporaries are no higher than
+    with the loop (``temp_mb``: 537 MB and 805 MB, PERF.md, PR 49; the
+    chunked form's were 619 and 1,009)."""
     model, _, _ = _solar_cell()
     params, _ = _weights(model, one_chip)
 
     compiled = _compile_prefill(model, params, one_chip, bucket)
     text = compiled.as_text()
     found, _ = _outside_fusions(text)
-    kernels = [line for _, _, opcode, line in found
-               if opcode == "custom-call" and "dstpu_kda_prefill" in line
-               and "tpu_custom_call" in line]
-    assert len(kernels) == 1, kernels       # the run of three layers: a loop
+    for name in ("dstpu_kda_prefill", "dstpu_gqa_prefill"):
+        kernels = [line for _, _, opcode, line in found
+                   if opcode == "custom-call" and name in line
+                   and "tpu_custom_call" in line]
+        # the run of three layers is a loop, the softmax layer is one
+        assert len(kernels) == 1, (name, kernels)
+    scores = [line.strip()[:200] for _, kind, _, line in found
+              for dims in re.findall(r"f32\[([\d,]*)\]", kind)
+              if dims.split(",")[-2:] == ["2048", "512"]]
+    assert not scores, "\n".join(scores)
+    if bucket > 2048:
+        rows = _weight_sized_copies(text, {("bf16", (8, 128, bucket))})
+        assert not rows, "\n".join(rows)
     assert "triangular" not in text.lower() and "cholesky" not in text.lower()
     chunked = [line.strip()[:200] for _, kind, _, line in found
                for dims in re.findall(r"f32\[([\d,]*)\]", kind)
