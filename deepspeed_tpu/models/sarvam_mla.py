@@ -59,7 +59,7 @@ from deepspeed_tpu.models.base import (cache_positions, cross_entropy_loss, gath
                                        project_heads, qdot, rms_norm, whole_leaves)
 from deepspeed_tpu.models.moe_ffn import (DENSE, EXPERT_LEAVES, SPARSE, STEP_COUNTERS, ffn, gated_axes,
                                           gated_init, record_step_counters)
-from deepspeed_tpu.models.stack import cached_walk, next_cache, walk, wrapped_block
+from deepspeed_tpu.models.stack import cached_walk, next_cache, prompt_walk, walk, wrapped_block
 from deepspeed_tpu.ops.attention import multihead_attention
 from deepspeed_tpu.ops import mla_prefill
 from deepspeed_tpu.ops.mla_decode_step import count_form, fused_mla_decode_step, supports
@@ -491,13 +491,14 @@ class SarvamMlaModel:
         _, _, s_max, w = state["latent"].shape
         return num_slots >= 2 and supports(s_max, w)
 
-    def _layers(self, params, x, latent, counts, idx, valid, walk_):
+    def _layers(self, params, x, leaves, counts, idx, valid, walk_):
+        (latent,) = leaves
         for kind, first, count in self.config.runs():
             block = functools.partial(self._block, kind=kind, shift=first)
             x, (latent, counts) = cached_walk(
                 block, x, self._stack(params, kind), (latent, counts), idx,
                 valid, walk_, count=count)
-        return x, latent, counts
+        return x, (latent,), counts
 
     def forward_with_cache(self, params, input_ids, cache):
         """Prefill (T > 1) or decode (T == 1) against the cache tree.
@@ -510,36 +511,13 @@ class SarvamMlaModel:
         row's last real position alone, ``[B, 1, V]``. The returned cache
         carries ``step_counters`` (models/moe_ffn.STEP_COUNTERS)."""
         c = self.config
-        b, t = input_ids.shape
-        idx = cache["index"]
-        valid = cache.get("valid_len")
-        if valid is not None:
-            valid = jnp.broadcast_to(jnp.asarray(valid, jnp.int32), (b,))
-        embed = params["embed"].astype(self.compute_dtype)
-        counts = jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
-        pb = c.prompt_block
-        if t > pb and t % pb == 0:
-            def block(carry, i):
-                latent, counts = carry
-                ids = jax.lax.dynamic_slice_in_dim(input_ids, i * pb, pb, 1)
-                x, latent, counts = self._layers(
-                    params, embed[ids], latent, counts, idx + i * pb,
-                    None if valid is None else jnp.clip(valid - i * pb, 0, pb),
-                    None)
-                return (latent, counts), x
-
-            (latent, counts), xs = jax.lax.scan(
-                block, (cache["latent"], counts), jnp.arange(t // pb))
-            x = xs.transpose(1, 0, 2, 3).reshape(b, t, -1)
-        else:
-            x, latent, counts = self._layers(
-                params, embed[input_ids], cache["latent"], counts, idx, valid,
-                cache.get("slot_walk"))
-        if t > 1 and valid is not None:
-            x = jnp.take_along_axis(
-                x, jnp.maximum(valid - 1, 0)[:, None, None], axis=1)
+        x, (latent,), counts = prompt_walk(
+            functools.partial(self._layers, params),
+            params["embed"].astype(self.compute_dtype), input_ids,
+            (cache["latent"],), jnp.zeros((len(STEP_COUNTERS),), jnp.int32),
+            cache, c.prompt_block)
         hidden = rms_norm(x, params["final_norm"], c.eps)
-        out = next_cache(cache, t, latent=latent)
+        out = next_cache(cache, input_ids.shape[1], latent=latent)
         out["step_counters"] = counts
         return self.logits(params, hidden), out
 

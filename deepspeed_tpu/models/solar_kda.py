@@ -41,6 +41,7 @@ told the prompt's true length (``valid_len``) computes its head there alone:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -50,7 +51,7 @@ from deepspeed_tpu.models.base import (cache_positions, cross_entropy_loss, gath
                                        project_heads, qdot, rms_norm, whole_leaves)
 from deepspeed_tpu.models.moe_ffn import (EXPERT_LEAVES, SPARSE, STEP_COUNTERS, ffn, gated_axes, gated_init,
                                           record_step_counters)
-from deepspeed_tpu.models.stack import cached_walk, kv_cache, next_cache, walk, wrapped_block
+from deepspeed_tpu.models.stack import cached_walk, kv_cache, next_cache, prompt_walk, walk, wrapped_block
 from deepspeed_tpu.ops import gqa_prefill, kda
 from deepspeed_tpu.ops.attention import (blocked_prompt_attention, cached_attention, multihead_attention,
                                          write_kv_cache)
@@ -476,10 +477,12 @@ class SolarKdaModel:
                              max_len, c.head_dim, dtype), kda=state,
                     kda_conv=tail)
 
-    def _layers(self, params, x, leaves, counts, idx, valid, walk_, step):
+    def _layers(self, params, x, leaves, counts, idx, valid, walk_):
         """``x`` through the stack against the cache's leaves ``(k, v, kda,
         kda_conv)`` -> ``(x, leaves, counts)``."""
         kc, vc, state, tail = leaves
+        b, t = x.shape[:2]
+        step = self._decode_step(params, valid, b) if t == 1 else None
         for kind, first, count in self.config.runs():
             if kind == KDA:
                 x, (state, tail, counts) = cached_walk(
@@ -505,38 +508,15 @@ class SolarKdaModel:
         real position alone, ``[B, 1, V]``. The returned cache carries
         ``step_counters`` (models/moe_ffn.STEP_COUNTERS)."""
         c = self.config
-        b, t = input_ids.shape
-        idx = cache["index"]
-        valid = cache.get("valid_len")
-        if valid is not None:
-            valid = jnp.broadcast_to(jnp.asarray(valid, jnp.int32), (b,))
-        embed = params["embed"].astype(self.compute_dtype)
-        counts = jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
-        leaves = (cache["k"], cache["v"], cache["kda"], cache["kda_conv"])
-        pb = c.prompt_block
-        if t > pb and t % pb == 0:
-            def block(carry, i):
-                *leaves, counts = carry
-                ids = jax.lax.dynamic_slice_in_dim(input_ids, i * pb, pb, 1)
-                x, leaves, counts = self._layers(
-                    params, embed[ids], tuple(leaves), counts, idx + i * pb,
-                    None if valid is None else jnp.clip(valid - i * pb, 0, pb),
-                    None, None)
-                return (*leaves, counts), x
-
-            (*leaves, counts), xs = jax.lax.scan(
-                block, (*leaves, counts), jnp.arange(t // pb))
-            x = xs.transpose(1, 0, 2, 3).reshape(b, t, -1)
-        else:
-            step = self._decode_step(params, valid, b) if t == 1 else None
-            x, leaves, counts = self._layers(
-                params, embed[input_ids], leaves, counts, idx, valid,
-                cache.get("slot_walk"), step)
-        if t > 1 and valid is not None:
-            x = jnp.take_along_axis(
-                x, jnp.maximum(valid - 1, 0)[:, None, None], axis=1)
+        x, leaves, counts = prompt_walk(
+            functools.partial(self._layers, params),
+            params["embed"].astype(self.compute_dtype), input_ids,
+            tuple(cache[k] for k in self.slot_state_keys),
+            jnp.zeros((len(STEP_COUNTERS),), jnp.int32), cache,
+            c.prompt_block)
         hidden = rms_norm(x, params["final_norm"], c.eps)
-        out = next_cache(cache, t, **dict(zip(self.slot_state_keys, leaves)))
+        out = next_cache(cache, input_ids.shape[1],
+                         **dict(zip(self.slot_state_keys, leaves)))
         out["step_counters"] = counts
         return self.logits(params, hidden), out
 

@@ -3,7 +3,8 @@
 A model file keeps its configuration, its parameter tree, its embedding, its
 head and its *block functions*. What is not the model's lives here: the
 training walk (:func:`wrapped_block`, :func:`walk`), the cached walk of
-prefill and decode (:func:`cached_walk`), and the key-value cache tree with
+prefill and decode (:func:`cached_walk`), the walk of a long prompt a token
+block at a time (:func:`prompt_walk`), and the key-value cache tree with
 the cache a step returns (:func:`kv_cache`, :func:`next_cache`). A stack is
 a dict of leaves with a leading ``layer`` dimension under one key of the
 params tree (``"blocks"``; the hybrid model has two).
@@ -88,6 +89,49 @@ def cached_walk(block, x, stack, state, idx, *args, count: int,
     (x, *state, _), _ = jax.lax.scan(
         body, (x, *state, jnp.full((), first, jnp.int32)), None, length=count)
     return x, tuple(state)
+
+
+def prompt_walk(layers, embed, input_ids, leaves: tuple, counts, cache,
+                prompt_block: int):
+    """The ids of a step (a prompt block, ``T > 1``, or one token a row)
+    through all of a family's layers against its cache: ``layers(x, leaves,
+    counts, idx, valid, slot_walk) -> (x, leaves, counts)`` is the family's
+    walk over its runs of equal layers, ``leaves`` its cache's stacked leaves
+    and ``counts`` its step counters. Of ``cache`` this reads ``index``,
+    ``valid_len`` (scalar or ``[B]``: how many of the positions are real for
+    each row) and ``slot_walk`` (the decode program's walk order).
+
+    A prompt of a whole number (> 1) of ``prompt_block`` passes the stack a
+    token block at a time, leaves and counts carried, ``valid`` clipped to
+    the block and no ``slot_walk``: what a layer holds for all its positions
+    at once (an expert buffer's rows, a block's scores) is then a block's and
+    not the prompt's. Any other length passes whole. Where ``valid_len`` is
+    given, a prompt's ``x`` comes back as each row's last real position
+    alone, ``[B, 1, D]``."""
+    b, t = input_ids.shape
+    idx, valid, pb = cache["index"], cache.get("valid_len"), prompt_block
+    if valid is not None:
+        valid = jnp.broadcast_to(jnp.asarray(valid, jnp.int32), (b,))
+    if t > pb and t % pb == 0:
+        def block(carry, i):
+            *leaves, counts = carry
+            ids = jax.lax.dynamic_slice_in_dim(input_ids, i * pb, pb, 1)
+            x, leaves, counts = layers(
+                embed[ids], tuple(leaves), counts, idx + i * pb,
+                None if valid is None else jnp.clip(valid - i * pb, 0, pb),
+                None)
+            return (*leaves, counts), x
+
+        (*leaves, counts), xs = jax.lax.scan(
+            block, (*leaves, counts), jnp.arange(t // pb))
+        x, leaves = xs.transpose(1, 0, 2, 3).reshape(b, t, -1), tuple(leaves)
+    else:
+        x, leaves, counts = layers(embed[input_ids], leaves, counts, idx,
+                                   valid, cache.get("slot_walk"))
+    if t > 1 and valid is not None:
+        x = jnp.take_along_axis(
+            x, jnp.maximum(valid - 1, 0)[:, None, None], axis=1)
+    return x, leaves, counts
 
 
 def kv_cache(layers: int, batch: int, kv_heads: int, max_len: int,

@@ -136,6 +136,20 @@ def test_a_padded_prompt_leaves_the_rings_of_the_unpadded_one(built, length,
 # attention projections were fenced from the per-head work behind them
 # (models/base.project_heads, merge_heads: an ordering, no arithmetic); the
 # reference's best logit leads its second by 8.7e-3 at least along them
+@pytest.fixture(scope="module")
+def eng():
+    """One ``InferenceEngine`` for the module (float32, 64 positions, seed 3):
+    its weights are made once, and every ``ServingEngine`` built on it shares
+    the programs it has compiled."""
+    import deepspeed_tpu
+    from deepspeed_tpu.utils import groups
+
+    groups.reset()
+    return deepspeed_tpu.init_inference(family.build_model(CFG, {}),
+                                        dtype="fp32", max_out_tokens=64,
+                                        seed=3)
+
+
 SERVED_BEFORE_THE_FENCE = [
     [318, 299, 107, 10, 32, 372],
     [242, 357, 401, 372, 454, 377, 172, 187, 475, 190, 118, 272],
@@ -145,19 +159,13 @@ SERVED_BEFORE_THE_FENCE = [
     [219, 348, 49, 57, 357]]
 
 
-def test_the_serving_engine_serves_it_over_two_sizes_of_state(built):
+def test_the_serving_engine_serves_it_over_two_sizes_of_state(built, eng):
     """init_inference + ServingEngine: bucketed slot prefill, per-slot
     decode, slots reused; every served token is the reference's argmax, and
     the one served before the fence."""
-    import deepspeed_tpu
     from deepspeed_tpu.serving import Request, ServingEngine
     from deepspeed_tpu.telemetry.registry import MetricsRegistry
-    from deepspeed_tpu.utils import groups
 
-    groups.reset()
-    eng = deepspeed_tpu.init_inference(family.build_model(CFG, {}),
-                                       dtype="fp32", max_out_tokens=64,
-                                       seed=3)
     reg = MetricsRegistry()
     srv = ServingEngine(eng, num_slots=3, max_len=64, buckets=(16, 32),
                         telemetry=reg, tenants=False)
@@ -193,7 +201,6 @@ def test_the_serving_engine_serves_it_over_two_sizes_of_state(built):
         3 * 4 * c["serving/slot_iterations_active"]
     assert 0 < c["serving/moe_assignments_held"] < \
         c["serving/moe_assignments"] / 3
-    groups.reset()
 
 
 # (query heads, key-value heads, head size): multi-head, rep 4, rep 8
@@ -240,19 +247,13 @@ def test_project_and_merge_heads_are_the_einsum_and_the_reshape(hq, hkv, dh):
                                     dict(speculative={"mode": "ngram"}),
                                     dict(preemption="swap"),
                                     dict(prefix_cache=True, kv_dtype="int8")])
-def test_the_engine_refuses_what_addresses_a_ring_by_token_rows(option):
-    import deepspeed_tpu
+def test_the_engine_refuses_what_addresses_a_ring_by_token_rows(eng, option):
     from deepspeed_tpu.serving import ServingEngine
     from deepspeed_tpu.serving.errors import EngineConfigError
-    from deepspeed_tpu.utils import groups
 
-    groups.reset()
-    eng = deepspeed_tpu.init_inference(family.build_model(CFG, {}),
-                                       dtype="fp32", max_out_tokens=64)
     with pytest.raises(EngineConfigError, match="k_win"):
         ServingEngine(eng, num_slots=2, max_len=64, buckets=(16,),
                       telemetry=None, **option)
-    groups.reset()
 
 
 def test_a_slots_window_bytes_do_not_grow_with_max_len():
@@ -547,16 +548,9 @@ def test_topkgate_names_the_layer_for_more_than_two_experts_a_token():
         TopKGate(16, 8, k=8)
 
 
-def test_generate_runs_the_whole_path_on_one_request(built):
+def test_generate_runs_the_whole_path_on_one_request(built, eng):
     """``InferenceEngine.generate`` (a scalar cache index) greedy-decodes
     what the reference's argmax gives."""
-    import deepspeed_tpu
-    from deepspeed_tpu.utils import groups
-
-    groups.reset()
-    eng = deepspeed_tpu.init_inference(family.build_model(CFG, {}),
-                                       dtype="fp32", max_out_tokens=64,
-                                       seed=5)
     prompt = np.random.RandomState(6).randint(0, 512, (1, 13))
     out = np.asarray(eng.generate(jnp.asarray(prompt, jnp.int32),
                                   max_new_tokens=12))
@@ -565,4 +559,3 @@ def test_generate_runs_the_whole_path_on_one_request(built):
         rows = reference.forward_logits(eng.params, jnp.asarray(out), CFG)[0]
     gap = rows[12:24].max(-1) - rows[jnp.arange(12, 24), out[0, 13:]]
     assert float(gap.max()) < 1e-4
-    groups.reset()

@@ -30,6 +30,14 @@ def make_engine(model, tp=1, dtype="fp32", **kw):
         dtype=dtype, tensor_parallel={"tp_size": tp}, **kw), topology=topo)
 
 
+@pytest.fixture(scope="module")
+def gpt2():
+    """The tiny float32 GPT-2 and its engine on one device's worth of mesh
+    (``tp=1``), as six tests take them unchanged: built once for the module."""
+    model = GPT2Model(GPT2Config.tiny(), compute_dtype=jnp.float32)
+    return model, make_engine(model)
+
+
 def full_forward_rollout(model, params, input_ids, n_new):
     """Reference loop: re-run the full (no-cache) forward for every token."""
     ids = np.asarray(input_ids)
@@ -56,10 +64,9 @@ def test_kv_cache_decode_matches_full_forward(model_cls, cfg):
     np.testing.assert_array_equal(out, ref)
 
 
-def test_prefill_logits_match_forward():
-    cfg = GPT2Config.tiny()
-    model = GPT2Model(cfg, compute_dtype=jnp.float32)
-    engine = make_engine(model)
+def test_prefill_logits_match_forward(gpt2):
+    model, engine = gpt2
+    cfg = model.config
     ids = np.random.RandomState(1).randint(0, cfg.vocab_size, (2, 10)).astype(np.int32)
     full = np.asarray(engine.forward(ids).astype(jnp.float32))
     cache = model.init_cache(2, 16, dtype=jnp.float32)
@@ -68,12 +75,11 @@ def test_prefill_logits_match_forward():
     assert int(cache["index"]) == 10
 
 
-def test_tp_generation_matches_single_device():
+def test_tp_generation_matches_single_device(gpt2):
     cfg = GPT2Config.tiny()
     prompt = np.random.RandomState(2).randint(0, cfg.vocab_size, (2, 8)).astype(np.int32)
 
-    model1 = GPT2Model(cfg, compute_dtype=jnp.float32)
-    e1 = make_engine(model1, tp=1)
+    _, e1 = gpt2
     params_host = jax.device_get(e1.params)
     out1 = e1.generate(prompt, max_new_tokens=5)
 
@@ -89,9 +95,8 @@ def test_tp_generation_matches_single_device():
     np.testing.assert_array_equal(out1, out2)
 
 
-def test_sampling_reproducible_and_topk():
-    cfg = GPT2Config.tiny()
-    engine = make_engine(GPT2Model(cfg, compute_dtype=jnp.float32))
+def test_sampling_reproducible_and_topk(gpt2):
+    _, engine = gpt2
     prompt = np.zeros((1, 4), np.int32)
     a = engine.generate(prompt, max_new_tokens=8, do_sample=True, top_k=5, seed=7)
     b = engine.generate(prompt, max_new_tokens=8, do_sample=True, top_k=5, seed=7)
@@ -134,10 +139,9 @@ def test_max_tokens_guard():
         engine.generate(np.zeros((1, 10), np.int32), max_new_tokens=10)
 
 
-def test_eos_stops_and_pads():
-    cfg = GPT2Config.tiny()
-    model = GPT2Model(cfg, compute_dtype=jnp.float32)
-    engine = make_engine(model)
+def test_eos_stops_and_pads(gpt2):
+    model, engine = gpt2
+    cfg = model.config
     prompt = np.random.RandomState(3).randint(0, cfg.vocab_size, (1, 6)).astype(np.int32)
     ref = full_forward_rollout(model, engine.params, prompt, 8)
     gen = ref[0, 6:]
@@ -171,9 +175,9 @@ def test_top_p_filter_matches_hf_warper():
         np.testing.assert_allclose(ours[kept], logits[kept], rtol=1e-6)
 
 
-def test_top_p_generate_reproducible():
-    cfg = GPT2Config.tiny()
-    engine = make_engine(GPT2Model(cfg, compute_dtype=jnp.float32))
+def test_top_p_generate_reproducible(gpt2):
+    model, engine = gpt2
+    cfg = model.config
     prompt = np.zeros((2, 4), np.int32)
     a = engine.generate(prompt, max_new_tokens=8, do_sample=True, top_p=0.9, seed=7)
     b = engine.generate(prompt, max_new_tokens=8, do_sample=True, top_p=0.9, seed=7)
@@ -184,13 +188,12 @@ def test_top_p_generate_reproducible():
         engine.generate(prompt, max_new_tokens=4, do_sample=True, top_p=0.0)
 
 
-def test_eos_early_exit_matches_scan_path():
+def test_eos_early_exit_matches_scan_path(gpt2):
     """The while_loop EOS path must emit exactly what the scan path emits up
     to (and including) EOS, padding after — and stop early when every row is
     done (behavioral check: outputs agree with the no-eos rollout prefix)."""
-    cfg = GPT2Config.tiny()
-    model = GPT2Model(cfg, compute_dtype=jnp.float32)
-    engine = make_engine(model)
+    model, engine = gpt2
+    cfg = model.config
     prompt = np.random.RandomState(5).randint(0, cfg.vocab_size, (2, 6)).astype(np.int32)
     free = engine.generate(prompt, max_new_tokens=10)  # no eos: scan path
     # pick an eos that appears in row 0's continuation; row 1 may not hit it

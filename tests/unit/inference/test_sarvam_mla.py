@@ -178,19 +178,27 @@ def test_slot_programs_pieces_give_the_references_logits(built):
     assert list(lengths) == [18, 10, 0]
 
 
-def test_the_serving_engine_serves_it_over_a_latent_leaf(built):
-    """init_inference + ServingEngine: bucketed slot prefill, per-slot
-    decode, three slots for five requests; every served token is the
-    reference's argmax."""
+@pytest.fixture(scope="module")
+def eng():
+    """One ``InferenceEngine`` for the module (float32, 64 positions, seed 3):
+    its weights are made once, and every ``ServingEngine`` built on it shares
+    the programs it has compiled."""
     import deepspeed_tpu
-    from deepspeed_tpu.serving import Request, ServingEngine
-    from deepspeed_tpu.telemetry.registry import MetricsRegistry
     from deepspeed_tpu.utils import groups
 
     groups.reset()
-    eng = deepspeed_tpu.init_inference(family.build_model(CFG, {}),
-                                       dtype="fp32", max_out_tokens=64,
-                                       seed=3)
+    return deepspeed_tpu.init_inference(family.build_model(CFG, {}),
+                                        dtype="fp32", max_out_tokens=64,
+                                        seed=3)
+
+
+def test_the_serving_engine_serves_it_over_a_latent_leaf(built, eng):
+    """init_inference + ServingEngine: bucketed slot prefill, per-slot
+    decode, three slots for five requests; every served token is the
+    reference's argmax."""
+    from deepspeed_tpu.serving import Request, ServingEngine
+    from deepspeed_tpu.telemetry.registry import MetricsRegistry
+
     reg = MetricsRegistry()
     srv = ServingEngine(eng, num_slots=3, max_len=64, buckets=(16, 32),
                         telemetry=reg, tenants=False)
@@ -227,7 +235,6 @@ def test_the_serving_engine_serves_it_over_a_latent_leaf(built):
     # 64 rows are no whole chunk of the fused walk: its two counters stay out
     assert "serving/decode_rows_live" not in c
     assert c["serving/prefill_rows_run"] == 16 + 32 + 32 + 16 + 32
-    groups.reset()
 
 
 def test_the_row_counters_follow_the_latent_walk(built):
@@ -366,31 +373,18 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(built):
                                     dict(speculative={"mode": "ngram"}),
                                     dict(preemption="swap"),
                                     dict(prefix_cache=True, kv_dtype="int8")])
-def test_the_engine_refuses_what_addresses_rows_of_key_value_pairs(option):
-    import deepspeed_tpu
+def test_the_engine_refuses_what_addresses_rows_of_key_value_pairs(eng, option):
     from deepspeed_tpu.serving import ServingEngine
     from deepspeed_tpu.serving.errors import EngineConfigError
-    from deepspeed_tpu.utils import groups
 
-    groups.reset()
-    eng = deepspeed_tpu.init_inference(family.build_model(CFG, {}),
-                                       dtype="fp32", max_out_tokens=64)
     with pytest.raises(EngineConfigError, match="one latent row a token"):
         ServingEngine(eng, num_slots=2, max_len=64, buckets=(16,),
                       telemetry=None, **option)
-    groups.reset()
 
 
-def test_generate_takes_the_einsum_route_over_the_same_leaf(built):
+def test_generate_takes_the_einsum_route_over_the_same_leaf(built, eng):
     """``generate()``: a uniform batch, scalar index; greedy tokens are the
     reference's argmax along the way."""
-    import deepspeed_tpu
-    from deepspeed_tpu.utils import groups
-
-    groups.reset()
-    eng = deepspeed_tpu.init_inference(family.build_model(CFG, {}),
-                                       dtype="fp32", max_out_tokens=64,
-                                       seed=3)
     prompt = jnp.asarray(np.random.RandomState(4).randint(0, 512, (2, 9)),
                          jnp.int32)
     out = np.asarray(eng.generate(prompt, max_new_tokens=6))
@@ -400,7 +394,6 @@ def test_generate_takes_the_einsum_route_over_the_same_leaf(built):
     gap = rows[:, 8:14].max(-1) - jnp.take_along_axis(
         rows[:, 8:14], jnp.asarray(out[:, 9:])[..., None], -1)[..., 0]
     assert float(gap.max()) < 1e-4
-    groups.reset()
 
 
 def test_a_chunked_prefill_continues_the_latent_rows(built):

@@ -196,19 +196,27 @@ def test_slot_programs_pieces_give_the_references_logits(built):
     assert list(lengths) == [18, 10, 0]
 
 
-def test_the_serving_engine_serves_it_over_recurrent_leaves(built):
-    """init_inference + ServingEngine: bucketed slot prefill, per-slot
-    decode, three slots for five requests; every served token is the
-    reference's argmax."""
+@pytest.fixture(scope="module")
+def eng():
+    """One ``InferenceEngine`` for the module (float32, 64 positions, seed 3):
+    its weights are made once, and every ``ServingEngine`` built on it shares
+    the programs it has compiled."""
     import deepspeed_tpu
-    from deepspeed_tpu.serving import Request, ServingEngine
-    from deepspeed_tpu.telemetry.registry import MetricsRegistry
     from deepspeed_tpu.utils import groups
 
     groups.reset()
-    eng = deepspeed_tpu.init_inference(family.build_model(CFG, {}),
-                                       dtype="fp32", max_out_tokens=64,
-                                       seed=3)
+    return deepspeed_tpu.init_inference(family.build_model(CFG, {}),
+                                        dtype="fp32", max_out_tokens=64,
+                                        seed=3)
+
+
+def test_the_serving_engine_serves_it_over_recurrent_leaves(built, eng):
+    """init_inference + ServingEngine: bucketed slot prefill, per-slot
+    decode, three slots for five requests; every served token is the
+    reference's argmax."""
+    from deepspeed_tpu.serving import Request, ServingEngine
+    from deepspeed_tpu.telemetry.registry import MetricsRegistry
+
     reg = MetricsRegistry()
     srv = ServingEngine(eng, num_slots=3, max_len=64, buckets=(16, 32),
                         telemetry=reg, tenants=False)
@@ -244,7 +252,6 @@ def test_the_serving_engine_serves_it_over_recurrent_leaves(built):
     assert 0 < c["serving/moe_assignments_held"] < c["serving/moe_assignments"]
     assert c["serving/prefill_rows_run"] == 16 + 32 + 32 + 16 + 32
     assert c["serving/prefill_rows_padding"] == 11 + 12 + 1 + 4 + 5
-    groups.reset()
 
 
 def test_the_eight_shares_add_up_to_the_uncut_layer(built):
@@ -291,31 +298,18 @@ def test_the_eight_shares_add_up_to_the_uncut_layer(built):
                                     dict(speculative={"mode": "ngram"}),
                                     dict(preemption="swap"),
                                     dict(prefix_cache=True, kv_dtype="int8")])
-def test_the_engine_refuses_what_addresses_token_rows(option):
-    import deepspeed_tpu
+def test_the_engine_refuses_what_addresses_token_rows(eng, option):
     from deepspeed_tpu.serving import ServingEngine
     from deepspeed_tpu.serving.errors import EngineConfigError
-    from deepspeed_tpu.utils import groups
 
-    groups.reset()
-    eng = deepspeed_tpu.init_inference(family.build_model(CFG, {}),
-                                       dtype="fp32", max_out_tokens=64)
     with pytest.raises(EngineConfigError, match="kda"):
         ServingEngine(eng, num_slots=2, max_len=64, buckets=(16,),
                       telemetry=None, **option)
-    groups.reset()
 
 
-def test_generate_takes_the_jnp_route_over_the_same_leaves(built):
+def test_generate_takes_the_jnp_route_over_the_same_leaves(built, eng):
     """``generate()``: a uniform batch, scalar index; greedy tokens are the
     reference's argmax along the way."""
-    import deepspeed_tpu
-    from deepspeed_tpu.utils import groups
-
-    groups.reset()
-    eng = deepspeed_tpu.init_inference(family.build_model(CFG, {}),
-                                       dtype="fp32", max_out_tokens=64,
-                                       seed=3)
     prompt = jnp.asarray(np.random.RandomState(4).randint(0, 512, (2, 9)),
                          jnp.int32)
     out = np.asarray(eng.generate(prompt, max_new_tokens=6))
@@ -325,7 +319,6 @@ def test_generate_takes_the_jnp_route_over_the_same_leaves(built):
     gap = rows[:, 8:14].max(-1) - jnp.take_along_axis(
         rows[:, 8:14], jnp.asarray(out[:, 9:])[..., None], -1)[..., 0]
     assert float(gap.max()) < 1e-4
-    groups.reset()
 
 
 def test_a_chunked_prefill_continues_state_tails_and_rows(built):
