@@ -1,28 +1,43 @@
-"""The feed-forward half of a decoder with a leading dense layer and sparse
-layers behind it, as two models here have it (``exaone_moe``, ``sarvam_mla``):
-a dense SwiGLU, or a shared expert plus the ``k`` routed experts a sigmoid
-router picks, of which the model HOLDS ``held = (first, count)``
-(moe/grouped.py). What is one model's (attention, the cache, the stack's
-order) stays in its file; the leaves' names, the layer's arithmetic and the
-counters a step returns are here, once.
+"""The feed-forward parts of a decoder with dense and sparse layers, as four
+models here have them (``exaone_moe``, ``sarvam_mla``, ``solar_kda``,
+``longcat_flash``): a dense SwiGLU, or the ``k`` routed experts a router
+picks, of which the model HOLDS ``held = (first, count)`` (moe/grouped.py),
+beside a shared expert where the layer has one. What is one model's
+(attention, the cache, the stack's order) stays in its file; the leaves'
+names, the layer's arithmetic and the counters a step returns are here, once.
 
     dense:  (silu(z Wg) * (z Wu)) Wd                      leaves ``w_*``
     sparse: Shared(z) + s * sum over the held of the chosen w_e Expert_e(z)
             leaves ``router``, ``select_bias``, ``shared_*``, ``expert_*``
+
+What a configuration may state beyond the four keys every one has
+(``num_experts_per_tok``, ``routed_scaling_factor``, ``norm_topk_prob``,
+``held``): ``scoring_func`` "softmax" for moe/grouped.softmax_topk_route in
+the sigmoid router's place, and ``zero_experts``, the router's last outputs
+that are ZERO-COMPUTE identity experts: a chosen one returns the token itself,
+
+    sparse += (s * sum over the chosen e >= E - zero_experts of w_e) z
+
+computed where the token is, whatever share is held: like a shared expert it
+is counted ONCE when the shares of a layer are added up. A layer without
+``shared_*`` leaves has no shared expert.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.models.base import qdot
-from deepspeed_tpu.moe.grouped import held_experts, sigmoid_topk_route
+from deepspeed_tpu.moe.grouped import held_experts, sigmoid_topk_route, softmax_topk_route
 
 DENSE, SPARSE = "dense", "sparse"
 # what a step counts on the device, in the order of the vector it returns
 STEP_COUNTERS = ("moe_experts_touched", "moe_experts_streamed",
-                 "moe_assignments_held", "moe_assignments")
+                 "moe_assignments_held", "moe_assignments",
+                 "moe_assignments_zero")
 EXPERT_LEAVES = ("expert_gate", "expert_up", "expert_down")
 
 
@@ -30,12 +45,14 @@ def record_step_counters(telemetry, counts) -> None:
     """A decode step's ``step_counters`` vector, fetched with the tokens,
     into the registry: held experts that got a token and held experts whose
     weights were read, summed over the sparse layers; (token, expert) pairs
-    routed here and all pairs of the step."""
-    touched, streamed, held, pairs = (int(n) for n in counts)
+    routed here, all pairs of the step, and those of them that went to
+    zero-compute experts."""
+    touched, streamed, held, pairs, zero = (int(n) for n in counts)
     telemetry.counter("serving/moe_experts_touched").inc(touched)
     telemetry.counter("serving/moe_experts_streamed").inc(streamed)
     telemetry.counter("serving/moe_assignments_held").inc(held)
     telemetry.counter("serving/moe_assignments").inc(pairs)
+    telemetry.counter("serving/moe_assignments_zero").inc(zero)
 
 
 def gated_init(init, keys, lead, d: int, width: int, prefix: str, dtype,
@@ -55,9 +72,10 @@ def gated_axes(prefix: str, *lead):
 
 
 def ffn(z, blk, kind: str, valid, c):
-    """-> ``(FFN(z), counts [4] int32)``; ``z [B, T, d]``; ``valid [B, T]``
-    bool or None; ``c`` the model's configuration (``num_experts_per_tok``,
-    ``routed_scaling_factor``, ``norm_topk_prob``, ``held``)."""
+    """-> ``(FFN(z), counts int32 in STEP_COUNTERS' order)``; ``z [B, T,
+    d]``; ``valid [B, T]`` bool or None; ``c`` the model's configuration
+    (``num_experts_per_tok``, ``routed_scaling_factor``, ``norm_topk_prob``,
+    ``held``; optional ``scoring_func``, ``zero_experts``)."""
 
     def gated(prefix):
         gate = jax.nn.silu(qdot("btd,dm->btm", z, blk[prefix + "gate"]))
@@ -69,11 +87,26 @@ def ffn(z, blk, kind: str, valid, c):
         return gated("w_"), jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
     b, t, d = z.shape
     flat = z.reshape(b * t, d)
-    routing = sigmoid_topk_route(
-        flat, blk["router"], blk["select_bias"], c.num_experts_per_tok,
-        scale=c.routed_scaling_factor, normalize=c.norm_topk_prob)
-    routed, counts = held_experts(
+    live = None if valid is None else valid.reshape(b * t)
+    route = softmax_topk_route \
+        if getattr(c, "scoring_func", "sigmoid") == "softmax" \
+        else functools.partial(sigmoid_topk_route, normalize=c.norm_topk_prob)
+    routing = route(flat, blk["router"], blk["select_bias"],
+                    c.num_experts_per_tok, scale=c.routed_scaling_factor)
+    y, counts = held_experts(
         flat, routing, blk["expert_gate"], blk["expert_up"],
-        blk["expert_down"], c.held,
-        valid=None if valid is None else valid.reshape(b * t))
-    return gated("shared_") + routed.reshape(b, t, d), jnp.stack(counts)
+        blk["expert_down"], c.held, valid=live)
+    zero = jnp.zeros((), jnp.int32)
+    if getattr(c, "zero_experts", 0):
+        # the identity experts are the router's last outputs: a chosen one
+        # adds its weight times the token, where the token is
+        chosen = routing.experts >= blk["router"].shape[-1] - c.zero_experts
+        if live is not None:
+            chosen &= live[:, None]
+        y = y + (jnp.where(chosen, routing.weights, 0.0).sum(-1, keepdims=True)
+                 * flat.astype(jnp.float32)).astype(y.dtype)
+        zero = chosen.sum().astype(jnp.int32)
+    y = y.reshape(b, t, d)
+    if "shared_gate" in blk:
+        y = gated("shared_") + y
+    return y, jnp.stack(counts + (zero,))

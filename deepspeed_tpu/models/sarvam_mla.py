@@ -15,28 +15,9 @@ experts HELD here (models/moe_ffn.py, shared with ``exaone_moe``).
 Rotation is rotate-half over the ``rope`` dimensions at YaRN's frequencies
 (ops/rotary.yarn_inv_freq); ``k_r`` is one row for all heads.
 
-**The cache** is one leaf ``latent [L, B, S, W]``: ``c~`` (``kv_lora_rank``),
-the rotated ``k_r`` behind it, zero lanes up to ``W``, a whole number of 128
-(512 + 64 -> 640): a token row that is no head's key or value. The model
-names it in ``slot_state_keys`` and in ``row_state_keys`` (models/base.py),
-and the serving layer handles it by that declaration (serving/kv_slots.py).
-
-**Two attention forms over it, the same numbers.** A prompt block takes the
-DECOMPRESSED form: a block of keys' ``k_nope`` and ``v`` are computed from
-their latents and attended at head sizes ``nope + rope`` / ``v``, key blocks
-walked up to the diagonal with a running softmax, so no score matrix over the
-context exists (:meth:`_prompt_attention`, scope ``dstpu_mla_prefill``): on a
-TPU ops/mla_prefill.py's one call a layer, which keeps a key block's scores in
-VMEM, elsewhere XLA's own matmuls in a ``lax`` loop. One token takes the
-ABSORBED form,
-
-    q^_h = q_nope_h W_UK[h]^T;  score_h(j) = s (q^_h . c~(j) + q_rope_h . k_r(j))
-    u_h = sum_j p_h(j) c~(j);   o_h = u_h W_UV[h]
-
-where a cached row is key and value at once and is read once: on a TPU under
-continuous batching ops/mla_decode_step.py's fused call, elsewhere an einsum
-over the same leaf. Absorbed, a prompt would cost 1,088 FLOPs a (query, key,
-head) for 320.
+**The cache leaf** ``latent [L, B, S, W]`` and the **two attention forms**
+over it (a prompt block decompressed, one token absorbed) are
+models/mla.py's, shared with ``longcat_flash``.
 
 **A prompt longer than ``prompt_block``** passes the whole stack a block of
 tokens at a time inside the one program call (write the block's rows, attend
@@ -55,14 +36,11 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import (cache_positions, cross_entropy_loss, gathered_top, merge_heads,
-                                       project_heads, qdot, rms_norm, whole_leaves)
+from deepspeed_tpu.models.base import cross_entropy_loss, gathered_top, project_heads, qdot, rms_norm, whole_leaves
+from deepspeed_tpu.models.mla import LatentAttention, latent_row_width
 from deepspeed_tpu.models.moe_ffn import (DENSE, EXPERT_LEAVES, SPARSE, STEP_COUNTERS, ffn, gated_axes,
                                           gated_init, record_step_counters)
 from deepspeed_tpu.models.stack import cached_walk, next_cache, prompt_walk, walk, wrapped_block
-from deepspeed_tpu.ops.attention import multihead_attention
-from deepspeed_tpu.ops import mla_prefill
-from deepspeed_tpu.ops.mla_decode_step import count_form, fused_mla_decode_step, supports
 from deepspeed_tpu.ops.rotary import apply_rotary_half_freqs, yarn_inv_freq, yarn_mscale
 
 
@@ -127,8 +105,7 @@ class SarvamMlaConfig:
 
     @property
     def row_width(self) -> int:
-        """Lanes of a cached row: latent and rotated key, padded to 128s."""
-        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+        return latent_row_width(self.kv_lora_rank, self.qk_rope_head_dim)
 
     @property
     def score_scale(self) -> float:
@@ -161,13 +138,10 @@ class SarvamMlaConfig:
                    intermediate_size=128, moe_intermediate_size=32, **kw)
 
 
-class SarvamMlaModel:
+class SarvamMlaModel(LatentAttention):
     """Causal-LM ModelSpec: batch = {"input_ids": [B,T], "labels": [B,T]}."""
 
     supports_weight_quant = False
-    # per-slot state: one leaf of token rows that is no head's key or value
-    slot_state_keys = ("latent",)
-    row_state_keys = ("latent",)
     step_counters = STEP_COUNTERS
     record_step_counters = staticmethod(record_step_counters)
 
@@ -256,145 +230,6 @@ class SarvamMlaModel:
         return (q[..., :n], apply_rotary_half_freqs(q[..., n:], pos, inv),
                 lat, k_r)
 
-    def _wkv_b(self, blk):
-        """The layer's ``Wkv_b [r, H * (nope + v)]``. The walk hands the
-        stack whole (:meth:`_stack`), so that the prompt kernel fetches a
-        head's columns where they lie; every other consumer is a matmul that
-        reads the layer's slice in place."""
-        w = blk["wkv_b"]
-        if isinstance(w, dict):
-            w = jax.lax.dynamic_index_in_dim(w["__whole__"], w["__layer__"],
-                                             0, keepdims=False)
-        return w.astype(self.compute_dtype)
-
-    def _up_projection(self, blk):
-        """``Wkv_b`` as ``[r, H, nope + v]``: head ``h``'s ``W_UK`` are its
-        first ``nope`` columns, ``W_UV`` the ``v`` behind them."""
-        c = self.config
-        return self._wkv_b(blk).reshape(
-            c.kv_lora_rank, c.num_heads, c.qk_nope_head_dim + c.v_head_dim)
-
-    def _prompt_attention(self, q_nope, q_rope, latent, layer, q_pos, blk,
-                          valid=None):
-        """The decompressed form over the cache's rows, a block of keys at a
-        time up to the diagonal, running softmax: ``q_* [B, T, H, .]`` at
-        the consecutive positions ``q_pos [B, T]``, of which the first
-        ``valid [B]`` are real (``None``: all), against ``latent[layer]``'s
-        rows, which already hold the block's own -> ``[B, T, H, v]``. On a
-        TPU ops/mla_prefill.py's one call where the shapes fit (the rows of a
-        query tile with no real position then come back as zeros: nothing
-        real attends them), elsewhere XLA's own matmuls in a ``lax`` loop."""
-        c = self.config
-        b, t, h, n = q_nope.shape
-        r, rope, vd = c.kv_lora_rank, c.qk_rope_head_dim, c.v_head_dim
-        s_max, w = latent.shape[2], latent.shape[3]
-        if jax.default_backend() == "tpu" and mla_prefill.supports(
-                s_max, w, c.key_block, t):
-            mla_prefill.count_traced()
-            wkv_b, w_layer = blk["wkv_b"], None
-            if isinstance(wkv_b, dict) and \
-                    wkv_b["__whole__"].dtype == latent.dtype:
-                wkv_b, w_layer = wkv_b["__whole__"], wkv_b["__layer__"]
-            else:                     # a cast is a copy: of the layer alone
-                wkv_b = self._wkv_b(blk).astype(latent.dtype)
-            with jax.named_scope("dstpu_mla_prefill"):
-                return mla_prefill.mla_prefill(
-                    q_nope, q_rope, latent, wkv_b, layer, q_pos[:, 0], valid,
-                    latent_width=r, scale=c.score_scale,
-                    key_block=c.key_block, w_layer=w_layer)
-        bk = c.key_block if s_max % c.key_block == 0 else s_max
-        f32 = jnp.float32
-        up = self._up_projection(blk)                     # [r, H, n + v]
-        blocks = jnp.minimum((jnp.max(q_pos) + bk) // bk, s_max // bk)
-        count_form(False)
-        # heads lead, queries and keys whole (nope | rope): one batched dot a
-        # block for the scores. Apart, the rotated key's product, which has no
-        # head dimension, was lowered as a convolution inside the row maximum
-        # and took longer than the scores themselves (PERF.md, PR 46)
-        q_all = jnp.concatenate([q_nope, q_rope], -1).transpose(0, 2, 1, 3)
-
-        def body(kb, carry):
-            m, l, acc = carry
-            rows = jax.lax.dynamic_slice(
-                latent, (layer, 0, kb * bk, 0), (1, b, bk, w))[0]
-            kv = jnp.einsum("bkc,che->bhke", rows[..., :r], up)
-            keys = jnp.concatenate(
-                [kv[..., :n], jnp.broadcast_to(
-                    rows[:, None, :, r:r + rope], (b, h, bk, rope))], -1)
-            s = jnp.einsum("bhtd,bhkd->bhtk", q_all, keys,
-                           preferred_element_type=f32) * c.score_scale
-            key_pos = kb * bk + jnp.arange(bk)
-            live = key_pos[None, None, :] <= q_pos[:, :, None]   # [B, T, bk]
-            s = jnp.where(live[:, None], s, -jnp.inf)
-            m_new = jnp.maximum(m, s.max(-1))
-            corr = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new[..., None])
-            pv = jnp.einsum("bhtk,bhkv->bhtv", p.astype(kv.dtype),
-                            kv[..., n:], preferred_element_type=f32)
-            return m_new, l * corr + p.sum(-1), acc * corr[..., None] + pv
-
-        # every query sees key 0, so the first block leaves a finite maximum
-        init = (jnp.full((b, h, t), -jnp.inf, f32), jnp.zeros((b, h, t), f32),
-                jnp.zeros((b, h, t, vd), f32))
-        with jax.named_scope("dstpu_mla_prefill"):
-            _, l, acc = jax.lax.fori_loop(0, blocks, body, init)
-        return (acc / l[..., None]).astype(q_nope.dtype).transpose(0, 2, 1, 3)
-
-    def _token_attention(self, q_nope, q_rope, row, latent, layer, idx, blk,
-                         walk_):
-        """The absorbed form for one token a row of the batch: ``q_nope [B,
-        H, n]``, ``q_rope [B, H, rope]``, ``row [B, W]`` the token's cache
-        row, not yet written -> ``(o [B, H, v], latent)``."""
-        c = self.config
-        b, h, n = q_nope.shape
-        r, w = c.kv_lora_rank, c.row_width
-        up = self._up_projection(blk)
-        fence = jax.lax.optimization_barrier
-        # batched over heads, the weight read where it lies: fenced from the
-        # per-head work on both sides (models/base.project_heads)
-        absorbed = fence(jnp.einsum("bhn,chn->bhc", q_nope, up[..., :n]))
-        qcat = jnp.concatenate(
-            [absorbed, q_rope,
-             jnp.zeros((b, h, w - r - q_rope.shape[-1]), q_nope.dtype)], -1)
-        per_slot = jnp.ndim(idx) == 1
-        fused = (per_slot and jax.default_backend() == "tpu"
-                 and self.fused_row_walk({"latent": latent}, b))
-        with jax.named_scope("dstpu_mla_decode"):
-            if fused:
-                count_form(True)
-                u, latent = fused_mla_decode_step(
-                    qcat, latent, row, layer, idx, value_width=r,
-                    scale=c.score_scale, active=walk_)
-            else:
-                latent = self._write_rows(latent, row[:, None], layer, idx)
-                rows = jax.lax.dynamic_index_in_dim(latent, layer, 0,
-                                                    keepdims=False)
-                s = jnp.einsum("bhw,bsw->bhs", qcat, rows,
-                               preferred_element_type=jnp.float32) \
-                    * c.score_scale
-                at = idx[:, None, None] if per_slot else idx
-                live = jnp.arange(rows.shape[1])[None, None, :] <= at
-                p = jax.nn.softmax(jnp.where(live, s, -jnp.inf), axis=-1)
-                u = jnp.einsum("bhs,bsc->bhc", p.astype(rows.dtype),
-                               rows[..., :r])
-        return jnp.einsum("bhc,chv->bhv", fence(u), up[..., n:]), latent
-
-    @staticmethod
-    def _write_rows(latent, rows, layer, idx):
-        """``rows [B, T, W]`` into ``latent[layer]`` from position ``idx`` on
-        (a scalar, or ``[B]``: each row of the batch at its own)."""
-        b, t, _ = rows.shape
-        rows = rows.astype(latent.dtype)
-        zero = jnp.zeros((), jnp.int32)
-        if jnp.ndim(idx) == 1 and b > 1:
-            slots = jnp.broadcast_to(jnp.arange(b)[:, None], (b, t))
-            pos = idx[:, None] + jnp.arange(t)[None, :]
-            return latent.at[layer, slots, pos].set(rows, mode="drop")
-        start = idx[0] if jnp.ndim(idx) == 1 else idx
-        return jax.lax.dynamic_update_slice(
-            latent, rows[None], (layer, zero, jnp.asarray(start, jnp.int32),
-                                 zero))
-
     # --------------------------------------------------------------- layers
     def _block(self, x, blk, state, layer, idx, valid, walk_, *, kind: str,
                shift: int = 0):
@@ -403,36 +238,11 @@ class SarvamMlaModel:
         shift``, and the step's counters. ``valid [B]``: the block's real
         positions a row; ``walk_``: the decode program's ``slot_walk``."""
         c = self.config
-        b, t, _ = x.shape
-        pos = cache_positions(0 if idx is None else idx, t)
-        q_nope, q_rope, lat, k_r = self._projections(x, blk, pos)
-        if state is None:
-            kv = project_heads(lat, self._wkv_b(blk), c.num_heads,
-                               c.qk_nope_head_dim + c.v_head_dim)
-            keys = jnp.concatenate(
-                [kv[..., :c.qk_nope_head_dim],
-                 jnp.broadcast_to(k_r[:, :, None], q_rope.shape)], -1)
-            out = multihead_attention(
-                jnp.concatenate([q_nope, q_rope], -1), keys,
-                kv[..., c.qk_nope_head_dim:], causal=True,
-                scale=c.score_scale)
-        else:
-            latent, counts = state
-            at = layer + shift
-            pad = c.row_width - lat.shape[-1] - k_r.shape[-1]
-            row = jnp.concatenate(
-                [lat, k_r, jnp.zeros((b, t, pad), lat.dtype)], -1)
-            if t == 1:
-                out, latent = self._token_attention(
-                    q_nope[:, 0], q_rope[:, 0], row[:, 0], latent, at, idx,
-                    blk, walk_)
-                out = out[:, None]
-            else:
-                latent = self._write_rows(latent, row, at, idx)
-                out = self._prompt_attention(
-                    q_nope, q_rope, latent, at,
-                    jnp.broadcast_to(pos, (b, t)), blk, valid)
-        x = x + merge_heads(out, blk["wo"])
+        t = x.shape[1]
+        latent, counts = (None, None) if state is None else state
+        x, latent = self._attention(
+            x, blk, latent, None if state is None else layer + shift, idx,
+            valid, walk_)
         z = rms_norm(x, blk["mlp_norm"], c.eps)
         tokens = None if valid is None else \
             jnp.arange(t)[None, :] < valid[:, None]
@@ -476,20 +286,8 @@ class SarvamMlaModel:
 
     # ------------------------------------------------------- inference path
     def init_cache(self, batch_size: int, max_len: int, dtype=None):
-        """``latent [L, B, max_len, W]`` and the index. The barrier makes
-        the zeros a real buffer (ops/attention.alloc_kv_cache)."""
-        c = self.config
-        return {"latent": jax.lax.optimization_barrier(jnp.zeros(
-            (c.num_layers, batch_size, max_len, c.row_width),
-            dtype or self.compute_dtype)), "index": jnp.zeros((), jnp.int32)}
-
-    def fused_row_walk(self, state, num_slots: int) -> bool:
-        """Whether a slot cache of these leaves routes a decode step to the
-        fused absorbed call on a TPU (the shapes' part of
-        :meth:`_token_attention`'s route): what serving/kv_slots.py asks of a
-        model with row leaves of its own."""
-        _, _, s_max, w = state["latent"].shape
-        return num_slots >= 2 and supports(s_max, w)
+        return self._latent_cache(self.config.num_layers, batch_size, max_len,
+                                  dtype)
 
     def _layers(self, params, x, leaves, counts, idx, valid, walk_):
         (latent,) = leaves

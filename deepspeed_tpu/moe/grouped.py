@@ -12,7 +12,10 @@ neither multiplied nor read).
 ``held=(first, count)`` is one chip's share of an expert-parallel layer: the
 router, the choice of ``k`` and the normalisation run over ALL experts, the
 sum runs over the chosen experts in ``[first, first + count)``, and what the
-other chips' experts would add is left out. The exchange between chips is not
+other chips' experts would add is left out. A pair whose expert lies outside
+every share of the real experts (a ZERO-COMPUTE expert, numbered behind them:
+:func:`softmax_topk_route`, models/moe_ffn.py) gets no row on any chip. The
+exchange between chips is not
 here (ROADMAP R2); summed over the shares ``(0, c), (c, c), ...`` the results
 give the uncut layer (tests/unit/inference/test_exaone_moe.py).
 
@@ -57,6 +60,21 @@ def sigmoid_topk_route(x, w_router, select_bias, k: int, *,
     w = jnp.take_along_axis(sigma, experts, axis=-1)
     if normalize:
         w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return Routing(experts.astype(jnp.int32), w * scale)
+
+
+def softmax_topk_route(x, w_router, select_bias, k: int, *,
+                       scale: float = 1.0) -> Routing:
+    """The router of a layer whose outputs are probabilities (LongCat-Flash):
+    ``p = softmax(x W)`` in float32 over all ``E`` outputs, zero-compute
+    experts included; the ``k`` with the largest ``p + select_bias`` (the bias
+    picks and does not weigh); ``w = scale * p_chosen``, NOT normalised over
+    the chosen."""
+    p = jax.nn.softmax(jnp.einsum(
+        "nd,de->ne", x.astype(jnp.float32), w_router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    _, experts = jax.lax.top_k(p + select_bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(p, experts, axis=-1)
     return Routing(experts.astype(jnp.int32), w * scale)
 
 

@@ -168,7 +168,7 @@ def _layer(head_dim=D, key_block=BK, s_max=S, t=T, packed=False):
         kc = jnp.asarray(rng.randn(2, 1, c.num_kv_heads, s_max, head_dim),
                          jnp.float32)
         vc = kc[::-1] * 0.5
-    counts = jnp.zeros((4,), jnp.int32)
+    counts = jnp.zeros((len(model.step_counters),), jnp.int32)
 
     def run():
         before = _counters()

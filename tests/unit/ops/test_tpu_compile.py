@@ -529,6 +529,16 @@ def _sarvam_cell():
         held=(0, 16))), 16, 16384
 
 
+def _longcat_cell():
+    from deepspeed_tpu.models.longcat_flash import (LongcatFlashConfig,
+                                                    LongcatFlashModel)
+
+    # two of the cell's four double layers: one loop, as the cell's
+    return LongcatFlashModel(LongcatFlashConfig(
+        vocab_size=16384, max_seq_len=4096, num_layers=2,
+        held=(0, 16))), 32, 4096
+
+
 def _solar_cell():
     from deepspeed_tpu.models.solar_kda import SolarKdaConfig, SolarKdaModel
 
@@ -557,10 +567,10 @@ def _assert_copies_no_weight(compiled, leaves):
 
 @pytest.mark.parametrize("cell", [_exaone_cell, _granite_cell,
                                   _gpt2_large_cell, _sarvam_cell,
-                                  _solar_cell],
+                                  _solar_cell, _longcat_cell],
                          ids=["k-exaone", "granite-4.0-h-micro",
                               "gpt2-large", "sarvam-105b",
-                              "solar-open2-250b"])
+                              "solar-open2-250b", "longcat-flash-chat"])
 def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
     """The serve cells' decode step (``InferenceEngine.slot_decode_program``'s
     call of the model: one token a slot, per-slot lengths, the slot walk) at
