@@ -621,16 +621,29 @@ class InferenceEngine:
         return tuple(range(1, n_state + 2)) \
             if jax.default_backend() == "tpu" else ()
 
-    @staticmethod
-    def _with_first_token(carry: tuple, token, slot, behind) -> tuple:
+    def _with_first_token(self, carry: tuple, token, slot, behind,
+                          cache) -> tuple:
         """A slot prefill program's outputs: the carry, the picked token,
-        and where the serving loop passed the decode step's previous tokens
-        (``behind``), those with the token written at ``slot``."""
+        where the serving loop passed the decode step's previous tokens
+        (``behind``), those with the token written at ``slot``, and for a
+        model that counts what a prompt block did (:meth:`_count_prompt`)
+        the returned ``cache``'s counts last, so that the host fetches them
+        with the token."""
         out = (*carry, token)
         if behind:
             out += (jax.lax.dynamic_update_index_in_dim(
                 behind[0], token, slot, 0),)
+        if "prompt_counts" in cache:
+            out += (cache["prompt_counts"],)
         return out
+
+    def _count_prompt(self, cache) -> None:
+        """Ask a model that counts what a prompt block did
+        (``model.prompt_counters``) for those counts: it returns
+        ``cache["prompt_counts"]`` with them added."""
+        counted = len(getattr(self.module, "prompt_counters", ()))
+        if counted:
+            cache["prompt_counts"] = jnp.zeros((counted,), jnp.int32)
 
     @staticmethod
     def _last_logits(logits, length):
@@ -669,8 +682,9 @@ class InferenceEngine:
 
         Signature of the returned program:
         ``(params, *state, lengths, ids[1, bucket], slot, length, temp,
-        rng[, previous[B]]) -> (*state, lengths, first_token[, previous])``
-        (cache operands donated on TPU). The serving loop passes
+        rng[, previous[B]]) -> (*state, lengths, first_token[, previous][,
+        prompt_counts])`` (cache operands donated on TPU;
+        :meth:`_with_first_token`). The serving loop passes
         ``previous``, the decode step's next tokens on the device (see
         :meth:`slot_decode_program`), and gets it back with the first token
         written at ``slot``: the next decode step can then be launched
@@ -690,6 +704,7 @@ class InferenceEngine:
                 state = dict(zip(names, leaves))
                 cache = model.init_cache(1, bucket_len, dtype=self.dtype)
                 cache["valid_len"] = length
+                self._count_prompt(cache)
                 with jax.named_scope("dstpu_prefill"):
                     logits, cache = model.forward_with_cache(params, ids,
                                                              cache)
@@ -704,7 +719,7 @@ class InferenceEngine:
                 last = self._last_logits(logits, length)         # [1, V]
                 return self._with_first_token(
                     (*(state[n] for n in names), lengths),
-                    pick(last, temp, rng)[0], slot, behind)
+                    pick(last, temp, rng)[0], slot, behind, cache)
 
             self._compiled[key] = _named_jit(
                 f"prefill_{bucket_len}", prefill,
@@ -1094,8 +1109,9 @@ class InferenceEngine:
 
         Signature: ``(params, *state, lengths, ids[1, bucket], slot, start,
         length, temp, rng[, previous[B]]) -> (*state, lengths, token[,
-        previous])`` with the state's leaves and the trailing operand as in
-        :meth:`slot_prefill_program` (cache operands donated on TPU)."""
+        previous][, prompt_counts])`` with the state's leaves and the
+        trailing operand as in :meth:`slot_prefill_program` (cache operands
+        donated on TPU)."""
         key = ("slot_chunk_pf", bucket_len, num_slots, max_len, do_sample,
                top_k, float(top_p))
         if key not in self._compiled:
@@ -1111,6 +1127,7 @@ class InferenceEngine:
                 idx = jnp.reshape(jnp.asarray(start, jnp.int32), (1,))
                 cache = {n: extract_slot_row(state[n], slot) for n in names}
                 cache.update(index=idx, valid_len=length)
+                self._count_prompt(cache)
                 logits, cache = model.forward_with_cache(params, ids, cache)
                 for name in names:
                     state[name] = insert_slot_row(state[name], cache[name],
@@ -1120,7 +1137,7 @@ class InferenceEngine:
                 last = self._last_logits(logits, length)         # [1, V]
                 return self._with_first_token(
                     (*(state[n] for n in names), lengths),
-                    pick(last, temp, rng)[0], slot, behind)
+                    pick(last, temp, rng)[0], slot, behind, cache)
 
             self._compiled[key] = _named_jit(
                 f"chunk_prefill_{bucket_len}", chunk,

@@ -30,8 +30,9 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.models.base import (cache_positions, cross_entropy_loss, gathered_top, merge_heads,
                                        project_heads, rms_norm, whole_leaves)
-from deepspeed_tpu.models.moe_ffn import (DENSE, EXPERT_LEAVES, SPARSE, STEP_COUNTERS, gated_axes,
-                                          gated_init, record_step_counters)
+from deepspeed_tpu.models.moe_ffn import (DENSE, EXPERT_LEAVES, PROMPT_COUNTERS, SPARSE, STEP_COUNTERS,
+                                          carried_counts, gated_axes, gated_init, record_prompt_counters,
+                                          record_step_counters, zero_counts)
 from deepspeed_tpu.models.moe_ffn import ffn as ffn_layer
 from deepspeed_tpu.models.stack import cached_walk, kv_cache, next_cache, walk, wrapped_block
 from deepspeed_tpu.ops.attention import cached_attention, multihead_attention, window_cached_attention
@@ -149,6 +150,8 @@ class ExaoneMoeModel:
     # ring leaves and the window they hold: SlotKVCache counts their rows
     window_state_keys = ("k_win", "v_win")
     step_counters = STEP_COUNTERS
+    prompt_counters = PROMPT_COUNTERS
+    record_prompt_counters = staticmethod(record_prompt_counters)
 
     def __init__(self, config: ExaoneMoeConfig, compute_dtype=jnp.bfloat16,
                  param_dtype=jnp.float32, remat: bool = False,
@@ -338,7 +341,7 @@ class ExaoneMoeModel:
         x = params["embed"].astype(self.compute_dtype)[input_ids]
         leaves = {GLOBAL: (cache["k"], cache["v"]),
                   SLIDING: (cache["k_win"], cache["v_win"])}
-        counts = jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
+        counts = zero_counts(t)
         for ffn, attn, first, first_cache, count in c.runs():
             block = functools.partial(self._block, ffn=ffn, attn=attn,
                                       shift=first_cache - first)
@@ -350,7 +353,7 @@ class ExaoneMoeModel:
         hidden = rms_norm(x, params["final_norm"], c.eps)
         out = next_cache(cache, t, k=leaves[GLOBAL][0], v=leaves[GLOBAL][1],
                          k_win=leaves[SLIDING][0], v_win=leaves[SLIDING][1])
-        out["step_counters"] = counts
+        out.update(carried_counts(cache, counts))
         return self.logits(params, hidden), out
 
     def num_params(self) -> int:

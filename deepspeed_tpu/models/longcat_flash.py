@@ -58,8 +58,9 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.models.base import cross_entropy_loss, gathered_top, project_heads, qdot, rms_norm, whole_leaves
 from deepspeed_tpu.models.mla import LatentAttention, latent_row_width
-from deepspeed_tpu.models.moe_ffn import (DENSE, EXPERT_LEAVES, SPARSE, STEP_COUNTERS, ffn, gated_axes,
-                                          gated_init, record_step_counters)
+from deepspeed_tpu.models.moe_ffn import (DENSE, EXPERT_LEAVES, PROMPT_COUNTERS, SPARSE, STEP_COUNTERS,
+                                          carried_counts, ffn, gated_axes, gated_init, record_prompt_counters,
+                                          record_step_counters, zero_counts)
 from deepspeed_tpu.models.stack import cached_walk, next_cache, prompt_walk, walk, wrapped_block
 from deepspeed_tpu.ops.rotary import apply_rotary_half
 
@@ -165,6 +166,8 @@ class LongcatFlashModel(LatentAttention):
 
     supports_weight_quant = False
     step_counters = STEP_COUNTERS
+    prompt_counters = PROMPT_COUNTERS
+    record_prompt_counters = staticmethod(record_prompt_counters)
     record_step_counters = staticmethod(record_step_counters)
 
     def __init__(self, config: LongcatFlashConfig, compute_dtype=jnp.bfloat16,
@@ -345,11 +348,11 @@ class LongcatFlashModel(LatentAttention):
         x, (latent,), counts = prompt_walk(
             functools.partial(self._layers, params),
             params["embed"].astype(self.compute_dtype), input_ids,
-            (cache["latent"],), jnp.zeros((len(STEP_COUNTERS),), jnp.int32),
+            (cache["latent"],), zero_counts(input_ids.shape[1]),
             cache, c.prompt_block)
         hidden = rms_norm(x, params["final_norm"], c.eps)
         out = next_cache(cache, input_ids.shape[1], latent=latent)
-        out["step_counters"] = counts
+        out.update(carried_counts(cache, counts))
         return self.logits(params, hidden), out
 
     def num_params(self) -> int:

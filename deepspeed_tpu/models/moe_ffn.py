@@ -38,7 +38,32 @@ DENSE, SPARSE = "dense", "sparse"
 STEP_COUNTERS = ("moe_experts_touched", "moe_experts_streamed",
                  "moe_assignments_held", "moe_assignments",
                  "moe_assignments_zero")
+# what a prompt block counts behind them (T > 1 alone: a decode step's vector
+# is STEP_COUNTERS'): calls of a sparse layer, those whose pairs outgrew the
+# compact sorted buffer, and those that held no pair (moe/grouped.held_experts)
+PROMPT_COUNTERS = ("moe_prompt_blocks", "moe_prompt_blocks_spilled",
+                   "moe_prompt_blocks_empty")
 EXPERT_LEAVES = ("expert_gate", "expert_up", "expert_down")
+
+
+def zero_counts(t: int):
+    """The counters a walk over ``t`` positions a row starts from."""
+    return jnp.zeros(
+        (len(STEP_COUNTERS) + (len(PROMPT_COUNTERS) if t > 1 else 0),),
+        jnp.int32)
+
+
+def carried_counts(cache, counts) -> dict:
+    """A walk's counters as the cache it returns carries them:
+    ``step_counters`` (STEP_COUNTERS'), and for a caller that asked by
+    handing ``cache["prompt_counts"]`` in (a serving prefill program; a cache
+    that is a loop's carry keeps its keys) a prompt's PROMPT_COUNTERS added
+    to those."""
+    n = len(STEP_COUNTERS)
+    out = {"step_counters": counts[:n] if counts.shape[0] > n else counts}
+    if "prompt_counts" in cache:
+        out["prompt_counts"] = cache["prompt_counts"] + counts[n:]
+    return out
 
 
 def record_step_counters(telemetry, counts) -> None:
@@ -53,6 +78,17 @@ def record_step_counters(telemetry, counts) -> None:
     telemetry.counter("serving/moe_assignments_held").inc(held)
     telemetry.counter("serving/moe_assignments").inc(pairs)
     telemetry.counter("serving/moe_assignments_zero").inc(zero)
+
+
+def record_prompt_counters(telemetry, counts) -> None:
+    """The ``prompt_counts`` of the prefill programs a prompt took (``[
+    programs, 3]``, fetched with its first token), into the registry: token
+    blocks through a sparse layer, those whose pairs outgrew the compact
+    sorted buffer, and those that held no pair."""
+    blocks, spilled, empty = (int(n) for n in sum(counts))
+    telemetry.counter("serving/moe_prompt_blocks").inc(blocks)
+    telemetry.counter("serving/moe_prompt_blocks_spilled").inc(spilled)
+    telemetry.counter("serving/moe_prompt_blocks_empty").inc(empty)
 
 
 def gated_init(init, keys, lead, d: int, width: int, prefix: str, dtype,
@@ -72,10 +108,15 @@ def gated_axes(prefix: str, *lead):
 
 
 def ffn(z, blk, kind: str, valid, c):
-    """-> ``(FFN(z), counts int32 in STEP_COUNTERS' order)``; ``z [B, T,
-    d]``; ``valid [B, T]`` bool or None; ``c`` the model's configuration
+    """-> ``(FFN(z), counts int32 as zero_counts(T))``; ``z [B, T, d]``;
+    ``valid [B, T]`` bool or None; ``c`` the model's configuration
     (``num_experts_per_tok``, ``routed_scaling_factor``, ``norm_topk_prob``,
-    ``held``; optional ``scoring_func``, ``zero_experts``)."""
+    ``held``; optional ``scoring_func``, ``zero_experts``).
+
+    ``T`` says what is walked: a decode step (``T == 1``) keeps the expert
+    layer's worst-case buffer, two or three row tiles; a prompt block hands
+    the router's width on, and its buffer follows the pairs held
+    (moe/grouped.held_experts)."""
 
     def gated(prefix):
         gate = jax.nn.silu(qdot("btd,dm->btm", z, blk[prefix + "gate"]))
@@ -83,9 +124,9 @@ def ffn(z, blk, kind: str, valid, c):
                                                blk[prefix + "up"]),
                     blk[prefix + "down"])
 
-    if kind == DENSE:
-        return gated("w_"), jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
     b, t, d = z.shape
+    if kind == DENSE:
+        return gated("w_"), zero_counts(t)
     flat = z.reshape(b * t, d)
     live = None if valid is None else valid.reshape(b * t)
     route = softmax_topk_route \
@@ -95,7 +136,8 @@ def ffn(z, blk, kind: str, valid, c):
                     c.num_experts_per_tok, scale=c.routed_scaling_factor)
     y, counts = held_experts(
         flat, routing, blk["expert_gate"], blk["expert_up"],
-        blk["expert_down"], c.held, valid=live)
+        blk["expert_down"], c.held, valid=live,
+        n_experts=blk["router"].shape[-1] if t > 1 else None)
     zero = jnp.zeros((), jnp.int32)
     if getattr(c, "zero_experts", 0):
         # the identity experts are the router's last outputs: a chosen one
@@ -109,4 +151,9 @@ def ffn(z, blk, kind: str, valid, c):
     y = y.reshape(b, t, d)
     if "shared_gate" in blk:
         y = gated("shared_") + y
-    return y, jnp.stack(counts + (zero,))
+    step = (counts.touched, counts.streamed, counts.assignments_held,
+            counts.assignments, zero)
+    if t > 1:       # PROMPT_COUNTERS
+        step += (jnp.ones((), jnp.int32), counts.spilled,
+                 (counts.assignments_held == 0).astype(jnp.int32))
+    return y, jnp.stack(step)

@@ -49,8 +49,9 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.models.base import (cache_positions, cross_entropy_loss, gathered_top, merge_heads,
                                        project_heads, qdot, rms_norm, whole_leaves)
-from deepspeed_tpu.models.moe_ffn import (EXPERT_LEAVES, SPARSE, STEP_COUNTERS, ffn, gated_axes, gated_init,
-                                          record_step_counters)
+from deepspeed_tpu.models.moe_ffn import (EXPERT_LEAVES, PROMPT_COUNTERS, SPARSE, STEP_COUNTERS, carried_counts, ffn,
+                                          gated_axes, gated_init, record_prompt_counters, record_step_counters,
+                                          zero_counts)
 from deepspeed_tpu.models.stack import cached_walk, kv_cache, next_cache, prompt_walk, walk, wrapped_block
 from deepspeed_tpu.ops import gqa_prefill, kda
 from deepspeed_tpu.ops.attention import (blocked_prompt_attention, cached_attention, multihead_attention,
@@ -158,6 +159,8 @@ class SolarKdaModel:
     # the delta rule's state and the convolutions' tails on the others
     slot_state_keys = ("k", "v", "kda", "kda_conv")
     step_counters = STEP_COUNTERS
+    prompt_counters = PROMPT_COUNTERS
+    record_prompt_counters = staticmethod(record_prompt_counters)
     record_step_counters = staticmethod(record_step_counters)
     # the state adds thousands of rank-one corrections to a decaying sum:
     # float32 whatever the compute dtype (4.19 MB a layer a slot at the
@@ -512,12 +515,12 @@ class SolarKdaModel:
             functools.partial(self._layers, params),
             params["embed"].astype(self.compute_dtype), input_ids,
             tuple(cache[k] for k in self.slot_state_keys),
-            jnp.zeros((len(STEP_COUNTERS),), jnp.int32), cache,
+            zero_counts(input_ids.shape[1]), cache,
             c.prompt_block)
         hidden = rms_norm(x, params["final_norm"], c.eps)
         out = next_cache(cache, input_ids.shape[1],
                          **dict(zip(self.slot_state_keys, leaves)))
-        out["step_counters"] = counts
+        out.update(carried_counts(cache, counts))
         return self.logits(params, hidden), out
 
     def num_params(self) -> int:

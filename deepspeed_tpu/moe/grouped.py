@@ -28,10 +28,17 @@ tried a decode step that multiplies every held expert: 4 ms a step slower,
 and no steadier; the benchmark's cell serves one checkpoint for that reason).
 
 Shapes are static: a token's ``k`` experts are distinct, so at most
-``min(k, count)`` of its pairs are held, and the sorted buffer has
-``N * min(k, count)`` rows whatever the routing; rows behind the last group
-are masked. ``TopKGate`` / ``top1gating`` / ``top2gating`` (sharded_moe.py)
-stay the capacity-einsum dispatch of ``gpt_moe``.
+``min(k, count)`` of its pairs are held, and a sorted buffer of
+``N * min(k, count)`` rows holds them whatever the routing; rows behind the
+last group are masked. A decode step has that buffer, two or three row tiles.
+A prompt block is routed ``k * count / E`` pairs a token on average, an eighth
+or a forty-eighth of the worst case, and everything computed a row would run
+over the rest for nothing: its buffer is one of two static sizes, chosen on
+the device by the pairs it holds (:func:`held_experts`, :func:`compact_rows`):
+twice the expectation, or the worst case when a router crowds this chip, so
+that no pair is ever dropped; a block that is all padding passes the layer
+by. ``TopKGate`` / ``top1gating`` / ``top2gating`` (sharded_moe.py) stay the
+capacity-einsum dispatch of ``gpt_moe``.
 """
 
 from __future__ import annotations
@@ -84,10 +91,43 @@ class ExpertCounts(NamedTuple):
     streamed: jax.Array          # held experts whose weights the call read
     assignments_held: jax.Array  # pairs routed to held experts
     assignments: jax.Array       # all pairs of the valid tokens
+    spilled: jax.Array           # 1: a prompt block's pairs outgrew the
+    #                              compact buffer and the full one ran
+
+
+# what XLA's grouped matmul makes its row tiles of (ROADMAP S11): the compact
+# buffer is whole tiles
+ROW_TILE = 128
+
+
+def compact_rows(n: int, k: int, count: int, n_experts: int) -> int:
+    """The rows of a prompt block's compact sorted buffer: twice the pairs
+    ``n`` tokens send to ``count`` of ``n_experts`` experts when the router
+    spreads them evenly (``n * k * count / n_experts``; about 48 standard
+    deviations of a binomial at 2,048 tokens an eighth held), in whole row
+    tiles. 4,096 of 16,384 rows at 2,048 tokens, ``k`` 8, an eighth held;
+    1,024 of 24,576 at ``k`` 12, 16 of 768 held."""
+    twice = -(-2 * n * k * count // n_experts)
+    return -(-twice // ROW_TILE) * ROW_TILE
+
+
+def count_traced(compact: bool) -> None:
+    """Say in the program's registry how a prompt's expert layer was traced:
+    ``moe/traced_prompt_compact`` (the three-way switch of
+    :func:`held_experts`) or ``moe/traced_prompt_full`` (the worst-case
+    buffer alone: a block so short that its compact buffer would be no
+    smaller). Both exist from the first call on."""
+    from deepspeed_tpu.telemetry.registry import get_registry
+
+    reg = get_registry()
+    counters = [reg.counter("moe/traced_prompt_" + n)
+                for n in ("full", "compact")]
+    counters[bool(compact)].inc()
 
 
 def held_experts(x, routing: Routing, w_gate, w_up, w_down,
-                 held: Tuple[int, int], *, valid: Optional[jax.Array] = None):
+                 held: Tuple[int, int], *, valid: Optional[jax.Array] = None,
+                 n_experts: Optional[int] = None):
     """Sum over the chosen experts held here of ``w_e * Expert_e(x)``, each a
     gated MLP ``(silu(x Wg) * (x Wu)) Wd``.
 
@@ -100,18 +140,39 @@ def held_experts(x, routing: Routing, w_gate, w_up, w_down,
     copy of the layer's experts (1.2 GB at 16 x 3 x 6144 x 2048) every step.
     ``valid [N]`` (bool): tokens that are real (bucket padding and slots that
     do not decode get no pair, touch no expert and come out zero).
+
+    ``n_experts``: the router's width, which the caller states for a PROMPT
+    block (``models/moe_ffn.ffn`` knows ``T``; this function sees ``N``
+    alone, and a decode step of many slots is as long as a short prompt). It
+    is what lets the sorted buffer follow the pairs held: their number is on
+    the device before any row-sized work, and ``lax.switch`` picks by it
+
+    - empty (no pair held: a token block that is all padding): zeros; no
+      gather, no grouped matmul, no combine;
+    - compact (at most :func:`compact_rows` pairs): the buffer, and all that
+      is computed a row of it, has that many rows;
+    - full (more: a router that crowds this chip; ``ExpertCounts.spilled``):
+      the worst case, ``N * min(k, count)`` rows.
+
+    A row's arithmetic, the order of the sum over a token's ``k`` and the
+    three grouped matmuls against the whole stacks are the same in the
+    compact and the full branch and the buffer holds every held pair in
+    both, so the result is the same bit for bit whichever runs: no pair is
+    dropped, there is no capacity. Without ``n_experts`` (a decode step,
+    whose buffer is two or three row tiles), or where the compact buffer
+    would be no smaller than the full one, there is no switch and the full
+    buffer alone.
     -> ``(y [N, d], ExpertCounts)``."""
     first, count = held
     n, k = routing.experts.shape
-    rows = n * min(k, count)
+    full = n * min(k, count)
     local = routing.experts - first
     here = (local >= 0) & (local < count)
     if valid is not None:
         here &= valid[:, None]
     # pairs of held experts first, by expert; the rest behind them
     key = jnp.where(here, local, count).reshape(-1)
-    order = jnp.argsort(key, stable=True)[:rows]
-    token = order // k
+    by_expert = jnp.argsort(key, stable=True)
     sizes = jnp.sum(jax.nn.one_hot(key, count, dtype=jnp.int32), axis=0)
     total = sizes.sum()
 
@@ -126,21 +187,50 @@ def held_experts(x, routing: Routing, w_gate, w_up, w_down,
         assert w.shape[0] == groups.shape[0], (w.shape, held)
         return jax.lax.ragged_dot(lhs, w.astype(lhs.dtype), groups)
 
-    with jax.named_scope("dstpu_moe_experts"):
-        xs = x[token]
-        h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
-        ys = grouped(h, w_down)
-    w = routing.weights.reshape(-1)[order]
-    # rows behind the last group hold whatever the kernel left there
-    ys = jnp.where((jnp.arange(rows) < total)[:, None],
-                   ys.astype(jnp.float32) * w[:, None], 0.0)
-    # back to tokens: a pair's row in the sorted buffer, then the k of a
-    # token summed (a gather; a scatter-add of the rows serialises on a TPU)
-    rank = jnp.zeros((n * k,), jnp.int32).at[order].set(
-        jnp.arange(rows, dtype=jnp.int32))
-    picked = jnp.where(here.reshape(-1)[:, None],
-                       ys[jnp.minimum(rank, rows - 1)], 0.0)
-    y = picked.reshape(n, k, -1).sum(1).astype(x.dtype)
+    def experts(rows: int, prompt: bool):
+        """The layer through a sorted buffer of ``rows`` rows, which must
+        hold every held pair. A decode step weighs the buffer's rows and
+        gathers them in float32; a prompt block gathers what the last matmul
+        wrote and weighs at the pair, in the reduction: the same products
+        summed in the same order, and two float32 passes over the buffer and
+        half the gather's bytes less."""
+        order = by_expert[:rows]
+        token = order // k
+        with jax.named_scope("dstpu_moe_experts"):
+            xs = x[token]
+            h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+            ys = grouped(h, w_down)
+        if not prompt:
+            w = routing.weights.reshape(-1)[order]
+            # rows behind the last group hold whatever the kernel left there
+            ys = jnp.where((jnp.arange(rows) < total)[:, None],
+                           ys.astype(jnp.float32) * w[:, None], 0.0)
+        # back to tokens: a pair's row in the sorted buffer, then the k of a
+        # token summed (a gather; a scatter-add of the rows serialises on a
+        # TPU)
+        rank = jnp.zeros((n * k,), jnp.int32).at[order].set(
+            jnp.arange(rows, dtype=jnp.int32))
+        picked = ys[jnp.minimum(rank, rows - 1)]
+        if prompt:
+            # a held pair's row lies before the last group's end
+            picked = picked.astype(jnp.float32) \
+                * routing.weights.reshape(-1)[:, None]
+        picked = jnp.where(here.reshape(-1)[:, None], picked, 0.0)
+        return picked.reshape(n, k, -1).sum(1).astype(x.dtype)
+
+    compact = full if n_experts is None else \
+        compact_rows(n, k, count, n_experts)
+    if n_experts is not None:
+        count_traced(compact < full)
+    if compact < full:
+        spilled = (total > compact).astype(jnp.int32)
+        y = jax.lax.switch(
+            (total > 0).astype(jnp.int32) + spilled,
+            (lambda: jnp.zeros_like(x), lambda: experts(compact, True),
+             lambda: experts(full, True)))
+    else:
+        spilled = jnp.zeros((), jnp.int32)
+        y = experts(full, n_experts is not None)
 
     n_valid = n if valid is None else valid.sum()
     counts = ExpertCounts(
@@ -152,5 +242,6 @@ def held_experts(x, routing: Routing, w_gate, w_up, w_down,
         # that multiplies every held expert counts ``count`` here
         streamed=(sizes > 0).sum().astype(jnp.int32),
         assignments_held=total.astype(jnp.int32),
-        assignments=jnp.asarray(n_valid * k, jnp.int32))
+        assignments=jnp.asarray(n_valid * k, jnp.int32),
+        spilled=spilled)
     return y, counts

@@ -160,13 +160,17 @@ class _FirstToken:
     token on the device, and what the commit needs to stamp the chunk's
     span from its program call."""
 
-    __slots__ = ("slot", "state", "token", "t_span0", "program", "bucket",
-                 "chunk")
+    __slots__ = ("slot", "state", "token", "counted", "t_span0", "program",
+                 "bucket", "chunk")
 
-    def __init__(self, slot, state, token, t_span0, program, bucket, chunk):
+    def __init__(self, slot, state, token, counted, t_span0, program,
+                 bucket, chunk):
         self.slot = slot
         self.state = state
         self.token = token
+        # the prompt-block counts of the programs launched up to this one
+        # and not yet fetched: done when the token is
+        self.counted = counted
         self.t_span0 = t_span0
         self.program = program
         self.bucket = bucket
@@ -609,6 +613,9 @@ class ServingEngine:
         self._flight: Optional[_Flight] = None
         # last chunks launched this schedule phase, first tokens unfetched
         self._firsts: List[_FirstToken] = []
+        # prompt-block counts of the prefill programs launched since, on the
+        # device: they ride to the host with the next first token
+        self._counted: list = []
         # every slot's newest pick, on the device: the last decode step's
         # next tokens, with the first token of each prefill launched since
         self._previous = self._canon(
@@ -719,8 +726,13 @@ class ServingEngine:
     def _adopt_first(self, out):
         """:meth:`_adopt` for a prefill program: a slot-paged one also
         hands back the decode step's previous tokens with its pick written
-        at the slot. Returns the pick, on the device."""
+        at the slot, and a model that counts what a prompt block did
+        (``prompt_counters``) its counts last, kept on the device until a
+        first token is fetched. Returns the pick, on the device."""
         token, *previous = self._adopt(out)
+        if self.prefix is None and getattr(self.engine.module,
+                                           "prompt_counters", ()):
+            self._counted.append(previous.pop())
         if previous:
             self._previous, = previous
         return token
@@ -1047,6 +1059,7 @@ class ServingEngine:
                         jnp.ones((self.num_slots,), jnp.int32))
         self.cache.lengths = self._canon(
             jnp.zeros((self.num_slots,), jnp.int32))
+        self._counted.clear()       # the dummy prompts' blocks
 
     def _table_args(self) -> tuple:
         """Extra traced operand for the block-paged programs: the full
@@ -1583,9 +1596,10 @@ class ServingEngine:
                     self.tenants.note_prefill(st.tenant, chunk)
                 if last:
                     st.in_flight += 1
+                    counted, self._counted = self._counted, []
                     self._firsts.append(_FirstToken(
-                        slot, st, token, t_span0 if armed else 0.0, pname,
-                        bucket, chunk))
+                        slot, st, token, counted, t_span0 if armed else 0.0,
+                        pname, bucket, chunk))
             if last and not self._ahead:
                 self._land_firsts(now, finished)
         return spent
@@ -1601,7 +1615,11 @@ class ServingEngine:
             slot, st = first.slot, first.state
             req = st.request
             with _Phase(self, "dstpu/serving_prefill", None, now):
-                tok = int(jax.device_get(first.token))  # dstpu-lint: fence=token emission: the chunk's final pick must reach the host stream
+                tok, counted = jax.device_get((first.token, first.counted))  # dstpu-lint: fence=token emission: the chunk's final pick must reach the host stream
+                tok = int(tok)
+            if counted and self.telemetry is not None:
+                self.engine.module.record_prompt_counters(self.telemetry,
+                                                          counted)
             st.in_flight -= 1
             self.prefill_calls += 1
             self.tokens_generated += 1

@@ -201,6 +201,13 @@ def test_the_serving_engine_serves_it_over_two_sizes_of_state(built, eng):
         3 * 4 * c["serving/slot_iterations_active"]
     assert 0 < c["serving/moe_assignments_held"] < \
         c["serving/moe_assignments"] / 3
+    # five prompts, each whole through three sparse layers, counted on the
+    # device and fetched with the first token; a bucket of 16 or 32 tokens is
+    # too short for a compact buffer (a row tile is 128 rows), so none spills,
+    # and every prompt holds real tokens
+    assert c["serving/moe_prompt_blocks"] == 3 * 5
+    assert c["serving/moe_prompt_blocks_spilled"] == 0
+    assert c["serving/moe_prompt_blocks_empty"] <= 3 * 5 // 2
 
 
 # (query heads, key-value heads, head size): multi-head, rep 4, rep 8

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.models.moe_ffn import zero_counts
 from deepspeed_tpu.ops import gqa_prefill
 from deepspeed_tpu.ops.attention import (blocked_prompt_attention,
                                          multihead_attention)
@@ -168,7 +169,7 @@ def _layer(head_dim=D, key_block=BK, s_max=S, t=T, packed=False):
         kc = jnp.asarray(rng.randn(2, 1, c.num_kv_heads, s_max, head_dim),
                          jnp.float32)
         vc = kc[::-1] * 0.5
-    counts = jnp.zeros((len(model.step_counters),), jnp.int32)
+    counts = zero_counts(t)
 
     def run():
         before = _counters()

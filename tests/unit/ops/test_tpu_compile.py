@@ -273,24 +273,38 @@ def test_held_experts_grouped_matmul(one_chip, tokens):
     """The expert layer's grouped matmuls (``jax.lax.ragged_dot``, XLA's own
     kernel on a TPU) at K-EXAONE's widths, 16 of 128 experts held, against
     the whole layer-stacked weights: no copy of a layer's experts (1.2 GB)
-    is made to slice them."""
-    from deepspeed_tpu.moe.grouped import held_experts, sigmoid_topk_route
+    is made to slice them. A decode step's 32 slots are three calls and no
+    conditional; a prompt of 4,096 tokens is told the router's width and
+    carries the switch: three calls in each branch that multiplies, 8,192
+    sorted rows in the compact one, and no float32 buffer of the worst
+    case's 32,768 rows (805 MB) anywhere, nor one in bfloat16 outside the
+    full branch but the gather back to a token's eight pairs."""
+    from deepspeed_tpu.moe.grouped import (compact_rows, held_experts,
+                                           sigmoid_topk_route)
 
-    d, m, e, held, layers = 6144, 2048, 128, 16, 4
+    d, m, e, held, layers, k = 6144, 2048, 128, 16, 4, 8
+    prompt = tokens > 32
 
     def fn(x, router, bias, wg, wu, wd, layer):
-        routing = sigmoid_topk_route(x, router, bias, 8, scale=2.5)
+        routing = sigmoid_topk_route(x, router, bias, k, scale=2.5)
         whole = [{"__whole__": w, "__layer__": layer} for w in (wg, wu, wd)]
-        return held_experts(x, routing, *whole, (0, held))
+        return held_experts(x, routing, *whole, (0, held),
+                            n_experts=e if prompt else None)
 
     text = _compiled_text(
         fn, _sds(one_chip, (tokens, d)), _sds(one_chip, (d, e)),
         _sds(one_chip, (e,)), _sds(one_chip, (layers, held, d, m)),
         _sds(one_chip, (layers, held, d, m)),
         _sds(one_chip, (layers, held, m, d)), _sds(one_chip, (), jnp.int32))
-    assert len(re.findall(r"%ragged-dot-(?!metadata)[\w.\-]* = ", text)) == 3
     # the stacks reach the kernels as they lie: nothing of a layer's size
     assert not re.search(r"= bf16\[16,(6144,2048|2048,6144)\]", text)
+    if prompt:
+        assert compact_rows(tokens, k, held, e) == 8192
+        assert _assert_prompt_buffer_is_compact(text, tokens * k, d, 1) == \
+            {8192}
+        return
+    assert len(re.findall(r"%ragged-dot-(?!metadata)[\w.\-]* = ", text)) == 3
+    assert " conditional(" not in text
 
 
 def test_window_prefill_scores_are_a_band(one_chip):
@@ -580,7 +594,9 @@ def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
     behind them, and a layer's ``wq``, ``wk``, ``wv`` were sliced out of the
     stack and transposed, ``wo`` copied, every step: 232 MB of temporaries,
     ``constant_dynamic-slice_fusion`` on the chip's trace, 0.87 ms of a step
-    of 7.4 (PERF.md, PR 41; ``models/base.project_heads``)."""
+    of 7.4 (PERF.md, PR 41; ``models/base.project_heads``). No step holds a
+    conditional: what a prompt block's expert layer chooses between (PR 53)
+    is not a decode step's to choose."""
     from deepspeed_tpu.ops.decode_step import slot_walk
 
     model, slots, max_len = cell()
@@ -603,6 +619,10 @@ def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
         params, state, per_slot, per_slot,
         _sds(one_chip, (slots,), jnp.bool_)).compile()
     _assert_copies_no_weight(compiled, leaves)
+    # one token a slot: the expert layer's buffer is two or three row tiles
+    # and the switch a prompt block carries (moe/grouped.held_experts) has
+    # no part in the step, nor has any other conditional
+    assert " conditional(" not in compiled.as_text()
     if "conv" in state:
         _assert_mamba_runs_are_folded(compiled.as_text(), state["conv"])
     if "kda" in state:
@@ -674,6 +694,46 @@ def test_prefill_copies_no_weight(one_chip, fused_routes):
     _assert_copies_no_weight(compiled, leaves)
 
 
+def _assert_prompt_buffer_is_compact(text, rows, width, switches):
+    """A prompt program's expert layers (``moe/grouped.held_experts`` told
+    the router's width): ``switches`` conditionals outside fused
+    computations, one a run of sparse layers, each with a branch that holds
+    nothing of the layer, a compact and a full one of three grouped matmuls;
+    of the worst case's ``[rows, width]`` no float32 buffer is left in the
+    program, and in bfloat16 the full branch's alone and, in the compact
+    one, the gather back to a token's ``k`` pairs. -> the compact branches'
+    row counts."""
+    found, _ = _outside_fusions(text)
+
+    def matmuls(branch):
+        return [kind for comp, kind, opcode, line in found
+                if comp == branch and opcode == "custom-call"
+                and re.search(r"%ragged-dot-(?!metadata)[\w.\-]* = ", line)]
+
+    conds = [line for _, _, opcode, line in found if opcode == "conditional"]
+    assert len(conds) == switches, conds
+    worst = [(comp, kind) for comp, kind, _, _ in found
+             if re.match(rf"(f32|bf16)\[{rows},{width}\]", kind)]
+    assert all(kind.startswith("bf16") for _, kind in worst), worst
+    compact_rows, inside = set(), set()
+    for line in conds:
+        empty, compact, full = re.search(
+            r"branch_computations=\{([^}]*)\}", line).group(1).replace(
+                "%", "").split(", ")
+        assert not matmuls(empty) and not [
+            kind for comp, kind, opcode, _ in found
+            if comp == empty and opcode == "fusion"]
+        assert len(matmuls(compact)) == len(matmuls(full)) == 3
+        assert all(kind.startswith(f"bf16[{rows},")
+                   for kind in matmuls(full)), matmuls(full)
+        compact_rows.add(int(re.match(r"bf16\[(\d+),",
+                                      matmuls(compact)[0]).group(1)))
+        assert sum(comp == compact for comp, _ in worst) == 1, worst
+        inside |= {compact, full}
+    assert all(comp in inside for comp, _ in worst), worst
+    return compact_rows
+
+
 def test_sarvam_prefill_keeps_scores_on_chip(one_chip, fused_routes):
     """Sarvam-105B's 4,096-bucket prefill (``slot_prefill_program``'s call of
     the model: two token blocks of 2,048 through the stack, a cache of its
@@ -682,12 +742,17 @@ def test_sarvam_prefill_keeps_scores_on_chip(one_chip, fused_routes):
     lies in: as a layer's slice it was written out, 16.8 MB a layer a token
     block) and no float32 buffer of a score block's size ``[64, 2048, 512]``
     is a temporary of the program. The ``lax`` loop wrote three such buffers a
-    key block and read them back: 0.84 s of a busy 2.22 s (PERF.md, PR 47)."""
+    key block and read them back: 0.84 s of a busy 2.22 s (PERF.md, PR 47).
+    The sparse run's expert layer sorts a token block's pairs into 4,096 rows
+    unless more are routed here (PR 53)."""
     model, _, _ = _sarvam_cell()
     params, leaves = _weights(model, one_chip)
     # the expert layer's sorted rows, prompt_block x 8 a token block, are
     # [16384, 4096] like the dense layer's w_down, and are gathered
     leaves = leaves - {("bf16", (4096, 16384))}
+    # a token block's activations [2048, 4096], which the expert layer's
+    # switch takes and gives, are the shared expert's matrices' size
+    leaves = leaves - {("bf16", (2048, 4096))}
 
     compiled = _compile_prefill(model, params, one_chip, 4096)
     text = compiled.as_text()
@@ -703,11 +768,14 @@ def test_sarvam_prefill_keeps_scores_on_chip(one_chip, fused_routes):
            for dims in re.findall(r"f32\[([\d,]*)\]", kind)
            if _sizes(dims.split(",")) in walked]
     assert not big, "\n".join(big)
-    # 643 MB here; the loop's program 694 MB
-    assert compiled.memory_analysis().temp_size_in_bytes < 680 * 2 ** 20
+    # the expert layers' sorted buffer: 4,096 of a token block's 16,384 rows
+    assert _assert_prompt_buffer_is_compact(text, 16384, 4096, 1) == {4096}
+    # 402 MiB here; 613 with the expert layer's float32 passes over the worst
+    # case's rows (PR 52), the loop's program 662
+    assert compiled.memory_analysis().temp_size_in_bytes < 420 * 2 ** 20
 
 
-@pytest.mark.parametrize("bucket,temp_mb", [(2048, 538), (16384, 806)])
+@pytest.mark.parametrize("bucket,temp_mb", [(2048, 390), (16384, 670)])
 def test_solar_prefill_keeps_a_chunk_on_chip(one_chip, fused_routes, bucket,
                                              temp_mb):
     """Solar-Open2's prefill (``slot_prefill_program``'s call of the model: a
@@ -722,9 +790,11 @@ def test_solar_prefill_keeps_a_chunk_on_chip(one_chip, fused_routes, bucket,
     score buffer ``[.., 2048, 512]`` of the ``lax`` loop is left, and in the
     16,384 bucket nothing outside the kernel copies or slices a layer's key
     or value rows (in the 2,048 bucket the block's own new rows, turned for
-    the write, are as many). The program's temporaries are no higher than
-    with the loop (``temp_mb``: 537 MB and 805 MB, PERF.md, PR 49; the
-    chunked form's were 619 and 1,009)."""
+    the write, are as many). Both runs' expert layers sort a token block's
+    pairs into 4,096 rows unless more are routed here. The program's
+    temporaries (``temp_mb``: 372 and 652 MiB) are below what they were with
+    the expert layer's float32 passes over the worst case's 16,384 rows (537
+    and 805, PERF.md, PR 49; the chunked form's were 619 and 1,009)."""
     model, _, _ = _solar_cell()
     params, _ = _weights(model, one_chip)
 
@@ -742,7 +812,11 @@ def test_solar_prefill_keeps_a_chunk_on_chip(one_chip, fused_routes, bucket,
               if dims.split(",")[-2:] == ["2048", "512"]]
     assert not scores, "\n".join(scores)
     if bucket > 2048:
-        rows = _weight_sized_copies(text, {("bf16", (8, 128, bucket))})
+        # the two leaves start as one broadcast zero and its copy, once a
+        # program (whichever of them the compiler keeps in fast memory)
+        rows = [line for line in _weight_sized_copies(
+            text, {("bf16", (8, 128, bucket))})
+            if " copy(%broadcast." not in line]
         assert not rows, "\n".join(rows)
     assert "triangular" not in text.lower() and "cholesky" not in text.lower()
     chunked = [line.strip()[:200] for _, kind, _, line in found
@@ -750,7 +824,26 @@ def test_solar_prefill_keeps_a_chunk_on_chip(one_chip, fused_routes, bucket,
                if _sizes(dims.split(","))[-3:] == (64, 64, 128)
                or _sizes(dims.split(",")) == (32, 64, 64, 64)]
     assert not chunked, "\n".join(chunked)
+    assert _assert_prompt_buffer_is_compact(text, 16384, 4096, 2) == {4096}
     assert compiled.memory_analysis().temp_size_in_bytes < temp_mb * 2 ** 20
+
+
+def test_longcat_prefill_sorts_the_pairs_it_holds(one_chip, fused_routes):
+    """LongCat-Flash-Chat's 2,048-bucket prefill (``slot_prefill_program``'s
+    call of the model: the prompt whole, a cache of its own, the true
+    length): 16 of the router's 768 outputs are held, so of a prompt's 24,576
+    pairs about 512 are routed here and the sorted buffer has 1,024 rows; no
+    weight is copied. 1,112 MiB of temporaries (1,399 with the float32
+    ``[24576, 6144]`` passes, 604 MB each, PERF.md, PR 52)."""
+    model, _, _ = _longcat_cell()
+    params, leaves = _weights(model, one_chip)
+
+    compiled = _compile_prefill(model, params, one_chip, 2048)
+    text = compiled.as_text()
+    copies = _weight_sized_copies(text, leaves)
+    assert not copies, "\n".join(copies)
+    assert _assert_prompt_buffer_is_compact(text, 24576, 6144, 1) == {1024}
+    assert compiled.memory_analysis().temp_size_in_bytes < 1150 * 2 ** 20
 
 
 @pytest.fixture
