@@ -31,12 +31,27 @@ position, the valid length and the key-block count ride scalar prefetch: one
 kernel body serves every bucket, every token block of a long prompt and a
 chunk continued at ``first > 0``.
 
+**Inside a cell** a query tile is walked in chunks of ``_CHUNK_ROWS`` rows in
+one basic block: a chunk's scores ``[128, key_block]`` float32 are 64 vector
+registers, so mask, maximum, ``exp``, sum and the rounding to bf16 never leave
+them, and the next chunk's two score matmuls are issued before this chunk's
+softmax, so the MXU works while the vector units do (ops/gqa_prefill.py walks
+its tiles the same way, and the running-softmax step of a chunk is that
+file's). A whole tile's scores at once (``[512, 512]``, 1 MB through VMEM and
+back for every pass) left 17.6 of a layer's 69.6 ms over a 16,384 prompt as
+softmax work beside an idle MXU (PERF.md, PR 47 and PR 54). The chunks are ONE
+traced body of a ``fori_loop`` that the lowering unrolls: unrolled in the
+trace they cost every prefill program a second and more of set-up, and left
+rolled the scores ride the loop's carry through VMEM.
+
 **A key block below every tile's diagonal, all tiles live**, is the common
-cell of a long prompt (28 of 32 in the last token block of 16,384): its tiles
-are unrolled in one basic block with every bound static, where the compiler
-overlaps a tile's matmuls with its neighbour's softmax (ops/flash_attention.py
-found the same). Every other cell takes a tile at a time, each under its own
-condition.
+cell of a long prompt (28 of 32 in the last token block of 16,384): all the
+chunks of all its tiles are one walk, so the next chunk's scores are issued
+across tile boundaries too. (Whole tiles unrolled so, with the overlap left to
+the compiler, were the 17.6 ms that stayed exposed.) Every other cell takes a
+tile at a time, each under its own condition, its chunks one walk; a tile the
+diagonal crosses builds its mask a chunk at a time from one ``[128,
+key_block]`` iota difference and the chunk's offset.
 
 **The numbers are the loop's** (models/sarvam_mla.py ``_prompt_attention``):
 bf16 operands on the MXU, float32 accumulation of the scores and of ``p v``,
@@ -61,10 +76,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.decode_step import _NEG, _VMEM_LIMIT
 from deepspeed_tpu.ops.flash_attention import _NN, _NT, _dot_f32, _rows_at
+from deepspeed_tpu.ops.gqa_prefill import _chunk_step
 
-# Rows of a query tile: the token block's queries are walked 512 at a time
-# against a key block (PERF.md, PR 47 has the chip's readings by tile)
+# Rows of a query tile (the unit that is skipped, masked or walked whole
+# against a key block: PERF.md, PR 47 has the chip's readings by tile), and
+# the rows of it that one pair of score matmuls takes: a chunk's scores are 64
+# vector registers, so its softmax never leaves them (PERF.md, PR 54 has the
+# chip's readings by chunk)
 _QUERY_TILE = 512
+_CHUNK_ROWS = 128
 
 
 def query_tile(t: int) -> int:
@@ -72,13 +92,19 @@ def query_tile(t: int) -> int:
     return min(t, _QUERY_TILE)
 
 
+def _chunk_rows(tq: int) -> int:
+    """Rows of a chunk of a query tile of ``tq`` rows."""
+    return min(tq, _CHUNK_ROWS)
+
+
 def supports(s_max: int, width: int, key_block: int, t: int) -> bool:
     """Shapes the kernel takes: cached rows of whole 128-lane tiles, a row
     count of whole key blocks, a token block of whole query tiles of whole
-    sublane tiles (16 rows of bf16)."""
+    chunks, tiles and key blocks of whole sublane tiles (16 rows of bf16)."""
     tq = query_tile(t)
     return (width % 128 == 0 and key_block % 16 == 0
-            and s_max % key_block == 0 and tq % 16 == 0 and t % tq == 0)
+            and s_max % key_block == 0 and tq % 16 == 0 and t % tq == 0
+            and tq % _chunk_rows(tq) == 0)
 
 
 def count_traced() -> None:
@@ -91,31 +117,24 @@ def count_traced() -> None:
     get_registry().counter("mla/traced_prefill_kernel").inc()
 
 
+# Jitted, so that a kernel traces it once a signature and not once a chunk
 @functools.partial(jax.jit, static_argnames=("n", "scale"))
-def _tile_step(q, k_nope, k_rope, v, m_prev, l_prev, acc, visible, *,
-               n: int, scale: float):
-    """One (query tile, key block) of the running softmax: ``q [tq, n +
-    rope]``, ``k_nope [bk, n]``, ``k_rope [bk, rope]``, ``v [bk, v]``;
-    ``visible [tq, bk]`` bool, or None where every key is. Jitted, so that a
-    kernel traces it once a signature and not once a tile."""
-    s = (_dot_f32(q[:, :n], k_nope, _NT)
-         + _dot_f32(q[:, n:], k_rope, _NT)) * scale
-    if visible is not None:
-        s = jnp.where(visible, s, _NEG)
-    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-    corr = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
-    return m_new, l_new, acc * corr + _dot_f32(p.astype(v.dtype), v, _NN)
+def _scores(q, k_nope, k_rope, *, n: int, scale: float):
+    """``q [rows, n + rope]`` against ``k_nope [bk, n]`` and the rotated key
+    ``k_rope [bk, rope]``: the two products summed in float32, times the
+    scale."""
+    return (_dot_f32(q[:, :n], k_nope, _NT)
+            + _dot_f32(q[:, n:], k_rope, _NT)) * scale
 
 
 def _kernel(layer_ref, wl_ref, first_ref, valid_ref, blocks_ref, q_ref,
             rows_ref, w_ref, o_ref, m_ref, l_ref, acc_ref, kv_ref, *,
-            r: int, rope: int, n: int, tq: int, scale: float):
+            r: int, rope: int, n: int, tq: int, cr: int, scale: float):
     """A grid cell: batch row ``b``, one head, key block ``kb``.
     ``first_ref [B]`` the position of the block's first query,
     ``valid_ref [B]`` how many of its positions are real, ``blocks_ref [B]``
-    how many key blocks its live tiles reach."""
+    how many key blocks its live tiles reach. A query tile is ``tq`` rows, a
+    chunk ``cr`` of them."""
     del layer_ref, wl_ref            # the index maps read them
     b, kb = pl.program_id(0), pl.program_id(2)
     t, bk = q_ref.shape[0], rows_ref.shape[0]
@@ -128,17 +147,38 @@ def _kernel(layer_ref, wl_ref, first_ref, valid_ref, blocks_ref, q_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def tile(j, masked: bool):
-        rs = _rows_at(j, tq)
-        visible = None
+    def walk(c0, chunks: int, masked: bool):
+        """Chunks ``[c0, c0 + chunks)`` of the token block's query rows
+        against the key block, in one basic block."""
+        def scores(c):
+            return _scores(q_ref[_rows_at(c0 + c, cr), :], kv_ref[:, :n],
+                           rows_ref[:, r:r + rope], n=n, scale=scale)
+
+        diff = None
         if masked:      # key column c, query row i: c + kb bk <= i + q0
-            diff = (jax.lax.broadcasted_iota(jnp.int32, (tq, bk), 1)
-                    - jax.lax.broadcasted_iota(jnp.int32, (tq, bk), 0))
-            visible = diff <= first + j * tq - kb * bk
-        m_ref[rs], l_ref[rs], acc_ref[rs] = _tile_step(
-            q_ref[rs, :], kv_ref[:, :n], rows_ref[:, r:r + rope],
-            kv_ref[:, n:], m_ref[rs], l_ref[rs], acc_ref[rs], visible,
-            n=n, scale=scale)
+            diff = (jax.lax.broadcasted_iota(jnp.int32, (cr, bk), 1)
+                    - jax.lax.broadcasted_iota(jnp.int32, (cr, bk), 0))
+
+        def step(c, s):
+            rs = _rows_at(c0 + c, cr)
+            m_ref[rs], l_ref[rs], acc_ref[rs] = _chunk_step(
+                s, kv_ref[:, n:], m_ref[rs], l_ref[rs], acc_ref[rs], diff,
+                first + (c0 + c) * cr - kb * bk if masked else 0)
+
+        def ahead(c, s):
+            # the next chunk's scores are issued before this chunk's
+            # softmax: the MXU works on them while the vector units work on
+            # these
+            s_next = scores(c + 1)
+            step(c, s)
+            return s_next
+
+        # one traced body that the lowering unrolls (a rolled loop carries
+        # the scores through VMEM: 91 ms a layer for 58, PERF.md, PR 54)
+        s = scores(0)
+        if chunks > 1:
+            s = jax.lax.fori_loop(0, chunks - 1, ahead, s, unroll=True)
+        step(chunks - 1, s)
 
     @pl.when(kb < blocks_ref[b])
     def _():
@@ -147,11 +187,7 @@ def _kernel(layer_ref, wl_ref, first_ref, valid_ref, blocks_ref, q_ref,
             kv_ref.dtype)
         last_key = kb * bk + bk - 1
         below_all = jnp.logical_and(last_key <= first, valid >= t)
-
-        @pl.when(below_all)
-        def _():
-            for j in range(n_tiles):
-                tile(j, False)
+        pl.when(below_all)(lambda: walk(0, t // cr, False))
 
         @pl.when(jnp.logical_not(below_all))
         def _():
@@ -161,9 +197,9 @@ def _kernel(layer_ref, wl_ref, first_ref, valid_ref, blocks_ref, q_ref,
                                          kb * bk <= lo + tq - 1)
                 crossed = last_key > lo
                 pl.when(jnp.logical_and(needed, crossed))(
-                    lambda: tile(j, True))
+                    lambda: walk(j * (tq // cr), tq // cr, True))
                 pl.when(jnp.logical_and(needed, jnp.logical_not(crossed)))(
-                    lambda: tile(j, False))
+                    lambda: walk(j * (tq // cr), tq // cr, False))
                 return 0
 
             jax.lax.fori_loop(0, n_tiles, each, 0)
@@ -255,7 +291,7 @@ def mla_prefill(q_nope: jax.Array, q_rope: jax.Array, latent: jax.Array,
         vmem_limit_bytes=_VMEM_LIMIT)}
     out = pl.pallas_call(
         functools.partial(_kernel, r=r, rope=rope, n=n, tq=tq,
-                          scale=float(scale)),
+                          cr=_chunk_rows(tq), scale=float(scale)),
         name="dstpu_mla_prefill",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, t, h * vd), q_nope.dtype),

@@ -3,8 +3,10 @@
 (``SarvamMlaModel._prompt_attention`` with the backend read as a TPU), against
 the ``lax`` loop of the same method and against dense ``multihead_attention``
 on decompressed heads: tiny widths with the cell's ratios (nope 2 x rope, the
-row padded to whole lanes), token blocks of two query tiles over key blocks of
-a tile's size, so that tiles are skipped, masked and walked whole."""
+row padded to whole lanes), token blocks of two query tiles of two chunks over
+key blocks of a tile's size, so that tiles are skipped, masked and walked
+whole and a masked tile's second chunk sees its own rows' diagonal; and the
+chunked walk against one chunk a tile, bit for bit."""
 
 import functools
 
@@ -70,9 +72,11 @@ def _positions(first, b):
 def kernel_route(monkeypatch):
     """The model picks the kernel where ``jax.default_backend()`` says tpu
     (steered here, not through an option of the program); the call itself
-    runs in the Pallas interpreter, two query tiles a token block."""
+    runs in the Pallas interpreter, two query tiles a token block, two chunks
+    a tile."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(mla_prefill, "_QUERY_TILE", TILE)
+    monkeypatch.setattr(mla_prefill, "_CHUNK_ROWS", TILE // 2)
     monkeypatch.setattr(mla_prefill, "mla_prefill", functools.partial(
         mla_prefill.mla_prefill, interpret=True))
 
@@ -94,11 +98,22 @@ CASES = {
     "shorter-than-its-bucket": ([T], 20, 1),
     "a-dead-block": ([T], 0, 1),
     "batch-of-two": ([np.asarray([8, 40])], np.asarray([T, 9]), 2),
+    # positions 21 to 52: no chunk starts at a multiple of its rows, and the
+    # diagonal crosses inside the chunks of both tiles
+    "a-diagonal-inside-a-chunk": ([21], None, 1),
+    # a tile smaller than a chunk is one chunk whole
+    "a-tile-smaller-than-a-chunk": ([8], 20, 1),
 }
+CHUNK_ROWS = {"a-tile-smaller-than-a-chunk": 4 * TILE}
+# a row meets the same key blocks in the same order however its tile is cut
+BIT_FOR_BIT = {c + "-chunked-as-whole-bit-for-bit": c
+               for c in ("three-token-blocks", "batch-of-two")}
 
 
-@pytest.mark.parametrize("case", list(CASES) + ["refused-by-shape"])
-def test_kernel_against_the_loop_and_dense_attention(kernel_route, case):
+@pytest.mark.parametrize(
+    "case", list(CASES) + list(BIT_FOR_BIT) + ["refused-by-shape"])
+def test_kernel_against_the_loop_and_dense_attention(kernel_route, case,
+                                                     monkeypatch):
     if case == "refused-by-shape":
         # 100 cached rows are no whole key blocks: the loop, and its counter
         model = _model()
@@ -113,18 +128,35 @@ def test_kernel_against_the_loop_and_dense_attention(kernel_route, case):
         assert _counters() == (before[0], before[1] + 1)
         np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
         return
-    firsts, valid, b = CASES[case]
+    firsts, valid, b = CASES[BIT_FOR_BIT.get(case, case)]
     model = _model()
     q_nope, q_rope, latent, wkv_b = _operands(model, b, len(case))
     # the walk hands the stack whole, the layer beside it (models/base.py)
     blk = {"wkv_b": {"__whole__": wkv_b, "__layer__": jnp.asarray(1)}}
-    for first in firsts:
+
+    def kernel(first, chunk_rows, **compiler_options):
+        monkeypatch.setattr(mla_prefill, "_CHUNK_ROWS", chunk_rows)
         before = _counters()
+        out = jax.jit(lambda *a: model._prompt_attention(
+            *a, 2, _positions(first, b), blk, valid)).lower(
+            q_nope, q_rope, latent).compile(compiler_options)(
+            q_nope, q_rope, latent)
+        assert _counters() == (before[0] + 1, before[1])
+        return out
+
+    for first in firsts:
         with jax.default_matmul_precision("highest"):
-            out = jax.jit(lambda *a: model._prompt_attention(
-                *a, 2, _positions(first, b), blk, valid))(
-                q_nope, q_rope, latent)
-            assert _counters() == (before[0] + 1, before[1])
+            if case in BIT_FOR_BIT:
+                # the kernel's arithmetic is the same; XLA:CPU's is not: it
+                # contracts ``l * corr + sum`` to a fused multiply-add in one
+                # of the two programs and not in the other, a last bit in a
+                # row whose maximum moved. At level 0 LLVM forms none
+                whole, chunked = (
+                    kernel(first, rows, xla_backend_optimization_level=0)
+                    for rows in (TILE, TILE // 2))
+                np.testing.assert_array_equal(chunked, whole)
+                continue
+            out = kernel(first, CHUNK_ROWS.get(case, TILE // 2))
             with pytest.MonkeyPatch.context() as loop:
                 loop.setattr(mla_prefill, "supports", lambda *a: False)
                 want = model._prompt_attention(

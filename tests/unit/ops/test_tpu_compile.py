@@ -174,19 +174,27 @@ def test_fused_mla_decode_step(one_chip):
     assert "dstpu_mla_decode_step" in text
 
 
-def test_mla_prefill_kernel(one_chip):
-    """The decompressed prompt attention at the Sarvam-105B cell's shapes: a
-    token block of 2,048 at 64 heads of 128 + 64 / 128 against 16,384 cached
-    rows of 640 lanes in key blocks of 512, ``wkv_b`` as the layer-stacked
-    leaf it is fetched from; the first position, the valid length and the
-    layers traced, as the prefill's scan over token blocks calls it."""
+# (cache layers, rows of the bucket, token block): the Sarvam-105B cell's
+# largest bucket; LongCat-Flash's smallest that takes the kernel (its 256
+# bucket is no whole key block of 512 and takes the loop), one tile of four
+# chunks; and a tile of 64 rows, smaller than a chunk and so one chunk whole
+# (no cell's: the shape ``min(tq, _CHUNK_ROWS)`` is there for)
+@pytest.mark.parametrize("l,s,t", [(5, 16384, 2048), (8, 512, 512),
+                                   (8, 64, 64)], ids=str)
+def test_mla_prefill_kernel(one_chip, l, s, t):
+    """The decompressed prompt attention at the two latent-attention cells'
+    widths: a token block at 64 heads of 128 + 64 / 128 against the cached
+    rows of 640 lanes in key blocks of 512 (one key block where the bucket
+    is smaller), ``wkv_b`` as the layer-stacked leaf it is fetched from; the
+    first position, the valid length and the layers traced, as the prefill's
+    scan over token blocks calls it."""
     from deepspeed_tpu.ops.mla_prefill import mla_prefill
 
-    l, b, s, w, h, t = 5, 1, 16384, 640, 64, 2048
+    b, w, h = 1, 640, 64
 
     def fn(q_nope, q_rope, latent, wkv_b, layer, first, valid, w_layer):
         return mla_prefill(q_nope, q_rope, latent, wkv_b, layer, first, valid,
-                           latent_width=512, scale=0.1, key_block=512,
+                           latent_width=512, scale=0.1, key_block=min(s, 512),
                            w_layer=w_layer, interpret=False)
 
     scalar = _sds(one_chip, (), jnp.int32)
