@@ -135,16 +135,21 @@ def prompt_walk(layers, embed, input_ids, leaves: tuple, counts, cache,
 
 
 def kv_cache(layers: int, batch: int, kv_heads: int, max_len: int,
-             head_dim: int, dtype, packed: bool = True):
+             head_dim: int, dtype, packed: bool = True,
+             v_head_dim: Optional[int] = None):
     """The static-shape key-value cache tree ``{"k", "v", "index"}``:
     stacked head-major ``[L, B, Hkv, S, Dh]``, token-pair packed for
     ``Dh < 128`` unless the model's decode always takes the einsum path
     (``packed=False``; ops/attention.alloc_kv_cache), and a scalar index.
-    A model with other per-layer state adds its leaves to this tree."""
+    ``v_head_dim``: the value rows' width where it is not the keys' (the
+    leaves are then unpacked, each of its own last dimension). A model with
+    other per-layer state adds its leaves to this tree."""
+    if v_head_dim not in (None, head_dim):
+        packed = False
     return {"k": alloc_kv_cache(layers, batch, kv_heads, max_len, head_dim,
                                 dtype, packed=packed),
-            "v": alloc_kv_cache(layers, batch, kv_heads, max_len, head_dim,
-                                dtype, packed=packed),
+            "v": alloc_kv_cache(layers, batch, kv_heads, max_len,
+                                v_head_dim or head_dim, dtype, packed=packed),
             "index": jnp.zeros((), jnp.int32)}
 
 

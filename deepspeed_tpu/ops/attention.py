@@ -83,6 +83,27 @@ def kv_pack_factor(head_dim: int) -> int:
     return 128 // head_dim
 
 
+def key_row_width(head_dim: int) -> int:
+    """Lanes a cached key row of ``head_dim`` takes where it is wider than
+    one 128-lane tile and no whole number of them (192 -> 256): the TPU's
+    HBM tiling pads such a row to whole tiles whatever the leaf's shape says,
+    and the kernels' DMA slices must be whole tiles, so the leaf states the
+    padding and the model hands queries and keys over with zeros behind the
+    live lanes (:func:`pad_lanes`): a dot product over them is the live
+    one."""
+    if head_dim <= 128 or head_dim % 128 == 0:
+        return head_dim
+    return -(-head_dim // 128) * 128
+
+
+def pad_lanes(x: jax.Array, width: int) -> jax.Array:
+    """``x [..., d]`` with zeros behind its last dimension up to ``width``."""
+    d = x.shape[-1]
+    if d == width:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - d)])
+
+
 def alloc_kv_cache(num_layers: int, batch: int, num_kv_heads: int,
                    max_len: int, head_dim: int, dtype, *,
                    packed: bool = True):
@@ -131,6 +152,10 @@ def cached_attention(q, k_full, v_full, k_new, v_new, layer, idx, *,
     (ops/decode_step.slot_walk). The fused step neither reads nor writes an
     inactive slot's rows and returns zeros for it; the einsum path ignores
     it (there an inactive slot's masked write lands behind its length).
+
+    The geometry is each LEAF's: ``v_full`` and ``v_new`` may be of another
+    last dimension than ``k_full`` and ``q`` (unpacked rows then), and the
+    result is as wide as the values.
 
     Single-token decode on TPU routes to the fused Pallas step
     (ops/decode_step.py): the kernel owns BOTH the cache write and the
@@ -184,7 +209,7 @@ def cached_attention(q, k_full, v_full, k_new, v_new, layer, idx, *,
         from deepspeed_tpu.ops.decode_step import fused_decode_step, supports
 
         if supports(q.shape[2], k_full.shape[2],
-                    k_full.shape[3] * pair, dh):
+                    k_full.shape[3] * pair, dh, v_new.shape[3]):
             return fused_decode_step(q, k_full, v_full, k_new, v_new,
                                      layer, idx, scale=scale,
                                      active=active)
@@ -205,6 +230,17 @@ def cached_attention(q, k_full, v_full, k_new, v_new, layer, idx, *,
     return attn, k_full, v_full
 
 
+def sink_softmax(logits, sink):
+    """Softmax over the last axis of float32 ``logits`` with a SINK in the
+    denominator: ``sink`` (broadcastable against ``logits``' leading axes,
+    last axis 1) is one more column of the softmax, dropped behind it:
+    ``p_j = exp(s_j) / (exp(sink) + sum_j' exp(s_j'))``."""
+    column = jnp.broadcast_to(sink.astype(jnp.float32),
+                              logits.shape[:-1] + (1,))
+    return jax.nn.softmax(jnp.concatenate([logits, column], axis=-1),
+                          axis=-1)[..., :-1]
+
+
 def ring_positions(idx, rows: int):
     """The position each row of a ring of ``rows`` rows holds for a request
     whose next position is ``idx`` (``[B]``): the latest position before
@@ -215,7 +251,7 @@ def ring_positions(idx, rows: int):
 
 
 def window_cached_attention(q, k_ring, v_ring, k_new, v_new, layer, idx, *,
-                            scale=None, valid=None, active=None):
+                            scale=None, valid=None, active=None, sink=None):
     """One sliding-window layer step against a RING cache ``[L, B, Hkv, W,
     Dh]`` whose ``W`` rows are the window: position ``p`` lives at row ``p %
     W``, so a slot's cache does not grow with the request. A query at
@@ -226,7 +262,10 @@ def window_cached_attention(q, k_ring, v_ring, k_new, v_new, layer, idx, *,
     ``idx``: first position of the block, a scalar or a per-slot ``[B]``
     vector; ``valid`` (scalar or ``[B]``): how many of the block's ``T``
     positions are real (bucket padding behind a prompt; 0 for a slot that
-    does not decode): the ring takes the last ``W`` REAL positions.
+    does not decode): the ring takes the last ``W`` REAL positions. ``v_ring``
+    may be of another last dimension than ``k_ring``. ``sink [Hq]``: a learned
+    logit a query head that takes part in the softmax's denominator and has
+    no value, ``p_ij = exp(s_ij) / (exp(sink_h) + sum_j' exp(s_ij'))``.
 
     One token on a TPU goes through the fused decode step with ``ring=True``
     (ops/decode_step.py: a slot fetches its ``min(idx, W)`` live rows and
@@ -236,18 +275,18 @@ def window_cached_attention(q, k_ring, v_ring, k_new, v_new, layer, idx, *,
     blocks of ``W``, each against the ``W`` keys before it and its own (a
     band, never ``T x T`` scores), the ring being the block before the first."""
     b, t, hq, dh = q.shape
-    hkv, w = k_ring.shape[2], k_ring.shape[3]
+    hkv, w, dv = k_ring.shape[2], k_ring.shape[3], v_ring.shape[4]
     assert k_ring.shape[4] == dh, "a ring cache is not token-pair packed"
     idx_v = jnp.broadcast_to(jnp.asarray(idx, jnp.int32), (b,))
     if t == 1 and b >= 2 and jax.default_backend() == "tpu":
         from deepspeed_tpu.ops.decode_step import fused_decode_step, supports
 
-        if supports(hq, hkv, w, dh) and dh % 128 == 0:
+        if supports(hq, hkv, w, dh, dv) and dh % 128 == 0:
             if active is None and valid is not None:
                 active = jnp.broadcast_to(valid, (b,)) > 0
             return fused_decode_step(q, k_ring, v_ring, k_new, v_new, layer,
                                      idx_v, scale=scale, active=active,
-                                     ring=True)
+                                     ring=True, sink=sink)
     scale = scale if scale is not None else dh ** -0.5
     rep = hq // hkv
     kl = jax.lax.dynamic_index_in_dim(k_ring, layer, 0, keepdims=False)
@@ -259,12 +298,18 @@ def window_cached_attention(q, k_ring, v_ring, k_new, v_new, layer, idx, *,
     q_pos = idx_v[:, None] + jnp.arange(t)[None, :]         # [B, T]
     pos = jnp.concatenate([ring_positions(idx_v, w), q_pos], axis=1)
 
-    def masked_softmax(logits, kp, qp, lead):
+    def masked_softmax(logits, kp, qp, lead, rep_axis):
         """Softmax over the keys at positions ``kp`` a query at ``qp`` may
-        see; ``lead`` places the mask among the logits' head dimensions."""
+        see; ``lead`` places the mask among the logits' head dimensions,
+        ``rep_axis`` is the logits' axis of a key-value head's query heads
+        (the key-value heads are axis 1)."""
         ok = (kp >= 0) & (kp <= qp) & (qp - kp < w)
-        return jax.nn.softmax(jnp.where(ok[lead], logits,
-                                        jnp.finfo(jnp.float32).min), axis=-1)
+        logits = jnp.where(ok[lead], logits, jnp.finfo(jnp.float32).min)
+        if sink is None:
+            return jax.nn.softmax(logits, axis=-1)
+        shape = [1] * logits.ndim
+        shape[1], shape[rep_axis] = hkv, rep
+        return sink_softmax(logits, sink.reshape(shape))
 
     qg = q.reshape(b, t, hkv, rep, dh)
     if t > w and t % w == 0:
@@ -285,17 +330,17 @@ def window_cached_attention(q, k_ring, v_ring, k_new, v_new, layer, idx, *,
         probs = masked_softmax(
             logits, pb[:, :, None, :],                      # [B, n, 1, 2W]
             q_pos.reshape(b, n, w)[:, :, :, None],          # [B, n, W, 1]
-            (slice(None), None, slice(None), None)).astype(vb.dtype)
+            (slice(None), None, slice(None), None), 3).astype(vb.dtype)
         attn = jnp.einsum("bknrqs,bknsd->bnqkrd", probs, vb
-                          ).reshape(b, t, hq, dh)
+                          ).reshape(b, t, hq, dv)
     else:
         logits = jnp.einsum("btkrd,bksd->bkrts", qg, k_all
                             ).astype(jnp.float32) * scale
         probs = masked_softmax(                             # [B, T, W+T]
             logits, pos[:, None, :], q_pos[:, :, None],
-            (slice(None), None, None)).astype(v_all.dtype)
+            (slice(None), None, None), 2).astype(v_all.dtype)
         attn = jnp.einsum("bkrts,bksd->btkrd", probs, v_all
-                          ).reshape(b, t, hq, dh)
+                          ).reshape(b, t, hq, dv)
     # the ring after the block: row r takes the last real position congruent
     # to r, if the block holds one
     n_real = jnp.full((b,), t, jnp.int32) if valid is None \
@@ -667,10 +712,11 @@ def decode_attention(
     via scalar-prefetch block clamping + VMEM online softmax."""
     b, t, hq, dh = q.shape
     rep_ = hq // k_cache.shape[1]
+    dv = v_cache.shape[3]
     per_slot = jnp.ndim(cache_index) == 1
     if (t == 1 and bias is None and window is None and not per_slot
             and k_cache.shape[2] % 128 == 0
-            and rep_ >= 8
+            and rep_ >= 8 and dv == dh
             and jax.default_backend() == "tpu"):
         # Wide-GQA only (rep >= 8): each grid cell feeds the MXU a
         # [rep, Dh] x [Dh, BS] slab. For MHA both kernel variants MEASURED
@@ -697,7 +743,7 @@ def decode_attention(
                 bias=bias, window=window)
 
         out = jax.lax.map(block, jnp.arange(t // qb))   # [n, B, qb, Hq, Dh]
-        return out.transpose(1, 0, 2, 3, 4).reshape(b, t, hq, dh)
+        return out.transpose(1, 0, 2, 3, 4).reshape(b, t, hq, dv)
     return _dense_decode_attention(q, k_cache, v_cache, cache_index,
                                    scale=scale, bias=bias, window=window)
 
@@ -738,7 +784,7 @@ def _dense_decode_attention(q, k_cache, v_cache, cache_index, *, scale, bias,
                            jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1).astype(v_cache.dtype)
     out = jnp.einsum("bkrts,bksd->btkrd", probs, v_cache)
-    return out.reshape(b, t, hq, dh)
+    return out.reshape(b, t, hq, v_cache.shape[3])
 
 
 def blocked_prompt_attention(q, k_layer, v_layer, q_pos, *, scale=None,
@@ -751,11 +797,12 @@ def blocked_prompt_attention(q, k_layer, v_layer, q_pos, *, scale=None,
     the masked half too).
 
     ``q [B, T, Hq, Dh]`` at the consecutive positions ``q_pos [B, T]``;
-    ``k_layer``, ``v_layer [B, Hkv, S, Dh]`` (unpacked rows, ``S`` a whole
-    number of key blocks) -> ``[B, T, Hq, Dh]`` in ``q``'s dtype. Key blocks
-    past the last query's are not visited; XLA's own matmuls."""
+    ``k_layer [B, Hkv, S, Dh]``, ``v_layer [B, Hkv, S, Dv]`` (unpacked rows,
+    ``S`` a whole number of key blocks) -> ``[B, T, Hq, Dv]`` in ``q``'s
+    dtype. Key blocks past the last query's are not visited; XLA's own
+    matmuls."""
     b, t, hq, dh = q.shape
-    hkv, s_max = k_layer.shape[1], k_layer.shape[2]
+    hkv, s_max, dv = k_layer.shape[1], k_layer.shape[2], v_layer.shape[3]
     rep, bk = hq // hkv, key_block
     assert s_max % bk == 0, (s_max, bk)
     scale = scale if scale is not None else dh ** -0.5
@@ -781,7 +828,7 @@ def blocked_prompt_attention(q, k_layer, v_layer, q_pos, *, scale=None,
     # every query sees key 0, so the first block leaves a finite maximum
     init = (jnp.full((b, hkv, rep, t), -jnp.inf, f32),
             jnp.zeros((b, hkv, rep, t), f32),
-            jnp.zeros((b, hkv, rep, t, dh), f32))
+            jnp.zeros((b, hkv, rep, t, dv), f32))
     _, l, acc = jax.lax.fori_loop(0, blocks, body, init)
-    out = (acc / l[..., None]).astype(q.dtype)           # [B, Hkv, rep, T, Dh]
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, hq, dh)
+    out = (acc / l[..., None]).astype(q.dtype)           # [B, Hkv, rep, T, Dv]
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, t, hq, dv)

@@ -45,6 +45,24 @@ read-modify-write of the 8-aligned pair-row window (HBM tiling forbids
 single-row writes), a ~100 KB round-trip per layer step (per ACTIVE slot
 in the per-slot walk).
 
+Two widths and a sink (the per-slot walks; MiMo-V2's layers): keys and
+values may differ in their last dimension. The two leaves then have chunk
+buffers, write windows and new rows of their own width (``dh`` and ``dv``),
+the scores contract over ``dh``, the accumulator and the result are ``dv``
+wide, and nothing else of a walk changes: the DMAs, the masks and the order
+are the one-width walk's. A key row must still be whole 128-lane tiles (the
+HBM tiling pads a 192-wide row to 256 lanes whatever the leaf's shape says,
+and Mosaic refuses a DMA slice of 192), so such a model caches its keys in
+rows of ``ops/attention.key_row_width`` lanes with zeros behind the live
+ones and hands queries over padded alike: the dot product over the padding
+adds nothing, the fetch of it is the layout's cost (a quarter of a key row at
+192). A ``sink`` (one learned logit a query head, in the softmax's
+denominator, with no value) is the running softmax's FIRST STATE: maximum
+``sink_h``, sum 1, accumulator 0, where a walk without one starts from
+``-inf``, 0, 0. It costs no pass, no mask and no column. For one width and
+no sink both walks trace to the kernels they traced to before
+(tests/unit/ops/test_tpu_compile.py holds their digests).
+
 MHA (rep == 1) scores/PV run as VPU broadcast-multiply + reduce;
 GQA (rep > 1) runs batched MXU ``dot_general`` ([rep, Dh] x [Dh, CS]
 slabs per kv head). Serving-only: no VJP (training uses
@@ -80,11 +98,17 @@ def _compiler_params(vmem_bytes: int = _VMEM_LIMIT):
     return pltpu.CompilerParams(vmem_limit_bytes=vmem_bytes)
 
 
-def supports(hq: int, hkv: int, s_max: int, dh: int) -> bool:
+def supports(hq: int, hkv: int, s_max: int, dh: int,
+             dv: Optional[int] = None) -> bool:
     """Shapes the fused kernel can stream: minor dim must tile to 128
-    (dh a multiple of 128, or dh*pair == 128 with s_max % pair == 0)."""
+    (dh a multiple of 128, or dh*pair == 128 with s_max % pair == 0).
+    ``dv``: the value rows' width where it is not the keys' (the per-slot
+    walks only): both rows as cached of whole 128-lane tiles (a key of 192
+    live lanes is cached in a row of 256: ops/attention.key_row_width)."""
     if hq % hkv:
         return False
+    if dv is not None and dv != dh:
+        return dv % 128 == 0 and dh % 128 == 0 and s_max % 128 == 0
     if dh >= 128:
         return dh % 128 == 0 and s_max % 128 == 0
     # s_max % 128 == 0 implies s_max % (128 // dh) == 0 for any dh | 128
@@ -184,7 +208,7 @@ def _resolve_block_plan(b: int, hkv: int, bs: int, dh: int, itemsize: int,
 
 def _attend_chunk(qv, kc, load_v, valid, m_ref, l_ref, acc_ref, *,
                   hq: int, hkv: int, dh: int, pair: int, scale: float,
-                  mha: str):
+                  mha: str, dv: Optional[int] = None):
     """One chunk of the online softmax, shared by the two slot-paged
     kernels. ``qv [bg, Hq, 1, Dh]`` (the unit dim comes pre-shaped from the
     wrapper: Mosaic cannot reshape bf16 vectors to add one before the minor
@@ -192,13 +216,16 @@ def _attend_chunk(qv, kc, load_v, valid, m_ref, l_ref, acc_ref, *,
     gives the V chunk and is called after the scores, so a kernel waits
     for V there; ``valid(h, shape)`` is the position mask of packed lane
     slice ``h`` (each slice keeps its own position stream). State in
-    ``m_ref / l_ref [bg, Hq]``, ``acc_ref [bg, Hq, Dh]``.
+    ``m_ref / l_ref [bg, Hq]``, ``acc_ref [bg, Hq, Dh]``. ``dv``: the
+    width of a value row where it is not the keys' ``dh`` (unpacked rows
+    only): scores contract over ``dh``, the accumulator is ``dv`` wide.
 
     bf16: products run in bf16 with f32 accumulation — the same precision
     contract as the einsum path's MXU (bf16 multiply, f32 accumulate); a
     full f32 materialization of both chunks measured ~2x the VPU time."""
     bg, _, csp, _ = kc.shape
     rep = hq // hkv
+    dv = dh if dv is None else dv
     ss = []
     for h in range(pair):
         k = kc[..., h * dh:(h + 1) * dh]    # [bg, Hkv, CSP, Dh]
@@ -231,7 +258,7 @@ def _attend_chunk(qv, kc, load_v, valid, m_ref, l_ref, acc_ref, *,
 
     vc = load_v()
     for h, p in enumerate(ps):
-        v = vc[..., h * dh:(h + 1) * dh]
+        v = vc[..., h * dv:(h + 1) * dv]
         if rep == 1 and mha == "vpu":
             pb = p[:, :, :, None].astype(v.dtype)  # None-insert in
             # f32 (bf16 unit-dim reshape is unsupported), cast after
@@ -239,11 +266,11 @@ def _attend_chunk(qv, kc, load_v, valid, m_ref, l_ref, acc_ref, *,
                          dtype=jnp.float32)        # VPU [bg, H, Dh]
         else:
             pg = p.reshape(bg * hkv, rep, csp).astype(v.dtype)
-            vg = v.reshape(bg * hkv, csp, dh)
+            vg = v.reshape(bg * hkv, csp, dv)
             pv = jax.lax.dot_general(              # MXU
                 pg, vg, (((2,), (1,)), ((0,), (0,))),
                 preferred_element_type=jnp.float32)
-            pv = pv.reshape(bg, hq, dh)
+            pv = pv.reshape(bg, hq, dv)
         acc = acc + pv
     l_ref[...] = l_new
     acc_ref[...] = acc
@@ -390,7 +417,8 @@ def _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, q_ref, kn_ref, vn_ref,
                  kbuf, vbuf, kwin, vwin, qrow, knrow, vnrow,
                  m_ref, l_ref, acc_ref, wsem, rsem,
                  *, b: int, bg: int, cs: int, hq: int, hkv: int, dh: int,
-                 pair: int, scale: float, mha: str = "mxu", wpos_ref=None):
+                 pair: int, scale: float, mha: str = "mxu", wpos_ref=None,
+                 dv: Optional[int] = None, sink_ref=None):
     """The per-slot walk (continuous batching): ``idx_ref [B]`` holds each
     slot's own length, ``order_ref [B]`` the slots with the active ones
     first by descending length (:func:`slot_walk`), ``n_ref [1]`` how many
@@ -422,12 +450,21 @@ def _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, q_ref, kn_ref, vn_ref,
       ``wpos`` and not at the length, and that row is left out of the walk:
       in a full ring it holds the one position that has just left the
       window. Without it the token lands at the length, which the walk's
-      ``< length`` mask already leaves out."""
+      ``< length`` mask already leaves out.
+    * ``dv``: value rows of another width than the keys' ``dh`` (unpacked
+      rows): the two leaves' chunks, windows and new rows are ``dh`` and
+      ``dv`` wide, the scores contract over ``dh``, the accumulator and the
+      output are ``dv`` wide.
+    * ``sink_ref [1, Hq]`` float32 (a learned logit a head that takes part
+      in the softmax's denominator and has no value): the running softmax
+      STARTS from it, maximum ``sink_h``, sum 1 (its own ``exp(0)``),
+      accumulator 0, so it costs no pass and no mask."""
     write_at = idx_ref if wpos_ref is None else wpos_ref
     layer = layer_ref[0]
     n_act = n_ref[0]
     csp = cs // pair          # pair-rows per chunk
     dhp = dh * pair           # packed minor dim (>= 128)
+    dvp = dhp if dv is None else dv
 
     def slot_at(p):
         # (clamped: a position past the last slot is never active)
@@ -471,6 +508,9 @@ def _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, q_ref, kn_ref, vn_ref,
                     == jax.lax.rem(i, pair))
         kwin[pl.ds(p, 1)] = jnp.where(sel, kn_ref[pl.ds(s, 1)],
                                       kwin[pl.ds(p, 1)])
+        if dvp != dhp:      # unpacked rows: the row alone selects
+            sel = (jax.lax.broadcasted_iota(jnp.int32, (1, hkv, 8, dvp), 2)
+                   == jax.lax.rem(i, 8))
         vwin[pl.ds(p, 1)] = jnp.where(sel, vn_ref[pl.ds(s, 1)],
                                       vwin[pl.ds(p, 1)])
         win_copy(p, 0, True).start()
@@ -528,14 +568,18 @@ def _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, q_ref, kn_ref, vn_ref,
         qv = qrow[...]                               # [bg, Hq, 1, Dh] bf16
         attend = functools.partial(
             _attend_chunk, qv, m_ref=m_ref, l_ref=l_ref, acc_ref=acc_ref,
-            hq=hq, hkv=hkv, dh=dh, pair=pair, scale=scale, mha=mha)
+            hq=hq, hkv=hkv, dh=dh, pair=pair, scale=scale, mha=mha, dv=dv)
 
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        if sink_ref is None:
+            m_ref[...] = jnp.full_like(m_ref, _NEG)
+            l_ref[...] = jnp.zeros_like(l_ref)
+        else:
+            m_ref[...] = jnp.broadcast_to(sink_ref[...], m_ref.shape)
+            l_ref[...] = jnp.ones_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
         # the new token first: one live position of an 8-row chunk
         attend(jnp.broadcast_to(knrow[...], (bg, hkv, 8, dhp)),
-               lambda: jnp.broadcast_to(vnrow[...], (bg, hkv, 8, dhp)),
+               lambda: jnp.broadcast_to(vnrow[...], (bg, hkv, 8, dvp)),
                lambda h, shape: jax.lax.broadcasted_iota(
                    jnp.int32, shape, 2) < (1 if h == 0 else 0))
 
@@ -582,6 +626,13 @@ def _ring_slot_kernel(layer_ref, idx_ref, order_ref, n_ref, wpos_ref, *refs,
     slot's ring, ``wpos_ref`` the row the new token takes."""
     _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, *refs,
                  wpos_ref=wpos_ref, **geometry)
+
+
+def _sink_kernel(kernel, n_scalar: int, *refs, **geometry):
+    """A per-slot kernel whose operands carry the heads' sink behind the new
+    value row: handed on by name."""
+    at = n_scalar + 3
+    kernel(*refs[:at], *refs[at + 1:], sink_ref=refs[at], **geometry)
 
 
 def supports_block(hq: int, hkv: int, block_size: int, dh: int) -> bool:
@@ -1090,7 +1141,8 @@ def fused_decode_step(q: jax.Array, k_full: jax.Array, v_full: jax.Array,
                       layer, idx, *, active=None,
                       scale: Optional[float] = None,
                       interpret: Optional[bool] = None,
-                      plan: Optional[dict] = None, ring: bool = False):
+                      plan: Optional[dict] = None, ring: bool = False,
+                      sink: Optional[jax.Array] = None):
     """One decode layer-step against the FULL stacked cache.
 
     q:            [B, 1, Hq, Dh]  — the new token's queries
@@ -1114,21 +1166,33 @@ def fused_decode_step(q: jax.Array, k_full: jax.Array, v_full: jax.Array,
                   ``min(idx, S)`` live rows and leaves out the row it
                   writes, so it attends the last ``S`` positions with the
                   new one and nothing older.
+    sink:         per-slot ``idx`` only: ``[Hq]`` learned logits, one a
+                  query head, that take part in the softmax's denominator
+                  and carry no value (``p_j = exp(s_j) / (exp(sink) + sum
+                  exp(s))``); they are the running softmax's first state.
     plan:         optional measured-plan override (the autotune
                   harness's candidate; ops/autotune.py entries are
                   consulted otherwise — ``_resolve_plan``). The per-slot
                   walk takes ``bg`` / ``cs`` from :func:`_slot_plan`
                   unless the override names them.
 
-    Returns ``(attn [B, 1, Hq, Dh], k_full, v_full)`` with the caches
+    Keys and values of two widths (``v_full [L, B, Hkv, S, Dv]``, ``v_new
+    [B, 1, Hkv, Dv]``, unpacked rows, per-slot ``idx``): the scores are over
+    ``Dh``, the result is ``[B, 1, Hq, Dv]``.
+
+    Returns ``(attn [B, 1, Hq, Dv], k_full, v_full)`` with the caches
     updated in place (the returned caches alias the inputs).
     """
     b, t, hq, dh = q.shape
     assert t == 1, "fused_decode_step is the single-token path"
     l, _, hkv, s_rows, d_last = k_full.shape
+    dv = v_new.shape[3]
+    two = dv != dh               # keys and values of two widths
     pair = d_last // dh          # caller may pass an already-packed cache
     s_max = s_rows * pair
-    assert supports(hq, hkv, s_max, dh), (hq, hkv, s_max, dh)
+    assert supports(hq, hkv, s_max, dh, dv), (hq, hkv, s_max, dh, dv)
+    assert not two or (pair == 1 and v_full.shape[4] == dv), \
+        (k_full.shape, v_full.shape)
     assert pair in (1, 128 // dh if dh < 128 else 1), (d_last, dh)
     want_pair = 128 // dh if dh < 128 else 1
     sc = float(scale) if scale is not None else dh ** -0.5
@@ -1160,7 +1224,10 @@ def fused_decode_step(q: jax.Array, k_full: jax.Array, v_full: jax.Array,
     vmem_spec = pl.BlockSpec(memory_space=pltpu.VMEM)
     scalars = [layer_a, idx_a]
     geometry = dict(b=b, hq=hq, hkv=hkv, dh=dh, pair=pair, scale=sc, mha=mha)
-    assert per_slot or not ring, "a ring cache is walked per slot"
+    assert per_slot or not (ring or two or sink is not None), \
+        "a ring, two widths and a sink are the per-slot walks'"
+    if two:
+        geometry["dv"] = dv
     if per_slot:
         walk = active if isinstance(active, SlotWalk) \
             else slot_walk(idx_a, active)
@@ -1177,36 +1244,40 @@ def fused_decode_step(q: jax.Array, k_full: jax.Array, v_full: jax.Array,
                                        **geometry)
     else:
         kernel = functools.partial(_kernel, bg=bg, cs=cs, **geometry)
-    chunk = (2, bg, hkv, cs // pair, dh * pair)
+    n_scalar = len(scalars)
+    operands = [qf, kn, vn]
+    if sink is not None:
+        kernel = functools.partial(_sink_kernel, kernel, n_scalar)
+        operands.append(sink.astype(jnp.float32).reshape(1, hq))
+    dvp = dv if two else dh * pair                 # a value row as cached
+    chunk = (2, bg, hkv, cs // pair)
     scratch = [
-        pltpu.VMEM(chunk, k_full.dtype),
-        pltpu.VMEM(chunk, v_full.dtype),
+        pltpu.VMEM(chunk + (dh * pair,), k_full.dtype),
+        pltpu.VMEM(chunk + (dvp,), v_full.dtype),
         pltpu.VMEM((b, hkv, 8, dh * pair), k_full.dtype),  # write window
-        pltpu.VMEM((b, hkv, 8, dh * pair), v_full.dtype),
+        pltpu.VMEM((b, hkv, 8, dvp), v_full.dtype),
     ]
     if per_slot:
         scratch += [  # a group's rows gathered by sorted position
             pltpu.VMEM((bg, hq, 1, dh), q.dtype),
             pltpu.VMEM((bg, hkv, 1, dh * pair), kn.dtype),
-            pltpu.VMEM((bg, hkv, 1, dh * pair), vn.dtype),
+            pltpu.VMEM((bg, hkv, 1, dvp), vn.dtype),
         ]
     scratch += [
         pltpu.VMEM((bg, hq), jnp.float32),                 # running max
         pltpu.VMEM((bg, hq), jnp.float32),                 # running sum
-        pltpu.VMEM((bg, hq, dh), jnp.float32),             # accumulator
+        pltpu.VMEM((bg, hq, dv), jnp.float32),             # accumulator
         # write sems: per-row windows in the per-slot path; read sems:
         # per-row chunks there
         pltpu.SemaphoreType.DMA((2, b if per_slot else 1)),
         pltpu.SemaphoreType.DMA((2, 2, bg) if per_slot else (2, 2)),
     ]
-    n_scalar = len(scalars)
     attn, k_out, v_out = pl.pallas_call(
         kernel,
         name="dstpu_decode_step",
         in_specs=[smem] * n_scalar + [
-            vmem_spec,                               # q
-            vmem_spec,                               # k_new
-            vmem_spec,                               # v_new
+            vmem_spec                                # q, k_new, v_new, sink
+        ] * len(operands) + [
             pl.BlockSpec(memory_space=pl.ANY),       # k_full (aliased)
             pl.BlockSpec(memory_space=pl.ANY),       # v_full (aliased)
         ],
@@ -1216,16 +1287,17 @@ def fused_decode_step(q: jax.Array, k_full: jax.Array, v_full: jax.Array,
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, hq, dh), q.dtype),
+            jax.ShapeDtypeStruct((b, hq, dv), q.dtype),
             jax.ShapeDtypeStruct(kview.shape, k_full.dtype),
             jax.ShapeDtypeStruct(vview.shape, v_full.dtype),
         ],
         scratch_shapes=scratch,
-        input_output_aliases={n_scalar + 3: 1, n_scalar + 4: 2},
+        input_output_aliases={n_scalar + len(operands): 1,
+                              n_scalar + len(operands) + 1: 2},
         compiler_params=_compiler_params(vmem),
         interpret=(jax.default_backend() != "tpu" if interpret is None
                    else interpret),
-    )(*scalars, qf, kn, vn, kview, vview)
+    )(*scalars, *operands, kview, vview)
     if k_out.shape != k_full.shape:
         k_out = k_out.reshape(k_full.shape)
         v_out = v_out.reshape(v_full.shape)

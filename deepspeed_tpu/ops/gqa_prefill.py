@@ -78,13 +78,16 @@ def _chunk_rows(tq: int) -> int:
 
 
 def supports(s_max: int, row_width: int, head_dim: int, key_block: int,
-             t: int, heads: int, kv_heads: int) -> bool:
+             t: int, heads: int, kv_heads: int,
+             v_head_dim: Optional[int] = None) -> bool:
     """Shapes the kernel takes: unpacked cached rows (a row is one head's
     ``head_dim``) of whole 128-lane tiles, a row count of whole key blocks,
     a token block of whole query tiles of whole chunks, tiles and key blocks
-    of whole sublane tiles (16 rows of bf16), query heads in whole groups."""
+    of whole sublane tiles (16 rows of bf16), query heads in whole groups;
+    value rows of another width (``v_head_dim``) in whole tiles too."""
     tq = query_tile(t)
     return (row_width == head_dim and head_dim % LANES == 0
+            and (v_head_dim or head_dim) % LANES == 0
             and key_block % 16 == 0 and s_max % key_block == 0
             and tq % 16 == 0 and t % tq == 0 and tq % _chunk_rows(tq) == 0
             and heads % kv_heads == 0)
@@ -143,7 +146,8 @@ def _kernel(layer_ref, first_ref, valid_ref, q_ref, k_ref, v_ref, o_ref,
     ``n`` of their rows."""
     del layer_ref                    # the index maps read it
     b, j, kb = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-    tq, bk, d = q_ref.shape[0], k_ref.shape[0], k_ref.shape[1]
+    tq, bk, d, dv = (q_ref.shape[0], k_ref.shape[0], k_ref.shape[1],
+                     v_ref.shape[1])
     lo = first_ref[b] + j * tq                    # the tile's first position
     blocks = _reach(first_ref[b], valid_ref[b], j, tq, bk,
                     pl.num_programs(3))
@@ -188,32 +192,36 @@ def _kernel(layer_ref, first_ref, valid_ref, q_ref, k_ref, v_ref, o_ref,
         for h in range(rep):
             l = l_ref[h * tq:(h + 1) * tq]
             # a tile with no real position was never visited: zeros, not 0/0
-            o_ref[:, h * d:(h + 1) * d] = (
+            o_ref[:, h * dv:(h + 1) * dv] = (
                 acc_ref[h * tq:(h + 1) * tq] / jnp.where(l > 0.0, l, 1.0)
             ).astype(o_ref.dtype)
 
 
 def gqa_prefill(q: jax.Array, k_full: jax.Array, v_full: jax.Array, layer,
                 first, valid=None, *, key_block: int,
+                scale: Optional[float] = None,
                 interpret: Optional[bool] = None):
     """One layer's prompt attention of one token block over the FULL stacked
     key and value caches, whose rows already hold the block's own.
 
     q:       ``[B, T, Hq, Dh]``
     k_full:  ``[L, B, Hkv, S, Dh]`` the stacked cache, unpacked rows;
-    v_full:  the same
+    v_full:  ``[L, B, Hkv, S, Dv]``, the values' own width
+    scale:   of the scores; ``None``: ``Dh ** -0.5``
     layer:   scalar int32, the cache's layer
     first:   scalar or ``[B]`` int32: the position of the block's first query
     valid:   scalar or ``[B]`` int32: how many of the block's positions are
              real; ``None``: all ``T``
 
-    Returns ``[B, T, Hq, Dh]`` in ``q``'s dtype; the rows of a query tile
+    Returns ``[B, T, Hq, Dv]`` in ``q``'s dtype; the rows of a query tile
     with no real position are zeros."""
     b, t, hq, d = q.shape
     l, _, hkv, s_max, w = k_full.shape
+    dv = v_full.shape[4]
     tq, bk = query_tile(t), key_block
-    assert k_full.shape == v_full.shape == (l, b, hkv, s_max, w) and \
-        supports(s_max, w, d, bk, t, hq, hkv), (q.shape, k_full.shape, bk)
+    assert k_full.shape == v_full.shape[:4] + (w,) == (l, b, hkv, s_max, w) \
+        and supports(s_max, w, d, bk, t, hq, hkv, dv), \
+        (q.shape, k_full.shape, v_full.shape, bk)
     rep, n_kb = hq // hkv, s_max // bk
     i32 = jnp.int32
     first = jnp.broadcast_to(jnp.asarray(first, i32), (b,))
@@ -225,18 +233,24 @@ def gqa_prefill(q: jax.Array, k_full: jax.Array, v_full: jax.Array, layer,
         last = _reach(first_ref[bi], valid_ref[bi], j, tq, bk, n_kb) - 1
         return layer_ref[0], bi, g, jnp.minimum(kb, jnp.maximum(last, 0)), 0
 
-    tile = pl.BlockSpec((None, tq, rep * d),
-                        lambda bi, g, j, kb, *_: (bi, j, g))
+    def tile_at(bi, g, j, kb, *_):
+        return bi, j, g
+
+    tile = pl.BlockSpec((None, tq, rep * d), tile_at)
     rows = pl.BlockSpec((None, None, None, bk, d), rows_at)
+    out_tile, v_rows = tile, rows
+    if dv != d:
+        out_tile = pl.BlockSpec((None, tq, rep * dv), tile_at)
+        v_rows = pl.BlockSpec((None, None, None, bk, dv), rows_at)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(b, hkv, t // tq, n_kb),
-        in_specs=[tile, rows, rows],
-        out_specs=tile,
+        in_specs=[tile, rows, v_rows],
+        out_specs=out_tile,
         scratch_shapes=[
             pltpu.VMEM((rep * tq, 1), jnp.float32),       # running max
             pltpu.VMEM((rep * tq, 1), jnp.float32),       # running sum
-            pltpu.VMEM((rep * tq, d), jnp.float32),       # accumulator
+            pltpu.VMEM((rep * tq, dv), jnp.float32),      # accumulator
         ])
     interp = jax.default_backend() != "tpu" if interpret is None \
         else interpret
@@ -245,11 +259,12 @@ def gqa_prefill(q: jax.Array, k_full: jax.Array, v_full: jax.Array, layer,
         vmem_limit_bytes=_VMEM_LIMIT)}
     out = pl.pallas_call(
         functools.partial(
-            _kernel, rep=rep, n=_chunk_rows(tq), scale=float(d ** -0.5)),
+            _kernel, rep=rep, n=_chunk_rows(tq),
+            scale=float(d ** -0.5 if scale is None else scale)),
         name="dstpu_gqa_prefill",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, t, hq * d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, t, hq * dv), q.dtype),
         interpret=interp,
         **kw,
     )(*scalars, q.reshape(b, t, hq * d), k_full, v_full)
-    return out.reshape(b, t, hq, d)
+    return out.reshape(b, t, hq, dv)

@@ -12,7 +12,11 @@ every other leaf is recurrent:
   * **key-value rows** ``k``, ``v``: ``[L, B_slots, Hkv, S_max/pair,
     Dh*pair]`` stacked caches (ops/attention.alloc_kv_cache layout —
     head-major, token-pair packed for Dh < 128) over the model's attention
-    layers. They grow with the request. A per-slot ``lengths`` int32 vector
+    layers. They grow with the request. The geometry is each LEAF's: ``v``
+    may be of another last dimension than ``k`` (models/mimo_v2.py: keys
+    192 wide in rows of 256 lanes, values 128; unpacked then), and a
+    model's rings may have other heads than its rows. A per-slot
+    ``lengths`` int32 vector
     replaces the single scalar cache position, so the fused decode kernel
     (ops/decode_step.py) streams only each ACTIVE slot's valid prefix and
     the einsum path masks per row. Rows behind a slot's length are dead:
@@ -108,22 +112,31 @@ class SlotKVCache:
             self.pair = 1
             self.fused_walk = bool(model.fused_row_walk(self.state, num_slots))
             return
+        # the geometry is each leaf's: ``k`` and ``v`` may differ in their
+        # last dimension (keys wider than values, a key row padded to whole
+        # lane tiles), a ring's heads need not be the rows'. ``head_dim`` is
+        # the model's unpadded key width: only a leaf of packed token pairs
+        # is a multiple of it, any other row is as wide as the leaf says
         head_dim = model.config.head_dim
-        hkv = self.k.shape[2]
-        self.pair = self.k.shape[4] // head_dim
+        hkv, rows, width = self.k.shape[2:]
+        self.pair = width // head_dim if width % head_dim == 0 else 1
+        dk, dv = width // self.pair, self.v.shape[4] // self.pair
         self.fused_walk = (
-            num_slots >= 2 and self.pair == kv_pack_factor(head_dim)
-            and supports(hkv, hkv, self.k.shape[3] * self.pair, head_dim))
+            num_slots >= 2 and self.pair == kv_pack_factor(dk)
+            and supports(hkv, hkv, rows * self.pair, dk, dv))
         # ring leaves (a sliding-window layer's last ``window`` positions):
         # their layers, the window, and whether the fused step walks them
         # too (ops/attention.window_cached_attention's route)
-        ring = next((self.state[n] for n in
-                     getattr(model, "window_state_keys", ())), None)
-        if ring is not None:
+        # (the model names the keys' ring first, the values' last)
+        rings = [self.state[n] for n in
+                 getattr(model, "window_state_keys", ())]
+        if rings:
+            ring = rings[0]
             self.window_layers, self.window = ring.shape[0], ring.shape[3]
             self.fused_window_walk = (
-                num_slots >= 2 and head_dim % 128 == 0
-                and supports(hkv, hkv, self.window, head_dim))
+                num_slots >= 2 and ring.shape[4] % 128 == 0
+                and supports(ring.shape[2], ring.shape[2], self.window,
+                             ring.shape[4], rings[-1].shape[4]))
 
     @property
     def k(self):

@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # Tier-1 verify: the command the driver runs (six xdist workers, a limit of
 # 1,470 s), then dstpu-lint. Run from the repo root.
-# tests/conftest.py keeps the torch modules and unit/analysis/ last and runs
+# tests/conftest.py keeps unit/analysis/ first, the torch modules last, and runs
 # one test's body in a child of its worker; there is no other runner. The
 # count of passes is guarded by the driver's floor (PERF_LEDGER.jsonl,
 # `tests`), not here.

@@ -31,6 +31,12 @@ CFG = family.tiny({
 TOL = dict(rtol=1e-4, atol=1e-5)
 
 
+# The reference as ONE program a shape: op by op (eager) every operation of
+# its layers compiles anew for each new sequence length, a minute a test.
+_reference_logits = jax.jit(
+    lambda params, ids: reference.forward_logits(params, ids, CFG))
+
+
 @pytest.fixture(scope="module")
 def built():
     model = family.build_model(CFG, {})
@@ -39,7 +45,7 @@ def built():
     ids = jnp.asarray(np.random.RandomState(0).randint(0, 512, (2, 29)),
                       jnp.int32)
     with jax.default_matmul_precision("highest"):
-        ref = reference.forward_logits(params, ids, CFG)
+        ref = _reference_logits(params, ids)
     return model, params, ids, ref
 
 
@@ -82,7 +88,8 @@ def test_the_tiny_model_is_the_stated_stack(built):
 def test_full_forward_matches_the_reference(built):
     model, params, ids, ref = built
     with jax.default_matmul_precision("highest"):
-        out = family.engine_logits(model, params, ids)
+        out = jax.jit(lambda p, x: family.engine_logits(model, p, x))(
+            params, ids)
     assert float(jnp.abs(ref).max()) > 0.1      # not a dead model
     np.testing.assert_allclose(out, ref, **TOL)
 
@@ -185,7 +192,7 @@ def test_the_serving_engine_serves_it_over_two_sizes_of_state(built, eng):
         for r in results:
             prompt = reqs[r.rid].prompt
             seq = jnp.asarray([prompt + list(r.tokens)], jnp.int32)
-            rows = reference.forward_logits(eng.params, seq, CFG)[0][
+            rows = _reference_logits(eng.params, seq)[0][
                 len(prompt) - 1:len(prompt) - 1 + len(r.tokens)]
             gap = rows.max(-1) - rows[jnp.arange(len(r.tokens)),
                                       jnp.asarray(r.tokens)]
@@ -563,6 +570,6 @@ def test_generate_runs_the_whole_path_on_one_request(built, eng):
                                   max_new_tokens=12))
     assert out.shape == (1, 25)
     with jax.default_matmul_precision("highest"):
-        rows = reference.forward_logits(eng.params, jnp.asarray(out), CFG)[0]
+        rows = _reference_logits(eng.params, jnp.asarray(out))[0]
     gap = rows[12:24].max(-1) - rows[jnp.arange(12, 24), out[0, 13:]]
     assert float(gap.max()) < 1e-4

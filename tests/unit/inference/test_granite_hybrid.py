@@ -31,6 +31,12 @@ CFG = family.tiny({
 TOL = dict(rtol=1e-4, atol=1e-6)
 
 
+# The reference as ONE program a shape: op by op (eager) every operation of
+# its layers compiles anew for each new sequence length.
+_reference_logits = jax.jit(
+    lambda params, ids: reference.forward_logits(params, ids, CFG))
+
+
 @pytest.fixture(scope="module")
 def built():
     model = family.build_model(CFG, {})
@@ -39,7 +45,7 @@ def built():
     ids = jnp.asarray(np.random.RandomState(0).randint(0, 512, (2, 21)),
                       jnp.int32)
     with jax.default_matmul_precision("highest"):
-        ref = reference.forward_logits(params, ids, CFG)
+        ref = _reference_logits(params, ids)
     return model, params, ids, ref
 
 
@@ -64,7 +70,8 @@ def test_the_tiny_model_is_the_stated_stack(built):
 def test_full_forward_matches_the_reference(built):
     model, params, ids, ref = built
     with jax.default_matmul_precision("highest"):
-        out = family.engine_logits(model, params, ids)
+        out = jax.jit(lambda p, x: family.engine_logits(model, p, x))(
+            params, ids)
     assert float(jnp.abs(ref).max()) > 1e-3
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TOL)
 
@@ -118,11 +125,11 @@ def test_gradients_match_the_reference_loss(built):
     model, params, ids, _ = built
     labels = jnp.roll(ids, -1, axis=1)
     with jax.default_matmul_precision("highest"):
-        (loss, aux), grads = jax.value_and_grad(
+        (loss, aux), grads = jax.jit(jax.value_and_grad(
             lambda p: model.apply(p, {"input_ids": ids, "labels": labels}),
-            has_aux=True)(params)
-        ref_loss, ref_grads = jax.value_and_grad(
-            lambda p: reference.loss(p, ids, labels, CFG))(params)
+            has_aux=True))(params)
+        ref_loss, ref_grads = jax.jit(jax.value_and_grad(
+            lambda p: reference.loss(p, ids, labels, CFG)))(params)
     np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5)
     assert int(aux["ntokens"]) == ids.size
     flat, _ = jax.tree_util.tree_flatten_with_path(grads)
@@ -196,6 +203,6 @@ def test_generate_runs_the_whole_path_on_one_request(built):
     out = np.asarray(eng.generate(ids[:1, :9], max_new_tokens=6))
     seq = jnp.asarray(out[:, :15])
     with jax.default_matmul_precision("highest"):
-        ref = reference.forward_logits(params, seq, CFG)
+        ref = _reference_logits(params, seq)
     assert out[0, 9:15].tolist() == \
         np.asarray(ref[0, 8:14].argmax(-1)).tolist()

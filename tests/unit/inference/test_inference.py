@@ -41,11 +41,18 @@ def gpt2():
 def full_forward_rollout(model, params, input_ids, n_new):
     """Reference loop: re-run the full (no-cache) forward for every token."""
     ids = np.asarray(input_ids)
+
+    # one program a length, not one an operation a length (eager, a MoE
+    # model's six lengths compiled for a minute)
+    @jax.jit
+    def last_logits(params, ids):
+        hidden = model.forward_hidden(params, ids, train=False)
+        return model.logits(params, hidden)[:, -1].astype(jnp.float32)
+
+    params = jax.tree_util.tree_map(jnp.asarray, params)
     for _ in range(n_new):
-        hidden = model.forward_hidden(jax.tree_util.tree_map(jnp.asarray, params),
-                                      jnp.asarray(ids), train=False)
-        logits = model.logits(params, hidden)
-        nxt = np.asarray(jnp.argmax(logits[:, -1].astype(jnp.float32), axis=-1))
+        nxt = np.asarray(jnp.argmax(last_logits(params, jnp.asarray(ids)),
+                                    axis=-1))
         ids = np.concatenate([ids, nxt[:, None].astype(np.int32)], axis=1)
     return ids
 

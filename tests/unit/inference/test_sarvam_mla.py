@@ -34,6 +34,12 @@ pytestmark = pytest.mark.quick
 # key blocks of 8
 CFG = family.tiny(harness.load_json("configs", "sarvam-105b.json"))
 TOL = dict(rtol=1e-4, atol=1e-5)
+
+
+# The reference as ONE program a shape: op by op (eager) every operation of
+# its layers compiles anew for each new sequence length, a minute a test.
+_reference_logits = jax.jit(
+    lambda params, ids: reference.forward_logits(params, ids, CFG))
 T = 48
 
 
@@ -45,7 +51,7 @@ def built():
     ids = jnp.asarray(np.random.RandomState(0).randint(0, 512, (2, T)),
                       jnp.int32)
     with jax.default_matmul_precision("highest"):
-        ref = reference.forward_logits(params, ids, CFG)
+        ref = _reference_logits(params, ids)
     return model, params, ids, ref
 
 
@@ -87,7 +93,8 @@ def test_the_tiny_model_is_the_stated_stack(built):
 def test_full_forward_matches_the_reference(built):
     model, params, ids, ref = built
     with jax.default_matmul_precision("highest"):
-        out = family.engine_logits(model, params, ids)
+        out = jax.jit(lambda p, x: family.engine_logits(model, p, x))(
+            params, ids)
     assert float(jnp.abs(ref).max()) > 0.1      # not a dead model
     np.testing.assert_allclose(out, ref, **TOL)
 
@@ -220,7 +227,7 @@ def test_the_serving_engine_serves_it_over_a_latent_leaf(built, eng):
             prompt = reqs[r.rid].prompt
             assert len(r.tokens) == reqs[r.rid].max_new_tokens
             seq = jnp.asarray([prompt + list(r.tokens)], jnp.int32)
-            rows = reference.forward_logits(eng.params, seq, CFG)[0][
+            rows = _reference_logits(eng.params, seq)[0][
                 len(prompt) - 1:len(prompt) - 1 + len(r.tokens)]
             gap = rows.max(-1) - rows[jnp.arange(len(r.tokens)),
                                       jnp.asarray(r.tokens)]
@@ -390,7 +397,7 @@ def test_generate_takes_the_einsum_route_over_the_same_leaf(built, eng):
     out = np.asarray(eng.generate(prompt, max_new_tokens=6))
     assert out.shape == (2, 15) and (out[:, :9] == np.asarray(prompt)).all()
     with jax.default_matmul_precision("highest"):
-        rows = reference.forward_logits(eng.params, jnp.asarray(out), CFG)
+        rows = _reference_logits(eng.params, jnp.asarray(out))
     gap = rows[:, 8:14].max(-1) - jnp.take_along_axis(
         rows[:, 8:14], jnp.asarray(out[:, 9:])[..., None], -1)[..., 0]
     assert float(gap.max()) < 1e-4
@@ -422,7 +429,7 @@ def test_a_chunked_prefill_continues_the_latent_rows(built):
         for r in results:
             prompt = reqs[r.rid].prompt
             seq = jnp.asarray([prompt + list(r.tokens)], jnp.int32)
-            rows = reference.forward_logits(eng.params, seq, CFG)[0][
+            rows = _reference_logits(eng.params, seq)[0][
                 len(prompt) - 1:len(prompt) - 1 + len(r.tokens)]
             gap = rows.max(-1) - rows[jnp.arange(len(r.tokens)),
                                       jnp.asarray(r.tokens)]

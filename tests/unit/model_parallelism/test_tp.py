@@ -2,6 +2,7 @@
 pure-DP execution (the reference only tests TP indirectly through megatron
 fixtures; here equivalence is asserted directly)."""
 
+import functools
 import sys
 from pathlib import Path
 
@@ -50,8 +51,15 @@ def run_training(model_factory, tp=1, sp=1, stage=0, steps=4, seed=0):
     return engine, losses
 
 
+@functools.lru_cache(maxsize=None)
+def gpt2_run(tp=1, sp=1):
+    """Tiny GPT-2's engine and losses on a mesh, trained once a process for
+    the tests that read them (tp=1 and tp=2 were each built by two)."""
+    return run_training(lambda: GPT2Model(GPT2Config.tiny()), tp=tp, sp=sp)
+
+
 def test_tp_shards_model_axis():
-    engine, _ = run_training(lambda: GPT2Model(GPT2Config.tiny()), tp=2)
+    engine, _ = gpt2_run(tp=2)
     spec = engine.state.params["blocks"]["mlp_fc_w"].sharding.spec
     assert "model" in str(spec), f"mlp weight not TP-sharded: {spec}"
     spec_attn = engine.state.params["blocks"]["qkv_w"].sharding.spec
@@ -59,8 +67,8 @@ def test_tp_shards_model_axis():
 
 
 def test_tp_matches_dp_numerics():
-    _, dp_losses = run_training(lambda: GPT2Model(GPT2Config.tiny()), tp=1)
-    _, tp_losses = run_training(lambda: GPT2Model(GPT2Config.tiny()), tp=2)
+    _, dp_losses = gpt2_run()
+    _, tp_losses = gpt2_run(tp=2)
     np.testing.assert_allclose(dp_losses, tp_losses, rtol=2e-4)
 
 
@@ -72,8 +80,8 @@ def test_tp_with_zero3():
 
 
 def test_sp_matches_dp_numerics():
-    _, dp_losses = run_training(lambda: GPT2Model(GPT2Config.tiny()), sp=1)
-    _, sp_losses = run_training(lambda: GPT2Model(GPT2Config.tiny()), sp=2)
+    _, dp_losses = gpt2_run()
+    _, sp_losses = gpt2_run(sp=2)
     np.testing.assert_allclose(dp_losses, sp_losses, rtol=2e-4)
 
 

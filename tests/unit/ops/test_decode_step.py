@@ -235,3 +235,90 @@ def test_generate_packed_cache_end_to_end():
         nxt = logits[:, -1].argmax(-1).astype(np.int32)
         cur = np.concatenate([cur, nxt[:, None]], axis=1)
     np.testing.assert_array_equal(out, cur)
+
+
+def _two_width_reference(q, kf, vf, kn, vn, idxs, sink, window):
+    """Plain einsum in float32: each slot's ``idx`` cached positions (the
+    last ``window`` of them on a ring) and the new one, ``sink [Hq]`` in the
+    denominator."""
+    b, _, hq, dk = q.shape
+    hkv = kf.shape[1]
+    out = []
+    for i in range(b):
+        n = int(idxs[i])
+        k = np.concatenate([kf[i, :, :n], kn[i].transpose(1, 0, 2)], 1)
+        v = np.concatenate([vf[i, :, :n], vn[i].transpose(1, 0, 2)], 1)
+        if window is not None:
+            k, v = k[:, -window:], v[:, -window:]
+        k, v = (np.repeat(a, hq // hkv, 0) for a in (k, v))
+        s = np.einsum("hd,hsd->hs", q[i, 0], k) * 192 ** -0.5
+        if sink is not None:
+            s = np.concatenate([s, sink[:, None]], 1)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p = p / p.sum(-1, keepdims=True)
+        out.append(np.einsum("hs,hsd->hd", p[:, :v.shape[1]], v))
+    return np.stack(out)[:, None]
+
+
+@pytest.mark.serving
+@pytest.mark.parametrize("sink", [False, True], ids=["plain", "sink"])
+@pytest.mark.parametrize("ring,hkv,s,idxs,active", [
+    # rows that grow, 4 key-value heads of 16 query heads each
+    pytest.param(False, 4, 512, [300, 5, 511, 129], [1, 1, 0, 1], id="rows"),
+    # a ring of 128: one not yet full, one full and wrapped, one just full
+    pytest.param(True, 8, 128, [77, 1000, 128, 0], [1, 1, 1, 1], id="ring"),
+])
+def test_per_slot_walks_keys_192_values_128(ring, hkv, s, idxs, active,
+                                            sink):
+    """Both per-slot walks with keys and values of two widths: a key row of
+    192 live lanes in a leaf of 256 (``key_row_width``), value rows of 128,
+    ``rep`` 16 on 4 heads and 8 on 8, with and without a sink that starts
+    the running softmax."""
+    from deepspeed_tpu.ops.attention import key_row_width, pad_lanes
+
+    rng = np.random.RandomState(3)
+    b, l, hq, dk, dv = 4, 2, 64, 192, 128
+    row = key_row_width(dk)
+    assert row == 256 and supports(hq, hkv, s, row, dv)
+    assert not supports(hq, hkv, s, dk, dv)     # no DMA slice of 192 lanes
+    bf = lambda a: np.asarray(jnp.asarray(a, jnp.bfloat16), np.float32)
+    q, kn = bf(rng.randn(b, 1, hq, dk)), bf(rng.randn(b, 1, hkv, dk))
+    vn = bf(rng.randn(b, 1, hkv, dv))
+    kf, vf = bf(rng.randn(b, hkv, s, dk)), bf(rng.randn(b, hkv, s, dv))
+    sinks = rng.randn(hq).astype(np.float32) if sink else None
+    if ring:    # position p at row p % s: lay the last s positions out so
+        def at_rows(a):
+            out = np.zeros_like(a)
+            for i, n in enumerate(idxs):
+                for p in range(max(0, n - s), n):
+                    out[i, :, p % s] = a[i, :, p - max(0, n - s)]
+            return out
+        k_leaf, v_leaf = at_rows(kf), at_rows(vf)
+    else:
+        k_leaf, v_leaf = kf, vf
+    want = _two_width_reference(q, kf, vf, kn, vn,
+                                [min(n, s) for n in idxs] if ring else idxs,
+                                sinks, s if ring else None)
+    stacked = lambda a: jnp.asarray(np.stack([np.zeros_like(a), a]),
+                                    jnp.bfloat16)
+    got, k1, v1 = fused_decode_step(
+        pad_lanes(jnp.asarray(q, jnp.bfloat16), row),
+        pad_lanes(stacked(k_leaf), row), stacked(v_leaf),
+        pad_lanes(jnp.asarray(kn, jnp.bfloat16), row),
+        jnp.asarray(vn, jnp.bfloat16), jnp.int32(1),
+        jnp.asarray(idxs, jnp.int32), active=jnp.asarray(active), ring=ring,
+        scale=dk ** -0.5, interpret=True,
+        sink=None if sinks is None else jnp.asarray(sinks))
+    act = np.asarray(active, bool)
+    got = np.asarray(got, np.float32)
+    assert got.shape == (b, 1, hq, dv)
+    np.testing.assert_allclose(got[act], want[act], atol=0.03)
+    np.testing.assert_array_equal(got[~act], 0.0)
+    # the new rows landed, each leaf at its own width
+    for i in np.flatnonzero(act):
+        at = idxs[i] % s if ring else idxs[i]
+        np.testing.assert_array_equal(
+            np.asarray(k1[1, i, :, at, :dk], np.float32), kn[i, 0])
+        np.testing.assert_array_equal(
+            np.asarray(v1[1, i, :, at], np.float32), vn[i, 0])
+    assert k1.shape[-1] == row and v1.shape[-1] == dv

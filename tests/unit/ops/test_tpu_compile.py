@@ -276,6 +276,142 @@ def test_gqa_prefill_kernel(one_chip, rows):
     assert "dstpu_gqa_prefill" in text
 
 
+# MiMo-V2.5's share (16 slots): the two global layers' rows at 16,384 with 4
+# key-value heads of 16 query heads, and the five sliding layers' rings of
+# 128 with 8 heads of 8 and a sink; a key row is 192 live lanes of 256
+TWO_WIDTH_SHAPES = [(2, 16, 64, 4, 16384, False), (5, 16, 64, 8, 128, True)]
+
+
+@pytest.mark.parametrize("dims", TWO_WIDTH_SHAPES, ids=str)
+def test_fused_decode_step_keys_256_values_128_and_a_sink(one_chip, dims):
+    """Both per-slot walks with a key leaf 256 lanes wide and a value leaf
+    of 128 (Mosaic refuses a DMA slice of 192 lanes: the HBM tiling pads the
+    row to 256 whatever the leaf says), ``rep`` 16 and 8, the ring with the
+    heads' sink as the running softmax's first state."""
+    from deepspeed_tpu.ops.attention import key_row_width
+    from deepspeed_tpu.ops.decode_step import fused_decode_step, slot_walk
+
+    l, b, hq, hkv, s, ring = dims
+    dk, dv = key_row_width(192), 128
+
+    def fn(q, k, v, kn, vn, layer, idx, active, sink):
+        return fused_decode_step(q, k, v, kn, vn, layer, idx,
+                                 active=slot_walk(idx, active), ring=ring,
+                                 sink=sink if ring else None,
+                                 scale=192 ** -0.5, interpret=False)
+
+    text = _compiled_text(
+        fn, _sds(one_chip, (b, 1, hq, dk)), _sds(one_chip, (l, b, hkv, s, dk)),
+        _sds(one_chip, (l, b, hkv, s, dv)), _sds(one_chip, (b, 1, hkv, dk)),
+        _sds(one_chip, (b, 1, hkv, dv)), _sds(one_chip, (), jnp.int32),
+        _sds(one_chip, (b,), jnp.int32), _sds(one_chip, (b,), jnp.bool_),
+        _sds(one_chip, (hq,), jnp.float32))
+    assert "dstpu_decode_step" in text
+
+
+def test_gqa_prefill_kernel_keys_256_values_128(one_chip):
+    """``dstpu_gqa_prefill`` at MiMo-V2.5's global layers: 64 query over 4
+    key-value heads (16 query heads a cell), keys of 256 lanes and values of
+    128, against the largest bucket's rows. The token block is 256 and not
+    the cell's 2,048: a tile of two chunks a head is the same lowering with
+    32 unrolled chunks for 64 a tile of 512 (12 s of Mosaic for 37; the
+    cell's own blocks compiled and ran on the chip, PERF.md, PR 55)."""
+    from deepspeed_tpu.ops import gqa_prefill
+
+    t, hq, hkv, dk, dv, rows = 256, 64, 4, 256, 128, 16384
+    assert gqa_prefill.supports(rows, dk, dk, 512, 2048, hq, hkv, dv)
+    assert gqa_prefill.supports(rows, dk, dk, 512, t, hq, hkv, dv)
+    assert not gqa_prefill.supports(rows, 192, 192, 512, t, hq, hkv, dv)
+    fn = functools.partial(gqa_prefill.gqa_prefill, key_block=512,
+                           scale=192 ** -0.5, interpret=False)
+    scalar = _sds(one_chip, (), jnp.int32)
+    text = _compiled_text(fn, _sds(one_chip, (1, t, hq, dk)),
+                          _sds(one_chip, (2, 1, hkv, rows, dk)),
+                          _sds(one_chip, (2, 1, hkv, rows, dv)),
+                          scalar, scalar, scalar)
+    assert "dstpu_gqa_prefill" in text
+
+
+def _lowered_text(fn, *args):
+    """The program as jax hands it to the TPU compiler: a Mosaic kernel is
+    whole in it (its module is serialized at lowering), and nothing is
+    compiled, which is nine tenths of such a case's time."""
+    text = jax.jit(fn).lower(*args).as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the lowered text"
+    return text
+
+
+def _kernel_digests(text):
+    """A digest of each Mosaic kernel in a lowered or compiled program: its
+    module printed WITHOUT source locations (the serialized body carries
+    files and lines, which move with every edit above a kernel)."""
+    import base64
+    import hashlib
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+
+    out = []
+    # the quotes of the lowered text's backend_config are escaped (\22)
+    for body in re.findall(r'body(?:"|\\22): ?(?:"|\\22)([\w+/=]+)', text):
+        ctx = jax_mlir.make_ir_context()
+        tpu.register_dialect(ctx)
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+                enable_debug_info=False)
+        out.append(hashlib.sha256(asm.encode()).hexdigest()[:16])
+    return out
+
+
+# (L, B, Hq, Hkv, S, Dh, ring) of the four older attention families' decode
+# steps and the kernel each traced to at PR 54 (the parent of the PR that
+# gave the step two widths and a sink)
+ONE_WIDTH_KERNELS = {
+    "gpt2-large": ((36, 32, 20, 20, 1024, 64, False), "f179d10f202a1411"),
+    "granite-4.0-h-micro": ((4, 64, 32, 8, 2048, 64, False),
+                            "fb4841169344c797"),
+    "k-exaone.rows": ((1, 32, 64, 8, 4096, 128, False), "4bb15ad6036121a2"),
+    "k-exaone.ring": ((4, 32, 64, 8, 128, 128, True), "3ccde5c38a1a3b00"),
+    "solar-open2-250b": ((1, 16, 64, 8, 16384, 128, False),
+                         "8f1d3eff373018e9"),
+}
+
+
+@pytest.mark.parametrize("family", list(ONE_WIDTH_KERNELS))
+def test_one_width_and_no_sink_trace_to_the_kernel_they_did(one_chip, family):
+    """For keys and values of one width and no sink, ``fused_decode_step``
+    lowers to the kernel it lowered to before it knew of two: operation for
+    operation the same module (PERF.md, PR 55: the families' whole decode
+    and prefill programs compiled to the parent's HLO). A PR that changes the
+    walk on purpose records the new digests here."""
+    from deepspeed_tpu.ops.decode_step import fused_decode_step, slot_walk
+
+    (*dims, ring), want = ONE_WIDTH_KERNELS[family]
+
+    def fn(q, k, v, kn, vn, layer, idx, active):
+        return fused_decode_step(q, k, v, kn, vn, layer, idx,
+                                 active=slot_walk(idx, active), ring=ring,
+                                 interpret=False)
+
+    ops = _decode_operands(one_chip, *dims)
+    text = _lowered_text(fn, *ops, _sds(one_chip, (dims[1],), jnp.bool_))
+    assert _kernel_digests(text) == [want]
+
+
+def test_solars_prompt_kernel_is_the_one_it_was(one_chip):
+    from deepspeed_tpu.ops import gqa_prefill
+
+    fn = functools.partial(gqa_prefill.gqa_prefill, key_block=512,
+                           interpret=False)
+    scalar = _sds(one_chip, (), jnp.int32)
+    leaf = _sds(one_chip, (1, 1, 8, 16384, 128))
+    text = _lowered_text(fn, _sds(one_chip, (1, 2048, 64, 128)), leaf, leaf,
+                         scalar, scalar, scalar)
+    assert _kernel_digests(text) == ["51ef2f4fd73336aa"]
+
+
 @pytest.mark.parametrize("tokens", [32, 4096], ids=["decode", "prefill"])
 def test_held_experts_grouped_matmul(one_chip, tokens):
     """The expert layer's grouped matmuls (``jax.lax.ragged_dot``, XLA's own
@@ -569,6 +705,17 @@ def _solar_cell():
         vocab_size=24576, max_seq_len=16384, held=(0, 40))), 16, 16384
 
 
+def _mimo_cell():
+    from deepspeed_tpu.models.mimo_v2 import MimoV2Config, MimoV2Model
+
+    # the cell's seven layers: a dense global one, a run of four sparse
+    # sliding ones, a sparse global one, a sparse sliding one
+    return MimoV2Model(MimoV2Config(
+        vocab_size=19072, max_seq_len=16384, held=(0, 16),
+        hybrid_layer_pattern=(0, 1, 1, 1, 1, 0, 1),
+        moe_layer_freq=(0, 1, 1, 1, 1, 1, 1))), 16, 16384
+
+
 def _weights(model, sharding):
     """The model's parameters as the serving engine holds them: bf16, as
     shapes on the described chip; and one layer's sizes of every stacked
@@ -589,10 +736,11 @@ def _assert_copies_no_weight(compiled, leaves):
 
 @pytest.mark.parametrize("cell", [_exaone_cell, _granite_cell,
                                   _gpt2_large_cell, _sarvam_cell,
-                                  _solar_cell, _longcat_cell],
+                                  _solar_cell, _longcat_cell, _mimo_cell],
                          ids=["k-exaone", "granite-4.0-h-micro",
                               "gpt2-large", "sarvam-105b",
-                              "solar-open2-250b", "longcat-flash-chat"])
+                              "solar-open2-250b", "longcat-flash-chat",
+                              "mimo-v2.5"])
 def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
     """The serve cells' decode step (``InferenceEngine.slot_decode_program``'s
     call of the model: one token a slot, per-slot lengths, the slot walk) at
@@ -633,6 +781,15 @@ def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
     assert " conditional(" not in compiled.as_text()
     if "conv" in state:
         _assert_mamba_runs_are_folded(compiled.as_text(), state["conv"])
+    if cell is _mimo_cell:
+        # the fused step on all seven layers, rows and rings: no einsum over
+        # the cache's 16,384 rows (the only matmuls that wide are the dense
+        # FFN's 16,384 columns)
+        text = compiled.as_text()
+        assert text.count("dstpu_decode_step") >= 4      # one a run of layers
+        assert not [line for line in text.splitlines()
+                    if re.search(r"= \w+\[[\d,]*\b16384,(256|128)\]", line)
+                    and (" dot(" in line or " convolution(" in line)]
     if "kda" in state:
         # one folded call the delta-rule run's layer, the state in place; the
         # prompt block's kernel has no part in a one-token step

@@ -13,7 +13,9 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
-from runtime.pipe.test_pipe import lm_stream, run_pipe_training  # noqa: E402
+# by the name pytest gives the file, so that `pipe_losses` keeps ONE table
+from tests.unit.runtime.pipe.test_pipe import (  # noqa: E402
+    lm_stream, pipe_losses, pipe_run, run_pipe_training)
 
 
 def run_1f1b_training(pp, gas=4, steps=3, seed=0, num_layers=None,
@@ -25,19 +27,19 @@ def run_1f1b_training(pp, gas=4, steps=3, seed=0, num_layers=None,
 
 def test_1f1b_matches_spmd_engine():
     """Same model/data/optimizer: interpreter losses == SPMD-scan losses."""
-    _, l_spmd = run_pipe_training(pp=2)
-    _, l_1f1b = run_1f1b_training(pp=2)
+    l_spmd = pipe_losses(pp=2)
+    l_1f1b = pipe_losses(pp=2, executor="host_1f1b")
     np.testing.assert_allclose(l_spmd, l_1f1b, rtol=2e-4)
 
 
 def test_1f1b_trains():
-    _, losses = run_1f1b_training(pp=2)
+    losses = pipe_losses(pp=2, executor="host_1f1b")
     assert losses[-1] < losses[0], losses
 
 
 def test_1f1b_four_stages_tied():
-    _, l1 = run_pipe_training(pp=1, num_layers=4)
-    _, l4 = run_1f1b_training(pp=4, num_layers=4)
+    l1 = pipe_losses(pp=1, num_layers=4)
+    l4 = pipe_losses(pp=4, num_layers=4, executor="host_1f1b")
     np.testing.assert_allclose(l1, l4, rtol=2e-4)
 
 
@@ -48,7 +50,7 @@ def test_1f1b_stage_submeshes_disjoint():
     partitions layers onto disjoint ranks; p2p.py:50 moves boundaries)."""
     import jax.numpy as jnp
 
-    engine, losses = run_1f1b_training(pp=2, steps=1)
+    engine, losses = pipe_run(pp=2, executor="host_1f1b")
     ex = engine._executor_1f1b
     assert ex.submeshes is not None, "submesh placement inactive on a pp=2 mesh"
     sets = ex.stage_device_sets()
@@ -68,11 +70,11 @@ def test_1f1b_dropout_matches_spmd():
     per-(microbatch, layer) keys through the same
     PipelinedModelAdapter.layer_key — losses stay numerics-identical, so
     dropout is applied (and applied IDENTICALLY) on both executors."""
-    _, l_spmd = run_pipe_training(pp=2, steps=2, dropout=0.25)
-    _, l_1f1b = run_1f1b_training(pp=2, steps=2, dropout=0.25)
+    l_spmd = pipe_losses(pp=2, steps=2, dropout=0.25)
+    l_1f1b = pipe_losses(pp=2, steps=2, dropout=0.25, executor="host_1f1b")
     np.testing.assert_allclose(l_spmd, l_1f1b, rtol=2e-4)
     # and it differs from the dropout-free run: the masks really fire
-    _, l_plain = run_1f1b_training(pp=2, steps=2)
+    l_plain = pipe_losses(pp=2, steps=2, executor="host_1f1b")
     assert abs(l_1f1b[0] - l_plain[0]) > 1e-4, (l_plain, l_1f1b)
 
 
@@ -96,7 +98,7 @@ def test_1f1b_memory_bounded_by_depth_not_microbatches():
 def test_1f1b_schedule_wire_pairing_validated():
     """The interpreter asserts send/recv pairing — running it IS the
     schedule-stream validation (schedules are no longer spec-only)."""
-    engine, losses = run_1f1b_training(pp=2, steps=1)
+    engine, losses = pipe_run(pp=2, executor="host_1f1b")
     assert np.isfinite(losses[0])
 
 
@@ -130,9 +132,9 @@ def test_1f1b_fp16_loss_scale_unscales():
 
 def test_1f1b_eval_batch_inference_schedule():
     """engine.eval_batch in host_1f1b mode interprets InferenceSchedule and
-    matches the SPMD eval loss (both engines trained one identical step)."""
-    engine_spmd, _ = run_pipe_training(pp=2, steps=1)
-    engine_1f1b, _ = run_1f1b_training(pp=2, steps=1)
+    matches the SPMD eval loss (both engines trained the same steps)."""
+    engine_spmd, _ = pipe_run(pp=2)
+    engine_1f1b, _ = pipe_run(pp=2, executor="host_1f1b")
     batch = lm_stream(4, n=1, seed=7)[0]
     l_spmd = float(jax.device_get(engine_spmd.eval_batch(batch)))
     l_1f1b = float(jax.device_get(engine_1f1b.eval_batch(batch)))

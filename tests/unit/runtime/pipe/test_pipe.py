@@ -112,20 +112,40 @@ def run_pipe_training(pp, gas=4, steps=3, stage=0, tie=True, seed=0, num_layers=
     return engine, losses
 
 
+_RUNS = {}
+
+
+def pipe_run(steps=3, **setting):
+    """``run_pipe_training(**setting)``'s engine and its first losses, for
+    the tests that train it no further (they read its losses, its shardings,
+    what it refuses, an evaluation). A setting is built and trained once a
+    process, three steps (the data and the seeds are the setting's, so a
+    shorter run's losses are a longer one's first): the plain two-stage run
+    alone was built and compiled by nine tests of this directory."""
+    key = tuple(sorted(setting.items()))
+    if len(_RUNS.get(key, (None, ()))[1]) < steps:
+        _RUNS[key] = run_pipe_training(steps=max(steps, 3), **setting)
+    return _RUNS[key]
+
+
+def pipe_losses(steps=3, **setting):
+    return pipe_run(steps, **setting)[1][:steps]
+
+
 def test_pipeline_engine_trains():
-    engine, losses = run_pipe_training(pp=2)
+    losses = pipe_losses(pp=2)
     assert losses[-1] < losses[0], losses
 
 
 def test_pipeline_matches_single_stage():
-    _, l1 = run_pipe_training(pp=1)
-    _, l2 = run_pipe_training(pp=2)
+    l1 = pipe_losses(pp=1)
+    l2 = pipe_losses(pp=2)
     np.testing.assert_allclose(l1, l2, rtol=2e-4)
 
 
 def test_pipeline_four_stages_tied():
-    _, l1 = run_pipe_training(pp=1, tie=True, num_layers=4)
-    _, l4 = run_pipe_training(pp=4, tie=True, num_layers=4)
+    l1 = pipe_losses(pp=1, num_layers=4)
+    l4 = pipe_losses(pp=4, num_layers=4)
     np.testing.assert_allclose(l1, l4, rtol=2e-4)
 
 
@@ -135,8 +155,8 @@ def test_pipeline_dropout_applied():
     PipelinedModelAdapter.layer_key reach the block layers (reference
     threads CudaRNGStatesTracker through its stages,
     activation_checkpointing/checkpointing.py:121)."""
-    _, l_plain = run_pipe_training(pp=2, steps=2)
-    _, l_drop = run_pipe_training(pp=2, steps=2, dropout=0.25)
+    l_plain = pipe_losses(pp=2, steps=2)
+    l_drop = pipe_losses(pp=2, steps=2, dropout=0.25)
     assert all(np.isfinite(l_drop)), l_drop
     # dropout must change the training forward — identical losses would
     # mean the rng never reached the attention dropout mask
@@ -146,7 +166,7 @@ def test_pipeline_dropout_applied():
 def test_pipeline_dropout_off_at_eval():
     """eval_batch never applies dropout: two evals agree bit-for-bit and
     match the no-dropout model's eval."""
-    engine, _ = run_pipe_training(pp=2, steps=1, dropout=0.25)
+    engine, _ = pipe_run(pp=2, dropout=0.25)
     batch = lm_stream(1, n=1)[0]
     e1 = float(jax.device_get(engine.eval_batch(batch)))
     e2 = float(jax.device_get(engine.eval_batch(batch)))
@@ -157,7 +177,7 @@ def test_pipeline_with_tensor_parallel():
     """3D composition: pipe=2 × tp=2 × data=2 matches pipe-only numerics
     (closes the PipeModelDataParallelTopology composition gap, reference
     runtime/pipe/topology.py:244)."""
-    _, l_ref = run_pipe_training(pp=2, tp=1, stage=1)
+    l_ref = pipe_losses(pp=2, stage=1)
     engine, l_tp = run_pipe_training(pp=2, tp=2, stage=1)
     np.testing.assert_allclose(l_ref, l_tp, rtol=3e-4)
     # TP really sharded: qkv fused dim carries the 'model' axis
@@ -166,7 +186,7 @@ def test_pipeline_with_tensor_parallel():
 
 
 def test_pipeline_with_zero1():
-    engine, losses = run_pipe_training(pp=2, stage=1)
+    engine, losses = pipe_run(pp=2, stage=1)
     assert losses[-1] < losses[0]
     spec = str(jax.tree_util.tree_leaves(
         jax.tree_util.tree_map(lambda x: x.sharding.spec,
@@ -175,13 +195,13 @@ def test_pipeline_with_zero1():
 
 
 def test_pipeline_body_sharded_over_pipe_axis():
-    engine, _ = run_pipe_training(pp=2, steps=1)
+    engine, _ = pipe_run(pp=2)
     for leaf in jax.tree_util.tree_leaves(engine.state.params["body"]):
         assert "pipe" in str(leaf.sharding.spec), leaf.sharding.spec
 
 
 def test_forward_backward_disabled():
-    engine, _ = run_pipe_training(pp=2, steps=1)
+    engine, _ = pipe_run(pp=2)
     with pytest.raises(PipelineError):
         engine.forward(None)
     with pytest.raises(PipelineError):
@@ -191,7 +211,7 @@ def test_forward_backward_disabled():
 
 
 def test_eval_batch():
-    engine, _ = run_pipe_training(pp=2, steps=1)
+    engine, _ = pipe_run(pp=2)
     batch = lm_stream(1, n=1)[0]
     loss = float(jax.device_get(engine.eval_batch(batch)))
     assert np.isfinite(loss)

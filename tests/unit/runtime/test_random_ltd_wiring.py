@@ -31,18 +31,25 @@ def test_model_ltd_keep_drops_tokens():
     params = model.init(jax.random.PRNGKey(0))
     batch = _batch(cfg, 2, 64)
     rngs = {"dropout": jax.random.PRNGKey(1)}
-    base, _ = model.apply(params, batch, rngs=rngs, train=True)
-    full, _ = model.apply(params, batch, rngs=rngs, train=True, ltd_keep=64)
-    half, _ = model.apply(params, batch, rngs=rngs, train=True, ltd_keep=32)
+
+    # one program a setting (op by op, four layers' forward and backward
+    # compiled an operation at a time: most of a minute)
+    def loss(**kw):
+        return jax.jit(lambda p: model.apply(p, batch, rngs=rngs, train=True,
+                                             **kw)[0])
+
+    base = loss()(params)
+    full = loss(ltd_keep=64)(params)
+    half = loss(ltd_keep=32)(params)
     assert float(full) == float(base)          # keep >= T: path disabled
     assert np.isfinite(float(half))
     assert float(half) != float(base)          # tokens were actually dropped
     # deterministic under the same rng
-    half2, _ = model.apply(params, batch, rngs=rngs, train=True, ltd_keep=32)
+    half2 = loss(ltd_keep=32)(params)
     assert float(half) == float(half2)
     # grads flow through the routed path
-    g = jax.grad(lambda p: model.apply(p, batch, rngs=rngs, train=True,
-                                       ltd_keep=32)[0])(params)
+    g = jax.jit(jax.grad(lambda p: model.apply(
+        p, batch, rngs=rngs, train=True, ltd_keep=32)[0]))(params)
     gn = sum(float(jnp.abs(x).sum()) for x in jax.tree_util.tree_leaves(g))
     assert np.isfinite(gn) and gn > 0
 

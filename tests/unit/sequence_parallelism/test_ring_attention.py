@@ -4,6 +4,8 @@ The reference has no SP (SURVEY §5.7) — equivalence is asserted against the
 dense jnp attention, forward AND gradients, which is stronger than the
 reference's block-sparse kernel tests (numeric vs dense torch)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -89,6 +91,7 @@ def test_ring_bf16_runs():
 
 
 # ------------------------------------------------------------- model-level
+@functools.lru_cache(maxsize=None)   # the dense run is three tests' reference
 def _train(attn_impl, sp, steps=3):
     groups.reset()
     topo = build_topology(sp=sp)

@@ -58,7 +58,11 @@ def _engine(family):
     return _ENGINES[family]
 
 
-@pytest.fixture(params=list(MODELS))
+# module-scoped for the ORDER it gives: pytest runs a family's cases one after
+# another, so a stretch of them handed to one xdist worker (`--dist load`
+# hands out runs of consecutive tests) builds that family's engine, and not
+# all three in every worker (the engine itself is `_ENGINES`' either way)
+@pytest.fixture(scope="module", params=list(MODELS))
 def engine(request):
     return _engine(request.param)
 
@@ -87,10 +91,19 @@ def _requests(engine, lens, new, seed=0, **kw):
             for i, (n, m) in enumerate(zip(lens, new))]
 
 
+GEN_NEW = 16        # the longest stream a case of this file asks for
+
+
 def _generate(engine, req):
+    """What ``generate()`` gives the request alone. A greedy stream is the
+    head of every longer one of its prompt, so ``generate()`` compiles one
+    program a prompt length (its key is the length and ``max_new_tokens``)
+    and not one a request; the cases draw their prompts from few lengths."""
+    assert req.max_new_tokens <= GEN_NEW
     out = engine.generate(np.asarray(req.prompt, np.int32)[None],
-                          max_new_tokens=req.max_new_tokens)
-    return np.asarray(out)[0, len(req.prompt):].tolist()
+                          max_new_tokens=GEN_NEW)
+    at = len(req.prompt)
+    return np.asarray(out)[0, at:at + req.max_new_tokens].tolist()
 
 
 def _counters(srv):
@@ -138,7 +151,7 @@ def _eos_case(engine):
     """Two requests and an EOS id from the model's own greedy streams: the
     first emits it mid-stream (not as its first token, not as its last), the
     second never does."""
-    reqs = _requests(engine, [9, 14, 5, 12, 7, 10], [14] * 6, seed=2)
+    reqs = _requests(engine, [8, 16, 3, 11, 21, 27], [14] * 6, seed=2)
     streams = [_generate(engine, r) for r in reqs]
     for a, sa in enumerate(streams):
         for b, sb in enumerate(streams):
@@ -175,7 +188,7 @@ def test_cancel_under_a_step_in_flight(engine):
     flight: no token of that step reaches it, no result is ever returned for
     it, the other slots' streams are whole, and the freed slot serves the
     next request as a fresh engine would."""
-    reqs = _requests(engine, [10, 6, 13, 8], [12, 12, 12, 10], seed=3)
+    reqs = _requests(engine, [11, 3, 16, 8], [12, 12, 12, 10], seed=3)
     seen = []
     reqs[1].on_token = seen.append
     srv = _serving(engine, num_slots=3)
@@ -227,7 +240,7 @@ def test_the_drain_commits_the_step_in_flight(gpt2):
 
 
 def test_a_request_of_one_token_needs_no_decode_step(engine):
-    reqs = _requests(engine, [12, 5], [1, 1], seed=4)
+    reqs = _requests(engine, [11, 3], [1, 1], seed=4)
     srv = _serving(engine, num_slots=1)
     out = {r.rid: r for r in srv.run(reqs)}
     for req in reqs:
@@ -235,7 +248,7 @@ def test_a_request_of_one_token_needs_no_decode_step(engine):
         assert out[req.rid].finish_reason == "length"
     assert srv.decode_steps == 0 and srv._flight is None
     # beside a longer request: it ends at its prefill, the other decodes on
-    mixed = _requests(engine, [12, 9], [1, 6], seed=4)
+    mixed = _requests(engine, [11, 8], [1, 6], seed=4)
     srv = _serving(engine, num_slots=2)
     out = {r.rid: r.tokens for r in srv.run(mixed)}
     for req in mixed:
@@ -341,7 +354,7 @@ def test_chunked_prefill_under_a_budget_needs_nothing_new(engine):
     around them (what an intermediate chunk writes into the previous tokens
     at its slot is never read); the step behind its last chunk takes the
     first token on the device."""
-    reqs = _requests(engine, [6, 45, 9, 40], [14, 5, 12, 6], seed=7)
+    reqs = _requests(engine, [3, 45, 8, 40], [14, 5, 12, 6], seed=7)
     srv = _serving(engine, num_slots=4, buckets=(16, 64),
                    prefill_token_budget=16)
     out = {r.rid: r for r in srv.run(reqs)}
