@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.models.base import ATTN_IMPLS, cache_positions, cross_entropy_loss, embed_tokens, gathered_top, gelu, layer_norm, qdot, sp_attention, tied_logits
-from deepspeed_tpu.models.stack import cached_walk, kv_cache, next_cache, wrapped_block
+from deepspeed_tpu.models.stack import cached_walk, kv_cache, next_cache, walk, wrapped_block
 from deepspeed_tpu.ops.attention import cached_attention, multihead_attention
 
 
@@ -202,9 +202,9 @@ class GPT2Model:
         top = gathered_top(params, "blocks")   # ZeRO-3: the embeddings, whole
         x = embed_tokens(top["wte"], input_ids, self.compute_dtype)
         x = x + top["wpe"].astype(self.compute_dtype)[:t][None]
-        block_fn = wrapped_block(
-            lambda x, blk, rng: self._block(x, blk, rng=rng, train=train)[0],
-            "blocks", self.remat, self.remat_policy)
+
+        def block(x, blk, rng):
+            return self._block(x, blk, rng=rng, train=train)[0]
 
         rng0 = rngs.get("dropout") if isinstance(rngs, dict) else rngs
         if (ltd_keep is not None and train and ltd_keep < t
@@ -219,6 +219,8 @@ class GPT2Model:
             assert rng0 is not None, "random-LTD needs a dropout rng"
             assert pld_theta is None, \
                 "random-LTD and progressive_layer_drop are exclusive"
+            block_fn = wrapped_block(block, "blocks", self.remat,
+                                     self.remat_policy)
             from deepspeed_tpu.runtime.data_pipeline.random_ltd import (
                 gather_tokens, sample_token_indices, scatter_tokens)
 
@@ -241,16 +243,17 @@ class GPT2Model:
             return layer_norm(x, top["ln_f_scale"], top["ln_f_bias"], c.eps)
 
         use_pld = pld_theta is not None and train
-        layer_idx = jnp.arange(c.num_layers)
+        # a block reads qkv_w first: ZeRO-3 fetches it a layer ahead
+        block_fn = wrapped_block(block, "blocks", self.remat,
+                                 self.remat_policy, first="qkv_w")
 
-        def scan_body(carry, layer_in):
+        def layer(carry, layer_params, i, ahead=None):
             x, rng = carry
-            layer_params, i = layer_in
             if rng is not None:
                 rng, sub = jax.random.split(rng)
             else:
                 sub = None
-            x_new = block_fn(x, layer_params, sub)
+            x_new = block_fn(x, layer_params, sub, ahead=ahead)
             if use_pld:
                 # stochastic depth (progressive layer drop): keep prob anneals
                 # linearly in depth from 1 to theta; expectation-preserving
@@ -264,10 +267,11 @@ class GPT2Model:
                 x = x + gate * (x_new - x)
             else:
                 x = x_new
-            return (x, rng), None
+            return x, rng
 
-        (x, _), _ = jax.lax.scan(scan_body, (x, rng0),
-                                 (params["blocks"], layer_idx))
+        x, _ = walk(layer, (x, rng0), params["blocks"],
+                    xs=(jnp.arange(c.num_layers),),
+                    first_leaf=block_fn.first_leaf)
         return layer_norm(x, top["ln_f_scale"], top["ln_f_bias"], c.eps)
 
     def logits(self, params, hidden):

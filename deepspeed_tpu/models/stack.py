@@ -17,32 +17,66 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import gathered, layer_view
+from deepspeed_tpu.models.base import gathered, gathers, layer_view
 from deepspeed_tpu.ops.attention import alloc_kv_cache
 from deepspeed_tpu.runtime.activation_checkpointing import checkpoint_policy
+from deepspeed_tpu.telemetry.registry import get_registry
+
+
+@jax.custom_vjp
+def _together(x, coming):
+    """``(x, coming)`` handed over as one: neither before the other is
+    there."""
+    return jax.lax.optimization_barrier((x, coming))
+
+
+# the order is the forward pass's alone, and nothing is differentiated
+# through ``coming``
+_together.defvjp(lambda x, coming: (_together(x, coming), None),
+                 lambda _, g: (g[0], None))
 
 
 def wrapped_block(block, stack: str, remat: bool = False,
-                  remat_policy: Optional[str] = None):
+                  remat_policy: Optional[str] = None,
+                  first: Optional[str] = None):
     """``block(x, blk, *args) -> x`` as the training walk runs it on one
     layer's slice ``blk`` of the stack found under ``params[stack]``.
 
     ZeRO-3 gathers the layer's weights inside what remat wraps, so the
     backward pass gathers them again and the scan saves no whole weight.
+    ``first`` names the leaf of the stack a block uses first, where the
+    model states one (and reads it through ``base.qdot``). Gathered in its
+    place that leaf has nothing of its own layer to wait under, so where the
+    plan shards it the FORWARD pass gathers it a layer ahead and carries it
+    (:func:`walk`); the backward pass is the one of a leaf gathered in
+    place (``base.Brought``). What that costs: the leaf in flight, and,
+    only where remat recomputes the product that reads the leaf instead of
+    keeping it (``dots_no_batch`` keeps it), the carried leaf saved whole
+    for every layer (GPT-2 XL's ``qkv_w``: 48 x 15.4 MB, 0.74 GB a chip).
+
+    The result is called as ``fn(x, blk, *args)``, and by a walk that
+    carries ``first`` as ``fn(x, blk, *args, ahead=whole)``: ``whole`` is
+    this layer's ``first`` as the layer before brought it, and stands in
+    the place of ``blk[first]`` gathered. ``fn.first_leaf`` keeps ``(stack,
+    first)`` for the walk, or None.
+
     Call this inside the model's ``forward_hidden``, once a trace: jax keeps
     a traced block (``jax.checkpoint``, ``lax.scan``) by its function, and a
     function that outlived its trace would replay the gathers, or their
     absence, of whichever engine traced first (``base.gathered``)."""
 
-    def fn(x, blk, *args):
-        return block(x, gathered(blk, stack, stacked=True), *args)
+    def fn(x, blk, *args, ahead=None):
+        brought = None if ahead is None else {first: ahead}
+        return block(x, gathered(blk, stack, stacked=True, brought=brought),
+                     *args)
 
     if remat:
         fn = jax.checkpoint(fn, policy=checkpoint_policy(remat_policy))
+    fn.first_leaf = None if first is None else (stack, first)
     return fn
 
 
-def walk(block, x, stack, *args, xs=(), run=None):
+def walk(block, x, stack, *args, xs=(), run=None, first_leaf=None):
     """``x`` through the layers of ``stack`` (its stacked leaves):
     ``block(x, blk, *xs_of_the_layer, *args)`` for each, ``block`` from
     :func:`wrapped_block`. The stack is the scan's input, so the layers'
@@ -50,7 +84,55 @@ def walk(block, x, stack, *args, xs=(), run=None):
 
     ``run=(first, count)`` walks that sub-range of the stack and indexes it
     by layer number instead: a slice of the stack as the scan's input would
-    be a copy of it (the hybrid model's runs of equal layers)."""
+    be a copy of it (the hybrid model's runs of equal layers).
+
+    ``first_leaf``: the wrapped block's, where ``block`` is a layer function
+    of the model's around it and passes ``ahead`` on to it (GPT-2's, for its
+    dropout key and layer drop). Where it names a leaf that the plan
+    shards (``base.gathers``), that leaf rides the scan's carry whole:
+    layer 0's is gathered before the scan, and the body gathers layer
+    i+1's (read by layer number: shifted by a layer as the scan's input it
+    would be a copy of the whole stacked leaf) and hands it over together
+    with layer i's output. Handed over apart, the gather has no reader in
+    the body and the compiler gives it no matmul to run beside: it takes a
+    body's gathers in the order of their readers, one at a time, each
+    beside the matmul before its reader (the compiled step for a v5e,
+    PERF.md, PR 56). Nothing is differentiated through the carry: the
+    gradient goes to the layer's own slice in the layer's own backward,
+    which gathers that slice in place (``base.Brought``). The last
+    iteration gathers the last layer's leaf once more and nobody reads it:
+    a body that is the same for every layer costs one transfer in
+    ``count`` more, a last layer outside the scan a second lowering of the
+    block. Where nothing is to be carried the program is the one without
+    it."""
+    first_leaf = first_leaf or getattr(block, "first_leaf", None)
+    if first_leaf is not None and gathers(*first_leaf):
+        assert run is None, "no family that walks by layer number states " \
+            "a first leaf: carry it in this form when one does"
+        name, key = first_leaf
+        leaf = jax.lax.stop_gradient(stack[key])
+        last = leaf.shape[0] - 1
+        get_registry().counter("zero/traced_prefetched_gather").inc()
+
+        def fetch(layer):
+            shard = jax.lax.dynamic_index_in_dim(leaf, layer, 0,
+                                                 keepdims=False)
+            return gathered({key: shard}, name, stacked=True,
+                            ahead=True)[key]
+
+        def body(carry, layer_in):
+            x, whole = carry
+            blk, layer, per_layer = layer_in
+            coming = fetch(jnp.minimum(layer + 1, last))
+            x = block(x, blk, *per_layer, *args, ahead=whole)
+            # with the output's first leaf alone: what else a model carries
+            # (GPT-2's dropout key) stays dead code where it was
+            out, tree = jax.tree_util.tree_flatten(x)
+            out[0], coming = _together(out[0], coming)
+            return (tree.unflatten(out), coming), None
+
+        return jax.lax.scan(body, (x, fetch(0)),
+                            (stack, jnp.arange(last + 1), xs))[0][0]
     if run is None:
         def body(x, layer_in):
             blk, per_layer = layer_in

@@ -5,7 +5,10 @@ decoder shares), the embedding and the head at their use. On the CPU's
 virtual devices: what the compiled train step moves between devices, and
 that the mathematics is stage 0's."""
 
+import contextlib
+import functools
 import re
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -21,13 +24,13 @@ from deepspeed_tpu.utils import groups
 VOCAB, SEQ, HIDDEN, LAYERS = 256, 32, 64, 3
 
 
-def _gpt2():
+def _gpt2(remat_policy="dots_no_batch"):
     from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
 
     cfg = GPT2Config(vocab_size=VOCAB, max_seq_len=SEQ, num_layers=LAYERS,
                      hidden_size=HIDDEN, num_heads=4, loss_chunk=16)
     return GPT2Model(cfg, compute_dtype=jnp.float32, remat=True,
-                     remat_policy="dots_no_batch")
+                     remat_policy=remat_policy)
 
 
 def _llama():
@@ -189,11 +192,15 @@ def test_what_lies_outside_the_stacks_is_named_by_the_model():
         len(jax.tree_util.tree_leaves(params["attention"]))
 
 
+@pytest.mark.parametrize("name", ["gpt2", "llama"])
 @pytest.mark.parametrize("stage,dp", [(0, 4), (1, 4), (2, 4), (3, 1)])
-def test_helper_is_the_identity_below_stage3_and_on_one_device(stage, dp):
+def test_helper_is_the_identity_below_stage3_and_on_one_device(stage, dp,
+                                                               name):
     """Nothing is sharded for compute, so the engine states no gather and a
-    model traces to the jaxpr it has outside any engine."""
-    model = _gpt2()
+    model traces to the jaxpr it has outside any engine: GPT-2, which names
+    the leaf its block uses first (``wrapped_block``'s ``first``), carries
+    nothing, and LLaMA, which names none, is not asked."""
+    model = MODELS[name]()
     engine = _engine(model, stage, dp)
     assert engine._param_use is None
     params = engine._params_shape
@@ -242,3 +249,193 @@ def test_one_model_object_under_two_engines():
     assert [s for s in stage3 if s.endswith(whole)], stage3
     again = _collectives(_step_text(_engine(model, 0, 4), batch), "all-gather")
     assert not [s for s in again if s.endswith(whole)], again
+
+
+# ---- the leaf a block uses first, gathered a layer ahead (GPT-2's qkv_w)
+QKV = f"f32[{HIDDEN},{3 * HIDDEN}]"
+
+
+def _zero_counters():
+    from deepspeed_tpu.telemetry.registry import get_registry
+
+    reg = get_registry()
+    return (reg.counter("zero/traced_prefetched_gather").value,
+            reg.counter("zero/traced_gather").value)
+
+
+def _every_gather_in_place():
+    """The walk as it is where no leaf is carried: ``first`` is stated and
+    the plan is taken to gather nothing ahead."""
+    return mock.patch("deepspeed_tpu.models.stack.gathers",
+                      lambda *path: False)
+
+
+@functools.lru_cache(maxsize=None)
+def _compiled_step(carried: bool, remat_policy="dots_no_batch"):
+    """(text, temporaries in bytes) of GPT-2's stage-3 step on data=4,
+    with ``qkv_w`` carried a layer ahead or every gather in place."""
+    batch = _batch(4)
+    with contextlib.nullcontext() if carried else _every_gather_in_place():
+        engine = _engine(_gpt2(remat_policy), 3, 4)
+        engine._build_train_step(batch)
+        placed = jax.device_put(batch, engine._gas_batch_shardings(batch))
+        compiled = engine._compiled_train_step.lower(
+            engine.state, placed, jnp.asarray(1e-3, jnp.float32),
+            jax.random.PRNGKey(0), None, None).compile()
+    return compiled.as_text(), compiled.memory_analysis().temp_size_in_bytes
+
+
+def test_first_leaf_a_layer_ahead_is_the_same_mathematics():
+    """Loss and every gradient of a micro-step with ``qkv_w`` gathered a
+    layer ahead equal those with every gather in place."""
+    engine, batch = _engine(_gpt2(), 3, 4), _batch(4)
+    micro = jax.device_put({k: v[0] for k, v in batch.items()},
+                           engine._batch_shardings(
+                               {k: v[0] for k, v in batch.items()}))
+
+    def loss_and_grads():   # a new function each time: a new trace
+        return jax.jit(lambda p, b: engine._micro_loss_and_grads(
+            p, b, 1.0, jax.random.PRNGKey(0))[:2])
+
+    before = _zero_counters()
+    ahead = jax.device_get(loss_and_grads()(engine.state.params, micro))
+    assert _zero_counters()[0] > before[0], "nothing was carried"
+    with _every_gather_in_place():
+        before = _zero_counters()
+        in_place = jax.device_get(
+            loss_and_grads()(engine.state.params, micro))
+        assert _zero_counters()[0] == before[0]
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6),
+        ahead, in_place)
+    assert float(np.abs(in_place[1]["blocks"]["qkv_w"]).max()) > 0
+
+
+def _computations(text):
+    """name -> instruction lines of each computation of a compiled text."""
+    out, name = {}, None
+    for line in text.split("\n"):
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if m:
+            name = m.group(1)
+            out[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name and line.strip():
+            out[name].append(line)
+    return out
+
+
+def _qkv_gathers(text, where):
+    """(computation, result name) of the all-gathers of a whole ``qkv_w``
+    whose ``op_name`` holds ``where``."""
+    return [(comp, re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line).group(1))
+            for comp, lines in _computations(text).items() for line in lines
+            if re.search(re.escape(QKV) + r"\S* all-gather(-start)?\(", line)
+            and where in re.search(r'op_name="([^"]*)"', line).group(1)]
+
+
+def test_first_leaf_leaves_the_forward_body_through_the_carry():
+    """The compiled step gathers ``qkv_w`` in the forward layer loop and no
+    matmul of that body reads the result: it goes out with the carry, for
+    the next layer. The backward loop is the one of a leaf gathered in
+    place: it gathers the layer's own slice, once, as it did."""
+    text, _ = _compiled_step(True)
+    forward = _qkv_gathers(text, "/jvp()/while/body/")
+    assert len(forward) == 1, forward
+    comp, gather = forward[0]
+    lines = _computations(text)[comp]
+    reached, grew = {gather}, True
+    while grew:     # everything in the body computed from the gather
+        grew = False
+        for line in lines:
+            name = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = ", line).group(1)
+            operands = set(re.findall(r"%([\w.\-]+)", line.split(" = ", 1)[1]))
+            if name not in reached and operands & reached:
+                reached.add(name)
+                grew = True
+                assert "dot" not in line.split(" = ", 1)[1].split("(")[0] \
+                    and "dot_general" not in line, line
+                if line.lstrip().startswith("ROOT"):
+                    assert " tuple(" in line, line
+    assert any(line.lstrip().startswith("ROOT") and re.match(
+        r"\s*ROOT %([\w.\-]+) = ", line).group(1) in reached
+        for line in lines), "the gathered leaf does not reach the carry"
+    assert len(_qkv_gathers(text, "transpose(jvp())")) == 1
+    # the control: with every gather in place the forward loop's one gather
+    # feeds that body's first matmul, and the backward loop is the same
+    in_place, _ = _compiled_step(False)
+    assert len(_qkv_gathers(in_place, "transpose(jvp())")) == 1
+    assert len(_qkv_gathers(in_place, "/jvp()/while/body/")) == 1
+
+
+@pytest.mark.parametrize("remat_policy,leaves", [("dots_no_batch", 3),
+                                                 ("nothing", LAYERS + 3)])
+def test_the_carry_saves_no_more_than_one_leaf_a_layer(remat_policy, leaves):
+    """What the step holds in temporaries beyond the walk with every gather
+    in place. Where remat keeps the product that reads the leaf
+    (``dots_no_batch``): the leaf in flight and the one being handed over,
+    and nothing for each layer. Where the product is recomputed: the
+    carried leaf saved for every layer besides, and still nothing like a
+    whole layer, whose four matrices would be four times that."""
+    leaf = HIDDEN * 3 * HIDDEN * 4
+    layer = (4 + 2 * 4) * HIDDEN * HIDDEN * 4
+    grown = _compiled_step(True, remat_policy)[1] \
+        - _compiled_step(False, remat_policy)[1]
+    assert 0 < grown <= leaves * leaf < LAYERS * layer, grown
+
+
+def test_counters_say_what_was_gathered_where():
+    """One trace of the step under the plan: one leaf of a block is stated a
+    layer ahead and the block's three other matrices in place (the scan's
+    body is traced once, not once a layer); with no plan, nothing counts."""
+    groups.reset()
+    conf = {"train_batch_size": 16, "train_micro_batch_size_per_gpu": 2,
+            "gradient_accumulation_steps": 2, "steps_per_print": 0,
+            "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+            # the four matrices of a block and the embedding, no vector
+            "zero_optimization": {"stage": 3,
+                                  "stage3_param_persistence_threshold": 10000}}
+    model = _gpt2()
+    engine, *_ = deepspeed_tpu.initialize(
+        model=model, config=DeepSpeedConfig(conf, world_size=4),
+        topology=build_topology(4, dp=4, tp=1))
+    use, params = engine._param_use, engine._params_shape
+    sharded = {k for k, v in use.compute["blocks"].items()
+               if v != use.gathered["blocks"][k]}
+    assert sharded == {"qkv_w", "attn_out_w", "mlp_fc_w", "mlp_out_w"}
+    top = sum(use.compute[k] != use.gathered[k] for k in use.compute
+              if k != "blocks")
+    batch = {k: jnp.zeros((8, SEQ), jnp.int32)
+             for k in ("input_ids", "labels")}
+
+    def grad():
+        return jax.grad(lambda p: model.apply(p, batch, train=True)[0])
+
+    before = _zero_counters()
+    jax.make_jaxpr(grad())(params)
+    assert _zero_counters() == before
+    with stating_param_use(use):
+        jax.make_jaxpr(grad())(params)
+    ahead, in_place = np.subtract(_zero_counters(), before)
+    # the embedding is gathered where it is read and again for the head
+    assert (ahead, in_place - 2 * top) == (1, 3)
+
+
+@pytest.mark.parametrize("name", ["llama", "transformer", "granite"])
+def test_a_family_that_states_no_first_leaf_is_not_asked(name):
+    """Under a stage-3 plan a family whose ``wrapped_block`` names no first
+    leaf never consults the plan for one and carries nothing."""
+    model = MODELS[name]()
+    engine = _engine(model, 3, 4)
+    batch = {k: jnp.zeros((8, SEQ), jnp.int32)
+             for k in ("input_ids", "labels")}
+    before = _zero_counters()
+    with mock.patch("deepspeed_tpu.models.stack.gathers",
+                    side_effect=AssertionError("asked")), \
+            stating_param_use(engine._param_use):
+        jax.make_jaxpr(jax.grad(
+            lambda p: model.apply(p, batch, train=True)[0]))(
+                engine._params_shape)
+    ahead, in_place = np.subtract(_zero_counters(), before)
+    assert ahead == 0 and in_place > 0
