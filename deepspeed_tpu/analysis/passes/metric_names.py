@@ -24,7 +24,7 @@ from deepspeed_tpu.analysis.core import (Corpus, Finding, LintPass,
                                          register)
 
 PREFIXES = ("train", "serving", "fabric", "resilience", "device", "entry",
-            "checkpoint", "elastic", "slo", "telemetry")
+            "host", "checkpoint", "elastic", "slo", "telemetry")
 _NAME_RE = re.compile(
     r"^(?:%s)/[A-Za-z0-9_][A-Za-z0-9_/<>*-]*$" % "|".join(PREFIXES))
 # methods whose first string argument is a metric/event name

@@ -51,6 +51,7 @@ from deepspeed_tpu.runtime.precision import (
 from deepspeed_tpu.runtime.zero.partition import PartitionPlan, stating_param_use
 from deepspeed_tpu.telemetry.compile_log import (SetupPhase, at_work,
                                                  compile_log)
+from deepspeed_tpu.telemetry.host_watch import TrainWatch
 from deepspeed_tpu.utils import groups as groups_mod
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (
@@ -68,6 +69,10 @@ class TrainState(NamedTuple):
     opt_state: Any
     scaler: LossScalerState
     global_step: jax.Array
+
+
+_STEP_ANNOTATIONS = ("dstpu/train_batch_put", "dstpu/train_step",
+                     "dstpu/train_after_step")
 
 
 class DeepSpeedEngine:
@@ -310,14 +315,17 @@ class DeepSpeedEngine:
         self.monitor = MonitorMaster(config.monitor_config)
 
         # ---- telemetry (ISSUE 3): in-process metrics registry + optional
-        # JSONL sink. Per-step cost is a few dict ops (the budget is 2%);
-        # device-truth metrics (device
+        # JSONL sink. Per-step cost is a few dict ops and the host watch's
+        # three clock reads (gpt2-large.train-1chip: 20,196.7 to 20,198.4
+        # tokens/s with the watch, 20,196.9 to 20,197.2 without; PERF.md
+        # section 6, PR 57); device-truth metrics (device
         # step time, MFU, grad-norm, fp16 skips, memory) are sampled at a
         # periodic block_until_ready fence so async dispatch survives.
         tcfg = config.telemetry_config
         self.telemetry = None
         self._telemetry_flops: Optional[float] = None  # None=unprobed, 0=n/a
         self._compile_sub = None
+        self._watch: Optional[TrainWatch] = None   # the host, from inside
         self._fence_t: Optional[float] = None
         self._fence_step = 0
         self._fence_tokens = 0
@@ -367,6 +375,20 @@ class DeepSpeedEngine:
             weakref.finalize(self, log.unsubscribe, self._compile_sub)
             setup_weights.publish(self.telemetry, self.tracer,
                                   trace_id=self._train_trace)
+
+            def span(name, t0, t1, **attrs):
+                live = engine()
+                if live is not None and live.tracer is not None:
+                    live.tracer.record(name, t0, t1,
+                                       trace_id=live._train_trace, **attrs)
+                return t1
+
+            # stalls of the step loop and the collector's pauses, where the
+            # compile log is followed and for as long (ISSUE 57); the
+            # tracer's clock here is perf_counter, the watch's own
+            self._watch = TrainWatch(self.telemetry, at_work=follows,
+                                     span=span, gc_span=span)
+            weakref.finalize(self, self._watch.close)
         # ---- flight recorder + SLO seam (ISSUE 13): the recorder tees
         # the telemetry/span streams into bounded rings and dumps one
         # postmortem JSON when the sentinel hits an actionable anomaly;
@@ -385,6 +407,7 @@ class DeepSpeedEngine:
 
             self.flight_recorder = _tele.FlightRecorder(
                 dump_dir=tcfg.flight_dir or None, registry=self.telemetry)
+            self._watch.recorder = self.flight_recorder
             self._attached_sink = self.flight_recorder.tee(
                 self.telemetry.sink)
             self.telemetry.attach_sink(self._attached_sink)
@@ -900,7 +923,11 @@ class DeepSpeedEngine:
             h.poll()  # deferred preemption: final save at the step boundary
         if self._host_opt is not None:
             return self._run_host_step(batch)
+        # the step that builds its program is set-up (``entry/*`` holds it):
+        # the host watch joins at the next
+        watch = self._watch
         if self._compiled_train_step is None:
+            watch = None
             if self.telemetry is not None:
                 self._setup_first_step = SetupPhase("first_step")
             self._build_train_step(batch)
@@ -908,8 +935,16 @@ class DeepSpeedEngine:
         self.timers(TRAIN_BATCH_TIMER).start()
         t_start = time.perf_counter()
         # host annotations name the device's idle gaps around the step on
-        # the profiler's clock; no fence, no clock read
-        with jax.profiler.TraceAnnotation("dstpu/train_batch_put"):
+        # the profiler's clock; no fence. With a registry the host watch
+        # holds them (a collection can then take the open one's place) and
+        # stamps the edge behind each: three clock reads a step
+        if watch is None:
+            put, run, after = (jax.profiler.TraceAnnotation(name)
+                               for name in _STEP_ANNOTATIONS)
+        else:
+            put, run, after = watch.phases
+            watch.enter(t_start)
+        with put:
             lr = jnp.asarray(self.get_lr()[0], jnp.float32)
             rng = jax.random.fold_in(self._dropout_rng, self.global_steps)
             batch = self._apply_curriculum(batch)
@@ -921,7 +956,7 @@ class DeepSpeedEngine:
             keep = self.random_ltd_scheduler.update_seq(self.global_steps)
             if seq_len is None or keep < seq_len:
                 ltd_keep = keep
-        with jax.profiler.TraceAnnotation("dstpu/train_step"):
+        with run:
             if self._use_pld:
                 theta = jnp.asarray(self.progressive_layer_drop.get_theta(),
                                     jnp.float32)
@@ -941,7 +976,7 @@ class DeepSpeedEngine:
             else:
                 self.state, metrics = self._compiled_train_step(
                     self.state, batch, lr, rng, None, ltd_keep)
-        with jax.profiler.TraceAnnotation("dstpu/train_after_step"):
+        with after:
             self._global_grad_norm = metrics["grad_norm"]
             self.micro_steps += self.gas
             self.global_steps += 1
@@ -959,6 +994,8 @@ class DeepSpeedEngine:
         if self._sync_each_step:
             # dstpu-lint: fence=opt-in per-step fence (config sync_each_step)
             jax.block_until_ready(self.state.params)
+        if watch is not None:
+            watch.leave()
         return metrics["loss"]
 
     def _run_host_step(self, batch):
@@ -1064,6 +1101,7 @@ class DeepSpeedEngine:
         # config key; 0 = legacy coupling to steps_per_print)
         mon_interval = cfg.monitor_interval or max(cfg.steps_per_print or 0, 1)
         if self.monitor.enabled and self.global_steps % mon_interval == 0:
+            self._step_fenced()
             # dstpu-lint: fence=monitor cadence read (mon_interval-gated)
             loss = float(jax.device_get(metrics["loss"]))
             events = [("Train/Samples/train_loss", loss, self.global_steps),
@@ -1073,6 +1111,7 @@ class DeepSpeedEngine:
                                float(jax.device_get(metrics["loss_scale"])), self.global_steps))  # dstpu-lint: fence=monitor cadence read
             self.monitor.write_events(events)
         if cfg.steps_per_print and self.global_steps % cfg.steps_per_print == 0:
+            self._step_fenced()
             # dstpu-lint: fence=steps_per_print cadence read
             loss = float(jax.device_get(metrics["loss"]))
             log_dist(f"step={self.global_steps} loss={loss:.4f} lr={self.get_lr()[0]:.3e}",
@@ -1126,11 +1165,22 @@ class DeepSpeedEngine:
         phase.close().publish(self.telemetry, self.tracer,
                               trace_id=self._train_trace, fenced=fenced)
 
+    def _step_fenced(self) -> None:
+        """This step ends at a wait for the device that the engine itself
+        makes at a cadence (the telemetry fence, a print or monitor read,
+        the sentinel's drain): its length is the device's, and the host
+        watch judges neither it nor the gap it belongs to."""
+        if self._watch is not None:
+            self._watch.fenced()
+
     def _reset_telemetry_window(self):
         """Invalidate the fence-to-fence device-rate baseline. Called
         around work that is NOT training steps (checkpoint save/load) so
         a multi-second blocking save between fences is never charged to
-        train/device_step_time_ms or train/mfu."""
+        train/device_step_time_ms or train/mfu, nor read as a stall of
+        the step loop."""
+        if self._watch is not None:
+            self._watch.forget()
         self._fence_t = None
         self._fence_step = self.global_steps
         self._fence_tokens = 0
@@ -1141,9 +1191,11 @@ class DeepSpeedEngine:
         read would otherwise break async dispatch. Assumes fence-to-fence
         wall time is training; engine-visible non-training work
         (checkpoint save/load) resets the window via
-        _reset_telemetry_window — caller-side stalls between steps are
-        still charged (they are invisible from here)."""
+        _reset_telemetry_window. A caller-side stall between steps is
+        still charged to the device rate here; the host watch names it
+        (``host_stall``, phase ``caller``: telemetry/host_watch.py)."""
         reg = self.telemetry
+        self._step_fenced()
         # dstpu-lint: fence=THE periodic telemetry fence (sync_interval): device-truth metrics
         jax.block_until_ready(self.state.params)
         if self._setup_first_step is not None:
@@ -1318,6 +1370,7 @@ class DeepSpeedEngine:
         t0 = time.perf_counter() if self.tracer is not None else 0.0
         pending, self._pending_anomaly_reads = \
             self._pending_anomaly_reads, []
+        self._step_fenced()
         # dstpu-lint: fence=sentinel drain: ONE batched fetch at the declared cadence
         vals = jax.device_get([(l, n, o) for _, l, n, o in pending])
         reg = self.telemetry
@@ -1569,6 +1622,8 @@ class DeepSpeedEngine:
         if self._compile_sub is not None:
             compile_log().unsubscribe(self._compile_sub)
             self._compile_sub = None
+        if self._watch is not None:
+            self._watch.close()
         if self._spans_sink is not None:
             self._spans_sink.close()
             self._spans_sink = None

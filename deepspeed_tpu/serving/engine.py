@@ -35,6 +35,7 @@ token rows — ``prefix_cache``, ``kv_dtype``, ``speculative``,
 
 from __future__ import annotations
 
+import functools
 import time
 import weakref
 from typing import Callable, Dict, List, Optional, Sequence
@@ -66,6 +67,7 @@ from deepspeed_tpu.serving.speculative import (AdaptiveK, DraftModelDrafter,
 from deepspeed_tpu.serving.swap import HostSwapBuffer
 from deepspeed_tpu.telemetry.compile_log import (SetupPhase, at_work,
                                                  compile_log)
+from deepspeed_tpu.telemetry.host_watch import ServingWatch
 from deepspeed_tpu.telemetry.registry import metric_label
 from deepspeed_tpu.utils.logging import log_dist
 
@@ -145,14 +147,21 @@ class _Flight:
     ``states`` pairs every slot active in the
     step with the ``_SlotState`` it held at launch, so that commit gives a
     token only to a slot that still holds the same object; ``overlapped``
-    says the step before it was still unfetched when this one launched."""
+    says the step before it was still unfetched when this one launched.
+    For the host watch: ``t_launch``, its clock as the launch began, and
+    ``behind_chunk``, whether a prefill chunk that nobody fetched was
+    queued ahead (the fetch then waits for the chunk too)."""
 
-    __slots__ = ("answer", "states", "overlapped")
+    __slots__ = ("answer", "states", "overlapped", "t_launch",
+                 "behind_chunk")
 
-    def __init__(self, answer, states, overlapped: bool):
+    def __init__(self, answer, states, overlapped: bool,
+                 t_launch: float = 0.0, behind_chunk: bool = False):
         self.answer = answer
         self.states = states
         self.overlapped = overlapped
+        self.t_launch = t_launch
+        self.behind_chunk = behind_chunk
 
 
 class _FirstToken:
@@ -161,10 +170,10 @@ class _FirstToken:
     span from its program call."""
 
     __slots__ = ("slot", "state", "token", "counted", "t_span0", "program",
-                 "bucket", "chunk")
+                 "bucket", "chunk", "t_call")
 
     def __init__(self, slot, state, token, counted, t_span0, program,
-                 bucket, chunk):
+                 bucket, chunk, t_call=0.0):
         self.slot = slot
         self.state = state
         self.token = token
@@ -175,6 +184,7 @@ class _FirstToken:
         self.program = program
         self.bucket = bucket
         self.chunk = chunk
+        self.t_call = t_call      # the host watch's clock at the program call
 
 
 class _Preempted:
@@ -229,9 +239,14 @@ class _Phase:
     idle gap by the annotation that covers most of it, and a nested phase
     would never win. ``span`` is recorded on exit only while an
     ``iteration`` span is open, which takes a tracer: the bare engine
-    pays the annotation and this one ``is None`` test."""
+    pays the annotation and two ``is None`` tests. With a registry the
+    engine has a host watch, and both edges are stamped on its clock:
+    the phase's length, less the phases nested in it, is the watch's to
+    judge (``telemetry/host_watch.py``), and the stamp of the exit is the
+    one the armed path closes the span at."""
 
-    __slots__ = ("engine", "annotation", "span", "now", "outer", "live")
+    __slots__ = ("engine", "annotation", "span", "now", "outer", "live",
+                 "t0", "nested", "flight")
 
     def __init__(self, engine: "ServingEngine", annotation: str,
                  span: Optional[str], now: float):
@@ -250,16 +265,32 @@ class _Phase:
             self.outer.live.__exit__(None, None, None)
         self._open()
         self.engine._open_phase = self
+        watch = self.engine._watch
+        if watch is not None:
+            self.nested = 0.0
+            self.flight = None
+            self.t0 = watch.clock()
         return self
 
     def __exit__(self, *exc) -> bool:
         self.live.__exit__(*exc)
-        self.engine._open_phase = self.outer
+        engine = self.engine
+        engine._open_phase = self.outer
         if self.outer is not None:
             self.outer._open()
-        if (self.engine._iter_span is not None and self.span is not None
+        t = None
+        watch = engine._watch
+        if watch is not None:
+            t = watch.clock()
+            wall = t - self.t0
+            if self.outer is not None:
+                self.outer.nested += wall
+            if exc[0] is None:
+                watch.phase(self.annotation, wall - self.nested, t,
+                            self.flight)
+        if (engine._iter_span is not None and self.span is not None
                 and exc[0] is None):
-            self.engine._phase_end(self.span, self.now)
+            engine._phase_end(self.span, self.now, t)
         return False
 
 
@@ -378,7 +409,9 @@ class ServingEngine:
         ``compile`` span for every stage of a program compiled inside
         ``step()`` lie on the same trace. Arming
         adds no device work: greedy output stays bit-identical (pinned by
-        tests/unit/serving/test_tracing.py); the armed-vs-bare budget is 2%.
+        tests/unit/serving/test_tracing.py). On the chip, armed against
+        the registry alone: ``itl_p95_ms`` 0.4 to 2.0% up, nothing else
+        outside its spread (PERF.md section 6, PR 57).
     slo: an :class:`~deepspeed_tpu.telemetry.slo.SLOEngine` (ISSUE 13),
         or None (default). When armed, the engine calls
         ``slo.maybe_evaluate(now)`` once per serving iteration ON THE
@@ -508,6 +541,8 @@ class ServingEngine:
         self._temp = jnp.asarray(max(temperature, 1e-6), jnp.float32)
         self._sample_kw = dict(do_sample=do_sample, top_k=top_k,
                                top_p=float(top_p))
+        # tokens of a prompt that pass the model's stack at once, if it says
+        self._prompt_block = getattr(mcfg, "prompt_block", None)
         self._time = time_fn or time.monotonic
         # a wall clock only ADVANCES WITH real time, so idle gaps must
         # time.sleep (a tight poll would spin one core for the whole
@@ -703,10 +738,16 @@ class ServingEngine:
         # context-carrying records awaiting their submit-time stamp
         # (resolved by the next step(); see _ReqTrace.submitted_t)
         self._pending_submit_stamps: List[_ReqTrace] = []
-        # ---- set-up and compiles, measured from inside (ISSUE 42)
+        # ---- set-up and compiles, measured from inside (ISSUE 42); the
+        # host process from inside (ISSUE 57): None without a registry
         self._compile_sub = None
-        # stages told while no iteration span was open (armed only)
+        self._watch: Optional[ServingWatch] = None
+        # a prefill chunk was launched that no fetch has waited for yet
+        self._chunk_unfetched = False
+        # stages told, and the host watch's spans, while no iteration span
+        # was open (armed only)
         self._loose_compiles: List[tuple] = []
+        self._loose_spans: List[tuple] = []
         self._subscribe_compile_log(cache_phase)
         # radix prefix index over the block pool (ISSUE 6) — created
         # after telemetry so its hit/miss/COW/eviction counters land in
@@ -878,6 +919,34 @@ class ServingEngine:
         self._compile_sub = log.subscribe(self.telemetry, on_stage, follows)
         # an engine dropped without close() must not be told for ever
         weakref.finalize(self, log.unsubscribe, self._compile_sub)
+        if self.telemetry is None:
+            return
+        # the host watch stamps on the engine's clock where that is a
+        # monotonic one (a stamp then serves the armed path too), else on
+        # perf_counter: a virtual clock is never read on its behalf
+
+        def span(name, t0, t1, offset, **attrs):
+            live = engine()
+            return t1 if live is None else live._watch_span(
+                name, t0, t1, offset, **attrs)
+
+        def holds_work(gap, now):
+            live = engine()
+            return 0.0 if live is None else live._held_for(gap, now)
+
+        def open_phase():
+            live = engine()
+            return None if live is None else live._open_phase
+
+        own = self._time in (time.monotonic, time.perf_counter)
+        self._watch = ServingWatch(
+            self.telemetry, at_work=follows, holds_work=holds_work,
+            span=functools.partial(
+                span, offset=0.0 if own else self._from_perf),
+            gc_span=functools.partial(span, offset=self._from_perf),
+            open_phase=open_phase,
+            clock=self._time if own else time.perf_counter)
+        weakref.finalize(self, self._watch.close)
 
     def _engine_clock(self, t0: float, t1: float, offset) -> tuple:
         """An interval of one of the host's clocks on the engine's."""
@@ -923,19 +992,59 @@ class ServingEngine:
 
     def _record_compile(self, stage: str, program: str, start: float,
                         end: float, parent) -> None:
-        t0, t1 = self._engine_clock(start, end, self._from_wall)
+        self._record_under(
+            parent, "compile",
+            *self._engine_clock(start, end, self._from_wall),
+            {"program": program, "stage": stage})
+
+    def _watch_span(self, name: str, t0: float, t1: float, offset,
+                    **attrs) -> float:
+        """An interval the host watch stamped (a stall, a collection), on
+        the engine's clock: where it ends there and, armed, a span under
+        the open ``iteration``."""
+        t0, t1 = self._engine_clock(t0, t1, offset)
+        if self.tracer is None:
+            pass
+        elif self._iter_span is None and self._at_work:
+            # the schedule phase closes before step() has opened the
+            # iteration it belongs to: step() records it then
+            self._loose_spans.append((name, t0, t1, attrs))
+        else:
+            self._record_under(self._iter_span, name, t0, t1, attrs)
+        return t1
+
+    def _record_under(self, parent, name: str, t0: float, t1: float,
+                      attrs: dict) -> None:
+        """A span of the engine's own trace under the ``iteration`` it
+        ended in, if it ended in one."""
         if parent is not None and t1 < parent.start:
             parent = None    # between two steps: no iteration's
         self.tracer.record(
-            "compile", t0, t1, trace_id=self._iter_trace(),
-            parent_id=None if parent is None else parent.span_id,
-            program=program, stage=stage)
+            name, t0, t1, trace_id=self._iter_trace(),
+            parent_id=None if parent is None else parent.span_id, **attrs)
+
+    def _held_for(self, gap: float, now: float) -> float:
+        """Of the ``gap`` seconds since ``step()`` last returned, those in
+        which the engine held work: all of them with a slot occupied or
+        anything launched and unfetched; with requests queued alone, since
+        the first of them was due (an empty system waiting for its next
+        arrival is not stalled)."""
+        if (self._flight is not None or self._firsts
+                or any(s is not None for s in self._slots)):
+            return gap
+        due = self.scheduler.next_arrival()
+        if due is None or not self._real_clock:
+            return 0.0
+        return min(gap, max(now - due, 0.0))
 
     def close(self) -> None:
-        """Stop following the compile log. The engine holds no other
-        process-wide registration; dropping it unsubscribes too."""
+        """Stop following the compile log and the collector. The engine
+        holds no other process-wide registration; dropping it unsubscribes
+        too."""
         compile_log().unsubscribe(self._compile_sub)
         self._compile_sub = None
+        if self._watch is not None:
+            self._watch.close()
 
     @at_work
     def warmup(self) -> None:
@@ -1186,14 +1295,17 @@ class ServingEngine:
         self._rng, sub = jax.random.split(self._rng)
         return sub
 
-    def _now(self, fallback: float) -> float:
+    def _now(self, fallback: float, stamp: Optional[float] = None) -> float:
         """Fresh clock read in run()'s offset base — result timestamps
         include the device work that happened since step() entry (the
         admission-gating ``now`` would understate latency by one
-        prefill/decode's compute)."""
+        prefill/decode's compute). ``stamp`` is a read the host watch has
+        just made: of this clock where the watch stamps on it."""
         if self._run_t0 is None:
             return fallback
-        return self._time() - self._run_t0
+        if stamp is None or self._watch.clock is not self._time:
+            stamp = self._time()
+        return stamp - self._run_t0
 
     def _finish(self, slot: int, now: float, reason: str) -> RequestResult:
         st = self._slots[slot]
@@ -1532,7 +1644,7 @@ class ServingEngine:
             # an idle device from the program call on is the prefill's, not
             # the admission's; the last chunk's fetch opens the same
             # annotation again
-            with _Phase(self, "dstpu/serving_prefill", None, now):
+            with _Phase(self, "dstpu/serving_prefill", None, now) as phase:
                 if self.prefix is not None:
                     pname = f"prefill_{bucket}"
                     out = self._prefill_fn(bucket)(
@@ -1599,7 +1711,10 @@ class ServingEngine:
                     counted, self._counted = self._counted, []
                     self._firsts.append(_FirstToken(
                         slot, st, token, counted, t_span0 if armed else 0.0,
-                        pname, bucket, chunk))
+                        pname, bucket, chunk,
+                        0.0 if self._watch is None else phase.t0))
+                else:
+                    self._chunk_unfetched = True
             if last and not self._ahead:
                 self._land_firsts(now, finished)
         return spent
@@ -1617,6 +1732,13 @@ class ServingEngine:
             with _Phase(self, "dstpu/serving_prefill", None, now):
                 tok, counted = jax.device_get((first.token, first.counted))  # dstpu-lint: fence=token emission: the chunk's final pick must reach the host stream
                 tok = int(tok)
+            self._chunk_unfetched = False     # this fetch waited for it
+            if self._watch is not None:
+                pb = self._prompt_block
+                self._watch.prefill_done(
+                    first.bucket,
+                    -(-first.chunk // pb) if pb and first.bucket > pb else 1,
+                    first.t_call)
             if counted and self.telemetry is not None:
                 self.engine.module.record_prompt_counters(self.telemetry,
                                                           counted)
@@ -1892,6 +2014,9 @@ class ServingEngine:
             self.warmup()
         if now is None:
             now = self._time()
+        watch = self._watch
+        if watch is not None:
+            watch.enter(now)
         self._last_step_now = now
         self._account_kv_occupancy(now)
         if self.slo is not None:
@@ -1927,6 +2052,10 @@ class ServingEngine:
                     self._record_compile(*told, self._iter_span)
                 self._loose_compiles.clear()
             self._phase_end("iter_schedule", now)
+        if self._loose_spans:
+            for told in self._loose_spans:
+                self._record_under(self._iter_span, *told)
+            self._loose_spans.clear()
         # a slot whose last token the step in flight is picking sits out:
         # an end by length is known at launch, one by EOS only at commit
         active_slots = [i for i, s in enumerate(self._slots)
@@ -1961,6 +2090,8 @@ class ServingEngine:
             # ends where its last phase ended
             self.tracer.end(self._iter_span, t=self._phase_t)
             self._iter_span = None
+        if watch is not None:
+            watch.leave()
         return finished
 
     def _account_kv_occupancy(self, now: float) -> None:
@@ -2001,11 +2132,13 @@ class ServingEngine:
             self._engine_trace = self.tracer.new_trace()
         return self._engine_trace
 
-    def _phase_end(self, name: str, now: float) -> None:
+    def _phase_end(self, name: str, now: float,
+                   stamp: Optional[float] = None) -> None:
         """Armed, inside an open iteration: record phase ``name`` from
         where the previous phase ended to a fresh read of the engine
-        clock, and start the next phase there."""
-        t = self._now(now)
+        clock (``stamp``, where the host watch has just made one), and
+        start the next phase there."""
+        t = self._now(now, stamp)
         self.tracer.record(name, self._phase_t, t,
                            trace_id=self._iter_span.trace_id,
                            parent_id=self._iter_span.span_id)
@@ -2069,7 +2202,10 @@ class ServingEngine:
                 self._previous = answer[0]
                 for _, st in states:
                     st.in_flight += 1
-                self._flight = _Flight(answer, states, landing is not None)
+                self._flight = _Flight(
+                    answer, states, landing is not None,
+                    0.0 if self._watch is None else self._watch.last_t,
+                    self._chunk_unfetched)
         # the prompts prefilled in this iteration's schedule phase: their
         # first tokens come back behind the last launch, in launch order
         self._land_firsts(now, finished)
@@ -2095,7 +2231,10 @@ class ServingEngine:
         """Wait for a launched decode step's answer on the host."""
         if flight is self._flight:
             self._flight = None
-        with _Phase(self, "dstpu/serving_fetch", "iter_fetch", now):
+        if flight.behind_chunk:
+            self._chunk_unfetched = False     # this fetch waits for it
+        with _Phase(self, "dstpu/serving_fetch", "iter_fetch", now) as phase:
+            phase.flight = flight
             return jax.device_get(flight.answer)  # dstpu-lint: fence=token emission: decode's picks feed host continuations + streams
 
     def _commit(self, flight: _Flight, fetched: list, now: float,

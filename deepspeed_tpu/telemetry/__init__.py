@@ -18,12 +18,18 @@ comms logger, redesigned for JAX's async-dispatch execution model:
   * :mod:`compile_log` — what start-up cost, from inside: JAX's trace,
     lowering, compile and cache events by program name as ``entry/*``
     counters, and the phases of set-up (ISSUE 42).
+  * :mod:`host_watch` — what the host process was doing when a step took
+    too long: a stall rule at the phase edges of both step loops and one
+    ``gc.callbacks`` entry, as ``host/*`` counters, ``host_stall`` events,
+    spans and one warning line a stall (ISSUE 57).
 
 Instrumentation points: ``runtime/engine.py`` (per-step wall/device time,
 tokens/sec, MFU, grad-norm, fp16 skip counters, device memory) and
 ``serving/engine.py`` (queue-wait/TTFT/TPOT histograms, slot occupancy,
-recompile counter, finished-requests/sec). Overhead is budgeted at 2%; no
-benchmark cell measures it yet.
+recompile counter, finished-requests/sec). What it costs on the chip:
+PERF.md section 6, PR 57 (the host watch over the registry moved no
+end-to-end metric of gpt2-large's two cells; an armed tracer 0.4 to 2.0% of
+``itl_p95_ms``; a run with no registry at all has not been measured).
 """
 
 from deepspeed_tpu.telemetry.compile_log import (CompileLog, SetupPhase,

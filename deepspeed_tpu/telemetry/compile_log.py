@@ -128,6 +128,9 @@ class CompileLog:
         # program is traced, or by a lowering rule while a Pallas kernel is
         # lowered, is traced inside that stage, and is the program's time
         self._open = threading.local()
+        # the newest stage, any thread's: (stage, program, start, end), the
+        # end None while it is open (``stage_since``)
+        self.last_stage: Optional[tuple] = None
         self._registered = False
 
     # ------------------------------------------------------- jax.monitoring
@@ -148,10 +151,12 @@ class CompileLog:
             monitoring.unregister_event_duration_listener(self.on_duration)
             self._registered = False
 
-    def on_scalar(self, event: str, value=None, **_) -> None:
+    def on_scalar(self, event: str, value=None, fun_name: str = "",
+                  **_) -> None:
         """JAX sends a timed event's start as a scalar when it opens."""
         if event in _STAGES:
             self._open.depth = getattr(self._open, "depth", 0) + 1
+            self.last_stage = (_STAGES[event], str(fun_name), value, None)
 
     def on_time_span(self, event: str, start: float, end: float,
                      fun_name: str = "", **_) -> None:
@@ -161,12 +166,12 @@ class CompileLog:
         program = str(fun_name)
         outer = max(getattr(self._open, "depth", 1) - 1, 0)
         self._open.depth = outer
-        if stage == "trace":
-            if outer:            # inside another stage, which holds its time
-                return
-        elif program.endswith(")") and "(" in program:
+        if stage != "trace" and program.endswith(")") and "(" in program:
             # lowering and compiling name the module, ``jit(<function>)``
             program = program[program.index("(") + 1:-1]
+        self.last_stage = (stage, program, start, end)
+        if stage == "trace" and outer:
+            return               # inside another stage, which holds its time
         with self._lock:
             subs = self._following()
             ms = (end - start) * 1e3
@@ -220,6 +225,16 @@ class CompileLog:
         rec[0] += 1
         rec[1] += ms
         return seen
+
+    def stage_since(self, t: float) -> Optional[str]:
+        """``<program>:<stage>`` of the stage that is open now, on whatever
+        thread, or that closed last and after ``t`` (``time.time()``'s
+        clock); None where neither: what a stalled phase may have stood
+        behind (``telemetry/host_watch.py``)."""
+        last = self.last_stage
+        if last is None or (last[3] is not None and last[3] < t):
+            return None
+        return f"{last[1]}:{last[0]}"
 
     def _following(self) -> List[_Subscription]:
         """The subscribers whose event this one is."""

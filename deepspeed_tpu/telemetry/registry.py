@@ -9,8 +9,9 @@ Design constraints, in order:
 
 1. **Overhead.** A hot-loop update is a dict lookup + an int add (counters),
    a float store (gauges) or a ``bisect`` + int add (histograms) — no
-   locks on the update path, no allocation, no syscalls. The budget for
-   instrumented train and decode steps is 2% against bare runs.
+   locks on the update path, no allocation, no syscalls. Against a run
+   with no registry at all this has never been measured on the chip;
+   what rides on top of it has (PERF.md section 6, PR 57).
 2. **Fixed memory.** Histograms are fixed-bucket (default: log-spaced
    latency buckets, ~1.25x ratio) so a week-long serving run costs the
    same bytes as a unit test. Percentiles (p50/p95/p99) are estimated by

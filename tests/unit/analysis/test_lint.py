@@ -223,7 +223,7 @@ def test_baseline_default_budget_is_count_weighted(tmp_path):
 def test_committed_baseline_is_burned_down():
     """The repo ships ZERO grandfathered findings; this number may only
     move toward (or stay at) zero — raising it needs a justification
-    visible in this diff (same spirit as the bench_trajectory gates)."""
+    visible in this diff."""
     bl = Baseline.load(os.path.join(REPO, "LINT_BASELINE.json"))
     assert bl.total == 0
     assert bl.budget == 0

@@ -23,8 +23,6 @@ tests/unit/serving/test_slo_plane.py). Pinned here:
   * telemetry_report: slo/tenants/postmortem sections, incl. degrade
     paths — empty JSONL, torn mid-record stream, streams missing each
     section's records entirely (satellite);
-  * bench_trajectory --markdown rendering over the checked-in rounds
-    (satellite);
   * the training engine's flight-recorder trigger on a sentinel
     anomaly.
 """
@@ -590,33 +588,6 @@ def test_report_degrade_paths(tmp_path):
     bad_dump = tmp_path / "bad_dump.json"
     bad_dump.write_text("{not json")
     assert mod.load_flight_dump(str(bad_dump)) is None
-
-
-# --------------------------------------------- bench trajectory satellite
-def test_bench_trajectory_markdown(capsys, tmp_path):
-    from tests.unit.telemetry.round_files import write_round_files
-
-    mod = _load_script("bench_trajectory")
-    paths = write_round_files(tmp_path)
-    rounds = mod.load_rounds(paths)
-    t = mod.trend(rounds)
-    md = mod.render_markdown(t, rounds)
-    assert "## Bench trajectory" in md
-    assert "| metric | flag | delta | series |" in md
-    assert "regression(s)" in md
-    # every metric row is a well-formed table line
-    body = [ln for ln in md.splitlines() if ln.startswith("| `")]
-    assert len(body) == len(t)
-    for ln in body:
-        assert ln.count(" | ") == 3, ln
-    # CLI: --markdown exits 0 and prints the table
-    assert mod.main(paths + ["--markdown"]) == 0
-    out = capsys.readouterr().out
-    assert "| metric | flag | delta | series |" in out
-    # flagged-only filtering drops stable rows
-    md_flagged = mod.render_markdown(t, rounds, only_flagged=True)
-    assert len([ln for ln in md_flagged.splitlines()
-                if ln.startswith("| `")]) <= len(body)
 
 
 # ------------------------------------------- training-engine integration
