@@ -33,7 +33,12 @@ VMEM scratch. Two walks, chosen by the shape of ``idx``:
   neither read nor written, whatever length it still carries. Until PR 33
   a group of eight NEIGHBOURING slots walked to its longest length, stale
   lengths of freed slots included, and three quarters of what the kernel
-  fetched at the chip's peak was dead (PERF.md, PR 33).
+  fetched at the chip's peak was dead (PERF.md, PR 33). How many rows a
+  loop step covers and how many slots share it comes from the cache's
+  geometry (``_slot_plan``): a chunk of 128 rows at 1,024 or 2,048 rows a
+  slot; where a slot holds thousands, a chunk of 512 fetched in four DMAs
+  of 128 (a row's tail still rounds up to 128) and a slot a group, because
+  a loop step costs what its bytes do not explain (PERF.md, PR 58).
 
 Head-dim handling: Mosaic requires DMA slices of the minor dim to be
 128-aligned, so for Dh < 128 the cache is VIEWED as token-pairs
@@ -92,6 +97,9 @@ _VMEM_LIMIT = 40 * 1024 * 1024
 # the tokens a row's last fetch rounds up to
 _SLOT_BUFFERS = 12 * 1024 * 1024
 _SLOT_CHUNK = 128
+# the float32 scores of one loop step of the per-slot walk: half of the
+# 64 vector registers of 4 KB
+_SCORE_TILE = 128 * 1024
 
 
 def _compiler_params(vmem_bytes: int = _VMEM_LIMIT):
@@ -433,10 +441,16 @@ def _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, q_ref, kn_ref, vn_ref,
       beyond ``ceil(n_active / bg)`` do not run. The chunk of group ``g+1``
       is prefetched under the last chunk of group ``g``: one DMA warm-up
       stall a layer, not one a group.
-    * A buffer row whose DMA was skipped holds whatever an earlier chunk
-      left there, and ``0 * NaN`` in the PV product is NaN, so ``vbuf`` is
-      zeroed on entry: from then on it only ever holds cache rows of
-      active slots (the K side is masked by select, which drops a NaN).
+    * A chunk of more than ``_SLOT_CHUNK`` rows is fetched in parts of that
+      many, each a DMA with a semaphore of its own into its rows of the
+      buffer, started and waited on while the row has rows left for THAT
+      part (``c * parts + u < ceil(len / 128)``): what a row fetches does
+      not depend on the chunk.
+    * A buffer row, or a part of one, whose DMA was skipped holds whatever
+      an earlier chunk left there, and ``0 * NaN`` in the PV product is
+      NaN, so ``vbuf`` is zeroed on entry: from then on it only ever holds
+      cache rows of active slots (the K side is masked by select, which
+      drops a NaN).
     * The new token is not spliced into a chunk: the online softmax starts
       from it (a one-position chunk made of ``k_new`` / ``v_new``), and the
       cache walk covers the positions strictly before it. So no chunk pays
@@ -465,14 +479,19 @@ def _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, q_ref, kn_ref, vn_ref,
     csp = cs // pair          # pair-rows per chunk
     dhp = dh * pair           # packed minor dim (>= 128)
     dvp = dhp if dv is None else dv
+    dma = _SLOT_CHUNK         # rows a DMA: what a row's tail rounds up to
+    parts = cs // dma         # DMAs a row a chunk
+    dmp = dma // pair         # pair-rows a DMA
 
     def slot_at(p):
         # (clamped: a position past the last slot is never active)
         return order_ref[jnp.minimum(p, b - 1)]
 
-    def nch_at(p):
-        """Chunks of cache rows sorted position ``p`` fetches."""
-        return jnp.where(p < n_act, (idx_ref[slot_at(p)] + cs - 1) // cs, 0)
+    def nch_at(p, rows=cs):
+        """Chunks of cache rows sorted position ``p`` walks (``rows=dma``:
+        the DMAs it starts)."""
+        return jnp.where(p < n_act,
+                         (idx_ref[slot_at(p)] + rows - 1) // rows, 0)
 
     # ---- the active slots' new K/V into the cache: the same 8-row window
     # read-modify-write as the uniform kernel's, one window a slot (write
@@ -521,25 +540,34 @@ def _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, q_ref, kn_ref, vn_ref,
         win_copy(p, 1, True).wait()
 
     # ---- the walk
-    def chunk_copy(p, j, c, slot, t):
+    def dma_at(c, u):
+        """Part ``u`` of chunk ``c`` among a row's DMAs."""
+        return c if parts == 1 else c * parts + u
+
+    def chunk_copy(p, j, u, c, slot, t):
+        s = slot_at(p)
+        at, buf = dma_at(c, u) * dmp, (kbuf, vbuf)[t].at[slot, pl.ds(j, 1)]
+        if parts > 1:         # into the part's own rows of the buffer
+            buf = buf.at[:, :, pl.ds(u * dmp, dmp)]
         return pltpu.make_async_copy(
-            (k_ref, v_ref)[t].at[layer, pl.ds(slot_at(p), 1), :,
-                                 pl.ds(c * csp, csp), :],
-            (kbuf, vbuf)[t].at[slot, pl.ds(j, 1)], rsem.at[slot, t, j])
+            (k_ref, v_ref)[t].at[layer, pl.ds(s, 1), :, pl.ds(at, dmp), :],
+            buf, rsem.at[slot, t, j * parts + u])
 
     def each_row(g, c, fn):
-        """``fn(p, j)`` for the rows of group ``g`` that hold chunk ``c``."""
+        """``fn(p, j, u)`` for the rows of group ``g`` that hold chunk ``c``
+        and the parts of it that they hold."""
         for j in range(bg):
             p = g * bg + j
-
-            @pl.when(c < nch_at(p))
-            def _():
-                fn(p, j)
+            held = nch_at(p, dma)
+            for u in range(parts):
+                @pl.when(dma_at(c, u) < held)
+                def _():
+                    fn(p, j, u)
 
     def start_chunk(g, c, slot):
-        def go(p, j):
-            chunk_copy(p, j, c, slot, 0).start()
-            chunk_copy(p, j, c, slot, 1).start()
+        def go(p, j, u):
+            chunk_copy(p, j, u, c, slot, 0).start()
+            chunk_copy(p, j, u, c, slot, 1).start()
         each_row(g, c, go)
 
     attn_ref[...] = jnp.zeros_like(attn_ref)
@@ -589,11 +617,10 @@ def _slot_kernel(layer_ref, idx_ref, order_ref, n_ref, q_ref, kn_ref, vn_ref,
             start_chunk(jnp.where(more, g, g + 1),
                         jnp.where(more, c + 1, 0), 1 - slot)
             # K first: the scores run while V is still in flight
-            each_row(g, c, lambda p, j: chunk_copy(p, j, c, slot, 0).wait())
+            each_row(g, c, lambda *r: chunk_copy(*r, c, slot, 0).wait())
 
             def load_v():
-                each_row(g, c,
-                         lambda p, j: chunk_copy(p, j, c, slot, 1).wait())
+                each_row(g, c, lambda *r: chunk_copy(*r, c, slot, 1).wait())
                 return vbuf[slot]
 
             def valid(h, shape):
@@ -1107,23 +1134,76 @@ def slot_walk(lengths, active=None) -> SlotWalk:
                     jnp.sum(act).astype(jnp.int32).reshape(1))
 
 
-def _slot_plan(b: int, hkv: int, s_max: int, dh: int, itemsize: int):
-    """(bg, cs) of the per-slot walk, by measurement on the v5e (PERF.md,
-    PR 33). A row has its own DMA a chunk, so ``bg`` is free of the uniform
-    plan's one-DMA-covers-the-group sizing: it is how many rows of like
-    length share one loop step and one masked compute pass. At gpt2-large's
-    geometry 36 layers took 2.38 / 2.48 / 2.89 / 3.87 ms at ``bg`` 2 / 4 /
-    8 / 16 with 18 of 32 slots active and 4.15 / 4.01 / 4.28 ms at 2 / 4 /
-    8 with all 32: wider groups compute more masked rows, narrower ones
-    pay more loop steps. ``cs`` is what a row's tail rounds up to
-    (:func:`decode_rows_fetched`): 256 fetched 9% more rows for no gain.
+def _slot_plan(b: int, hkv: int, s_max: int, dh: int, itemsize: int,
+               dv: Optional[int] = None, hq: Optional[int] = None):
+    """(bg, cs) of the per-slot walk from the geometry it is handed, by
+    measurement on the v5e. ``cs`` is the cache rows a loop step covers; the
+    DMAs stay ``_SLOT_CHUNK`` rows, ``cs // _SLOT_CHUNK`` a row a step, so a
+    row's tail rounds up to 128 under every plan
+    (:func:`decode_rows_fetched`). A row has its own DMAs, so ``bg`` is free
+    of the uniform plan's one-DMA-covers-the-group sizing: it is how many
+    rows of like length share one loop step and one masked compute pass.
+
+    A loop step has a cost that its bytes do not explain (a dependent chain
+    DMA wait -> scores -> maximum -> exp -> weighted sum, not overlapped
+    from one step to the next), and a masked row is computed whole. One
+    layer's call alone, us, wrapped anew for each plan (PERF.md, PR 58;
+    ``bg x cs``, all with DMAs of 128; in brackets one DMA a step):
+
+    ==========================  =====  =====  =====  =====  =====  =====
+    geometry, slots live        4x128  2x256  4x512  2x512  1x512  1x1024
+    ==========================  =====  =====  =====  =====  =====  =====
+    MiMo global, 3 at 4k-14k      359    161    159    136    127    126
+      (16, 4, 16384, 256/128)           (154)  (143)  (128)  (127)
+    the same, 6 at 4k-15k         534    270    279    248    251    247
+    the same, 16 at 2k-15k        972                  555    566    552
+    Solar, 2 at 7k and 15k        378    150    171    135    133    133
+      (16, 8, 16384, 128)
+    the same, 8 at 2k-15k         524                  358    353    354
+    K-EXAONE, 10 at 0.1k-3.9k     177    124    128    118    118
+      (32, 8, 4096, 128)
+    the same, 32                  475    399    384    387    394
+    hybrid, 24 at 0.1k-1.9k       214    149    119    120    164
+      (64, 8, 2048, 64 packed)
+    serve-chat, 6 at 0.1k-0.9k     45     41     52     43     42
+      (32, 20, 1024, 64 packed)
+    the same, 32 at 0.1k-1k       155    156    171    166    176
+    ==========================  =====  =====  =====  =====  =====  =====
+
+    So where a slot can hold thousands of rows (32 DMAs and more) a step
+    covers 512, and a group is as many rows as keep that step's float32
+    score tile ``[bg, hq, cs]`` inside ``_SCORE_TILE`` (one row at 64 query
+    heads: nothing masked is computed, and a step of 512 rows needs no
+    company to pay for itself). At 1,024 rows nothing gains and the plan is
+    PR 33's, measured there at gpt2-large's geometry (36 layers: 2.38 /
+    2.48 / 2.89 / 3.87 ms at ``bg`` 2 / 4 / 8 / 16 with 18 of 32 slots
+    active, 4.15 / 4.01 / 4.28 ms at 2 / 4 / 8 with all 32). The hybrid's
+    2,048 rows would gain and are left at ``(4, 128)`` by ISSUE 58: the
+    next issue's (ROADMAP S1). A ring (``s_max`` the window) is one chunk.
     The four chunk buffers (2 slots x {K, V}) of a group stay inside
     ``_SLOT_BUFFERS``."""
-    cs = _SLOT_CHUNK
+    dv = dh if dv is None else dv
+    long_rows = s_max >= 32 * _SLOT_CHUNK
+    cs = 4 * _SLOT_CHUNK if long_rows else _SLOT_CHUNK
     bg = next(g for g in (4, 2, 1) if b % g == 0)
-    while bg > 1 and 4 * bg * hkv * cs * dh * itemsize > _SLOT_BUFFERS:
+    while bg > 1 and (
+            2 * bg * hkv * cs * (dh + dv) * itemsize > _SLOT_BUFFERS
+            or (long_rows and (hq or 0) * bg * cs * 4 > _SCORE_TILE)):
         bg //= 2
     return bg, cs
+
+
+def count_walk(long_step: bool) -> None:
+    """Say in the program's registry which per-slot walk was traced:
+    ``decode/traced_walk_long`` (a loop step of more than ``_SLOT_CHUNK``
+    rows) or ``decode/traced_walk_128``. Both exist from the first call
+    on."""
+    from deepspeed_tpu.telemetry.registry import get_registry
+
+    reg = get_registry()
+    counters = [reg.counter("decode/traced_walk_" + n)
+                for n in ("128", "long")]
+    counters[bool(long_step)].inc()
 
 
 def decode_rows_fetched(active_lengths, cs: int = _SLOT_CHUNK) -> int:
@@ -1173,8 +1253,9 @@ def fused_decode_step(q: jax.Array, k_full: jax.Array, v_full: jax.Array,
     plan:         optional measured-plan override (the autotune
                   harness's candidate; ops/autotune.py entries are
                   consulted otherwise — ``_resolve_plan``). The per-slot
-                  walk takes ``bg`` / ``cs`` from :func:`_slot_plan`
-                  unless the override names them.
+                  walk takes ``bg`` / ``cs`` from :func:`_slot_plan`, by
+                  the cache's geometry, unless the override names them;
+                  its DMAs are ``_SLOT_CHUNK`` rows under every plan.
 
     Keys and values of two widths (``v_full [L, B, Hkv, S, Dv]``, ``v_new
     [B, 1, Hkv, Dv]``, unpacked rows, per-slot ``idx``): the scores are over
@@ -1232,7 +1313,8 @@ def fused_decode_step(q: jax.Array, k_full: jax.Array, v_full: jax.Array,
         walk = active if isinstance(active, SlotWalk) \
             else slot_walk(idx_a, active)
         if not (plan and {"bg", "cs"} <= plan.keys()):
-            bg, cs = _slot_plan(b, hkv, s_max, dh, itemsize)
+            bg, cs = _slot_plan(b, hkv, s_max, dh, itemsize, dv=dv, hq=hq)
+        count_walk(cs > _SLOT_CHUNK)
         if ring:
             scalars = [layer_a, jnp.minimum(idx_a, s_max), walk.order,
                        walk.n_active, idx_a % s_max]
@@ -1270,7 +1352,8 @@ def fused_decode_step(q: jax.Array, k_full: jax.Array, v_full: jax.Array,
         # write sems: per-row windows in the per-slot path; read sems:
         # per-row chunks there
         pltpu.SemaphoreType.DMA((2, b if per_slot else 1)),
-        pltpu.SemaphoreType.DMA((2, 2, bg) if per_slot else (2, 2)),
+        pltpu.SemaphoreType.DMA((2, 2, bg * (cs // _SLOT_CHUNK)) if per_slot
+                                else (2, 2)),
     ]
     attn, k_out, v_out = pl.pallas_call(
         kernel,

@@ -1105,8 +1105,8 @@ MUTATIONS = [
      "        fv.wait()\n", "", "pallas-dma"),
     # the same in the per-slot walk: a row's V-chunk wait
     ("drop-slot-chunk-wait", "deepspeed_tpu/ops/decode_step.py",
-     "                each_row(g, c,\n"
-     "                         lambda p, j: chunk_copy(p, j, c, slot, 1).wait())\n",
+     "                each_row(g, c, lambda *r: "
+     "chunk_copy(*r, c, slot, 1).wait())\n",
      "                pass\n", "pallas-dma"),
 ]
 

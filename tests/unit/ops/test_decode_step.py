@@ -5,6 +5,8 @@ scripts/check_decode_step.py; here the interpret-mode kernel and the
 packed-cache routing/fallback contract are pinned on CPU.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -237,7 +239,8 @@ def test_generate_packed_cache_end_to_end():
     np.testing.assert_array_equal(out, cur)
 
 
-def _two_width_reference(q, kf, vf, kn, vn, idxs, sink, window):
+def _two_width_reference(q, kf, vf, kn, vn, idxs, sink, window,
+                         scale=192 ** -0.5):
     """Plain einsum in float32: each slot's ``idx`` cached positions (the
     last ``window`` of them on a ring) and the new one, ``sink [Hq]`` in the
     denominator."""
@@ -251,7 +254,7 @@ def _two_width_reference(q, kf, vf, kn, vn, idxs, sink, window):
         if window is not None:
             k, v = k[:, -window:], v[:, -window:]
         k, v = (np.repeat(a, hq // hkv, 0) for a in (k, v))
-        s = np.einsum("hd,hsd->hs", q[i, 0], k) * 192 ** -0.5
+        s = np.einsum("hd,hsd->hs", q[i, 0], k) * scale
         if sink is not None:
             s = np.concatenate([s, sink[:, None]], 1)
         p = np.exp(s - s.max(-1, keepdims=True))
@@ -322,3 +325,131 @@ def test_per_slot_walks_keys_192_values_128(ring, hkv, s, idxs, active,
         np.testing.assert_array_equal(
             np.asarray(v1[1, i, :, at], np.float32), vn[i, 0])
     assert k1.shape[-1] == row and v1.shape[-1] == dv
+
+
+# (b, hkv, s_max, dk, dv, hq) of the two cells whose rows run to 16k, and the
+# same cut in ``b`` and ``s_max`` for the interpreter
+_LONG_ROWS = {
+    "mimo-global": ((16, 4, 16384, 256, 128, 64), (8, 4, 1536)),
+    "solar": ((16, 8, 16384, 128, 128, 64), (8, 8, 1536)),
+}
+
+
+def _long_plan(family):
+    from deepspeed_tpu.ops.decode_step import _SLOT_CHUNK, _slot_plan
+
+    (b, hkv, s_max, dk, dv, hq), _ = _LONG_ROWS[family]
+    bg, cs = _slot_plan(b, hkv, s_max, dk, 2, dv=dv, hq=hq)
+    assert cs > _SLOT_CHUNK, "the long step is what these cases are for"
+    return bg, cs
+
+
+@functools.lru_cache(maxsize=None)
+def _interpreted_step(bg, cs):
+    """One jitted program a plan and a family's shapes: an interpreted call
+    outside ``jit`` is traced and compiled anew every time."""
+    return jax.jit(functools.partial(fused_decode_step, interpret=True,
+                                     plan={"bg": bg, "cs": cs}))
+
+
+def _edge_lengths(cs, s):
+    """Lengths on both sides of every DMA and step edge."""
+    return [0, 1, 127, 128, 129, cs - 1, cs, cs + 1, 2 * cs - 1, 2 * cs,
+            s - 129, s - 1]
+
+
+def _long_cases():
+    for family in _LONG_ROWS:
+        for name, bg, pick in [
+                # twelve lengths in two draws of eight slots, all active
+                ("edges-a", None, lambda e: (e[:8], [1] * 8)),
+                ("edges-b", None, lambda e: (e[4:], [1] * 8)),
+                # inactive slots between active ones, their stale lengths
+                # longer than every active slot's
+                ("inactive-between", None, lambda e: (
+                    [e[-1], e[5], e[-1], e[7], e[1], e[-1], e[8], e[-1]],
+                    [0, 1, 0, 1, 1, 0, 1, 0])),
+                # rows of one group of four that end in different steps:
+                # three steps, two, two and one, then one, one and none
+                ("group-ends-apart", 4, lambda e: (
+                    [e[7], 2 * e[6], 130, 3 * e[6] - 3, 5, e[6], 0, 700],
+                    [1, 1, 1, 1, 1, 1, 1, 0]))]:
+            yield pytest.param(family, bg, pick, id=f"{family}-{name}")
+
+
+@pytest.mark.serving
+@pytest.mark.parametrize("family,group,pick", list(_long_cases()))
+def test_long_step_matches_einsum_and_writes_as_the_128_walk(family, group,
+                                                             pick):
+    """The plan of a cache whose rows run to 16k (a loop step of more than
+    128 rows, DMAs of 128) at the cell's own widths and heads, the cache cut
+    in ``b`` and ``s_max``: against the float32 einsum on the active slots,
+    and the cache written in place bit for bit as the ``(4, 128)`` plan
+    writes it. ``group``: rows a group where not the plan's own."""
+    (_, _, _, dk, dv, hq), (b, hkv, s) = _LONG_ROWS[family]
+    bg, cs = _long_plan(family)
+    bg = group or bg
+    idxs, active = pick(_edge_lengths(cs, s))
+    rng = np.random.RandomState(7)
+    bf = lambda a: np.asarray(jnp.asarray(a, jnp.bfloat16), np.float32)
+    q, kn = bf(rng.randn(b, 1, hq, dk)), bf(rng.randn(b, 1, hkv, dk))
+    vn = bf(rng.randn(b, 1, hkv, dv))
+    kf, vf = bf(rng.randn(b, hkv, s, dk)), bf(rng.randn(b, hkv, s, dv))
+    want = _two_width_reference(q, kf, vf, kn, vn, idxs, None, None,
+                                scale=dk ** -0.5)
+    stacked = lambda a: jnp.asarray(np.stack([np.zeros_like(a), a]),
+                                    jnp.bfloat16)
+
+    def step(bg, cs):
+        return _interpreted_step(bg, cs)(
+            jnp.asarray(q, jnp.bfloat16), stacked(kf), stacked(vf),
+            jnp.asarray(kn, jnp.bfloat16), jnp.asarray(vn, jnp.bfloat16),
+            jnp.int32(1), jnp.asarray(idxs, jnp.int32),
+            active=jnp.asarray(active))
+
+    got, k1, v1 = step(bg, cs)
+    _, k0, v0 = step(4, 128)
+    act = np.asarray(active, bool)
+    got = np.asarray(got, np.float32)
+    assert got.shape == (b, 1, hq, dv) and np.isfinite(got).all()
+    np.testing.assert_allclose(got[act], want[act], atol=0.03)
+    np.testing.assert_array_equal(got[~act], 0.0)
+    for new, old in ((k1, k0), (v1, v0)):
+        np.testing.assert_array_equal(np.asarray(new, np.float32),
+                                      np.asarray(old, np.float32))
+    for i in np.flatnonzero(act):       # and the token is where it belongs
+        np.testing.assert_array_equal(
+            np.asarray(k1[1, i, :, idxs[i]], np.float32), kn[i, 0])
+        np.testing.assert_array_equal(
+            np.asarray(v1[1, i, :, idxs[i]], np.float32), vn[i, 0])
+
+
+@pytest.mark.parametrize("b,hq,hkv,s,dk,dv,ring,long_step", [
+    pytest.param(16, 64, 4, 16384, 256, 128, False, True, id="mimo-global"),
+    pytest.param(16, 64, 8, 128, 256, 128, True, False, id="mimo-ring"),
+    pytest.param(16, 64, 8, 16384, 128, 128, False, True, id="solar"),
+    pytest.param(32, 64, 8, 4096, 128, 128, False, True, id="k-exaone"),
+    pytest.param(64, 32, 8, 2048, 64, 64, False, False, id="hybrid"),
+    pytest.param(32, 20, 20, 1024, 64, 64, False, False, id="serve-chat"),
+])
+def test_traced_walk_counters_say_which_walk(b, hq, hkv, s, dk, dv, ring,
+                                             long_step):
+    """``decode/traced_walk_long`` and ``decode/traced_walk_128`` in the
+    global registry, bumped once a trace of the per-slot step at each cell's
+    full geometry (traced only: shapes, nothing runs)."""
+    from deepspeed_tpu.telemetry.registry import get_registry
+
+    pair = kv_pack_factor(dk)
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    reg = get_registry()
+    names = ["decode/traced_walk_128", "decode/traced_walk_long"]
+    before = [reg.counter(n).value for n in names]
+    jax.eval_shape(
+        lambda q, k, v, kn, vn, idx: fused_decode_step(
+            q, k, v, kn, vn, jnp.int32(0), idx, ring=ring, interpret=True),
+        sds(b, 1, hq, dk), sds(1, b, hkv, s // pair, dk * pair),
+        sds(1, b, hkv, s // pair, dv * pair), sds(b, 1, hkv, dk),
+        sds(b, 1, hkv, dv), jax.ShapeDtypeStruct((b,), jnp.int32))
+    after = [reg.counter(n).value for n in names]
+    assert [a - c for a, c in zip(after, before)] \
+        == [int(not long_step), int(long_step)]

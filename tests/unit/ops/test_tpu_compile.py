@@ -282,6 +282,15 @@ def test_gqa_prefill_kernel(one_chip, rows):
 TWO_WIDTH_SHAPES = [(2, 16, 64, 4, 16384, False), (5, 16, 64, 8, 128, True)]
 
 
+def _two_width_operands(sh, l, b, hq, hkv, s, dk, dv):
+    """``_decode_operands`` with keys ``dk`` and values ``dv`` wide, and the
+    active mask."""
+    return (_sds(sh, (b, 1, hq, dk)), _sds(sh, (l, b, hkv, s, dk)),
+            _sds(sh, (l, b, hkv, s, dv)), _sds(sh, (b, 1, hkv, dk)),
+            _sds(sh, (b, 1, hkv, dv)), _sds(sh, (), jnp.int32),
+            _sds(sh, (b,), jnp.int32), _sds(sh, (b,), jnp.bool_))
+
+
 @pytest.mark.parametrize("dims", TWO_WIDTH_SHAPES, ids=str)
 def test_fused_decode_step_keys_256_values_128_and_a_sink(one_chip, dims):
     """Both per-slot walks with a key leaf 256 lanes wide and a value leaf
@@ -301,12 +310,34 @@ def test_fused_decode_step_keys_256_values_128_and_a_sink(one_chip, dims):
                                  scale=192 ** -0.5, interpret=False)
 
     text = _compiled_text(
-        fn, _sds(one_chip, (b, 1, hq, dk)), _sds(one_chip, (l, b, hkv, s, dk)),
-        _sds(one_chip, (l, b, hkv, s, dv)), _sds(one_chip, (b, 1, hkv, dk)),
-        _sds(one_chip, (b, 1, hkv, dv)), _sds(one_chip, (), jnp.int32),
-        _sds(one_chip, (b,), jnp.int32), _sds(one_chip, (b,), jnp.bool_),
+        fn, *_two_width_operands(one_chip, l, b, hq, hkv, s, dk, dv),
         _sds(one_chip, (hq,), jnp.float32))
     assert "dstpu_decode_step" in text
+
+
+def test_long_step_at_mimos_rows_fits_the_scoped_vmem(one_chip):
+    """The plan of MiMo's global layers (16 slots x 16,384, 4 heads, keys of
+    256 lanes and values of 128) is the long step, and the kernel it names
+    compiles for the described chip inside the scoped VMEM limit: its chunk
+    buffers are 3 MB of the 40, a group of four's (the widest plan the table
+    measured) 12 MB."""
+    from deepspeed_tpu.ops import decode_step
+
+    l, b, hq, hkv, s, dk, dv = 2, 16, 64, 4, 16384, 256, 128
+    bg, cs = decode_step._slot_plan(b, hkv, s, dk, 2, dv=dv, hq=hq)
+    assert cs > decode_step._SLOT_CHUNK
+    assert 2 * bg * hkv * cs * (dk + dv) * 2 <= decode_step._SLOT_BUFFERS
+
+    for plan in (None, {"bg": 4, "cs": cs}):
+        def fn(q, k, v, kn, vn, layer, idx, active):
+            return decode_step.fused_decode_step(
+                q, k, v, kn, vn, layer, idx,
+                active=decode_step.slot_walk(idx, active), plan=plan,
+                scale=192 ** -0.5, interpret=False)
+
+        text = _compiled_text(
+            fn, *_two_width_operands(one_chip, l, b, hq, hkv, s, dk, dv))
+        assert "dstpu_decode_step" in text
 
 
 def test_gqa_prefill_kernel_keys_256_values_128(one_chip):
@@ -367,15 +398,17 @@ def _kernel_digests(text):
 
 # (L, B, Hq, Hkv, S, Dh, ring) of the four older attention families' decode
 # steps and the kernel each traced to at PR 54 (the parent of the PR that
-# gave the step two widths and a sink)
+# gave the step two widths and a sink). PR 58 recorded two anew: K-EXAONE's
+# 4,096 rows and Solar's 16,384 are walked a loop step of 512 rows, a row a
+# group; the walks at 1,024 and 2,048 rows and the rings are PR 54's still
 ONE_WIDTH_KERNELS = {
     "gpt2-large": ((36, 32, 20, 20, 1024, 64, False), "f179d10f202a1411"),
     "granite-4.0-h-micro": ((4, 64, 32, 8, 2048, 64, False),
                             "fb4841169344c797"),
-    "k-exaone.rows": ((1, 32, 64, 8, 4096, 128, False), "4bb15ad6036121a2"),
+    "k-exaone.rows": ((1, 32, 64, 8, 4096, 128, False), "103597fa4ee57595"),
     "k-exaone.ring": ((4, 32, 64, 8, 128, 128, True), "3ccde5c38a1a3b00"),
     "solar-open2-250b": ((1, 16, 64, 8, 16384, 128, False),
-                         "8f1d3eff373018e9"),
+                         "d5750f9021028bf1"),
 }
 
 

@@ -20,21 +20,26 @@ from deepspeed_tpu.utils import groups
 pytestmark = [pytest.mark.serving, pytest.mark.quick]
 
 
-def _walk_rows(lengths, active, bg, cs):
+def _walk_rows(lengths, active, bg, cs, dma=None):
     """Rows the kernel's chunk DMAs move, by its own control flow: groups of
     ``bg`` sorted positions, each walked to its first row's chunk count, a
-    row's DMA started while ``c < ceil(len / cs)``."""
+    row's DMA started while ``c < ceil(len / cs)``; where a chunk is fetched
+    in parts of ``dma`` rows, part ``u`` while ``c * parts + u < ceil(len /
+    dma)``."""
     walk = decode_step.slot_walk(jnp.asarray(lengths), jnp.asarray(active))
     order, n = np.asarray(walk.order), int(walk.n_active[0])
     b = len(lengths)
+    dma = cs if dma is None else dma
+    parts = cs // dma
 
-    def nch(p):
-        return -(-int(lengths[order[min(p, b - 1)]]) // cs) if p < n else 0
+    def nch(p, rows=cs):
+        return -(-int(lengths[order[min(p, b - 1)]]) // rows) if p < n else 0
 
     rows = 0
     for g in range(-(-n // bg)):
         for c in range(nch(g * bg)):
-            rows += cs * sum(c < nch(g * bg + j) for j in range(bg))
+            rows += dma * sum(c * parts + u < nch(g * bg + j, dma)
+                              for j in range(bg) for u in range(parts))
     return rows
 
 
@@ -49,12 +54,43 @@ def test_rows_fetched_is_the_walks_count(seed, b, bg, cs):
             == _walk_rows(lengths, active, bg, cs)
 
 
-def test_rows_fetched_counts_in_the_plans_chunk():
-    # the serve cells' geometries: (b, hkv, s_max, dh, itemsize)
-    for geometry in [(32, 20, 1024, 64, 2), (64, 8, 2048, 64, 2)]:
-        assert decode_step._slot_plan(*geometry)[1] == decode_step._SLOT_CHUNK
-    cs = decode_step._SLOT_CHUNK
-    assert decode_step.decode_rows_fetched([0, 1, cs, cs + 1]) == 4 * cs
+# the plan of every cell that decodes through the per-slot walk, by its
+# global layers' geometry (b, hkv, s_max, dh, itemsize, dv, hq): a chunk of
+# 128 where a slot holds 1,024 or 2,048 rows, the long step where it holds
+# thousands. The rings (``s_max`` the window of 128) are one chunk whatever
+SLOT_PLANS = {
+    "gpt2-large.serve-chat": ((32, 20, 1024, 64, 2, 64, 20), (4, 128)),
+    "granite-4.0-h-micro.serve-chat-bursty":
+        ((64, 8, 2048, 64, 2, 64, 32), (4, 128)),
+    "k-exaone-236b-a23b.serve-mixed-lengths":
+        ((32, 8, 4096, 128, 2, 128, 64), (1, 512)),
+    "solar-open2-250b.serve-agent-contexts":
+        ((16, 8, 16384, 128, 2, 128, 64), (1, 512)),
+    "mimo-v2.5.serve-long-context-decode":
+        ((16, 4, 16384, 256, 2, 128, 64), (1, 512)),
+    "k-exaone-236b-a23b.rings": ((32, 8, 128, 128, 2, 128, 64), (4, 128)),
+    "mimo-v2.5.rings": ((16, 8, 128, 256, 2, 128, 64), (4, 128)),
+}
+
+
+@pytest.mark.parametrize("cell", list(SLOT_PLANS))
+def test_rows_fetched_counts_in_the_plans_chunk(cell):
+    """The plan's table, and the host's count against the walk's own under
+    the plan each cache gets: a row's tail rounds up to the DMA's 128 rows
+    whatever the loop step covers."""
+    (b, hkv, s_max, dh, itemsize, dv, hq), want = SLOT_PLANS[cell]
+    bg, cs = decode_step._slot_plan(b, hkv, s_max, dh, itemsize, dv=dv,
+                                    hq=hq)
+    assert (bg, cs) == want
+    assert s_max % cs == 0 and cs % decode_step._SLOT_CHUNK == 0
+    dma = decode_step._SLOT_CHUNK
+    assert decode_step.decode_rows_fetched([0, 1, dma, dma + 1]) == 4 * dma
+    rng = np.random.RandomState(len(cell))
+    for fill in (0.0, 0.1, 0.4, 1.0):
+        lengths = rng.randint(0, s_max, size=b)
+        active = rng.rand(b) < fill
+        assert decode_step.decode_rows_fetched(lengths[active]) \
+            == _walk_rows(lengths, active, bg, cs, dma)
 
 
 class VirtualClock:
