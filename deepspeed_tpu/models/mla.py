@@ -1,10 +1,12 @@
-"""Multi-head LATENT attention over one cached row a token, as two families
-here have it (``sarvam_mla``, ``longcat_flash``): keys and values of all heads
-are up-projections of one compressed latent a token, so the cache holds ONE
-row a token a layer, whatever the number of heads. What is one family's (how
+"""Multi-head LATENT attention over one cached row a token, as three families
+here have it (``sarvam_mla``, ``longcat_flash``, ``gigachat35``): keys and
+values of all heads are up-projections of one compressed latent a token, so
+the cache holds ONE row a token a layer, whatever the number of heads. What is
+one family's (how
 queries and the latent are projected, the rotation's frequencies, the stack
-around the attention) stays in its file; the cache leaf, its two attention
-forms and the residual sum behind them are here, once.
+around the attention, a gate before ``Wo``) stays in its file; the cache leaf,
+its two attention forms and the plain residual sum behind them are here,
+once.
 
     [k_nope_h | v_h] = c~ Wkv_b[h];  k_r one rotated row for all heads
     score_h(i, j) = s (q_nope_h(i) . k_nope_h(j) + q_rope_h(i) . k_r(j)), j <= i
@@ -204,12 +206,20 @@ class LatentAttention:
                                  zero))
 
     def _attention(self, x, blk, latent, at, idx, valid, walk_):
-        """``x + Attn(x)`` -> ``(x, latent)``: the family's projections, then
-        the plain causal form where there is no cache (``latent`` None), the
-        absorbed step for one token a row, or the block's rows written at
-        cache layer ``at`` and the decompressed form over them. ``valid
-        [B]``: the block's real positions a row; ``walk_``: the decode
-        program's ``slot_walk``."""
+        """``x + Attn(x) Wo`` -> ``(x, latent)``: :meth:`_attend` and the
+        residual sum behind ``Wo``, as two families have it. A family whose
+        block does something else between the heads and the stream (a gate
+        before ``Wo``, a norm behind it) takes :meth:`_attend` itself."""
+        out, latent = self._attend(x, blk, latent, at, idx, valid, walk_)
+        return x + merge_heads(out, blk["wo"]), latent
+
+    def _attend(self, x, blk, latent, at, idx, valid, walk_):
+        """What stands before ``Wo`` -> ``(out [B, T, H, v], latent)``: the
+        family's projections of ``x``, then the plain causal form where
+        there is no cache (``latent`` None), the absorbed step for one token
+        a row, or the block's rows written at cache layer ``at`` and the
+        decompressed form over them. ``valid [B]``: the block's real
+        positions a row; ``walk_``: the decode program's ``slot_walk``."""
         c = self.config
         b, t, _ = x.shape
         pos = cache_positions(0 if idx is None else idx, t)
@@ -238,7 +248,7 @@ class LatentAttention:
                 out = self._prompt_attention(
                     q_nope, q_rope, latent, at,
                     jnp.broadcast_to(pos, (b, t)), blk, valid)
-        return x + merge_heads(out, blk["wo"]), latent
+        return out, latent
 
     def _latent_cache(self, layers: int, batch_size: int, max_len: int,
                       dtype=None):

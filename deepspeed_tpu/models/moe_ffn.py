@@ -13,7 +13,11 @@ names, the layer's arithmetic and the counters a step returns are here, once.
 What a configuration may state beyond the four keys every one has
 (``num_experts_per_tok``, ``routed_scaling_factor``, ``norm_topk_prob``,
 ``held``): ``scoring_func`` "softmax" for moe/grouped.softmax_topk_route in
-the sigmoid router's place, and ``zero_experts``, the router's last outputs
+the sigmoid router's place; ``swiglu_limit``, a clamp on every gated MLP of
+the layer, dense, shared and routed alike (``silu(min(z Wg, limit)) * clip(z
+Wu, -limit, limit)``, moe/grouped.swiglu_gate: taken at trace time, so a
+configuration without the key traces the operations it did); and
+``zero_experts``, the router's last outputs
 that are ZERO-COMPUTE identity experts: a chosen one returns the token itself,
 
     sparse += (s * sum over the chosen e >= E - zero_experts of w_e) z
@@ -27,11 +31,11 @@ from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.models.base import qdot
-from deepspeed_tpu.moe.grouped import held_experts, sigmoid_topk_route, softmax_topk_route
+from deepspeed_tpu.moe.grouped import (held_experts, sigmoid_topk_route, softmax_topk_route, swiglu_gate,
+                                       swiglu_up)
 
 DENSE, SPARSE = "dense", "sparse"
 # what a step counts on the device, in the order of the vector it returns
@@ -118,11 +122,13 @@ def ffn(z, blk, kind: str, valid, c):
     the router's width on, and its buffer follows the pairs held
     (moe/grouped.held_experts)."""
 
+    limit = getattr(c, "swiglu_limit", None)
+
     def gated(prefix):
-        gate = jax.nn.silu(qdot("btd,dm->btm", z, blk[prefix + "gate"]))
-        return qdot("btm,md->btd", gate * qdot("btd,dm->btm", z,
-                                               blk[prefix + "up"]),
-                    blk[prefix + "down"])
+        gate = swiglu_gate(qdot("btd,dm->btm", z, blk[prefix + "gate"]), limit)
+        return qdot("btm,md->btd", gate * swiglu_up(
+            qdot("btd,dm->btm", z, blk[prefix + "up"]), limit),
+            blk[prefix + "down"])
 
     b, t, d = z.shape
     if kind == DENSE:
@@ -137,7 +143,7 @@ def ffn(z, blk, kind: str, valid, c):
     y, counts = held_experts(
         flat, routing, blk["expert_gate"], blk["expert_up"],
         blk["expert_down"], c.held, valid=live,
-        n_experts=blk["router"].shape[-1] if t > 1 else None)
+        n_experts=blk["router"].shape[-1] if t > 1 else None, limit=limit)
     zero = jnp.zeros((), jnp.int32)
     if getattr(c, "zero_experts", 0):
         # the identity experts are the router's last outputs: a chosen one

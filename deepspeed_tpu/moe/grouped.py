@@ -100,6 +100,19 @@ class ExpertCounts(NamedTuple):
 ROW_TILE = 128
 
 
+def swiglu_gate(gate, limit: Optional[float] = None):
+    """A gated MLP's ``silu(gate)``; where a configuration states a
+    ``swiglu_limit``, ``silu(min(gate, limit))``. The clamp is taken at trace
+    time: without a limit the operations are the ones they were."""
+    return jax.nn.silu(gate if limit is None else jnp.minimum(gate, limit))
+
+
+def swiglu_up(up, limit: Optional[float] = None):
+    """A gated MLP's linear half, clamped to ``[-limit, limit]`` where a
+    configuration states a ``swiglu_limit`` (:func:`swiglu_gate`)."""
+    return up if limit is None else jnp.clip(up, -limit, limit)
+
+
 def compact_rows(n: int, k: int, count: int, n_experts: int) -> int:
     """The rows of a prompt block's compact sorted buffer: twice the pairs
     ``n`` tokens send to ``count`` of ``n_experts`` experts when the router
@@ -127,9 +140,12 @@ def count_traced(compact: bool) -> None:
 
 def held_experts(x, routing: Routing, w_gate, w_up, w_down,
                  held: Tuple[int, int], *, valid: Optional[jax.Array] = None,
-                 n_experts: Optional[int] = None):
+                 n_experts: Optional[int] = None,
+                 limit: Optional[float] = None):
     """Sum over the chosen experts held here of ``w_e * Expert_e(x)``, each a
-    gated MLP ``(silu(x Wg) * (x Wu)) Wd``.
+    gated MLP ``(silu(x Wg) * (x Wu)) Wd``; with ``limit`` (a configuration's
+    ``swiglu_limit``) the gate's input clamped from above and the linear half
+    to ``[-limit, limit]`` (:func:`swiglu_gate`, :func:`swiglu_up`).
 
     ``x [N, d]``; ``w_gate, w_up [count, d, m]``, ``w_down [count, m, d]``:
     the weights of experts ``first .. first + count - 1``, or each a
@@ -198,7 +214,8 @@ def held_experts(x, routing: Routing, w_gate, w_up, w_down,
         token = order // k
         with jax.named_scope("dstpu_moe_experts"):
             xs = x[token]
-            h = jax.nn.silu(grouped(xs, w_gate)) * grouped(xs, w_up)
+            h = swiglu_gate(grouped(xs, w_gate), limit) \
+                * swiglu_up(grouped(xs, w_up), limit)
             ys = grouped(h, w_down)
         if not prompt:
             w = routing.weights.reshape(-1)[order]

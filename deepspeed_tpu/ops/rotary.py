@@ -111,3 +111,21 @@ def apply_rotary_half_freqs(x: jax.Array, positions: jax.Array,
     x1, x2 = jnp.split(x32, 2, axis=-1)
     turned = jnp.concatenate([-x2, x1], axis=-1)
     return (x32 * jnp.cos(ang) + turned * jnp.sin(ang)).astype(x.dtype)
+
+
+def apply_rotary_pairs_freqs(x: jax.Array, positions: jax.Array,
+                             inv_freq: jax.Array) -> jax.Array:
+    """Rotation in the INTERLEAVED convention (``rope_interleave``): the
+    neighbours ``(x[2i], x[2i + 1])`` are pair ``i``, turned by ``positions *
+    inv_freq[i]`` where they lie. ``x [B, T, H, Dh]`` at ``positions`` (``[T]``
+    or ``[B, T]``), ``inv_freq [Dh / 2]`` (:func:`yarn_inv_freq`), in
+    float32. A score of two vectors rotated so is the score of the same two
+    rotated by halves after a de-interleave: the convention names which lanes
+    of a checkpoint's projection are partners."""
+    ang = positions.astype(jnp.float32)[..., None] * inv_freq   # [.., T, Dh/2]
+    ang = ang[None, :, None] if ang.ndim == 2 else ang[:, :, None]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., 0::2], x32[..., 1::2]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)

@@ -237,6 +237,40 @@ def test_kda_update_kernel(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
+def test_gdn_update_kernel(one_chip):
+    """``dstpu_gdn_update`` at the GigaChat 3.5 cell's shapes: 64 slots, four
+    delta-rule layers of 32 key and 64 value heads of 128 x 128 float32
+    state, four taps; a grid cell 32 value heads of an active slot (8 MB of
+    state blocks in VMEM), a slot's ``q | k | v`` and tails one block a slot,
+    the state and the tails in place (no temporary of either's size)."""
+    from deepspeed_tpu.ops import gdn
+    from deepspeed_tpu.ops.ssm import slot_order
+
+    b, l, hk, hv, d, taps = 64, 4, 32, 64, 128, 4
+    rows = gdn.conv_rows(hk, hv)
+    f32 = jnp.float32
+
+    def fn(qkv, ab, gate, state, tail, conv_w, a_log, dt_bias, o_norm, layer,
+           active):
+        weights = gdn.fold_weights({"conv_w": conv_w, "A_log": a_log,
+                                    "dt_bias": dt_bias, "o_norm": o_norm},
+                                   hk, hv)
+        return gdn.gdn_step(qkv, ab, gate, state, tail, layer, weights,
+                            slot_order(active), active, eps=1e-6,
+                            gate_scale=2.0, interpret=False)
+
+    sds = functools.partial(_sds, one_chip)
+    compiled = jax.jit(fn, donate_argnums=(3, 4)).lower(
+        sds((b, rows * d)), sds((b, 2, hv)), sds((b, hv * d)),
+        sds((l, b, hv, d, d), f32),
+        sds((l, b) + gdn.tail_shape(taps, hk, hv, d)),
+        sds((l, taps, rows * d), f32), sds((l, hv), f32), sds((l, hv), f32),
+        sds((l, d), f32), sds((), jnp.int32), sds((b,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "dstpu_gdn_update" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 def test_kda_prefill_kernel(one_chip):
     """``dstpu_kda_prefill`` at the Solar-Open2 cell's shapes: a token block
     of 2,048 positions, 64 heads of 128 keys and values, chunks of 64, the
@@ -749,6 +783,17 @@ def _mimo_cell():
         moe_layer_freq=(0, 1, 1, 1, 1, 1, 1))), 16, 16384
 
 
+def _gigachat_cell():
+    from deepspeed_tpu.models.gigachat35 import (GigaChat35Config,
+                                                 GigaChat35Model)
+
+    # the cell's five layers: the dense delta-rule layer, the latent layer,
+    # a run of three sparse delta-rule layers
+    return GigaChat35Model(GigaChat35Config(
+        vocab_size=16032, max_seq_len=4096, num_layers=5,
+        full_attention_layers=(1,), first_k_dense=1, held=(0, 16))), 64, 4096
+
+
 def _weights(model, sharding):
     """The model's parameters as the serving engine holds them: bf16, as
     shapes on the described chip; and one layer's sizes of every stacked
@@ -769,11 +814,12 @@ def _assert_copies_no_weight(compiled, leaves):
 
 @pytest.mark.parametrize("cell", [_exaone_cell, _granite_cell,
                                   _gpt2_large_cell, _sarvam_cell,
-                                  _solar_cell, _longcat_cell, _mimo_cell],
+                                  _solar_cell, _longcat_cell, _mimo_cell,
+                                  _gigachat_cell],
                          ids=["k-exaone", "granite-4.0-h-micro",
                               "gpt2-large", "sarvam-105b",
                               "solar-open2-250b", "longcat-flash-chat",
-                              "mimo-v2.5"])
+                              "mimo-v2.5", "gigachat3.5-432b-a28b"])
 def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
     """The serve cells' decode step (``InferenceEngine.slot_decode_program``'s
     call of the model: one token a slot, per-slot lengths, the slot walk) at
@@ -823,6 +869,13 @@ def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
         assert not [line for line in text.splitlines()
                     if re.search(r"= \w+\[[\d,]*\b16384,(256|128)\]", line)
                     and (" dot(" in line or " convolution(" in line)]
+    if "gdn" in state:
+        # one folded call a run of delta-rule layers, the state in place,
+        # beside the latent layer's absorbed step in the one program; every
+        # run is folded: no update out of XLA's own operations
+        text = compiled.as_text()
+        assert text.count("dstpu_gdn_update") >= 2
+        assert text.count("dstpu_mla_decode_step") >= 1
     if "kda" in state:
         # one folded call the delta-rule run's layer, the state in place; the
         # prompt block's kernel has no part in a one-token step
