@@ -1193,15 +1193,15 @@ def _slot_plan(b: int, hkv: int, s_max: int, dh: int, itemsize: int,
     return bg, cs
 
 
-def count_walk(long_step: bool) -> None:
+def count_walk(long_step: bool, kernel: str = "decode") -> None:
     """Say in the program's registry which per-slot walk was traced:
     ``decode/traced_walk_long`` (a loop step of more than ``_SLOT_CHUNK``
-    rows) or ``decode/traced_walk_128``. Both exist from the first call
-    on."""
+    rows) or ``decode/traced_walk_128``; ``mla/...`` for ``kernel="mla"``
+    (ops/mla_decode_step.py). Both exist from the first call on."""
     from deepspeed_tpu.telemetry.registry import get_registry
 
     reg = get_registry()
-    counters = [reg.counter("decode/traced_walk_" + n)
+    counters = [reg.counter(f"{kernel}/traced_walk_{n}")
                 for n in ("128", "long")]
     counters[bool(long_step)].inc()
 

@@ -152,15 +152,25 @@ def test_fused_decode_step_head_128_rep_8_and_ring(one_chip, dims):
     _compiled_text(fn, *ops, _sds(one_chip, (dims[1],), jnp.bool_))
 
 
-def test_fused_mla_decode_step(one_chip):
-    """The absorbed latent-attention step at the Sarvam-105B cell's shapes:
-    five layers, 16 slots x 16,384 rows of 640 lanes (512 + 64, padded), 64
-    heads; the walk order made outside the kernel, as the decode program
-    calls it."""
+@pytest.mark.parametrize("l,b,s,plan", [
+    pytest.param(5, 16, 16384, (1, 1024), id="sarvam-105b"),
+    pytest.param(8, 32, 4096, (4, 256), id="longcat-flash-chat"),
+])
+def test_fused_mla_decode_step(one_chip, l, b, s, plan):
+    """The absorbed latent-attention step at the Sarvam-105B cell's shapes
+    (five layers, 16 slots x 16,384 rows of 640 lanes (512 + 64, padded), 64
+    heads) and at LongCat-Flash's (eight attention sublayers, 32 slots x
+    4,096), each under the plan its geometry takes since PR 60 (a loop step
+    of 1,024 rows of one slot in eight DMAs; 256 rows of four slots in two).
+    The call asks for ``_VMEM_LIMIT`` of fast memory (``_compiler_params``)
+    and the compiler refuses a kernel that needs more. The walk order is made
+    outside the kernel, as the decode program calls it."""
     from deepspeed_tpu.ops.decode_step import slot_walk
-    from deepspeed_tpu.ops.mla_decode_step import fused_mla_decode_step
+    from deepspeed_tpu.ops.mla_decode_step import (_walk_plan,
+                                                   fused_mla_decode_step)
 
-    l, b, s, w, h = 5, 16, 16384, 640, 64
+    w, h = 640, 64
+    assert _walk_plan(b, s, h) == plan
 
     def fn(q, latent, row, layer, idx, active):
         return fused_mla_decode_step(
