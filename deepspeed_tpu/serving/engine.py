@@ -533,6 +533,13 @@ class ServingEngine:
                     f"prefill bucket {b} must be a multiple of the cache "
                     f"token-pair pack factor {self.cache.pair} "
                     "(ops/attention.kv_pack_factor)")
+            window = getattr(self.cache, "restart_window", 0)
+            if window and b % window:
+                raise EngineConfigError(
+                    f"prefill bucket {b} must be a multiple of the window "
+                    f"of {window} positions that "
+                    f"{type(model).__name__}'s cache starts over at: a "
+                    "prompt, and a prefill chunk, passes in whole windows")
         self.num_slots = num_slots
         self.max_len = max_len
         self.eos_token_id = eos_token_id

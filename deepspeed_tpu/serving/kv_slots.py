@@ -4,7 +4,7 @@ The vLLM PagedAttention idea specialized to XLA's static-shape world: a
 persistent per-slot state TREE whose BATCH dimension is the page table. The
 model declares the tree (``model.init_cache``; the leaves' names and operand
 order are its ``slot_state_keys``, ``("k", "v")`` where it declares none).
-Three kinds of state can live in it side by side; a model says which of its
+Five kinds of state can live in it side by side; a model says which of its
 leaves hold token rows (``row_state_keys``, models/base.py; ``("k", "v")``
 where it declares none) and which are rings (``window_state_keys``), and
 every other leaf is recurrent:
@@ -51,6 +51,23 @@ every other leaf is recurrent:
     ``W`` real positions, decode overwrites the row that leaves the window.
     Like recurrent state it is not addressed by token rows, so it counts
     among ``recurrent_keys`` and the same options are refused.
+
+  * **summary rows beside a window that starts over** (leaves the model
+    names in ``summary_state_keys`` and ``restart_window_keys``;
+    models/evabyte.py's ``k_sum``, ``v_sum`` ``[L, B, H, S_max / c, Dh]`` and
+    ``k_win``, ``v_win`` ``[L, B, H, W, Dh]``): state that grows SLOWER than
+    the request, one row for every ``c`` positions (chunk ``j`` at row ``j``),
+    and becomes visible a window at a time: at length ``n`` the ``(W / c)
+    floor(n / W)`` rows of the closed windows. The window beside it is no
+    ring: position ``p`` lies at row ``p % W`` and the live rows are ``0 ..
+    n % W``; the rows behind belong to the window before and are not read.
+    Both are bounded functions of the slot's one ``lengths`` entry
+    (:meth:`SlotKVCache.live_rows`), so a freed slot's rows need no clearing:
+    a shorter request that takes the slot reads what it wrote itself and
+    nothing else. Prefill writes whole windows (its buckets are multiples of
+    ``W``: serving/engine.py refuses others), decode writes one row of each
+    kind a step for active slots. Neither is addressed by token rows: they
+    count among ``recurrent_keys`` and the same options are refused.
 
 A finished request's slot is reused by the next admission with ZERO cache
 reshaping — the prefill program overwrites the slot's prefix rows
@@ -108,6 +125,16 @@ class SlotKVCache:
         # itself whether its fused step walks this allocation
         self.window_layers = self.window = 0
         self.fused_window_walk = False
+        # summary rows beside a window that starts over: the window, and the
+        # positions a summary row pools (the leaf holds a row a chunk of the
+        # whole windows that ``max_len`` reaches into)
+        self.restart_window = self.summary_chunk = 0
+        summaries = getattr(model, "summary_state_keys", ())
+        if summaries:
+            w = self.state[model.restart_window_keys[0]].shape[3]
+            self.restart_window = w
+            self.summary_chunk = (-(-max_len // w) * w
+                                  // self.state[summaries[0]].shape[3])
         if "k" not in self.state:
             self.pair = 1
             self.fused_walk = bool(model.fused_row_walk(self.state, num_slots))
@@ -150,6 +177,14 @@ class SlotKVCache:
     def recurrent_keys(self) -> Tuple[str, ...]:
         """Leaves that are not token rows: state that no row addresses."""
         return recurrent_state_keys(self.keys, self.row_keys)
+
+    def live_rows(self, length: int) -> Tuple[int, int]:
+        """``(window rows, summary rows)`` of a slot that holds ``length``
+        tokens, where the model keeps summary rows beside a window that
+        starts over: what its next step may read, whatever an earlier
+        request left in the rows behind."""
+        w, c = self.restart_window, self.summary_chunk
+        return length % w, (w // c) * (length // w)
 
     # ------------------------------------------------------------- carry
     def carry(self) -> Tuple:

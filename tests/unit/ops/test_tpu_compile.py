@@ -184,6 +184,57 @@ def test_fused_mla_decode_step(one_chip, l, b, s, plan):
     assert "dstpu_mla_decode_step" in text
 
 
+def test_fused_eva_decode_step(one_chip):
+    """The EVA step at the EvaByte cell's shapes: eight layers, 12 slots, 32
+    heads of 128, a window of 2,048 rows beside 2,048 summary rows a slot (a
+    chunk of 16, 32,768 positions), a loop step of 256 rows of one slot in
+    two DMAs from either leaf, the two rows written in place through a
+    16-row and an 8-row window. The call asks for ``_VMEM_LIMIT`` of fast
+    memory and the compiler refuses a kernel that needs more."""
+    from deepspeed_tpu.ops.decode_step import slot_walk
+    from deepspeed_tpu.ops.eva import fused_eva_decode_step, supports_step
+
+    l, b, h, w, d, c, rows = 8, 12, 32, 2048, 128, 16, 2048
+    assert supports_step(h, d, w, c, rows)
+
+    def fn(q, kw, vw, ks, vs, kn, vn, phi, mu, layer, pos, active):
+        return fused_eva_decode_step(
+            q, kw, vw, ks, vs, kn, vn, phi, mu, layer, pos, chunk=c,
+            scale=d ** -0.5, active=slot_walk(pos, active), interpret=False)
+
+    new, head = _sds(one_chip, (b, h, d)), _sds(one_chip, (h, d), jnp.float32)
+    win, summ = (_sds(one_chip, (l, b, h, n, d)) for n in (w, rows))
+    text = _compiled_text(
+        fn, new, win, win, summ, summ, new, new, head, head,
+        _sds(one_chip, (), jnp.int32), _sds(one_chip, (b,), jnp.int32),
+        _sds(one_chip, (b,), jnp.bool_))
+    assert "dstpu_eva_decode_step" in text
+
+
+@pytest.mark.parametrize("rows", [256, 2048], ids=["bucket-4096",
+                                                   "bucket-32768"])
+def test_eva_prefill_kernel(one_chip, rows):
+    """One prompt block of the EvaByte cell: 2,048 queries at 32 heads of 128
+    against the summary rows of a bucket's own cache (the smallest bucket's
+    256, the largest's 2,048) and against itself, the layer and the visible
+    rows traced, as the prefill's scan over blocks calls it."""
+    from deepspeed_tpu.ops.eva import fused_eva_prompt_block, supports_prompt
+
+    l, t, h, d = 8, 2048, 32, 128
+    assert supports_prompt(t, d, rows)
+
+    def fn(q, k, v, ks, vs, layer, visible):
+        return fused_eva_prompt_block(q, k, v, ks, vs, layer, visible,
+                                      scale=d ** -0.5, interpret=False)
+
+    blk, summ = _sds(one_chip, (1, t, h, d)), _sds(one_chip,
+                                                   (l, 1, h, rows, d))
+    text = _compiled_text(fn, blk, blk, blk, summ, summ,
+                          _sds(one_chip, (), jnp.int32),
+                          _sds(one_chip, (1,), jnp.int32))
+    assert "dstpu_eva_prefill" in text
+
+
 # (cache layers, rows of the bucket, token block): the Sarvam-105B cell's
 # largest bucket; LongCat-Flash's smallest that takes the kernel (its 256
 # bucket is no whole key block of 512 and takes the loop), one tile of four
@@ -804,6 +855,13 @@ def _gigachat_cell():
         full_attention_layers=(1,), first_k_dense=1, held=(0, 16))), 64, 4096
 
 
+def _evabyte_cell():
+    from deepspeed_tpu.models.evabyte import EvaByteConfig, EvaByteModel
+
+    # the cell's widths at two of its eight layers (one kind: a loop run)
+    return EvaByteModel(EvaByteConfig(num_layers=2)), 12, 32768
+
+
 def _weights(model, sharding):
     """The model's parameters as the serving engine holds them: bf16, as
     shapes on the described chip; and one layer's sizes of every stacked
@@ -825,11 +883,12 @@ def _assert_copies_no_weight(compiled, leaves):
 @pytest.mark.parametrize("cell", [_exaone_cell, _granite_cell,
                                   _gpt2_large_cell, _sarvam_cell,
                                   _solar_cell, _longcat_cell, _mimo_cell,
-                                  _gigachat_cell],
+                                  _gigachat_cell, _evabyte_cell],
                          ids=["k-exaone", "granite-4.0-h-micro",
                               "gpt2-large", "sarvam-105b",
                               "solar-open2-250b", "longcat-flash-chat",
-                              "mimo-v2.5", "gigachat3.5-432b-a28b"])
+                              "mimo-v2.5", "gigachat3.5-432b-a28b",
+                              "evabyte"])
 def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
     """The serve cells' decode step (``InferenceEngine.slot_decode_program``'s
     call of the model: one token a slot, per-slot lengths, the slot walk) at
@@ -886,6 +945,12 @@ def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
         text = compiled.as_text()
         assert text.count("dstpu_gdn_update") >= 2
         assert text.count("dstpu_mla_decode_step") >= 1
+    if "k_sum" in state:
+        # the one fused call over both leaves, in place: no einsum over a
+        # leaf's 2,048 rows, no scatter into one
+        text = compiled.as_text()
+        assert text.count("dstpu_eva_decode_step") >= 1
+        assert " scatter(" not in text
     if "kda" in state:
         # one folded call the delta-rule run's layer, the state in place; the
         # prompt block's kernel has no part in a one-token step
