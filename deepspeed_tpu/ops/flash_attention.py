@@ -7,9 +7,11 @@ memory-efficient form is a Pallas kernel tiled for the MXU: O(block) VMEM
 instead of materializing the [T, T] score matrix in HBM.
 
 Layout: inputs [B, T, H, Dh] (framework-standard), flattened to (batch x
-head) rows. Three kernels: forward, and the standard two-kernel FA2
-recomputation backward (dq; dk/dv) from the saved log-sum-exp rows. All
-three share one tile program, whose parameters come from the operands'
+head) rows. Three kernels: forward, and the FA2 recomputation backward from
+the saved log-sum-exp rows, as ONE kernel where a row is one grid step (the
+dk/dv kernel with dq as a third result: sequence 2048 and under, every
+training call of the cells) and as the standard two (dq; dk/dv) everywhere
+else. All share one tile program, whose parameters come from the operands'
 shapes alone:
 
 * **Rows a tile.** Where two rows' heads fit the 128 lanes (Dh <= 64) and
@@ -45,14 +47,25 @@ shapes alone:
   ``K Q^T``, against which a statistics row broadcasts as it lies and
   ``P^T dO`` / ``dS^T Q`` are plain matmuls; forward and dq turn rows
   into columns with a 128 x 128 transpose a statistics chunk.
+* **One backward kernel.** Each of the two backward kernels recomputes
+  the scores, their exponentials and ``dO V^T`` for itself: seven stacked
+  matmuls a block visit where the mathematics needs five. Where both are
+  one grid step a row with every bound static, and the row fits
+  ``_FUSED_VMEM``, the dk/dv kernel's visit also adds ``dS^T K`` to its
+  query block's dq, a float32 value carried across the key blocks and
+  written once at the end. The stacked keys hold zeros in the other row's
+  lanes, so that one product contracts over the whole stack and lands each
+  row's dq in its own lanes. dk and dv are the split kernel's bit for bit;
+  dq differs by the order of its float32 sums alone (on the chip, in bf16
+  storage, by no bit at the cells' shapes).
 
 The numbers that chose each parameter (TPU v5e, PR 31) stand at the
 constants below; PERF.md section 6 has the table of single changes.
 
 Matmuls run in the storage dtype (bf16 on the training path — full MXU
 rate) with f32 accumulation; softmax statistics and ``exp`` are f32.
-Precision note: the P·V, dS·K, P^T·dO and dS^T·Q products see their p/ds
-operand ROUNDED to the storage dtype before the MXU — the standard
+Precision note: the P·V, dS·K (fused: dS^T·K), P^T·dO and dS^T·Q products
+see their p/ds operand ROUNDED to the storage dtype before the MXU — the standard
 FA2-on-bf16 tradeoff; set ``DSTPU_FLASH_F32_PRECISE=1`` to keep those
 operands in f32 (half MXU rate) for tolerance-sensitive runs. The softmax
 scale is folded into the query (dk/dv: key) tile once a block where that
@@ -86,7 +99,16 @@ from jax.experimental.pallas import tpu as pltpu
 # a row make an unrolled kernel of 370-490 operations (512: 170-260), and
 # every warm start lowers each kernel to Mosaic two or three times, about a
 # millisecond an operation on the chip's host: +3.5 s of warm set-up on 21
-# at 256, under one second at 512
+# at 256, under one second at 512. The backward as one kernel (my chip runs,
+# PR 62; dq + dk-dv -> fused, us a call): [40, 1024, 64] 129 + 170 -> 211
+# with dS^T K as a matmul that contracts dimension 0 of both operands (Mosaic
+# turns dS, [1024, 512] a visit, through the transpose unit, which the kernel
+# left idle), 212 with dq^T = K^T dS (K^T made once a key block, dq^T turned
+# once a query block); [50, 1024, 64] 161 + 212 -> 264 / 264; not causal
+# [40, 1024, 64] 170 + 226 -> 280 / 281; [64, 2048, 128] 672 + 891 -> 1118 /
+# 1116. Five units of 8.05 GFLOP of MXU passes for seven: 204 us at 197
+# TFLOP/s, so the fused kernel issues at 97% of the MXU's rate. The two
+# orientations are one speed; the first is kept, being the fewer lines
 _BLOCK = 512
 # A row whose score matrix has this many blocks or fewer (sequence 2048 and
 # under) is one grid step unrolled by hand, every bound static: at sequence
@@ -100,6 +122,12 @@ _MAX_UNROLL = 16
 # (forward 1213 us resident against 2578 in chunks of 2048, same run)
 _WALK_VMEM_BUDGET = 4 * 1024 * 1024
 _MAX_TILE = 1024        # tile-side rows a grid step of a walk not unrolled
+# bytes ``_fused_bytes`` may count for a row of the one-kernel backward. The
+# count is a bound from the shapes, not the compiler's own: compiled for the
+# described v5e under the 16 MiB scoped default, rows counted at 12.0 (both
+# train cells; [64, 2048, 128] bf16), 14.0 and 15.5 MiB fit, one at 16.0 fits
+# causal and is refused not causal by 0.4 MiB, and 19.0 and more are refused
+_FUSED_VMEM = 14 * 1024 * 1024
 _LANES = 128
 _NEG_INF = -1e30
 
@@ -113,6 +141,7 @@ def _dot_f32(a, b, dims):
 
 _NT = ((1,), (1,))      # a @ b.T
 _NN = ((1,), (0,))      # a @ b
+_TN = ((0,), (0,))      # a.T @ b
 
 
 def _mm_dtype(storage_dtype):
@@ -299,13 +328,18 @@ def _dq_step(q, do, k, v, lse, delta, acc, diff, offset, *, sc, mm):
 
 
 @functools.partial(jax.jit, static_argnames=_STEP_STATICS)
-def _dkv_step(k, v, q, do, lse, delta, dk, dv, diff, offset, *, sc, mm):
+def _dkv_step(k, v, q, do, lse, delta, dk, dv, dq, diff, offset, *, sc, mm):
     """The transposed scores K Q^T: keys run down the sublanes, and the
-    statistics are rows."""
+    statistics are rows. ``dq`` not None (the one-kernel backward): the
+    visit's scores, exponentials and V dO^T serve the query side too. The
+    stacked keys carry zeros in the other row's lanes, so ``dS^T K``
+    contracts over the whole stack and lands each row's dq in its own lanes
+    of [bq, w]."""
     pt = jnp.exp(_scores(k, q, diff, offset, sc) - lse)  # [rows*bk, bq]
     dv = dv + _dot_f32(pt.astype(mm), do, _NN)
-    dst = pt * (_dot_f32(v, do, _NT) - delta)
-    return dk + _dot_f32(dst.astype(mm), q, _NN), dv
+    dst = (pt * (_dot_f32(v, do, _NT) - delta)).astype(mm)
+    return (dk + _dot_f32(dst, q, _NN), dv,
+            None if dq is None else dq + _dot_f32(dst, k, _TN))
 
 
 # ------------------------------------------------------------------ kernels
@@ -431,8 +465,11 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *state, causal, scale, fold, rows, dh,
-                    bq, bk, n_chunks, static):
-    """Keys on the tile side, queries walked; the scores are K Q^T."""
+                    bq, bk, n_chunks, static, fused=False):
+    """Keys on the tile side, queries walked; the scores are K Q^T.
+    ``fused`` (a row is one grid step, every bound static): ``state`` is a
+    third result, dq, and a visit adds its ``dS^T K`` to the float32 block
+    of its queries, carried across the key blocks as a value."""
     tk, w = k_ref.shape
     qc = q_ref.shape[0]
     n_sub, n_qb, sw_n = tk // bk, qc // bq, bq // lse_ref.shape[-1]
@@ -440,8 +477,10 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
     mm = _mm_dtype(q_ref.dtype)
     sc = None if fold else scale
     diff = _stacked_diff(bq, bk, rows, keys_on_lanes=False)
-
-    if n_chunks > 1:
+    if fused:
+        (dq_ref,) = state
+        dq = [jnp.zeros((bq, w), jnp.float32)] * n_qb
+    elif n_chunks > 1:
         dk_acc_ref, dv_acc_ref = state
 
         @pl.when(c == 0)
@@ -461,11 +500,15 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
 
         def step(i, carry, masked):
             rs = _rows_at(i, bq)
-            return _dkv_step(k, v, q_ref[rs, :], do_ref[rs, :],
-                             _stat_rows(lse_ref, i * sw_n, sw_n, bk),
-                             _stat_rows(delta_ref, i * sw_n, sw_n, bk),
-                             *carry, diff if masked else None,
-                             (q0 + i * bq) - c0, sc=sc, mm=mm)
+            dk, dv, dq_i = _dkv_step(
+                k, v, q_ref[rs, :], do_ref[rs, :],
+                _stat_rows(lse_ref, i * sw_n, sw_n, bk),
+                _stat_rows(delta_ref, i * sw_n, sw_n, bk), *carry,
+                dq[i] if fused else None, diff if masked else None,
+                (q0 + i * bq) - c0, sc=sc, mm=mm)
+            if fused:
+                dq[i] = dq_i
+            return dk, dv
 
         dk, dv = _walk(_stretches(causal, c0, bk, q0, bq, n_qb,
                                   walk_is_keys=False), step, carry)
@@ -482,6 +525,11 @@ def _bwd_dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
         return 0
 
     _loop(0, n_sub, sub_block, 0)
+    if fused:
+        # dq came from the stacked keys, scaled already where folded
+        for i in range(n_qb):
+            dq_ref[_rows_at(i, bq), :] = (
+                dq[i] if fold else dq[i] * scale).astype(dq_ref.dtype)
 
 
 # ------------------------------------------------------------------ layouts
@@ -577,15 +625,34 @@ def _plan(t_tile, t_walk, w, itemsize, block_tile, block_walk, walk_budget):
     return tile, b_tile, t_walk, b_walk, False
 
 
-def _count_walk(n_chunks: int) -> None:
-    """Say in the program's registry which walk a traced flash kernel
-    took; both counters exist from the first call on."""
+def _count_traced(this: str, other: str) -> None:
+    """Say in the program's registry which of two forms a traced flash
+    kernel took; both counters exist from the first call on."""
     from deepspeed_tpu.telemetry.registry import get_registry
 
     reg = get_registry()
-    short, grid = (reg.counter("flash/traced_short_seq"),
-                   reg.counter("flash/traced_grid_walk"))
-    (short if n_chunks == 1 else grid).inc()
+    reg.counter(other)
+    reg.counter(this).inc()
+
+
+def _count_walk(n_chunks: int) -> None:
+    """Which walk: the walk side resident, or in chunks a grid step."""
+    names = ("flash/traced_short_seq", "flash/traced_grid_walk")
+    _count_traced(*(names if n_chunks == 1 else names[::-1]))
+
+
+def _count_bwd(fused: bool) -> None:
+    """Which backward: one kernel for dq, dk and dv, or two."""
+    names = ("flash/traced_bwd_fused", "flash/traced_bwd_split")
+    _count_traced(*(names if fused else names[::-1]))
+
+
+def _fused_bytes(t, tk, w, item, rows, bq, bk) -> int:
+    """VMEM the one-kernel backward holds for a row: q, dO, dq and k, v,
+    dk, dv whole and double-buffered, dq's float32 blocks, and a visit's
+    float32 scores with the three values derived from them."""
+    return (2 * (3 * t + 4 * tk) * w * item + t * w * 4
+            + 4 * rows * bk * bq * 4)
 
 
 def _fold_scale(scale: float, dtype) -> bool:
@@ -691,7 +758,9 @@ def _fwd_tiles(qp, kp, vp, *, rows, causal, scale, block_q, block_k,
 def _bwd_tiles(qp, kp, vp, dop, lse, delta, *, rows, causal, scale, block_q,
                block_k, interpret, walk_budget=_WALK_VMEM_BUDGET):
     """Backward on tile-layout operands -> (dq, dk, dv) in that layout;
-    ``lse`` / ``delta`` lane-dense ``[G, T/sw, rows, sw]``."""
+    ``lse`` / ``delta`` lane-dense ``[G, T/sw, rows, sw]``. One kernel where
+    both sides' plans make a row one grid step and the row fits
+    ``_FUSED_VMEM`` (the dk/dv call with dq as a third result), else two."""
     g, t, w = qp.shape
     tk = kp.shape[1]
     dh = w // rows
@@ -705,43 +774,55 @@ def _bwd_tiles(qp, kp, vp, dop, lse, delta, *, rows, causal, scale, block_q,
 
     tq, bq, kc, bk, static = _plan(t, tk, w, item, block_q, block_k,
                                    walk_budget)
-    n_chunks = tk // kc
-    _count_walk(n_chunks)
-    q_spec, kv_spec, stat_spec, _ = _side_specs(
-        tq, kc, n_chunks, w, rows, sw, causal=causal, walk_is_keys=True)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, bq=bq, bk=bk, n_chunks=n_chunks,
-                          static=static, **common),
-        name="dstpu_flash_bwd_dq",
-        grid=(g, t // tq, n_chunks),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
-        out_specs=q_spec,
-        out_shape=shp((g, t, w), qp.dtype),
-        scratch_shapes=([] if n_chunks == 1
-                        else [pltpu.VMEM((rows * bq, w), jnp.float32)]),
-        interpret=interp,
-        **kw,
-    )(qp, kp, vp, dop, lse, delta)
+    dkv_plan = _plan(tk, t, w, item, block_k, block_q, walk_budget)
+    fused = (static and dkv_plan[-1]
+             and _fused_bytes(t, tk, w, item, rows, bq, bk) <= _FUSED_VMEM)
+    _count_bwd(fused)
+    if not fused:
+        n_chunks = tk // kc
+        _count_walk(n_chunks)
+        q_spec, kv_spec, stat_spec, _ = _side_specs(
+            tq, kc, n_chunks, w, rows, sw, causal=causal, walk_is_keys=True)
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, bq=bq, bk=bk,
+                              n_chunks=n_chunks, static=static, **common),
+            name="dstpu_flash_bwd_dq",
+            grid=(g, t // tq, n_chunks),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, stat_spec, stat_spec],
+            out_specs=q_spec,
+            out_shape=shp((g, t, w), qp.dtype),
+            scratch_shapes=([] if n_chunks == 1
+                            else [pltpu.VMEM((rows * bq, w), jnp.float32)]),
+            interpret=interp,
+            **kw,
+        )(qp, kp, vp, dop, lse, delta)
 
-    tkt, bk, qc, bq, static = _plan(tk, t, w, item, block_k, block_q,
-                                    walk_budget)
+    tkt, bk, qc, bq, static = dkv_plan
     n_chunks = t // qc
     _count_walk(n_chunks)
     k_spec, q_spec, _, stat_spec = _side_specs(
         tkt, qc, n_chunks, w, rows, sw, causal=causal, walk_is_keys=False)
-    dk, dv = pl.pallas_call(
+    results = [(k_spec, shp((g, tk, w), kp.dtype)),
+               (k_spec, shp((g, tk, w), vp.dtype))]
+    if fused:   # the whole row of queries is this grid step's: so is its dq
+        results.append((q_spec, shp((g, t, w), qp.dtype)))
+    grads = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, bq=bq, bk=bk, n_chunks=n_chunks,
-                          static=static, **common),
+                          static=static, fused=fused, **common),
         name="dstpu_flash_bwd_dkv",
         grid=(g, tk // tkt, n_chunks),
         in_specs=[k_spec, k_spec, q_spec, q_spec, stat_spec, stat_spec],
-        out_specs=[k_spec, k_spec],
-        out_shape=[shp((g, tk, w), kp.dtype), shp((g, tk, w), vp.dtype)],
+        out_specs=[spec for spec, _ in results],
+        out_shape=[shape for _, shape in results],
         scratch_shapes=([] if n_chunks == 1
                         else [pltpu.VMEM((rows * bk, w), jnp.float32)] * 2),
         interpret=interp,
         **kw,
     )(kp, vp, qp, dop, lse, delta)
+    if fused:
+        dk, dv, dq = grads
+    else:
+        dk, dv = grads
     return dq, dk, dv
 
 

@@ -123,15 +123,23 @@ def test_flash_composes_with_tp_and_zero(tp, stage):
 
 
 # ----------------------------------------------- the tile program's choices
-def _walk_counts():
+def _flash_counts(*names):
     from deepspeed_tpu.telemetry.registry import get_registry
 
-    reg = get_registry()
-    return (reg.counter("flash/traced_short_seq").value,
-            reg.counter("flash/traced_grid_walk").value)
+    return tuple(get_registry().counter(name).value for name in names)
 
 
-def _flash_tiles(q, k, v, do, causal, block_q, block_k, walk_budget=None):
+def _walk_counts():
+    return _flash_counts("flash/traced_short_seq", "flash/traced_grid_walk")
+
+
+def _bwd_counts():
+    """(backwards traced as one kernel, as two)."""
+    return _flash_counts("flash/traced_bwd_fused", "flash/traced_bwd_split")
+
+
+def _flash_tiles(q, k, v, do, causal, block_q, block_k, walk_budget=None,
+                 scale=None):
     """Forward and backward through the internal tile-layout calls, the way
     ``flash_attention`` makes them, with the walk side's VMEM budget forced
     where a case wants the chunked walk at a tiny size. [B, T, H, Dh] in and
@@ -142,8 +150,8 @@ def _flash_tiles(q, k, v, do, causal, block_q, block_k, walk_budget=None):
     rows = fa._tile_rows(b * h, dh, t)
     qp, kp, vp, dop = (fa._pack(fa._reshape_bh(x), rows)
                        for x in (q, k, v, do))
-    kw = dict(rows=rows, causal=causal, scale=dh ** -0.5, block_q=block_q,
-              block_k=block_k, interpret=True)
+    kw = dict(rows=rows, causal=causal, scale=scale or dh ** -0.5,
+              block_q=block_q, block_k=block_k, interpret=True)
     if walk_budget is not None:
         kw["walk_budget"] = walk_budget
     outp, lse = fa._fwd_tiles(qp, kp, vp, **kw)
@@ -153,18 +161,23 @@ def _flash_tiles(q, k, v, do, causal, block_q, block_k, walk_budget=None):
             for x in (outp,) + tuple(grads)], rows
 
 
-def _dense_with_grads(q, k, v, do, causal):
+def _dense_with_grads(q, k, v, do, causal, scale=None):
+    """In float32, whatever the storage: what bf16 results are held to."""
+    f32 = lambda x: x.astype(jnp.float32)
     out, vjp = jax.vjp(
-        lambda q, k, v: multihead_attention(q, k, v, causal=causal), q, k, v)
-    return [out, *vjp(do)]
+        lambda q, k, v: multihead_attention(q, k, v, causal=causal,
+                                            scale=scale),
+        f32(q), f32(k), f32(v))
+    return [out, *vjp(f32(do))]
 
 
-def _assert_matches_dense(got, want):
-    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
-                               rtol=2e-5, atol=2e-5, err_msg="out")
+def _assert_matches_dense(got, want, out_tol=2e-5, grad_tol=2e-4):
+    f32 = lambda x: np.asarray(x, np.float32)
+    np.testing.assert_allclose(f32(got[0]), f32(want[0]), rtol=out_tol,
+                               atol=out_tol, err_msg="out")
     for name, a, b in zip(("dq", "dk", "dv"), got[1:], want[1:]):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
-                                   atol=2e-4, err_msg=name)
+        np.testing.assert_allclose(f32(a), f32(b), rtol=grad_tol,
+                                   atol=grad_tol, err_msg=name)
 
 
 # name, (b, tq, tk, h, dh), blocks, walk budget in bytes, rows a tile, walk
@@ -191,46 +204,95 @@ def test_tile_program_matches_dense(name, dims, blocks, budget, rows, walk,
     k, v, _ = qkv(b=b, t=tk, h=h, dh=dh, seed=6)
     do, _, _ = qkv(b=b, t=tq, h=h, dh=dh, seed=7)
     short0, grid0 = _walk_counts()
+    fused0, split0 = _bwd_counts()
     got, got_rows = _flash_tiles(q, k, v, do, causal, *blocks,
                                  walk_budget=budget)
     short1, grid1 = _walk_counts()
+    fused1, split1 = _bwd_counts()
     assert got_rows == rows
-    # three kernels a call, each says which walk it was traced with
+    # each kernel says which walk it was traced with: a row that is one grid
+    # step has the forward and ONE backward kernel, any other walk has two
     assert (short1 - short0, grid1 - grid0) == \
-        ((3, 0) if walk == "short" else (0, 3))
+        ((2, 0) if walk == "short" else (0, 3))
+    assert (fused1 - fused0, split1 - split0) == \
+        ((1, 0) if walk == "short" else (0, 1))
     _assert_matches_dense(got, _dense_with_grads(q, k, v, do, causal))
 
 
 @pytest.mark.parametrize("budget", [None, 8192], ids=["short", "grid"])
 def test_ring_hop_keys_longer_than_queries(budget):
-    """A ring hop: not causal, 64 queries against 128 keys."""
+    """A ring hop: not causal, 64 queries against 128 keys; down the one
+    backward kernel where the keys stay resident, down two in chunks."""
     q, _, do = qkv(b=2, t=64, h=3, dh=16, seed=8)
     k, v, _ = qkv(b=2, t=128, h=3, dh=16, seed=9)
+    fused0, split0 = _bwd_counts()
     got, rows = _flash_tiles(q, k, v, do, False, 32, 32, walk_budget=budget)
+    fused1, split1 = _bwd_counts()
     assert rows == 2
+    assert (fused1 - fused0, split1 - split0) == \
+        ((1, 0) if budget is None else (0, 1))
     _assert_matches_dense(got, _dense_with_grads(q, k, v, do, False))
 
 
+# name, storage, scale (None: head size ** -0.5, a power of two), p and ds in
+# float32 (DSTPU_FLASH_F32_PRECISE), tolerance against dense (out, gradients)
+WALK_STORAGE = [
+    ("f32", jnp.float32, None, False, (2e-5, 2e-4)),
+    # any scale is folded into a float32 tile; in bf16 0.3 multiplies the
+    # scores instead, and dq carries it at the end as dk does
+    ("f32_scale_0.3", jnp.float32, 0.3, False, (2e-5, 2e-4)),
+    ("bf16", jnp.bfloat16, None, False, (3e-2, 3e-2)),
+    ("bf16_precise", jnp.bfloat16, None, True, (3e-2, 3e-2)),
+    ("bf16_scale_0.3", jnp.bfloat16, 0.3, False, (3e-2, 3e-2)),
+]
+
+
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
-def test_all_walks_agree(causal, monkeypatch):
-    """The same inputs and blocks down the resident walk unrolled by hand,
-    down the resident walk as a fori_loop (a row of more blocks than are
-    unrolled) and down the chunked one: only the loop, and who carries the
-    softmax state, differ."""
+@pytest.mark.parametrize("name,dtype,scale,precise,dense_tol", WALK_STORAGE,
+                         ids=[c[0] for c in WALK_STORAGE])
+def test_all_walks_agree(name, dtype, scale, precise, dense_tol, causal,
+                         monkeypatch):
+    """The same inputs and blocks down the resident walk unrolled by hand
+    (one backward kernel for dq, dk and dv), down the same walk with the
+    backward split in two (the fused form refused), down the resident walk
+    as a fori_loop (a row of more blocks than are unrolled) and down the
+    chunked one: only the loop, who carries the softmax state, and the
+    order of dq's float32 sums differ."""
     from deepspeed_tpu.ops import flash_attention as fa
 
-    q, k, v = qkv(b=2, t=128, h=5, dh=16, seed=10)
-    do, _, _ = qkv(b=2, t=128, h=5, dh=16, seed=11)
-    unrolled, _ = _flash_tiles(q, k, v, do, causal, 32, 32)
-    chunked, _ = _flash_tiles(q, k, v, do, causal, 32, 32, walk_budget=4096)
+    if precise:
+        monkeypatch.setenv("DSTPU_FLASH_F32_PRECISE", "1")
+    q, k, v = qkv(b=2, t=128, h=5, dh=16, seed=10, dtype=dtype)
+    do, _, _ = qkv(b=2, t=128, h=5, dh=16, seed=11, dtype=dtype)
+    walk = lambda **kw: _flash_tiles(q, k, v, do, causal, 32, 32,
+                                     scale=scale, **kw)[0]
+    counts = [_bwd_counts()]
+    fused = walk()
+    counts.append(_bwd_counts())
+    chunked = walk(walk_budget=4096)
+    monkeypatch.setattr(fa, "_FUSED_VMEM", 0)    # no row fits: two kernels
+    split = walk()
     monkeypatch.setattr(fa, "_MAX_UNROLL", 4)    # the row has 16 blocks
     monkeypatch.setattr(fa, "_MAX_TILE", 64)     # and two tiles of queries
-    looped, _ = _flash_tiles(q, k, v, do, causal, 32, 32)
-    _assert_matches_dense(looped, _dense_with_grads(q, k, v, do, causal))
-    for other in (looped, chunked):
-        for name, a, b in zip(("out", "dq", "dk", "dv"), unrolled, other):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=1e-6, atol=1e-6, err_msg=name)
+    looped = walk()
+    counts.append(_bwd_counts())
+    assert [(b[0] - a[0], b[1] - a[1]) for a, b in zip(counts, counts[1:])] \
+        == [(1, 0), (0, 3)]
+    want = _dense_with_grads(q, k, v, do, causal, scale)
+    _assert_matches_dense(fused, want, *dense_tol)
+    _assert_matches_dense(looped, want, *dense_tol)
+    # the same visits in the same order: dk and dv are the split kernel's
+    for a, b in zip(fused[2:], split[2:]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # dq's float32 sums run in another order; in bf16 one rounding of a
+    # result is all any two walks may differ by
+    for other in (split, looped, chunked):
+        for name_, a, b in zip(("out", "dq", "dk", "dv"), fused, other):
+            tol = (2 ** -7 if dtype == jnp.bfloat16
+                   else 1e-5 if name_ == "dq" else 1e-6)
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32), np.asarray(b, np.float32),
+                rtol=tol, atol=tol, err_msg=name_)
 
 
 @pytest.mark.parametrize("b,h", [(2, 5), (1, 3)], ids=["paired", "one_row"])
@@ -245,9 +307,11 @@ def test_public_call_pairs_rows_over_batch_times_heads(b, h):
                                                  None, True))
     dense = loss(lambda q, k, v: multihead_attention(q, k, v, causal=True))
     short0, grid0 = _walk_counts()
+    fused0, split0 = _bwd_counts()
     g1 = jax.grad(flash, argnums=(0, 1, 2))(q, k, v)
     short1, grid1 = _walk_counts()
     assert short1 > short0 and grid1 == grid0
+    assert _bwd_counts() == (fused0 + 1, split0)
     g2 = jax.grad(dense, argnums=(0, 1, 2))(q, k, v)
     for name, a, b_ in zip("qkv", g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=2e-4,

@@ -74,10 +74,25 @@ def test_flash_forward(one_chip, shape):
     _compiled_text(_flash_loss, q, q, q)
 
 
+def _flash_backward_kernels(text):
+    """The backward kernels of a compiled text, by the names their call
+    sites give them."""
+    return set(re.findall(r"dstpu_flash_bwd_(?:dq|dkv)", text))
+
+
 @pytest.mark.parametrize("shape", FLASH_SHAPES, ids=str)
 def test_flash_backward(one_chip, shape):
+    """A row that is one grid step (sequence 2,048 and under: the first four
+    shapes, the train cells' ``(2, 1024, 20, 64)`` and ``(2, 1024, 25,
+    64)`` among them) lowers ONE backward kernel, the ``dstpu_flash_bwd_dkv``
+    site with dq as its third result; ``(2, 2048, 32, 128)``, sixteen blocks
+    at 128 lanes, is the largest such row and compiles under the scoped VMEM
+    default. The two long rows keep the dq and the dk/dv kernel."""
     q = _sds(one_chip, shape)
-    _compiled_text(jax.grad(_flash_loss, argnums=(0, 1, 2)), q, q, q)
+    text = _compiled_text(jax.grad(_flash_loss, argnums=(0, 1, 2)), q, q, q)
+    assert _flash_backward_kernels(text) == (
+        {"dstpu_flash_bwd_dkv"} if shape[1] <= 2048
+        else {"dstpu_flash_bwd_dq", "dstpu_flash_bwd_dkv"})
 
 
 # (L, B, Hq, Hkv, S, Dh): 125M serving at 8 slots (token-pair packed cache);
@@ -663,14 +678,19 @@ def _mesh_flash_loss(q, k, v):
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
 def test_flash_partitions_under_mesh(mesh_2x2, grad):
     """Bare, the kernel fails here with "Mosaic kernels cannot be
-    automatically partitioned"; ``sp_attention`` wraps it in shard_map."""
+    automatically partitioned"; ``sp_attention`` wraps it in shard_map.
+    A device's share, ``(4, 1024, 6, 64)``, is a row of one grid step: its
+    backward is the one fused kernel, whose three results carry the
+    operands' varying axes out of the shard_map."""
     from deepspeed_tpu.parallel.topology import BATCH_AXES, MODEL_AXIS
 
     sharding = NamedSharding(mesh_2x2, P(BATCH_AXES, None, MODEL_AXIS, None))
     q = _sds(sharding, (8, 1024, 12, 64))
     fn = jax.grad(_mesh_flash_loss, argnums=(0, 1, 2)) if grad \
         else _mesh_flash_loss
-    _compiled_text(fn, q, q, q)
+    text = _compiled_text(fn, q, q, q)
+    assert _flash_backward_kernels(text) == (
+        {"dstpu_flash_bwd_dkv"} if grad else set())
 
 
 def test_in_program_kv_cache_is_zero_filled(one_chip):

@@ -144,6 +144,8 @@ def test_ring_flash_matches_dense_forward(sp, causal):
 
 @pytest.mark.parametrize("causal", [True, False])
 def test_ring_flash_matches_dense_gradients(causal):
+    """The reverse ring's hops run the one-kernel backward, by their plan
+    and not by an accident of these sizes: the counters say so."""
     groups.reset()
     topo = build_topology(sp=4)
     q, k, v = qkv(seed=1)
@@ -154,7 +156,17 @@ def test_ring_flash_matches_dense_gradients(causal):
     def loss_dense(q, k, v):
         return jnp.sum(multihead_attention(q, k, v, causal=causal) ** 2)
 
+    from deepspeed_tpu.telemetry.registry import get_registry
+
+    counters = [get_registry().counter(name) for name in
+                ("flash/traced_bwd_fused", "flash/traced_bwd_split")]
+    before = [c.value for c in counters]
     g1 = jax.jit(jax.grad(loss_rf, argnums=(0, 1, 2)))(q, k, v)
+    # the reverse ring traces its own block's hop and the one its scan
+    # repeats: each a row of one grid step, so each hop's dq, dk and dv come
+    # from ONE kernel (ops/flash_attention._bwd_tiles), against the global
+    # lse and not causal behind hop 0
+    assert [c.value - b for c, b in zip(counters, before)] == [2, 0]
     g2 = jax.jit(jax.grad(loss_dense, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
