@@ -13,7 +13,31 @@ Public API parity with reference ``deepspeed/__init__.py``:
 from __future__ import annotations
 
 import argparse
+import re
 from typing import Any, Optional, Union
+
+import jax
+
+# written against this jax's own names (``jax.shard_map`` with ``check_vma``
+# and ``axis_names``, ``lax.pcast``, avals that carry ``vma``) with no layer
+# that translates to an older one's: the version the tests and the chip run
+MIN_JAX = (0, 9, 0)
+
+
+class UnsupportedJaxError(ImportError):
+    """The installed jax is older than the one this package is written for."""
+
+
+def require_jax(installed: str) -> None:
+    """Refuse a jax older than :data:`MIN_JAX`, here and by name, before an
+    import deeper in the package fails on a name that jax does not have."""
+    if tuple(map(int, re.findall(r"\d+", installed)[:3])) < MIN_JAX:
+        raise UnsupportedJaxError(
+            f"deepspeed_tpu needs jax >= {'.'.join(map(str, MIN_JAX))}, "
+            f"found {installed}")
+
+
+require_jax(jax.__version__)
 
 from deepspeed_tpu.accelerator import get_accelerator, set_accelerator
 from deepspeed_tpu import comm

@@ -18,8 +18,7 @@ by content hash with dependent-region invalidation;
 Every perf/robustness win since PR 2 rests on invariants the test suite
 can only probe dynamically and per-site: zero recompiles after warmup,
 no host synchronization inside engine hot loops except at declared
-fences, typed errors in the serving paths, and metric-name / jax_compat
-discipline.  This package makes those contracts *static*: one shared AST
+fences, typed errors in the serving paths, and metric-name discipline.  This package makes those contracts *static*: one shared AST
 walk over ``deepspeed_tpu/``, a registry of passes that each encode one
 contract, inline suppressions that require a written justification, and
 a committed baseline for grandfathered findings that may only burn down.

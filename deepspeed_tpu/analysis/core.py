@@ -468,8 +468,8 @@ def run_lint(root: str, *, pass_ids: Optional[Sequence[str]] = None,
     and the baseline.  ``pass_ids=None`` runs every registered pass;
     unused-directive reporting defaults to on only for full runs (a
     directive for a pass that was not selected is not stale).  Pass a
-    pre-built ``corpus`` to reuse already-parsed files (the CLI shares
-    one corpus between the lint and the jax-compat inventory).
+    pre-built ``corpus`` to reuse already-parsed files (the CLI builds
+    it once, for the file cache and the passes).
 
     ``file_cache`` (incremental mode, ISSUE 15): any object with
     ``lookup(ctx) -> Optional[List[Finding]]`` and ``store(ctx,

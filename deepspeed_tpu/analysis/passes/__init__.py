@@ -5,6 +5,5 @@ built-in pass.  To add a pass: new module here, subclass
 ``tests/unit/analysis/fixtures/`` (README "how to add a pass")."""
 
 from deepspeed_tpu.analysis.passes import (  # noqa: F401
-    donation, host_sync, jax_compat, metric_names, pallas_dma,
-    pallas_tile, recompile, sharding_contract, slo_rules, typed_errors,
-    vmem_budget)
+    donation, host_sync, metric_names, pallas_dma, pallas_tile, recompile,
+    sharding_contract, slo_rules, typed_errors, vmem_budget)

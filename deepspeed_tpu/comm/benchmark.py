@@ -26,10 +26,10 @@ def run_collective_bench(op: str = "all_reduce", sizes: List[int] = None,
                          trials: int = 10, dtype_str: str = "float32"):
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from deepspeed_tpu.parallel.topology import DATA_AXIS
-    from deepspeed_tpu.utils.jax_compat import shard_map
 
     devices = jax.devices()
     n = len(devices)

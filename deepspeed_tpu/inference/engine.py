@@ -357,7 +357,7 @@ class InferenceEngine:
         per-row dequant, models/base.embed_tokens) and the lm-head
         matmul (scale on the output logit column, base.tied_logits).
         At 125M the tied table is ~77 MB of the 249 MB int8 weight
-        stream (PROFILE_DECODE.md) — the last unquantized resident.
+        stream — the last unquantized resident.
         Requires the model to route wte through the quant-aware helpers
         (``supports_embedding_quant``); fails loudly otherwise, exactly
         like the block-weight support check."""

@@ -53,9 +53,8 @@ def _partitioned_flash_attention(q, k, v, causal: bool):
     if (mesh is None or mesh.size == 1
             or jax.sharding.get_abstract_mesh().manual_axes):
         return flash_attention(q, k, v, causal)
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from deepspeed_tpu.utils.jax_compat import has_vma_typing, shard_map
 
     def axes_dividing(dim, axes):
         n = 1
@@ -68,10 +67,9 @@ def _partitioned_flash_attention(q, k, v, causal: bool):
     # strict vma checking for compiled TPU runs only: the interpreter cannot
     # type kernel-internal literals against varying refs (the same idiom as
     # ops/ring_attention.ulysses_attention)
-    strict = not _interpret_default() and has_vma_typing()
     return shard_map(lambda ql, kl, vl: flash_attention(ql, kl, vl, causal),
                      mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                     check_vma=strict)(q, k, v)
+                     check_vma=not _interpret_default())(q, k, v)
 
 
 def sp_attention(attn_impl: str, q, k, v, *, causal: bool = True):

@@ -30,14 +30,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import (cache_positions, cross_entropy_loss, gathered_top, merge_heads,
-                                       project_heads, qdot, rms_norm)
-from deepspeed_tpu.models.stack import cached_walk, next_cache, prompt_walk, walk, wrapped_block
+from deepspeed_tpu.models.base import cache_positions, gathered_top, merge_heads, project_heads, qdot, rms_norm
+from deepspeed_tpu.models.stack import StackedDecoder, cached_walk, next_cache, prompt_walk, walk, wrapped_block
 from deepspeed_tpu.ops import eva
 from deepspeed_tpu.ops.rotary import apply_rotary_half
 
@@ -90,34 +88,26 @@ class EvaByteConfig:
         return cls(**{**sizes, **kw})
 
 
-class EvaByteModel:
-    """Causal-LM ModelSpec: batch = {"input_ids": [B,T], "labels": [B,T]}."""
+class EvaByteModel(StackedDecoder):
+    """One stack of layers of one kind, walked as the scan's input; of the
+    decoder's frame (models/stack.StackedDecoder) the constructor, the loss
+    and the engines' contract: the stream, the norms, the head and what a step
+    counts are this family's own."""
 
-    supports_weight_quant = False
+    stacks = ("blocks",)
     # per-slot state, in operand order. None is a row a token: the serving
-    # engine refuses prefix reuse, speculation, swap and kv_dtype by this list
+    # engine refuses prefix reuse, speculation, swap and kv_dtype by this
+    # list, and what it counts by a request's length
+    # (``serving/decode_rows_*``) is not what a step moves here: the step
+    # counts its own (:data:`STEP_COUNTERS`)
     slot_state_keys = ("k_win", "v_win", "k_sum", "v_sum")
+    row_state_keys = ()
     # the window that starts over, and the summary rows behind it
     # (serving/kv_slots.py)
     restart_window_keys = ("k_win", "v_win")
     summary_state_keys = ("k_sum", "v_sum")
     step_counters = STEP_COUNTERS
-
-    def __init__(self, config: EvaByteConfig, compute_dtype=jnp.bfloat16,
-                 param_dtype=jnp.float32, remat: bool = False,
-                 remat_policy: Optional[str] = None):
-        self.config = config
-        self.compute_dtype = compute_dtype
-        self.param_dtype = param_dtype
-        self.remat = remat
-        self.remat_policy = remat_policy
-
-    @staticmethod
-    def fused_row_walk(state, num_slots: int) -> bool:
-        """No leaf of this cache is a row a token: what the engine counts by
-        a request's length (``serving/decode_rows_*``) is not what a step
-        moves here. The step counts its own (:data:`STEP_COUNTERS`)."""
-        return False
+    prompt_counters = ()
 
     @staticmethod
     def record_step_counters(telemetry, counts) -> None:
@@ -330,14 +320,6 @@ class EvaByteModel:
             "btd,dv->btv", hidden,
             params["lm_head"][:, :v].astype(hidden.dtype),
             preferred_element_type=jnp.float32)
-
-    def apply(self, params, batch, *, rngs=None, train: bool = False):
-        hidden = self.forward_hidden(params, batch["input_ids"], rngs=rngs,
-                                     train=train)
-        loss, n = cross_entropy_loss(
-            self.logits(gathered_top(params, "blocks"), hidden),
-            batch["labels"])
-        return loss, {"loss": loss, "ntokens": n}
 
     # ------------------------------------------------------- inference path
     def init_cache(self, batch_size: int, max_len: int, dtype=None):

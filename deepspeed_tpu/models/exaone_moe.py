@@ -28,17 +28,20 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import (cache_positions, cross_entropy_loss, gathered_top, merge_heads,
-                                       project_heads, rms_norm, whole_leaves)
-from deepspeed_tpu.models.moe_ffn import (DENSE, EXPERT_LEAVES, PROMPT_COUNTERS, SPARSE, STEP_COUNTERS,
-                                          carried_counts, gated_axes, gated_init, record_prompt_counters,
-                                          record_step_counters, zero_counts)
+from deepspeed_tpu.models.base import cache_positions, merge_heads, project_heads, rms_norm
+from deepspeed_tpu.models.moe_ffn import (DENSE, EXPERT_LEAVES, SPARSE, carried_counts, gated_axes, gated_init,
+                                          zero_counts)
 from deepspeed_tpu.models.moe_ffn import ffn as ffn_layer
-from deepspeed_tpu.models.stack import cached_walk, kv_cache, next_cache, walk, wrapped_block
+from deepspeed_tpu.models.stack import StackedDecoder, kv_cache, next_cache
 from deepspeed_tpu.ops.attention import cached_attention, multihead_attention, window_cached_attention
 from deepspeed_tpu.ops.rotary import apply_rotary_half
 
 SLIDING, GLOBAL = "sliding_attention", "full_attention"
+# a kind of layer is its (FFN kind, attention kind): the FFN kind's stack, and
+# the attention kind's cache leaves (models/stack.runs_of)
+KINDS = {(ffn, attn): (ffn, leaves) for ffn in (DENSE, SPARSE)
+         for attn, leaves in ((GLOBAL, ("k", "v")),
+                              (SLIDING, ("k_win", "v_win")))}
 
 
 @dataclasses.dataclass
@@ -111,20 +114,6 @@ class ExaoneMoeConfig:
     def count(self, kind: str) -> int:
         return sum(t == kind for t in self.layer_types + self.mlp_layer_types)
 
-    def runs(self):
-        """Runs of layers of equal (FFN kind, attention kind) as ``(ffn,
-        attention, first index in the FFN kind's stacked tree, first index in
-        the attention kind's cache leaves, count)``, in stack order."""
-        out, seen = [], {DENSE: 0, SPARSE: 0, SLIDING: 0, GLOBAL: 0}
-        for attn, ffn in zip(self.layer_types, self.mlp_layer_types):
-            if out and out[-1][:2] == [ffn, attn]:
-                out[-1][4] += 1
-            else:
-                out.append([ffn, attn, seen[ffn], seen[attn], 1])
-            seen[ffn] += 1
-            seen[attn] += 1
-        return tuple(tuple(r) for r in out)
-
     @classmethod
     def tiny(cls, **kw):
         kw.setdefault("layer_types", (SLIDING, SLIDING, GLOBAL, SLIDING))
@@ -138,10 +127,15 @@ class ExaoneMoeConfig:
                    intermediate_size=128, moe_intermediate_size=32, **kw)
 
 
-class ExaoneMoeModel:
-    """Causal-LM ModelSpec: batch = {"input_ids": [B,T], "labels": [B,T]}."""
+class ExaoneMoeModel(StackedDecoder):
+    """Layers of four kinds in runs of equal pairs: the stacked weights are
+    indexed by FFN kind, the cache by attention kind
+    (models/stack.StackedDecoder)."""
 
-    supports_weight_quant = False
+    stacks = (DENSE, SPARSE)
+    kinds = KINDS
+    # the expert stacks, for the grouped matmul to address by group
+    whole = EXPERT_LEAVES
     # per-slot state, in operand order: rows that grow with the request on
     # the global layers, rings of the window on the sliding layers. Leaves
     # other than k, v are not addressed by token rows: the serving engine
@@ -149,20 +143,14 @@ class ExaoneMoeModel:
     slot_state_keys = ("k", "v", "k_win", "v_win")
     # ring leaves and the window they hold: SlotKVCache counts their rows
     window_state_keys = ("k_win", "v_win")
-    step_counters = STEP_COUNTERS
-    prompt_counters = PROMPT_COUNTERS
-    record_prompt_counters = staticmethod(record_prompt_counters)
 
-    def __init__(self, config: ExaoneMoeConfig, compute_dtype=jnp.bfloat16,
-                 param_dtype=jnp.float32, remat: bool = False,
-                 remat_policy: Optional[str] = None):
-        self.config = config
-        self.compute_dtype = compute_dtype
-        self.param_dtype = param_dtype
-        self.remat = remat
-        self.remat_policy = remat_policy
+    def layer_kinds(self):
+        c = self.config
+        return tuple(zip(c.mlp_layer_types, c.layer_types))
 
-    record_step_counters = staticmethod(record_step_counters)
+    def _block_of(self, kind, shift, walk_, step):
+        return functools.partial(self._block, walk_=walk_, ffn=kind[0],
+                                 attn=kind[1], shift=shift)
 
     # ----------------------------------------------------------------- init
     def init(self, rng):
@@ -276,39 +264,6 @@ class ExaoneMoeModel:
         y, n = ffn_layer(z, blk, ffn, tokens, c)
         return x + y, (None if state is None else (kc, vc, counts + n))
 
-    @staticmethod
-    def _stack(params, ffn: str):
-        """The stacked layers of one FFN kind as the walk takes them: the
-        expert stacks whole, for the grouped matmul to address by group."""
-        return whole_leaves(params[ffn], *EXPERT_LEAVES)
-
-    # -------------------------------------------------------------- forward
-    def forward_hidden(self, params, input_ids, *, rngs=None,
-                       train: bool = False):
-        c = self.config
-        top = gathered_top(params, DENSE, SPARSE)
-        x = top["embed"].astype(self.compute_dtype)[input_ids]
-        for ffn, attn, first, _, count in c.runs():
-            block_fn = wrapped_block(
-                lambda x, blk, ffn=ffn, attn=attn: self._block(
-                    x, blk, None, None, None, None, None, ffn=ffn,
-                    attn=attn)[0],
-                ffn, self.remat, self.remat_policy)
-            x = walk(block_fn, x, self._stack(params, ffn), run=(first, count))
-        return rms_norm(x, top["final_norm"], c.eps)
-
-    def logits(self, params, hidden):
-        return jnp.einsum("btd,dv->btv", hidden,
-                          params["lm_head"].astype(hidden.dtype))
-
-    def apply(self, params, batch, *, rngs=None, train: bool = False):
-        hidden = self.forward_hidden(params, batch["input_ids"], rngs=rngs,
-                                     train=train)
-        head = gathered_top(params, DENSE, SPARSE)
-        loss, n = cross_entropy_loss(self.logits(head, hidden),
-                                     batch["labels"])
-        return loss, {"loss": loss, "ntokens": n}
-
     # ------------------------------------------------------- inference path
     def init_cache(self, batch_size: int, max_len: int, dtype=None):
         """``k``, ``v`` over the global layers at ``max_len`` rows; ``k_win``,
@@ -330,29 +285,20 @@ class ExaoneMoeModel:
         REAL positions, and a position that is not real is routed to no
         expert. ``cache["slot_walk"]`` is the decode program's walk order
         for the fused decode step of both kinds of layer. The returned cache
-        carries ``step_counters`` (:data:`STEP_COUNTERS`), summed over the
-        sparse layers."""
-        c = self.config
+        carries ``step_counters`` (models/moe_ffn.STEP_COUNTERS), summed over
+        the sparse layers. Not the shared frame's step: a prompt passes whole,
+        whatever its length, and every position's logits come back (ROADMAP.md,
+        D16)."""
         b, t = input_ids.shape
-        idx = cache["index"]
         valid = cache.get("valid_len")
         if valid is not None:
             valid = jnp.broadcast_to(jnp.asarray(valid, jnp.int32), (b,))
-        x = params["embed"].astype(self.compute_dtype)[input_ids]
-        leaves = {GLOBAL: (cache["k"], cache["v"]),
-                  SLIDING: (cache["k_win"], cache["v_win"])}
-        counts = zero_counts(t)
-        for ffn, attn, first, first_cache, count in c.runs():
-            block = functools.partial(self._block, ffn=ffn, attn=attn,
-                                      shift=first_cache - first)
-            x, (kc, vc, counts) = cached_walk(
-                block, x, self._stack(params, ffn), (*leaves[attn], counts),
-                idx, valid,
-                cache.get("slot_walk"), first=first, count=count)
-            leaves[attn] = (kc, vc)
-        hidden = rms_norm(x, params["final_norm"], c.eps)
-        out = next_cache(cache, t, k=leaves[GLOBAL][0], v=leaves[GLOBAL][1],
-                         k_win=leaves[SLIDING][0], v_win=leaves[SLIDING][1])
+        x, leaves, counts = self._layers(
+            params, self._embed(params, input_ids),
+            tuple(cache[k] for k in self.slot_state_keys), zero_counts(t),
+            cache["index"], valid, cache.get("slot_walk"))
+        hidden = self._norm(x, params["final_norm"])
+        out = next_cache(cache, t, **dict(zip(self.slot_state_keys, leaves)))
         out.update(carried_counts(cache, counts))
         return self.logits(params, hidden), out
 

@@ -62,13 +62,11 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import (cross_entropy_loss, gathered_top, merge_heads, project_heads, qdot, rms_norm,
-                                       whole_leaves)
+from deepspeed_tpu.models.base import merge_heads, project_heads, qdot, rms_norm
 from deepspeed_tpu.models.mla import LatentAttention, latent_row_width
-from deepspeed_tpu.models.moe_ffn import (DENSE, EXPERT_LEAVES, PROMPT_COUNTERS, SPARSE, STEP_COUNTERS,
-                                          carried_counts, ffn, gated_axes, gated_init, record_prompt_counters,
-                                          record_step_counters, zero_counts)
-from deepspeed_tpu.models.stack import cached_walk, next_cache, prompt_walk, walk, wrapped_block
+from deepspeed_tpu.models.moe_ffn import (DENSE, EXPERT_LEAVES, SPARSE, ffn, gated_axes, gated_init,
+                                          record_step_counters)
+from deepspeed_tpu.models.stack import runs_of
 from deepspeed_tpu.ops import gdn
 from deepspeed_tpu.ops.rotary import apply_rotary_pairs_freqs, yarn_inv_freq, yarn_mscale
 from deepspeed_tpu.ops.ssm import causal_conv, slot_order
@@ -77,6 +75,10 @@ GDN, MLA = "gdn", "mla"
 # the stacks: a layer's mixer and its FFN
 KINDS = {f"{mixer}_{kind}": (mixer, kind)
          for mixer in (GDN, MLA) for kind in (DENSE, SPARSE)}
+# a kind of layer is its stack, counted in its mixer's cache leaves
+# (models/stack.runs_of)
+LAYERS = {name: (name, ("latent",) if mixer == MLA else ("gdn", "gdn_conv"))
+          for name, (mixer, _) in KINDS.items()}
 
 
 @dataclasses.dataclass
@@ -190,16 +192,7 @@ class GigaChat35Config:
     def runs(self) -> Tuple[Tuple[str, int, int, int], ...]:
         """Runs of equal layers as ``(stack, first index in that stack, first
         index in the mixer's cache leaves, count)``, in stack order."""
-        out, in_stack, in_cache = [], dict.fromkeys(KINDS, 0), {GDN: 0, MLA: 0}
-        for kind in self.layer_kinds():
-            mixer = KINDS[kind][0]
-            if out and out[-1][0] == kind:
-                out[-1][3] += 1
-            else:
-                out.append([kind, in_stack[kind], in_cache[mixer], 1])
-            in_stack[kind] += 1
-            in_cache[mixer] += 1
-        return tuple(tuple(r) for r in out)
+        return runs_of(self.layer_kinds(), LAYERS)
 
     @classmethod
     def tiny(cls, **kw):
@@ -226,29 +219,29 @@ def _inv_softplus(x):
 
 
 class GigaChat35Model(LatentAttention):
-    """Causal-LM ModelSpec: batch = {"input_ids": [B,T], "labels": [B,T]}."""
+    """Layers of four kinds, a stack each; a mixer's layers share its cache
+    leaves (models/stack.StackedDecoder)."""
 
-    supports_weight_quant = False
+    stacks = tuple(KINDS)
+    kinds = LAYERS
+    # the expert stacks, for the grouped matmul to address by group, and
+    # ``wkv_b``, for the prompt kernel to address by layer and head
+    # (models/sarvam_mla.py)
+    whole = (*EXPERT_LEAVES, "wkv_b")
     # per-slot state, in operand order: the latent layers' token rows, the
-    # delta rule's state and the convolution's tails on the others
+    # delta rule's state (``state_dtype``: 4.19 MB a layer a slot at the
+    # published sizes) and the convolution's tails (in the compute dtype) on
+    # the others
     slot_state_keys = ("latent", "gdn", "gdn_conv")
-    row_state_keys = ("latent",)
-    step_counters = STEP_COUNTERS
-    prompt_counters = PROMPT_COUNTERS
-    record_prompt_counters = staticmethod(record_prompt_counters)
-    # the state adds thousands of rank-one corrections to a decaying sum:
-    # float32 whatever the compute dtype (4.19 MB a layer a slot at the
-    # published sizes; the tails are in the compute dtype)
-    state_dtype = jnp.float32
 
-    def __init__(self, config: GigaChat35Config, compute_dtype=jnp.bfloat16,
-                 param_dtype=jnp.float32, remat: bool = False,
-                 remat_policy: Optional[str] = None):
-        self.config = config
-        self.compute_dtype = compute_dtype
-        self.param_dtype = param_dtype
-        self.remat = remat
-        self.remat_policy = remat_policy
+    def layer_kinds(self):
+        return self.config.layer_kinds()
+
+    def _block_of(self, name, shift, walk_, step):
+        mixer, kind = KINDS[name]
+        return functools.partial(
+            self._block, extra=step if mixer == GDN else walk_, mixer=mixer,
+            kind=kind, shift=shift)
 
     @staticmethod
     def record_step_counters(telemetry, counts) -> None:
@@ -540,43 +533,6 @@ class GigaChat35Model(LatentAttention):
         out = h + self._norm(y, blk["mlp_post_norm"])
         return out, (None if state is None else (*leaves, counts + n))
 
-    @staticmethod
-    def _stack(params, name: str):
-        """The stacked layers of one kind as the walk takes them: the expert
-        stacks whole, for the grouped matmul to address by group, and
-        ``wkv_b`` whole, for the prompt kernel to address by layer and head
-        (models/sarvam_mla.py)."""
-        return whole_leaves(params[name], *EXPERT_LEAVES, "wkv_b")
-
-    # -------------------------------------------------------------- forward
-    def forward_hidden(self, params, input_ids, *, rngs=None,
-                       train: bool = False):
-        c = self.config
-        top = gathered_top(params, *KINDS)
-        x = top["embed"].astype(self.compute_dtype)[input_ids]
-        for name, first, _, count in c.runs():
-            mixer, kind = KINDS[name]
-            block_fn = wrapped_block(
-                lambda x, blk, mixer=mixer, kind=kind: self._block(
-                    x, blk, None, None, None, None, None, mixer=mixer,
-                    kind=kind)[0],
-                name, self.remat, self.remat_policy)
-            x = walk(block_fn, x, self._stack(params, name),
-                     run=(first, count))
-        return self._norm(x, top["final_norm"])
-
-    def logits(self, params, hidden):
-        return jnp.einsum("btd,dv->btv", hidden,
-                          params["lm_head"].astype(hidden.dtype))
-
-    def apply(self, params, batch, *, rngs=None, train: bool = False):
-        hidden = self.forward_hidden(params, batch["input_ids"], rngs=rngs,
-                                     train=train)
-        head = gathered_top(params, *KINDS)
-        loss, n = cross_entropy_loss(self.logits(head, hidden),
-                                     batch["labels"])
-        return loss, {"loss": loss, "ntokens": n}
-
     # ------------------------------------------------------- inference path
     def init_cache(self, batch_size: int, max_len: int, dtype=None):
         """One tree for both kinds of per-request state: ``latent`` rows over
@@ -596,51 +552,6 @@ class GigaChat35Model(LatentAttention):
             dtype)
         return dict(self._latent_cache(c.count(MLA), batch_size, max_len,
                                        dtype), gdn=state, gdn_conv=tail)
-
-    def _layers(self, params, x, leaves, counts, idx, valid, walk_):
-        """``x`` through the stack against the cache's leaves ``(latent, gdn,
-        gdn_conv)`` -> ``(x, leaves, counts)``."""
-        latent, state, tail = leaves
-        b, t = x.shape[:2]
-        step = self._decode_step(params, valid, b) if t == 1 else None
-        for name, first, at, count in self.config.runs():
-            mixer, kind = KINDS[name]
-            block = functools.partial(self._block, mixer=mixer, kind=kind,
-                                      shift=at - first)
-            if mixer == GDN:
-                x, (state, tail, counts) = cached_walk(
-                    block, x, self._stack(params, name),
-                    (state, tail, counts), idx, valid, step, first=first,
-                    count=count)
-            else:
-                x, (latent, counts) = cached_walk(
-                    block, x, self._stack(params, name), (latent, counts),
-                    idx, valid, walk_, first=first, count=count)
-        return x, (latent, state, tail), counts
-
-    def forward_with_cache(self, params, input_ids, cache):
-        """Prefill (T > 1) or decode (T == 1) against the cache tree.
-        ``cache["index"]`` is a scalar or a per-slot ``[B]`` vector;
-        ``cache["valid_len"]`` (scalar or ``[B]``) how many of the block's
-        positions are real for each row: the recurrent state and the tails
-        stop there (a row with 0 valid positions keeps both), a position
-        that is not real is routed to no expert, and the latent rows it
-        writes lie behind the length and are dead; ``cache["slot_walk"]`` the
-        decode program's walk order for the latent layers' fused step. With
-        ``valid_len`` a prompt block's logits are those of each row's last
-        real position alone, ``[B, 1, V]``. The returned cache carries
-        ``step_counters`` (models/moe_ffn.STEP_COUNTERS)."""
-        c = self.config
-        x, leaves, counts = prompt_walk(
-            functools.partial(self._layers, params),
-            params["embed"].astype(self.compute_dtype), input_ids,
-            tuple(cache[k] for k in self.slot_state_keys),
-            zero_counts(input_ids.shape[1]), cache, c.prompt_block)
-        hidden = self._norm(x, params["final_norm"])
-        out = next_cache(cache, input_ids.shape[1],
-                         **dict(zip(self.slot_state_keys, leaves)))
-        out.update(carried_counts(cache, counts))
-        return self.logits(params, hidden), out
 
     def num_params(self) -> int:
         """Parameters held here: ``held[1]`` of the experts a sparse layer."""

@@ -18,14 +18,14 @@ attention layers another, and the forward walks runs of equal layers
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Sequence, Tuple
+import functools
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import (cross_entropy_loss, gathered_top, merge_heads, project_heads, qdot, rms_norm,
-                                       tied_logits)
-from deepspeed_tpu.models.stack import cached_walk, kv_cache, next_cache, walk, wrapped_block
+from deepspeed_tpu.models.base import merge_heads, project_heads, qdot, rms_norm, tied_logits
+from deepspeed_tpu.models.stack import StackedDecoder, kv_cache, next_cache
 from deepspeed_tpu.ops import ssm
 from deepspeed_tpu.ops.attention import cached_attention, multihead_attention
 
@@ -102,18 +102,6 @@ class GraniteHybridConfig:
     def count(self, kind: str) -> int:
         return sum(t == kind for t in self.layer_types)
 
-    def runs(self) -> Tuple[Tuple[str, int, int], ...]:
-        """Runs of equal layers as ``(kind, first index in that kind's
-        stacked tree, count)``, in stack order."""
-        out, seen = [], {MAMBA: 0, ATTENTION: 0}
-        for kind in self.layer_types:
-            if out and out[-1][0] == kind:
-                out[-1][2] += 1
-            else:
-                out.append([kind, seen[kind], 1])
-            seen[kind] += 1
-        return tuple(tuple(r) for r in out)
-
     @classmethod
     def tiny(cls, **kw):
         kw.setdefault("layer_types", (MAMBA, MAMBA, ATTENTION, MAMBA))
@@ -128,29 +116,27 @@ def _inv_softplus(x):
     return x + jnp.log(-jnp.expm1(-x))
 
 
-class GraniteHybridModel:
-    """Causal-LM ModelSpec: batch = {"input_ids": [B,T], "labels": [B,T]}."""
+class GraniteHybridModel(StackedDecoder):
+    """Layers of two kinds, a stack and a pair of cache leaves each
+    (models/stack.StackedDecoder); the embedding is scaled, the head is the
+    embedding's, scaled, and no step counts anything."""
 
-    supports_weight_quant = False
+    stacks = (MAMBA, ATTENTION)
+    kinds = {MAMBA: (MAMBA, ("ssm", "conv")), ATTENTION: (ATTENTION, ("k", "v"))}
     # the per-slot state the serving cache holds, in operand order: key-value
     # rows on the attention layers, recurrent state on the Mamba layers
+    # (``state_dtype``: 75.5 MB a slot at the published sizes; the
+    # convolution's tail is in the compute dtype)
     slot_state_keys = ("k", "v", "ssm", "conv")
+    step_counters = prompt_counters = ()
 
-    # the recurrent state adds thousands of small terms to a slowly decaying
-    # sum: float32 whatever the compute dtype (75.5 MB a slot at the
-    # published sizes; the convolution's tail is in the compute dtype)
-    state_dtype = jnp.float32
+    def layer_kinds(self):
+        return self.config.layer_types
 
-    def __init__(self, config: GraniteHybridConfig,
-                 compute_dtype=jnp.bfloat16, param_dtype=jnp.float32,
-                 remat: bool = False, remat_policy: Optional[str] = None):
-        self.config = config
-        self.compute_dtype = compute_dtype
-        # what init() draws the matrices in: float32 master weights for
-        # training, the checkpoint's bfloat16 where only serving follows
-        self.param_dtype = param_dtype
-        self.remat = remat
-        self.remat_policy = remat_policy
+    def _block_of(self, kind, shift, walk_, step):
+        return functools.partial(self._mamba_layer, step=step) \
+            if kind == MAMBA else functools.partial(self._attn_layer,
+                                                    walk_=walk_)
 
     # ----------------------------------------------------------------- init
     def init(self, rng):
@@ -243,8 +229,9 @@ class GraniteHybridModel:
     def _mamba_layer(self, x, blk, state=None, layer=None, idx=None, valid=None,
                      step=None):
         """-> ``(x, state)``. ``state``: ``None`` (no cache: zeros in, nothing
-        out) or ``(ssm_full [Lm,B,H,P,N], conv_full [Lm,B,...])`` at
-        ``layer``, ``idx`` and the rows' ``valid`` lengths. One token (``T ==
+        out) or ``(ssm_full [Lm,B,H,P,N], conv_full [Lm,B,...], counts)`` at
+        ``layer``, ``idx`` and the rows' ``valid`` lengths (``counts``: the
+        walk's, handed on as it came). One token (``T ==
         1``) with a cache runs the recurrence in place on the stacked state,
         with ``step`` (:meth:`_decode_step`) what the step's Mamba layers
         share; where the shapes fold, everything between the two matmuls is
@@ -262,12 +249,13 @@ class GraniteHybridModel:
         if step is not None:
             ssm.count_step(folded)
         if folded:
+            *state, counts = state
             y, *state = ssm.mamba_step(
                 zxbcdt[:, 0], *state, layer, step["weights"], step["walk"],
                 step["active"], eps=c.eps)
             x = x + c.residual_multiplier * qdot("bte,ed->btd", y[:, None],
                                                  blk["out_proj"])
-            return self._mlp(x, blk), tuple(state)
+            return self._mlp(x, blk), (*state, counts)
         z, xbc, dt = jnp.split(zxbcdt, [d_in, d_in + c.conv_dim], axis=-1)
         dt = jax.nn.softplus(dt.astype(jnp.float32)
                              + blk["dt_bias"].astype(jnp.float32))
@@ -276,7 +264,7 @@ class GraniteHybridModel:
         if state is None:
             conv0 = jnp.zeros((b, c.mamba_d_conv - 1, c.conv_dim), x.dtype)
         else:
-            ssm_full, conv_full = state
+            ssm_full, conv_full, counts = state
             conv0 = ssm.rows_to_tail(
                 jax.lax.dynamic_index_in_dim(conv_full, layer, 0, False),
                 c.mamba_d_conv, d_in, 2 * g * n)
@@ -312,7 +300,7 @@ class GraniteHybridModel:
         y = rms_norm(y, blk["gate_norm"], c.eps).astype(x.dtype)
         x = x + c.residual_multiplier * qdot("bte,ed->btd", y, blk["out_proj"])
         x = self._mlp(x, blk)
-        return x, (None if state is None else (ssm_full, conv_full))
+        return x, (None if state is None else (ssm_full, conv_full, counts))
 
     def _decode_step(self, params, valid, b):
         """What the Mamba layers of one decode step (one token a slot, a
@@ -330,12 +318,14 @@ class GraniteHybridModel:
                 "weights": ssm.fold_weights(params[MAMBA], c.mamba_d_head)
                 if folds else None}
 
-    def _attn_layer(self, x, blk, cache=None, layer=None, idx=None,
-                    active=None):
+    def _attn_layer(self, x, blk, state=None, layer=None, idx=None,
+                    valid=None, walk_=None):
         """No rotary and no position term; softmax of
-        ``q k^T * attention_multiplier``. ``cache``: ``None`` or
-        ``(k_full, v_full)`` at ``layer`` and ``idx``; ``active``: the
-        decode program's ``cache["slot_walk"]``. -> ``(x, cache)``."""
+        ``q k^T * attention_multiplier``. ``state``: ``None`` or
+        ``(k_full, v_full, counts)`` at ``layer`` and ``idx``; ``walk_``: the
+        decode program's ``cache["slot_walk"]``. Key-value rows need no
+        ``valid``: padding is causally invisible and masked by the lengths.
+        -> ``(x, state)``."""
         c = self.config
         b, t, _ = x.shape
         hq, hkv, dh = c.num_heads, c.num_kv_heads, c.head_dim
@@ -343,18 +333,19 @@ class GraniteHybridModel:
         q = project_heads(y, blk["wq"], hq, dh)
         k_ = project_heads(y, blk["wk"], hkv, dh)
         v_ = project_heads(y, blk["wv"], hkv, dh)
-        if cache is None:
+        if state is None:
             rep = hq // hkv
             attn = multihead_attention(
                 q, jnp.repeat(k_, rep, axis=2), jnp.repeat(v_, rep, axis=2),
                 causal=True, scale=c.attention_multiplier)
         else:
-            attn, kc, vc = cached_attention(q, *cache, k_, v_, layer, idx,
+            kc, vc, counts = state
+            attn, kc, vc = cached_attention(q, kc, vc, k_, v_, layer, idx,
                                             scale=c.attention_multiplier,
-                                            active=active)
-            cache = (kc, vc)
+                                            active=walk_)
+            state = (kc, vc, counts)
         x = x + c.residual_multiplier * merge_heads(attn, blk["wo"])
-        return self._mlp(x, blk), cache
+        return self._mlp(x, blk), state
 
     # -------------------------------------------------------------- forward
     def _embed(self, params, input_ids):
@@ -362,30 +353,9 @@ class GraniteHybridModel:
                 * jnp.asarray(self.config.embedding_multiplier,
                               self.compute_dtype))
 
-    def forward_hidden(self, params, input_ids, *, rngs=None,
-                       train: bool = False):
-        c = self.config
-        top = gathered_top(params, MAMBA, ATTENTION)
-        x = self._embed(top, input_ids)
-        for kind, first, count in c.runs():
-            block = self._mamba_layer if kind == MAMBA else self._attn_layer
-            block_fn = wrapped_block(
-                lambda x, blk, block=block: block(x, blk)[0], kind,
-                self.remat, self.remat_policy)
-            x = walk(block_fn, x, params[kind], run=(first, count))
-        return rms_norm(x, top["final_norm"], c.eps)
-
     def logits(self, params, hidden):
         out = tied_logits(hidden, params["embed"])
         return out / jnp.asarray(self.config.logits_scaling, out.dtype)
-
-    def apply(self, params, batch, *, rngs=None, train: bool = False):
-        hidden = self.forward_hidden(params, batch["input_ids"], rngs=rngs,
-                                     train=train)
-        head = gathered_top(params, MAMBA, ATTENTION)
-        loss, n = cross_entropy_loss(self.logits(head, hidden),
-                                     batch["labels"])
-        return loss, {"loss": loss, "ntokens": n}
 
     # ------------------------------------------------------- inference path
     def init_cache(self, batch_size: int, max_len: int, dtype=None):
@@ -418,28 +388,20 @@ class GraniteHybridModel:
         ``valid_len``: a row with 0 valid positions keeps its state. The
         decode program's ``cache["slot_walk"]`` says the same to the fused
         decode step of the attention layers (ops/attention.cached_attention,
-        ``active``): it skips the rows of a slot that is not decoding."""
-        c = self.config
+        ``active``): it skips the rows of a slot that is not decoding. Not
+        the shared frame's step: a prompt passes whole, whatever its length,
+        and every position's logits come back (ROADMAP.md, D16)."""
         b, t = input_ids.shape
-        idx = cache["index"]
         valid = cache.get("valid_len")
         if valid is not None:
             valid = jnp.broadcast_to(jnp.asarray(valid, jnp.int32), (b,))
-        x = self._embed(params, input_ids)
-        kv, recurrent = (cache["k"], cache["v"]), (cache["ssm"], cache["conv"])
-        step = self._decode_step(params, valid, b) if t == 1 else None
-        for kind, first, count in c.runs():
-            if kind == MAMBA:
-                x, recurrent = cached_walk(
-                    self._mamba_layer, x, params[MAMBA], recurrent, idx,
-                    valid, step, first=first, count=count)
-            else:
-                x, kv = cached_walk(self._attn_layer, x, params[ATTENTION],
-                                    kv, idx, cache.get("slot_walk"),
-                                    first=first, count=count)
-        hidden = rms_norm(x, params["final_norm"], c.eps)
+        x, leaves, _ = self._layers(
+            params, self._embed(params, input_ids),
+            tuple(cache[k] for k in self.slot_state_keys), None,
+            cache["index"], valid, cache.get("slot_walk"))
+        hidden = self._norm(x, params["final_norm"])
         return self.logits(params, hidden), next_cache(
-            cache, t, k=kv[0], v=kv[1], ssm=recurrent[0], conv=recurrent[1])
+            cache, t, **dict(zip(self.slot_state_keys, leaves)))
 
     def num_params(self) -> int:
         c = self.config

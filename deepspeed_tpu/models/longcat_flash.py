@@ -56,12 +56,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import cross_entropy_loss, gathered_top, project_heads, qdot, rms_norm, whole_leaves
+from deepspeed_tpu.models.base import project_heads, qdot, rms_norm, whole_leaves
 from deepspeed_tpu.models.mla import LatentAttention, latent_row_width
-from deepspeed_tpu.models.moe_ffn import (DENSE, EXPERT_LEAVES, PROMPT_COUNTERS, SPARSE, STEP_COUNTERS,
-                                          carried_counts, ffn, gated_axes, gated_init, record_prompt_counters,
-                                          record_step_counters, zero_counts)
-from deepspeed_tpu.models.stack import cached_walk, next_cache, prompt_walk, walk, wrapped_block
+from deepspeed_tpu.models.moe_ffn import DENSE, EXPERT_LEAVES, SPARSE, ffn, gated_axes, gated_init
 from deepspeed_tpu.ops.rotary import apply_rotary_half
 
 SUB, PAIR = "sub", "pair"
@@ -162,22 +159,17 @@ class LongcatFlashConfig:
 
 
 class LongcatFlashModel(LatentAttention):
-    """Causal-LM ModelSpec: batch = {"input_ids": [B,T], "labels": [B,T]}."""
+    """Double layers of one kind: a walk takes BOTH stacks, under the first
+    one's name (models/stack.StackedDecoder)."""
 
-    supports_weight_quant = False
-    step_counters = STEP_COUNTERS
-    prompt_counters = PROMPT_COUNTERS
-    record_prompt_counters = staticmethod(record_prompt_counters)
-    record_step_counters = staticmethod(record_step_counters)
+    stacks = (SUB, PAIR)
+    kinds = {PAIR: (SUB, ("latent",))}
 
-    def __init__(self, config: LongcatFlashConfig, compute_dtype=jnp.bfloat16,
-                 param_dtype=jnp.float32, remat: bool = False,
-                 remat_policy: Optional[str] = None):
-        self.config = config
-        self.compute_dtype = compute_dtype
-        self.param_dtype = param_dtype
-        self.remat = remat
-        self.remat_policy = remat_policy
+    def layer_kinds(self):
+        return (PAIR,) * self.config.num_layers
+
+    def _block_of(self, kind, shift, walk_, step):
+        return functools.partial(self._block, walk_=walk_)
 
     # ----------------------------------------------------------------- init
     def init(self, rng):
@@ -291,69 +283,18 @@ class LongcatFlashModel(LatentAttention):
                            DENSE, None, c)[0] + m
         return out, (None if state is None else (latent, counts + n))
 
-    @staticmethod
-    def _stack(params):
+    def _stack(self, params, kind):
         """Both stacks as the walk takes them: the expert stacks whole, for
         the grouped matmul to address by group, and every sublayer's leaf
         whole, for :meth:`_sub` to address by sublayer."""
         return {SUB: whole_leaves(params[SUB], *SUB_LEAVES),
                 PAIR: whole_leaves(params[PAIR], *EXPERT_LEAVES, *WKV_B)}
 
-    # -------------------------------------------------------------- forward
-    def forward_hidden(self, params, input_ids, *, rngs=None,
-                       train: bool = False):
-        c = self.config
-        top = gathered_top(params, SUB, PAIR)
-        x = top["embed"].astype(self.compute_dtype)[input_ids]
-        block_fn = wrapped_block(
-            lambda x, blk: self._block(x, blk, None, None, None, None,
-                                       None)[0],
-            SUB, self.remat, self.remat_policy)
-        x = walk(block_fn, x, self._stack(params), run=(0, c.num_layers))
-        return rms_norm(x, top["final_norm"], c.eps)
-
-    def logits(self, params, hidden):
-        return jnp.einsum("btd,dv->btv", hidden,
-                          params["lm_head"].astype(hidden.dtype))
-
-    def apply(self, params, batch, *, rngs=None, train: bool = False):
-        hidden = self.forward_hidden(params, batch["input_ids"], rngs=rngs,
-                                     train=train)
-        head = gathered_top(params, SUB, PAIR)
-        loss, n = cross_entropy_loss(self.logits(head, hidden),
-                                     batch["labels"])
-        return loss, {"loss": loss, "ntokens": n}
-
     # ------------------------------------------------------- inference path
     def init_cache(self, batch_size: int, max_len: int, dtype=None):
         """Two rows of the leaf a double layer."""
         return self._latent_cache(2 * self.config.num_layers, batch_size,
                                   max_len, dtype)
-
-    def _layers(self, params, x, leaves, counts, idx, valid, walk_):
-        (latent,) = leaves
-        x, (latent, counts) = cached_walk(
-            self._block, x, self._stack(params), (latent, counts), idx,
-            valid, walk_, count=self.config.num_layers)
-        return x, (latent,), counts
-
-    def forward_with_cache(self, params, input_ids, cache):
-        """Prefill (T > 1) or decode (T == 1) against the cache tree, as
-        ``SarvamMlaModel.forward_with_cache``: ``cache["index"]`` a scalar or
-        a per-slot ``[B]`` vector, ``cache["valid_len"]`` the real positions
-        a row, ``cache["slot_walk"]`` the decode program's walk order. The
-        returned cache carries ``step_counters``
-        (models/moe_ffn.STEP_COUNTERS)."""
-        c = self.config
-        x, (latent,), counts = prompt_walk(
-            functools.partial(self._layers, params),
-            params["embed"].astype(self.compute_dtype), input_ids,
-            (cache["latent"],), zero_counts(input_ids.shape[1]),
-            cache, c.prompt_block)
-        hidden = rms_norm(x, params["final_norm"], c.eps)
-        out = next_cache(cache, input_ids.shape[1], latent=latent)
-        out.update(carried_counts(cache, counts))
-        return self.logits(params, hidden), out
 
     def num_params(self) -> int:
         """Parameters held here: ``held[1]`` of the real experts a layer; the

@@ -36,13 +36,10 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import (cache_positions, cross_entropy_loss, gathered_top, merge_heads,
-                                       project_heads, rms_norm, whole_leaves)
-from deepspeed_tpu.models.moe_ffn import (DENSE, EXPERT_LEAVES, PROMPT_COUNTERS, SPARSE, STEP_COUNTERS,
-                                          carried_counts, gated_axes, gated_init, record_prompt_counters,
-                                          record_step_counters, zero_counts)
+from deepspeed_tpu.models.base import cache_positions, merge_heads, project_heads, rms_norm
+from deepspeed_tpu.models.moe_ffn import DENSE, EXPERT_LEAVES, SPARSE, gated_axes, gated_init
 from deepspeed_tpu.models.moe_ffn import ffn as ffn_layer
-from deepspeed_tpu.models.stack import cached_walk, kv_cache, next_cache, prompt_walk, walk, wrapped_block
+from deepspeed_tpu.models.stack import StackedDecoder, kv_cache
 from deepspeed_tpu.ops import gqa_prefill
 from deepspeed_tpu.ops.attention import (blocked_prompt_attention, cached_attention, key_row_width, pad_lanes,
                                          sink_softmax, window_cached_attention, write_kv_cache)
@@ -158,20 +155,6 @@ class MimoV2Config:
         return (self.num_heads * self.head_dim
                 + self.kv_heads(attn) * (self.head_dim + self.v_head_dim))
 
-    def runs(self):
-        """Runs of layers of equal (FFN kind, attention kind) as ``(ffn,
-        attention, first index in the pair's stacked tree, first index in
-        the attention kind's cache leaves, count)``, in stack order."""
-        out, seen = [], {}
-        for ffn, attn in self.layer_kinds:
-            at, at_cache = seen.get((ffn, attn), 0), seen.get(attn, 0)
-            if out and out[-1][:2] == [ffn, attn]:
-                out[-1][4] += 1
-            else:
-                out.append([ffn, attn, at, at_cache, 1])
-            seen[ffn, attn], seen[attn] = at + 1, at_cache + 1
-        return tuple(tuple(r) for r in out)
-
     @classmethod
     def tiny(cls, **kw):
         sizes = dict(hybrid_layer_pattern=(0, 1, 1, 0, 1),
@@ -189,6 +172,13 @@ def stack_name(ffn: str, attn: str) -> str:
     return f"{ffn}_{attn}"
 
 
+# a kind of layer is its pair: the pair's stack, and the attention kind's
+# cache leaves (models/stack.runs_of)
+KINDS = {(ffn, attn): (stack_name(ffn, attn), leaves)
+         for ffn in (DENSE, SPARSE) for attn, leaves in
+         ((GLOBAL, ("k", "v")), (SLIDING, ("k_win", "v_win")))}
+
+
 def count_window_traced(ring_step: bool) -> None:
     """Say in the program's registry how a sliding layer with a cache was
     traced: ``swa/traced_ring_step`` (one token a row: the ring's step) or
@@ -202,10 +192,14 @@ def count_window_traced(ring_step: bool) -> None:
     counters[bool(ring_step)].inc()
 
 
-class MimoV2Model:
-    """Causal-LM ModelSpec: batch = {"input_ids": [B,T], "labels": [B,T]}."""
+class MimoV2Model(StackedDecoder):
+    """Layers of four kinds in runs of equal pairs: the stacked weights are
+    indexed by the pair, the cache by attention kind
+    (models/stack.StackedDecoder)."""
 
-    supports_weight_quant = False
+    kinds = KINDS
+    # the expert stacks, for the grouped matmul to address by group
+    whole = EXPERT_LEAVES
     # per-slot state, in operand order: rows that grow with the request on
     # the global layers, rings of the window on the sliding layers. Leaves
     # other than k, v are not addressed by token rows: the serving engine
@@ -213,25 +207,19 @@ class MimoV2Model:
     slot_state_keys = ("k", "v", "k_win", "v_win")
     # ring leaves and the window they hold: SlotKVCache counts their rows
     window_state_keys = ("k_win", "v_win")
-    step_counters = STEP_COUNTERS
-    prompt_counters = PROMPT_COUNTERS
-    record_prompt_counters = staticmethod(record_prompt_counters)
-    record_step_counters = staticmethod(record_step_counters)
-
-    def __init__(self, config: MimoV2Config, compute_dtype=jnp.bfloat16,
-                 param_dtype=jnp.float32, remat: bool = False,
-                 remat_policy: Optional[str] = None):
-        self.config = config
-        self.compute_dtype = compute_dtype
-        self.param_dtype = param_dtype
-        self.remat = remat
-        self.remat_policy = remat_policy
 
     @property
     def stacks(self) -> Tuple[str, ...]:
         """The params tree's layer stacks, in order of first appearance."""
         return tuple(dict.fromkeys(stack_name(f, a)
                                    for f, a in self.config.layer_kinds))
+
+    def layer_kinds(self):
+        return self.config.layer_kinds
+
+    def _block_of(self, kind, shift, walk_, step):
+        return functools.partial(self._block, walk_=walk_, ffn=kind[0],
+                                 attn=kind[1], shift=shift)
 
     # ----------------------------------------------------------------- init
     def init(self, rng):
@@ -406,41 +394,6 @@ class MimoV2Model:
         y, n = ffn_layer(z, blk, ffn, tokens, c)
         return x + y, (None if state is None else (kc, vc, counts + n))
 
-    @staticmethod
-    def _stack(params, ffn: str, attn: str):
-        """The stacked layers of one pair of kinds as the walk takes them:
-        the expert stacks whole, for the grouped matmul to address by
-        group."""
-        return whole_leaves(params[stack_name(ffn, attn)], *EXPERT_LEAVES)
-
-    # -------------------------------------------------------------- forward
-    def forward_hidden(self, params, input_ids, *, rngs=None,
-                       train: bool = False):
-        c = self.config
-        top = gathered_top(params, *self.stacks)
-        x = top["embed"].astype(self.compute_dtype)[input_ids]
-        for ffn, attn, first, _, count in c.runs():
-            block_fn = wrapped_block(
-                lambda x, blk, ffn=ffn, attn=attn: self._block(
-                    x, blk, None, None, None, None, None, ffn=ffn,
-                    attn=attn)[0],
-                stack_name(ffn, attn), self.remat, self.remat_policy)
-            x = walk(block_fn, x, self._stack(params, ffn, attn),
-                     run=(first, count))
-        return rms_norm(x, top["final_norm"], c.eps)
-
-    def logits(self, params, hidden):
-        return jnp.einsum("btd,dv->btv", hidden,
-                          params["lm_head"].astype(hidden.dtype))
-
-    def apply(self, params, batch, *, rngs=None, train: bool = False):
-        hidden = self.forward_hidden(params, batch["input_ids"], rngs=rngs,
-                                     train=train)
-        head = gathered_top(params, *self.stacks)
-        loss, n = cross_entropy_loss(self.logits(head, hidden),
-                                     batch["labels"])
-        return loss, {"loss": loss, "ntokens": n}
-
     # ------------------------------------------------------- inference path
     def init_cache(self, batch_size: int, max_len: int, dtype=None):
         """``k``, ``v`` over the global layers at ``max_len`` rows and the
@@ -457,45 +410,6 @@ class MimoV2Model:
         return dict(kv_cache(c.count(GLOBAL), batch_size, c.num_kv_heads,
                              max_len, dk, dtype, packed=False, v_head_dim=dv),
                     k_win=ring["k"], v_win=ring["v"])
-
-    def _layers(self, params, x, leaves, counts, idx, valid, walk_):
-        """``x`` through the stack against the cache's leaves ``(k, v, k_win,
-        v_win)`` -> ``(x, leaves, counts)``."""
-        held = {GLOBAL: leaves[:2], SLIDING: leaves[2:]}
-        for ffn, attn, first, first_cache, count in self.config.runs():
-            block = functools.partial(self._block, ffn=ffn, attn=attn,
-                                      shift=first_cache - first)
-            x, (kc, vc, counts) = cached_walk(
-                block, x, self._stack(params, ffn, attn),
-                (*held[attn], counts), idx, valid, walk_, first=first,
-                count=count)
-            held[attn] = (kc, vc)
-        return x, (*held[GLOBAL], *held[SLIDING]), counts
-
-    def forward_with_cache(self, params, input_ids, cache):
-        """Prefill (T > 1) or decode (T == 1) against the cache tree.
-        ``cache["index"]`` is a scalar or a per-slot ``[B]`` vector;
-        ``cache["valid_len"]`` (scalar or ``[B]``) how many of the block's
-        positions are real for each row: a ring keeps the last ``window``
-        REAL positions, and a position that is not real is routed to no
-        expert. ``cache["slot_walk"]`` is the decode program's walk order
-        for the fused decode step of both kinds of layer. A prompt longer
-        than ``prompt_block`` passes the stack a token block at a time
-        (models/stack.prompt_walk); with ``valid_len`` a prompt's logits are
-        those of each row's last real position alone, ``[B, 1, V]``. The
-        returned cache carries ``step_counters`` (:data:`STEP_COUNTERS`),
-        summed over the sparse layers."""
-        c = self.config
-        x, leaves, counts = prompt_walk(
-            functools.partial(self._layers, params),
-            params["embed"].astype(self.compute_dtype), input_ids,
-            tuple(cache[k] for k in self.slot_state_keys),
-            zero_counts(input_ids.shape[1]), cache, c.prompt_block)
-        hidden = rms_norm(x, params["final_norm"], c.eps)
-        out = next_cache(cache, input_ids.shape[1],
-                         **dict(zip(self.slot_state_keys, leaves)))
-        out.update(carried_counts(cache, counts))
-        return self.logits(params, hidden), out
 
     def _layer_params(self, ffn: str, attn: str, held: int) -> int:
         c = self.config

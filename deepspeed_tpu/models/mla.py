@@ -45,6 +45,7 @@ import jax.numpy as jnp
 from deepspeed_tpu.models.base import cache_positions, merge_heads, project_heads
 from deepspeed_tpu.ops import mla_prefill
 from deepspeed_tpu.ops.attention import multihead_attention
+from deepspeed_tpu.models.stack import StackedDecoder
 from deepspeed_tpu.ops.mla_decode_step import count_form, fused_mla_decode_step, supports
 
 
@@ -53,8 +54,9 @@ def latent_row_width(kv_lora_rank: int, qk_rope_head_dim: int) -> int:
     return -(-(kv_lora_rank + qk_rope_head_dim) // 128) * 128
 
 
-class LatentAttention:
-    """What a model of this attention inherits. It reads ``self.config``
+class LatentAttention(StackedDecoder):
+    """What a model of this attention inherits, on top of the decoder's frame
+    (models/stack.StackedDecoder). It reads ``self.config``
     (``num_heads``, ``kv_lora_rank``, ``qk_nope_head_dim``,
     ``qk_rope_head_dim``, ``v_head_dim``, ``row_width``, ``key_block``,
     ``score_scale``) and ``self.compute_dtype``, and calls the family's own

@@ -38,12 +38,9 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import cross_entropy_loss, gathered_top, project_heads, qdot, rms_norm, whole_leaves
+from deepspeed_tpu.models.base import project_heads, qdot, rms_norm
 from deepspeed_tpu.models.mla import LatentAttention, latent_row_width
-from deepspeed_tpu.models.moe_ffn import (DENSE, EXPERT_LEAVES, PROMPT_COUNTERS, SPARSE, STEP_COUNTERS,
-                                          carried_counts, ffn, gated_axes, gated_init, record_prompt_counters,
-                                          record_step_counters, zero_counts)
-from deepspeed_tpu.models.stack import cached_walk, next_cache, prompt_walk, walk, wrapped_block
+from deepspeed_tpu.models.moe_ffn import DENSE, EXPERT_LEAVES, SPARSE, ffn, gated_axes, gated_init
 from deepspeed_tpu.ops.rotary import apply_rotary_half_freqs, yarn_inv_freq, yarn_mscale
 
 
@@ -119,13 +116,6 @@ class SarvamMlaConfig:
         return self.first_k_dense if kind == DENSE \
             else self.num_layers - self.first_k_dense
 
-    def runs(self):
-        """``(ffn kind, first layer of the cache, count)``, in stack order:
-        the stacked weights are indexed by FFN kind, the cache by layer."""
-        return tuple((kind, first, self.count(kind)) for kind, first in
-                     ((DENSE, 0), (SPARSE, self.first_k_dense))
-                     if self.count(kind))
-
     @classmethod
     def tiny(cls, **kw):
         kw.setdefault("vocab_size", 512)
@@ -142,22 +132,24 @@ class SarvamMlaConfig:
 
 
 class SarvamMlaModel(LatentAttention):
-    """Causal-LM ModelSpec: batch = {"input_ids": [B,T], "labels": [B,T]}."""
+    """The leading dense layers, then the sparse ones: a stack a kind of
+    FFN, the cache leaf indexed by layer (models/stack.StackedDecoder)."""
 
-    supports_weight_quant = False
-    step_counters = STEP_COUNTERS
-    prompt_counters = PROMPT_COUNTERS
-    record_prompt_counters = staticmethod(record_prompt_counters)
-    record_step_counters = staticmethod(record_step_counters)
+    stacks = (DENSE, SPARSE)
+    kinds = {DENSE: (DENSE, ("latent",)), SPARSE: (SPARSE, ("latent",))}
+    # the expert stacks, for the grouped matmul to address by group, and
+    # ``wkv_b``, for the prompt kernel to address by layer and head (a layer's
+    # slice as a kernel's operand is written out: 16.8 MB a layer a token
+    # block)
+    whole = (*EXPERT_LEAVES, "wkv_b")
 
-    def __init__(self, config: SarvamMlaConfig, compute_dtype=jnp.bfloat16,
-                 param_dtype=jnp.float32, remat: bool = False,
-                 remat_policy: Optional[str] = None):
-        self.config = config
-        self.compute_dtype = compute_dtype
-        self.param_dtype = param_dtype
-        self.remat = remat
-        self.remat_policy = remat_policy
+    def layer_kinds(self):
+        c = self.config
+        return (DENSE,) * c.count(DENSE) + (SPARSE,) * c.count(SPARSE)
+
+    def _block_of(self, kind, shift, walk_, step):
+        return functools.partial(self._block, walk_=walk_, kind=kind,
+                                 shift=shift)
 
     # ----------------------------------------------------------------- init
     def init(self, rng):
@@ -254,75 +246,10 @@ class SarvamMlaModel(LatentAttention):
         y, n = ffn(z, blk, kind, tokens, c)
         return x + y, (None if state is None else (latent, counts + n))
 
-    @staticmethod
-    def _stack(params, kind: str):
-        """The stacked layers of one FFN kind as the walk takes them: the
-        expert stacks whole, for the grouped matmul to address by group, and
-        ``wkv_b`` whole, for the prompt kernel to address by layer and head
-        (a layer's slice as a kernel's operand is written out: 16.8 MB a
-        layer a token block)."""
-        return whole_leaves(params[kind], *EXPERT_LEAVES, "wkv_b")
-
-    # -------------------------------------------------------------- forward
-    def forward_hidden(self, params, input_ids, *, rngs=None,
-                       train: bool = False):
-        c = self.config
-        top = gathered_top(params, DENSE, SPARSE)
-        x = top["embed"].astype(self.compute_dtype)[input_ids]
-        for kind, _, count in c.runs():
-            block_fn = wrapped_block(
-                lambda x, blk, kind=kind: self._block(
-                    x, blk, None, None, None, None, None, kind=kind)[0],
-                kind, self.remat, self.remat_policy)
-            x = walk(block_fn, x, self._stack(params, kind), run=(0, count))
-        return rms_norm(x, top["final_norm"], c.eps)
-
-    def logits(self, params, hidden):
-        return jnp.einsum("btd,dv->btv", hidden,
-                          params["lm_head"].astype(hidden.dtype))
-
-    def apply(self, params, batch, *, rngs=None, train: bool = False):
-        hidden = self.forward_hidden(params, batch["input_ids"], rngs=rngs,
-                                     train=train)
-        head = gathered_top(params, DENSE, SPARSE)
-        loss, n = cross_entropy_loss(self.logits(head, hidden),
-                                     batch["labels"])
-        return loss, {"loss": loss, "ntokens": n}
-
     # ------------------------------------------------------- inference path
     def init_cache(self, batch_size: int, max_len: int, dtype=None):
         return self._latent_cache(self.config.num_layers, batch_size, max_len,
                                   dtype)
-
-    def _layers(self, params, x, leaves, counts, idx, valid, walk_):
-        (latent,) = leaves
-        for kind, first, count in self.config.runs():
-            block = functools.partial(self._block, kind=kind, shift=first)
-            x, (latent, counts) = cached_walk(
-                block, x, self._stack(params, kind), (latent, counts), idx,
-                valid, walk_, count=count)
-        return x, (latent,), counts
-
-    def forward_with_cache(self, params, input_ids, cache):
-        """Prefill (T > 1) or decode (T == 1) against the cache tree.
-        ``cache["index"]`` is a scalar or a per-slot ``[B]`` vector;
-        ``cache["valid_len"]`` (scalar or ``[B]``) how many of the block's
-        positions are real for each row (padding is routed to no expert;
-        rows it writes lie behind the length and are dead);
-        ``cache["slot_walk"]`` the decode program's walk order for the fused
-        step. With ``valid_len`` a prompt block's logits are those of each
-        row's last real position alone, ``[B, 1, V]``. The returned cache
-        carries ``step_counters`` (models/moe_ffn.STEP_COUNTERS)."""
-        c = self.config
-        x, (latent,), counts = prompt_walk(
-            functools.partial(self._layers, params),
-            params["embed"].astype(self.compute_dtype), input_ids,
-            (cache["latent"],), zero_counts(input_ids.shape[1]),
-            cache, c.prompt_block)
-        hidden = rms_norm(x, params["final_norm"], c.eps)
-        out = next_cache(cache, input_ids.shape[1], latent=latent)
-        out.update(carried_counts(cache, counts))
-        return self.logits(params, hidden), out
 
     def num_params(self) -> int:
         """Parameters held here: ``held[1]`` of the experts a sparse layer."""

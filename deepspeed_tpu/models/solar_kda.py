@@ -47,18 +47,17 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import (cache_positions, cross_entropy_loss, gathered_top, merge_heads,
-                                       project_heads, qdot, rms_norm, whole_leaves)
-from deepspeed_tpu.models.moe_ffn import (EXPERT_LEAVES, PROMPT_COUNTERS, SPARSE, STEP_COUNTERS, carried_counts, ffn,
-                                          gated_axes, gated_init, record_prompt_counters, record_step_counters,
-                                          zero_counts)
-from deepspeed_tpu.models.stack import cached_walk, kv_cache, next_cache, prompt_walk, walk, wrapped_block
+from deepspeed_tpu.models.base import cache_positions, merge_heads, project_heads, qdot, rms_norm
+from deepspeed_tpu.models.moe_ffn import EXPERT_LEAVES, SPARSE, ffn, gated_axes, gated_init
+from deepspeed_tpu.models.stack import StackedDecoder, kv_cache, runs_of
 from deepspeed_tpu.ops import gqa_prefill, kda
 from deepspeed_tpu.ops.attention import (blocked_prompt_attention, cached_attention, multihead_attention,
                                          write_kv_cache)
 from deepspeed_tpu.ops.ssm import causal_conv, slot_order
 
 GQA, KDA = "gqa", "kda"
+# a kind of layer: its stack, and its cache leaves (models/stack.runs_of)
+KINDS = {GQA: (GQA, ("k", "v")), KDA: (KDA, ("kda", "kda_conv"))}
 
 
 @dataclasses.dataclass
@@ -124,14 +123,7 @@ class SolarKdaConfig:
     def runs(self) -> Tuple[Tuple[str, int, int], ...]:
         """Runs of equal layers as ``(kind, first index in that kind's
         stacked tree and cache leaves, count)``, in stack order."""
-        out, seen = [], {GQA: 0, KDA: 0}
-        for kind in self.layer_types:
-            if out and out[-1][0] == kind:
-                out[-1][2] += 1
-            else:
-                out.append([kind, seen[kind], 1])
-            seen[kind] += 1
-        return tuple(tuple(r) for r in out)
+        return tuple((k, i, n) for k, i, _, n in runs_of(self.layer_types, KINDS))
 
     @classmethod
     def tiny(cls, **kw):
@@ -151,30 +143,26 @@ def _inv_softplus(x):
     return x + jnp.log(-jnp.expm1(-x))
 
 
-class SolarKdaModel:
-    """Causal-LM ModelSpec: batch = {"input_ids": [B,T], "labels": [B,T]}."""
+class SolarKdaModel(StackedDecoder):
+    """Layers of two kinds, a stack and a pair of cache leaves each
+    (models/stack.StackedDecoder)."""
 
-    supports_weight_quant = False
+    stacks = (GQA, KDA)
+    kinds = KINDS
+    # the expert stacks, for the grouped matmul to address by group
+    whole = EXPERT_LEAVES
     # per-slot state, in operand order: key-value rows on the softmax layers,
-    # the delta rule's state and the convolutions' tails on the others
+    # the delta rule's state (``state_dtype``: 4.19 MB a layer a slot at the
+    # published sizes) and the convolutions' tails (in the compute dtype) on
+    # the others
     slot_state_keys = ("k", "v", "kda", "kda_conv")
-    step_counters = STEP_COUNTERS
-    prompt_counters = PROMPT_COUNTERS
-    record_prompt_counters = staticmethod(record_prompt_counters)
-    record_step_counters = staticmethod(record_step_counters)
-    # the state adds thousands of rank-one corrections to a decaying sum:
-    # float32 whatever the compute dtype (4.19 MB a layer a slot at the
-    # published sizes; the tails are in the compute dtype)
-    state_dtype = jnp.float32
 
-    def __init__(self, config: SolarKdaConfig, compute_dtype=jnp.bfloat16,
-                 param_dtype=jnp.float32, remat: bool = False,
-                 remat_policy: Optional[str] = None):
-        self.config = config
-        self.compute_dtype = compute_dtype
-        self.param_dtype = param_dtype
-        self.remat = remat
-        self.remat_policy = remat_policy
+    def layer_kinds(self):
+        return self.config.layer_types
+
+    def _block_of(self, kind, shift, walk_, step):
+        return functools.partial(self._kda_layer, step=step) if kind == KDA \
+            else functools.partial(self._gqa_layer, walk_=walk_)
 
     # ----------------------------------------------------------------- init
     def init(self, rng):
@@ -427,39 +415,6 @@ class SolarKdaModel:
         x, counts = self._ffn(x, blk, valid, counts)
         return x, (None if state is None else (kc, vc, counts))
 
-    @staticmethod
-    def _stack(params, kind: str):
-        """The stacked layers of one kind as the walk takes them: the expert
-        stacks whole, for the grouped matmul to address by group."""
-        return whole_leaves(params[kind], *EXPERT_LEAVES)
-
-    # -------------------------------------------------------------- forward
-    def forward_hidden(self, params, input_ids, *, rngs=None,
-                       train: bool = False):
-        c = self.config
-        top = gathered_top(params, GQA, KDA)
-        x = top["embed"].astype(self.compute_dtype)[input_ids]
-        for kind, first, count in c.runs():
-            block = self._kda_layer if kind == KDA else self._gqa_layer
-            block_fn = wrapped_block(
-                lambda x, blk, block=block: block(x, blk)[0], kind,
-                self.remat, self.remat_policy)
-            x = walk(block_fn, x, self._stack(params, kind),
-                     run=(first, count))
-        return rms_norm(x, top["final_norm"], c.eps)
-
-    def logits(self, params, hidden):
-        return jnp.einsum("btd,dv->btv", hidden,
-                          params["lm_head"].astype(hidden.dtype))
-
-    def apply(self, params, batch, *, rngs=None, train: bool = False):
-        hidden = self.forward_hidden(params, batch["input_ids"], rngs=rngs,
-                                     train=train)
-        head = gathered_top(params, GQA, KDA)
-        loss, n = cross_entropy_loss(self.logits(head, hidden),
-                                     batch["labels"])
-        return loss, {"loss": loss, "ntokens": n}
-
     # ------------------------------------------------------- inference path
     def init_cache(self, batch_size: int, max_len: int, dtype=None):
         """One tree for both kinds of per-request state: ``k``, ``v`` over
@@ -479,49 +434,6 @@ class SolarKdaModel:
         return dict(kv_cache(c.count(GQA), batch_size, c.num_kv_heads,
                              max_len, c.head_dim, dtype), kda=state,
                     kda_conv=tail)
-
-    def _layers(self, params, x, leaves, counts, idx, valid, walk_):
-        """``x`` through the stack against the cache's leaves ``(k, v, kda,
-        kda_conv)`` -> ``(x, leaves, counts)``."""
-        kc, vc, state, tail = leaves
-        b, t = x.shape[:2]
-        step = self._decode_step(params, valid, b) if t == 1 else None
-        for kind, first, count in self.config.runs():
-            if kind == KDA:
-                x, (state, tail, counts) = cached_walk(
-                    self._kda_layer, x, self._stack(params, KDA),
-                    (state, tail, counts), idx, valid, step, first=first,
-                    count=count)
-            else:
-                x, (kc, vc, counts) = cached_walk(
-                    self._gqa_layer, x, self._stack(params, GQA),
-                    (kc, vc, counts), idx, valid, walk_, first=first,
-                    count=count)
-        return x, (kc, vc, state, tail), counts
-
-    def forward_with_cache(self, params, input_ids, cache):
-        """Prefill (T > 1) or decode (T == 1) against the cache tree.
-        ``cache["index"]`` is a scalar or a per-slot ``[B]`` vector;
-        ``cache["valid_len"]`` (scalar or ``[B]``) how many of the block's
-        positions are real for each row: the recurrent state and the tails
-        stop there (a row with 0 valid positions keeps both), and a position
-        that is not real is routed to no expert; ``cache["slot_walk"]`` the
-        decode program's walk order for the softmax layers' fused step. With
-        ``valid_len`` a prompt block's logits are those of each row's last
-        real position alone, ``[B, 1, V]``. The returned cache carries
-        ``step_counters`` (models/moe_ffn.STEP_COUNTERS)."""
-        c = self.config
-        x, leaves, counts = prompt_walk(
-            functools.partial(self._layers, params),
-            params["embed"].astype(self.compute_dtype), input_ids,
-            tuple(cache[k] for k in self.slot_state_keys),
-            zero_counts(input_ids.shape[1]), cache,
-            c.prompt_block)
-        hidden = rms_norm(x, params["final_norm"], c.eps)
-        out = next_cache(cache, input_ids.shape[1],
-                         **dict(zip(self.slot_state_keys, leaves)))
-        out.update(carried_counts(cache, counts))
-        return self.logits(params, hidden), out
 
     def num_params(self) -> int:
         """Parameters held here: ``held[1]`` of the experts a layer."""

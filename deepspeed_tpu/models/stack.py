@@ -1,23 +1,30 @@
 """How a model's layers are stacked and walked: the one place.
 
-A model file keeps its configuration, its parameter tree, its embedding, its
-head and its *block functions*. What is not the model's lives here: the
-training walk (:func:`wrapped_block`, :func:`walk`), the cached walk of
-prefill and decode (:func:`cached_walk`), the walk of a long prompt a token
-block at a time (:func:`prompt_walk`), and the key-value cache tree with
-the cache a step returns (:func:`kv_cache`, :func:`next_cache`). A stack is
-a dict of leaves with a leading ``layer`` dimension under one key of the
-params tree (``"blocks"``; the hybrid model has two).
+A model file keeps its configuration, its parameter tree and its *block
+functions*. What is not the model's lives here: the training walk
+(:func:`wrapped_block`, :func:`walk`), the cached walk of prefill and decode
+(:func:`cached_walk`), the walk of a long prompt a token block at a time
+(:func:`prompt_walk`), the key-value cache tree with the cache a step returns
+(:func:`kv_cache`, :func:`next_cache`), the runs of equal layers of a stack of
+mixed ones (:func:`runs_of`), and the frame of a decoder around all of them
+(:class:`StackedDecoder`: the embedding, the walks over the runs, the head,
+the loss, the cached step). A stack is a dict of leaves with a leading
+``layer`` dimension under one key of the params tree (``"blocks"``; a model of
+mixed layers has one a kind).
 """
 
 from __future__ import annotations
 
-from typing import Optional
+import collections
+import functools
+from typing import Dict, Hashable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.base import gathered, gathers, layer_view
+from deepspeed_tpu.models import moe_ffn
+from deepspeed_tpu.models.base import (cross_entropy_loss, gathered, gathered_top, gathers, layer_view, rms_norm,
+                                       whole_leaves)
 from deepspeed_tpu.ops.attention import alloc_kv_cache
 from deepspeed_tpu.runtime.activation_checkpointing import checkpoint_policy
 from deepspeed_tpu.telemetry.registry import get_registry
@@ -244,3 +251,196 @@ def next_cache(cache, t: int, **state):
     if cache.get("block_table") is not None:
         out["block_table"] = cache["block_table"]
     return out
+
+
+def runs_of(layer_kinds: Sequence[Hashable],
+            kinds: Dict[Hashable, Tuple[str, Tuple[str, ...]]]):
+    """The runs of equal kind among ``layer_kinds`` (each layer's kind, in
+    stack order) as ``(kind, first index in the kind's stack, first index in
+    the kind's cache leaves, count)``. ``kinds[kind]`` is ``(stack, leaves)``:
+    the params tree's stack the kind's layers are stored in, and the cache
+    leaves they are counted in. Kinds that share a stack are numbered in it
+    together, and so are kinds that share their leaves."""
+    out, in_stack, in_leaves = [], collections.Counter(), collections.Counter()
+    for kind in layer_kinds:
+        stack, leaves = kinds[kind]
+        if out and out[-1][0] == kind:
+            out[-1][3] += 1
+        else:
+            out.append([kind, in_stack[stack], in_leaves[leaves], 1])
+        in_stack[stack] += 1
+        in_leaves[leaves] += 1
+    return tuple(tuple(r) for r in out)
+
+
+class StackedDecoder:
+    """A causal LM as a stack of layers of one or several kinds: ModelSpec
+    (``batch = {"input_ids": [B, T], "labels": [B, T]}``) and what the
+    inference and serving engines ask of a model. A family states
+
+    - ``stacks``: the params tree's layer stacks;
+    - ``kinds``: a layer kind -> ``(its stack, the cache leaves its layers
+      read and write)`` (:func:`runs_of`), and :meth:`layer_kinds`;
+    - ``whole``: the leaves of a stack that a walk hands whole
+      (``base.whole_leaves``);
+    - :meth:`_block_of`: the block function of a kind;
+    - ``init``, ``logical_axes``, ``init_cache``, ``num_params``,
+      ``flops_per_token``, and of the attributes below those that are not the
+      defaults.
+
+    It reads ``self.config`` for ``eps`` and ``prompt_block``. The params tree
+    holds ``embed [V, d]``, ``final_norm [d]`` and ``lm_head [d, V]`` beside
+    the stacks. Where a family differs it overrides the method."""
+
+    stacks: Tuple[str, ...] = ()
+    kinds: Dict[Hashable, Tuple[str, Tuple[str, ...]]] = {}
+    whole: Tuple[str, ...] = ()
+
+    # ---- what the engines read of a model (models/base.slot_state_keys and
+    # the like, inference/engine.py, serving/engine.py, serving/kv_slots.py),
+    # and ``state_dtype``, which a family's own ``init_cache`` reads
+    supports_weight_quant = False
+    # the per-slot state leaves of ``init_cache`` (all but the index), in the
+    # order the serving programs take them as operands
+    slot_state_keys: Tuple[str, ...] = ("k", "v")
+    # of them: the leaves of TOKEN ROWS, which grow with the request (leaves
+    # that are neither these nor named below are fixed-size recurrent state);
+    row_state_keys: Tuple[str, ...] = ("k", "v")
+    # rings of a sliding window's last positions, keys' first, values' last;
+    window_state_keys: Tuple[str, ...] = ()
+    # a window that starts over, and the summary rows behind it
+    restart_window_keys: Tuple[str, ...] = ()
+    summary_state_keys: Tuple[str, ...] = ()
+    # recurrent state adds thousands of small terms to a slowly decaying sum:
+    # float32 whatever the compute dtype
+    state_dtype = jnp.float32
+    # what a decode step and a prompt block count on the device, as the
+    # returned cache carries them (``step_counters``, ``prompt_counts``), and
+    # how the serving engine's registry takes them: the expert layer's
+    step_counters: Tuple[str, ...] = moe_ffn.STEP_COUNTERS
+    prompt_counters: Tuple[str, ...] = moe_ffn.PROMPT_COUNTERS
+    record_step_counters = staticmethod(moe_ffn.record_step_counters)
+    record_prompt_counters = staticmethod(moe_ffn.record_prompt_counters)
+
+    def __init__(self, config, compute_dtype=jnp.bfloat16,
+                 param_dtype=jnp.float32, remat: bool = False,
+                 remat_policy: Optional[str] = None):
+        self.config = config
+        self.compute_dtype = compute_dtype
+        # what init() draws the matrices in: float32 master weights for
+        # training, the checkpoint's bfloat16 where only serving follows
+        self.param_dtype = param_dtype
+        self.remat = remat
+        self.remat_policy = remat_policy
+
+    def fused_row_walk(self, state, num_slots: int) -> bool:
+        """Whether a slot cache of these leaves routes a decode step to a
+        fused call that walks a request's own rows: asked of a model whose
+        cache has no ``k`` (serving/kv_slots.py, which works it out itself
+        for one that has). No: what the engine counts by a request's length
+        (``serving/decode_rows_*``) is then not what a step moves."""
+        return False
+
+    # --------------------------------------------------------------- layers
+    def layer_kinds(self) -> Sequence[Hashable]:
+        """Each layer's kind, in stack order."""
+        raise NotImplementedError
+
+    def runs(self):
+        return runs_of(self.layer_kinds(), self.kinds)
+
+    def _block_of(self, kind, shift: int, walk_, step):
+        """The block of a layer of ``kind`` as a walk calls it: ``block(x,
+        blk, state, layer, idx, valid) -> (x, state)``. ``state``: ``None``
+        (no cache), or the kind's cache leaves and the step's counters, the
+        layer's own at ``layer + shift`` (the stack's index of a layer, and
+        what its leaves' index is ahead of it); ``valid [B]``: the real
+        positions a row; ``walk_``: the decode program's ``slot_walk``;
+        ``step``: :meth:`_decode_step`'s."""
+        raise NotImplementedError
+
+    def _decode_step(self, params, valid, b: int):
+        """What the recurrent layers of one decode step (one token a slot, a
+        cache) share, made once a step and not once a layer. A family
+        without such layers makes nothing."""
+        return None
+
+    def _stack(self, params, kind):
+        """The stacked layers of ``kind`` as a walk takes them."""
+        return whole_leaves(params[self.kinds[kind][0]], *self.whole)
+
+    def _embed(self, params, input_ids):
+        return params["embed"].astype(self.compute_dtype)[input_ids]
+
+    def _norm(self, x, w):
+        """The norm on the stream behind the last layer."""
+        return rms_norm(x, w, self.config.eps)
+
+    # -------------------------------------------------------------- forward
+    def forward_hidden(self, params, input_ids, *, rngs=None,
+                       train: bool = False):
+        top = gathered_top(params, *self.stacks)
+        x = self._embed(top, input_ids)
+        for kind, first, _, count in self.runs():
+            block = self._block_of(kind, 0, None, None)
+            block_fn = wrapped_block(
+                lambda x, blk, block=block: block(x, blk, None, None, None,
+                                                  None)[0],
+                self.kinds[kind][0], self.remat, self.remat_policy)
+            x = walk(block_fn, x, self._stack(params, kind),
+                     run=(first, count))
+        return self._norm(x, top["final_norm"])
+
+    def logits(self, params, hidden):
+        return jnp.einsum("btd,dv->btv", hidden,
+                          params["lm_head"].astype(hidden.dtype))
+
+    def apply(self, params, batch, *, rngs=None, train: bool = False):
+        hidden = self.forward_hidden(params, batch["input_ids"], rngs=rngs,
+                                     train=train)
+        head = gathered_top(params, *self.stacks)
+        loss, n = cross_entropy_loss(self.logits(head, hidden),
+                                     batch["labels"])
+        return loss, {"loss": loss, "ntokens": n}
+
+    # ------------------------------------------------------- inference path
+    def _layers(self, params, x, leaves, counts, idx, valid, walk_):
+        """``x`` through the stack against the cache's ``leaves`` (in
+        ``slot_state_keys`` order) -> ``(x, leaves, counts)``: a run's block
+        carries its kind's leaves and the counters, whatever those are."""
+        held = dict(zip(self.slot_state_keys, leaves))
+        b, t = x.shape[:2]
+        step = self._decode_step(params, valid, b) if t == 1 else None
+        for kind, first, at, count in self.runs():
+            names = self.kinds[kind][1]
+            x, (*state, counts) = cached_walk(
+                self._block_of(kind, at - first, walk_, step), x,
+                self._stack(params, kind), (*(held[n] for n in names), counts),
+                idx, valid, first=first, count=count)
+            held.update(zip(names, state))
+        return x, tuple(held[n] for n in self.slot_state_keys), counts
+
+    def forward_with_cache(self, params, input_ids, cache):
+        """Prefill (T > 1) or decode (T == 1) against the cache tree.
+        ``cache["index"]`` is a scalar or a per-slot ``[B]`` vector;
+        ``cache["valid_len"]`` (scalar or ``[B]``) how many of the block's
+        positions are real for each row: recurrent state and a ring stop
+        there (a row with 0 valid positions keeps its state), a position
+        that is not real is routed to no expert, and the token rows it
+        writes lie behind the length and are dead; ``cache["slot_walk"]`` the
+        decode program's walk order for the fused decode steps. A prompt of
+        a whole number (> 1) of ``prompt_block`` passes the stack a token
+        block at a time (:func:`prompt_walk`); with ``valid_len`` a prompt's
+        logits are those of each row's last real position alone, ``[B, 1,
+        V]``. The returned cache carries ``step_counters``
+        (models/moe_ffn.STEP_COUNTERS), summed over the sparse layers."""
+        t = input_ids.shape[1]
+        x, leaves, counts = prompt_walk(
+            functools.partial(self._layers, params),
+            params["embed"].astype(self.compute_dtype), input_ids,
+            tuple(cache[k] for k in self.slot_state_keys),
+            moe_ffn.zero_counts(t), cache, self.config.prompt_block)
+        hidden = self._norm(x, params["final_norm"])
+        out = next_cache(cache, t, **dict(zip(self.slot_state_keys, leaves)))
+        out.update(moe_ffn.carried_counts(cache, counts))
+        return self.logits(params, hidden), out

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 # B=1 fused-decode routing threshold: bytes of ONE layer's K cache at
 # full allocated length (V doubles the actual stream; the threshold is
 # calibrated in the same K-only unit). The kernel's fixed per-invocation
-# cost (~28 us/call at 125M geometry, PROFILE_DECODE.md) only amortizes
+# cost (~28 us/call at 125M geometry, round-4 profile) only amortizes
 # when the cache stream is fat enough: measured LOSS at 125M B=1 Dh=64
 # (~1.0 MB K/layer: einsum 0.46 vs kernel 0.60 ms/tok) and WIN at 6.7B
 # B=1 Dh=128 (~5.2 MB K/layer: 19.15 -> 18.25 ms/tok). 2 MB splits the
@@ -162,7 +162,7 @@ def cached_attention(q, k_full, v_full, k_new, v_new, layer, idx, *,
     streaming read, so XLA keeps the decode loop's cache carry in the
     default streaming-friendly layout instead of the einsum-oriented one
     a ``dynamic_update_slice`` write anchors (round-4 root cause of
-    batch-8 decode at half its roofline — PROFILE_DECODE.md). Everything
+    batch-8 decode at half its roofline). Everything
     else (prefill blocks, ALiBi bias, sliding windows, CPU) takes the
     einsum path, view-unpacking packed caches first.
 
@@ -724,7 +724,7 @@ def decode_attention(
         # vs 5.05 MXU-cell kernel / 1.94 head-batched VPU kernel): XLA
         # lays the decode loop's cache carry out for einsum lane
         # parallelism, and a pallas operand in that layout pays a
-        # relayout copy per step — see PROFILE_DECODE.md. Cache length
+        # relayout copy per step. Cache length
         # must tile (the engine pads its KV allocation to 128).
         from deepspeed_tpu.ops.flash_decode import flash_decode
 

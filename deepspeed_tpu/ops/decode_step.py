@@ -12,7 +12,7 @@ order — each (layer, batch, head) panel's [S, Dh] block contiguous in
 HBM — instead of the einsum-oriented ``{4,2,1,3,0}`` layout it picks when
 a ``dynamic_update_slice`` write anchors the carry (measured round 4:
 that layout S-strides cache reads by 12 KB and capped batch-8 decode at
-2.6x batch-1 vs a ~5x streaming roofline; PROFILE_DECODE.md).
+2.6x batch-1 vs a ~5x streaming roofline).
 
 Why manual DMA instead of a gridded ``pallas_call``: the gridded decode
 kernels measured ~2 us of per-grid-cell overhead, which at 125M shapes

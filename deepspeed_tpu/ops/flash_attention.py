@@ -661,14 +661,6 @@ def _fold_scale(scale: float, dtype) -> bool:
     return dtype == jnp.float32 or math.frexp(scale)[0] == 0.5
 
 
-def vma_typing_supported() -> bool:
-    """The installed JAX carries shard_map varying-axis (vma) typing
-    (aval ``.vma`` + ``ShapeDtypeStruct(vma=...)``), which ``_sds`` below
-    declares on pallas_call outputs; callers (ops/ring_attention.py) keep
-    asking so that strict checking stays a decision made in one place."""
-    return True
-
-
 def _sds(*operands_then_args):
     """ShapeDtypeStruct factory that propagates shard_map varying-axes (vma)
     typing from the kernel operands — pallas_call under `shard_map` with

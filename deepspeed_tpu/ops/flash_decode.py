@@ -23,7 +23,7 @@ GQA native: q heads grouped per kv head ([rep, Dh] q tile against the
 [S, Dh] cache of their shared kv head). Serving-only: no VJP (training
 uses ops/flash_attention.py).
 
-Status per variant (round-4 measurements, PROFILE_DECODE.md):
+Status per variant (round-4 measurements):
   * wide-GQA (rep >= 8) MXU-slab kernel — the PRODUCTION route
     (ops/attention.decode_attention gates on rep).
   * MHA head-batched VPU kernel (``_mha_kernel``) — measured SLOWER than

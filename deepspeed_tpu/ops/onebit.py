@@ -47,9 +47,7 @@ def _ensure_varying(x: jax.Array, axis_name: str) -> jax.Array:
         return x
     if axis_name in vma:
         return x
-    from deepspeed_tpu.utils.jax_compat import pcast_varying
-
-    return pcast_varying(x, axis_name)
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 # ----------------------------------------------------------- core compression

@@ -35,28 +35,14 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.parallel.topology import SEQ_AXIS
-from deepspeed_tpu.utils.jax_compat import (has_vma_typing, pcast_varying,
-                                            shard_map)
 
 # true -inf (not finfo.min): fully-masked blocks must zero out in the online
 # softmax; the isfinite() guards below depend on it
 _NEG_INF = -jnp.inf
-
-
-def _require_vma(name: str) -> None:
-    """Fail FAST on pre-vma jax: these kernels' partial-manual shard_map
-    (manual over 'seq' only) wedges the old auto-mode rep machinery inside
-    a collective on some backends — a hang-then-SIGABRT is strictly worse
-    than a clear error at the call site."""
-    if not has_vma_typing():
-        raise NotImplementedError(
-            f"{name} needs shard_map varying-manual-axes typing "
-            f"(jax.lax.pcast; jax {jax.__version__} predates it) — "
-            "use attn_impl='dense'/'flash' without sequence parallelism "
-            "on this jax")
 
 
 def ring_attention(
@@ -75,7 +61,6 @@ def ring_attention(
         from deepspeed_tpu.ops.attention import multihead_attention
 
         return multihead_attention(q, k, v, causal=causal, scale=scale)
-    _require_vma("ring_attention")
     dh = q.shape[-1]
     sc = scale if scale is not None else dh ** -0.5
 
@@ -110,7 +95,7 @@ def ring_attention(
 
         # accumulators become varying over the seq axis after step 1 — mark
         # the initial values accordingly (shard_map VMA typing)
-        vary = lambda x: pcast_varying(x, (axis,))
+        vary = lambda x: jax.lax.pcast(x, (axis,), to="varying")
         m0 = vary(jnp.full((b, h, t_loc), _NEG_INF, jnp.float32))
         l0 = vary(jnp.zeros((b, h, t_loc), jnp.float32))
         o0 = vary(jnp.zeros((b, h, t_loc, dh), jnp.float32))
@@ -157,7 +142,6 @@ def ring_flash_attention(q, k, v, mesh, causal: bool = True,
 def _ring_flash_fwd(q, k, v, mesh, causal, axis, scale=None):
     from deepspeed_tpu.ops.flash_attention import flash_fwd_parts
 
-    _require_vma("ring_flash_attention")
     sp = mesh.shape[axis]
     b, h, dh = q.shape[0], q.shape[2], q.shape[3]
 
@@ -204,7 +188,7 @@ def _ring_flash_fwd(q, k, v, mesh, causal, axis, scale=None):
         return out.astype(ql.dtype), lse_run
 
     spec = P(None, axis)
-    check = jax.default_backend() == "tpu" and has_vma_typing()
+    check = jax.default_backend() == "tpu"
     out, lse = shard_map(
         local, mesh=mesh, in_specs=(spec, spec, spec),
         out_specs=(spec, P(None, axis, None)), axis_names={axis},
@@ -281,7 +265,7 @@ def _ring_flash_bwd(mesh, causal, axis, scale, res, g):
                 unflat(dv_acc).astype(vl.dtype))
 
     spec = P(None, axis)
-    check = jax.default_backend() == "tpu" and has_vma_typing()
+    check = jax.default_backend() == "tpu"
     dq, dk, dv = shard_map(
         local2, mesh=mesh,
         in_specs=(spec, spec, spec, spec, P(None, axis, None),
@@ -325,7 +309,6 @@ def ulysses_attention(
 
     if sp == 1:
         return attend(q, k, v)
-    _require_vma("ulysses_attention")
     assert q.shape[2] % sp == 0, (
         f"ulysses needs heads ({q.shape[2]}) divisible by sp ({sp})")
 
@@ -349,8 +332,7 @@ def ulysses_attention(
     # checking — that's what flash_attention._sds's vma plumbing is for.
     from deepspeed_tpu.ops.flash_attention import _interpret_default
 
-    strict = (inner != "flash" or not _interpret_default()) and \
-        has_vma_typing()
+    strict = inner != "flash" or not _interpret_default()
     return shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
                      out_specs=spec, axis_names={axis},
                      check_vma=strict)(q, k, v)

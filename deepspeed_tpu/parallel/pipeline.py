@@ -27,11 +27,10 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.parallel.topology import PIPE_AXIS
-from deepspeed_tpu.utils.jax_compat import (has_vma_typing, pcast_varying,
-                                            shard_map)
 
 
 def spmd_pipeline(
@@ -91,7 +90,8 @@ def spmd_pipeline(
         # per-device view: params leaves [1, ...]; xs is the full [M, ...] stream.
         # Make the stream varying over 'pipe' BEFORE the compute-dtype cast so
         # the transpose's boundary psum runs in the (f32) boundary dtype.
-        xs = pcast_varying(xs, (PIPE_AXIS,)).astype(compute_dtype)
+        xs = jax.lax.pcast(xs, (PIPE_AXIS,), to="varying").astype(
+            compute_dtype)
         params_one = jax.tree_util.tree_map(lambda p: p[0], params_local)
         stage = jax.lax.axis_index(PIPE_AXIS)
 
@@ -118,10 +118,6 @@ def spmd_pipeline(
         in_specs=(pipe_in, P()),
         out_specs=P(PIPE_AXIS),
         axis_names={PIPE_AXIS},
-        # pre-vma jax cannot type the scan carries' varying-ness (the
-        # pcast above is an identity there) — disable its rep checker;
-        # vma-typed jax keeps the default strict check
-        check_vma=has_vma_typing(),
     )(stage_params, inputs)
     return outputs[-1]  # last stage's buffer
 

@@ -33,6 +33,7 @@ from typing import Any, Callable, Dict, Iterator, NamedTuple, Optional, Tuple, U
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.accelerator import get_accelerator
@@ -780,8 +781,6 @@ class DeepSpeedEngine:
         the sync — runtime/comm/nccl.py:54). shard_map over the data axis
         keeps grads LOCAL; the optimizer's error-compensated momentum sync
         is the only cross-device traffic (int8 signs over ICI)."""
-        from deepspeed_tpu.utils.jax_compat import shard_map
-
         if self._use_pld:
             log_dist("progressive_layer_drop is not supported on the 1-bit "
                      "compressed path; disabling", ranks=[0])

@@ -1,7 +1,7 @@
 """Fault-tolerant multi-replica serving fabric (ISSUE 9).
 
 The traffic layer over N :class:`~deepspeed_tpu.serving.engine.ServingEngine`
-replicas (ROADMAP item 2): health-checked least-loaded routing with
+replicas: health-checked least-loaded routing with
 per-replica circuit breakers, retry/backoff failover that resumes a
 dead replica's in-flight requests on a survivor bit-identically (greedy),
 bounded-queue backpressure + priority/deadline load shedding, and an

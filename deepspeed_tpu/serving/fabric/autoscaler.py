@@ -1,6 +1,6 @@
 """SLO-alert-driven elastic autoscaler for the serving fabric (ISSUE 16).
 
-Closes ROADMAP item 1's telemetry->action loop: PR 12's burn-rate
+Closes the loop from telemetry to action: PR 12's burn-rate
 alerts (telemetry/slo.py) and the router's load gauges become BOUNDED
 scale decisions against the elastic replica pool
 (:meth:`FabricRouter.add_replica` / :meth:`FabricRouter.remove_replica`).

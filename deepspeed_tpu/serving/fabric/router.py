@@ -1,10 +1,10 @@
 """Fault-tolerant request router over N serving replicas (ISSUE 9).
 
 The traffic layer that turns one :class:`ServingEngine` into a service:
-ROADMAP item 2's router/replica split, built with robustness as the
-headline — at "millions of users" scale replica failure is the steady
-state, and the fabric must keep serving (and keep its SLOs) through
-crashes, stragglers, and overload. Four pillars:
+a router over replicas, built with robustness as the headline — at
+"millions of users" scale replica failure is the steady state, and the
+fabric must keep serving (and keep its SLOs) through crashes, stragglers,
+and overload. Four pillars:
 
 **Health-checked dispatch.** Periodic heartbeat probes feed per-replica
 circuit breakers (fabric/health.py): ``failure_threshold`` consecutive

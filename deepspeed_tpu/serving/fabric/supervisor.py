@@ -83,11 +83,11 @@ class ReplicaSupervisor:
         self.tracer = tracer
         self._trace: Optional[str] = None
         # SLO alert subscription (ISSUE 13): transitions delivered via
-        # SLOEngine.set_alert_callback(supervisor.on_slo_alert) — today
+        # SLOEngine.set_alert_callback(supervisor.on_slo_alert) — here
         # they are recorded + evented (the operator sees WHICH objective
-        # burned while a replica was down); the elastic autoscaler
-        # (ROADMAP item 2) will act on them (scale out on sustained
-        # page-severity burn)
+        # burned while a replica was down); scaling out on a sustained
+        # page-severity burn is the autoscaler's, which subscribes itself
+        # (serving/fabric/autoscaler.py)
         self.slo_alerts: List = []
 
     def on_slo_alert(self, alert) -> None:

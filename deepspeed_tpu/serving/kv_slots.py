@@ -82,7 +82,7 @@ same shape would pin, but shared by an unbounded request stream. There is no
 fragmentation because pages are whole slots; the cost of that simplicity
 is internal padding (a short request holds a full slot row) — the
 iteration-level scheduler keeps slots hot, which is where the throughput
-win lives (ISSUE 2 / PROFILE_DECODE.md 4-4.8x batch-8 aggregate).
+win lives (ISSUE 2: 4-4.8x batch-8 aggregate).
 """
 
 from __future__ import annotations

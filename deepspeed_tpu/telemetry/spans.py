@@ -33,7 +33,7 @@ Design constraints, in order:
    (``trace_id``, ``parent_span``) riding on
    :class:`~deepspeed_tpu.serving.scheduler.Request` — exactly what a
    wire protocol would carry — so a request hopping replicas (failover,
-   ROADMAP item 2's cross-process fabric) keeps ONE trace id and the
+   a fabric whose replicas are processes) keeps ONE trace id and the
    survivor's spans link under the original root.
 
 Outputs: every finished span goes to the bounded in-memory buffer and,

@@ -6,14 +6,13 @@ Usage:
     python scripts/dstpu_lint.py [--root R] [--passes a,b] [--json]
                                  [--baseline PATH | --no-baseline]
                                  [--write-baseline]
-                                 [--jaxcompat-report PATH]
                                  [--changed-only] [--cache PATH]
                                  [--sarif PATH|-]
                                  [--list-passes] [--show-suppressed]
 
 Runs every registered pass (deepspeed_tpu/analysis/passes/) over
 ``deepspeed_tpu/``: host-sync, recompile-hazard, typed-error,
-jax-compat, donation-safety, metric-names, slo-rules, and the ISSUE 15
+donation-safety, metric-names, slo-rules, and the ISSUE 15
 TPU-native families — pallas-tile (dtype tile quanta), pallas-dma
 (start/wait pairing), vmem-budget (scratch + committed plans vs the
 ops/autotune.py capacity table), sharding-contract (interprocedural
@@ -56,52 +55,6 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def _write_jaxcompat_report(path: str, rows, root: str) -> None:
-    """LINT_JAXCOMPAT.md — the ROADMAP item 4 migration work-list."""
-    direct = [r for r in rows if r["status"] == "direct"]
-    shim = [r for r in rows if r["status"] == "shim"]
-    routed = [r for r in rows if r["status"] == "routed"]
-    lines = [
-        "# LINT_JAXCOMPAT — version-gated jax API call sites",
-        "",
-        "Generated by `python scripts/dstpu_lint.py --jaxcompat-report "
-        "LINT_JAXCOMPAT.md` (the `jax-compat` pass inventory). This is "
-        "the concrete work-list for ROADMAP item 4: every remaining "
-        "direct use of a renamed or version-gated jax API, plus the "
-        "sanctioned shim-internal sites that implement the "
-        "translation. The jax-compat lint fails on any NEW direct "
-        "site, so this list only burns down.",
-        "",
-        f"**Direct (must migrate): {len(direct)}** · "
-        f"shim-internal (sanctioned): {len(shim)} · "
-        f"routed through shims: {len(routed)}",
-        "",
-        "## Direct call sites (migration work-list)",
-        "",
-    ]
-    if direct:
-        lines += ["| site | api | migrate to |", "|---|---|---|"]
-        lines += [f"| `{r['path']}:{r['line']}` | {r['api']} | "
-                  f"{r['fix']} |" for r in direct]
-    else:
-        lines.append("*(none — every version-gated API routes through "
-                     "the shims)*")
-    lines += ["", "## Shim-internal sites (sanctioned)", "",
-              "| site | api |", "|---|---|"]
-    lines += [f"| `{r['path']}:{r['line']}` | {r['api']} |" for r in shim]
-    lines += ["", "## Call sites routed through the shims", "",
-              "The version-sensitive surface: every call that depends on "
-              "the compat layer's translation. When ROADMAP item 4 "
-              "finishes the migration (or a jax upgrade changes the "
-              "translation), these are the sites to re-verify.", "",
-              "| site | entry point | in |", "|---|---|---|"]
-    lines += [f"| `{r['path']}:{r['line']}` | {r['api'][10:]} | "
-              f"{r['symbol'] or '(module)'} |" for r in routed]
-    lines.append("")
-    with open(path, "w", encoding="utf-8") as f:
-        f.write("\n".join(lines))
-
-
 def main(argv=None) -> int:
     from deepspeed_tpu.analysis import (
         EXIT_CLEAN, EXIT_FINDINGS, EXIT_INTERNAL, EXIT_USAGE, Baseline,
@@ -125,9 +78,6 @@ def main(argv=None) -> int:
                     help="write the current findings as the baseline "
                          "(edit in a justification per entry before "
                          "committing)")
-    ap.add_argument("--jaxcompat-report", default=None, metavar="PATH",
-                    help="also write the jax-compat call-site inventory "
-                         "(LINT_JAXCOMPAT.md work-list artifact)")
     ap.add_argument("--changed-only", action="store_true",
                     help="incremental: reuse cached per-file findings "
                          "for unchanged files (git diff seeds the "
@@ -208,18 +158,6 @@ def main(argv=None) -> int:
         print(f"dstpu-lint: internal error: {type(e).__name__}: {e}",
               file=sys.stderr)
         return EXIT_INTERNAL
-
-    if args.jaxcompat_report:
-        jc = passes["jax-compat"]
-        rows = jc.inventory(corpus)   # reuse the already-parsed files
-        try:
-            _write_jaxcompat_report(args.jaxcompat_report, rows, root)
-        except OSError as e:
-            print(f"dstpu-lint: cannot write {args.jaxcompat_report}: "
-                  f"{e}", file=sys.stderr)
-            return EXIT_USAGE
-        print(f"dstpu-lint: wrote jax-compat inventory -> "
-              f"{args.jaxcompat_report}")
 
     if args.write_baseline:
         bl = Baseline(budget=len(result.findings), entries=[

@@ -1,8 +1,7 @@
 """Shared fixtures for the analysis suite.
 
 The whole-repo pins — clean end-to-end, the committed vmem-budget
-artifact, the jax-compat work-list, and the tier-1 wall-clock budget —
-all need the same expensive object: one cold full lint over the
+artifact and the tier-1 wall-clock budget — all need the same expensive object: one cold full lint over the
 committed tree (corpus parse + phase-1 index + every pass, exactly
 what `scripts/dstpu_lint.py` runs).  Running it once per pin cost
 tier-1 ~18 s; this session fixture pays for it once and hands the
@@ -27,11 +26,9 @@ REPO = os.path.abspath(os.path.join(os.path.dirname(__file__),
 @pytest.fixture(scope="session")
 def repo_full_lint():
     from deepspeed_tpu.analysis import Baseline, run_lint
-    from deepspeed_tpu.analysis.core import build_corpus
 
     t0 = time.monotonic()
-    corpus = build_corpus(REPO)
-    result = run_lint(REPO, corpus=corpus, baseline=Baseline.load(
+    result = run_lint(REPO, baseline=Baseline.load(
         os.path.join(REPO, "LINT_BASELINE.json")))
     elapsed = time.monotonic() - t0
-    return SimpleNamespace(corpus=corpus, result=result, elapsed=elapsed)
+    return SimpleNamespace(result=result, elapsed=elapsed)

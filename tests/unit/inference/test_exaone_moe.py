@@ -71,11 +71,11 @@ def test_the_tiny_model_is_the_stated_stack(built):
                              "full_attention", "sliding_attention")
     assert c.mlp_layer_types == ("dense", "sparse", "sparse", "sparse")
     assert c.held == (0, 2) and c.num_experts == 16
-    # (ffn, attention, first of the ffn's stack, first of the cache, count)
-    assert c.runs() == (("dense", "sliding_attention", 0, 0, 1),
-                        ("sparse", "sliding_attention", 0, 1, 1),
-                        ("sparse", "full_attention", 1, 0, 1),
-                        ("sparse", "sliding_attention", 2, 2, 1))
+    # ((ffn, attention), first of the ffn's stack, first of the cache, count)
+    assert model.runs() == ((("dense", "sliding_attention"), 0, 0, 1),
+                            (("sparse", "sliding_attention"), 0, 1, 1),
+                            (("sparse", "full_attention"), 1, 0, 1),
+                            (("sparse", "sliding_attention"), 2, 2, 1))
     n = sum(x.size for x in jax.tree_util.tree_leaves(params))
     assert n == model.num_params() == family.shapes(CFG)["params"]
     assert jax.tree_util.tree_structure(params) == \

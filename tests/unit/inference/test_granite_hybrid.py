@@ -53,7 +53,9 @@ def test_the_tiny_model_is_the_stated_stack(built):
     model, params, _, _ = built
     c = model.config
     assert c.layer_types == ("mamba", "mamba", "attention", "mamba")
-    assert c.runs() == (("mamba", 0, 2), ("attention", 0, 1), ("mamba", 2, 1))
+    # (kind, first of its stack, first of its cache leaves, count)
+    assert model.runs() == (("mamba", 0, 0, 2), ("attention", 0, 0, 1),
+                            ("mamba", 2, 2, 1))
     assert (c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state,
             c.mamba_chunk_size) == (4, 32, 16, 8)
     n = sum(x.size for x in jax.tree_util.tree_leaves(params))

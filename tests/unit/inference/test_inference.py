@@ -114,7 +114,7 @@ def test_sampling_reproducible_and_topk(gpt2):
 
 def test_compiled_programs_accessor_and_kv_padding():
     """compiled_programs() exposes the exact prefill/decode programs
-    generate() uses (benches time them directly — PROFILE_DECODE.md), and
+    generate() uses (benches time them directly), and
     the KV allocation pads to a multiple of 128 (flash-decode tiling)
     while masking keeps padded positions inert: the accessor-driven
     two-program path must reproduce generate()'s tokens exactly."""

@@ -54,11 +54,11 @@ def test_the_tiny_model_is_the_stated_stack(built):
     assert c.hybrid_layer_pattern == (0, 1, 1, 1, 1, 0, 1)
     assert c.moe_layer_freq == (0, 1, 1, 1, 1, 1, 1)
     assert c.held == (0, 2) and c.num_experts == 16
-    # (ffn, attention, first of the pair's stack, first of the cache, count)
-    assert c.runs() == (("dense", GLOBAL, 0, 0, 1),
-                        ("sparse", SLIDING, 0, 0, 4),
-                        ("sparse", GLOBAL, 0, 1, 1),
-                        ("sparse", SLIDING, 4, 4, 1))
+    # ((ffn, attention), first of the pair's stack, first of the cache, count)
+    assert model.runs() == ((("dense", GLOBAL), 0, 0, 1),
+                            (("sparse", SLIDING), 0, 0, 4),
+                            (("sparse", GLOBAL), 0, 1, 1),
+                            (("sparse", SLIDING), 4, 4, 1))
     assert model.stacks == ("dense_global", "sparse_sliding", "sparse_global")
     n = sum(x.size for x in jax.tree_util.tree_leaves(params))
     assert n == model.num_params() == family.shapes(CFG)["params"]

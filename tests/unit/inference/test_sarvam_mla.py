@@ -71,7 +71,8 @@ def _jitted_step(model):
 def test_the_tiny_model_is_the_stated_stack(built):
     model, params, _, _ = built
     c = model.config
-    assert c.runs() == (("dense", 0, 1), ("sparse", 1, 2))
+    # (kind, first of its stack, first of the cache leaf, count)
+    assert model.runs() == (("dense", 0, 0, 1), ("sparse", 0, 1, 2))
     assert c.held == (0, 2) and c.num_experts == 16
     assert (c.q_head_dim, c.row_width, c.prompt_block, c.key_block) == \
         (24, 128, 16, 8)

@@ -12,8 +12,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from deepspeed_tpu.utils.jax_compat import shard_map
 
 from deepspeed_tpu.ops.onebit import (
     OnebitAdam,
