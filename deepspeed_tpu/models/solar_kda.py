@@ -28,10 +28,15 @@ state by that declaration alone (serving/kv_slots.py).
 where the shapes fold (ops/kda.supports) everything between the projections
 and the output matmul is the one call ``dstpu_kda_update``. **A prompt block**
 runs the chunked form from the layer's state and writes the state at the true
-length back; a prompt longer than ``prompt_block`` passes the whole stack a
-block of tokens at a time inside the one program call, the state and tails
-carried from block to block, and the softmax layer attends rows ``[0, end of
-block)`` in key blocks with a running softmax (scope ``dstpu_gqa_prefill``:
+length back: with a cache, on a TPU and where the shapes fit
+(ops/kda.supports_prefill) everything between the convolutions and the output
+matmul is the one call ``dstpu_kda_prefill``, which reads the convolution's
+result and the gates where they lie; elsewhere (training, a CPU, a tiny
+configuration) XLA's own norms, decay, ``kda_chunked``, head norm and gate. A
+prompt longer than ``prompt_block`` passes the whole stack a block of tokens
+at a time inside the one program call, the state and tails carried from block
+to block, and the softmax layer attends rows ``[0, end of block)`` in key
+blocks with a running softmax (scope ``dstpu_gqa_prefill``:
 on a TPU ops/gqa_prefill.py's one call, which keeps a key block's scores in
 VMEM, elsewhere ops/attention.blocked_prompt_attention). A prefill that is
 told the prompt's true length (``valid_len``) computes its head there alone:
@@ -301,9 +306,14 @@ class SolarKdaModel(StackedDecoder):
 
     def _kda_mixer(self, qkv, g_pre, beta, gate_pre, blk, kda_full, tail_full,
                    layer, idx, valid, step):
-        """The mixer out of XLA's own operations: the carried convolutions,
-        the norms, the one-token update or the chunked prompt form, the head
-        norm and the gate -> ``(o [B, T, H, V], kda_full, tail_full)``."""
+        """The mixer between the projections and the output matmul -> ``(o [B,
+        T, H, V], kda_full, tail_full)``. The carried convolutions are XLA's
+        own. Behind them a cached prompt block on a TPU whose shapes fit is
+        the one call :func:`kda.kda_prefill`, which takes the convolution's
+        result and the gates as they are; everything else (no cache,
+        training, a CPU, shapes that fit no tile, the split one-token step)
+        is XLA's too: the norms, the decay, the one-token update or the
+        chunked prompt form, the head norm and the gate."""
         c = self.config
         b, t, _ = qkv.shape
         h, dk, w = c.kda_heads, c.kda_head_dim, c.kda_width
@@ -321,6 +331,22 @@ class SolarKdaModel(StackedDecoder):
                 s0 = jnp.where(fresh[..., None], 0, s0)
         act, tail1 = causal_conv(qkv, tail0, blk["conv_w"],
                                  jnp.zeros((3 * w,), jnp.float32), valid)
+        if tail_full is not None:
+            tail_full = jax.lax.dynamic_update_index_in_dim(
+                tail_full, tail1.reshape((b,) + tail_full.shape[2:]).astype(
+                    tail_full.dtype), layer, 0)
+        # serving only (the kernel has no VJP): a cache, a TPU, shapes that
+        # fit; training and everything else take the chunked form
+        if step is None and s0 is not None \
+                and kda.default_route() == "pallas" \
+                and kda.supports_prefill(t, h, dk, dk, c.kda_chunk):
+            kda.count_prefill_kernel()
+            o, s1 = kda.kda_prefill(
+                act, g_pre, beta, gate_pre, kda.fold_layer(blk), s0,
+                chunk=c.kda_chunk, eps=c.eps, length=valid)
+            return o.reshape(b, t, h, dk), \
+                jax.lax.dynamic_update_index_in_dim(
+                    kda_full, s1.astype(kda_full.dtype), layer, 0), tail_full
         q, k_, v_ = (a.reshape(b, t, h, dk) for a in jnp.split(act, 3, -1))
         q = kda.l2_normalize(q) * dk ** -0.5
         k_ = kda.l2_normalize(k_)
@@ -332,22 +358,12 @@ class SolarKdaModel(StackedDecoder):
                                          step["active"])
             o = o[:, None]
         else:
-            # serving only (the kernel has no VJP): a cache, a TPU, shapes
-            # that fit; training and everything else take the chunked form
-            kernel = s0 is not None and kda.default_route() == "pallas" \
-                and kda.supports_prefill(t, h, dk, dk, c.kda_chunk)
-            count, prompt = (kda.count_prefill_kernel, kda.kda_prefill) \
-                if kernel else (kda.count_chunked_block, kda.kda_chunked)
-            count()
-            o, s1 = prompt(q, k_, v_, g, beta, chunk=c.kda_chunk,
-                           init_state=s0, length=valid)
+            kda.count_chunked_block()
+            o, s1 = kda.kda_chunked(q, k_, v_, g, beta, chunk=c.kda_chunk,
+                                    init_state=s0, length=valid)
             if kda_full is not None:
                 kda_full = jax.lax.dynamic_update_index_in_dim(
                     kda_full, s1.astype(kda_full.dtype), layer, 0)
-        if tail_full is not None:
-            tail_full = jax.lax.dynamic_update_index_in_dim(
-                tail_full, tail1.reshape((b,) + tail_full.shape[2:]).astype(
-                    tail_full.dtype), layer, 0)
         o = rms_norm(o, blk["o_norm"], c.eps) * jax.nn.sigmoid(
             gate_pre.reshape(b, t, h, dk).astype(jnp.float32))
         return o.astype(qkv.dtype), kda_full, tail_full

@@ -38,19 +38,26 @@ Entry points, by serving phase:
     overflows where a channel decays fast), the in-chunk dependence is a unit
     lower-triangular solve, and a short ``lax.scan`` carries the state from
     chunk to chunk. XLA's own operations: the route of a CPU, of training
-    (it is differentiable) and of shapes the kernel does not fit, and the
-    kernel's reference. Positions at or beyond ``length`` get ``g = 0`` and
-    ``beta = 0``: the state stops at the true length (padding is not
-    invisible to a recurrence).
-  * :func:`kda_prefill` — the same block, for a cached prompt on a TPU, as
-    the one Pallas call ``dstpu_kda_prefill``: a grid cell ``PREFILL_HEADS``
-    heads of a chunk, the chunk axis sequential with the heads' state in
-    VMEM from the block's first chunk to its last; the chunk in sub-chunks of
-    ``SUB``, whose own pairwise decays are the only ones formed element by
-    element and whose part of the solve is a forward substitution, all else
-    matmuls of rows and columns scaled against a sub-chunk's first position;
-    a chunk of padding is neither fetched nor computed. Taken where
-    :func:`supports_prefill` says the shapes fit.
+    (it is differentiable) and of shapes the kernel does not fit. Positions
+    at or beyond ``length`` get ``g = 0`` and ``beta = 0``: the state stops
+    at the true length (padding is not invisible to a recurrence).
+  * :func:`kda_prefill` — a cached prompt block on a TPU, everything a
+    layer does between its convolutions and its output matmul, as the one
+    Pallas call ``dstpu_kda_prefill``: it takes ``q | k | v`` as the
+    convolution leaves them and the two gates as the low-rank matmuls leave
+    them, in the stream's dtype, and hands back gated, normed heads in that
+    dtype. A grid cell ``PREFILL_HEADS`` heads of a chunk, the chunk axis
+    sequential with the heads' state in VMEM from the block's first chunk to
+    its last. In a chunk, in float32: the L2 norms and ``-exp(A_log)
+    softplus(. + dt_bias)`` (the layer's small weights from
+    :func:`fold_layer`); the chunk in sub-chunks of ``SUB``, whose own
+    pairwise decays are the only ones formed element by element and whose
+    part of the solve is a forward substitution, all else matmuls of rows and
+    columns scaled against a sub-chunk's first position; the head-wise RMS
+    norm and the sigmoid gate on a head's result. A chunk of padding is
+    neither fetched nor computed. Taken where :func:`supports_prefill` says
+    the shapes fit; :func:`kda_chunked` between :func:`l2_normalize`,
+    :func:`log_decay` and XLA's norm and gate is its reference.
 
 The step is bound by memory (the state is read and written once a token, 0.87
 FLOPs a byte). The two kernels serve only: no VJP.
@@ -165,6 +172,19 @@ def tail_shape(taps: int, heads: int, key_dim: int):
     return (taps - 1, 3, heads, key_dim)
 
 
+def _fold_gates(a, dt_bias, o_norm):
+    """``a = -exp(A_log)`` a head spread over its lanes beside ``dt_bias``
+    ``[.., 2, H, 128]`` and the head norm's weight ``[.., 1, 128]``, float32:
+    a layer's, or with a leading ``L`` a stack's."""
+    f32 = jnp.float32
+    rows = a.shape + (LANES,)
+    return {
+        "heads": jnp.stack([jnp.broadcast_to(a[..., None], rows),
+                            dt_bias.astype(f32).reshape(rows)], axis=-3),
+        "o_norm": o_norm.astype(f32).reshape(rows[:-2] + (1, LANES)),
+    }
+
+
 def fold_weights(stack, heads: int):
     """The layer stack's small weights as the folded call reads them through
     its index maps, float32, made once a step: the taps ``[L, taps, 3, H,
@@ -176,11 +196,16 @@ def fold_weights(stack, heads: int):
     return {
         "conv_w": stack["conv_w"].astype(f32).reshape(lk, taps, 3, heads,
                                                       LANES),
-        "heads": jnp.stack(
-            [jnp.broadcast_to(a[..., None], (lk, heads, LANES)),
-             stack["dt_bias"].astype(f32).reshape(lk, heads, LANES)], axis=1),
-        "o_norm": stack["o_norm"].astype(f32).reshape(lk, 1, LANES),
+        **_fold_gates(a, stack["dt_bias"], stack["o_norm"]),
     }
+
+
+def fold_layer(blk):
+    """ONE layer's decay and head-norm weights as :func:`fold_weights` gives a
+    stack's, for the prompt kernel: ``{"heads": [2, H, 128], "o_norm": [1,
+    128]}`` float32, a few KB a call (the taps stay the convolution's)."""
+    return _fold_gates(-jnp.exp(blk["A_log"].astype(jnp.float32)),
+                       blk["dt_bias"], blk["o_norm"])
 
 
 def _columns(rows, hb: int):
@@ -451,17 +476,21 @@ def supports_prefill(tokens: int, heads: int, key_dim: int, value_dim: int,
             and tokens % chunk == 0)
 
 
-def _prefill_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref,
-                    o_ref, s1_ref, st, q_t, k_t, v_t, g_t, b_t, xv_t, xk_t,
-                    t_t, a_t, *, c: int, hb: int):
-    """``hb`` heads of one chunk of ``c`` positions of one row. The operands'
-    blocks are ``[c, hb x 128]``: a head's ``[c, 128]`` is a slice of whole
-    tiles. The ``_t`` scratches are ``[c x hb, 128]``, a position's ``hb``
-    heads one tile: head ``h``'s rows are every ``hb``-th. ``st`` holds the
-    heads' states TRANSPOSED (``[values, keys]``: the decay then scales
-    lanes) from the row's first chunk to its last."""
+def _prefill_kernel(len_ref, q_ref, k_ref, v_ref, gp_ref, og_ref, b_ref,
+                    hp_ref, nw_ref, s0_ref, o_ref, s1_ref, st, q_t, k_t, v_t,
+                    g_t, b_t, xv_t, xk_t, t_t, a_t, *, c: int, hb: int,
+                    eps: float):
+    """``hb`` heads of one chunk of ``c`` positions of one row, between the
+    convolution and the output matmul. The operands' blocks are ``[c, hb x
+    128]`` in the stream's dtype (``q_ref``, ``k_ref``, ``v_ref`` three
+    column blocks of the one activation): a head's ``[c, 128]`` is a slice of
+    whole tiles; ``b_ref [c, H]`` is ``beta`` of every head. The ``_t``
+    scratches are ``[c x hb, 128]`` float32, a position's ``hb`` heads one
+    tile: head ``h``'s rows are every ``hb``-th. ``st`` holds the heads'
+    states TRANSPOSED (``[values, keys]``: the decay then scales lanes) from
+    the row's first chunk to its last."""
     f32 = jnp.float32
-    row, n = pl.program_id(0), pl.program_id(2)
+    row, cell, n = (pl.program_id(axis) for axis in range(3))
     length = len_ref[row]
     nsub = c // SUB
 
@@ -503,18 +532,31 @@ def _prefill_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref,
 
     @pl.when(n * c < length)
     def _live():
-        # ---- every head at once, a position a [hb, 128] tile
+        # ---- the mixer's row-local float32 work, a head's [c, 128] at a
+        # time: the L2 norms, the query's scale, the log decay
+        head = jax.lax.broadcasted_iota(jnp.int32, b_ref.shape, 1)
+        live = n * c + jax.lax.broadcasted_iota(jnp.int32, (c, 1), 0) < length
+
         @heads
         def _(h):
-            for ref, to in ((q_ref, q_t), (k_ref, k_t), (v_ref, v_t),
-                            (g_ref, g_t)):
-                to[of_head(h), :] = ref[:, lanes(h)].astype(f32)
+            def rows(ref):
+                return ref[:, lanes(h)].astype(f32)
 
+            q_t[of_head(h), :] = l2_normalize(rows(q_ref)) * LANES ** -0.5
+            k_t[of_head(h), :] = l2_normalize(rows(k_ref))
+            v_t[of_head(h), :] = rows(v_ref)
+            g_t[of_head(h), :] = hp_ref[0, pl.ds(h, 1), :] * jax.nn.softplus(
+                rows(gp_ref) + hp_ref[1, pl.ds(h, 1), :])
+            # the head's column of ``beta``, spread over the lanes here
+            mine = jnp.sum(jnp.where(head == cell * hb + h, b_ref[...], 0.0),
+                           axis=1, keepdims=True)
+            b_t[of_head(h), :] = jnp.broadcast_to(
+                jnp.where(live, mine, 0.0), (c, LANES))
+
+        # ---- every head at once, a position a [hb, 128] tile
         def cum(i, acc):
-            live = n * c + i < length
-            acc = acc + jnp.where(live, g_t[tile(i), :], 0.0)
+            acc = acc + jnp.where(n * c + i < length, g_t[tile(i), :], 0.0)
             g_t[tile(i), :] = acc
-            b_t[tile(i), :] = jnp.where(live, b_ref[i], 0.0)
             return acc
 
         jax.lax.fori_loop(0, c, cum, jnp.zeros((hb, LANES), f32))
@@ -570,8 +612,7 @@ def _prefill_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref,
 
         @heads
         def _(h):
-            q, k = q_ref[:, lanes(h)].astype(f32), k_ref[:, lanes(h)].astype(
-                f32)
+            q, k = q_t[of_head(h), :], k_t[of_head(h), :]
             gc, bt = g_t[of_head(h), :], b_t[of_head(h), :]
             firsts = [jnp.broadcast_to(gc[a * SUB:a * SUB + 1], (SUB, LANES))
                       for a in range(nsub)]
@@ -610,6 +651,10 @@ def _prefill_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref,
                      ((1,), (1,)))                           # [2 c, V]
             u = u0 - ws[:c]
             o = ws[c:] + dot(a_qk, u, ((1,), (0,)))
+            # the head-wise RMS norm and the sigmoid gate, one rounding
+            var = jnp.sum(o * o, axis=-1, keepdims=True) / LANES
+            o = o * jax.lax.rsqrt(var + eps) * nw_ref[...] * jax.nn.sigmoid(
+                og_ref[:, lanes(h)].astype(f32))
             o_ref[:, lanes(h)] = o.astype(o_ref.dtype)
             last = gc[c - 1:c]
             k_out = k * jnp.exp(last - gc)
@@ -622,79 +667,107 @@ def _prefill_kernel(len_ref, q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref,
             s1_ref[h] = st[h].T.astype(s1_ref.dtype)
 
 
-def kda_prefill(q, k, v, g, beta, *, chunk: int, init_state, length=None,
+def kda_prefill(act, g_pre, beta, gate_pre, weights, init_state, *,
+                chunk: int, eps: float, length=None,
                 interpret: Optional[bool] = None):
-    """:func:`kda_chunked` for a cached prompt block as the one Pallas call
-    ``dstpu_kda_prefill``: same operands (``init_state [B, H, 128, 128]``
-    given), same results in float32, every intermediate of a chunk in VMEM.
+    """A cached prompt block through a layer's mixer between the convolution
+    and the output matmul, as the one Pallas call ``dstpu_kda_prefill``.
+
+    ``act [B, T, 3 H 128]``: ``q | k | v`` as ``causal_conv`` returns them,
+    in the stream's dtype; ``g_pre``, ``gate_pre [B, T, H 128]``: the two
+    low-rank gates before ``softplus`` / ``sigmoid``, in the stream's dtype;
+    ``beta [B, T, H]`` float32, after ``2 sigmoid``; ``weights``:
+    :func:`fold_layer`; ``init_state [B, H, 128, 128]``. Returns ``(o [B, T,
+    H 128]`` in ``act``'s dtype, what the output matmul takes``, state [B, H,
+    128, 128] float32)``: :func:`l2_normalize`, :func:`log_decay`,
+    :func:`kda_chunked`, the head-wise RMS norm (``eps``) and the gate, every
+    intermediate of a chunk in VMEM and float32, ``o`` rounded once.
 
     A grid cell is ``PREFILL_HEADS`` heads of one chunk; the chunk axis is
     sequential and carries the heads' state in a scratch, read from
     ``init_state`` at the first chunk and written once behind the last. The
-    operands are fetched as the projections and the convolution leave them
-    (``[B, T, H x 128]``, a position a row: a block is ``[chunk, heads x
-    128]`` and a head's part of it whole tiles; no transposed copy is made
-    outside). In a chunk the cumulative logs, each sub-chunk's own scores (the
-    only ``exp(G_i - G_j)`` formed element by element) and its forward
-    substitution run for the cell's heads at once, on copies in VMEM that
-    hold a position's heads as one tile; then, a head at a time on the MXU:
-    the scores between sub-chunks (rows times ``exp(G_i - G_r)`` against
-    columns times ``exp(G_r - G_j)``, ``r`` the row sub-chunk's first
-    position: both exponents at most 0), the solve across sub-chunks and
-    :func:`kda_chunked`'s chunk step. Positions at or beyond ``length`` get
-    ``g = 0``, ``beta = 0``; a chunk that holds none before ``length`` is not
-    fetched, does no arithmetic, writes zeros and leaves the state as it is.
-    Float32 scores, solve, state and accumulations, matmuls at
-    ``Precision.HIGHEST``. Serving only: no VJP."""
-    b, t, h, dk = k.shape
-    assert supports_prefill(t, h, dk, v.shape[-1], chunk), (
-        k.shape, v.shape, chunk)
+    operands are fetched where the projections and the convolution left them
+    (a position a row: a block is ``[chunk, heads x 128]`` and a head's part
+    of it whole tiles; ``q``, ``k`` and ``v`` are three column blocks of the
+    one ``act``, ``beta`` a chunk's ``[chunk, H]``: no split, no float32
+    copy, no transposed copy, nothing spread over lanes is made outside). In
+    a live chunk, first the row-local work a head at a time: the L2 norms,
+    the query's ``128 ** -0.5``, ``g = -exp(A_log) softplus(g_pre +
+    dt_bias)``, the head's column of ``beta``. Then the cumulative logs, each sub-chunk's
+    own scores (the only ``exp(G_i - G_j)`` formed element by element) and
+    its forward substitution run for the cell's heads at once, on copies in
+    VMEM that hold a position's heads as one tile; then, a head at a time on
+    the MXU: the scores between sub-chunks (rows times ``exp(G_i - G_r)``
+    against columns times ``exp(G_r - G_j)``, ``r`` the row sub-chunk's first
+    position: both exponents at most 0), the solve across sub-chunks,
+    :func:`kda_chunked`'s chunk step and, on its result, the head norm and
+    the gate. Positions at or beyond ``length`` get ``g = 0``, ``beta = 0``;
+    a chunk that holds none before ``length`` is not fetched, does no
+    arithmetic, writes zeros and leaves the state as it is. Float32 scores,
+    solve, state and accumulations, matmuls at ``Precision.HIGHEST``. Serving
+    only: no VJP."""
+    b, t, _ = g_pre.shape
+    h = init_state.shape[1]
+    assert supports_prefill(t, h, *init_state.shape[2:], chunk) \
+        and act.shape == (b, t, 3 * h * LANES), (act.shape, init_state.shape,
+                                                 chunk)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     length = jnp.full((b,), t, jnp.int32) if length is None else \
         jnp.broadcast_to(jnp.asarray(length, jnp.int32), (b,))
-    return _prefill_call(length, q, k, v, g, beta, init_state, chunk=chunk,
-                         interpret=interpret)
+    return _prefill_call(length, act, g_pre, gate_pre, beta, weights["heads"],
+                         weights["o_norm"], init_state, chunk=chunk,
+                         eps=float(eps), interpret=interpret)
 
 
 # jitted (and inlined where it is called), so that the kernel's body, some 700
 # equations, is traced once a process and not once in each of the four prefill
 # programs that hold it: set-up time on every warm start (PERF.md, PR 49)
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"),
+@functools.partial(jax.jit, static_argnames=("chunk", "eps", "interpret"),
                    inline=True)
-def _prefill_call(length, q, k, v, g, beta, init_state, *, chunk: int,
-                  interpret: bool):
+def _prefill_call(length, act, g_pre, gate_pre, beta, gates, o_norm,
+                  init_state, *, chunk: int, eps: float, interpret: bool):
     f32 = jnp.float32
-    b, t, h, dk = k.shape
+    b, t, _ = g_pre.shape
+    h, dk = init_state.shape[1:3]
     c, hb = chunk, PREFILL_HEADS
+    nh = h // hb
 
     def live_chunk(i, n, len_ref):
         # chunks of padding stay on the last live one: nothing is fetched
         return jnp.minimum(n, jnp.maximum(len_ref[i] - 1, 0) // c)
 
-    rows = pl.BlockSpec((None, c, hb * LANES), lambda i, j, n, len_ref: (
-        i, live_chunk(i, n, len_ref), j))
+    def rows(part):
+        """The cell's heads of a chunk in column block ``part`` of an
+        operand's blocks of ``H x 128``."""
+        return pl.BlockSpec((None, c, hb * LANES), lambda i, j, n, len_ref: (
+            i, live_chunk(i, n, len_ref), part * nh + j))
+
     state = pl.BlockSpec((None, hb, dk, LANES),
                          lambda i, j, n, len_ref: (i, j, 0, 0))
     tile = pltpu.VMEM((c * hb, LANES), f32)
-    o, s1 = pl.pallas_call(
-        functools.partial(_prefill_kernel, c=c, hb=hb),
+    return pl.pallas_call(
+        functools.partial(_prefill_kernel, c=c, hb=hb, eps=eps),
         name="dstpu_kda_prefill",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(b, h // hb, t // c),
-            in_specs=[rows] * 4 + [
-                pl.BlockSpec((None, c, hb, LANES), lambda i, j, n, len_ref: (
-                    i, live_chunk(i, n, len_ref), j, 0)), state],
+            num_scalar_prefetch=1, grid=(b, nh, t // c),
+            in_specs=[
+                rows(0), rows(1), rows(2),              # q | k | v of ``act``
+                rows(0), rows(0),                       # the two gates
+                pl.BlockSpec((None, c, h), lambda i, j, n, len_ref: (
+                    i, live_chunk(i, n, len_ref), 0)),      # beta, every head
+                pl.BlockSpec((2, hb, LANES),
+                             lambda i, j, n, len_ref: (0, j, 0)),
+                pl.BlockSpec((1, LANES), lambda i, j, n, len_ref: (0, 0)),
+                state],
             out_specs=[pl.BlockSpec((None, c, hb * LANES),
                                     lambda i, j, n, len_ref: (i, n, j)),
                        state],
             scratch_shapes=[pltpu.VMEM((hb, LANES, dk), f32)] + [tile] * 9),
-        out_shape=[jax.ShapeDtypeStruct((b, t, h * LANES), f32),
+        out_shape=[jax.ShapeDtypeStruct((b, t, h * LANES), act.dtype),
                    jax.ShapeDtypeStruct((b, h, dk, LANES), f32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(length, *(x.reshape(b, t, h * LANES) for x in (q, k, v, g)),
-      jnp.broadcast_to(beta.astype(f32)[..., None], (b, t, h, LANES)),
+    )(length, act, act, act, g_pre, gate_pre, beta.astype(f32), gates, o_norm,
       init_state.astype(f32))
-    return o.reshape(b, t, h, LANES), s1

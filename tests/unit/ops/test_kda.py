@@ -3,9 +3,11 @@ against the token-by-token recurrence written out here (also under fast decay
 and with ``beta`` near 2), the one-token ``jnp`` update as one step of that
 recurrence, a layer's whole decode step folded into the Pallas call, in
 interpret mode, against the carried convolution + the ``jnp`` update, and the
-prompt block as the Pallas call ``dstpu_kda_prefill``, in interpret mode,
-against the chunked form and the recurrence, at the cell's key and value width
-with one group of heads."""
+prompt block between the convolution and the output matmul as the Pallas call
+``dstpu_kda_prefill``, in interpret mode with bf16 operands, against the split
+route (``l2_normalize`` -> ``log_decay`` -> ``kda_chunked`` -> the head norm x
+the gate) and the recurrence, at the cell's key and value width with one group
+of heads."""
 
 import functools
 
@@ -224,13 +226,14 @@ def test_the_kernel_has_a_stable_name_of_its_own():
 
 
 # ------------------------------------------------ the prompt block's kernel
-HB, D, CHUNK = kda.PREFILL_HEADS, kda.LANES, 64
+HB, D, CHUNK, EPS = kda.PREFILL_HEADS, kda.LANES, 64, 1e-5
+BF16 = jnp.bfloat16
 
 
 @functools.lru_cache(maxsize=None)
 def _prefill():
     """One compilation a block length for every case below."""
-    return jax.jit(functools.partial(kda.kda_prefill, chunk=CHUNK,
+    return jax.jit(functools.partial(kda.kda_prefill, chunk=CHUNK, eps=EPS,
                                      interpret=True))
 
 
@@ -239,26 +242,82 @@ def _state(seed, b=1):
                        jnp.float32)
 
 
-def _assert_prefill(args, s0, length, rtol=1e-4, atol=2e-5):
-    """The kernel against the chunked form and the recurrence up to
-    ``length``; zeros behind the last chunk that holds a real position."""
-    t = args[0].shape[1]
-    o, s = _prefill()(*args, init_state=s0, length=jnp.asarray([length]))
-    o, s = np.asarray(o), np.asarray(s)
-    assert np.isfinite(o).all() and np.isfinite(s).all()
+def _prompt_operands(b, t, seed, beta_range=(0.1, 1.9), bias=-3.0,
+                     dtype=BF16):
+    """What the mixer holds behind the convolution, in the stream's dtype:
+    ``(act [B, T, 3 H 128], g_pre, beta float32, gate_pre)`` and the layer's
+    small weights; ``bias`` shifts ``dt_bias``, the decay's speed."""
+    rng = np.random.RandomState(seed)
+    w = HB * D
+    f = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)  # noqa: E731
+    blk = {"A_log": jnp.asarray(np.log(rng.uniform(1, 4, (HB,))), jnp.float32),
+           "dt_bias": f(w) + bias, "o_norm": 1.0 + 0.1 * f(D)}
+    beta = jnp.asarray(rng.uniform(*beta_range, (b, t, HB)), jnp.float32)
+    return (f(b, t, 3 * w).astype(dtype), f(b, t, w).astype(dtype), beta,
+            f(b, t, w).astype(dtype)), blk
+
+
+def _split_operands(ops, blk):
+    """``l2_normalize`` and ``log_decay`` as the split route applies them:
+    ``(q, k, v, g, beta)``, what ``kda_chunked`` and ``_sequential`` take."""
+    act, g_pre, beta, _ = ops
+    b, t, _ = g_pre.shape
+    q, k, v = (x.reshape(b, t, HB, D) for x in jnp.split(act, 3, -1))
+    g = kda.log_decay(g_pre.reshape(b, t, HB, D), blk["A_log"],
+                      blk["dt_bias"].reshape(HB, D))
+    return (kda.l2_normalize(q) * D ** -0.5, kda.l2_normalize(k),
+            v.astype(jnp.float32), g, beta)
+
+
+def _head_norm_and_gate(o, ops, blk):
+    """``rms_norm(o; o_norm) * sigmoid(gate_pre)`` in float64 -> ``[B, T, H x
+    128]``, not rounded."""
+    o = np.asarray(o, np.float64)
+    gate = np.asarray(ops[3], np.float64).reshape(o.shape)
+    o = o / np.sqrt((o * o).mean(-1, keepdims=True) + EPS) \
+        * np.asarray(blk["o_norm"], np.float64) / (1.0 + np.exp(-gate))
+    return o.reshape(o.shape[:2] + (HB * D,))
+
+
+def _assert_rounded_once(o, want, slack=2e-5):
+    """``o`` in the stream's dtype lies within HALF a unit of its last place
+    of the unrounded ``want`` (and the float32 arithmetic's ``slack``):
+    rounded once, and from float32. A second rounding on the way (an ``o``
+    stored in bf16 before the norm, a gate applied in bf16) doubles that."""
+    bits = 7 if o.dtype == BF16 else 23
+    o = np.asarray(o.astype(jnp.float32), np.float64)
+    unit = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(want), 1e-30))) - bits)
+    room = unit / 2 + slack * (1 + np.abs(want))
+    worst = np.max(np.abs(o - want) - room)
+    assert worst <= 0, worst
+
+
+def _assert_prefill(ops, blk, s0, length, slack=2e-5):
+    """The folded call against the split route (``l2_normalize`` ->
+    ``log_decay`` -> ``kda_chunked`` -> the head norm x the gate) and against
+    the recurrence up to ``length``; zeros behind the last chunk that holds a
+    real position."""
+    o, s = _prefill()(*ops, kda.fold_layer(blk), s0,
+                      length=jnp.asarray([length]))
+    assert o.dtype == ops[0].dtype and o.shape == ops[1].shape
+    assert s.dtype == jnp.float32
+    assert np.isfinite(np.asarray(o.astype(jnp.float32))).all() \
+        and np.isfinite(np.asarray(s)).all()
+    args = _split_operands(ops, blk)
     o_c, s_c = kda.kda_chunked(*args, chunk=CHUNK, init_state=s0,
                                length=length)
     o_r, s_r = _sequential(*args, s0=s0, length=length)
     live = -(-length // CHUNK) * CHUNK
     # inside a live chunk the padded positions read the state as the chunked
     # form's do
-    np.testing.assert_allclose(o[:, :live], np.asarray(o_c)[:, :live],
-                               rtol=rtol, atol=atol)
-    np.testing.assert_allclose(o[:, :length], o_r[:, :length], rtol=rtol,
-                               atol=atol)
-    assert not o[:, live:].any()
-    np.testing.assert_allclose(s, np.asarray(s_c), rtol=rtol, atol=atol)
-    np.testing.assert_allclose(s, s_r, rtol=rtol, atol=atol)
+    _assert_rounded_once(o[:, :live], _head_norm_and_gate(o_c, ops, blk)[
+        :, :live], slack)
+    _assert_rounded_once(o[:, :length], _head_norm_and_gate(o_r, ops, blk)[
+        :, :length], slack)
+    assert not np.asarray(o[:, live:].astype(jnp.float32)).any()
+    s = np.asarray(s)
+    np.testing.assert_allclose(s, np.asarray(s_c), rtol=1e-4, atol=slack)
+    np.testing.assert_allclose(s, s_r, rtol=1e-4, atol=slack)
     return o, s
 
 
@@ -267,71 +326,119 @@ def _assert_prefill(args, s0, length, rtol=1e-4, atol=2e-5):
     (128, 1, False), (256, 70, True), (256, 192, False)],
     ids=["fresh", "carried", "inside-a-sub-chunk", "on-a-chunk-edge",
          "one-position", "chunks-of-padding", "a-chunk-of-padding"])
-def test_prefill_kernel_matches_the_chunked_form_and_the_recurrence(
+def test_prefill_kernel_matches_the_split_route_and_the_recurrence(
         t, length, carried):
-    args = _inputs(1, t, HB, D, D, seed=21)
+    ops, blk = _prompt_operands(1, t, seed=21)
     s0 = _state(22) if carried else jnp.zeros((1, HB, D, D), jnp.float32)
-    _assert_prefill(args, s0, length)
+    _assert_prefill(ops, blk, s0, length)
 
 
 def test_prefill_kernel_leaves_a_row_of_padding_alone():
     """``length`` 0: zeros out, the state bit for bit, nothing non-finite
     even where the operands of the padding are."""
-    q, k, v, g, beta = _inputs(1, 128, HB, D, D, seed=23)
-    v = v.at[:, 5].set(jnp.inf)
+    (act, g_pre, beta, gate_pre), blk = _prompt_operands(1, 128, seed=23)
+    act = act.at[:, 5].set(jnp.inf)
     s0 = _state(24)
-    o, s = _prefill()(q, k, v, g, beta, init_state=s0,
-                      length=jnp.asarray([0]))
-    assert not np.asarray(o).any()
+    o, s = _prefill()(act, g_pre, beta, gate_pre, kda.fold_layer(blk),
+                      s0, length=jnp.asarray([0]))
+    assert not np.asarray(o.astype(jnp.float32)).any()
     np.testing.assert_array_equal(np.asarray(s), np.asarray(s0))
 
 
 def test_prefill_kernel_is_finite_and_right_under_fast_decay():
-    """The case the chunked form is held to: a channel at ``log 0.05`` a
+    """The case the chunked form is held to: a channel near ``log 0.05`` a
     position for a whole chunk (``G`` to -190), so that neither a sub-chunk's
     own ``exp(G_i - G_j)`` nor the scalings between sub-chunks may be formed
     as a quotient."""
-    q, k, v, g, beta = _inputs(1, 128, HB, D, D, seed=25)
-    g = g.at[:, :, :, 0].set(np.log(0.05)).at[:, :64, 1, 3].set(np.log(0.05))
-    _assert_prefill((q, k, v, g, beta), _state(26), 128)
+    (act, g_pre, beta, gate_pre), blk = _prompt_operands(1, 128, seed=25)
+    # g = -exp(A_log) softplus(3) = -3.05 on channel 0 of every head, and on
+    # channel 3 of head 1 for the first chunk
+    blk["A_log"] = jnp.zeros_like(blk["A_log"])
+    blk["dt_bias"] = blk["dt_bias"].reshape(HB, D).at[:, 0].set(0.0).at[
+        1, 3].set(0.0).reshape(-1)
+    g_pre = g_pre.reshape(1, 128, HB, D).at[:, :, :, 0].set(3.0).at[
+        :, :64, 1, 3].set(3.0).reshape(1, 128, HB * D)
+    _assert_prefill((act, g_pre, beta, gate_pre), blk, _state(26), 128)
 
 
 def test_prefill_kernel_holds_with_beta_near_two():
-    args = _inputs(1, 128, HB, D, D, seed=27, g_range=(0.001, 0.05),
-                   beta_range=(1.9, 1.999))
-    _assert_prefill(args, _state(28), 128, atol=2e-4)
+    ops, blk = _prompt_operands(1, 128, seed=27, beta_range=(1.9, 1.999),
+                                bias=-6.0)
+    _assert_prefill(ops, blk, _state(28), 128, slack=2e-4)
 
 
 def test_prefill_kernel_two_blocks_in_a_row_equal_one_call():
     """A token block continues from the state the one before it returned, as
     ``forward_with_cache`` walks a long prompt."""
-    args = _inputs(1, 256, HB, D, D, seed=29)
+    ops, blk = _prompt_operands(1, 256, seed=29)
+    weights = kda.fold_layer(blk)
     s0 = _state(30)
     length = 200
-    o_all, s_all = _prefill()(*args, init_state=s0,
-                              length=jnp.asarray([length]))
-    o1, s1 = _prefill()(*(x[:, :128] for x in args), init_state=s0,
+    o_all, s_all = _prefill()(*ops, weights, s0, length=jnp.asarray([length]))
+    o1, s1 = _prefill()(*(x[:, :128] for x in ops), weights, s0,
                         length=jnp.asarray([128]))
-    o2, s2 = _prefill()(*(x[:, 128:] for x in args), init_state=s1,
+    o2, s2 = _prefill()(*(x[:, 128:] for x in ops), weights, s1,
                         length=jnp.asarray([length - 128]))
-    np.testing.assert_allclose(np.asarray(jnp.concatenate([o1, o2], 1)),
-                               np.asarray(o_all), rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(
+        np.asarray(jnp.concatenate([o1, o2], 1).astype(jnp.float32)),
+        np.asarray(o_all.astype(jnp.float32)))
     np.testing.assert_allclose(np.asarray(s2), np.asarray(s_all), rtol=1e-5,
                                atol=1e-6)
 
 
 def test_prefill_kernel_takes_rows_of_their_own_lengths():
-    args = _inputs(2, 128, HB, D, D, seed=31)
+    ops, blk = _prompt_operands(2, 128, seed=31)
     s0 = _state(32, b=2)
-    lengths = jnp.asarray([128, 50])
-    o, s = _prefill()(*args, init_state=s0, length=lengths)
+    o, s = _prefill()(*ops, kda.fold_layer(blk), s0,
+                      length=jnp.asarray([128, 50]))
+    args = _split_operands(ops, blk)
     for row, length in enumerate((128, 50)):
         o_r, s_r = _sequential(*(x[row:row + 1] for x in args),
                                s0=s0[row:row + 1], length=length)
-        np.testing.assert_allclose(np.asarray(o)[row, :length],
-                                   o_r[0, :length], rtol=1e-4, atol=2e-5)
+        one = tuple(x[row:row + 1] for x in ops)
+        _assert_rounded_once(o[row:row + 1, :length], _head_norm_and_gate(
+            o_r, one, blk)[:, :length])
         np.testing.assert_allclose(np.asarray(s)[row], s_r[0], rtol=1e-4,
                                    atol=2e-5)
+
+
+def test_prefill_kernel_keeps_a_zero_row_zero_under_the_l2_norm():
+    """``|x|^2 + 1e-6`` under the root: a head whose query, key or all three
+    are zero at a position (a convolution over zeros) divides nothing by
+    zero; a zero key writes nothing, a zero query reads nothing."""
+    (act, g_pre, beta, gate_pre), blk = _prompt_operands(1, 128, seed=33)
+    w = HB * D
+    act = act.reshape(1, 128, 3, HB, D).at[:, 7, 0, 2].set(0.0).at[
+        :, 9, 1, 3].set(0.0).at[:, 70, :, 5].set(0.0).at[:, 100].set(
+            0.0).reshape(1, 128, 3 * w)
+    o, _ = _assert_prefill((act, g_pre, beta, gate_pre), blk, _state(34), 128)
+    o = np.asarray(o.astype(jnp.float32)).reshape(128, HB, D)
+    # a zero query reads nothing: the head norm of zeros is zero
+    assert not o[7, 2].any() and not o[70, 5].any() and not o[100].any()
+    assert o[9, 3].any()
+
+
+@pytest.mark.parametrize("dtype", [BF16, jnp.float32], ids=["bf16", "f32"])
+def test_prefill_kernel_rounds_its_result_once(dtype):
+    """``o`` leaves the call in the stream's dtype, rounded from float32
+    behind the norm and the gate, where ``.astype(qkv.dtype)`` rounds the
+    split route's; with float32 operands nothing is rounded at all. Against
+    the split route's own float32 result the two agree to a unit of the last
+    place, and all but a few elements bit for bit."""
+    ops, blk = _prompt_operands(1, 128, seed=35, dtype=dtype)
+    s0 = _state(36)
+    o, _ = _assert_prefill(ops, blk, s0, 128)
+    o_c, _ = kda.kda_chunked(*_split_operands(ops, blk), chunk=CHUNK,
+                             init_state=s0, length=128)
+    gate = jax.nn.sigmoid(ops[3].reshape(o_c.shape).astype(jnp.float32))
+    want = (o_c * jax.lax.rsqrt(jnp.mean(o_c * o_c, -1, keepdims=True) + EPS)
+            * blk["o_norm"] * gate).astype(dtype).reshape(o.shape)
+    o, want = (np.asarray(x.astype(jnp.float32)) for x in (o, want))
+    if dtype == BF16:
+        np.testing.assert_allclose(o, want, rtol=2.0 ** -7, atol=1e-6)
+        assert (o == want).mean() > 0.98
+    else:
+        np.testing.assert_allclose(o, want, rtol=1e-4, atol=2e-5)
 
 
 @pytest.mark.parametrize("shape,fits", [

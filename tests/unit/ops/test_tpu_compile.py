@@ -349,21 +349,30 @@ def test_gdn_update_kernel(one_chip):
 
 def test_kda_prefill_kernel(one_chip):
     """``dstpu_kda_prefill`` at the Solar-Open2 cell's shapes: a token block
-    of 2,048 positions, 64 heads of 128 keys and values, chunks of 64, the
-    values in bf16 as the convolution leaves them."""
+    of 2,048 positions, 64 heads of 128 keys and values, chunks of 64; the
+    convolution's result ``q | k | v`` and the two gates in bf16 as the mixer
+    holds them, ``beta`` float32, the layer's small weights folded."""
     from deepspeed_tpu.ops import kda
 
     t, h, d = 2048, 64, 128
     assert kda.supports_prefill(t, h, d, d, 64)
     sds = functools.partial(_sds, one_chip)
-    fn = functools.partial(kda.kda_prefill, chunk=64, interpret=False)
-    rows = sds((1, t, h, d), jnp.float32)
-    text = jax.jit(lambda q, k, v, g, beta, state, length: fn(
-        q, k, v, g, beta, init_state=state, length=length)).lower(
-            rows, rows, sds((1, t, h, d)), rows, sds((1, t, h), jnp.float32),
-            sds((1, h, d, d), jnp.float32),
-            sds((1,), jnp.int32)).compile().as_text()
+    f32 = jnp.float32
+
+    def fn(act, g_pre, beta, gate_pre, heads, o_norm, state, length):
+        return kda.kda_prefill(act, g_pre, beta, gate_pre,
+                               {"heads": heads, "o_norm": o_norm}, state,
+                               chunk=64, eps=1e-5, length=length,
+                               interpret=False)
+
+    compiled = jax.jit(fn).lower(
+        sds((1, t, 3 * h * d)), sds((1, t, h * d)), sds((1, t, h), f32),
+        sds((1, t, h * d)), sds((2, h, d), f32), sds((1, d), f32),
+        sds((1, h, d, d), f32), sds((1,), jnp.int32)).compile()
+    text = compiled.as_text()
     assert "tpu_custom_call" in text and "dstpu_kda_prefill" in text
+    # nothing beside the call: every operand is read where it lies
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
 
 
 @pytest.mark.parametrize("rows", [2048, 16384], ids=str)
@@ -900,6 +909,34 @@ def _assert_copies_no_weight(compiled, leaves):
     assert compiled.memory_analysis().temp_size_in_bytes < 32 * 2 ** 20
 
 
+def _lower_decode_step(cell, sharding):
+    """A cell's decode step (``InferenceEngine.slot_decode_program``'s call of
+    the model: one token a slot, per-slot lengths, the slot walk), lowered
+    for the described chip -> ``(lowered, the state's shapes, _weights'
+    leaves)``."""
+    from deepspeed_tpu.ops.decode_step import slot_walk
+
+    model, slots, max_len = cell()
+    params, leaves = _weights(model, sharding)
+    state = jax.eval_shape(
+        lambda: model.init_cache(slots, max_len, dtype=BF16))
+    del state["index"]
+    state = jax.tree_util.tree_map(
+        lambda s: _sds(sharding, s.shape, s.dtype), state)
+
+    def step(params, state, lengths, tokens, active):
+        cache = dict(state, index=lengths, valid_len=active.astype(jnp.int32),
+                     slot_walk=slot_walk(lengths, active))
+        logits, cache = model.forward_with_cache(params, tokens[:, None],
+                                                 cache)
+        return logits[:, -1], {k: cache[k] for k in state}
+
+    per_slot = _sds(sharding, (slots,), jnp.int32)
+    return jax.jit(step, donate_argnums=1).lower(
+        params, state, per_slot, per_slot,
+        _sds(sharding, (slots,), jnp.bool_)), state, leaves
+
+
 @pytest.mark.parametrize("cell", [_exaone_cell, _granite_cell,
                                   _gpt2_large_cell, _sarvam_cell,
                                   _solar_cell, _longcat_cell, _mimo_cell,
@@ -921,27 +958,8 @@ def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
     of 7.4 (PERF.md, PR 41; ``models/base.project_heads``). No step holds a
     conditional: what a prompt block's expert layer chooses between (PR 53)
     is not a decode step's to choose."""
-    from deepspeed_tpu.ops.decode_step import slot_walk
-
-    model, slots, max_len = cell()
-    params, leaves = _weights(model, one_chip)
-    state = jax.eval_shape(
-        lambda: model.init_cache(slots, max_len, dtype=BF16))
-    del state["index"]
-    state = jax.tree_util.tree_map(
-        lambda s: _sds(one_chip, s.shape, s.dtype), state)
-
-    def step(params, state, lengths, tokens, active):
-        cache = dict(state, index=lengths, valid_len=active.astype(jnp.int32),
-                     slot_walk=slot_walk(lengths, active))
-        logits, cache = model.forward_with_cache(params, tokens[:, None],
-                                                 cache)
-        return logits[:, -1], {k: cache[k] for k in state}
-
-    per_slot = _sds(one_chip, (slots,), jnp.int32)
-    compiled = jax.jit(step, donate_argnums=1).lower(
-        params, state, per_slot, per_slot,
-        _sds(one_chip, (slots,), jnp.bool_)).compile()
+    lowered, state, leaves = _lower_decode_step(cell, one_chip)
+    compiled = lowered.compile()
     _assert_copies_no_weight(compiled, leaves)
     # one token a slot: the expert layer's buffer is two or three row tiles
     # and the switch a prompt block carries (moe/grouped.held_experts) has
@@ -1121,7 +1139,16 @@ def test_sarvam_prefill_keeps_scores_on_chip(one_chip, fused_routes):
     assert compiled.memory_analysis().temp_size_in_bytes < 420 * 2 ** 20
 
 
-@pytest.mark.parametrize("bucket,temp_mb", [(2048, 390), (16384, 670)])
+@functools.lru_cache(maxsize=None)
+def _solar_prefill(sharding, bucket):
+    """Solar-Open2's prefill program of a bucket, compiled once a process
+    (under ``fused_routes``: both its readers ask for the fixture)."""
+    model, _, _ = _solar_cell()
+    params, _ = _weights(model, sharding)
+    return _compile_prefill(model, params, sharding, bucket)
+
+
+@pytest.mark.parametrize("bucket,temp_mb", [(2048, 345), (16384, 620)])
 def test_solar_prefill_keeps_a_chunk_on_chip(one_chip, fused_routes, bucket,
                                              temp_mb):
     """Solar-Open2's prefill (``slot_prefill_program``'s call of the model: a
@@ -1138,13 +1165,12 @@ def test_solar_prefill_keeps_a_chunk_on_chip(one_chip, fused_routes, bucket,
     or value rows (in the 2,048 bucket the block's own new rows, turned for
     the write, are as many). Both runs' expert layers sort a token block's
     pairs into 4,096 rows unless more are routed here. The program's
-    temporaries (``temp_mb``: 372 and 652 MiB) are below what they were with
-    the expert layer's float32 passes over the worst case's 16,384 rows (537
-    and 805, PERF.md, PR 49; the chunked form's were 619 and 1,009)."""
-    model, _, _ = _solar_cell()
-    params, _ = _weights(model, one_chip)
-
-    compiled = _compile_prefill(model, params, one_chip, bucket)
+    temporaries (``temp_mb``: 327 and 595 MiB) are below what they were with
+    the float32 operands XLA made for the delta-rule kernel (372 and 652,
+    PERF.md, PR 64) and with the expert layer's float32 passes over the worst
+    case's 16,384 rows (537 and 805, PR 49; the chunked form's were 619 and
+    1,009)."""
+    compiled = _solar_prefill(one_chip, bucket)
     text = compiled.as_text()
     found, _ = _outside_fusions(text)
     for name in ("dstpu_kda_prefill", "dstpu_gqa_prefill"):
@@ -1172,6 +1198,76 @@ def test_solar_prefill_keeps_a_chunk_on_chip(one_chip, fused_routes, bucket,
     assert not chunked, "\n".join(chunked)
     assert _assert_prompt_buffer_is_compact(text, 16384, 4096, 2) == {4096}
     assert compiled.memory_analysis().temp_size_in_bytes < temp_mb * 2 ** 20
+
+
+def test_solar_prompt_block_rounds_nothing_to_float32_around_its_kernel(
+        one_chip, fused_routes):
+    """The 2,048 bucket's delta-rule run: ``dstpu_kda_prefill`` takes the
+    convolution's result and the two gates in bf16 as they are and hands the
+    output matmul its bf16 operand. Between the convolution and ``wo``
+    nothing outside the call writes a float32 array of a token block's
+    elements: before PR 64 the two L2 norms, the log decay, ``beta`` spread
+    over 128 lanes and the kernel's own result were five of them, 64 MB each
+    a layer a token block, with three ``reshape f32[1,2048,8192]`` and two
+    copies ``f32[256,8,64,128]`` between layouts (PERF.md, PR 50's trace)."""
+    text = _solar_prefill(one_chip, 2048).as_text()
+    found, bodies = _outside_fusions(text)
+    runs = {comp for comp, _, opcode, line in found
+            if opcode == "custom-call" and "dstpu_kda_prefill" in line}
+    assert len(runs) == 1, runs
+    block = ((2048, 8192), (64, 128, 2048), (8, 64, 128, 256))
+    ops = [(kind, opcode, line) for comp, kind, opcode, line in found
+           if comp in runs]
+    (call, line), = [(kind, line) for kind, opcode, line in ops
+                     if opcode == "custom-call"
+                     and "dstpu_kda_prefill" in line]
+    wide = [line.strip()[:200] for kind, opcode, line in ops
+            if opcode not in ("custom-call", "parameter", "tuple",
+                              "get-tuple-element")
+            for dims in re.findall(r"f32\[([\d,]*)\]", kind)
+            if _sizes(dims.split(",")) in block]
+    assert not wide, "\n".join(wide)
+    # the call's own result: the stream's dtype where it is a token block
+    # wide, float32 for the state alone
+    assert "bf16[1,2048,8192]" in call and "f32[1,64,128,128]" in call
+    assert not [d for d in re.findall(r"f32\[([\d,]*)\]", call)
+                if _sizes(d.split(",")) in block]
+    # its operands, by the lines that make them: the convolution's result
+    # whole, three times (no split), the gates as the low-rank matmuls leave
+    # them
+    made = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+)",
+                           "\n".join(line for _, _, line in ops), re.M))
+    operands = re.search(r"custom-call\(([^)]*)\)", line).group(1).split(", ")
+    assert operands[1] == operands[2] == operands[3], operands[:4]
+    assert made[operands[1]].startswith("bf16[1,2048,24576]")
+    assert all(made[name].startswith("bf16[1,2048,8192]")
+               for name in operands[4:6]), operands
+
+
+def _program_digest(text):
+    """A digest of a LOWERED program: its text with every Mosaic kernel's
+    serialized module replaced by :func:`_kernel_digests`' (the bodies carry
+    source lines), as jax hands it to the TPU compiler."""
+    import hashlib
+
+    kernels = _kernel_digests(text)
+    bare = re.sub(r'(body(?:"|\\22): ?(?:"|\\22))[\w+/=]+', r"\1", text)
+    return hashlib.sha256(
+        (bare + " ".join(kernels)).encode()).hexdigest()[:16], kernels
+
+
+def test_solars_decode_program_is_the_one_it_was(one_chip, fused_routes):
+    """The cell's decode step (one token a slot, 16 slots of 16,384 rows, the
+    folded ``dstpu_kda_update`` and the per-slot walk) lowers to the program
+    it lowered to at PR 63, the parent of the PR that moved the prompt
+    kernel's boundary: operation for operation the same text and the same
+    two kernels (PERF.md, PR 64: compiled for the described v5e, the two
+    programs were the same HLO). A PR that changes the step on purpose
+    records the new digest here."""
+    text = _lower_decode_step(_solar_cell, one_chip)[0].as_text()
+    # the per-slot walk (ONE_WIDTH_KERNELS' own) and ``dstpu_kda_update``
+    assert _program_digest(text) == ("bceee5ce4f1dad16", [
+        "d5750f9021028bf1", "4fe8519fbb6a26b5"])
 
 
 def test_longcat_prefill_sorts_the_pairs_it_holds(one_chip, fused_routes):
