@@ -24,6 +24,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.models import mamba
 from deepspeed_tpu.models.base import merge_heads, project_heads, qdot, rms_norm, tied_logits
 from deepspeed_tpu.models.stack import StackedDecoder, kv_cache, next_cache
 from deepspeed_tpu.ops import ssm
@@ -228,95 +229,20 @@ class GraniteHybridModel(StackedDecoder):
 
     def _mamba_layer(self, x, blk, state=None, layer=None, idx=None, valid=None,
                      step=None):
-        """-> ``(x, state)``. ``state``: ``None`` (no cache: zeros in, nothing
-        out) or ``(ssm_full [Lm,B,H,P,N], conv_full [Lm,B,...], counts)`` at
-        ``layer``, ``idx`` and the rows' ``valid`` lengths (``counts``: the
-        walk's, handed on as it came). One token (``T ==
-        1``) with a cache runs the recurrence in place on the stacked state,
-        with ``step`` (:meth:`_decode_step`) what the step's Mamba layers
-        share; where the shapes fold, everything between the two matmuls is
-        one kernel (``ops/ssm.mamba_step``). A block runs the chunked form
-        from the layer's state and writes the state at the true length
-        back."""
-        c = self.config
-        b, t, _ = x.shape
-        h, p, n, g = (c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state,
-                      c.mamba_n_groups)
-        d_in = c.d_inner
-        u = rms_norm(x, blk["norm"], c.eps)
-        zxbcdt = qdot("btd,de->bte", u, blk["in_proj"])
-        folded = step is not None and step["weights"] is not None
-        if step is not None:
-            ssm.count_step(folded)
-        if folded:
-            *state, counts = state
-            y, *state = ssm.mamba_step(
-                zxbcdt[:, 0], *state, layer, step["weights"], step["walk"],
-                step["active"], eps=c.eps)
-            x = x + c.residual_multiplier * qdot("bte,ed->btd", y[:, None],
-                                                 blk["out_proj"])
-            return self._mlp(x, blk), (*state, counts)
-        z, xbc, dt = jnp.split(zxbcdt, [d_in, d_in + c.conv_dim], axis=-1)
-        dt = jax.nn.softplus(dt.astype(jnp.float32)
-                             + blk["dt_bias"].astype(jnp.float32))
-        a = -jnp.exp(blk["A_log"].astype(jnp.float32))
-        s0 = None
-        if state is None:
-            conv0 = jnp.zeros((b, c.mamba_d_conv - 1, c.conv_dim), x.dtype)
-        else:
-            ssm_full, conv_full, counts = state
-            conv0 = ssm.rows_to_tail(
-                jax.lax.dynamic_index_in_dim(conv_full, layer, 0, False),
-                c.mamba_d_conv, d_in, 2 * g * n)
-            if t > 1:
-                # a row at position 0 has no history, whatever its slot held
-                s0 = jax.lax.dynamic_index_in_dim(ssm_full, layer, 0, False)
-                fresh = jnp.reshape(idx == 0, (-1, 1, 1))
-                conv0 = jnp.where(fresh, 0, conv0)
-                s0 = jnp.where(fresh[..., None], 0, s0)
-        xbc, conv1 = ssm.causal_conv(xbc, conv0, blk["conv_w"], blk["conv_b"],
-                                     valid)
-        xs, bm, cm = jnp.split(xbc, [d_in, d_in + g * n], axis=-1)
-        if step is not None:
-            y, ssm_full = ssm.ssm_update(
-                ssm_full, layer, xs.reshape(b, h, p), dt[:, 0], a,
-                bm.reshape(b, g, n), cm.reshape(b, g, n), blk["D"],
-                step["active"], walk=step["walk"])
-            y = y.astype(x.dtype)[:, None]
-        else:
-            y, s1 = ssm.ssd_prefill(
-                xs.reshape(b, t, h, p), dt, a, bm.reshape(b, t, g, n),
-                cm.reshape(b, t, g, n), blk["D"], chunk=c.mamba_chunk_size,
-                init_state=s0, length=valid)
-            if state is not None:
-                ssm_full = jax.lax.dynamic_update_index_in_dim(
-                    ssm_full, s1.astype(ssm_full.dtype), layer, 0)
-        if state is not None:
-            conv_full = jax.lax.dynamic_update_index_in_dim(
-                conv_full, ssm.tail_to_rows(conv1, d_in), layer, 0)
-        # gated RMSNorm over all of d_inner (one group)
-        y = y.reshape(b, t, d_in).astype(jnp.float32) * \
-            jax.nn.silu(z.astype(jnp.float32))
-        y = rms_norm(y, blk["gate_norm"], c.eps).astype(x.dtype)
-        x = x + c.residual_multiplier * qdot("bte,ed->btd", y, blk["out_proj"])
-        x = self._mlp(x, blk)
-        return x, (None if state is None else (ssm_full, conv_full, counts))
+        """-> ``(x, state)``: the Mamba mixer (models/mamba.py; one gated
+        norm over all of ``d_inner``) at the residual multiplier, then the
+        MLP. ``state``: ``None`` or ``(ssm_full, conv_full, counts)``
+        (``counts``: the walk's, handed on as it came); ``step``:
+        :meth:`_decode_step`'s."""
+        leaves, counts = (None, None) if state is None else \
+            (state[:-1], state[-1])
+        y, leaves = mamba.mixer(x, blk, self.config, leaves, layer, idx,
+                                valid, step)
+        x = self._mlp(x + self.config.residual_multiplier * y, blk)
+        return x, (None if state is None else (*leaves, counts))
 
     def _decode_step(self, params, valid, b):
-        """What the Mamba layers of one decode step (one token a slot, a
-        cache) share, made once a step and not once a layer: which slots
-        decode, their order for the kernel's grid (``ops/ssm.slot_order``)
-        and, where the kernel route is taken and the shapes fold
-        (``ops/ssm.step_folds``), the stack's small weights as the folded
-        call reads them; ``weights`` ``None`` says the layers run split."""
-        c = self.config
-        active = jnp.ones((b,), bool) if valid is None else valid > 0
-        folds = ssm.default_route() == "pallas" and ssm.step_folds(
-            c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state,
-            c.mamba_n_groups)
-        return {"active": active, "walk": ssm.slot_order(active),
-                "weights": ssm.fold_weights(params[MAMBA], c.mamba_d_head)
-                if folds else None}
+        return mamba.decode_step(params[MAMBA], self.config, valid, b)
 
     def _attn_layer(self, x, blk, state=None, layer=None, idx=None,
                     valid=None, walk_=None):

@@ -25,16 +25,27 @@ that are ZERO-COMPUTE identity experts: a chosen one returns the token itself,
 computed where the token is, whatever share is held: like a shared expert it
 is counted ONCE when the shares of a layer are added up. A layer without
 ``shared_*`` leaves has no shared expert.
+
+A layer with ``latent_down`` and ``latent_up`` leaves is a LATENT expert layer
+(Nemotron-H's LatentMoE): the router and the shared expert read the stream,
+the routed experts work in a narrower latent,
+
+    sparse: Shared(z) + Up(s * sum over the held of the chosen w_e Expert_e(Down(z)))
+
+and ``Up`` is linear, so the shares of a layer still add up to the uncut one.
+A layer without ``*_gate`` leaves has two-matrix experts ``relu(z W_up)^2
+W_down`` (moe/grouped.relu2), the shared one included.
 """
 
 from __future__ import annotations
 
 import functools
 
+import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.models.base import qdot
-from deepspeed_tpu.moe.grouped import (held_experts, sigmoid_topk_route, softmax_topk_route, swiglu_gate,
+from deepspeed_tpu.moe.grouped import (held_experts, relu2, sigmoid_topk_route, softmax_topk_route, swiglu_gate,
                                        swiglu_up)
 
 DENSE, SPARSE = "dense", "sparse"
@@ -48,25 +59,29 @@ STEP_COUNTERS = ("moe_experts_touched", "moe_experts_streamed",
 PROMPT_COUNTERS = ("moe_prompt_blocks", "moe_prompt_blocks_spilled",
                    "moe_prompt_blocks_empty")
 EXPERT_LEAVES = ("expert_gate", "expert_up", "expert_down")
+# what a family may count behind STEP_COUNTERS in a step vector of its own
+# (``ffn(..., buffer_counters=True)``): rows of the sorted buffer a decode step
+# of the layer runs (its worst case, from shapes), and the experts it holds
+BUFFER_COUNTERS = ("moe_buffer_rows", "moe_experts_held_steps")
 
 
-def zero_counts(t: int):
-    """The counters a walk over ``t`` positions a row starts from."""
-    return jnp.zeros(
-        (len(STEP_COUNTERS) + (len(PROMPT_COUNTERS) if t > 1 else 0),),
-        jnp.int32)
+def zero_counts(t: int, step: int = len(STEP_COUNTERS)):
+    """The counters a walk over ``t`` positions a row starts from; ``step``:
+    how many a decode step of the family counts."""
+    return jnp.zeros((step + (len(PROMPT_COUNTERS) if t > 1 else 0),),
+                     jnp.int32)
 
 
-def carried_counts(cache, counts) -> dict:
+def carried_counts(cache, counts, step: int = len(STEP_COUNTERS)) -> dict:
     """A walk's counters as the cache it returns carries them:
-    ``step_counters`` (STEP_COUNTERS'), and for a caller that asked by
-    handing ``cache["prompt_counts"]`` in (a serving prefill program; a cache
-    that is a loop's carry keeps its keys) a prompt's PROMPT_COUNTERS added
-    to those."""
-    n = len(STEP_COUNTERS)
-    out = {"step_counters": counts[:n] if counts.shape[0] > n else counts}
+    ``step_counters`` (the family's first ``step``: STEP_COUNTERS'), and for
+    a caller that asked by handing ``cache["prompt_counts"]`` in (a serving
+    prefill program; a cache that is a loop's carry keeps its keys) a
+    prompt's PROMPT_COUNTERS added to those."""
+    out = {"step_counters": counts[:step] if counts.shape[0] > step
+           else counts}
     if "prompt_counts" in cache:
-        out["prompt_counts"] = cache["prompt_counts"] + counts[n:]
+        out["prompt_counts"] = cache["prompt_counts"] + counts[step:]
     return out
 
 
@@ -111,20 +126,34 @@ def gated_axes(prefix: str, *lead):
             prefix + "down": ("layer", *lead, "mlp", "hidden")}
 
 
-def ffn(z, blk, kind: str, valid, c):
+def count_latent() -> None:
+    """Say in the program's registry that a latent expert layer was traced
+    (``moe/traced_latent``)."""
+    from deepspeed_tpu.telemetry.registry import get_registry
+
+    get_registry().counter("moe/traced_latent").inc()
+
+
+def ffn(z, blk, kind: str, valid, c, buffer_counters: bool = False):
     """-> ``(FFN(z), counts int32 as zero_counts(T))``; ``z [B, T, d]``;
     ``valid [B, T]`` bool or None; ``c`` the model's configuration
     (``num_experts_per_tok``, ``routed_scaling_factor``, ``norm_topk_prob``,
     ``held``; optional ``scoring_func``, ``zero_experts``).
+    ``buffer_counters``: BUFFER_COUNTERS follow STEP_COUNTERS in the vector
+    (``zero_counts(T, 7)``).
 
     ``T`` says what is walked: a decode step (``T == 1``) keeps the expert
-    layer's worst-case buffer, two or three row tiles; a prompt block hands
-    the router's width on, and its buffer follows the pairs held
+    layer's worst-case buffer, ``B * min(k, held)`` rows; a prompt block
+    hands the router's width on, and its buffer follows the pairs held
     (moe/grouped.held_experts)."""
 
     limit = getattr(c, "swiglu_limit", None)
 
     def gated(prefix):
+        if prefix + "gate" not in blk:
+            return qdot("btm,md->btd", relu2(qdot("btd,dm->btm", z,
+                                                  blk[prefix + "up"])),
+                        blk[prefix + "down"])
         gate = swiglu_gate(qdot("btd,dm->btm", z, blk[prefix + "gate"]), limit)
         return qdot("btm,md->btd", gate * swiglu_up(
             qdot("btd,dm->btm", z, blk[prefix + "up"]), limit),
@@ -140,10 +169,19 @@ def ffn(z, blk, kind: str, valid, c):
         else functools.partial(sigmoid_topk_route, normalize=c.norm_topk_prob)
     routing = route(flat, blk["router"], blk["select_bias"],
                     c.num_experts_per_tok, scale=c.routed_scaling_factor)
+    latent = "latent_down" in blk
+    inner = flat
+    if latent:
+        count_latent()
+        with jax.named_scope("dstpu_moe_latent_down"):
+            inner = qdot("nd,dl->nl", flat, blk["latent_down"])
     y, counts = held_experts(
-        flat, routing, blk["expert_gate"], blk["expert_up"],
+        inner, routing, blk.get("expert_gate"), blk["expert_up"],
         blk["expert_down"], c.held, valid=live,
         n_experts=blk["router"].shape[-1] if t > 1 else None, limit=limit)
+    if latent:
+        with jax.named_scope("dstpu_moe_latent_up"):
+            y = qdot("nl,ld->nd", y, blk["latent_up"])
     zero = jnp.zeros((), jnp.int32)
     if getattr(c, "zero_experts", 0):
         # the identity experts are the router's last outputs: a chosen one
@@ -155,10 +193,14 @@ def ffn(z, blk, kind: str, valid, c):
                  * flat.astype(jnp.float32)).astype(y.dtype)
         zero = chosen.sum().astype(jnp.int32)
     y = y.reshape(b, t, d)
-    if "shared_gate" in blk:
+    if "shared_up" in blk:
         y = gated("shared_") + y
     step = (counts.touched, counts.streamed, counts.assignments_held,
             counts.assignments, zero)
+    if buffer_counters:
+        rows = flat.shape[0] * min(c.num_experts_per_tok, c.held[1])
+        step += (jnp.asarray(rows, jnp.int32),
+                 jnp.asarray(c.held[1], jnp.int32))
     if t > 1:       # PROMPT_COUNTERS
         step += (jnp.ones((), jnp.int32), counts.spilled,
                  (counts.assignments_held == 0).astype(jnp.int32))

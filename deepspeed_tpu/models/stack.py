@@ -439,8 +439,10 @@ class StackedDecoder:
             functools.partial(self._layers, params),
             params["embed"].astype(self.compute_dtype), input_ids,
             tuple(cache[k] for k in self.slot_state_keys),
-            moe_ffn.zero_counts(t), cache, self.config.prompt_block)
+            moe_ffn.zero_counts(t, len(self.step_counters)), cache,
+            self.config.prompt_block)
         hidden = self._norm(x, params["final_norm"])
         out = next_cache(cache, t, **dict(zip(self.slot_state_keys, leaves)))
-        out.update(moe_ffn.carried_counts(cache, counts))
+        out.update(moe_ffn.carried_counts(cache, counts,
+                                          len(self.step_counters)))
         return self.logits(params, hidden), out

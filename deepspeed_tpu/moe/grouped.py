@@ -30,15 +30,20 @@ and no steadier; the benchmark's cell serves one checkpoint for that reason).
 Shapes are static: a token's ``k`` experts are distinct, so at most
 ``min(k, count)`` of its pairs are held, and a sorted buffer of
 ``N * min(k, count)`` rows holds them whatever the routing; rows behind the
-last group are masked. A decode step has that buffer, two or three row tiles.
-A prompt block is routed ``k * count / E`` pairs a token on average, an eighth
-or a forty-eighth of the worst case, and everything computed a row would run
-over the rest for nothing: its buffer is one of two static sizes, chosen on
-the device by the pairs it holds (:func:`held_experts`, :func:`compact_rows`):
-twice the expectation, or the worst case when a router crowds this chip, so
-that no pair is ever dropped; a block that is all padding passes the layer
-by. ``TopKGate`` / ``top1gating`` / ``top2gating`` (sharded_moe.py) stay the
-capacity-einsum dispatch of ``gpt_moe``.
+last group are masked. A decode step has that buffer: two to four row tiles
+at 32 or 64 slots and ``k`` 8, eleven at 64 slots and ``k`` 22 of which 128
+are held, where 16 live slots fill a part of one; the grouped matmul makes
+row tiles for filled groups only, so the rows behind the pairs cost a gather
+and an activation and no multiplication, less than a switch on the pairs
+held would (PERF.md, PR 65: the step 7 to 10% slower with one). A prompt block is routed ``k * count / E`` pairs a token on
+average, an eighth or a forty-eighth of the worst case, and everything
+computed a row would run over the rest for nothing: its buffer is one of two
+static sizes, chosen on the device by the pairs it holds
+(:func:`held_experts`, :func:`compact_rows`): twice the expectation, or the
+worst case when a router crowds this chip, so that no pair is ever dropped; a
+block that is all padding passes the layer by. ``TopKGate`` / ``top1gating``
+/ ``top2gating`` (sharded_moe.py) stay the capacity-einsum dispatch of
+``gpt_moe``.
 """
 
 from __future__ import annotations
@@ -113,6 +118,12 @@ def swiglu_up(up, limit: Optional[float] = None):
     return up if limit is None else jnp.clip(up, -limit, limit)
 
 
+def relu2(x):
+    """``relu(x) ** 2``: the activation of an ungated expert (Nemotron-H's
+    ``relu2``)."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def compact_rows(n: int, k: int, count: int, n_experts: int) -> int:
     """The rows of a prompt block's compact sorted buffer: twice the pairs
     ``n`` tokens send to ``count`` of ``n_experts`` experts when the router
@@ -145,7 +156,10 @@ def held_experts(x, routing: Routing, w_gate, w_up, w_down,
     """Sum over the chosen experts held here of ``w_e * Expert_e(x)``, each a
     gated MLP ``(silu(x Wg) * (x Wu)) Wd``; with ``limit`` (a configuration's
     ``swiglu_limit``) the gate's input clamped from above and the linear half
-    to ``[-limit, limit]`` (:func:`swiglu_gate`, :func:`swiglu_up`).
+    to ``[-limit, limit]`` (:func:`swiglu_gate`, :func:`swiglu_up`). With
+    ``w_gate`` ``None`` an expert is the two-matrix ``relu(x Wu)^2 Wd``
+    (:func:`relu2`; the same sort, groups and counts; two grouped matmuls for
+    three).
 
     ``x [N, d]``; ``w_gate, w_up [count, d, m]``, ``w_down [count, m, d]``:
     the weights of experts ``first .. first + count - 1``, or each a
@@ -175,9 +189,9 @@ def held_experts(x, routing: Routing, w_gate, w_up, w_down,
     compact and the full branch and the buffer holds every held pair in
     both, so the result is the same bit for bit whichever runs: no pair is
     dropped, there is no capacity. Without ``n_experts`` (a decode step,
-    whose buffer is two or three row tiles), or where the compact buffer
-    would be no smaller than the full one, there is no switch and the full
-    buffer alone.
+    whose worst case is some row tiles), or where the compact buffer would
+    be no smaller than the full one, there is no switch and the full buffer
+    alone.
     -> ``(y [N, d], ExpertCounts)``."""
     first, count = held
     n, k = routing.experts.shape
@@ -214,7 +228,8 @@ def held_experts(x, routing: Routing, w_gate, w_up, w_down,
         token = order // k
         with jax.named_scope("dstpu_moe_experts"):
             xs = x[token]
-            h = swiglu_gate(grouped(xs, w_gate), limit) \
+            h = relu2(grouped(xs, w_up)) if w_gate is None else \
+                swiglu_gate(grouped(xs, w_gate), limit) \
                 * swiglu_up(grouped(xs, w_up), limit)
             ys = grouped(h, w_down)
         if not prompt:

@@ -94,16 +94,11 @@ def _count(name: str) -> None:
 
 
 def record_traced(telemetry) -> None:
-    """The ``eva/traced_*`` counters brought level in ``telemetry``, where a
-    serving engine keeps a registry of its own (as ops/gdn.record_traced)."""
-    from deepspeed_tpu.telemetry.registry import get_registry
+    """The ``eva/traced_*`` counters brought level in ``telemetry``
+    (telemetry/registry.level_counters)."""
+    from deepspeed_tpu.telemetry.registry import level_counters
 
-    reg = get_registry()
-    if telemetry is reg:
-        return
-    for n in _TRACED:
-        mine = telemetry.counter("eva/traced_" + n)
-        mine.inc(reg.counter("eva/traced_" + n).value - mine.value)
+    level_counters(telemetry, ["eva/traced_" + n for n in _TRACED])
 
 
 def live_rows(position, window: int, chunk: int):

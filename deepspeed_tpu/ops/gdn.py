@@ -116,18 +116,11 @@ def count_chunked_block() -> None:
 
 
 def record_traced(telemetry) -> None:
-    """The ``gdn/traced_*`` counters brought level in ``telemetry``, where a
-    serving engine keeps a registry of its own: a route is counted while a
-    program is traced, in the process's registry, and the engine's registry
-    is the one its run reports (``model.record_step_counters``)."""
-    from deepspeed_tpu.telemetry.registry import get_registry
+    """The ``gdn/traced_*`` counters brought level in ``telemetry``
+    (telemetry/registry.level_counters)."""
+    from deepspeed_tpu.telemetry.registry import level_counters
 
-    reg = get_registry()
-    if telemetry is reg:
-        return
-    for n in _TRACED:
-        mine = telemetry.counter("gdn/traced_" + n)
-        mine.inc(reg.counter("gdn/traced_" + n).value - mine.value)
+    level_counters(telemetry, ["gdn/traced_" + n for n in _TRACED])
 
 
 def log_decay(a, a_log, dt_bias):

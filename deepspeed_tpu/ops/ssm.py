@@ -22,7 +22,10 @@ Entry points, by serving phase:
     step (:func:`fold_weights`), the slot order once a step
     (:func:`slot_order`). An inactive slot's state and tail are neither read
     nor written. Taken where :func:`step_folds` says the shapes fit (a cell
-    holds every head of a slot, one group, lane-aligned widths).
+    holds every head of a slot, a group's heads tile whole rows of lanes). With
+    ``g`` groups a head reads its group's ``B`` and ``C`` and the gated norm
+    runs over a group's ``d_inner / g`` channels; the cell works through its
+    groups one after the other.
   * :func:`ssm_update` — the state update and read-out alone, for the layers
     whose shapes do not fold and for the CPU: on TPU the same Pallas call
     with ``block_heads`` heads a cell, elsewhere a plain ``jnp`` route. The
@@ -83,15 +86,21 @@ def _interpret(interpret: Optional[bool]) -> bool:
     return jax.default_backend() != "tpu" if interpret is None else interpret
 
 
-def count_step(folded: bool) -> None:
+# which way a one-token Mamba layer was traced, and where one of several
+# groups folded
+TRACED = ("ssm/traced_folded_step", "ssm/traced_split_step",
+          "ssm/traced_step_folded_groups")
+
+
+def count_step(folded: bool, groups: int = 1) -> None:
     """Say in the program's registry which way a one-token Mamba layer was
-    traced; both counters exist from the first call on."""
+    traced (TRACED); the counters exist from the first call on."""
     from deepspeed_tpu.telemetry.registry import get_registry
 
-    reg = get_registry()
-    fold, split = (reg.counter("ssm/traced_folded_step"),
-                   reg.counter("ssm/traced_split_step"))
+    fold, split, grouped = (get_registry().counter(n) for n in TRACED)
     (fold if folded else split).inc()
+    if folded and groups > 1:
+        grouped.inc()
 
 
 # ------------------------------------------------------------ convolution
@@ -137,11 +146,14 @@ def _slot(i, order_ref, n_ref):
 
 
 def _slot_call(kernel, layer, walk, operands, *, grid, in_specs, out_specs,
-               out_shape, aliases, interpret, scratch=()):
+               out_shape, aliases, interpret, scratch=(), vmem_bytes=None):
     """The one Pallas call of this file, whatever a cell holds. Scalars in
     front of ``operands``: the layer, the slot order, the active count
-    (``aliases`` counts them)."""
+    (``aliases`` counts them). ``vmem_bytes``: the call's own VMEM limit
+    where a cell's blocks outgrow the default."""
     order, n_active = walk
+    params = {} if vmem_bytes is None else {
+        "compiler_params": pltpu.CompilerParams(vmem_limit_bytes=vmem_bytes)}
     return pallas_call(
         kernel,
         name="dstpu_ssm_update",
@@ -151,6 +163,7 @@ def _slot_call(kernel, layer, walk, operands, *, grid, in_specs, out_specs,
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
+        **params,
     )(jnp.asarray(layer, jnp.int32).reshape(1), order, n_active, *operands)
 
 
@@ -270,11 +283,13 @@ def ssm_update(state, layer, x, dt, a, bm, cm, d, active=None, *,
 def step_folds(h: int, p: int, n: int, g: int, *,
                block_heads: int = DEFAULT_BLOCK_HEADS) -> bool:
     """Whether a layer's decode step fits the folded call: a cell holds every
-    head of a slot and there is one group (the gated norm runs over all of
-    ``d_inner``), the state is one row of lanes wide, and heads tile whole
-    rows of lanes (``d_inner`` as ``[R, 128]``, ``128 / P`` heads a row)."""
-    return (g == 1 and block_heads >= h and n == LANES and LANES % p == 0
-            and (h * p) % LANES == 0 and h <= LANES)
+    head of a slot and works on the ``h / g`` heads of a group at a time (the
+    gated norm runs over a group's ``d_inner / g`` channels: all of
+    ``d_inner`` where there is one group), the state is one row of lanes
+    wide, and a group's heads tile whole rows of lanes (``d_inner`` as ``[R,
+    128]``, ``128 / P`` heads a row, ``R / g`` rows a group)."""
+    return (h % g == 0 and block_heads >= h // g and n == LANES
+            and LANES % p == 0 and (h // g * p) % LANES == 0 and h <= LANES)
 
 
 def conv_tail_shape(d_conv: int, d_inner: int, bc: int):
@@ -340,19 +355,21 @@ def fold_weights(stack, p: int):
 
 def _step_kernel(layer_ref, order_ref, n_ref, zx_ref, cw_ref, cb_ref, hp_ref,
                  gw_ref, s_ref, t_ref, y_ref, o_ref, u_ref, y_row, *, r: int,
-                 nb: int, k: int, p: int, eps: float):
+                 nb: int, k: int, p: int, g: int, eps: float):
     """One slot, one token, between ``in_proj`` and ``out_proj``. Rows are
     128 lanes: ``zx`` holds ``z`` (``r`` rows), this token's convolution
-    inputs (``x`` ``r`` rows, ``B`` and ``C`` ``nb`` rows) and ``dt`` (a row,
-    a head a lane); the tail ``t`` the ``x`` rows of the ``k - 1`` taps
-    before, then their ``B, C`` rows; the state ``[r, 128, N]``, row ``i``
-    lane ``l`` the head ``i (128 / p) + l // p``."""
+    inputs (``x`` ``r`` rows, ``B`` then ``C`` ``nb = 2 g`` rows, a group a
+    row) and ``dt`` (a row, a head a lane); the tail ``t`` the ``x`` rows of
+    the ``k - 1`` taps before, then their ``B, C`` rows; the state ``[r, 128,
+    N]``, row ``i`` lane ``l`` the head ``i (128 / p) + l // p``, group ``j``
+    rows ``j r / g`` onward."""
     del layer_ref, order_ref
     i = pl.program_id(0)
     n_active = n_ref[0]
     f32 = jnp.float32
     cdt = zx_ref.dtype
     bc0 = (k - 1) * r                 # where the tail's B, C rows start
+    rg = r // g                       # rows of lanes a group
 
     @pl.when(i < n_active)
     def _live():
@@ -370,14 +387,16 @@ def _step_kernel(layer_ref, order_ref, n_ref, zx_ref, cw_ref, cb_ref, hp_ref,
         bc = bc + bc_in * cw_ref[k - 1, r:r + nb]
         x = jax.nn.silu(x).astype(cdt).astype(f32)
         bc = jax.nn.silu(bc).astype(cdt).astype(f32)
-        bm, cm = bc[0:nb // 2], bc[nb // 2:nb]               # [1, N] each
+        # a group's B and C, [1, N] each
+        bcs = [(bc[j:j + 1], bc[g + j:g + j + 1]) for j in range(g)]
         # the tail moves on by one tap
         if k > 2:
             u_ref[0:(k - 2) * r] = t_ref[r:(k - 1) * r]
         u_ref[(k - 2) * r:(k - 1) * r] = x_in
-        u_ref[bc0:] = jnp.concatenate(
-            [old[nb:(k - 1) * nb], bc_in, old[(k - 1) * nb:]],
-            axis=0).astype(u_ref.dtype)
+        moved = [old[nb:(k - 1) * nb], bc_in]
+        if old.shape[0] > (k - 1) * nb:         # the padding behind them
+            moved.append(old[(k - 1) * nb:])
+        u_ref[bc0:] = jnp.concatenate(moved, axis=0).astype(u_ref.dtype)
         # dt, a head a lane, spread over the head's p lanes of its row
         hpr = LANES // p
         row = jax.lax.broadcasted_iota(jnp.int32, (r, LANES), 0)
@@ -390,21 +409,30 @@ def _step_kernel(layer_ref, order_ref, n_ref, zx_ref, cw_ref, cb_ref, hp_ref,
             spread = jnp.where(lane // p == j, head, spread)
         dt = jax.nn.softplus(spread + hp_ref[0])
         da = jnp.exp(dt * hp_ref[1])
-        s = s_ref[...].astype(f32) * da[:, :, None] \
-            + (dt * x)[:, :, None] * bm
-        o_ref[...] = s.astype(o_ref.dtype)
-        # the read-out leaves the reduction a value a sublane; stored and
-        # loaded it is rows of lanes again, where used as it stands every
-        # operation behind it shuffles registers (15.1 us a slot for 7.4, the
-        # state's DMA: chip runs, PR 44)
-        y_row[...] = (s * cm).sum(axis=-1)
-        y = y_row[...] + hp_ref[2] * x
-        # the gate and the gated norm over all of d_inner
-        y = y.astype(cdt).astype(f32) * jax.nn.silu(z)
-        var = jnp.sum(jnp.sum(y * y, axis=1, keepdims=True), axis=0,
-                      keepdims=True) / (r * LANES)
-        y_ref[...] = (y * jax.lax.rsqrt(var + eps)
-                      * gw_ref[...]).astype(y_ref.dtype)
+        for j in range(g):
+            # group j's rows of a ref, and of a value: the whole of it where
+            # there is one group
+            at = ... if g == 1 else pl.ds(j * rg, rg)
+
+            def of(v):
+                return v if g == 1 else v[j * rg:(j + 1) * rg]
+
+            bm, cm = bcs[j]
+            s = s_ref[at].astype(f32) * of(da)[:, :, None] \
+                + (of(dt) * of(x))[:, :, None] * bm
+            o_ref[at] = s.astype(o_ref.dtype)
+            # the read-out leaves the reduction a value a sublane; stored
+            # and loaded it is rows of lanes again, where used as it stands
+            # every operation behind it shuffles registers (15.1 us a slot
+            # for 7.4, the state's DMA: chip runs, PR 44)
+            y_row[at] = (s * cm).sum(axis=-1)
+            y = y_row[at] + of(hp_ref[2]) * of(x)
+            # the gate and the gated norm over the group's channels
+            y = y.astype(cdt).astype(f32) * jax.nn.silu(of(z))
+            var = jnp.sum(jnp.sum(y * y, axis=1, keepdims=True), axis=0,
+                          keepdims=True) / (rg * LANES)
+            y_ref[at] = (y * jax.lax.rsqrt(var + eps)
+                         * gw_ref[at]).astype(y_ref.dtype)
 
     # nothing active: every cell sits on one block, which is written back
     # once at the end, so it has to hold what was read
@@ -421,7 +449,7 @@ def mamba_step(zxbcdt, ssm, conv, layer, weights, walk, active, *, eps: float,
     two matmuls, in the one Pallas call (see the module's head).
 
     ``zxbcdt [B, E]``: ``in_proj``'s result, columns ``z`` (``d_inner``),
-    ``x, B, C`` (``d_inner + 2 N``), ``dt`` (``H``); ``ssm [L, B, H, P, N]``
+    ``x, B, C`` (``d_inner + 2 G N``), ``dt`` (``H``); ``ssm [L, B, H, P, N]``
     and ``conv [L, B, rows, 128]`` (:func:`conv_tail_shape`), both updated at
     ``layer`` in place, the active slots' blocks only; ``weights``:
     :func:`fold_weights`; ``walk``: :func:`slot_order` of ``active [B]``.
@@ -431,6 +459,9 @@ def mamba_step(zxbcdt, ssm, conv, layer, weights, walk, active, *, eps: float,
     r = h * p // LANES
     k, cr = weights["conv_w"].shape[1:3]
     nb, zr, tr = cr - r, r + cr + 1, conv.shape[2]
+    g = nb * LANES // (2 * n)
+    # a slot's state in and out, each held twice by the pipeline
+    held = 4 * r * LANES * n * ssm.dtype.itemsize
     # whole rows of lanes: dt's row is padded out behind its h heads
     zx = jnp.pad(zxbcdt, ((0, 0), (0, zr * LANES - zxbcdt.shape[1]))
                  ).reshape(b, zr, LANES)
@@ -447,7 +478,7 @@ def mamba_step(zxbcdt, ssm, conv, layer, weights, walk, active, *, eps: float,
     state_spec = block((r, LANES, n), True, True)
     tail_spec = block((tr, LANES), True, True)
     y, state, conv = _slot_call(
-        functools.partial(_step_kernel, r=r, nb=nb, k=k, p=p, eps=eps),
+        functools.partial(_step_kernel, r=r, nb=nb, k=k, p=p, g=g, eps=eps),
         layer, walk,
         (zx, weights["conv_w"], weights["conv_b"], weights["heads"],
          weights["gate_norm"], state, conv),
@@ -468,7 +499,8 @@ def mamba_step(zxbcdt, ssm, conv, layer, weights, walk, active, *, eps: float,
         # state, tail
         aliases={8: 1, 9: 2},
         interpret=_interpret(interpret),
-        scratch=[pltpu.VMEM((r, LANES), jnp.float32)])
+        scratch=[pltpu.VMEM((r, LANES), jnp.float32)],
+        vmem_bytes=held + (8 << 20) if held > (8 << 20) else None)
     # blocks of slots that did not run were never written
     y = jnp.where(active[:, None, None], y, 0).reshape(b, h * p)
     return y, state.reshape(ssm.shape), conv

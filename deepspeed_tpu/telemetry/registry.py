@@ -322,6 +322,19 @@ def get_registry() -> MetricsRegistry:
     return _default_registry
 
 
+def level_counters(telemetry: MetricsRegistry, names: Sequence[str]) -> None:
+    """The global registry's counters ``names`` brought level in
+    ``telemetry``, where a serving engine keeps a registry of its own: a
+    route is counted while a program is traced, in the process's registry,
+    and the engine's registry is the one its run reports
+    (``model.record_step_counters``)."""
+    if telemetry is _default_registry:
+        return
+    for name in names:
+        mine = telemetry.counter(name)
+        mine.inc(_default_registry.counter(name).value - mine.value)
+
+
 def reset_registry() -> None:
     """Clear the global registry (tests / benchmark isolation). The
     attached sink, if any, is kept."""

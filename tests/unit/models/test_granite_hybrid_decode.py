@@ -105,3 +105,39 @@ def test_widths_that_do_not_fold_run_split_on_the_kernel_route(monkeypatch):
         np.testing.assert_allclose(logits, logits_ref, **TOL)
         np.testing.assert_allclose(cache["ssm"], cache_ref["ssm"],
                                    rtol=1e-5, atol=1e-5)
+
+
+def test_a_stack_of_single_sublayers_folds_at_two_groups(monkeypatch):
+    """``models/mamba.mixer`` under the other family that calls it:
+    Nemotron-H's layers are ONE sublayer each and its Mamba-2 has several
+    groups of heads (a head reads its group's ``B`` and ``C``, the gated norm
+    runs a group). Folded at two groups the three decode steps are the split
+    route's, the expert layers between them carry no leaf, and the registry
+    says that a step of several groups folded."""
+    from deepspeed_tpu.models.nemotron_h import NemotronHConfig, NemotronHModel
+
+    model = NemotronHModel(NemotronHConfig.tiny(
+        hybrid_override_pattern="MEM*E", mamba_n_heads=16, mamba_n_groups=2,
+        mamba_d_state=128, max_seq_len=32), compute_dtype=jnp.float32)
+    c = model.config
+    assert ssm.step_folds(c.mamba_n_heads, c.mamba_d_head, c.mamba_d_state,
+                          c.mamba_n_groups)
+    assert not ssm.step_folds(c.mamba_n_heads, c.mamba_d_head,
+                              c.mamba_d_state, 4)   # a group is half a row
+    grouped = get_registry().counter("ssm/traced_step_folded_groups")
+    params = model.init(jax.random.PRNGKey(2))
+    split = _decode(model, params, monkeypatch, folded=False)
+    before = (*_counts(), grouped.value)
+    folded = _decode(model, params, monkeypatch, folded=True)
+    after = (*_counts(), grouped.value)
+    # one decode trace, two Mamba layers, each a run of its own
+    assert tuple(a - b for a, b in zip(after, before)) == (2, 0, 2)
+    for (logits, cache), (logits_ref, cache_ref) in zip(folded, split):
+        np.testing.assert_allclose(logits, logits_ref, rtol=1e-4, atol=1e-5)
+        for name in ("k", "v", "ssm", "conv"):
+            np.testing.assert_allclose(cache[name], cache_ref[name],
+                                       rtol=1e-5, atol=1e-5, err_msg=name)
+        assert cache["step_counters"].shape == (7,)
+    first, second, third = (cache["ssm"] for _, cache in folded)
+    np.testing.assert_array_equal(second[:, 1], first[:, 1])
+    assert (third[:, 1] != second[:, 1]).any()

@@ -50,6 +50,16 @@ MIXED = {
         (("gdn_dense", 0, 0, 1), ("mla_sparse", 0, 0, 1),
          ("gdn_sparse", 0, 1, 3)),
         lambda kind, first, at, n: (kind, first, at, n)),
+    # no parent loop: the family was written against ``runs_of`` (PR 65).
+    # Eleven runs of ONE layer, an expert layer numbered in its stack and
+    # among the layers that hold no leaf
+    "nemotron_h": (
+        "nemotron-3-super-120b-a12b",
+        tuple((kind, i, i, 1) for kind, i in (
+            ("mamba", 0), ("moe", 0), ("mamba", 1), ("moe", 1), ("mamba", 2),
+            ("moe", 2), ("mamba", 3), ("moe", 3), ("mamba", 4),
+            ("attention", 0), ("moe", 4))),
+        lambda kind, first, at, n: (kind, first, at, n)),
 }
 ALL = dict({name: cell for name, (cell, _, _) in MIXED.items()},
            longcat_flash="longcat-flash-chat", evabyte="evabyte")

@@ -122,14 +122,14 @@ def test_pallas_update_in_interpret_mode_matches_the_jnp_route(
     assert not np.asarray(y)[idle].any()
 
 
-def _layer_inputs(slots, h, p, n, layers=3, k=4, seed=5):
+def _layer_inputs(slots, h, p, n, g=1, layers=3, k=4, seed=5):
     """A stack of Mamba mixers between their matmuls at small lane-aligned
     sizes: the weights as the model's stack holds them, ``in_proj``'s result
     for one token a slot, the state and the convolution's tail as the cache
     keeps them."""
     rng = np.random.RandomState(seed)
     f = lambda *s: jnp.asarray(rng.randn(*s), jnp.float32)  # noqa: E731
-    d_in, c = h * p, h * p + 2 * n
+    d_in, c = h * p, h * p + 2 * g * n
     stack = {"conv_w": f(layers, k, c) * 0.5, "conv_b": f(layers, c) * 0.1,
              "dt_bias": f(layers, h), "D": f(layers, h),
              "A_log": jnp.log(jnp.asarray(rng.uniform(1.0, 8.0, (layers, h)),
@@ -138,42 +138,47 @@ def _layer_inputs(slots, h, p, n, layers=3, k=4, seed=5):
     state = f(layers, slots, h, p, n)
     conv = jnp.stack([ssm.tail_to_rows(f(slots, k - 1, c), d_in)
                       for _ in range(layers)])
-    return stack, f(slots, 2 * d_in + 2 * n + h), state, conv
+    return stack, f(slots, 2 * d_in + 2 * g * n + h), state, conv
 
 
-def _split_step(stack, zxbcdt, state, conv, layer, active, h, p, n, eps):
-    """The layer as ``GraniteHybridModel._mamba_layer`` runs it split."""
+def _split_step(stack, zxbcdt, state, conv, layer, active, h, p, n, eps, g=1):
+    """The layer as ``models/mamba.mixer`` runs it split: a head reads its
+    group's ``B`` and ``C``, the gated norm runs over a group's channels."""
     b, d_in = zxbcdt.shape[0], h * p
     blk = {name: v[layer] for name, v in stack.items()}
-    z, xbc, dt = jnp.split(zxbcdt, [d_in, 2 * d_in + 2 * n], axis=-1)
+    z, xbc, dt = jnp.split(zxbcdt, [d_in, 2 * d_in + 2 * g * n], axis=-1)
     dt = jax.nn.softplus(dt + blk["dt_bias"])
-    tail = ssm.rows_to_tail(conv[layer], blk["conv_w"].shape[0], d_in, 2 * n)
+    tail = ssm.rows_to_tail(conv[layer], blk["conv_w"].shape[0], d_in,
+                            2 * g * n)
     xbc, tail = causal_conv(xbc[:, None], tail, blk["conv_w"], blk["conv_b"],
                             active.astype(jnp.int32))
-    xs, bm, cm = jnp.split(xbc[:, 0], [d_in, d_in + n], axis=-1)
+    xs, bm, cm = jnp.split(xbc[:, 0], [d_in, d_in + g * n], axis=-1)
     y, state = ssm_update(state, layer, xs.reshape(b, h, p), dt,
-                          -jnp.exp(blk["A_log"]), bm[:, None], cm[:, None],
-                          blk["D"], active, impl="jnp")
-    y = y.reshape(b, d_in) * jax.nn.silu(z)
-    y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps) \
-        * blk["gate_norm"]
-    return y, state, conv.at[layer].set(ssm.tail_to_rows(tail, d_in))
+                          -jnp.exp(blk["A_log"]), bm.reshape(b, g, n),
+                          cm.reshape(b, g, n), blk["D"], active, impl="jnp")
+    y = (y.reshape(b, d_in) * jax.nn.silu(z)).reshape(b, g, d_in // g)
+    y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + eps)
+    return y.reshape(b, d_in) * blk["gate_norm"], state, \
+        conv.at[layer].set(ssm.tail_to_rows(tail, d_in))
 
 
 @MASKS
-@pytest.mark.parametrize("layer", [0, 2])
-def test_folded_step_in_interpret_mode_matches_the_split_route(active, layer):
+@pytest.mark.parametrize("layer,g", [(0, 1), (2, 1), (1, 2), (2, 8)])
+def test_folded_step_in_interpret_mode_matches_the_split_route(active, layer,
+                                                               g):
     """Everything between ``in_proj`` and ``out_proj`` inside the one call:
     ``y``, the state and the convolution's tail as the split route leaves
-    them; a slot that is not decoding keeps its state AND its tail, and so
-    does every other layer, bit for bit."""
-    h, p, n, eps = 4, 64, 128, 1e-5
-    assert ssm.step_folds(h, p, n, 1) and not ssm.step_folds(h, p, n, 2) \
-        and not ssm.step_folds(h, p, n, 1, block_heads=2)
-    stack, zxbcdt, state, conv = _layer_inputs(5, h, p, n)
+    them, at one group, two and eight (a head reads its group's ``B`` and
+    ``C``; the norm runs a group); a slot that is not decoding keeps its
+    state AND its tail, and so does every other layer, bit for bit."""
+    h, p, n, eps = 2 * max(g, 2), 64, 128, 1e-5
+    assert ssm.step_folds(h, p, n, g) \
+        and not ssm.step_folds(h, p, n, g, block_heads=h // g // 2) \
+        and not ssm.step_folds(h, p, n, h)      # a head is half a row
+    stack, zxbcdt, state, conv = _layer_inputs(5, h, p, n, g)
     act = jnp.asarray(active)
     y_ref, s_ref, c_ref = _split_step(stack, zxbcdt, state, conv, layer, act,
-                                      h, p, n, eps)
+                                      h, p, n, eps, g)
     y, s, c = ssm.mamba_step(
         zxbcdt, state, conv, jnp.int32(layer), ssm.fold_weights(stack, p),
         ssm.slot_order(act), act, eps=eps, interpret=True)

@@ -564,6 +564,57 @@ def test_solars_prompt_kernel_is_the_one_it_was(one_chip):
     assert _kernel_digests(text) == ["51ef2f4fd73336aa"]
 
 
+def _folded_mamba_step(sharding, l, b, h, p, n, g, k=4):
+    """``ops/ssm.mamba_step`` between a stack's ``in_proj`` and ``out_proj``,
+    the small weights folded inside the program as a decode step makes them
+    -> ``(fn, operands)``."""
+    from deepspeed_tpu.ops import ssm
+
+    f32 = jnp.float32
+    d_in, c = h * p, h * p + 2 * g * n
+    stack = {"conv_w": _sds(sharding, (l, k, c), f32),
+             "conv_b": _sds(sharding, (l, c), f32),
+             "gate_norm": _sds(sharding, (l, d_in), f32),
+             **{name: _sds(sharding, (l, h), f32)
+                for name in ("dt_bias", "A_log", "D")}}
+
+    def fn(stack, zx, state, conv, layer, active):
+        return ssm.mamba_step(zx, state, conv, layer,
+                              ssm.fold_weights(stack, p),
+                              ssm.slot_order(active), active, eps=1e-5,
+                              interpret=False)
+
+    return fn, (stack, _sds(sharding, (b, 2 * d_in + 2 * g * n + h)),
+                _sds(sharding, (l, b, h, p, n), f32),
+                _sds(sharding, (l, b) + ssm.conv_tail_shape(k, d_in,
+                                                            2 * g * n)),
+                _sds(sharding, (), jnp.int32),
+                _sds(sharding, (b,), jnp.bool_))
+
+
+def test_folded_mamba_step_at_one_group_is_the_kernel_it_was(one_chip):
+    """granite-4.0-h-micro's folded step (36 layers, 64 slots, 64 heads of 64,
+    one group): operation for operation the module it lowered to before the
+    call knew of groups (PR 64's tree gives the same digest). A PR that
+    changes the step on purpose records the new digest here."""
+    fn, ops = _folded_mamba_step(one_chip, 36, 64, 64, 64, 128, 1)
+    assert _kernel_digests(_lowered_text(fn, *ops)) == ["60051eab88159733"]
+
+
+def test_folded_mamba_step_at_eight_groups(one_chip):
+    """Nemotron 3 Super's Mamba-2 layer (128 heads of 64, state 128, EIGHT
+    groups, 64 slots): a cell holds a slot's 4 MB of state in and out, twice
+    each for the pipeline, past the default 16 MB of scoped VMEM, so the call
+    states its own limit; a group's 8 rows of lanes at a time. It compiles,
+    in place: no temporary of the state's size."""
+    fn, ops = _folded_mamba_step(one_chip, 5, 64, 128, 64, 128, 8)
+    lowered = jax.jit(fn, donate_argnums=(2, 3)).lower(*ops)
+    assert "dstpu_ssm_update" in lowered.as_text()
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 @pytest.mark.parametrize("tokens", [32, 4096], ids=["decode", "prefill"])
 def test_held_experts_grouped_matmul(one_chip, tokens):
     """The expert layer's grouped matmuls (``jax.lax.ragged_dot``, XLA's own
@@ -891,6 +942,14 @@ def _evabyte_cell():
     return EvaByteModel(EvaByteConfig(num_layers=2)), 12, 32768
 
 
+def _nemotron_cell():
+    from deepspeed_tpu.models.nemotron_h import NemotronHConfig, NemotronHModel
+
+    # the cell whole: one period of eleven layers, every run one layer
+    return NemotronHModel(NemotronHConfig(
+        vocab_size=32768, max_seq_len=4096, held=(0, 128))), 64, 4096
+
+
 def _weights(model, sharding):
     """The model's parameters as the serving engine holds them: bf16, as
     shapes on the described chip; and one layer's sizes of every stacked
@@ -961,9 +1020,10 @@ def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
     lowered, state, leaves = _lower_decode_step(cell, one_chip)
     compiled = lowered.compile()
     _assert_copies_no_weight(compiled, leaves)
-    # one token a slot: the expert layer's buffer is two or three row tiles
-    # and the switch a prompt block carries (moe/grouped.held_experts) has
-    # no part in the step, nor has any other conditional
+    # one token a slot: the expert layer's worst-case buffer is two or three
+    # row tiles in these cells, and the switch a prompt block carries
+    # (moe/grouped.held_experts) has no part in the step, nor has any other
+    # conditional (Nemotron's step, eleven tiles, asks for it: below)
     assert " conditional(" not in compiled.as_text()
     if "conv" in state:
         _assert_mamba_runs_are_folded(compiled.as_text(), state["conv"])
@@ -994,6 +1054,42 @@ def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
         # prompt block's kernel has no part in a one-token step
         assert compiled.as_text().count("dstpu_kda_update") >= 1
         assert "dstpu_kda_prefill" not in compiled.as_text()
+
+
+def test_nemotrons_decode_step_fits_the_chip_and_folds(one_chip, fused_routes):
+    """The new cell's decode step at its own size (eleven layers, 128 of 512
+    experts held, 64 slots of 4,096 rows): arguments and temporaries fit a
+    v5e's 16 GB with room for the prefill programs; every Mamba layer is the
+    one folded call at eight groups with the state and the tail in place; no
+    weight is copied; and the step holds no conditional: the sparse layers
+    run the worst-case sorted buffer of 1,408 rows as every family's step
+    does."""
+    lowered, state, leaves = _lower_decode_step(_nemotron_cell, one_chip)
+    compiled = lowered.compile()
+    _assert_copies_no_weight(compiled, leaves)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.5e9
+    text = compiled.as_text()
+    # every run is ONE layer, so the runs' loops are unrolled into the entry
+    # computation: five folded calls there, the slot order made once, and no
+    # operation outside a kernel that writes a result of the state's or the
+    # tail's size (both leaves are updated in place)
+    found, _ = _outside_fusions(text)
+    kernels = [line for _, _, opcode, line in found
+               if opcode == "custom-call" and "dstpu_ssm_update" in line]
+    assert len(kernels) == 5, kernels
+    assert len([kind for _, kind, opcode, _ in found
+                if opcode == "sort" and "pred[" in kind]) == 1
+    in_place = {("f32", _sizes(state["ssm"].shape)),
+                ("bf16", _sizes(state["conv"].shape))}
+    for _, kind, opcode, line in found:
+        if opcode in ("fusion", "copy", "slice", "dynamic-slice"):
+            sized = {(d, _sizes(dims.split(","))) for d, dims in
+                     re.findall(r"(\w+)\[([\d,]*)\]", kind)}
+            assert not in_place & sized, line.strip()[:240]
+    assert " conditional(" not in text
+    # the one attention layer takes the fused step over a request's own rows
+    assert "dstpu_decode_step" in text
 
 
 def _assert_mamba_runs_are_folded(text, conv):
