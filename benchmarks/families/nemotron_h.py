@@ -27,11 +27,13 @@ def shapes(cfg: Mapping) -> dict:
     parameter HELD HERE (the held experts, the held vocabulary rows, an
     untied head); ``active_params`` those a token passes through on average:
     of its ``experts_per_token`` experts the held share. ``mamba_layers`` and
-    the ``ssm_*`` sizes are for ``work/ssm_update.py``; ``latent``,
-    ``expert_mlp``, ``experts``, ``experts_held``, ``experts_per_token`` and
-    ``sparse_layers`` for ``work/moe_latent_experts.py``. ``layers`` counts
-    the ATTENTION layers, the only ones whose cache ``flops.py`` computes
-    from; ``total_layers`` every layer. ``mlp`` is the shared expert's width."""
+    the ``ssm_*`` sizes are for ``work/ssm_update.py``; ``expert_matrices``
+    and ``expert_in_width`` (an expert is TWO matrices of ``latent x
+    expert_mlp``), ``expert_mlp``, ``experts``, ``experts_held``,
+    ``experts_per_token`` and ``sparse_layers`` for ``work/moe_experts.py``.
+    ``layers`` counts the ATTENTION layers, the only ones whose cache
+    ``flops.py`` computes from; ``total_layers`` every layer. ``mlp`` is the
+    shared expert's width."""
     d, dh = cfg["hidden_size"], cfg["head_dim"]
     heads, kv_heads = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     h, p, n = cfg["mamba_num_heads"], cfg["mamba_head_dim"], \
@@ -67,6 +69,7 @@ def shapes(cfg: Mapping) -> dict:
             "state_bytes_per_slot": n_mamba * (h * p * n * state
                                                + (k - 1) * conv * 2),
             "latent": lat, "expert_mlp": em, "experts": experts,
+            "expert_matrices": 2, "expert_in_width": lat,
             "experts_held": held, "experts_per_token": per_token,
             "sparse_layers": n_moe,
             # by kind, for the tests that hold the count to the published one

@@ -10,6 +10,10 @@ cache rows are of another size says so in two optional keys: ``v_head_dim``
 (default ``head_dim``) and ``cache_row_dim``, the elements one cached token
 holds in one layer (default ``2 * kv_heads * head_dim``: a key and a value a
 key-value head). Nothing here reads a configuration's own keys.
+
+``*_model_flops(observations)`` are the model FLOPs a traced window's work
+needed, what ``readers/trace_program_mfu.py`` divides by the peak and a
+program's device seconds: found by name from a metric file's ``flops``.
 """
 from __future__ import annotations
 
@@ -60,6 +64,81 @@ def decode_attn_work(s: Mapping[str, int], *, context_lens: Sequence[int]):
     flops = s["layers"] * rows * h * 2.0 * (dh + s.get("v_head_dim", dh))
     nbytes = s["layers"] * rows * s.get("cache_row_dim", 2 * hkv * dh) * BF16
     return flops, nbytes
+
+
+def attended_rows(s: Mapping[str, int], upto: int) -> float:
+    """Cached rows that the tokens at contexts 1..``upto`` of one sequence
+    attended, summed over the tokens and over the attention layers
+    (``attn_layers`` where a family's ``layers`` counts other mixers too).
+    A token at context c attends c rows of a global layer. A family with a
+    ``window`` states either ``sliding_layers`` and ``global_layers`` (the
+    sliding ones attend min(c, window)) or, for every layer, an exact window
+    with one pooled row per ``chunk`` tokens before it."""
+    n, w = s.get("attn_layers", s["layers"]), s.get("window")
+    full = upto * (upto + 1) / 2.0
+    if w is None:
+        return n * full
+    past = max(upto - w, 0)
+    near = full - past * (past + 1) / 2.0       # sum of min(c, w)
+    if "sliding_layers" in s:
+        return s["sliding_layers"] * near + s["global_layers"] * full
+    pooled = past * (past + 1) / 2.0 / s["chunk"] if "chunk" in s else 0.0
+    return n * (near + pooled)
+
+
+def _serve_flops(s: Mapping[str, int], tokens: float, through_head: float,
+                 rows: float) -> float:
+    """2 FLOPs a parameter a token passes through (``active_params``, of
+    which the LM head's ``vocab x hidden`` only where a position's logits
+    were needed) plus attention's 2 * (head_dim + v_head_dim) a query head
+    for every cached row attended. The embedding table of an untied model is
+    counted though it is looked up (the usual count, as in training);
+    recurrent mixers' state updates (under 1% of a layer's matmuls) are
+    not, so the share can only read low by them."""
+    dh = s["head_dim"]
+    head = float(s["vocab"]) * s["hidden"]
+    return (2.0 * (s["active_params"] - head) * tokens
+            + 2.0 * head * through_head
+            + 2.0 * s["heads"] * (dh + s.get("v_head_dim", dh)) * rows)
+
+
+def decode_model_flops(obs: Mapping) -> float:
+    """Model FLOPs of the tokens that decode steps committed inside the
+    traced window of a ``serve_open_loop`` run: every token of a finished
+    request but its first (the prefill's), by its commit stamp; the token at
+    index i of a prompt of p attended p + i rows a layer."""
+    lo, hi = obs["trace_span"]
+    s = obs["shapes"]
+    tokens, rows = 0, 0.0
+    for r in obs["requests"]:
+        inside = [i for i, t in enumerate(r["token_times"])
+                  if i > 0 and lo <= t < hi]
+        if inside:
+            tokens += len(inside)
+            rows += (attended_rows(s, r["prompt_len"] + inside[-1])
+                     - attended_rows(s, r["prompt_len"] + inside[0] - 1))
+    return _serve_flops(s, tokens, tokens, rows)
+
+
+def prefill_model_flops(obs: Mapping) -> float:
+    """Model FLOPs of the prompts admitted inside the traced window of a
+    ``serve_open_loop`` run, each counted whole (as the prefill kernels'
+    ``work`` files count it): causal attention over the prompt, the LM head
+    at its last position alone."""
+    lo, hi = obs["trace_span"]
+    s = obs["shapes"]
+    prompts = [r["prompt_len"] for r in obs["requests"]
+               if lo <= r["admitted"] < hi]
+    return _serve_flops(s, sum(prompts), len(prompts),
+                        sum(attended_rows(s, p) for p in prompts))
+
+
+def train_model_flops(obs: Mapping) -> float:
+    """Model FLOPs one device's rows needed in the traced steps of a
+    ``train_job`` run: ``train_flops_per_token`` for every token."""
+    t = obs["train"]
+    return (train_flops_per_token(obs["shapes"], t["seq_len"])
+            * t["rows_per_device_step"] * t["seq_len"] * t["traced_steps"])
 
 
 def roofline_seconds(flops: float, nbytes: float, peak: Mapping[str, float]):

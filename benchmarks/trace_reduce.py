@@ -9,9 +9,14 @@ host and device planes share. Device planes are the ``/device:TPU:<n>``
 ones; their ``XLA Ops`` line carries one event for each operation, with
 control flow (``while``, ``conditional``) as a parent around its body, so
 times by name are *self* times: an event's duration less its children's.
+Their ``XLA Modules`` line carries one event for each run of a jitted
+program, named ``jit_<function>(<fingerprint>)``: an operation ran under the
+program whose event holds its start, so a reduction can be held to one
+program (``program``: a regex on that name) where a window runs several.
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import glob
 import os
@@ -23,6 +28,7 @@ Interval = Tuple[float, float]
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 WINDOW_ANNOTATION = "bench/window"
 # A device event is named by its whole HLO instruction, which also names its
 # operands: only the result name, at the start, says what ran. The last name
@@ -37,11 +43,15 @@ COLLECTIVE = re.compile(
 class Trace:
     """``device_ops[d]``: events of device d's op line. ``host``: the
     ``dstpu/*`` and ``bench/*`` annotations of every host thread.
-    ``window``: the traced window on the profile's clock."""
+    ``window``: the traced window on the profile's clock.
+    ``device_programs[d]``: events of device d's module line, one for each
+    run of a jitted program."""
 
     device_ops: Dict[int, List[Event]]
     host: List[Event]
     window: Interval
+    device_programs: Dict[int, List[Event]] = dataclasses.field(
+        default_factory=dict)
     _self: Dict[int, list] = dataclasses.field(default_factory=dict,
                                                repr=False, compare=False)
 
@@ -64,12 +74,14 @@ def load(path: str) -> Trace:
 
     data = ProfileData.from_file(path)
     device_ops: Dict[int, List[Event]] = {}
+    device_programs: Dict[int, List[Event]] = {}
     host: List[Event] = []
     for plane in data.planes:
         m = DEVICE_PLANE.match(plane.name)
         for line in plane.lines:
-            if m and line.name == OPS_LINE:
-                device_ops.setdefault(int(m.group(1)), []).extend(
+            if m and line.name in (OPS_LINE, MODULES_LINE):
+                into = device_ops if line.name == OPS_LINE else device_programs
+                into.setdefault(int(m.group(1)), []).extend(
                     (e.name, e.start_ns * 1e-9,
                      (e.start_ns + e.duration_ns) * 1e-9)
                     for e in line.events)
@@ -83,7 +95,7 @@ def load(path: str) -> Trace:
     if not windows:
         raise ValueError(f"{path}: no {WINDOW_ANNOTATION!r} annotation")
     window = (min(w[1] for w in windows), max(w[2] for w in windows))
-    return Trace(device_ops, host, window)
+    return Trace(device_ops, host, window, device_programs)
 
 
 # ------------------------------------------------------------- intervals
@@ -162,24 +174,54 @@ def self_times(events: Iterable[Event]) -> List[Tuple[str, float, float, float]]
     return [(n, s, e, max(t, 0.0)) for n, s, e, t in out]
 
 
-def time_by_name(trace: Trace, pattern: Optional[str] = None
-                 ) -> Dict[str, float]:
+def program_intervals(trace: Trace, device: int, program: str
+                      ) -> List[Interval]:
+    """When the programs whose names the regex finds ran on one device:
+    disjoint sorted intervals, not clipped to the window."""
+    rx = re.compile(program)
+    return union((s, e) for name, s, e in
+                 trace.device_programs.get(device, ()) if rx.search(name))
+
+
+def program_seconds(trace: Trace, program: str) -> float:
+    """Seconds inside the window in which a program the regex finds was
+    running, from its first operation to its last, averaged over the
+    devices."""
+    if not trace.device_ops:
+        return 0.0
+    return sum(total(clip(program_intervals(trace, dev, program),
+                          trace.window))
+               for dev in trace.device_ops) / len(trace.device_ops)
+
+
+def time_by_name(trace: Trace, pattern: Optional[str] = None,
+                 program: Optional[str] = None) -> Dict[str, float]:
     """Self seconds by operation name inside the window, averaged over the
-    devices; with ``pattern``, only names the regex finds."""
+    devices; with ``pattern``, only names the regex finds; with ``program``,
+    only operations that started under a program whose name that regex
+    finds (none, in a trace without a module line)."""
     rx = re.compile(pattern) if pattern else None
     acc: Dict[str, float] = {}
     lo, hi = trace.window
     for dev in trace.device_ops:
+        under = (None if program is None
+                 else program_intervals(trace, dev, program))
+        starts = [iv[0] for iv in under or ()]
         for name, s, e, t in trace.self_times(dev):
             if e <= lo or s >= hi or (rx and not rx.search(name)):
                 continue
+            if under is not None:
+                i = bisect.bisect_right(starts, s) - 1
+                if i < 0 or s >= under[i][1]:
+                    continue
             acc[name] = acc.get(name, 0.0) + t
     n = max(len(trace.device_ops), 1)
     return {k: v / n for k, v in acc.items()}
 
 
-def matched_seconds(trace: Trace, pattern: str) -> float:
-    return sum(time_by_name(trace, pattern).values())
+def matched_seconds(trace: Trace, pattern: str,
+                    program: Optional[str] = None) -> float:
+    return sum(time_by_name(trace, pattern, program).values())
 
 
 def exposed_collective_seconds(trace: Trace) -> float:

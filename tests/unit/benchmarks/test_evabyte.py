@@ -51,7 +51,7 @@ KIND_WIDE = (
     "step.prefill_chunk_ms", "step.upload_host_ms", "step.launch_host_ms",
     "step.fetch_wait_ms", "step.commit_host_ms", "step.decode_overlap_share",
     "device.idle_share.serve", "host.stall_ms.serve",
-    "host.gc_pause_ms.serve")
+    "host.gc_pause_ms.serve", "step.decode_mfu", "step.prefill_mfu")
 OWN = ("kernel.eva_decode_roofline", "kernel.eva_decode_share",
        "kernel.eva_prefill_roofline", "kernel.eva_prefill_share",
        "cache.eva_live_share", "mixer.eva_split_steps")

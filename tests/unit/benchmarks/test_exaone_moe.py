@@ -251,7 +251,9 @@ def test_the_new_metric_files_through_their_readers():
     tr = trace_reduce.Trace(
         {0: [(experts, 0.0, 0.002), (meta, 0.002, 0.003),
              (other, 0.003, 0.008)]},
-        [("bench/window", 0.0, 1.0)], (0.0, 1.0))
+        [("bench/window", 0.0, 1.0)], (0.0, 1.0),
+        # the roofline reads the decode program's events alone
+        {0: [("jit_decode(4070338962791433473)", 0.0, 0.008)]})
     obs = {"trace": tr, "peak": {"bf16_tflops": 197.0, "hbm_gbps": 819.0},
            "shapes": s, "trace_span": [0.0, 1.0], "requests": [],
            "spans": [{"name": "decode_step", "start": 0.5, "end": 0.6}],

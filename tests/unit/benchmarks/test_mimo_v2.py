@@ -66,8 +66,9 @@ KIND_WIDE = (
     "sched.iter_schedule_p95_ms", "step.prefill_ms", "step.decode_ms",
     "step.prefill_chunk_ms", "step.upload_host_ms", "step.launch_host_ms",
     "step.fetch_wait_ms", "step.commit_host_ms", "step.decode_overlap_share",
-    "device.idle_share.serve")
+    "device.idle_share.serve", "step.decode_mfu", "step.prefill_mfu")
 LISTED = KIND_WIDE + (
+    "kernel.moe_experts_prefill_roofline",
     "step.prefill_pad_share", "kernel.decode_attn_share",
     "kernel.decode_attn_live_share", "cache.window_live_share",
     "kernel.moe_experts_roofline", "kernel.moe_experts_share",

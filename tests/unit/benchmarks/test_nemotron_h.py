@@ -267,7 +267,13 @@ def test_the_cell_is_one_chip_and_lists_what_it_reports(bench):
             (spec["unit"], spec["better"], spec["source"], spec["layer"])
     for name in NEW[:2]:
         spec = harness.load_json("layer_metrics", name + ".json")
-        assert spec["params"]["pattern"] == r"^%ragged-dot-(?!metadata)"
+        # XLA's kernel, or a grouped matmul of the repo's own by its name
+        assert spec["params"]["pattern"] == (
+            r"^%ragged-dot-(?!metadata)|^%[\w.\-]*dstpu_moe_experts")
+    spec = harness.load_json("layer_metrics",
+                             "kernel.moe_latent_experts_roofline.json")
+    assert (spec["params"]["work"], spec["params"]["phase"]) == \
+        ("moe_experts", "decode")
     spec = harness.load_json("layer_metrics",
                              "moe.held_experts_read_share.json")
     assert (spec["reader"], spec["params"]["numerator"],
@@ -322,14 +328,20 @@ def test_latent_experts_work_against_a_hand_worked_window():
                         "serving/moe_assignments": 1_760_000},
            "requests": [{"prompt_len": 1000, "admitted": 10.2},
                         {"prompt_len": 3000, "admitted": 9.0}]}
-    n_flops, n_bytes = harness.module("work", "moe_latent_experts").work(obs)
+    # one work file for every family's grouped matmul: the family states an
+    # expert's form (benchmarks/work/moe_experts.py)
+    assert (s["expert_matrices"], s["expert_in_width"]) == (2, 1024)
+    n_flops, n_bytes = harness.module("work", "moe_experts").work(obs)
     elems = 2 * 1024 * 2688
     pairs = 20 * 440 + 1000 * 22 * 0.25 * 5
     read = 20 * 310 + 1 * 5 * 128
     assert n_flops == pytest.approx(2.0 * elems * pairs)
     assert n_bytes == pytest.approx(2.0 * elems * read)
-    # a sixth of what work/moe_experts.py counts for the same window
-    wide = dict(obs, shapes=dict(s, width=4096))
+    # a sixth of what it counts for the same window where an expert is the
+    # default three matrices of width x expert_mlp
+    wide = dict(obs, shapes={k: v for k, v in dict(s, width=4096).items()
+                             if k not in ("expert_matrices",
+                                          "expert_in_width")})
     f3, b3 = harness.module("work", "moe_experts").work(wide)
     assert f3 == pytest.approx(6 * n_flops) and b3 == pytest.approx(6 * n_bytes)
 
@@ -352,7 +364,9 @@ def test_the_new_metric_files_through_their_readers():
     tr = trace_reduce.Trace(
         {0: [(dot, 0.0, 0.002), (meta, 0.002, 0.003), (reader, 0.003, 0.004),
              (step, 0.004, 0.008)]},
-        [("bench/window", 0.0, 1.0)], (0.0, 1.0))
+        [("bench/window", 0.0, 1.0)], (0.0, 1.0),
+        # the roofline reads the decode program's events alone
+        {0: [("jit_decode(4070338962791433473)", 0.0, 0.008)]})
     obs = {"trace": tr, "peak": {"bf16_tflops": 197.0, "hbm_gbps": 819.0},
            "shapes": s, "trace_span": [0.0, 1.0],
            "spans": [{"name": "decode_step", "start": 0.5, "end": 0.51}],
