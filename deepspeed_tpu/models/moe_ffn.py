@@ -142,9 +142,11 @@ def ffn(z, blk, kind: str, valid, c, buffer_counters: bool = False):
     ``buffer_counters``: BUFFER_COUNTERS follow STEP_COUNTERS in the vector
     (``zero_counts(T, 7)``).
 
-    ``T`` says what is walked: a decode step (``T == 1``) keeps the expert
-    layer's worst-case buffer, ``B * min(k, held)`` rows; a prompt block
-    hands the router's width on, and its buffer follows the pairs held
+    ``T`` says what is walked: a decode step (``T == 1``) hands no width on
+    and is, on the chip, the one call that streams each touched expert once
+    for all ``B`` rows (ops/moe_experts.py; elsewhere the sorted buffer's
+    worst case, ``B * min(k, held)`` rows); a prompt block hands the router's
+    width on, and its buffer follows the pairs held
     (moe/grouped.held_experts)."""
 
     limit = getattr(c, "swiglu_limit", None)

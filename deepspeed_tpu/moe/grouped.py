@@ -3,11 +3,18 @@
 The DeepSeek-V3 style router that today's large open models use (sigmoid
 scores, the ``k`` largest after a selection bias, weights normalised over the
 chosen, a scaling factor) and a dispatch with no capacity and no drop: the
-(token, expert) pairs routed to the experts held HERE are sorted by expert
-into contiguous groups and each group is multiplied by its expert
-(``jax.lax.ragged_dot``: on a TPU a grouped-matmul kernel of XLA's own that
-visits only the row tiles the groups fill, so an expert no token chose is
-neither multiplied nor read).
+(token, expert) pairs routed to the experts held HERE reach their experts
+one of two ways. A PROMPT block's are sorted by expert into contiguous groups
+and each group is multiplied by its expert (``jax.lax.ragged_dot``: on a TPU
+a grouped-matmul kernel of XLA's own that visits only the row tiles the
+groups fill). A DECODE step's, one token a slot, are not sorted at all: on
+the chip the step is the one Pallas call ``dstpu_moe_experts_decode``
+(ops/moe_experts.py), which streams each expert that got a pair once and
+multiplies all the step's rows by it, weighed by the token's weight where it
+chose the expert and 0 where it did not; off the chip, and for more rows than
+ride free under a streamed matrix, it is the sorted buffer's worst case and
+``ragged_dot`` (:func:`ops.moe_experts.default_route`). Either way an expert
+no token chose is neither multiplied nor read.
 
 ``held=(first, count)`` is one chip's share of an expert-parallel layer: the
 router, the choice of ``k`` and the normalisation run over ALL experts, the
@@ -20,23 +27,19 @@ here (ROADMAP R2); summed over the shares ``(0, c), (c, c), ...`` the results
 give the uncut layer (tests/unit/inference/test_exaone_moe.py).
 
 A step's time follows what it touches: a decode step's 5 to 12 tokens leave
-about half of 16 held experts without one, the kernel reads the others only,
-and each costs about 0.19 ms. With weights drawn from a seed the share of
-pairs routed here is the seed's (10.5% to 14.1% over 12 seeds for the
-expected 12.5%), and the decode gap moves with it (PERF.md, PR 35, which also
-tried a decode step that multiplies every held expert: 4 ms a step slower,
-and no steadier; the benchmark's cell serves one checkpoint for that reason).
+about half of 16 held experts without one and the step reads the others
+only, at what their bytes cost (PERF.md, PR 67). With weights drawn from a
+seed the share of pairs routed here is the seed's (10.5% to 14.1% over 12
+seeds for the expected 12.5%), and the decode gap moves with it (PERF.md, PR
+35, which also tried a decode step that multiplies every held expert: 4 ms a
+step slower, and no steadier; the benchmark's cell serves one checkpoint for
+that reason).
 
 Shapes are static: a token's ``k`` experts are distinct, so at most
 ``min(k, count)`` of its pairs are held, and a sorted buffer of
 ``N * min(k, count)`` rows holds them whatever the routing; rows behind the
-last group are masked. A decode step has that buffer: two to four row tiles
-at 32 or 64 slots and ``k`` 8, eleven at 64 slots and ``k`` 22 of which 128
-are held, where 16 live slots fill a part of one; the grouped matmul makes
-row tiles for filled groups only, so the rows behind the pairs cost a gather
-and an activation and no multiplication, less than a switch on the pairs
-held would (PERF.md, PR 65: the step 7 to 10% slower with one). A prompt block is routed ``k * count / E`` pairs a token on
-average, an eighth or a forty-eighth of the worst case, and everything
+last group are masked. A prompt block is routed ``k * count / E`` pairs a
+token on average, an eighth or a forty-eighth of the worst case, and everything
 computed a row would run over the rest for nothing: its buffer is one of two
 static sizes, chosen on the device by the pairs it holds
 (:func:`held_experts`, :func:`compact_rows`): twice the expectation, or the
@@ -52,6 +55,8 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from deepspeed_tpu.ops import moe_experts
 
 
 class Routing(NamedTuple):
@@ -93,15 +98,17 @@ def softmax_topk_route(x, w_router, select_bias, k: int, *,
 class ExpertCounts(NamedTuple):
     """What a call did, counted on the device (int32 scalars)."""
     touched: jax.Array           # held experts that got at least one pair
-    streamed: jax.Array          # held experts whose weights the call read
+    streamed: jax.Array          # held experts whose weights the call read:
+    #                              the touched ones, by either route
     assignments_held: jax.Array  # pairs routed to held experts
     assignments: jax.Array       # all pairs of the valid tokens
     spilled: jax.Array           # 1: a prompt block's pairs outgrew the
     #                              compact buffer and the full one ran
 
 
-# what XLA's grouped matmul makes its row tiles of (ROADMAP S11): the compact
-# buffer is whole tiles
+# what XLA's grouped matmul makes its row tiles of (ROADMAP S11): a prompt
+# block's compact buffer is whole tiles (a decode step on the chip has no
+# buffer: ops/moe_experts.py)
 ROW_TILE = 128
 
 
@@ -135,18 +142,23 @@ def compact_rows(n: int, k: int, count: int, n_experts: int) -> int:
     return -(-twice // ROW_TILE) * ROW_TILE
 
 
-def count_traced(compact: bool) -> None:
-    """Say in the program's registry how a prompt's expert layer was traced:
-    ``moe/traced_prompt_compact`` (the three-way switch of
+# the two ways an expert layer of each phase is traced
+TRACED = {"prompt": ("full", "compact"), "decode": ("grouped", "fused")}
+
+
+def count_traced(phase: str, second: bool) -> None:
+    """Say in the program's registry how an expert layer was traced. A
+    prompt's: ``moe/traced_prompt_compact`` (the three-way switch of
     :func:`held_experts`) or ``moe/traced_prompt_full`` (the worst-case
     buffer alone: a block so short that its compact buffer would be no
-    smaller). Both exist from the first call on."""
+    smaller). A decode step's: ``moe/traced_decode_fused`` (the one call of
+    ops/moe_experts.py) or ``moe/traced_decode_grouped`` (the sorted buffer
+    and ``ragged_dot``). A phase's two exist from its first call on."""
     from deepspeed_tpu.telemetry.registry import get_registry
 
     reg = get_registry()
-    counters = [reg.counter("moe/traced_prompt_" + n)
-                for n in ("full", "compact")]
-    counters[bool(compact)].inc()
+    counters = [reg.counter(f"moe/traced_{phase}_{n}") for n in TRACED[phase]]
+    counters[bool(second)].inc()
 
 
 def held_experts(x, routing: Routing, w_gate, w_up, w_down,
@@ -158,7 +170,7 @@ def held_experts(x, routing: Routing, w_gate, w_up, w_down,
     ``swiglu_limit``) the gate's input clamped from above and the linear half
     to ``[-limit, limit]`` (:func:`swiglu_gate`, :func:`swiglu_up`). With
     ``w_gate`` ``None`` an expert is the two-matrix ``relu(x Wu)^2 Wd``
-    (:func:`relu2`; the same sort, groups and counts; two grouped matmuls for
+    (:func:`relu2`; the same routes, groups and counts; two matrices for
     three).
 
     ``x [N, d]``; ``w_gate, w_up [count, d, m]``, ``w_down [count, m, d]``:
@@ -188,10 +200,24 @@ def held_experts(x, routing: Routing, w_gate, w_up, w_down,
     three grouped matmuls against the whole stacks are the same in the
     compact and the full branch and the buffer holds every held pair in
     both, so the result is the same bit for bit whichever runs: no pair is
-    dropped, there is no capacity. Without ``n_experts`` (a decode step,
-    whose worst case is some row tiles), or where the compact buffer would
-    be no smaller than the full one, there is no switch and the full buffer
-    alone.
+    dropped, there is no capacity. Where the compact buffer would be no
+    smaller than the full one there is no switch and the full buffer alone.
+
+    Without ``n_experts`` the call is a DECODE step and holds no conditional.
+    Where :func:`ops.moe_experts.default_route` says ``"fused"`` (a TPU, rows
+    that ride free under a streamed matrix, widths in whole rows of lanes:
+    every cell's step) it is ONE Pallas call, ``dstpu_moe_experts_decode``:
+    no sort, no buffer, the stacks as they lie with the layer in the DMA's
+    index, each touched expert streamed once against all ``N`` rows into a
+    float32 sum weighed by ``weights[n, e]``, selected where the token chose
+    the expert. The sum then runs in expert order and an expert's result is
+    not rounded before it is weighed: rounding alone tells it from the other
+    route (``"grouped"``: the full buffer through ``ragged_dot``, weighed a
+    row in float32), which runs off the chip and for more rows. The fused
+    call serves only: it has no VJP and sits in no ``shard_map``, so a step
+    on the chip that is differentiated, or sharded over a mesh, has no route
+    here yet (no cell does either). Which way a step was traced:
+    ``moe/traced_decode_fused`` / ``_grouped``.
     -> ``(y [N, d], ExpertCounts)``."""
     first, count = held
     n, k = routing.experts.shape
@@ -200,11 +226,34 @@ def held_experts(x, routing: Routing, w_gate, w_up, w_down,
     here = (local >= 0) & (local < count)
     if valid is not None:
         here &= valid[:, None]
-    # pairs of held experts first, by expert; the rest behind them
+    prompt = n_experts is not None
+    whole = w_up["__whole__"] if isinstance(w_up, dict) else w_up
+    fused = not prompt and moe_experts.default_route(
+        n, *whole.shape[-2:]) == "fused"
+    if not prompt:
+        count_traced("decode", fused)
+    # pairs of held experts first, by expert; the rest behind them (the
+    # fused step sorts nothing)
     key = jnp.where(here, local, count).reshape(-1)
-    by_expert = jnp.argsort(key, stable=True)
+    by_expert = None if fused else jnp.argsort(key, stable=True)
     sizes = jnp.sum(jax.nn.one_hot(key, count, dtype=jnp.int32), axis=0)
     total = sizes.sum()
+
+    def streamed():
+        """The one call a decode step is on the chip: every touched
+        expert against all ``n`` rows, weighed by the token's weight where
+        it chose the expert and 0 where it did not."""
+        chose = here[:, :, None] & (
+            local[:, :, None] == jnp.arange(count, dtype=local.dtype))
+        weights = jnp.where(chose, routing.weights[:, :, None], 0.0).sum(1)
+        # the three are stacked together or not at all
+        layer = w_up["__layer__"] if isinstance(w_up, dict) else None
+        gate, up, down = (w["__whole__"] if isinstance(w, dict) else w
+                          for w in (w_gate, w_up, w_down))
+        act = relu2 if w_gate is None else \
+            lambda g, u: swiglu_gate(g, limit) * swiglu_up(u, limit)
+        return moe_experts.experts_decode(x, weights, sizes, gate, up, down,
+                                          layer, act=act)
 
     def grouped(lhs, w):
         groups = sizes
@@ -250,11 +299,13 @@ def held_experts(x, routing: Routing, w_gate, w_up, w_down,
         picked = jnp.where(here.reshape(-1)[:, None], picked, 0.0)
         return picked.reshape(n, k, -1).sum(1).astype(x.dtype)
 
-    compact = full if n_experts is None else \
-        compact_rows(n, k, count, n_experts)
-    if n_experts is not None:
-        count_traced(compact < full)
-    if compact < full:
+    compact = compact_rows(n, k, count, n_experts) if prompt else full
+    if prompt:
+        count_traced("prompt", compact < full)
+    if fused:
+        spilled = jnp.zeros((), jnp.int32)
+        y = streamed()
+    elif compact < full:
         spilled = (total > compact).astype(jnp.int32)
         y = jax.lax.switch(
             (total > 0).astype(jnp.int32) + spilled,
@@ -262,16 +313,16 @@ def held_experts(x, routing: Routing, w_gate, w_up, w_down,
              lambda: experts(full, True)))
     else:
         spilled = jnp.zeros((), jnp.int32)
-        y = experts(full, n_experts is not None)
+        y = experts(full, prompt)
 
     n_valid = n if valid is None else valid.sum()
     counts = ExpertCounts(
         touched=(sizes > 0).sum().astype(jnp.int32),
-        # XLA's TPU kernel for ragged_dot makes row tiles for filled groups
-        # only, so an expert without a pair is not read (measured, PERF.md
-        # PR 35: 0.41 ms a matrix a layer in decode, where all 16 experts'
-        # 402 MB would take 0.49 ms at the chip's peak); an implementation
-        # that multiplies every held expert counts ``count`` here
+        # the fused step walks the touched experts alone; XLA's TPU kernel
+        # for ragged_dot makes row tiles for filled groups only, so an
+        # expert without a pair is not read either (measured, PERF.md PR
+        # 35); an implementation that multiplies every held expert counts
+        # ``count`` here
         streamed=(sizes > 0).sum().astype(jnp.int32),
         assignments_held=total.astype(jnp.int32),
         assignments=jnp.asarray(n_valid * k, jnp.int32),

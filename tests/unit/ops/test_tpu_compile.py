@@ -616,16 +616,18 @@ def test_folded_mamba_step_at_eight_groups(one_chip):
 
 
 @pytest.mark.parametrize("tokens", [32, 4096], ids=["decode", "prefill"])
-def test_held_experts_grouped_matmul(one_chip, tokens):
-    """The expert layer's grouped matmuls (``jax.lax.ragged_dot``, XLA's own
-    kernel on a TPU) at K-EXAONE's widths, 16 of 128 experts held, against
-    the whole layer-stacked weights: no copy of a layer's experts (1.2 GB)
-    is made to slice them. A decode step's 32 slots are three calls and no
-    conditional; a prompt of 4,096 tokens is told the router's width and
-    carries the switch: three calls in each branch that multiplies, 8,192
-    sorted rows in the compact one, and no float32 buffer of the worst
-    case's 32,768 rows (805 MB) anywhere, nor one in bfloat16 outside the
-    full branch but the gather back to a token's eight pairs."""
+def test_held_experts_grouped_matmul(one_chip, fused_routes, tokens):
+    """The expert layer at K-EXAONE's widths, 16 of 128 experts held,
+    against the whole layer-stacked weights: no copy of a layer's experts
+    (1.2 GB) is made to slice them. A decode step's 32 slots are ONE call,
+    ``dstpu_moe_experts_decode`` (ops/moe_experts.py), with no grouped
+    matmul of XLA's, no sort of the step's pairs and no conditional; a
+    prompt of 4,096 tokens is told the router's width and carries the
+    switch over ``jax.lax.ragged_dot`` (XLA's own kernel on a TPU): three
+    calls in each branch that multiplies, 8,192 sorted rows in the compact
+    one, and no float32 buffer of the worst case's 32,768 rows (805 MB)
+    anywhere, nor one in bfloat16 outside the full branch but the gather
+    back to a token's eight pairs."""
     from deepspeed_tpu.moe.grouped import (compact_rows, held_experts,
                                            sigmoid_topk_route)
 
@@ -650,8 +652,49 @@ def test_held_experts_grouped_matmul(one_chip, tokens):
         assert _assert_prompt_buffer_is_compact(text, tokens * k, d, 1) == \
             {8192}
         return
-    assert len(re.findall(r"%ragged-dot-(?!metadata)[\w.\-]* = ", text)) == 3
+    assert len(re.findall(r"custom-call\(.*dstpu_moe_experts_decode",
+                          text)) == 1
+    assert not re.search(r"%ragged-dot-(?!metadata)[\w.\-]* = ", text)
     assert " conditional(" not in text
+    # the router's top-k is the step's one sort: none of its pairs
+    assert all("/top_k" in line for line in text.splitlines()
+               if " sort(" in line)
+
+
+# (d, m, matrices, N, held, swiglu_limit): Nemotron's latent experts,
+# GigaChat's, K-EXAONE's and LongCat's, Sarvam's and MiMo's, Solar's; the
+# widest of them at the most rows the route sends to the call (FREE_ROWS)
+EXPERT_STEPS = [(1024, 2688, 2, 64, 128, None), (7168, 2048, 3, 64, 16, 10.0),
+                (6144, 2048, 3, 32, 16, None), (4096, 2048, 3, 16, 16, None),
+                (4096, 1280, 3, 16, 40, None), (7168, 2048, 3, 128, 16, 10.0)]
+
+
+@pytest.mark.parametrize("dims", EXPERT_STEPS, ids=str)
+def test_moe_experts_decode(one_chip, fused_routes, dims):
+    """A decode step's routed experts at every expert cell's widths, slots
+    and held experts: the one call compiles under the VMEM limit it states,
+    two buffers of every matrix's tile, the accumulator and a tile's float32
+    results together, and the route is the one the shapes choose."""
+    from deepspeed_tpu.moe.grouped import Routing, held_experts
+    from deepspeed_tpu.ops import moe_experts
+
+    d, m, mats, n, held, limit = dims
+    assert moe_experts.default_route(n, d, m) == "fused"
+    tile = moe_experts.block_m(d, m, mats, 2)
+    assert m % tile == 0 and tile % 128 == 0
+    assert 2 * mats * d * tile * 2 <= moe_experts._TILE_BUFFERS
+
+    def fn(x, experts, weights, wu, wd, *wg):
+        return held_experts(x, Routing(experts, weights), *(wg or (None,)),
+                            wu, wd, (0, held), limit=limit)
+
+    k = 8
+    text = _compiled_text(
+        fn, _sds(one_chip, (n, d)), _sds(one_chip, (n, k), jnp.int32),
+        _sds(one_chip, (n, k), jnp.float32), _sds(one_chip, (held, d, m)),
+        _sds(one_chip, (held, m, d)),
+        *[_sds(one_chip, (held, d, m))] * (mats - 2))
+    assert "dstpu_moe_experts_decode" in text
 
 
 def test_window_prefill_scores_are_a_band(one_chip):
@@ -1020,11 +1063,15 @@ def test_decode_step_copies_no_weight(one_chip, fused_routes, cell):
     lowered, state, leaves = _lower_decode_step(cell, one_chip)
     compiled = lowered.compile()
     _assert_copies_no_weight(compiled, leaves)
-    # one token a slot: the expert layer's worst-case buffer is two or three
-    # row tiles in these cells, and the switch a prompt block carries
-    # (moe/grouped.held_experts) has no part in the step, nor has any other
-    # conditional (Nemotron's step, eleven tiles, asks for it: below)
+    # one token a slot: the expert layers are the one call that streams the
+    # touched experts (ops/moe_experts.py), and the switch a prompt block
+    # carries (moe/grouped.held_experts) has no part in the step, nor has
+    # any other conditional, nor XLA's grouped matmul
     assert " conditional(" not in compiled.as_text()
+    assert "ragged-dot" not in compiled.as_text()
+    if cell in (_exaone_cell, _sarvam_cell, _solar_cell, _longcat_cell,
+                _mimo_cell, _gigachat_cell):
+        assert "dstpu_moe_experts_decode" in compiled.as_text()
     if "conv" in state:
         _assert_mamba_runs_are_folded(compiled.as_text(), state["conv"])
     if cell is _mimo_cell:
@@ -1061,9 +1108,9 @@ def test_nemotrons_decode_step_fits_the_chip_and_folds(one_chip, fused_routes):
     experts held, 64 slots of 4,096 rows): arguments and temporaries fit a
     v5e's 16 GB with room for the prefill programs; every Mamba layer is the
     one folded call at eight groups with the state and the tail in place; no
-    weight is copied; and the step holds no conditional: the sparse layers
-    run the worst-case sorted buffer of 1,408 rows as every family's step
-    does."""
+    weight is copied; and the step holds no conditional and no grouped
+    matmul of XLA's: each of the five sparse layers is the one call that
+    streams the touched experts of its 128, as every family's step is."""
     lowered, state, leaves = _lower_decode_step(_nemotron_cell, one_chip)
     compiled = lowered.compile()
     _assert_copies_no_weight(compiled, leaves)
@@ -1088,6 +1135,10 @@ def test_nemotrons_decode_step_fits_the_chip_and_folds(one_chip, fused_routes):
                      re.findall(r"(\w+)\[([\d,]*)\]", kind)}
             assert not in_place & sized, line.strip()[:240]
     assert " conditional(" not in text
+    assert "ragged-dot" not in text
+    assert len([line for _, _, opcode, line in found
+                if opcode == "custom-call"
+                and "dstpu_moe_experts_decode" in line]) == 5
     # the one attention layer takes the fused step over a request's own rows
     assert "dstpu_decode_step" in text
 
@@ -1355,15 +1406,18 @@ def _program_digest(text):
 def test_solars_decode_program_is_the_one_it_was(one_chip, fused_routes):
     """The cell's decode step (one token a slot, 16 slots of 16,384 rows, the
     folded ``dstpu_kda_update`` and the per-slot walk) lowers to the program
-    it lowered to at PR 63, the parent of the PR that moved the prompt
-    kernel's boundary: operation for operation the same text and the same
-    two kernels (PERF.md, PR 64: compiled for the described v5e, the two
-    programs were the same HLO). A PR that changes the step on purpose
-    records the new digest here."""
+    it lowered to at PR 67, which made the expert layers of the step ONE
+    call each on purpose (``dstpu_moe_experts_decode``: the softmax layer's
+    and the delta-rule run's, in the program's order); the two kernels it had
+    are the ones of PR 63 (``bceee5ce4f1dad16`` then, which PR 64, the PR
+    that moved the prompt kernel's boundary, left as it was). A PR that
+    changes the step on purpose records the new digest here."""
     text = _lower_decode_step(_solar_cell, one_chip)[0].as_text()
-    # the per-slot walk (ONE_WIDTH_KERNELS' own) and ``dstpu_kda_update``
-    assert _program_digest(text) == ("bceee5ce4f1dad16", [
-        "d5750f9021028bf1", "4fe8519fbb6a26b5"])
+    # the per-slot walk (ONE_WIDTH_KERNELS' own), the softmax layer's
+    # experts, ``dstpu_kda_update``, the delta-rule run's experts
+    assert _program_digest(text) == ("32b706b6c16dd54e", [
+        "d5750f9021028bf1", "4fea0099f4601fe2", "4fe8519fbb6a26b5",
+        "7efad80a37eb6151"])
 
 
 def test_longcat_prefill_sorts_the_pairs_it_holds(one_chip, fused_routes):
